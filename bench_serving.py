@@ -87,6 +87,8 @@ import json
 import threading
 import time
 
+from xllm_service_tpu.runtime import compile_cache
+
 
 def load_sharegpt(path: str, n: int, rng):
     """(prompt_text, out_tokens) pairs from a ShareGPT-format JSON."""
@@ -1309,7 +1311,7 @@ def run_prefix_trace_bench(args) -> None:
                 ),
                 instance_name=f"pfx{i}", instance_type="DEFAULT",
                 enable_local_kv_transfer=False,  # measure the wire path
-                compilation_cache_dir="/tmp/xllm-jit-cache",
+                compilation_cache_dir=compile_cache.DEFAULT_DIR,
             )
             srv = InstanceServer(
                 ecfg, master_rpc_addr=master.rpc_address,
@@ -1602,7 +1604,7 @@ def run_mm_trace_bench(args) -> None:
             num_blocks=256, max_running_requests=16, max_seq_len=256,
             prefill_buckets=[64, 128], instance_name="mm-lm",
             instance_type="MIX",
-            compilation_cache_dir="/tmp/xllm-jit-cache",
+            compilation_cache_dir=compile_cache.DEFAULT_DIR,
         ),
         master_rpc_addr=master.rpc_address, heartbeat_interval_s=0.25,
     )
@@ -2012,11 +2014,6 @@ def main() -> None:
         and not args.mm_trace
     ):
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    plat = os.environ.get("JAX_PLATFORMS")
-    if plat:
-        import jax
-
-        jax.config.update("jax_platforms", plat)
 
     if args.trace_tails:
         run_trace_tails_bench(args)
@@ -2105,7 +2102,7 @@ def main() -> None:
                 instance_name=f"bench{i}",
                 instance_type=args.instance_type,
                 # persistent jit cache: repeat runs skip the compiles
-                compilation_cache_dir="/tmp/xllm-jit-cache",
+                compilation_cache_dir=compile_cache.DEFAULT_DIR,
             )
             return InstanceServer(
                 ecfg, master_rpc_addr=master.rpc_address,

@@ -183,36 +183,90 @@ def test_engine_ep_parity_overlap(cpu_devices, monkeypatch):
         moe_ops.set_ep_context(None)
 
 
-def test_spec_overlap_parity(cpu_devices, monkeypatch):
+def _spec_overlap_streams():
+    """One tp=2 speculative engine run: an accept-heavy greedy stream and
+    a reject-heavy seeded one."""
+    cfg = _cfg(tp_size=2, speculative_tokens=3)
+    eng = InferenceEngine(cfg, executor=ModelExecutor(cfg, init_seed=0))
+    cols = {}
+    for name, prompt, sp in [
+        ("accept", [7, 11, 13, 17] * 8,
+         SamplingParams(temperature=0.0, max_new_tokens=12)),
+        ("reject",
+         list(np.random.RandomState(42).randint(0, 500, size=29)),
+         SamplingParams(temperature=0.9, top_k=20, seed=7,
+                        max_new_tokens=9)),
+    ]:
+        c = C()
+        cols[name] = c
+        eng.add_request(EngineRequest(name, list(prompt), sp, c))
+    _drive(eng)
+    assert all(c.done.is_set() for c in cols.values())
+    assert eng.spec_pipeline_steps > 0
+    return {k: [int(t) for t in c.tokens] for k, c in cols.items()}, eng
+
+
+def _spec_overlap_child():
+    """Body of test_spec_overlap_parity, run in a process of its own:
+    prints one JSON line with both streams and whether the hatch took."""
+    import json
+    import os
+
+    off, _ = _spec_overlap_streams()
+    os.environ["XLLM_OVERLAP_COLLECTIVES"] = "1"
+    on, eng = _spec_overlap_streams()
+    print(json.dumps({
+        "off": off, "on": on,
+        "active": bool(eng.executor.overlap_collectives_active),
+    }), flush=True)
+
+
+def test_spec_overlap_parity():
     """Speculative decoding (the composed overlap+mixed pipeline) at
     tp=2: accept-heavy and reject-heavy streams under the hatch equal
     the hatch-off run byte for byte — the decomposed o-proj combine
-    rides the verify/mixed-verify builders too."""
-    def run():
-        cfg = _cfg(tp_size=2, speculative_tokens=3)
-        eng = InferenceEngine(cfg, executor=ModelExecutor(cfg, init_seed=0))
-        cols = {}
-        for name, prompt, sp in [
-            ("accept", [7, 11, 13, 17] * 8,
-             SamplingParams(temperature=0.0, max_new_tokens=12)),
-            ("reject",
-             list(np.random.RandomState(42).randint(0, 500, size=29)),
-             SamplingParams(temperature=0.9, top_k=20, seed=7,
-                            max_new_tokens=9)),
-        ]:
-            c = C()
-            cols[name] = c
-            eng.add_request(EngineRequest(name, list(prompt), sp, c))
-        _drive(eng)
-        assert all(c.done.is_set() for c in cols.values())
-        assert eng.spec_pipeline_steps > 0
-        return {k: c.tokens for k, c in cols.items()}, eng
+    rides the verify/mixed-verify builders too.
 
-    off, _ = run()
-    monkeypatch.setenv("XLLM_OVERLAP_COLLECTIVES", "1")
-    on, eng = run()
-    assert eng.executor.overlap_collectives_active
-    assert on == off
+    The engines run in a child process. On the virtual CPU mesh the
+    hatch-on engine deadlocks in about one run in three (rendezvous.cc:
+    one device waits in an all-reduce, the other in an all-to-all of the
+    same run; 0 of 6 hatch-off runs did) and XLA aborts the interpreter
+    60 s later, which would take the xdist worker down with it. A run
+    that dies that way is tried once more; a second death, or any
+    disagreement of the streams, fails the test."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    here = os.path.dirname(os.path.abspath(__file__))
+    env = dict(os.environ)
+    env.pop("XLLM_OVERLAP_COLLECTIVES", None)
+    code = ("import conftest, test_overlap_collectives as t; "
+            "t._spec_overlap_child()")
+    deaths = []
+    for _ in range(2):
+        try:
+            proc = subprocess.run(
+                [sys.executable, "-c", code], cwd=here, env=env,
+                capture_output=True, text=True, timeout=300,
+            )
+        except subprocess.TimeoutExpired:
+            deaths.append("no end after 300 s")
+            continue
+        stuck = [ln for ln in proc.stderr.splitlines()
+                 if "rendezvous.cc" in ln]
+        if proc.returncode < 0 or stuck:
+            deaths.append(f"rc {proc.returncode}: "
+                          + (stuck[-1][:300] if stuck else "killed"))
+            continue
+        assert proc.returncode == 0, proc.stderr[-4000:]
+        got = json.loads(proc.stdout.strip().splitlines()[-1])
+        assert got["active"]
+        assert got["on"] == got["off"]
+        return
+    pytest.fail("the tp=2 speculative engine hung twice in a row: "
+                + " | ".join(deaths), pytrace=False)
 
 
 def test_guided_overlap_parity(cpu_devices, monkeypatch):
@@ -437,14 +491,16 @@ def test_zero_fresh_lowerings_after_prewarm(cpu_devices):
 
 def test_cold_vs_warm_cache_equivalence(cpu_devices, tmp_path,
                                         monkeypatch):
-    """The keyed on-disk cache changes timings, never tokens: a cold
-    engine (fresh dir) and a warm engine (same dir, executables
-    reloaded from disk) emit identical streams, and the keyed dir
-    actually holds compiled entries after the cold run."""
+    """The on-disk cache changes timings, never tokens: a cold engine
+    (fresh dir) and a warm engine (same dir, executables reloaded from
+    disk) emit identical streams, and the dir actually holds compiled
+    entries after the cold run."""
     from xllm_service_tpu.runtime import compile_cache as cc
 
     # Persist even sub-second compiles so the warm run exercises disk.
     monkeypatch.setenv("XLLM_COMPILE_CACHE_MIN_COMPILE_S", "0")
+    # An outside placement wins over the config field; this test's is its own.
+    monkeypatch.delenv(cc.ENV_DIR, raising=False)
     base = str(tmp_path / "jit-cache")
     kw = dict(compilation_cache_dir=base)
 
@@ -456,17 +512,16 @@ def test_cold_vs_warm_cache_equivalence(cpu_devices, tmp_path,
         return {k: c.tokens for k, c in cols.items()}, eng
 
     cold, eng_cold = run()
-    key = eng_cold.executor.compile_cache_key
-    assert key
-    assert cc.cache_entries(base, key) > 0
+    assert eng_cold.executor.compile_cache_dir == base
+    assert cc.cache_entries(base) > 0
     warm, eng_warm = run()
-    assert eng_warm.executor.compile_cache_key == key
+    assert eng_warm.executor.compile_cache_dir == base
     assert warm == cold
 
 
 def test_cache_disabled_fallback(cpu_devices, tmp_path, monkeypatch):
-    """XLLM_COMPILE_CACHE=0 routes around the keyed persistent cache
-    entirely (no key, no dir, no on-disk writes) and the engine still
+    """XLLM_COMPILE_CACHE=0 routes around the persistent cache
+    entirely (no dir, no on-disk writes) and the engine still
     serves the identical streams — the hatch is an operational lever,
     never a numeric one."""
     off_dir = str(tmp_path / "never-used")
@@ -474,7 +529,7 @@ def test_cache_disabled_fallback(cpu_devices, tmp_path, monkeypatch):
 
     cfg = _tiny_cfg(compilation_cache_dir=off_dir)
     ex = ModelExecutor(cfg, init_seed=0)
-    assert ex.compile_cache_key == ""
+    assert ex.compile_cache_dir == ""
     eng = InferenceEngine(cfg, executor=ex)
     cols = _mixed_workload(eng)
     _drive(eng)
@@ -483,7 +538,7 @@ def test_cache_disabled_fallback(cpu_devices, tmp_path, monkeypatch):
     monkeypatch.delenv("XLLM_COMPILE_CACHE")
     ref, _ = _run_workload(model_cfg=_tiny_cfg)
     assert streams == ref
-    # The disabled run never materialized a keyed dir.
+    # The disabled run never materialized the dir.
     import os
     assert not os.path.isdir(off_dir) or not os.listdir(off_dir)
 
@@ -495,6 +550,9 @@ def test_prewarm_gates_on_start(cpu_devices, monkeypatch, tmp_path):
     the basic split warmup without one or under XLLM_COMPILE_CACHE=0 —
     the engine's compile_cache_prewarm_ms instrument reads the
     executor's report."""
+    from xllm_service_tpu.runtime import compile_cache as cc
+
+    monkeypatch.delenv(cc.ENV_DIR, raising=False)
     calls = []
 
     cfg = _tiny_cfg(

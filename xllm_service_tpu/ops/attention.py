@@ -84,14 +84,6 @@ def declared_shard_context():
     return getattr(_SHARD_TLS, "ctx", None)
 
 
-def _shard_map_fn():
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map
-
-
 def _cache_shard_spec(cache, axis: str):
     """shard_map spec pytree for a per-layer cache operand: data
     [N, Hc, BS, D] and int8 scale [N, Hc, G, BS] both carry the head
@@ -129,7 +121,7 @@ def _sharded_kernel_call(body, ctx, q_spec_ndim: int, q, k_cache, v_cache,
     q_spec = P(*(
         axis if i == head_ax else None for i in range(q_spec_ndim)
     ))
-    fn = _shard_map_fn()(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -138,7 +130,7 @@ def _sharded_kernel_call(body, ctx, q_spec_ndim: int, q, k_cache, v_cache,
             _cache_shard_spec(v_cache, axis),
         ) + (P(),) * len(rep_args),
         out_specs=q_spec,
-        check_rep=False,
+        check_vma=False,
     )
     return fn(q, k_cache, v_cache, *rep_args)
 
@@ -707,10 +699,9 @@ def mla_prefill_blockwise(
 
 @functools.lru_cache(maxsize=1)
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    # A backend that fails to initialise raises here: an engine meant for
+    # the chip must not be routed to the gather/blockwise reference.
+    return jax.devices()[0].platform == "tpu"
 
 
 def paged_attention(

@@ -89,14 +89,6 @@ def _note_site() -> None:
     _TRACE_TLS.sites = getattr(_TRACE_TLS, "sites", 0) + 1
 
 
-def _shard_map_fn():
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map
-
-
 def _ring_perm(n: int):
     return [(j, (j + 1) % n) for j in range(n)]
 
@@ -191,12 +183,12 @@ def maybe_overlap_matmul(
     from jax.sharding import PartitionSpec as P
 
     x_spec = P(*([None] * (x.ndim - 1) + [axis]))
-    fn = _shard_map_fn()(
+    fn = jax.shard_map(
         lambda xb, wb: _ring_matmul_body(xb, wb, axis=axis, n=n),
         mesh=mesh,
         in_specs=(x_spec, P(axis, None)),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     _note_site()
     return fn(x, w)

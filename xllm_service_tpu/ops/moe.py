@@ -394,19 +394,13 @@ def grouped_moe(
                 return cm_ops.ring_all_reduce(y, axis, n_shards)
             return jax.lax.psum(y, axis)
 
-        shard_map = (
-            jax.shard_map if hasattr(jax, "shard_map")
-            else __import__(
-                "jax.experimental.shard_map", fromlist=["shard_map"]
-            ).shard_map
-        )
-        fn = shard_map(
+        fn = jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(P(), P(), P(), P(), P(), P())
             + (P(axis, None, None),) * 3,
             out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )
         y_slots = fn(
             x, flat_e, rank, live, tok, counts, w_gate, w_up, w_down,

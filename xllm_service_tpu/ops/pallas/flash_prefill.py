@@ -269,7 +269,7 @@ def flash_prefill_kernel(
     if MBp != MB:
         bt = jnp.pad(bt, ((0, 0), (0, MBp - MB)))
 
-    hbm = pl.BlockSpec(memory_space=mosaic.hbm_space())
+    hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)
     in_specs = [
         pl.BlockSpec(
             (1, 1, 1, Rp, D), lambda p, h, t, bt, sp, tl: (p, h, t, 0, 0)
@@ -321,7 +321,7 @@ def flash_prefill_kernel(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((P, Hkv, NT, Rp, D), q.dtype),
-        compiler_params=mosaic.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(

@@ -233,7 +233,7 @@ def mla_attention_kernel(
     if MBp != MB:
         bt = jnp.pad(bt, ((0, 0), (0, MBp - MB)))
 
-    hbm = pl.BlockSpec(memory_space=mosaic.hbm_space())
+    hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)
     in_specs = [
         pl.BlockSpec((1, Hqp, C), lambda r, bt, sl: (r, 0, 0)),
         hbm,
@@ -267,7 +267,7 @@ def mla_attention_kernel(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R, Hqp, kv_rank), q_lat.dtype),
-        compiler_params=mosaic.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         cost_estimate=pl.CostEstimate(
@@ -315,7 +315,7 @@ def mla_multiquery_attention_kernel(
     if MBp != MB:
         bt = jnp.pad(bt, ((0, 0), (0, MBp - MB)))
 
-    hbm = pl.BlockSpec(memory_space=mosaic.hbm_space())
+    hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)
     in_specs = [
         pl.BlockSpec((1, S * Hqp, C), lambda r, bt, sl: (r, 0, 0)),
         hbm,
@@ -351,7 +351,7 @@ def mla_multiquery_attention_kernel(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R, S * Hqp, kv_rank), q_lat.dtype),
-        compiler_params=mosaic.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         cost_estimate=pl.CostEstimate(

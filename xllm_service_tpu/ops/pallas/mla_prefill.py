@@ -215,7 +215,7 @@ def mla_flash_prefill_kernel(
     if MBp != MB:
         bt = jnp.pad(bt, ((0, 0), (0, MBp - MB)))
 
-    hbm = pl.BlockSpec(memory_space=mosaic.hbm_space())
+    hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)
     in_specs = [
         pl.BlockSpec((1, 1, Rp, C), lambda p, t, bt, sp, tl: (p, t, 0, 0)),
         hbm,
@@ -254,7 +254,7 @@ def mla_flash_prefill_kernel(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((P, NT, Rp, kv_rank), q_lat.dtype),
-        compiler_params=mosaic.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(

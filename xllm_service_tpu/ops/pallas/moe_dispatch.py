@@ -249,7 +249,7 @@ def moe_grouped_dispatch_kernel(
     wu = w_up.reshape(Xl, E, NF, FT).transpose(0, 2, 1, 3)
     wd = w_down.reshape(Xl, NF, FT, E)
 
-    hbm = pl.BlockSpec(memory_space=mosaic.hbm_space())
+    hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(NT,),
@@ -275,7 +275,7 @@ def moe_grouped_dispatch_kernel(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((G, E), xg.dtype),
-        compiler_params=mosaic.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         cost_estimate=pl.CostEstimate(

@@ -97,29 +97,6 @@ def checked_at(ref, *idx):
     return ref.at[tuple(idx)]
 
 
-def hbm_space():
-    """The HBM memory-space enum across jax versions: newer jax exposes
-    `pltpu.MemorySpace.HBM` (the explicit pin the kernels want — under
-    ANY the compiler may place a small cache in VMEM where sub-128-lane
-    block slices are illegal); older releases only have
-    `pltpu.TPUMemorySpace.ANY`, their equivalent for DMA-from-HBM
-    operands."""
-    ms = getattr(pltpu, "MemorySpace", None)
-    if ms is not None and hasattr(ms, "HBM"):
-        return ms.HBM
-    return pltpu.TPUMemorySpace.ANY
-
-
-def compiler_params(**kw):
-    """`pltpu.CompilerParams(**kw)` with fallback to the pre-rename
-    `TPUCompilerParams` (jax < 0.5) — one shim instead of a per-kernel
-    version check."""
-    cp = getattr(pltpu, "CompilerParams", None)
-    if cp is None:
-        cp = pltpu.TPUCompilerParams
-    return cp(**kw)
-
-
 def async_copy(src, dst, sem):
     """`pltpu.make_async_copy` with rule-1 validation on both endpoints.
 

@@ -295,7 +295,7 @@ def paged_attention_kernel(
     # Pin the caches to HBM explicitly: under pl.ANY the compiler may place
     # a small cache in VMEM, where the [BS, D] per-block slice is illegal
     # for D < 128 (lane-padded tiling); HBM DMA slices are contiguous.
-    hbm = pl.BlockSpec(memory_space=mosaic.hbm_space())
+    hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)
     in_specs = [
         pl.BlockSpec((1, 1, Gp, D), lambda r, h, bt, sl: (r, h, 0, 0)),
         hbm,
@@ -343,7 +343,7 @@ def paged_attention_kernel(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R, Hkv, Gp, D), q.dtype),
-        compiler_params=mosaic.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         cost_estimate=pl.CostEstimate(
@@ -402,7 +402,7 @@ def multiquery_paged_attention_kernel(
     if MBp != MB:
         bt = jnp.pad(bt, ((0, 0), (0, MBp - MB)))
 
-    hbm = pl.BlockSpec(memory_space=mosaic.hbm_space())
+    hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)
     in_specs = [
         pl.BlockSpec((1, 1, S * Gp, D), lambda r, h, bt, sl: (r, h, 0, 0)),
         hbm,
@@ -450,7 +450,7 @@ def multiquery_paged_attention_kernel(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R, Hkv, S * Gp, D), q.dtype),
-        compiler_params=mosaic.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         cost_estimate=pl.CostEstimate(

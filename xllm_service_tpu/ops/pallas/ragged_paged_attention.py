@@ -55,9 +55,10 @@ Layouts: q [T, Hq_packed, D] (GQA head packing via the
 kernel_io_for/pack_queries contract happens in the ops.attention
 dispatcher), caches [N, Hkv, BS, D], block_tables [B, MB] int32,
 q_len/pos0 [B] int32. Returns [T, Hq, D]; dead rows emit zeros.
-Chip validation: scripts/validate_kernel_tpu.py ragged-* cases (queued
-via scripts/tpu_supervisor.py; opt-in XLLM_RAGGED_ATTENTION_KERNEL=1
-until PARITY OK per the repo convention).
+Chip validation: scripts/validate_kernel_tpu.py ragged-* cases (opt-in
+XLLM_RAGGED_ATTENTION_KERNEL=1 until PARITY OK per the repo convention);
+chip_smoke.py serves llama3-3b through it on a v5e and checks every
+served token against the dense forward.
 """
 
 from __future__ import annotations
@@ -340,7 +341,7 @@ def ragged_paged_attention_kernel(
         # columns are masked out by position anyway.
         bt = jnp.pad(bt, ((0, 0), (0, MBp - MB)))
 
-    hbm = pl.BlockSpec(memory_space=mosaic.hbm_space())
+    hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)
     in_specs = [
         pl.BlockSpec((1, 1, Rp, D), lambda t, h, *_: (h, t, 0, 0)),
         hbm,
@@ -392,7 +393,7 @@ def ragged_paged_attention_kernel(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Hkv, NT, Rp, D), q.dtype),
-        compiler_params=mosaic.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         cost_estimate=pl.CostEstimate(

@@ -49,11 +49,7 @@ def _ring_attention_local(
     B, Lc, Hq, D = q.shape
     Hkv = k.shape[2]
     G = Hq // Hkv
-    n = (
-        jax.lax.axis_size(axis_name)
-        if hasattr(jax.lax, "axis_size")
-        else jax.lax.psum(1, axis_name)  # jax < 0.5 spelling
-    )
+    n = jax.lax.axis_size(axis_name)
     me = jax.lax.axis_index(axis_name)
 
     qf = q.astype(jnp.float32).reshape(B, Lc, Hkv, G, D)
@@ -128,16 +124,8 @@ def ring_attention(
         scale=scale,
         causal=causal,
     )
-    if hasattr(jax, "shard_map"):
-        fn = jax.shard_map(
-            local, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-            check_vma=False,
-        )
-    else:  # jax < 0.6: the API (and the check_vma knob, née check_rep)
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        fn = _shard_map(
-            local, mesh, in_specs=(spec, spec, spec), out_specs=spec,
-            check_rep=False,
-        )
+    fn = jax.shard_map(
+        local, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False,
+    )
     return fn(q, k, v)

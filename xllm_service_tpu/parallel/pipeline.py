@@ -184,17 +184,10 @@ def pipeline_forward_dense(
         jax.tree.map(lambda _: P(pp_axis), params["layers"]),
         rep, rep, rep, rep,
     )
-    if hasattr(jax, "shard_map"):
-        fn = jax.shard_map(
-            local, mesh=mesh, in_specs=in_specs, out_specs=rep,
-            check_vma=False,
-        )
-    else:  # jax < 0.6: the API (and the check_vma knob, née check_rep)
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        fn = _shard_map(
-            local, mesh, in_specs=in_specs, out_specs=rep, check_rep=False
-        )
+    fn = jax.shard_map(
+        local, mesh=mesh, in_specs=in_specs, out_specs=rep,
+        check_vma=False,
+    )
     return fn(
         params["layers"], params["embed"], params["final_norm"], head,
         token_ids,

@@ -1,106 +1,70 @@
-"""Persistent AOT compile cache keying for the executor's bucket-program
-family (ISSUE 18 tentpole b — the host-side dispatch war).
+"""Where the persistent XLA compile cache lives, and whether the full
+bucket-program prewarm that feeds it is on (ISSUE 18 tentpole b — the
+host-side dispatch war).
 
 PR 11 measured a 2.7-4 s recompile ambush on the first post-idle
 dispatch of each bucket-program variant. Two layers kill that class:
 
-  * **On-disk persistence** — the executor routes jax's persistent
-    compilation cache into a subdirectory KEYED by (config hash, jax
-    version, mesh shape), so a restarted instance with the same
-    geometry reloads every compiled executable from disk instead of
-    re-running XLA, while a changed config/mesh/jax build gets a fresh
-    keyspace (no silent reuse of stale executables across geometries
-    that happen to share program shapes).
+  * **On-disk persistence** — jax's persistent compilation cache, so a
+    restarted instance with the same geometry reloads every compiled
+    executable from disk instead of re-running XLA. XLA's own cache key
+    (HLO + compile options + backend) already separates geometries, so
+    the directory is flat.
   * **Prewarm enumeration** — `ModelExecutor.prewarm_programs()` walks
     the FULL bucket-program family the engine can dispatch (context
     buckets x step builders x spec/guided variants) and compiles each
     through its jit entry point, populating both the in-process jit
     dispatch caches (zero fresh lowerings afterwards — the engine's
     compile-cache hit/miss instruments count against this) and the
-    keyed on-disk cache (warm restarts skip the XLA invocations).
+    on-disk cache (warm restarts skip the XLA invocations).
 
-Hatches: `XLLM_COMPILE_CACHE=0` disables the keyed persistent cache
-(and drops prewarm back to the basic split-step warmup);
-`XLLM_COMPILE_CACHE_DIR` overrides EngineConfig.compilation_cache_dir
-without a config edit (the bench's cold-vs-warm A/B lever).
+The directory can be placed from outside: where `JAX_COMPILATION_CACHE_DIR`
+is set jax reads it itself and NO code path of this repo sets any other
+directory (`resolve_cache_dir` is the one place that reads it). Where it
+is not set, entry points (the instance server, chip_smoke.py, the benches)
+configure one fixed path inside the checkout (`DEFAULT_DIR`: a directory
+that moves from run to run never hits), and a library caller may pass its
+own `EngineConfig.compilation_cache_dir`.
+
+Hatch: `XLLM_COMPILE_CACHE=0` disables the engine-configured cache (and
+drops prewarm back to the basic split-step warmup).
 """
 
 from __future__ import annotations
 
-import dataclasses
-import hashlib
 import os
-from typing import Optional
+
+ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    ".jax-compile-cache",
+)
 
 
 def compile_cache_enabled() -> bool:
-    """Whether the keyed persistent compile cache (and the full-family
-    prewarm that feeds it) is on. Default ON when a cache dir is
-    configured; =0 always wins."""
+    """Whether the persistent compile cache (and the full-family prewarm
+    that feeds it) is on. Default ON when a cache dir is configured; =0
+    always wins."""
     return os.environ.get("XLLM_COMPILE_CACHE", "1") not in (
         "0", "false", "off",
     )
 
 
-def resolve_cache_dir(engine_cfg) -> str:
-    """The base cache directory: XLLM_COMPILE_CACHE_DIR overrides the
-    config field; "" (no dir anywhere, or XLLM_COMPILE_CACHE=0) means
-    no persistent cache."""
+def resolve_cache_dir(configured: str) -> str:
+    """The directory compiles persist to, given the one configured
+    (`EngineConfig.compilation_cache_dir`; entry points configure
+    `DEFAULT_DIR`): the outside placement wins over it; "" (no dir
+    anywhere, or XLLM_COMPILE_CACHE=0) means no persistent cache."""
     if not compile_cache_enabled():
         return ""
-    return (
-        os.environ.get("XLLM_COMPILE_CACHE_DIR", "")
-        or getattr(engine_cfg, "compilation_cache_dir", "")
-        or ""
-    )
+    return os.environ.get(ENV_DIR) or configured or ""
 
 
-def _cfg_items(cfg) -> list:
-    if dataclasses.is_dataclass(cfg):
-        d = dataclasses.asdict(cfg)
-    elif hasattr(cfg, "__dict__"):
-        d = dict(vars(cfg))
-    else:
-        d = {"repr": repr(cfg)}
-    # The cache location must not key the cache contents (pointing the
-    # same geometry at a new dir would otherwise also change its key).
-    d.pop("compilation_cache_dir", None)
-    return sorted((k, repr(v)) for k, v in d.items())
-
-
-def cache_key(engine_cfg, model_cfg, mesh) -> str:
-    """Stable hex key for one executor geometry: engine + model config
-    hash, jax version, mesh (axis name, extent) pairs. Anything that
-    changes compiled programs MUST move the key — XLA's own cache keys
-    catch HLO-level drift, this layer keeps unrelated geometries from
-    interleaving in one directory (and makes `rm -rf <dir>/<key>` a
-    targeted invalidation)."""
-    import jax
-
-    h = hashlib.sha256()
-    for part in (
-        repr(_cfg_items(engine_cfg)),
-        repr(_cfg_items(model_cfg)),
-        jax.__version__,
-        repr(sorted((str(a), int(n)) for a, n in dict(mesh.shape).items())),
-    ):
-        h.update(part.encode())
-        h.update(b"\x00")
-    return h.hexdigest()[:16]
-
-
-def keyed_dir(base: str, key: str) -> str:
-    """The keyed cache subdirectory (created on first use)."""
-    path = os.path.join(base, key)
-    os.makedirs(path, exist_ok=True)
-    return path
-
-
-def cache_entries(base: str, key: str) -> int:
-    """How many compiled executables the keyed cache holds on disk
-    (the bench's cold/warm discriminator; -atime bookkeeping files
-    don't count)."""
-    path = os.path.join(base, key)
+def cache_entries(path: str) -> int:
+    """How many compiled executables the cache directory holds (the
+    cold/warm discriminator; -atime bookkeeping files don't count)."""
     if not os.path.isdir(path):
         return 0
     return sum(1 for f in os.listdir(path) if f.endswith("-cache"))

@@ -865,15 +865,15 @@ class InferenceEngine:
 
     def start(self) -> None:
         if self.cfg.warmup_on_start and hasattr(self.executor, "warmup"):
-            # With a keyed persistent cache dir configured, walk the
+            # With a persistent cache dir configured, walk the
             # FULL bucket-program family (runtime/compile_cache.py) so
             # no first-post-idle dispatch ever lowers fresh — the disk
             # cache amortizes the enumeration across restarts. Without
             # a dir the full walk would pay its whole compile bill
             # every start, so keep the classic split-step warmup.
-            if compile_cache_mod.resolve_cache_dir(self.cfg) and hasattr(
-                self.executor, "prewarm_programs"
-            ):
+            if compile_cache_mod.resolve_cache_dir(
+                self.cfg.compilation_cache_dir
+            ) and hasattr(self.executor, "prewarm_programs"):
                 self.executor.prewarm_programs()
             else:
                 self.executor.warmup()

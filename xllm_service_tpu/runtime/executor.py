@@ -124,12 +124,14 @@ _EMBED_INIT_LOCK = _threading.Lock()
 
 
 def _setup_compilation_cache(cache_dir: str) -> None:
-    """Set the process-global persistent jit cache ONCE (restarts / PD
-    role flips / elastic scale-outs then skip the 20-40 s/shape TPU
-    compiles). The jax config is process-global, so first non-empty dir
-    wins; a co-resident engine asking for a DIFFERENT dir gets a warning
-    and shares the first (an engine with "" simply doesn't call this —
-    it cannot unset what another engine enabled)."""
+    """Point jax's process-global persistent jit cache at `cache_dir`
+    ONCE (restarts / PD role flips / elastic scale-outs then skip the
+    per-shape TPU compiles). Where JAX_COMPILATION_CACHE_DIR places the
+    cache from outside, jax has read it already and no directory is set
+    here. The jax config is process-global, so first non-empty dir wins;
+    a co-resident engine asking for a DIFFERENT dir gets a warning and
+    shares the first (an engine with "" simply doesn't call this — it
+    cannot unset what another engine enabled)."""
     global _COMPILATION_CACHE_DIR
     if _COMPILATION_CACHE_DIR is not None:
         if _COMPILATION_CACHE_DIR != cache_dir:
@@ -143,20 +145,19 @@ def _setup_compilation_cache(cache_dir: str) -> None:
             )
         return
     _COMPILATION_CACHE_DIR = cache_dir
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    # jax initializes the persistent cache ONCE, at the first compile —
-    # any compile before this point (weight init of an earlier cacheless
-    # engine, a warmed-up sibling model) permanently pins it to the
-    # no-dir state and every later write silently vanishes. Reset so the
-    # next compile re-initializes against the dir just configured.
-    try:
+    if not os.environ.get(compile_cache_mod.ENV_DIR):
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+        # jax initializes the persistent cache ONCE, at the first
+        # compile — any compile before this point (weight init of an
+        # earlier cacheless engine, a warmed-up sibling model)
+        # permanently pins it to the no-dir state and every later write
+        # silently vanishes. Reset so the next compile re-initializes
+        # against the dir just configured.
         from jax.experimental.compilation_cache import (
             compilation_cache as _jax_cc,
         )
 
         _jax_cc.reset_cache()
-    except Exception:
-        pass  # never let cache plumbing take an engine down
     # XLLM_COMPILE_CACHE_MIN_COMPILE_S: persistence floor (s) below which
     # a compile isn't written to disk. 0.5 keeps TPU caches lean; the
     # CPU bench/tests pin 0 so their sub-second programs persist and the
@@ -243,19 +244,14 @@ class ModelExecutor:
                 )
             self.cfg = resolved
 
-        # Persistent compile cache, KEYED by (config hash, jax version,
-        # mesh shape): a restarted instance with the same geometry
-        # reloads every executable from disk; a changed geometry gets a
-        # fresh keyspace (runtime/compile_cache.py, ISSUE 18).
-        self.compile_cache_key = ""
-        _cache_base = compile_cache_mod.resolve_cache_dir(engine_cfg)
-        if _cache_base:
-            self.compile_cache_key = compile_cache_mod.cache_key(
-                engine_cfg, self.cfg, self.mesh
-            )
-            _setup_compilation_cache(
-                compile_cache_mod.keyed_dir(_cache_base, self.compile_cache_key)
-            )
+        # Persistent compile cache (runtime/compile_cache.py, ISSUE 18): a
+        # restarted instance with the same geometry reloads every
+        # executable from disk. "" = this engine configured none.
+        self.compile_cache_dir = compile_cache_mod.resolve_cache_dir(
+            engine_cfg.compilation_cache_dir
+        )
+        if self.compile_cache_dir:
+            _setup_compilation_cache(self.compile_cache_dir)
         # Prewarm bookkeeping (prewarm_programs): lowerings present when
         # the prewarm finished (0 = never prewarmed — every lowering is
         # a compile-cache miss for the engine's instruments).
@@ -587,6 +583,22 @@ class ModelExecutor:
                 )
                 self.params[stack][name] = qfn(leaf)
 
+    def _device_bytes_limit(self) -> int:
+        """Device memory the pool is sized against. A TPU that reports no
+        `bytes_limit` is an error (guessing a size hides the device); the
+        CPU backend reports none, so there the pool sizes against a nominal
+        16 GiB (tests and CPU benches pass num_blocks explicitly)."""
+        dev = self.mesh.devices.flat[0]
+        stats = dev.memory_stats() or {}
+        if "bytes_limit" in stats:
+            return stats["bytes_limit"]
+        if dev.platform == "tpu":
+            raise RuntimeError(
+                f"{dev} reports no bytes_limit in memory_stats(); cannot "
+                f"size the KV pool — pass num_blocks explicitly"
+            )
+        return 16 * 2**30
+
     def _decide_num_blocks(self) -> int:
         if self.engine_cfg.num_blocks > 0:
             return self.engine_cfg.num_blocks
@@ -605,15 +617,16 @@ class ModelExecutor:
             "int4": 0.65,
         }.get(self.engine_cfg.weight_dtype, dtype_bytes)
         n_params = approx_param_count(cfg)
-        try:
-            stats = jax.devices()[0].memory_stats() or {}
-            total_hbm = stats.get("bytes_limit", 16 * 2**30)
-        except Exception:
-            total_hbm = 16 * 2**30
+        total_hbm = self._device_bytes_limit()
         tp = self.mesh.shape.get("tp", 1)
-        # XLA's AOT peak-memory estimate counts donated KV caches on both
-        # sides of the step, so budget for 2x the pool (params are not
-        # donated and count once).
+        # Budget for 2x the pool (params count once): the step programs
+        # scan the layers with the caches as scanned inputs AND stacked
+        # outputs, and XLA gives the stacked outputs a buffer of their own
+        # (an AllocateBuffer the size of each cache among the HLO temps)
+        # before the donated arguments are reused. On a v5e (15.75 GiB)
+        # llama3-3b gets 299 blocks this way and runs; built with 400 the
+        # chip's compiler refuses the decode step at 17.23G, with 512 at
+        # 20.50G (PERF.md, PR 26).
         budget = (
             total_hbm * self.engine_cfg.hbm_utilization
             - n_params * param_bytes / tp
@@ -1327,7 +1340,7 @@ class ModelExecutor:
         DIFFERENT lowering than the host-fed sync call), the fused
         mixed prefill+decode family (CBd x (Lpad, CBp), both feedback
         variants), and the pipelined verify / mixed-verify programs
-        when speculative decoding is configured. With the keyed
+        when speculative decoding is configured. With the
         persistent cache enabled every compile also lands on disk, so a
         warm restart replays this walk as disk reads.
 
