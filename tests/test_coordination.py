@@ -115,6 +115,32 @@ class TestMemoryStore:
         store.revoke_lease(lease)
         assert store.get("R:x") is None
 
+    @pytest.mark.parametrize(
+        "stalled_s,injected_clock,kept",
+        [(4.0, False, True), (0.2, False, False), (4.0, True, False)],
+        ids=["stalled-sweep-gives-time-back", "late-within-grace", "injected-clock"],
+    )
+    def test_stalled_sweep_does_not_expire_leases(
+        self, stalled_s, injected_clock, kept
+    ):
+        """A sweep that comes seconds late (the process was held up, so no
+        holder could keep its lease alive either) extends the leases by the
+        lost time; a punctual one, or lease time on an injected clock, does
+        not. No wall clock: the lease is made overdue by hand."""
+        now = [100.0]
+        st = MemoryStore(clock=(lambda: now[0]) if injected_clock else None)
+        try:
+            lease = st.grant_lease(ttl_s=30)
+            st.set("S:master", "me", lease_id=lease)
+            with st._mu:
+                # overdue by 1 s: what a 4 s stall leaves of a 3 s lease
+                st._leases[lease].expires_at = st._clock() - 1.0
+                assert st._sweep_once(stalled_s)
+            assert (st.get("S:master") == "me") is kept
+            assert st.keepalive(lease) is kept
+        finally:
+            st.close()
+
     def test_compare_create_single_winner(self, store):
         wins = sum(
             store.compare_create("E:master", f"id{i}") for i in range(5)

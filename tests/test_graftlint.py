@@ -532,6 +532,27 @@ class TestSpanStages:
         from xllm_service_tpu.obs.spans import ALL_SPAN_STAGES
         assert SpanStagesPass().vocab == frozenset(ALL_SPAN_STAGES)
 
+    @pytest.mark.parametrize("src,bad", [
+        ('with self._phases.phase("dispatch"):\n'
+         '    with phase("sampling", annotate=False):\n        pass\n',
+         "sampling"),
+        ('with _leaf("launch"):\n    pass\nwith _leaf("fetch"):\n    pass\n',
+         "fetch"),
+    ], ids=["engine-phase", "executor-leaf"])
+    def test_off_vocabulary_phase_or_leaf_trips(self, src, bad):
+        fs = run_one(self._pass(), src)
+        assert len(fs) == 1 and fs[0].line in (2, 3)
+        assert repr(bad) in fs[0].message
+
+    def test_phase_like_names_are_not_phases(self):
+        # another object's method, a non-literal, and the def itself
+        src = (
+            'moon._phase("waxing")\n'
+            'with phase(name):\n    pass\n'
+            'def phase(self, name):\n    pass\n'
+        )
+        assert run_one(self._pass(), src) == []
+
     def test_registry_rows_point_at_live_needles(self):
         # The shipped TRACE_PLANES rows must hold on the real tree (the
         # repo-wide run below enforces this too; this pins the registry

@@ -1,7 +1,7 @@
 """Span-stages pass: distributed-tracing vocabulary + plane coverage.
 
-Two layers, mirroring the fault-points registry idiom
-(docs/OBSERVABILITY.md "Distributed tracing"):
+Three layers, mirroring the fault-points registry idiom
+(docs/OBSERVABILITY.md "Distributed tracing", "Engine step timeline"):
 
 * VOCABULARY — scan the package plus the bench entry points for every
   literal stage emitted through a tracing surface
@@ -10,6 +10,13 @@ Two layers, mirroring the fault-points registry idiom
   member of the canonical vocabulary (`obs.spans.ALL_SPAN_STAGES`). A
   stage outside the vocabulary renders as an orphan track in the merged
   Perfetto timeline and silently escapes `blame_stages`' edges.
+
+* ENGINE PHASES — every literal handed to `EnginePhases.phase(...)`
+  (the engine loop's step timeline) must be in
+  `obs.spans.ENGINE_PHASES`, and every executor leaf annotation
+  (`_leaf(...)`) in `obs.spans.EXECUTOR_LEAVES`: a phase outside the
+  vocabulary has no `xllm_engine_loop_seconds_total` child, and the
+  benchmark's readers key on the names.
 
 * TRACE PLANES — a registry of RPC-client call sites (one row per
   cross-process plane: dispatch, PD handoff commit, KV stream OPEN,
@@ -35,6 +42,12 @@ EMIT_RE = re.compile(
     r"(?:\.stage|\.emit|\bspan_hook|\b_span|\b_span_hook)"
     r"\(\s*[^,()]*,\s*[\r\n ]*[\"']([a-z_]+)[\"']"
 )
+
+# An engine-loop phase scope / an executor leaf annotation with a LITERAL
+# name: `phase("dispatch")`, `self._phases.phase("idle", ...)`,
+# `_leaf("launch")`.
+PHASE_RE = re.compile(r"(?<![A-Za-z0-9_])phase\(\s*[\"']([a-z_]+)[\"']")
+LEAF_RE = re.compile(r"(?<![A-Za-z0-9_])_leaf\(\s*[\"']([a-z_]+)[\"']")
 
 # Contractual trace-context forwarding sites, one row per RPC plane:
 # (repo-relative file, verbatim needle, plane). The needle is the exact
@@ -72,11 +85,15 @@ class SpanStagesPass(LintPass):
         self,
         vocab: Optional[Sequence[str]] = None,
         planes: Optional[Sequence[Tuple[str, str, str]]] = None,
+        phases: Optional[Sequence[str]] = None,
+        leaves: Optional[Sequence[str]] = None,
     ):
         # Injectable for fixture tests; the repo run uses the canonical
-        # vocabulary and the plane registry above.
+        # vocabularies and the plane registry above.
         self._vocab = vocab
         self.planes = TRACE_PLANES if planes is None else tuple(planes)
+        self._phases = phases
+        self._leaves = leaves
 
     @property
     def vocab(self) -> frozenset:
@@ -86,10 +103,35 @@ class SpanStagesPass(LintPass):
             self._vocab = ALL_SPAN_STAGES
         return frozenset(self._vocab)
 
+    @property
+    def phase_vocabs(self) -> tuple:
+        """(pattern, names, what, vocabulary's name) per name family."""
+        from xllm_service_tpu.obs import spans
+
+        phases, leaves = self._phases, self._leaves
+        if phases is None:
+            phases = spans.ENGINE_PHASES
+        if leaves is None:
+            leaves = spans.EXECUTOR_LEAVES
+        return (
+            (PHASE_RE, frozenset(phases), "engine phase", "ENGINE_PHASES"),
+            (LEAF_RE, frozenset(leaves), "executor leaf", "EXECUTOR_LEAVES"),
+        )
+
     def run(self, project: Project) -> List[Finding]:
         findings: List[Finding] = []
         vocab = self.vocab
         for src in project.all_lintable():
+            for pattern, names, what, where in self.phase_vocabs:
+                for m in pattern.finditer(src.text):
+                    if m.group(1) in names:
+                        continue
+                    line = src.text.count("\n", 0, m.start()) + 1
+                    findings.append(Finding(
+                        self.id, src.rel, line,
+                        f"{what} {m.group(1)!r} is not in obs.spans.{where}"
+                        f" — it would have no counter child and no reader",
+                    ))
             for m in EMIT_RE.finditer(src.text):
                 stage = m.group(1)
                 if stage in vocab:

@@ -172,6 +172,12 @@ class CoordinationStore:
 # ---------------------------------------------------------------------------
 
 
+# MemoryStore's sweeper: its period, and how late a sweep must come before
+# the process counts as having been stalled (MemoryStore._sweep_once).
+_SWEEP_PERIOD_S = 0.05
+_STALL_GRACE_S = 0.5
+
+
 class _Lease:
     __slots__ = ("lease_id", "ttl_s", "expires_at", "keys")
 
@@ -251,22 +257,43 @@ class MemoryStore(CoordinationStore):
                         pass
 
     def _sweep_loop(self) -> None:
+        last = time.monotonic()
         while True:
-            time.sleep(0.05)
-            with self._mu:
-                if self._closed:
-                    return
-                now = self._clock()
-                expired = [l for l in self._leases.values() if l.expires_at <= now]
-                events: List[WatchEvent] = []
-                for lease in expired:
-                    for key in lease.keys:
-                        if self._key_lease.get(key) == lease.lease_id:
-                            self._kv.pop(key, None)
-                            self._key_lease.pop(key, None)
-                            events.append(WatchEvent(EventType.DELETE, key))
-                    del self._leases[lease.lease_id]
-                self._emit(events)
+            time.sleep(_SWEEP_PERIOD_S)
+            woke = time.monotonic()
+            stalled_s, last = woke - last - _SWEEP_PERIOD_S, woke
+            if not self._sweep_once(stalled_s):
+                return
+
+    def _sweep_once(self, stalled_s: float = 0.0) -> bool:
+        """Expire what is due; False once the store is closed.
+
+        `stalled_s` is how late this sweep came on the real clock. This
+        store and every holder of its leases live in ONE process, so a
+        sweep that is seconds late (a C call that kept the GIL: device
+        start-up, a compile, a large executable being serialized) means
+        no holder could send its keepalive either. The lost time is given
+        back to every lease, as etcd extends leases after a stalled
+        leader, instead of fencing a master for the store's own absence.
+        Lease time on an injected clock is the test's to move: untouched."""
+        with self._mu:
+            if self._closed:
+                return False
+            if stalled_s > _STALL_GRACE_S and self._clock is time.monotonic:
+                for lease in self._leases.values():
+                    lease.expires_at += stalled_s
+            now = self._clock()
+            expired = [l for l in self._leases.values() if l.expires_at <= now]
+            events: List[WatchEvent] = []
+            for lease in expired:
+                for key in lease.keys:
+                    if self._key_lease.get(key) == lease.lease_id:
+                        self._kv.pop(key, None)
+                        self._key_lease.pop(key, None)
+                        events.append(WatchEvent(EventType.DELETE, key))
+                del self._leases[lease.lease_id]
+            self._emit(events)
+            return True
 
     def _attach(self, key: str, lease_id: int) -> None:
         # caller holds _mu

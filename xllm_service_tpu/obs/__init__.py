@@ -24,9 +24,13 @@ from xllm_service_tpu.obs.metrics import (
 from xllm_service_tpu.obs.flight import FlightRecorder, SpanRing
 from xllm_service_tpu.obs.spans import (
     ALL_SPAN_STAGES,
+    ENGINE_PHASES,
+    EXECUTOR_LEAVES,
     INSTANCE_SPAN_STAGES,
     SPAN_STAGES,
     ClockSync,
+    EnginePhases,
+    annotation,
     assemble_trace,
     blame_stages,
     build_timeline,
@@ -46,9 +50,13 @@ __all__ = [
     "parse_exposition",
     "render_families",
     "ALL_SPAN_STAGES",
+    "ENGINE_PHASES",
+    "EXECUTOR_LEAVES",
     "INSTANCE_SPAN_STAGES",
     "SPAN_STAGES",
     "ClockSync",
+    "EnginePhases",
+    "annotation",
     "FlightRecorder",
     "SpanRing",
     "assemble_trace",

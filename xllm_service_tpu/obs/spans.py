@@ -18,8 +18,9 @@ disaggregated serving is tuned by.
 from __future__ import annotations
 
 import json
+import time
 from collections import OrderedDict
-from typing import Any, Dict, Iterable, List, Tuple
+from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
 SPAN_STAGES = (
     "receive",
@@ -69,6 +70,116 @@ ALL_SPAN_STAGES = SPAN_STAGES + INSTANCE_SPAN_STAGES
 
 # Terminal stages close a request's timeline.
 TERMINAL_STAGES = frozenset(("finish", "cancel", "error", "shed"))
+
+# Engine-loop phase vocabulary (docs/OBSERVABILITY.md "Engine step
+# timeline"): what the engine THREAD is doing, exclusive and contiguous
+# over every iteration of InferenceEngine._loop_owned. Each phase is a
+# `jax.profiler.TraceAnnotation("xllm.engine.<phase>")` on the profiler's
+# clock and one child of `xllm_engine_loop_seconds_total{phase=...}`.
+# The span-stages lint pass rejects a phase literal outside this tuple.
+ENGINE_PHASES = (
+    "idle",          # _work.wait with nothing to do
+    "housekeeping",  # imports, exports, cancels, schema-row flush
+    "schedule",      # chunk cutting, admission, block allocation
+    "dispatch",      # capacity pass, host inputs, the executor call
+    "device_wait",   # blocking reads of a step's device results
+    "emit",          # per-token bookkeeping, stop checks, callbacks
+)
+
+# Leaf annotations inside the executor's dispatch entry points
+# ("xllm.executor.<leaf>"): what the host does before a step launches.
+EXECUTOR_LEAVES = ("step_keys", "host_inputs", "launch")
+
+_TRACE_ANNOTATION = None
+
+
+def annotation(name: str):
+    """A `jax.profiler.TraceAnnotation(name)`: a host event on the
+    profiler's own clock, recorded only while a profiler session runs.
+    JAX is resolved on first use — the master and the load generators
+    import `obs` and must not pull JAX in with it."""
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+
+        _TRACE_ANNOTATION = TraceAnnotation
+    return _TRACE_ANNOTATION(name)
+
+
+class _PhaseScope:
+    __slots__ = ("_owner", "_name", "_annotate")
+
+    def __init__(self, owner: "EnginePhases", name: str, annotate: bool):
+        self._owner = owner
+        self._name = name
+        self._annotate = annotate
+
+    def __enter__(self) -> None:
+        self._owner._push(self._name, self._annotate)
+
+    def __exit__(self, *exc) -> None:
+        self._owner._pop()
+
+
+class EnginePhases:
+    """The engine thread's time by phase: one helper, two outputs.
+
+    `with phases.phase("dispatch"):` opens the phase's annotation and, on
+    leaving, adds the elapsed seconds of `clock` to `add(phase, seconds)`
+    (the engine hands in its labelled counter). Phases are EXCLUSIVE:
+    entering one inside another suspends the outer — its interval and
+    its annotation close, and both reopen when the inner one leaves — so
+    the seconds of all phases sum to the time spent under the outermost
+    scope and no annotation of this helper ever encloses another.
+    `annotate=False` keeps the counter and opens no annotation: the
+    engine uses it around the executor call, whose own leaf annotations
+    (EXECUTOR_LEAVES) then stay leaves. Single-threaded by contract (the
+    engine thread); outside any scope nothing is recorded."""
+
+    def __init__(
+        self,
+        add: Callable[[str, float], None],
+        clock: Callable[[], float] = time.monotonic,
+        annotate: Optional[Callable[[str], Any]] = annotation,
+    ):
+        self._add = add
+        self._clock = clock
+        self._annotate = annotate
+        self._stack: List[Tuple[str, bool]] = []
+        self._since = 0.0
+        self._open: Any = None
+
+    def phase(self, name: str, annotate: bool = True) -> _PhaseScope:
+        if name not in ENGINE_PHASES:
+            raise ValueError(f"{name!r} is not one of ENGINE_PHASES")
+        return _PhaseScope(self, name, annotate)
+
+    def _close(self, now: float) -> None:
+        if self._open is not None:
+            self._open.__exit__(None, None, None)
+            self._open = None
+        if self._stack:
+            self._add(self._stack[-1][0], now - self._since)
+
+    def _begin(self, now: float) -> None:
+        self._since = now
+        if self._stack:
+            name, annotate = self._stack[-1]
+            if annotate and self._annotate is not None:
+                self._open = self._annotate("xllm.engine." + name)
+                self._open.__enter__()
+
+    def _push(self, name: str, annotate: bool) -> None:
+        now = self._clock()
+        self._close(now)
+        self._stack.append((name, annotate))
+        self._begin(now)
+
+    def _pop(self) -> None:
+        now = self._clock()
+        self._close(now)
+        self._stack.pop()
+        self._begin(now)
 
 
 def load_spans(path: str) -> List[Dict[str, Any]]:
@@ -187,11 +298,6 @@ def to_chrome_trace(records: Iterable[Dict[str, Any]]) -> Dict[str, Any]:
                     }
                 )
     return {"traceEvents": events, "displayTimeUnit": "ms"}
-
-
-def write_chrome_trace(records: Iterable[Dict[str, Any]], path: str) -> None:
-    with open(path, "w", encoding="utf-8") as f:
-        json.dump(to_chrome_trace(records), f)
 
 
 # --------------------------------------------------------------------- #

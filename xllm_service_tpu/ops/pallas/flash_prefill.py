@@ -319,6 +319,7 @@ def flash_prefill_kernel(
     )
     out = pl.pallas_call(
         kernel,
+        name="flash_prefill_kernel",  # op name in the device trace
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((P, Hkv, NT, Rp, D), q.dtype),
         compiler_params=pltpu.CompilerParams(

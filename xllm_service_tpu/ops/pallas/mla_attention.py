@@ -265,6 +265,7 @@ def mla_attention_kernel(
     )
     out = pl.pallas_call(
         kernel,
+        name="mla_attention_kernel",  # op name in the device trace
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R, Hqp, kv_rank), q_lat.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -349,6 +350,7 @@ def mla_multiquery_attention_kernel(
     )
     out = pl.pallas_call(
         kernel,
+        name="mla_multiquery_attention_kernel",  # op name in the device trace
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R, S * Hqp, kv_rank), q_lat.dtype),
         compiler_params=pltpu.CompilerParams(

@@ -252,6 +252,7 @@ def mla_flash_prefill_kernel(
     )
     out = pl.pallas_call(
         kernel,
+        name="mla_flash_prefill_kernel",  # op name in the device trace
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((P, NT, Rp, kv_rank), q_lat.dtype),
         compiler_params=pltpu.CompilerParams(

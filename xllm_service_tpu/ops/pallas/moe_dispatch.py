@@ -273,6 +273,7 @@ def moe_grouped_dispatch_kernel(
     wbytes = wg.dtype.itemsize
     return pl.pallas_call(
         kernel,
+        name="moe_grouped_dispatch_kernel",  # op name in the device trace
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((G, E), xg.dtype),
         compiler_params=pltpu.CompilerParams(

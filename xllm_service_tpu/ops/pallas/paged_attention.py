@@ -341,6 +341,7 @@ def paged_attention_kernel(
     )
     out = pl.pallas_call(
         kernel,
+        name="paged_attention_kernel",  # op name in the device trace
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R, Hkv, Gp, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -448,6 +449,7 @@ def multiquery_paged_attention_kernel(
     )
     out = pl.pallas_call(
         kernel,
+        name="multiquery_paged_attention_kernel",  # op name in the device trace
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R, Hkv, S * Gp, D), q.dtype),
         compiler_params=pltpu.CompilerParams(

@@ -391,6 +391,7 @@ def ragged_paged_attention_kernel(
     )
     out = pl.pallas_call(
         kernel,
+        name="ragged_paged_attention_kernel",  # op name in the device trace
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Hkv, NT, Rp, D), q.dtype),
         compiler_params=pltpu.CompilerParams(

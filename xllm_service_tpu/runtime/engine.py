@@ -46,7 +46,9 @@ from xllm_service_tpu.common.types import (
 )
 from xllm_service_tpu.obs import (
     BATCH_BUCKETS,
+    ENGINE_PHASES,
     LATENCY_BUCKETS_MS,
+    EnginePhases,
     MetricsRegistry,
 )
 from xllm_service_tpu.ops.sampling import SamplingParams
@@ -65,6 +67,11 @@ class EngineRequest:
     # common/xllm/output.h:131).
     callback: Callable[[RequestOutput], bool]
     arrival_time: float = field(default_factory=time.monotonic)
+    # Stamped by InferenceEngine.add_request: the start of the engine's
+    # queue wait (xllm_engine_queue_wait_ms), zeroed again when the first
+    # prefill chunk dispatches. arrival_time above is when the request
+    # object was built and orders preemption; it is not this.
+    queued_at: float = 0.0
     # PD disaggregation (prefill side): emit the first token, then hand the
     # sequence off instead of decoding (reference flow: prefill instance
     # returns the first chunk, decode instance continues —
@@ -287,9 +294,11 @@ class _InFlight:
         self.nactive = nactive
         self.total_ctx = total_ctx
         # Mixed (ragged) step: [(seq, admit_gen, row_idx, chunk_start,
-        # chunk_end)] prefill rows riding this dispatch — their sampled
-        # tokens sit at output index R + row_idx (docs/KERNELS.md), or in
-        # pf_tok/pf_lp when this is a speculative verify step.
+        # chunk_end, queued_ms — the engine queue wait on a request's
+        # first chunk, else None)] prefill rows riding this dispatch —
+        # their sampled tokens sit at output index R + row_idx
+        # (docs/KERNELS.md), or in pf_tok/pf_lp when this is a
+        # speculative verify step.
         self.pf = pf
         # Pipelined speculative verify: tokens/logprobs are [R, S] and
         # each slot consumes its first n_emit[slot] entries at drain
@@ -299,6 +308,11 @@ class _InFlight:
         self.pf_tok = pf_tok
         self.pf_lp = pf_lp
 
+
+# get_latency_metrics looks back at most this far (its default is 30 s).
+LATENCY_WINDOW_S = 60.0
+# Samples kept of each TimePredictor seed curve (engine.profiling_data).
+PROFILE_SAMPLES = 512
 
 # The waiting queue holds fresh EngineRequests and preempted _Seqs (which
 # resume with their full token history + generation accounting intact).
@@ -490,11 +504,19 @@ class InferenceEngine:
         self.host_gap_ms_sum = 0.0
         self.host_gap_steps = 0
         self._t_host_free: Optional[float] = None
-        # Latency windows (ms) for LatencyMetrics.
+        # Latency windows (ms) for LatencyMetrics: (t, ms) per finished
+        # prefill and (t, worst tbt ms) per drained STEP. Only the engine
+        # thread mutates them (append + trim past LATENCY_WINDOW_S);
+        # get_latency_metrics reads a GIL-atomic copy from other threads.
         self._ttft_window: Deque[Tuple[float, float]] = collections.deque()
         self._tbt_window: Deque[Tuple[float, float]] = collections.deque()
+        # TimePredictor seed curves, sent once at instance registration
+        # (profiling_data): the first PROFILE_SAMPLES of each, then no more.
         self._profile_ttft: List[Tuple[int, float]] = []
         self._profile_tpot: List[Tuple[int, int, float]] = []
+        # Work done by prefill, as counts (exported by pull functions).
+        self.prefill_chunks = 0
+        self.prefill_tokens = 0  # tokens computed; cached prefix excluded
         # Guided decoding context (set_guided_context): device mask table
         # lives on the executor; the engine keeps token bytes + row
         # liveness for exact host tracking.
@@ -545,6 +567,12 @@ class InferenceEngine:
         # hybrid-scheduling eviction).
         self.preemptions = 0
         self._build_metrics()
+        # The executor's synchronous entry points (decode, verify,
+        # prefill_batch, prefill_long) block on their results inside the
+        # call: that read is the loop's `device_wait`, not `dispatch`.
+        self.executor.fetch_scope = (
+            lambda: self._phases.phase("device_wait")
+        )
 
     def _build_metrics(self) -> None:
         """Engine registry (obs.metrics), rendered into the instance's
@@ -555,9 +583,38 @@ class InferenceEngine:
         extra."""
         self.metrics = MetricsRegistry()
         self._m_ttft = self.metrics.histogram(
-            "xllm_engine_ttft_ms", "Prefill time to first token",
+            "xllm_engine_ttft_ms", "Dispatch of a request's first prefill "
+            "chunk to its first token (add_request to that dispatch is "
+            "xllm_engine_queue_wait_ms; the two sum to the engine's TTFT)",
             buckets=LATENCY_BUCKETS_MS,
         )
+        self._m_queue_wait = self.metrics.histogram(
+            "xllm_engine_queue_wait_ms", "add_request to the dispatch of "
+            "the request's first prefill chunk, once per request (first "
+            "dispatch to first token is xllm_engine_ttft_ms)",
+            buckets=LATENCY_BUCKETS_MS,
+        )
+        self.metrics.counter(
+            "xllm_engine_prefill_chunks_total",
+            "Prefill chunks dispatched (rows of mixed steps and of split "
+            "prefill steps)",
+        ).set_function(lambda: self.prefill_chunks)
+        self.metrics.counter(
+            "xllm_engine_prefill_tokens_total",
+            "Prompt tokens computed by prefill (cached prefix excluded)",
+        ).set_function(lambda: self.prefill_tokens)
+        # Engine step timeline (docs/OBSERVABILITY.md): the engine
+        # thread's seconds by exclusive phase; the same scopes are
+        # xllm.engine.<phase> annotations on the profiler's clock.
+        loop_seconds = self.metrics.counter(
+            "xllm_engine_loop_seconds_total",
+            "Engine thread time by exclusive loop phase",
+            labelnames=("phase",),
+        )
+        phase_inc = {
+            p: loop_seconds.labels(phase=p).inc for p in ENGINE_PHASES
+        }
+        self._phases = EnginePhases(lambda p, dt: phase_inc[p](dt))
         self._m_tbt = self.metrics.histogram(
             "xllm_engine_tbt_ms", "Time between tokens per running "
             "sequence", buckets=LATENCY_BUCKETS_MS,
@@ -824,6 +881,7 @@ class InferenceEngine:
     # -------------------------------------------------------------- public
 
     def add_request(self, req: EngineRequest) -> None:
+        req.queued_at = time.monotonic()
         with self._lock:
             self._waiting.append(req)
         self._work.set()
@@ -908,14 +966,31 @@ class InferenceEngine:
         )
 
     def get_latency_metrics(self, window_s: float = 30.0) -> LatencyMetrics:
-        now = time.monotonic()
-        for dq in (self._ttft_window, self._tbt_window):
-            while dq and now - dq[0][0] > window_s:
-                dq.popleft()
+        """Heartbeat / scrape threads: never mutates the windows. tuple(dq)
+        copies under the GIL in one C call, so the engine thread's appends
+        cannot fail it (a Python-level iteration of the live deque did:
+        "deque mutated during iteration" killed the heartbeat)."""
+        horizon = time.monotonic() - window_s
+
+        def recent_max(dq) -> int:
+            return int(
+                max((v for t, v in tuple(dq) if t >= horizon), default=0)
+            )
+
         return LatencyMetrics(
-            recent_max_ttft=int(max((v for _, v in self._ttft_window), default=0)),
-            recent_max_tbt=int(max((v for _, v in self._tbt_window), default=0)),
+            recent_max_ttft=recent_max(self._ttft_window),
+            recent_max_tbt=recent_max(self._tbt_window),
         )
+
+    def _window_append(self, dq, now: float, ms: float) -> None:
+        """Engine thread: one latency-window sample, old ones trimmed."""
+        dq.append((now, ms))
+        while now - dq[0][0] > LATENCY_WINDOW_S:
+            dq.popleft()
+
+    def _profile_step(self, nactive: int, total_ctx: int, ms: float) -> None:
+        if len(self._profile_tpot) < PROFILE_SAMPLES:
+            self._profile_tpot.append((nactive, total_ctx, ms))
 
     def take_cache_event(self) -> KvCacheEvent:
         return self.block_mgr.take_cache_event()
@@ -967,24 +1042,32 @@ class InferenceEngine:
     @thread_owned("engine")
     def _loop_owned(self) -> None:
         log = logging.getLogger(__name__)
-        while not self._stop:
-            if not self.has_work():
-                self._work.wait(timeout=0.05)
-                self._work.clear()
-                continue
-            try:
-                produced = self.step()
-                if produced == 0 and self._inflight is None:
-                    # Waiting work that cannot run yet (e.g. blocked on KV
-                    # capacity): sleep on the work event — set when KV
-                    # blocks are freed (_finish), imports/cancels land, or
-                    # new requests arrive — instead of a blind busy-backoff.
-                    self._work.wait(timeout=0.05)
+        phase = self._phases.phase
+        # The loop's own lines (has_work, the error path) are housekeeping;
+        # every phase step() enters suspends it, so the phases partition
+        # the loop's time (obs.spans.EnginePhases).
+        with phase("housekeeping"):
+            while not self._stop:
+                if not self.has_work():
+                    with phase("idle"):
+                        self._work.wait(timeout=0.05)
                     self._work.clear()
-            except Exception:  # pragma: no cover — keep the loop alive
-                self.loop_errors += 1
-                log.exception("engine loop iteration failed")
-                time.sleep(0.1)
+                    continue
+                try:
+                    produced = self.step()
+                    if produced == 0 and self._inflight is None:
+                        # Waiting work that cannot run yet (e.g. blocked on
+                        # KV capacity): sleep on the work event — set when
+                        # KV blocks are freed (_finish), imports/cancels
+                        # land, or new requests arrive — instead of a blind
+                        # busy-backoff.
+                        with phase("idle"):
+                            self._work.wait(timeout=0.05)
+                        self._work.clear()
+                except Exception:  # pragma: no cover — keep the loop alive
+                    self.loop_errors += 1
+                    log.exception("engine loop iteration failed")
+                    time.sleep(0.1)
 
     # ---------------------------------------------------------------- step
 
@@ -1030,6 +1113,8 @@ class InferenceEngine:
         XLLM_SPEC_PIPELINE=0 degrading speculative engines — fetches and
         books each step before dispatching the next; the eligibility
         decision is re-made every step so hatch flips land mid-run."""
+        phase = self._phases.phase
+        # up to the first phase below: the loop's `housekeeping`
         if not self._running and self._inflight is None:
             self._t_host_free = None  # idle time is not a host gap
         self._drain_imports()
@@ -1042,7 +1127,8 @@ class InferenceEngine:
             # the in-flight step and requeues mixed-held mid-prefill
             # seqs into the split midchunk flow).
             produced0 = self._flush_pipeline_state()
-            admitted = self._admit()
+            with phase("schedule"):
+                admitted = self._admit()
             produced = self._decode_once()
             return produced0 + admitted + produced
         if self.cfg.speculative_tokens > 0:
@@ -1059,7 +1145,8 @@ class InferenceEngine:
             # Mode flip mid-prefill (mixed stepping turned off): drain
             # the in-flight mixed step, requeue the held seqs.
             produced0 = self._flush_pipeline_state()
-        admitted = self._admit()
+        with phase("schedule"):
+            admitted = self._admit()
         produced = self._step_overlap()
         return produced0 + admitted + produced
 
@@ -1067,7 +1154,8 @@ class InferenceEngine:
     def _step_overlap(self) -> int:
         """One pipeline iteration: dispatch decode step N+1 (fed from step
         N's device-resident tokens), THEN drain/book step N while N+1 runs."""
-        nxt = self._dispatch_decode()
+        with self._phases.phase("dispatch"):
+            nxt = self._dispatch_decode()
         produced = self._drain_step(self._inflight, nxt)
         self._inflight = nxt
         return produced
@@ -1109,11 +1197,13 @@ class InferenceEngine:
         feedback, one-step-late stops) is unchanged
         (docs/ENGINE_PIPELINE.md + docs/KERNELS.md)."""
         items_meta: List[tuple] = []
-        budget = self._continue_pf_chunks(
-            items_meta, self.cfg.max_prefill_tokens
-        )
-        legacy = self._admit(mixed_collect=items_meta, budget=budget)
-        nxt = self._dispatch_mixed(items_meta)
+        with self._phases.phase("schedule"):
+            budget = self._continue_pf_chunks(
+                items_meta, self.cfg.max_prefill_tokens
+            )
+            legacy = self._admit(mixed_collect=items_meta, budget=budget)
+        with self._phases.phase("dispatch"):
+            nxt = self._dispatch_mixed(items_meta)
         produced = self._drain_step(self._inflight, nxt)
         self._inflight = nxt
         return legacy + produced
@@ -1184,8 +1274,9 @@ class InferenceEngine:
             # First chunk: TTFT base. The unset check (0.0 = never set)
             # covers a deferred first chunk whose start moved past
             # num_cached via frontier adoption before it dispatched.
+            queued_ms = None
             if start <= seq.num_cached or seq.prefill_start_time == 0.0:
-                seq.prefill_start_time = t0
+                queued_ms = self._first_dispatch(seq, t0)
             items.append(PrefillItem(
                 token_ids=np.asarray(seq.tokens[start:start + n], np.int32),
                 start_pos=start,
@@ -1222,9 +1313,26 @@ class InferenceEngine:
                     else None
                 ),
             ))
-            pf_entries.append((seq, seq.admit_gen, j, start, start + n))
+            pf_entries.append(
+                (seq, seq.admit_gen, j, start, start + n, queued_ms)
+            )
             seq.pf_dispatched = start + n
+            self.prefill_tokens += n
+        self.prefill_chunks += len(items)
         return items, pf_entries
+
+    def _first_dispatch(self, seq: "_Seq", t0: float) -> Optional[float]:
+        """A first prefill chunk dispatches at t0: the TTFT base and,
+        once per request that came through add_request, the engine's
+        queue wait (returned in ms; None for a resumed sequence)."""
+        seq.prefill_start_time = t0
+        req = seq.req
+        if not req.queued_at:
+            return None
+        queued_ms = (t0 - req.queued_at) * 1000
+        req.queued_at = 0.0
+        self._m_queue_wait.observe(queued_ms)
+        return queued_ms
 
     @thread_owned("engine")
     def _apply_guided_pacing(self, can: np.ndarray) -> np.ndarray:
@@ -1289,17 +1397,19 @@ class InferenceEngine:
         t0 = time.monotonic()
         items, pf_entries = self._build_pf_items(items_meta, t0)
         prev_tokens = prev.tokens[:R] if prev is not None else None
-        tokens, logprobs = self.executor.mixed_start(
-            items,
-            self._ps_last_tok,
-            fresh_mask,
-            prev_tokens,
-            self._ps_positions,
-            self._block_tables,
-            can,
-            batch,
-            interpret=self._ragged_interpret,
-        )
+        # annotate=False: the executor's leaf annotations stay leaves
+        with self._phases.phase("dispatch", annotate=False):
+            tokens, logprobs = self.executor.mixed_start(
+                items,
+                self._ps_last_tok,
+                fresh_mask,
+                prev_tokens,
+                self._ps_positions,
+                self._block_tables,
+                can,
+                batch,
+                interpret=self._ragged_interpret,
+            )
         nactive = int(can.sum())
         total_ctx = int(self._ps_positions[can].sum()) + nactive
         snapshot = {}
@@ -1748,7 +1858,9 @@ class InferenceEngine:
 
     @thread_owned("engine")
     def _prefill_admitted(self, batch: List[_Seq]) -> int:
-        from xllm_service_tpu.runtime.executor import PrefillItem
+        """Split prefill (no fused step): build the items and launch them
+        (`dispatch`; the executor's blocking read is `device_wait`), then
+        book the results (`emit`)."""
         # Long-context path: prompts past the SP threshold prefill over the
         # mesh's sequence-parallel ring (ring attention) one at a time;
         # they skip prefix reuse (ring attends from position 0) and media
@@ -1763,6 +1875,34 @@ class InferenceEngine:
                 return done + (
                     self._prefill_admitted(batch) if batch else 0
                 )
+        phase = self._phases.phase
+        with phase("dispatch"):
+            items = self._split_pf_items(batch)
+            t0 = time.monotonic()
+            for seq in batch:
+                # First chunk: TTFT base. The unset check (0.0 = never
+                # set) covers a seq whose first chunk never dispatched
+                # before adoption advanced `prefilled` past num_cached
+                # (mixed-mode requeue after a mode flip).
+                if seq.prefilled <= seq.num_cached or (
+                    seq.prefill_start_time == 0.0
+                ):
+                    self._first_dispatch(seq, t0)
+            self._m_kernel_dispatch.labels(
+                kernel=self._kernel_names["prefill"]
+            ).inc(self._prefill_group_count(items))
+            self.prefill_chunks += len(items)
+            self.prefill_tokens += sum(len(it.token_ids) for it in items)
+            # annotate=False: the executor's leaf annotations stay leaves
+            with phase("dispatch", annotate=False):
+                outs = self.executor.prefill_batch(items)
+        with phase("emit"):
+            return self._book_split_prefill(batch, items, outs)
+
+    def _split_pf_items(self, batch: List[_Seq]) -> list:
+        """PrefillItems for the split path's due chunks."""
+        from xllm_service_tpu.runtime.executor import PrefillItem
+
         items = []
         for seq in batch:
             table = np.zeros((self.max_blocks,), np.int32)
@@ -1848,20 +1988,10 @@ class InferenceEngine:
                     ),
                 )
             )
-        t0 = time.monotonic()
-        for seq in batch:
-            # First chunk: TTFT base. The unset check (0.0 = never set)
-            # covers a seq whose first chunk never dispatched before
-            # adoption advanced `prefilled` past num_cached (mixed-mode
-            # requeue after a mode flip).
-            if seq.prefilled <= seq.num_cached or (
-                seq.prefill_start_time == 0.0
-            ):
-                seq.prefill_start_time = t0
-        self._m_kernel_dispatch.labels(
-            kernel=self._kernel_names["prefill"]
-        ).inc(self._prefill_group_count(items))
-        outs = self.executor.prefill_batch(items)
+        return items
+
+    @thread_owned("engine")
+    def _book_split_prefill(self, batch: List[_Seq], items, outs) -> int:
         now = time.monotonic()
         admitted = 0
         for seq, item, (tok, lp) in zip(batch, items, outs):
@@ -1904,9 +2034,10 @@ class InferenceEngine:
         """Shared post-prefill bookkeeping for the batched and SP paths:
         TTFT windows + profiling curve, block commit, first token, running
         insert, emit, and the prefill-only handoff."""
-        self._ttft_window.append((now, ms))
+        self._window_append(self._ttft_window, now, ms)
         self._m_ttft.observe(ms)
-        self._profile_ttft.append((profiled_len, ms))
+        if len(self._profile_ttft) < PROFILE_SAMPLES:
+            self._profile_ttft.append((profiled_len, ms))
         seq.prefill_done_time = seq.last_token_time = now
         self._commit_full_blocks(seq)
         seq.generated.append((tok, lp))
@@ -1970,19 +2101,24 @@ class InferenceEngine:
             table[: len(seq.block_ids)] = seq.block_ids
             s = seq.req.sampling
             t0 = time.monotonic()
+            self._first_dispatch(seq, t0)
             self._m_kernel_dispatch.labels(kernel="ring-sp").inc()
-            tok, lp = self.executor.prefill_long(
-                np.asarray(seq.tokens, np.int32),
-                table,
-                temperature=s.temperature,
-                top_k=s.top_k,
-                top_p=s.top_p,
-                seed=s.seed,
-                step=len(seq.generated),
-            )
+            self.prefill_chunks += 1
+            self.prefill_tokens += len(seq.tokens)
+            with self._phases.phase("dispatch", annotate=False):
+                tok, lp = self.executor.prefill_long(
+                    np.asarray(seq.tokens, np.int32),
+                    table,
+                    temperature=s.temperature,
+                    top_k=s.top_k,
+                    top_p=s.top_p,
+                    seed=s.seed,
+                    step=len(seq.generated),
+                )
             now = time.monotonic()
             ms = (now - t0) * 1000
-            self._finish_prefill(seq, tok, lp, now, ms, len(seq.tokens))
+            with self._phases.phase("emit"):
+                self._finish_prefill(seq, tok, lp, now, ms, len(seq.tokens))
             admitted += 1
         return admitted
 
@@ -2692,60 +2828,71 @@ class InferenceEngine:
             return self._decode_spec_once()
         if not self._running:
             return 0
-        self._ensure_decode_capacity(1)
-        if not self._running:
-            return 0
+        phase = self._phases.phase
+        with phase("dispatch"):
+            self._ensure_decode_capacity(1)
+            if not self._running:
+                return 0
 
-        active = self._ps_active.copy()
-        batch = self._sampling_batch_view()
-        if self._guided_tokens is not None and self._guided_slots:
-            rows = np.full((self.R,), self.executor.permissive_row, np.int32)
-            for slot, seq in self._running.items():
-                rows[slot] = self._guided_row(seq)
-            batch.mask_rows = rows
+            active = self._ps_active.copy()
+            batch = self._sampling_batch_view()
+            if self._guided_tokens is not None and self._guided_slots:
+                rows = np.full(
+                    (self.R,), self.executor.permissive_row, np.int32
+                )
+                for slot, seq in self._running.items():
+                    rows[slot] = self._guided_row(seq)
+                batch.mask_rows = rows
 
-        self._observe_host_gap()
-        t0 = time.monotonic()
-        tokens, logprobs = self.executor.decode(
-            self._ps_last_tok,
-            self._ps_positions,
-            self._block_tables,
-            active,
-            batch,
-        )
-        self._m_kernel_dispatch.labels(
-            kernel=self._kernel_names["decode"]
-        ).inc()
-        step_ms = (time.monotonic() - t0) * 1000
-        nactive = int(active.sum())
-        total_ctx = int(self._ps_positions[active].sum()) + nactive
-        self._profile_tpot.append((nactive, total_ctx, step_ms))
-        self._m_batch.observe(nactive)
-        self._m_steps.inc()
-        self.decode_dispatches += 1
-        self.collective_overlap_steps += self._overlap_collectives
-        self._ps_steps[active] += 1
-        self._ps_positions[active] += 1
+            self._observe_host_gap()
+            t0 = time.monotonic()
+            # annotate=False: the executor's leaf annotations stay leaves
+            # (its blocking read enters `device_wait`: fetch_scope)
+            with phase("dispatch", annotate=False):
+                tokens, logprobs = self.executor.decode(
+                    self._ps_last_tok,
+                    self._ps_positions,
+                    self._block_tables,
+                    active,
+                    batch,
+                )
+            self._m_kernel_dispatch.labels(
+                kernel=self._kernel_names["decode"]
+            ).inc()
+            step_ms = (time.monotonic() - t0) * 1000
+            nactive = int(active.sum())
+            total_ctx = int(self._ps_positions[active].sum()) + nactive
+            self._profile_step(nactive, total_ctx, step_ms)
+            self._m_batch.observe(nactive)
+            self._m_steps.inc()
+            self.decode_dispatches += 1
+            self.collective_overlap_steps += self._overlap_collectives
+            self._ps_steps[active] += 1
+            self._ps_positions[active] += 1
 
-        produced = 0
-        now = time.monotonic()
-        for slot in list(self._running.keys()):
-            seq = self._running[slot]
-            tok, lp = int(tokens[slot]), float(logprobs[slot])
-            tbt_ms = (now - seq.last_token_time) * 1000
-            self._tbt_window.append((now, tbt_ms))
-            self._m_tbt.observe(tbt_ms)
-            seq.last_token_time = now
-            seq.generated.append((tok, lp))
-            seq.tokens.append(tok)
-            self._ps_last_tok[slot] = tok
-            self._ps_gen_count[slot] += 1
-            self._ps_tok_count[slot] += 1
-            self._fresh[slot] = True
-            self._commit_full_blocks(seq)
-            produced += 1
-            self._emit(seq, finished=self._check_stop(seq))
-        self._t_host_free = time.monotonic()
+        with phase("emit"):
+            produced = 0
+            worst_tbt = 0.0
+            now = time.monotonic()
+            for slot in list(self._running.keys()):
+                seq = self._running[slot]
+                tok, lp = int(tokens[slot]), float(logprobs[slot])
+                tbt_ms = (now - seq.last_token_time) * 1000
+                worst_tbt = max(worst_tbt, tbt_ms)
+                self._m_tbt.observe(tbt_ms)
+                seq.last_token_time = now
+                seq.generated.append((tok, lp))
+                seq.tokens.append(tok)
+                self._ps_last_tok[slot] = tok
+                self._ps_gen_count[slot] += 1
+                self._ps_tok_count[slot] += 1
+                self._fresh[slot] = True
+                self._commit_full_blocks(seq)
+                produced += 1
+                self._emit(seq, finished=self._check_stop(seq))
+            if produced:
+                self._window_append(self._tbt_window, now, worst_tbt)
+            self._t_host_free = time.monotonic()
         return produced
 
     # ------------------------------------------------ overlapped pipeline
@@ -2791,17 +2938,19 @@ class InferenceEngine:
         assert prev is not None or bool(fresh_mask[can].all())
         self._observe_host_gap()
         t0 = time.monotonic()
-        tokens, logprobs = self.executor.decode_start(
-            self._ps_last_tok,
-            fresh_mask,
-            # A mixed in-flight step's output is [R + P]; the decode
-            # feedback is always the leading R slots.
-            prev.tokens[: self.R] if prev is not None else None,
-            self._ps_positions,
-            self._block_tables,
-            can,
-            batch,
-        )
+        # annotate=False: the executor's leaf annotations stay leaves
+        with self._phases.phase("dispatch", annotate=False):
+            tokens, logprobs = self.executor.decode_start(
+                self._ps_last_tok,
+                fresh_mask,
+                # A mixed in-flight step's output is [R + P]; the decode
+                # feedback is always the leading R slots.
+                prev.tokens[: self.R] if prev is not None else None,
+                self._ps_positions,
+                self._block_tables,
+                can,
+                batch,
+            )
         self._m_kernel_dispatch.labels(
             kernel=self._kernel_names["decode"]
         ).inc()
@@ -2837,11 +2986,20 @@ class InferenceEngine:
             return 0
         if flt.n_emit is not None:
             return self._drain_spec(flt, newer)
-        tokens = np.asarray(flt.tokens)
-        logprobs = np.asarray(flt.logprobs)
+        with self._phases.phase("device_wait"):
+            tokens = np.asarray(flt.tokens)
+            logprobs = np.asarray(flt.logprobs)
+        with self._phases.phase("emit"):
+            return self._book_step(flt, newer, tokens, logprobs)
+
+    @thread_owned("engine")
+    def _book_step(self, flt: _InFlight, newer: Optional[_InFlight],
+                   tokens: np.ndarray, logprobs: np.ndarray) -> int:
+        """_drain_step's host half, once the results are on the host."""
         step_ms = (time.monotonic() - flt.t0) * 1000
-        self._profile_tpot.append((flt.nactive, flt.total_ctx, step_ms))
+        self._profile_step(flt.nactive, flt.total_ctx, step_ms)
         produced = 0
+        worst_tbt = 0.0
         now = time.monotonic()
         for slot, (seq, gen) in flt.slots.items():
             if self._running.get(slot) is not seq or seq.admit_gen != gen:
@@ -2856,7 +3014,7 @@ class InferenceEngine:
             self._ps_pending[slot] -= 1
             tok, lp = int(tokens[slot]), float(logprobs[slot])
             tbt_ms = (now - seq.last_token_time) * 1000
-            self._tbt_window.append((now, tbt_ms))
+            worst_tbt = max(worst_tbt, tbt_ms)
             self._m_tbt.observe(tbt_ms)
             seq.last_token_time = now
             seq.generated.append((tok, lp))
@@ -2870,6 +3028,8 @@ class InferenceEngine:
             self._commit_full_blocks(seq)
             produced += 1
             self._emit(seq, finished=self._check_stop(seq))
+        if produced:
+            self._window_append(self._tbt_window, now, worst_tbt)
         produced += self._drain_pf_rows(flt, tokens, logprobs)
         if self.span_hook is not None and produced:
             # One span per drained STEP BATCH (never per token): the
@@ -2894,12 +3054,13 @@ class InferenceEngine:
         re-admitted between dispatch and drain, like the decode-slot
         check. Plain mixed steps carry the pf samples at output rows
         [R + j]; speculative verify steps carry them in pf_tok/pf_lp."""
-        pf_tok = (
-            np.asarray(flt.pf_tok) if flt.pf_tok is not None else None
-        )
-        pf_lp = np.asarray(flt.pf_lp) if flt.pf_lp is not None else None
+        pf_tok = pf_lp = None
+        if flt.pf_tok is not None:
+            with self._phases.phase("device_wait"):
+                pf_tok = np.asarray(flt.pf_tok)
+                pf_lp = np.asarray(flt.pf_lp)
         produced = 0
-        for seq, gen, j, c_start, c_end in flt.pf:
+        for seq, gen, j, c_start, c_end, queued_ms in flt.pf:
             if (
                 self._pf_active.get(seq.req.request_id) is not seq
                 or seq.admit_gen != gen
@@ -2911,10 +3072,15 @@ class InferenceEngine:
                 # Per prefill CHUNK (bounded by chunk count, not tokens);
                 # keyed by the engine request id — the instance layer's
                 # srid-keyed admit span brackets the whole prefill.
+                # A request's first chunk carries its engine queue wait.
+                extra = (
+                    {} if queued_ms is None
+                    else {"queued_ms": round(queued_ms, 3)}
+                )
                 self.span_hook(
                     seq.req.request_id, "prefill_chunk",
                     prefilled=c_end, total=len(seq.tokens),
-                    final=c_end >= len(seq.tokens),
+                    final=c_end >= len(seq.tokens), **extra,
                 )
             if c_end < len(seq.tokens):
                 self._stream_chunk_kv(seq)
@@ -3419,18 +3585,24 @@ class InferenceEngine:
         fuse = self.mixed_step_enabled and getattr(
             self.executor, "supports_spec_mixed", False
         )
+        phase = self._phases.phase
         if fuse:
-            budget = self._continue_pf_chunks(
-                items_meta, self.cfg.max_prefill_tokens
-            )
-            legacy = self._admit(mixed_collect=items_meta, budget=budget)
+            with phase("schedule"):
+                budget = self._continue_pf_chunks(
+                    items_meta, self.cfg.max_prefill_tokens
+                )
+                legacy = self._admit(
+                    mixed_collect=items_meta, budget=budget
+                )
         else:
             if self._pf_active:
                 # Mixed support flipped off mid-run: drain and hand the
                 # held seqs to the split midchunk flow.
                 produced0 = self._flush_pipeline_state()
-            legacy = self._admit()
-        nxt = self._dispatch_verify(items_meta)
+            with phase("schedule"):
+                legacy = self._admit()
+        with phase("dispatch"):
+            nxt = self._dispatch_verify(items_meta)
         produced = self._drain_step(self._inflight, nxt)
         self._inflight = nxt
         return produced0 + legacy + produced
@@ -3490,22 +3662,24 @@ class InferenceEngine:
         self._observe_host_gap()
         t0 = time.monotonic()
         items, pf_entries = self._build_pf_items(items_meta, t0)
-        tokens, logprobs, n_emit, pf_tok, pf_lp = (
-            self.executor.verify_start(
-                items,
-                drafts,
-                self._ps_last_tok,
-                self._ps_positions,
-                self._ps_steps,
-                fresh_mask,
-                prev.tokens if prev is not None else None,
-                prev.n_emit if prev is not None else None,
-                self._block_tables,
-                can,
-                batch,
-                interpret=self._ragged_interpret,
+        # annotate=False: the executor's leaf annotations stay leaves
+        with self._phases.phase("dispatch", annotate=False):
+            tokens, logprobs, n_emit, pf_tok, pf_lp = (
+                self.executor.verify_start(
+                    items,
+                    drafts,
+                    self._ps_last_tok,
+                    self._ps_positions,
+                    self._ps_steps,
+                    fresh_mask,
+                    prev.tokens if prev is not None else None,
+                    prev.n_emit if prev is not None else None,
+                    self._block_tables,
+                    can,
+                    batch,
+                    interpret=self._ragged_interpret,
+                )
             )
-        )
         nactive = int(can.sum())
         total_ctx = int(self._ps_positions[can].sum()) + nactive
         snapshot = {}
@@ -3552,12 +3726,22 @@ class InferenceEngine:
         variable emission); surviving slots re-derive their host
         dispatch state from token truth — incremental +1 advances
         cannot track variable accepted counts."""
-        tokens = np.asarray(flt.tokens)
-        logprobs = np.asarray(flt.logprobs)
-        n_emit = np.asarray(flt.n_emit)
+        with self._phases.phase("device_wait"):
+            tokens = np.asarray(flt.tokens)
+            logprobs = np.asarray(flt.logprobs)
+            n_emit = np.asarray(flt.n_emit)
+        with self._phases.phase("emit"):
+            return self._book_spec(flt, newer, tokens, logprobs, n_emit)
+
+    @thread_owned("engine")
+    def _book_spec(self, flt: _InFlight, newer: Optional[_InFlight],
+                   tokens: np.ndarray, logprobs: np.ndarray,
+                   n_emit: np.ndarray) -> int:
+        """_drain_spec's host half, once the results are on the host."""
         step_ms = (time.monotonic() - flt.t0) * 1000
-        self._profile_tpot.append((flt.nactive, flt.total_ctx, step_ms))
+        self._profile_step(flt.nactive, flt.total_ctx, step_ms)
         produced = 0
+        worst_tbt = 0.0
         now = time.monotonic()
         for slot, (seq, gen) in flt.slots.items():
             if self._running.get(slot) is not seq or seq.admit_gen != gen:
@@ -3569,7 +3753,7 @@ class InferenceEngine:
             self.spec_tokens_emitted += ne
             if ne:
                 tbt_ms = (now - seq.last_token_time) * 1000
-                self._tbt_window.append((now, tbt_ms))
+                worst_tbt = max(worst_tbt, tbt_ms)
                 self._m_tbt.observe(tbt_ms)
                 seq.last_token_time = now
             alive = True
@@ -3587,6 +3771,8 @@ class InferenceEngine:
                 ent = newer.slots.get(slot) if newer is not None else None
                 if ent is None or ent[0] is not seq or ent[1] != gen:
                     self._fresh[slot] = True
+        if worst_tbt:
+            self._window_append(self._tbt_window, now, worst_tbt)
         produced += self._drain_pf_rows(flt, tokens, logprobs)
         self._t_host_free = time.monotonic()
         return produced
@@ -3599,6 +3785,37 @@ class InferenceEngine:
         (see EngineConfig.speculative_tokens), 1..k+1 tokens per step."""
         if not self._running:
             return 0
+        phase = self._phases.phase
+        with phase("dispatch"):
+            launched = self._launch_spec_sync()
+        if launched is None:
+            return 0
+        tokens, logprobs, n_emit = launched
+        with phase("emit"):
+            produced = 0
+            worst_tbt = 0.0
+            now = time.monotonic()
+            for slot in list(self._running.keys()):
+                seq = self._running[slot]
+                tbt_ms = (now - seq.last_token_time) * 1000
+                worst_tbt = max(worst_tbt, tbt_ms)
+                self._m_tbt.observe(tbt_ms)
+                seq.last_token_time = now
+                for i in range(int(n_emit[slot])):
+                    tok, lp = int(tokens[slot, i]), float(logprobs[slot, i])
+                    seq.generated.append((tok, lp))
+                    seq.tokens.append(tok)
+                    self._commit_full_blocks(seq)
+                    produced += 1
+                    if not self._emit(seq, finished=self._check_stop(seq)):
+                        break  # finished or cancelled: drop the rest
+            self._window_append(self._tbt_window, now, worst_tbt)
+        return produced
+
+    @thread_owned("engine")
+    def _launch_spec_sync(self):
+        """_decode_spec_once's dispatch half: (tokens, logprobs, n_emit)
+        on the host, or None when the capacity pass emptied the batch."""
         k = self.cfg.speculative_tokens
         S = k + 1
         max_len = self.cfg.max_seq_len
@@ -3608,7 +3825,7 @@ class InferenceEngine:
             self._refresh_slot_arrays(slot, seq)
         self._ensure_decode_capacity(S)
         if not self._running:
-            return 0
+            return None
 
         token_ids = np.zeros((self.R, S), np.int32)
         positions = np.zeros((self.R,), np.int32)
@@ -3638,18 +3855,21 @@ class InferenceEngine:
         self._m_kernel_dispatch.labels(
             kernel=self._kernel_names["mq"]
         ).inc()
-        tokens, logprobs, n_emit = self.executor.verify(
-            token_ids,
-            positions,
-            true_len,
-            self._block_tables,
-            active,
-            batch,
-        )
+        # annotate=False: the executor's leaf annotations stay leaves
+        # (its blocking read enters `device_wait`: fetch_scope)
+        with self._phases.phase("dispatch", annotate=False):
+            tokens, logprobs, n_emit = self.executor.verify(
+                token_ids,
+                positions,
+                true_len,
+                self._block_tables,
+                active,
+                batch,
+            )
         step_ms = (time.monotonic() - t0) * 1000
         nactive = int(active.sum())
         total_ctx = int(positions[active].sum()) + nactive
-        self._profile_tpot.append((nactive, total_ctx, step_ms))
+        self._profile_step(nactive, total_ctx, step_ms)
         self._m_batch.observe(nactive)
         self._m_steps.inc()
         self.decode_dispatches += 1
@@ -3658,24 +3878,7 @@ class InferenceEngine:
         self.spec_sync_steps += 1
         self.spec_slot_steps += nactive
         self.spec_tokens_emitted += int(n_emit[active].sum())
-
-        produced = 0
-        now = time.monotonic()
-        for slot in list(self._running.keys()):
-            seq = self._running[slot]
-            tbt_ms = (now - seq.last_token_time) * 1000
-            self._tbt_window.append((now, tbt_ms))
-            self._m_tbt.observe(tbt_ms)
-            seq.last_token_time = now
-            for i in range(int(n_emit[slot])):
-                tok, lp = int(tokens[slot, i]), float(logprobs[slot, i])
-                seq.generated.append((tok, lp))
-                seq.tokens.append(tok)
-                self._commit_full_blocks(seq)
-                produced += 1
-                if not self._emit(seq, finished=self._check_stop(seq)):
-                    break  # finished or cancelled: drop remaining tokens
-        return produced
+        return tokens, logprobs, n_emit
 
     # ---------------------------------------------------------- preemption
 

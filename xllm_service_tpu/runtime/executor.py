@@ -19,6 +19,7 @@ TPU design points:
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import math
 import os
@@ -31,6 +32,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from xllm_service_tpu.common.config import EngineConfig
+from xllm_service_tpu.obs import spans as obs_spans
 from xllm_service_tpu.runtime import compile_cache as compile_cache_mod
 from xllm_service_tpu import models
 from xllm_service_tpu.models.configs import (
@@ -168,10 +170,64 @@ def _setup_compilation_cache(cache_dir: str) -> None:
     )
 
 
+def _leaf(name: str):
+    """Leaf annotation of a dispatch entry point (obs.spans
+    EXECUTOR_LEAVES): what the host does before the step launches, on the
+    profiler's clock. Leaves never nest; the engine keeps its own
+    `dispatch` annotation closed around the executor call."""
+    return obs_spans.annotation("xllm.executor." + name)
+
+
 class ModelExecutor:
     # guided decoding: index of the appended all-True row once
     # set_guided_table runs; a safe default for unguided paths
     permissive_row = 0
+    # Entered around the blocking device->host read of the synchronous
+    # entry points (decode, verify, prefill_batch, prefill_long); the
+    # engine hangs its `device_wait` phase here.
+    fetch_scope = staticmethod(contextlib.nullcontext)
+
+    def _step_keys(self, seeds, steps):
+        """Per-row sampling keys: eager device programs (threefry seed,
+        convert, fold-in) ahead of the step, under their own name."""
+        with _leaf("step_keys"):
+            return sampling_ops.make_step_keys(
+                jnp.asarray(seeds, jnp.uint32), jnp.asarray(steps, jnp.int32)
+            )
+
+    def _batch_opts(self, batch: "SamplingBatch"):
+        """(presence, frequency, optional keyword arrays) of one decode or
+        verify dispatch: the per-slot sampling features that only ride
+        when some slot uses them (each keys its own compiled variant)."""
+        zeros = np.zeros((self.R,), np.float32)
+        presence = batch.presence if batch.presence is not None else zeros
+        frequency = batch.frequency if batch.frequency is not None else zeros
+        opts = {}
+        if batch.bias_ids is not None:
+            opts = dict(
+                bias_ids=jnp.asarray(batch.bias_ids, jnp.int32),
+                bias_vals=jnp.asarray(batch.bias_vals, jnp.float32),
+            )
+        if batch.mask_rows is not None:
+            opts.update(
+                mask_rows=jnp.asarray(batch.mask_rows, jnp.int32),
+                guided_table=self._flushed_guided_table(),
+            )
+        if batch.adapter_idx is not None:
+            opts.update(lora_idx=jnp.asarray(batch.adapter_idx, jnp.int32))
+        if batch.min_p is not None:
+            opts.update(min_p=jnp.asarray(batch.min_p, jnp.float32))
+        if batch.rope_delta is not None:
+            opts.update(rope_delta=jnp.asarray(batch.rope_delta, jnp.int32))
+        return (
+            jnp.asarray(presence, jnp.float32),
+            jnp.asarray(frequency, jnp.float32),
+            opts,
+        )
+
+    def _fetch(self, *arrays) -> tuple:
+        with self.fetch_scope():
+            return tuple(np.asarray(a) for a in arrays)
 
     def __init__(
         self,
@@ -875,70 +931,49 @@ class ModelExecutor:
         # Per-position keys on the sequential schedule: position j uses
         # step base+j, so the emitted stream is bit-identical to the
         # non-speculative path under the same seeds.
-        seeds = jnp.asarray(batch.seeds, jnp.uint32)
-        keys = jnp.stack(
-            [
-                sampling_ops.make_step_keys(
-                    seeds, jnp.asarray(batch.steps, jnp.int32) + j
+        with _leaf("step_keys"):
+            seeds = jnp.asarray(batch.seeds, jnp.uint32)
+            keys = jnp.stack(
+                [
+                    sampling_ops.make_step_keys(
+                        seeds, jnp.asarray(batch.steps, jnp.int32) + j
+                    )
+                    for j in range(S)
+                ],
+                axis=1,
+            )  # [R, S, 2]
+        with _leaf("host_inputs"):
+            need = 1
+            if active.any():
+                last_pos = np.asarray(positions) + np.asarray(true_len) - 1
+                need = int(
+                    (last_pos[np.asarray(active)].max() // self.block_size)
+                    + 1
                 )
-                for j in range(S)
-            ],
-            axis=1,
-        )  # [R, S, 2]
-        need = 1
-        if active.any():
-            last_pos = np.asarray(positions) + np.asarray(true_len) - 1
-            need = int(
-                (last_pos[np.asarray(active)].max() // self.block_size) + 1
+            CB = self._pow2_bucket(need, self.max_blocks_per_seq)
+            presence, frequency, opts = self._batch_opts(batch)
+            args = (
+                jnp.asarray(token_ids, jnp.int32),
+                jnp.asarray(positions, jnp.int32),
+                jnp.asarray(true_len, jnp.int32),
+                jnp.asarray(block_tables[:, :CB], jnp.int32),
+                jnp.asarray(batch.temperature, jnp.float32),
+                jnp.asarray(batch.top_k, jnp.int32),
+                jnp.asarray(batch.top_p, jnp.float32),
+                keys,
+                jnp.asarray(active),
+                presence,
+                frequency,
             )
-        CB = self._pow2_bucket(need, self.max_blocks_per_seq)
-        R = self.R
-        zeros = np.zeros((R,), np.float32)
-        presence = batch.presence if batch.presence is not None else zeros
-        frequency = batch.frequency if batch.frequency is not None else zeros
-        bias_kwargs = {}
-        if batch.bias_ids is not None:
-            bias_kwargs = dict(
-                bias_ids=jnp.asarray(batch.bias_ids, jnp.int32),
-                bias_vals=jnp.asarray(batch.bias_vals, jnp.float32),
+        with _leaf("launch"):
+            (
+                self.k_cache, self.v_cache, self.token_counts,
+                tokens, logprobs, n_emit,
+            ) = self._verify_jit(
+                self.k_cache, self.v_cache, self.token_counts, self.params,
+                *args, **opts,
             )
-        if batch.mask_rows is not None:
-            bias_kwargs.update(
-                mask_rows=jnp.asarray(batch.mask_rows, jnp.int32),
-                guided_table=self._flushed_guided_table(),
-            )
-        if batch.adapter_idx is not None:
-            bias_kwargs.update(
-                lora_idx=jnp.asarray(batch.adapter_idx, jnp.int32)
-            )
-        if batch.min_p is not None:
-            bias_kwargs.update(min_p=jnp.asarray(batch.min_p, jnp.float32))
-        if batch.rope_delta is not None:
-            bias_kwargs.update(
-                rope_delta=jnp.asarray(batch.rope_delta, jnp.int32)
-            )
-        (
-            self.k_cache, self.v_cache, self.token_counts,
-            tokens, logprobs, n_emit,
-        ) = self._verify_jit(
-            self.k_cache,
-            self.v_cache,
-            self.token_counts,
-            self.params,
-            jnp.asarray(token_ids, jnp.int32),
-            jnp.asarray(positions, jnp.int32),
-            jnp.asarray(true_len, jnp.int32),
-            jnp.asarray(block_tables[:, :CB], jnp.int32),
-            jnp.asarray(batch.temperature, jnp.float32),
-            jnp.asarray(batch.top_k, jnp.int32),
-            jnp.asarray(batch.top_p, jnp.float32),
-            keys,
-            jnp.asarray(active),
-            jnp.asarray(presence, jnp.float32),
-            jnp.asarray(frequency, jnp.float32),
-            **bias_kwargs,
-        )
-        return np.asarray(tokens), np.asarray(logprobs), np.asarray(n_emit)
+        return self._fetch(tokens, logprobs, n_emit)
 
     def bucket_len(self, n: int) -> int:
         for b in self.prefill_buckets:
@@ -1001,6 +1036,21 @@ class ModelExecutor:
 
     def _prefill_group(self, group: List["PrefillItem"]) -> List[Tuple[int, float]]:
         self._set_shard_ctx()
+        with _leaf("host_inputs"):
+            args, seeds, steps, mm_args, opts = self._prefill_inputs(group)
+        keys = self._step_keys(seeds, steps)
+        with _leaf("launch"):
+            self.k_cache, self.v_cache, toks, lps = self._prefill_jit(
+                self.k_cache, self.v_cache, self.params,
+                *args, keys, *mm_args, **opts,
+            )
+        toks, lps = self._fetch(toks, lps)
+        return [(int(toks[i]), float(lps[i])) for i in range(len(group))]
+
+    def _prefill_inputs(self, group: List["PrefillItem"]):
+        """Pack one prefill group's host inputs and stage them on the
+        device: (positional arrays up to top_p, seeds, steps, media
+        arrays, optional keyword arrays) in _prefill_impl's order."""
         n_real = len(group)
         P = self._pow2_bucket(n_real, self.PREFILL_GROUP_MAX)
         Lpad = self.bucket_len(max(len(it.token_ids) for it in group))
@@ -1031,9 +1081,6 @@ class ModelExecutor:
             top_ps[i] = it.top_p
             seeds[i] = it.seed & 0xFFFFFFFF
             steps[i] = it.step
-        keys = sampling_ops.make_step_keys(
-            jnp.asarray(seeds), jnp.asarray(steps, jnp.int32)
-        )
         # Media-token injection: bucket the per-seq override count to a
         # power of two; padded entries point at Lpad (the model's discard
         # row). Positions are chunk-relative; overrides outside this chunk
@@ -1129,10 +1176,7 @@ class ModelExecutor:
                 presence=jnp.asarray(pres),
                 frequency=jnp.asarray(freq),
             )
-        self.k_cache, self.v_cache, toks, lps = self._prefill_jit(
-            self.k_cache,
-            self.v_cache,
-            self.params,
+        return (
             jnp.asarray(token_ids),
             jnp.asarray(start_pos),
             jnp.asarray(true_len),
@@ -1140,13 +1184,7 @@ class ModelExecutor:
             jnp.asarray(temps),
             jnp.asarray(top_ks),
             jnp.asarray(top_ps),
-            keys,
-            *mm_args,
-            **pen_kwargs,
-        )
-        toks = np.asarray(toks)
-        lps = np.asarray(lps)
-        return [(int(toks[i]), float(lps[i])) for i in range(n_real)]
+        ), seeds, steps, mm_args, pen_kwargs
 
     def warmup(self) -> List[Tuple[int, int]]:
         """Compile the common serving shapes against the garbage block, so
@@ -1588,12 +1626,10 @@ class ModelExecutor:
         idx = np.minimum(offsets // self.block_size, len(block_table) - 1)
         blk = np.where(valid, block_table[idx], 0)
         off = np.where(valid, offsets % self.block_size, 0)
-        key = sampling_ops.make_step_keys(
-            jnp.asarray([seed], jnp.uint32), jnp.int32(step)
-        )[0]
+        key = self._step_keys([seed], step)[0]
         if not hasattr(self, "_sp_jit"):
             self._sp_jit = jax.jit(self._sp_impl, donate_argnums=(0, 1))
-        with self.mesh:
+        with _leaf("launch"), self.mesh:
             self.k_cache, self.v_cache, tok, lp = self._sp_jit(
                 self.k_cache,
                 self.v_cache,
@@ -1607,6 +1643,7 @@ class ModelExecutor:
                 jnp.float32(top_p),
                 key,
             )
+        tok, lp = self._fetch(tok, lp)
         return int(tok), float(lp)
 
     def prefill(
@@ -1651,7 +1688,7 @@ class ModelExecutor:
             token_ids, None, None, positions, block_tables, active, batch,
             use_kernel=use_kernel,
         )
-        return np.asarray(tokens), np.asarray(logprobs)
+        return self._fetch(tokens, logprobs)
 
     def decode_start(
         self,
@@ -1670,79 +1707,56 @@ class ModelExecutor:
         the previous step's device-resident sample — so the overlapped
         pipeline's autoregressive feedback never round-trips the host."""
         self._set_shard_ctx()
-        keys = sampling_ops.make_step_keys(
-            jnp.asarray(batch.seeds, jnp.uint32),
-            jnp.asarray(batch.steps, jnp.int32),
-        )
-        # Slice the block table to the batch's true context bound (pow2
-        # bucket: <= log2(max_blocks) compiles). The gather fallback
-        # otherwise materializes [R, max_blocks*BS] context per layer even
-        # when every sequence is short.
-        need = 1
-        if active.any():
-            need = int(
-                (np.asarray(positions)[np.asarray(active)].max() // self.block_size)
-                + 1
+        keys = self._step_keys(batch.seeds, batch.steps)
+        with _leaf("host_inputs"):
+            # Slice the block table to the batch's true context bound
+            # (pow2 bucket: <= log2(max_blocks) compiles). The gather
+            # fallback otherwise materializes [R, max_blocks*BS] context
+            # per layer even when every sequence is short.
+            need = 1
+            if active.any():
+                need = int(
+                    (
+                        np.asarray(positions)[np.asarray(active)].max()
+                        // self.block_size
+                    )
+                    + 1
+                )
+            CB = self._pow2_bucket(need, self.max_blocks_per_seq)
+            presence, frequency, opts = self._batch_opts(batch)
+            fresh = jnp.asarray(fresh_tokens, jnp.int32)
+            if fresh_mask is None:
+                mask = jnp.ones((self.R,), bool)
+                prev = fresh
+            else:
+                mask = jnp.asarray(fresh_mask)
+                prev = (
+                    jnp.asarray(prev_tokens, jnp.int32)
+                    if prev_tokens is not None
+                    else fresh
+                )
+            args = (
+                fresh,
+                mask,
+                prev,
+                jnp.asarray(positions, jnp.int32),
+                jnp.asarray(block_tables[:, :CB], jnp.int32),
+                jnp.asarray(active),
+                jnp.asarray(batch.temperature, jnp.float32),
+                jnp.asarray(batch.top_k, jnp.int32),
+                jnp.asarray(batch.top_p, jnp.float32),
+                keys,
+                presence,
+                frequency,
             )
-        CB = self._pow2_bucket(need, self.max_blocks_per_seq)
-        R = self.R
-        zeros = np.zeros((R,), np.float32)
-        presence = batch.presence if batch.presence is not None else zeros
-        frequency = batch.frequency if batch.frequency is not None else zeros
-        bias_kwargs = {}
-        if batch.bias_ids is not None:
-            bias_kwargs = dict(
-                bias_ids=jnp.asarray(batch.bias_ids, jnp.int32),
-                bias_vals=jnp.asarray(batch.bias_vals, jnp.float32),
+        with _leaf("launch"):
+            (
+                self.k_cache, self.v_cache, self.token_counts,
+                tokens, logprobs,
+            ) = self._decode_jit(
+                self.k_cache, self.v_cache, self.token_counts, self.params,
+                *args, use_kernel=use_kernel, **opts,
             )
-        if batch.mask_rows is not None:
-            bias_kwargs.update(
-                mask_rows=jnp.asarray(batch.mask_rows, jnp.int32),
-                guided_table=self._flushed_guided_table(),
-            )
-        if batch.adapter_idx is not None:
-            bias_kwargs.update(
-                lora_idx=jnp.asarray(batch.adapter_idx, jnp.int32)
-            )
-        if batch.min_p is not None:
-            bias_kwargs.update(min_p=jnp.asarray(batch.min_p, jnp.float32))
-        if batch.rope_delta is not None:
-            bias_kwargs.update(
-                rope_delta=jnp.asarray(batch.rope_delta, jnp.int32)
-            )
-        fresh = jnp.asarray(fresh_tokens, jnp.int32)
-        if fresh_mask is None:
-            mask = jnp.ones((R,), bool)
-            prev = fresh
-        else:
-            mask = jnp.asarray(fresh_mask)
-            prev = (
-                jnp.asarray(prev_tokens, jnp.int32)
-                if prev_tokens is not None
-                else fresh
-            )
-        (
-            self.k_cache, self.v_cache, self.token_counts, tokens, logprobs,
-        ) = self._decode_jit(
-            self.k_cache,
-            self.v_cache,
-            self.token_counts,
-            self.params,
-            fresh,
-            mask,
-            prev,
-            jnp.asarray(positions, jnp.int32),
-            jnp.asarray(block_tables[:, :CB], jnp.int32),
-            jnp.asarray(active),
-            jnp.asarray(batch.temperature, jnp.float32),
-            jnp.asarray(batch.top_k, jnp.int32),
-            jnp.asarray(batch.top_p, jnp.float32),
-            keys,
-            jnp.asarray(presence, jnp.float32),
-            jnp.asarray(frequency, jnp.float32),
-            use_kernel=use_kernel,
-            **bias_kwargs,
-        )
         return tokens, logprobs
 
     # ------------------------------------------------------- mixed step
@@ -2028,118 +2042,126 @@ class ModelExecutor:
         items DO ride (ISSUE 13): final chunks carry mask_row and the
         decode half takes batch.mask_rows — both applied in-graph."""
         self._set_shard_ctx()
-        R = self.R
-        n_pf = len(items)
-        P = self._pow2_bucket(max(n_pf, 1), self.PREFILL_GROUP_MAX)
-        Lpad = self.bucket_len(
-            max((len(it.token_ids) for it in items), default=1)
-        )
-        bs = self.block_size
-        # Each half buckets its context width EXACTLY like its split
-        # program (decode_start / _prefill_group) — the bucket cadence is
-        # part of the byte-parity contract (a different table width means
-        # a different compiled program for that half).
-        need_d = 1
-        if active.any():
-            need_d = int(
-                (np.asarray(positions)[np.asarray(active)].max() // bs) + 1
+        keys = self._step_keys(batch.seeds, batch.steps)
+        with _leaf("host_inputs"):
+            R = self.R
+            n_pf = len(items)
+            P = self._pow2_bucket(max(n_pf, 1), self.PREFILL_GROUP_MAX)
+            Lpad = self.bucket_len(
+                max((len(it.token_ids) for it in items), default=1)
             )
-        CBd = self._pow2_bucket(need_d, self.max_blocks_per_seq)
-        need_p = max(
-            ((it.start_pos + len(it.token_ids) + bs - 1) // bs
-             for it in items),
-            default=1,
-        )
-        CBp = self._pow2_bucket(max(need_p, 1), self.max_blocks_per_seq)
-
-        keys = sampling_ops.make_step_keys(
-            jnp.asarray(batch.seeds, jnp.uint32),
-            jnp.asarray(batch.steps, jnp.int32),
-        )
-        zeros = np.zeros((R,), np.float32)
-        presence = batch.presence if batch.presence is not None else zeros
-        frequency = batch.frequency if batch.frequency is not None else zeros
-
-        pf_args, pf_opt = self._pf_half(items, P, Lpad, CBp)
-
-        opt = dict(pf_opt)
-        if batch.bias_ids is not None:
-            opt.update(
-                bias_ids=jnp.asarray(batch.bias_ids, jnp.int32),
-                bias_vals=jnp.asarray(batch.bias_vals, jnp.float32),
+            bs = self.block_size
+            # Each half buckets its context width EXACTLY like its split
+            # program (decode_start / _prefill_group) — the bucket cadence
+            # is part of the byte-parity contract (a different table width
+            # means a different compiled program for that half).
+            need_d = 1
+            if active.any():
+                need_d = int(
+                    (np.asarray(positions)[np.asarray(active)].max() // bs)
+                    + 1
+                )
+            CBd = self._pow2_bucket(need_d, self.max_blocks_per_seq)
+            need_p = max(
+                ((it.start_pos + len(it.token_ids) + bs - 1) // bs
+                 for it in items),
+                default=1,
             )
-        if batch.min_p is not None:
-            opt.update(min_p=jnp.asarray(batch.min_p, jnp.float32))
-        if batch.rope_delta is not None:
-            opt.update(rope_delta=jnp.asarray(batch.rope_delta, jnp.int32))
-        # Guided decoding rides per half like the split programs: the
-        # decode half takes the engine's per-slot rows (sync _decode_once
-        # contract), the prefill half the per-item final-chunk rows
-        # (_prefill_group contract). One table serves both.
-        if batch.mask_rows is not None:
-            opt.update(
-                mask_rows=jnp.asarray(batch.mask_rows, jnp.int32),
-                guided_table=self._flushed_guided_table(),
-            )
-        # LoRA rides per half, gated exactly like the split programs
-        # (decode_start keys on batch.adapter_idx, _prefill_group on any
-        # item adapter) — an adapter on one half must not flip the other
-        # half onto the lora-apply path.
-        if batch.adapter_idx is not None:
-            opt.update(
-                lora_dec=jnp.asarray(batch.adapter_idx, jnp.int32)
+            CBp = self._pow2_bucket(max(need_p, 1), self.max_blocks_per_seq)
+
+            zeros = np.zeros((R,), np.float32)
+            presence = batch.presence if batch.presence is not None else zeros
+            frequency = (
+                batch.frequency if batch.frequency is not None else zeros
             )
 
-        fresh = jnp.asarray(fresh_tokens, jnp.int32)
-        if fresh_mask is None:
-            mask = jnp.ones((R,), bool)
-            prev = fresh
-        else:
-            mask = jnp.asarray(fresh_mask)
-            prev = (
-                jnp.asarray(prev_tokens, jnp.int32)
-                if prev_tokens is not None
-                else fresh
+            pf_args, pf_seeds, pf_steps, pf_opt = self._pf_half(
+                items, P, Lpad, CBp
             )
-        if not hasattr(self, "_mixed_jit"):
-            self._mixed_jit = jax.jit(
-                self._mixed_impl,
-                donate_argnums=(0, 1, 2),
-                static_argnames=("use_ragged", "interpret"),
+
+            opt = dict(pf_opt)
+            if batch.bias_ids is not None:
+                opt.update(
+                    bias_ids=jnp.asarray(batch.bias_ids, jnp.int32),
+                    bias_vals=jnp.asarray(batch.bias_vals, jnp.float32),
+                )
+            if batch.min_p is not None:
+                opt.update(min_p=jnp.asarray(batch.min_p, jnp.float32))
+            if batch.rope_delta is not None:
+                opt.update(
+                    rope_delta=jnp.asarray(batch.rope_delta, jnp.int32)
+                )
+            # Guided decoding rides per half like the split programs: the
+            # decode half takes the engine's per-slot rows (sync
+            # _decode_once contract), the prefill half the per-item
+            # final-chunk rows (_prefill_group contract). One table
+            # serves both.
+            if batch.mask_rows is not None:
+                opt.update(
+                    mask_rows=jnp.asarray(batch.mask_rows, jnp.int32),
+                    guided_table=self._flushed_guided_table(),
+                )
+            # LoRA rides per half, gated exactly like the split programs
+            # (decode_start keys on batch.adapter_idx, _prefill_group on
+            # any item adapter) — an adapter on one half must not flip the
+            # other half onto the lora-apply path.
+            if batch.adapter_idx is not None:
+                opt.update(
+                    lora_dec=jnp.asarray(batch.adapter_idx, jnp.int32)
+                )
+
+            fresh = jnp.asarray(fresh_tokens, jnp.int32)
+            if fresh_mask is None:
+                mask = jnp.ones((R,), bool)
+                prev = fresh
+            else:
+                mask = jnp.asarray(fresh_mask)
+                prev = (
+                    jnp.asarray(prev_tokens, jnp.int32)
+                    if prev_tokens is not None
+                    else fresh
+                )
+            args = (
+                fresh,
+                mask,
+                prev,
+                jnp.asarray(positions, jnp.int32),
+                jnp.asarray(block_tables[:, :CBd], jnp.int32),
+                jnp.asarray(active),
+                jnp.asarray(batch.temperature, jnp.float32),
+                jnp.asarray(batch.top_k, jnp.int32),
+                jnp.asarray(batch.top_p, jnp.float32),
+                keys,
+                jnp.asarray(presence, jnp.float32),
+                jnp.asarray(frequency, jnp.float32),
             )
-        (
-            self.k_cache, self.v_cache, self.token_counts, tokens, logprobs,
-        ) = self._mixed_jit(
-            self.k_cache,
-            self.v_cache,
-            self.token_counts,
-            self.params,
-            fresh,
-            mask,
-            prev,
-            jnp.asarray(positions, jnp.int32),
-            jnp.asarray(block_tables[:, :CBd], jnp.int32),
-            jnp.asarray(active),
-            jnp.asarray(batch.temperature, jnp.float32),
-            jnp.asarray(batch.top_k, jnp.int32),
-            jnp.asarray(batch.top_p, jnp.float32),
-            keys,
-            jnp.asarray(presence, jnp.float32),
-            jnp.asarray(frequency, jnp.float32),
-            *pf_args,
-            use_ragged=use_ragged,
-            interpret=interpret,
-            **opt,
-        )
+        pf_keys = self._step_keys(pf_seeds, pf_steps)
+        with _leaf("launch"):
+            if not hasattr(self, "_mixed_jit"):
+                self._mixed_jit = jax.jit(
+                    self._mixed_impl,
+                    donate_argnums=(0, 1, 2),
+                    static_argnames=("use_ragged", "interpret"),
+                )
+            (
+                self.k_cache, self.v_cache, self.token_counts,
+                tokens, logprobs,
+            ) = self._mixed_jit(
+                self.k_cache, self.v_cache, self.token_counts, self.params,
+                *args, *pf_args, pf_keys,
+                use_ragged=use_ragged, interpret=interpret, **opt,
+            )
         return tokens, logprobs
 
     def _pf_half(self, items: List["PrefillItem"], P: int, Lpad: int,
                  CBp: int):
         """Pack the prefill half of a fused dispatch: the positional
-        arrays (tokens, start, len, tables, temps, top_k, top_p, keys —
-        as jnp arrays, in _mixed_impl/_mixed_verify_impl argument order)
-        plus the optional pf_* sampling features, gated per item exactly
-        like _prefill_group. Shared by mixed_start and verify_start."""
+        arrays (tokens, start, len, tables, temps, top_k, top_p — as jnp
+        arrays, in _mixed_impl/_mixed_verify_impl argument order; the
+        caller appends the keys it makes from the returned seeds and
+        steps) plus the optional pf_* sampling features, gated per item
+        exactly like _prefill_group. Shared by mixed_start and
+        verify_start, inside their `host_inputs` leaf."""
         n_pf = len(items)
         pf_tokens = np.zeros((P, Lpad), np.int32)
         pf_start = np.zeros((P,), np.int32)
@@ -2162,9 +2184,6 @@ class ModelExecutor:
             pf_top_p[i] = it.top_p
             pf_seeds[i] = it.seed & 0xFFFFFFFF
             pf_steps[i] = it.step
-        pf_keys = sampling_ops.make_step_keys(
-            jnp.asarray(pf_seeds), jnp.asarray(pf_steps, jnp.int32)
-        )
         opt = {}
         if any(it.adapter_idx for it in items):
             opt.update(
@@ -2227,8 +2246,7 @@ class ModelExecutor:
             jnp.asarray(pf_temps),
             jnp.asarray(pf_top_k),
             jnp.asarray(pf_top_p),
-            pf_keys,
-        ), opt
+        ), pf_seeds, pf_steps, opt
 
     # ------------------------------------------- pipelined verify (spec)
 
@@ -2476,117 +2494,102 @@ class ModelExecutor:
         context-bucket bound covers host positions + TWO steps of
         worst-case emission (the in-flight step's and this one's)."""
         self._set_shard_ctx()
-        R = self.R
-        S = drafts.shape[1] + 1
-        bs = self.block_size
-        max_len = self.engine_cfg.max_seq_len
-        need = 1
-        if active.any():
-            worst = (
-                int(np.asarray(host_pos)[np.asarray(active)].max())
-                + 2 * S - 1
-            )
-            need = min(worst, max_len - 1) // bs + 1
-        CB = self._pow2_bucket(max(need, 1), self.max_blocks_per_seq)
-        zeros = np.zeros((R,), np.float32)
-        presence = batch.presence if batch.presence is not None else zeros
-        frequency = batch.frequency if batch.frequency is not None else zeros
-        bias_kwargs = {}
-        if batch.bias_ids is not None:
-            bias_kwargs = dict(
-                bias_ids=jnp.asarray(batch.bias_ids, jnp.int32),
-                bias_vals=jnp.asarray(batch.bias_vals, jnp.float32),
-            )
-        if batch.mask_rows is not None:
-            bias_kwargs.update(
-                mask_rows=jnp.asarray(batch.mask_rows, jnp.int32),
-                guided_table=self._flushed_guided_table(),
-            )
-        if batch.adapter_idx is not None:
-            bias_kwargs.update(
-                lora_idx=jnp.asarray(batch.adapter_idx, jnp.int32)
-            )
-        if batch.min_p is not None:
-            bias_kwargs.update(min_p=jnp.asarray(batch.min_p, jnp.float32))
-        if batch.rope_delta is not None:
-            bias_kwargs.update(
-                rope_delta=jnp.asarray(batch.rope_delta, jnp.int32)
-            )
-        if prev_tokens is None:
-            # Committed device zeros with the SAME replicated sharding a
-            # real verify output carries — a host numpy array here keys
-            # a second pjit lowering per context bucket (unspecified- vs
-            # named-sharding args), recompiling the whole verify program
-            # on the first post-idle dispatch.
-            cached = getattr(self, "_null_prev", None)
-            if cached is None or cached[0] != S:
-                # jax.sharding spelled out: `P` is shadowed by the local
-                # prefill-group bucket below.
-                rep = NamedSharding(self.mesh, jax.sharding.PartitionSpec())
-                self._null_prev = (
-                    S,
-                    jax.device_put(np.zeros((R, S), np.int32), rep),
-                    jax.device_put(np.zeros((R,), np.int32), rep),
+        with _leaf("host_inputs"):
+            R = self.R
+            S = drafts.shape[1] + 1
+            bs = self.block_size
+            max_len = self.engine_cfg.max_seq_len
+            need = 1
+            if active.any():
+                worst = (
+                    int(np.asarray(host_pos)[np.asarray(active)].max())
+                    + 2 * S - 1
                 )
-                cached = self._null_prev
-            prev_tokens, prev_n_emit = cached[1], cached[2]
-        common = (
-            self.k_cache,
-            self.v_cache,
-            self.token_counts,
-            self.params,
-            jnp.asarray(drafts, jnp.int32),
-            jnp.asarray(host_last, jnp.int32),
-            jnp.asarray(host_pos, jnp.int32),
-            jnp.asarray(host_steps, jnp.int32),
-            jnp.asarray(fresh_mask),
-            jnp.asarray(prev_tokens, jnp.int32),
-            jnp.asarray(prev_n_emit, jnp.int32),
-            jnp.asarray(batch.seeds, jnp.uint32),
-            jnp.asarray(block_tables[:, :CB], jnp.int32),
-            jnp.asarray(active),
-            jnp.asarray(batch.temperature, jnp.float32),
-            jnp.asarray(batch.top_k, jnp.int32),
-            jnp.asarray(batch.top_p, jnp.float32),
-            jnp.asarray(presence, jnp.float32),
-            jnp.asarray(frequency, jnp.float32),
-        )
+                need = min(worst, max_len - 1) // bs + 1
+            CB = self._pow2_bucket(max(need, 1), self.max_blocks_per_seq)
+            presence, frequency, opt = self._batch_opts(batch)
+            if prev_tokens is None:
+                # Committed device zeros with the SAME replicated sharding
+                # a real verify output carries — a host numpy array here
+                # keys a second pjit lowering per context bucket
+                # (unspecified- vs named-sharding args), recompiling the
+                # whole verify program on the first post-idle dispatch.
+                cached = getattr(self, "_null_prev", None)
+                if cached is None or cached[0] != S:
+                    # jax.sharding spelled out: `P` is shadowed by the
+                    # local prefill-group bucket below.
+                    rep = NamedSharding(
+                        self.mesh, jax.sharding.PartitionSpec()
+                    )
+                    self._null_prev = (
+                        S,
+                        jax.device_put(np.zeros((R, S), np.int32), rep),
+                        jax.device_put(np.zeros((R,), np.int32), rep),
+                    )
+                    cached = self._null_prev
+                prev_tokens, prev_n_emit = cached[1], cached[2]
+            args = (
+                jnp.asarray(drafts, jnp.int32),
+                jnp.asarray(host_last, jnp.int32),
+                jnp.asarray(host_pos, jnp.int32),
+                jnp.asarray(host_steps, jnp.int32),
+                jnp.asarray(fresh_mask),
+                jnp.asarray(prev_tokens, jnp.int32),
+                jnp.asarray(prev_n_emit, jnp.int32),
+                jnp.asarray(batch.seeds, jnp.uint32),
+                jnp.asarray(block_tables[:, :CB], jnp.int32),
+                jnp.asarray(active),
+                jnp.asarray(batch.temperature, jnp.float32),
+                jnp.asarray(batch.top_k, jnp.int32),
+                jnp.asarray(batch.top_p, jnp.float32),
+                presence,
+                frequency,
+            )
+            if items:
+                P = self._pow2_bucket(len(items), self.PREFILL_GROUP_MAX)
+                Lpad = self.bucket_len(
+                    max(len(it.token_ids) for it in items)
+                )
+                need_p = max(
+                    (it.start_pos + len(it.token_ids) + bs - 1) // bs
+                    for it in items
+                )
+                CBp = self._pow2_bucket(
+                    max(need_p, 1), self.max_blocks_per_seq
+                )
+                pf_args, pf_seeds, pf_steps, pf_opt = self._pf_half(
+                    items, P, Lpad, CBp
+                )
+                opt = {**pf_opt, **opt}
         if not items:
-            if not hasattr(self, "_verify_pipe_jit"):
-                self._verify_pipe_jit = jax.jit(
-                    self._verify_pipe_impl, donate_argnums=(0, 1, 2)
+            with _leaf("launch"):
+                if not hasattr(self, "_verify_pipe_jit"):
+                    self._verify_pipe_jit = jax.jit(
+                        self._verify_pipe_impl, donate_argnums=(0, 1, 2)
+                    )
+                (
+                    self.k_cache, self.v_cache, self.token_counts,
+                    tokens, logprobs, n_emit,
+                ) = self._verify_pipe_jit(
+                    self.k_cache, self.v_cache, self.token_counts,
+                    self.params, *args, **opt,
+                )
+            return tokens, logprobs, n_emit, None, None
+        pf_keys = self._step_keys(pf_seeds, pf_steps)
+        with _leaf("launch"):
+            if not hasattr(self, "_mixed_verify_jit"):
+                self._mixed_verify_jit = jax.jit(
+                    self._mixed_verify_impl,
+                    donate_argnums=(0, 1, 2),
+                    static_argnames=("use_ragged", "interpret"),
                 )
             (
                 self.k_cache, self.v_cache, self.token_counts,
-                tokens, logprobs, n_emit,
-            ) = self._verify_pipe_jit(*common, **bias_kwargs)
-            return tokens, logprobs, n_emit, None, None
-        n_pf = len(items)
-        P = self._pow2_bucket(max(n_pf, 1), self.PREFILL_GROUP_MAX)
-        Lpad = self.bucket_len(
-            max((len(it.token_ids) for it in items), default=1)
-        )
-        need_p = max(
-            ((it.start_pos + len(it.token_ids) + bs - 1) // bs
-             for it in items),
-            default=1,
-        )
-        CBp = self._pow2_bucket(max(need_p, 1), self.max_blocks_per_seq)
-        pf_args, pf_opt = self._pf_half(items, P, Lpad, CBp)
-        opt = dict(pf_opt)
-        opt.update(bias_kwargs)
-        if not hasattr(self, "_mixed_verify_jit"):
-            self._mixed_verify_jit = jax.jit(
-                self._mixed_verify_impl,
-                donate_argnums=(0, 1, 2),
-                static_argnames=("use_ragged", "interpret"),
+                tokens, logprobs, n_emit, pf_tok, pf_lp,
+            ) = self._mixed_verify_jit(
+                self.k_cache, self.v_cache, self.token_counts, self.params,
+                *args, *pf_args, pf_keys, interpret=interpret, **opt,
             )
-        (
-            self.k_cache, self.v_cache, self.token_counts,
-            tokens, logprobs, n_emit, pf_tok, pf_lp,
-        ) = self._mixed_verify_jit(
-            *common, *pf_args, interpret=interpret, **opt,
-        )
         return tokens, logprobs, n_emit, pf_tok, pf_lp
 
     def seed_slot_counts(self, slot: int, generated: "List[int]") -> None:
