@@ -823,3 +823,254 @@ def test_mosaic_rules_dynamic_offset_placement():
         mosaic.check_slice_indices(2, (blk,))
     with _pytest.raises(mosaic.MosaicLayoutError, match="dynamic"):
         mosaic.check_slice_indices(4, (0, 1, blk))
+
+
+# ------------------------------------------------------------ stacked pool
+# The serving steps hand every GQA kernel the WHOLE stacked pool
+# [L, N, Hc, BS, D] plus a layer index (models/llama.py carries the stack
+# through its layer scan; docs/KV_CACHE.md). Each kernel must read exactly
+# that layer: the other layers of the stack hold different data, so a
+# wrong or ignored index cannot pass.
+
+from xllm_service_tpu.ops import attention as attn_ops
+from xllm_service_tpu.ops import kv_cache as kvc
+from xllm_service_tpu.ops import kv_write as kvw
+
+_STACK_L = 3
+
+
+def _stacked_case(rng, cache_kind, Hq=4, Hkv=2, R=2, MB=2, N=10):
+    """(q_head_dim, BS, k_stack, v_stack, tables): a pool of _STACK_L
+    layers of independent random data in one of the three cache layouts."""
+    if cache_kind == "packed64":
+        D, BS = 64, 16
+        shape = (_STACK_L, N, Hkv // 2, BS, 128)  # two heads per 128 lanes
+    else:
+        D, BS = 128, (128 if cache_kind == "int8" else 16)
+        shape = (_STACK_L, N, Hkv, BS, D)
+    mk = lambda: jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    if cache_kind == "int8":
+        k, v = kvc.quantize_pool(mk()), kvc.quantize_pool(mk())
+    else:
+        k, v = mk().astype(jnp.bfloat16), mk().astype(jnp.bfloat16)
+    bt = jnp.asarray(
+        rng.permutation(np.arange(1, N))[: R * MB].reshape(R, MB), jnp.int32
+    )
+    return D, BS, k, v, bt
+
+
+def _layer_of(cache, layer):
+    return jax.tree.map(lambda a: a[layer], cache)
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("cache_kind", ["bf16", "int8", "packed64"])
+@pytest.mark.parametrize("kernel", ["decode", "multiquery", "flash", "ragged"])
+def test_stacked_kernels_read_their_layer(kernel, cache_kind, layer, monkeypatch):
+    monkeypatch.setenv("XLLM_PACKED_KV_KERNEL", "1")
+    monkeypatch.setenv("XLLM_MQ_ATTENTION_KERNEL", "1")
+    rng = np.random.default_rng(17)
+    Hq, R = 4, 2
+    D, BS, k, v, bt = _stacked_case(rng, cache_kind, Hq=Hq, R=R)
+    k4, v4 = _layer_of(k, layer), _layer_of(v, layer)
+    scale = D ** -0.5
+    lyr = jnp.int32(layer)
+    ctx = bt.shape[1] * BS
+
+    def q_of(*lead):
+        return jnp.asarray(
+            rng.standard_normal((*lead, Hq, D)), jnp.bfloat16
+        )
+
+    if kernel == "decode":
+        q = q_of(R)
+        seq_lens = jnp.asarray([ctx - 3, BS // 2 + 1], jnp.int32)
+        out = attn_ops.paged_attention(
+            q, k, v, bt, seq_lens, scale, use_kernel=True, interpret=True,
+            layer=lyr,
+        )
+        ref = attn_ops.paged_attention_gather(q, k4, v4, bt, seq_lens, scale)
+    elif kernel in ("multiquery", "flash"):
+        S = 4 if kernel == "multiquery" else 16
+        q = q_of(R, S)
+        start = jnp.asarray([ctx - S - 2, 3], jnp.int32)
+        true_len = jnp.asarray([S, S], jnp.int32)
+        out = attn_ops.prefill_attention(
+            q, k, v, bt, start, true_len, scale, interpret=True, layer=lyr,
+            use_kernel=None if kernel == "multiquery" else True,
+        )
+        ref = jax.vmap(
+            lambda qi, ti, sp, tl: attn_ops.prefill_attention_gather(
+                qi, k4, v4, ti, sp, tl, scale
+            )
+        )(q, bt, start, true_len)
+    else:
+        seg_lens = (1, 16)  # a decode row and a prefill row in one launch
+        q = q_of(sum(seg_lens))
+        q_len = jnp.asarray([1, 13], jnp.int32)
+        pos0 = jnp.asarray([ctx - 5, 2], jnp.int32)
+        out = attn_ops.ragged_paged_attention(
+            q, k, v, bt, q_len, pos0, seg_lens, scale, use_kernel=True,
+            interpret=True, layer=lyr,
+        )
+        ref = attn_ops.ragged_attention_blockwise(
+            q, k4, v4, bt, q_len, pos0, seg_lens, scale
+        )
+    out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
+    np.testing.assert_allclose(out, ref, atol=3e-2, rtol=3e-2)
+
+
+def test_stacked_fallbacks_index_the_stack():
+    """The gather / blockwise fallbacks take the same (stack, layer)
+    operands and gather blocks out of the stack: same numbers as on the
+    layer sliced out by hand."""
+    rng = np.random.default_rng(5)
+    D, BS, k, v, bt = _stacked_case(rng, "int8")
+    q = jnp.asarray(rng.standard_normal((2, 4, D)), jnp.float32)
+    sl = jnp.asarray([BS + 9, 4], jnp.int32)
+    for layer in range(_STACK_L):
+        a = attn_ops.paged_attention_gather(
+            q, k, v, bt, sl, 0.1, layer=jnp.int32(layer)
+        )
+        b = attn_ops.paged_attention_gather(
+            q, _layer_of(k, layer), _layer_of(v, layer), bt, sl, 0.1
+        )
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        a = attn_ops.prefill_attention_blockwise(
+            q, k, v, bt[0], jnp.int32(7), jnp.int32(2), 0.1,
+            layer=jnp.int32(layer),
+        )
+        b = attn_ops.prefill_attention_blockwise(
+            q, _layer_of(k, layer), _layer_of(v, layer), bt[0],
+            jnp.int32(7), jnp.int32(2), 0.1,
+        )
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# ------------------------------------------------------- the in-place write
+# ops/kv_write.py: both routes (the XLA scatter into the carried stack, and
+# the Pallas tile write of ops/pallas/kv_write.py that replaces it on the
+# chip) against rows placed one by one in numpy: the right layer, block,
+# offset and scale lane, and nothing else touched. Garbage block 0 is left
+# out: the scatter parks dead rows there, the kernel writes nothing.
+
+
+def _write_case(rng, cache_kind, S, CB=4, N=12, Hc=2, D=128):
+    BS = 128 if cache_kind == "int8" else 32
+    base = jnp.asarray(
+        rng.standard_normal((_STACK_L, N, Hc, BS, D)), jnp.float32
+    )
+    if cache_kind == "int8":
+        mk = kvc.quantize_pool
+    else:
+        dt = jnp.bfloat16 if cache_kind == "bf16" else jnp.float32
+        mk = lambda a: kvc.PagedKV(a.astype(dt), None)
+    tables = rng.permutation(np.arange(1, N))[: S * 2].reshape(S, 2)
+    tables = np.concatenate([tables, np.zeros_like(tables)], 1)[:, :CB]
+    return BS, mk(base), mk(base * 0.5), jnp.asarray(tables, jnp.int32)
+
+
+def _placed(cache, rows, tables, start, length, width, layer):
+    """The oracle: `cache` with each live row put in its slot by hand."""
+    data = np.array(cache.data)
+    scale = None if cache.scale is None else np.array(cache.scale)
+    BS = data.shape[-2]
+    if scale is None:
+        new = np.asarray(rows.astype(cache.data.dtype))
+    else:
+        # jitted like the write: eager rounds a few values differently
+        new, new_scale = map(
+            np.asarray,
+            jax.jit(kvc.quantize_rows, static_argnums=1)(
+                rows, scale.shape[-2]
+            ),
+        )
+    for s in range(len(start)):
+        for j in range(int(length[s])):
+            pos = int(start[s]) + j
+            blk, off = int(tables[s, pos // BS]), pos % BS
+            data[layer, blk, :, off] = new[s * width + j]
+            if scale is not None:
+                scale[layer, blk, :, :, off] = new_scale[s * width + j]
+    return data, scale
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize(
+    "width,start,length",
+    [
+        (1, [3, 40, 0, 31], [1, 1, 0, 1]),  # decode rows, one inactive
+        (4, [30, 5, 0, 17], [4, 2, 0, 4]),  # verify rows across a block edge
+        (40, [7, 32, 0, 1], [40, 17, 0, 33]),  # chunks, unaligned starts
+    ],
+)
+@pytest.mark.parametrize("cache_kind", ["bf16", "int8", "f32"])
+@pytest.mark.parametrize("route", ["scatter", "kernel"])
+def test_write_kv_lands_rows_in_the_stack(
+    route, cache_kind, width, start, length, layer
+):
+    rng = np.random.default_rng(width)
+    S = len(start)
+    BS, kc, vc, tables = _write_case(rng, cache_kind, S)
+    scale_up = BS // 32  # same block crossings at BS 128 as at BS 32
+    start = np.asarray(start, np.int32) * scale_up
+    length = np.asarray(length, np.int32)
+    rows = jnp.asarray(
+        rng.standard_normal((S * width, 2, 128)),
+        jnp.float32 if cache_kind == "f32" else jnp.bfloat16,
+    )
+    plan = kvw.write_plan(
+        kc, tables, jnp.asarray(start), jnp.asarray(length), width,
+        interpret=route == "kernel",
+    )
+    assert (plan.units is not None) == (route == "kernel")
+    assert (plan.scale_units is not None) == (
+        route == "kernel" and cache_kind == "int8"
+    )
+    out = jax.jit(
+        lambda k, v: kvw.write_kv(k, v, plan, rows, rows * 2, jnp.int32(layer))
+    )(kc, vc)
+    np_tables = np.asarray(tables)
+    for cache, new, got in zip((kc, vc), (rows, rows * 2), out):
+        data, scale = _placed(
+            cache, new, np_tables, start, length, width, layer
+        )
+        np.testing.assert_array_equal(np.asarray(got.data)[:, 1:], data[:, 1:])
+        assert not np.array_equal(data[layer], np.asarray(cache.data)[layer])
+        if scale is not None:
+            np.testing.assert_array_equal(
+                np.asarray(got.scale)[:, 1:], scale[:, 1:]
+            )
+        if route == "kernel":  # dead units write nothing, not even garbage
+            np.testing.assert_array_equal(
+                np.asarray(got.data)[:, 0], np.asarray(cache.data)[:, 0]
+            )
+
+
+def test_kv_write_kernel_per_shard():
+    """Under a tp shard context the write launches once per shard over its
+    own heads (shard_map), like the attention kernels."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    rng = np.random.default_rng(9)
+    BS, kc, vc, tables = _write_case(rng, "bf16", 2)
+    mesh = Mesh(np.asarray(jax.devices()[:2]), ("tp",))
+    start, length = jnp.asarray([5, 30]), jnp.asarray([1, 1])
+    rows = jnp.asarray(rng.standard_normal((2, 2, 128)), jnp.bfloat16)
+    ref = kvw.write_kv(
+        kc, vc, kvw.write_plan(kc, tables, start, length, 1), rows, rows,
+        jnp.int32(1),
+    )
+    attn_ops.set_shard_context(mesh)
+    try:
+        plan = kvw.write_plan(kc, tables, start, length, 1, interpret=True)
+        assert plan.units is not None and plan.ctx is not None
+        pool = NamedSharding(mesh, P(None, None, "tp"))
+        with mesh:
+            out = jax.jit(
+                lambda k, v: kvw.write_kv(k, v, plan, rows, rows, jnp.int32(1)),
+            )(jax.device_put(kc, pool), jax.device_put(vc, pool))
+    finally:
+        attn_ops.set_shard_context(None)
+    for r, o in zip(jax.tree.leaves(ref), jax.tree.leaves(out)):
+        np.testing.assert_array_equal(np.asarray(o), np.asarray(r))

@@ -25,6 +25,8 @@ from jax.sharding import SingleDeviceSharding
 from xllm_service_tpu.models import llama
 from xllm_service_tpu.models.configs import get_model_config
 from xllm_service_tpu.ops import attention
+from xllm_service_tpu.ops import kv_cache as kvc
+from xllm_service_tpu.ops import kv_write as kvw
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CFG = get_model_config("llama3-3b")
@@ -75,6 +77,42 @@ def as_on_tpu(monkeypatch):
 def _compile(fn, *args):
     compiled = jax.jit(fn).lower(*args).compile()
     return compiled.as_text()
+
+
+def _assert_pool_still(fn, args, cache, shards=1):
+    """Compile a step with its caches donated, as the executor does, and
+    hold the chip's program to what tests/test_kv_pool_still.py holds the
+    CPU's to: the new rows go in through kv_write_kernel, nothing has a
+    layer- or stack-shaped result but that write and the loop's own
+    plumbing, and the temporaries are smaller than one layer of one pool.
+    On a tp mesh the compiled module is one shard's: the pool holds
+    1/`shards` of the heads there (a gathered pool would have the whole
+    shape, so that is looked for too). Returns the HLO text."""
+    import re
+
+    compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "kv_write_kernel" in text
+    data = kvc.raw(cache)  # (an int8 pool's scale planes are a 32nd of it)
+    whole, stack = tuple(data.shape), list(data.shape)
+    stack[2] //= shards
+    shapes = {
+        ",".join(map(str, s)) for s in (whole, whole[1:], stack, stack[1:])
+    }
+    plumbing = {
+        "parameter", "get-tuple-element", "tuple", "bitcast", "while",
+        "custom-call",
+    }
+    moved = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (\S+) ([\w\-]+)\(", line)
+        dims = m and re.match(r"\w+\[([\d,]*)\]", m.group(1))
+        if dims and dims.group(1) in shapes and m.group(2) not in plumbing:
+            moved.append(line.strip()[:160])
+    assert not moved, "\n".join(moved)
+    layer_bytes = data.dtype.itemsize * NB * (HKV // shards) * BS * D
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+    return text
 
 
 def _kernel_shapes(one_chip):
@@ -147,6 +185,38 @@ def test_ragged_kernel_compiles(one_chip, no_persistent_cache):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize(
+    "quantized,rows,width", [(False, R, 1), (False, 2, 256), (True, R, 4)],
+    ids=["decode-bf16", "chunk-bf16", "verify-int8"],
+)
+def test_kv_write_kernel_compiles(
+    one_chip, no_persistent_cache, as_on_tpu, quantized, rows, width
+):
+    """The in-place write of a step's rows into the stacked pool
+    (ops/pallas/kv_write.py through kv_write.write_kv): Mosaic takes the
+    tile shapes of every row layout and both pool dtypes."""
+    s, _ = _kernel_shapes(one_chip)
+    if quantized:
+        cache = kvc.PagedKV(
+            s((2, NB, HKV, BS, D), jnp.int8),
+            s((2, NB, HKV, kvc.GQA_SCALE_GROUPS, BS), jnp.float32),
+        )
+    else:
+        cache = kvc.PagedKV(s((2, NB, HKV, BS, D)), None)
+
+    def write(k, v, tables, start, length, rows_k, rows_v):
+        plan = kvw.write_plan(k, tables, start, length, width)
+        assert plan.units is not None
+        return kvw.write_kv(k, v, plan, rows_k, rows_v, jnp.int32(1))
+
+    new = s((rows * width, HKV, D))
+    text = jax.jit(write, donate_argnums=(0, 1)).lower(
+        cache, cache, s((rows, 16), jnp.int32), s((rows,), jnp.int32),
+        s((rows,), jnp.int32), new, new,
+    ).compile().as_text()
+    assert text.count("kv_write_kernel") >= (2 if quantized else 1)
+
+
 def _model_shapes(one_chip, layers=2):
     """llama3-3b at full widths cut to `layers` layers: abstract params
     and caches placed on the described chip (no arrays exist)."""
@@ -173,30 +243,95 @@ def _model_shapes(one_chip, layers=2):
     return cfg, params, cache, s
 
 
-def test_decode_step_compiles(one_chip, no_persistent_cache, as_on_tpu):
-    cfg, params, cache, s = _model_shapes(one_chip)
-    text = _compile(
-        lambda p, k, v, t, pos, bt, act: llama.decode_step(
-            p, cfg, k, v, t, pos, bt, act
+def _step_case(step, cfg, s):
+    """(function of (params, k, v, *rest), rest) for one of the four
+    cache-threading steps of models/llama.py, at R decode or verify rows
+    and two 256-token chunks."""
+    P, Lpad, S = 2, 256, 4
+    dec = (s((R,)), s((R,)), s((R, 16)), s((R,), jnp.bool_))
+    pf = (s((P, Lpad)), s((P,)), s((P,)), s((P, 16)))
+    fn, rest = {
+        "decode": (llama.decode_step, dec),
+        "mixed": (llama.mixed_step, dec + pf),
+        "prefill": (llama.prefill_batch_step, pf),
+        "mixed-verify": (
+            llama.mixed_verify_step,
+            (s((R, S)), s((R,)), s((R,)), s((R, 16))) + pf,
         ),
-        params, cache, cache,
-        s((R,)), s((R,)), s((R, 16)), s((R,), jnp.bool_),
-    )
-    assert "tpu_custom_call" in text
+    }[step]
+    return (lambda p, k, v, *a: fn(p, cfg, k, v, *a)), rest
 
 
-def test_mixed_step_compiles(one_chip, no_persistent_cache, as_on_tpu):
+@pytest.mark.parametrize(
+    "step,quantized",
+    [("decode", False), ("mixed", False), ("prefill", False),
+     ("mixed-verify", False), ("decode", True), ("mixed-verify", True)],
+    ids=["decode", "mixed", "prefill", "mixed-verify", "decode-int8",
+         "mixed-verify-int8"],
+)
+def test_step_compiles_and_keeps_the_pool_still(
+    one_chip, no_persistent_cache, as_on_tpu, step, quantized
+):
     cfg, params, cache, s = _model_shapes(one_chip)
-    P, Lpad = 2, 256
-    text = _compile(
-        lambda p, k, v, t, pos, bt, act, pt, ps, pl_, ptab: llama.mixed_step(
-            p, cfg, k, v, t, pos, bt, act, pt, ps, pl_, ptab
-        ),
-        params, cache, cache,
-        s((R,)), s((R,)), s((R, 16)), s((R,), jnp.bool_),
-        s((P, Lpad)), s((P,)), s((P,)), s((P, 16)),
+    if quantized:
+        cache = kvc.PagedKV(
+            s(cache.shape, jnp.int8),
+            s((*cache.shape[:3], kvc.GQA_SCALE_GROUPS, BS), jnp.float32),
+        )
+    fn, rest = _step_case(step, cfg, s)
+    _assert_pool_still(fn, (params, cache, cache) + rest, cache)
+
+
+def _tp4_shapes(topo, layers=2):
+    """_model_shapes on the described 2x2 host: tp = 4 over its four
+    chips, parameters and pools sharded as the executor shards them."""
+    from xllm_service_tpu.parallel import mesh as mesh_lib, sharding
+
+    cfg = dataclasses.replace(CFG, num_layers=layers)
+    mesh = mesh_lib.build_mesh(tp=4, devices=topo.devices)
+    shapes = jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.key(0), jnp.bfloat16)
     )
-    assert "tpu_custom_call" in text
+    params = jax.tree.map(
+        lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh),
+        shapes, sharding.param_shardings(cfg, mesh),
+    )
+    cache = jax.ShapeDtypeStruct(
+        (layers, NB, HKV, BS, D), jnp.bfloat16,
+        sharding=sharding.kv_cache_sharding(mesh),
+    )
+
+    def s(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=mesh_lib.replicated(mesh)
+        )
+
+    return cfg, mesh, params, cache, s
+
+
+@pytest.mark.parametrize("step", ["decode", "mixed"])
+def test_tp4_step_compiles(topo, no_persistent_cache, as_on_tpu, step):
+    """The tensor-parallel programs of a 2x2 host (no cell runs them yet):
+    the pool is sharded over its KV heads, the attention kernels and the
+    in-place write launch once per shard on the shard's stack (shard_map),
+    and the shard's pool stays as still as the one chip's."""
+    cfg, mesh, params, cache, s = _tp4_shapes(topo)
+    fn, rest = _step_case(step, cfg, s)
+    attention.set_shard_context(mesh)
+    try:
+        with mesh:
+            text = _assert_pool_still(
+                fn, (params, cache, cache) + rest, cache, shards=4
+            )
+    finally:
+        attention.set_shard_context(None)
+    assert "all-reduce" in text
+    # per shard: the kernels' cache operand is the shard's stack
+    shard = f"bf16[2,{NB},{HKV // 4},{BS},{D}]"
+    assert any(
+        "tpu_custom_call" in line and shard in line
+        for line in text.splitlines()
+    )
 
 
 def test_chip_smoke_refuses_cpu():

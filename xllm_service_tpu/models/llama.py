@@ -27,6 +27,7 @@ import jax.numpy as jnp
 
 from xllm_service_tpu.models.configs import ModelConfig
 from xllm_service_tpu.ops import kv_cache as kv_cache_ops
+from xllm_service_tpu.ops import kv_write as kv_write_ops
 from xllm_service_tpu.ops.attention import (
     mixed_attention,
     mixed_prefill_attention,
@@ -386,22 +387,27 @@ def _qkv(lp, cfg: ModelConfig, x: jnp.ndarray, positions: jnp.ndarray,
     return q, k, v
 
 
-def _scatter_kv(k_cache, v_cache, blk, offset, k, v):
-    """Write per-token K/V rows into cache slots.
+def _scan_layers(layer_fn, x, params, k_caches, v_caches):
+    """The cache-threading layer scan: the stacked caches ride the CARRY
+    (never scanned inputs and stacked outputs, which made every layer of
+    every step slice, re-tile and restack its whole pool slice: PERF.md,
+    PR 29); the scanned inputs are the layer's parameters and its index.
+    `layer_fn(x, lp, layer, k_caches, v_caches) -> (x, k_caches,
+    v_caches)` lands its rows in place (kv_write_ops.write_kv, by a plan
+    made once outside the scan) and hands the whole stack plus `layer`
+    to the attention ops."""
 
-    k_cache: [num_blocks, Hc, bs, Dc] plain array or PagedKV (int8 caches
-    quantize the rows on write); blk/offset: [T] block ids and in-block
-    offsets per token; inactive/invalid tokens carry (0, 0), pointing into
-    the reserved garbage block 0. Packed caches (Hc < Hkv — head_dim < 128
-    models, see cache_row_dims) take the rows reshaped to the packed
-    layout: consecutive heads concatenate on lanes."""
-    kf = kv_cache_ops.scatter_rows(
-        k_cache, blk, offset, kv_cache_ops.pack_rows(k, k_cache)
+    def body(carry, scanned):
+        lp, layer = scanned
+        return layer_fn(carry[0], lp, layer, *carry[1:]), None
+
+    n_layers = kv_cache_ops.raw(k_caches).shape[0]
+    (x, k_caches, v_caches), _ = jax.lax.scan(
+        body,
+        (x, k_caches, v_caches),
+        (params["layers"], jnp.arange(n_layers, dtype=jnp.int32)),
     )
-    vf = kv_cache_ops.scatter_rows(
-        v_cache, blk, offset, kv_cache_ops.pack_rows(v, v_cache)
-    )
-    return kf, vf
+    return x, k_caches, v_caches
 
 
 def decode_step(
@@ -419,7 +425,6 @@ def decode_step(
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One generation step for R sequences. Returns (logits [R, V],
     k_caches', v_caches')."""
-    bs = k_caches.shape[3]
     scale = cfg.head_dim**-0.5
     x = _embed(params, cfg, token_ids, wdtype(params["layers"]["wq"]))  # [R, E]
 
@@ -427,20 +432,22 @@ def decode_step(
     # image spans): rope_delta <= 0 shifts the ROTATION only — cache
     # slots, block lookup, and attention lengths stay token-count-based.
     rope_pos = positions + rope_delta if rope_delta is not None else positions
-    block_idx = positions // bs
-    offset = jnp.where(active, positions % bs, 0)
-    blk = jnp.take_along_axis(block_tables, block_idx[:, None], axis=1)[:, 0]
-    blk = jnp.where(active, blk, 0)
+    # One row per active slot at its position; inactive slots write
+    # nothing real (garbage block 0 on the scatter route).
+    plan = kv_write_ops.write_plan(
+        k_caches, block_tables, positions, active, 1
+    )
     seq_lens = jnp.where(active, positions + 1, 0)
 
-    def layer_fn(x, scanned):
-        lp, k_l, v_l = scanned
+    def layer_fn(x, lp, layer, k_caches, v_caches):
         h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
         q, k, v = _qkv(lp, cfg, h, rope_pos, lora_idx)
-        k_l, v_l = _scatter_kv(k_l, v_l, blk, offset, k, v)
+        k_caches, v_caches = kv_write_ops.write_kv(
+            k_caches, v_caches, plan, k, v, layer
+        )
         attn = paged_attention(
-            q, k_l, v_l, block_tables, seq_lens, scale,
-            use_kernel=use_kernel, window=cfg.sliding_window,
+            q, k_caches, v_caches, block_tables, seq_lens, scale,
+            use_kernel=use_kernel, window=cfg.sliding_window, layer=layer,
         )
         attn_flat = attn.reshape(attn.shape[0], -1)
         o = _row_parallel("rh,he->re", attn_flat,
@@ -449,10 +456,10 @@ def decode_step(
         x = x + (o + d if d is not None else o)
         h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
         x = x + _mlp_block(lp, cfg, h, lora_idx, rows_valid=active)
-        return x, (k_l, v_l)
+        return x, k_caches, v_caches
 
-    x, (k_caches, v_caches) = jax.lax.scan(
-        layer_fn, x, (params["layers"], k_caches, v_caches)
+    x, k_caches, v_caches = _scan_layers(
+        layer_fn, x, params, k_caches, v_caches
     )
     logits = _unembed(params, cfg, x)  # [R, V]
     return logits, k_caches, v_caches
@@ -492,7 +499,6 @@ def mixed_step(
 
     Returns (dec_logits [R, V], pf_logits [P, V] — each prefill row's
     LAST valid position — k', v')."""
-    bs = k_caches.shape[3]
     scale = cfg.head_dim**-0.5
     R = dec_tokens.shape[0]
     P, Lpad = pf_tokens.shape
@@ -501,38 +507,29 @@ def mixed_step(
     x_pf = _embed(params, cfg, pf_tokens, wd)  # [P, Lpad, E]
 
     # Decode-half coordinates: verbatim decode_step (M-RoPE rope_delta
-    # shifts the rotation only; inactive slots scatter into garbage
-    # block 0).
+    # shifts the rotation only; inactive slots write nothing real).
     dec_rope = (
         dec_positions + rope_delta if rope_delta is not None
         else dec_positions
     )
-    dec_blk = jnp.take_along_axis(
-        dec_tables, (dec_positions // bs)[:, None], axis=1
-    )[:, 0]
-    dec_blk = jnp.where(dec_active, dec_blk, 0)
-    dec_off = jnp.where(dec_active, dec_positions % bs, 0)
+    dec_plan = kv_write_ops.write_plan(
+        k_caches, dec_tables, dec_positions, dec_active, 1
+    )
     dec_seq_lens = jnp.where(dec_active, dec_positions + 1, 0)
 
-    # Prefill-half coordinates: verbatim prefill_batch_step (invalid
-    # rows land in garbage block 0). Media prompts never ride the mixed
+    # Prefill-half coordinates: verbatim prefill_batch_step (rows past
+    # pf_len write nothing real). Media prompts never ride the mixed
     # step, so positions are always the plain sequential streams.
     offsets = jnp.arange(Lpad, dtype=jnp.int32)[None, :]
     pf_positions = pf_start[:, None] + offsets  # [P, Lpad]
     pf_valid = offsets < pf_len[:, None]
-    pf_blk = jnp.where(
-        pf_valid,
-        jnp.take_along_axis(pf_tables, pf_positions // bs, axis=1),
-        0,
+    pf_plan = kv_write_ops.write_plan(
+        k_caches, pf_tables, pf_start, pf_len, Lpad
     )
-    pf_off = jnp.where(pf_valid, pf_positions % bs, 0)
-    pf_flat_blk = pf_blk.reshape(P * Lpad)
-    pf_flat_off = pf_off.reshape(P * Lpad)
     li = lora_pf if lora_pf is not None else jnp.zeros((P,), jnp.int32)
 
-    def layer_fn(carry, scanned):
-        x_dec, x_pf = carry
-        lp, k_l, v_l = scanned
+    def layer_fn(x, lp, layer, k_caches, v_caches):
+        x_dec, x_pf = x
         # Decode half QKV: decode_step's [R, E] shapes.
         h_dec = rms_norm(x_dec, lp["attn_norm"], cfg.rms_norm_eps)
         q_dec, k_dec, v_dec = _qkv(lp, cfg, h_dec, dec_rope, lora_dec)
@@ -543,18 +540,21 @@ def mixed_step(
                 lp, cfg, hx, pos, ai if lora_pf is not None else None
             )
         )(h_pf, pf_positions, li)  # q_pf [P, Lpad, Hq, D]
-        k_l, v_l = _scatter_kv(k_l, v_l, dec_blk, dec_off, k_dec, v_dec)
-        k_l, v_l = _scatter_kv(
-            k_l, v_l, pf_flat_blk, pf_flat_off,
+        k_caches, v_caches = kv_write_ops.write_kv(
+            k_caches, v_caches, dec_plan, k_dec, v_dec, layer
+        )
+        k_caches, v_caches = kv_write_ops.write_kv(
+            k_caches, v_caches, pf_plan,
             k_pf.reshape(P * Lpad, *k_pf.shape[2:]),
             v_pf.reshape(P * Lpad, *v_pf.shape[2:]),
+            layer,
         )
         attn_dec, attn_pf = mixed_attention(
-            q_dec, q_pf, k_l, v_l,
+            q_dec, q_pf, k_caches, v_caches,
             dec_tables, dec_seq_lens,
             pf_tables, pf_start, pf_len,
             scale, use_ragged=use_ragged, interpret=interpret,
-            window=cfg.sliding_window,
+            window=cfg.sliding_window, layer=layer,
         )
         # Output projection + MLP, per half, split-step shapes.
         attn_dec_flat = attn_dec.reshape(attn_dec.shape[0], -1)
@@ -581,10 +581,10 @@ def mixed_step(
         x_pf = x_pf + _mlp_block(
             lp, cfg, h_pf, lora_pf, rows_valid=pf_valid
         )
-        return (x_dec, x_pf), (k_l, v_l)
+        return (x_dec, x_pf), k_caches, v_caches
 
-    (x_dec, x_pf), (k_caches, v_caches) = jax.lax.scan(
-        layer_fn, (x_dec, x_pf), (params["layers"], k_caches, v_caches)
+    (x_dec, x_pf), k_caches, v_caches = _scan_layers(
+        layer_fn, (x_dec, x_pf), params, k_caches, v_caches
     )
     dec_logits = _unembed(params, cfg, x_dec)  # [R, V]
     last = jnp.take_along_axis(
@@ -629,7 +629,6 @@ def mixed_verify_step(
 
     Returns (ver_logits [R, S, V] — every position, the speculative
     verify contract — pf_logits [P, V], k', v')."""
-    bs = k_caches.shape[3]
     scale = cfg.head_dim**-0.5
     R, S = ver_tokens.shape
     P, Lpad = pf_tokens.shape
@@ -637,20 +636,14 @@ def mixed_verify_step(
     x_ver = _embed(params, cfg, ver_tokens, wd)  # [R, S, E]
     x_pf = _embed(params, cfg, pf_tokens, wd)  # [P, Lpad, E]
 
-    def half_coords(start, length, tables, L):
-        offs = jnp.arange(L, dtype=jnp.int32)[None, :]
-        pos = start[:, None] + offs
-        valid = offs < length[:, None]
-        blk = jnp.where(
-            valid, jnp.take_along_axis(tables, pos // bs, axis=1), 0
-        )
-        off = jnp.where(valid, pos % bs, 0)
-        return pos, blk.reshape(-1), off.reshape(-1)
-
-    ver_pos, ver_blk, ver_off = half_coords(
-        ver_start, ver_len, ver_tables, S
+    ver_pos = ver_start[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
+    pf_pos = pf_start[:, None] + jnp.arange(Lpad, dtype=jnp.int32)[None, :]
+    ver_plan = kv_write_ops.write_plan(
+        k_caches, ver_tables, ver_start, ver_len, S
     )
-    pf_pos, pf_blk, pf_off = half_coords(pf_start, pf_len, pf_tables, Lpad)
+    pf_plan = kv_write_ops.write_plan(
+        k_caches, pf_tables, pf_start, pf_len, Lpad
+    )
     # Live-row masks for the grouped-MoE dispatch (_mlp_block rows_valid
     # — padding lanes stay out of routing stats/capacity).
     ver_valid = (
@@ -672,9 +665,8 @@ def mixed_verify_step(
     li_ver = lora_ver if lora_ver is not None else jnp.zeros((R,), jnp.int32)
     li_pf = lora_pf if lora_pf is not None else jnp.zeros((P,), jnp.int32)
 
-    def layer_fn(carry, scanned):
-        x_ver, x_pf = carry
-        lp, k_l, v_l = scanned
+    def layer_fn(x, lp, layer, k_caches, v_caches):
+        x_ver, x_pf = x
         h_ver = rms_norm(x_ver, lp["attn_norm"], cfg.rms_norm_eps)
         q_ver, k_v, v_v = jax.vmap(
             lambda hx, pos, ai: _qkv(
@@ -687,22 +679,24 @@ def mixed_verify_step(
                 lp, cfg, hx, pos, ai if lora_pf is not None else None
             )
         )(h_pf, pf_pos, li_pf)
-        k_l, v_l = _scatter_kv(
-            k_l, v_l, ver_blk, ver_off,
+        k_caches, v_caches = kv_write_ops.write_kv(
+            k_caches, v_caches, ver_plan,
             k_v.reshape(R * S, *k_v.shape[2:]),
             v_v.reshape(R * S, *v_v.shape[2:]),
+            layer,
         )
-        k_l, v_l = _scatter_kv(
-            k_l, v_l, pf_blk, pf_off,
+        k_caches, v_caches = kv_write_ops.write_kv(
+            k_caches, v_caches, pf_plan,
             k_p.reshape(P * Lpad, *k_p.shape[2:]),
             v_p.reshape(P * Lpad, *v_p.shape[2:]),
+            layer,
         )
         attn_ver, attn_pf = mixed_prefill_attention(
-            q_ver, q_pf, k_l, v_l,
+            q_ver, q_pf, k_caches, v_caches,
             ver_tables, ver_start, ver_len,
             pf_tables, pf_start, pf_len,
             scale, use_ragged=use_ragged, interpret=interpret,
-            window=cfg.sliding_window,
+            window=cfg.sliding_window, layer=layer,
         )
 
         def half_tail(x, attn, L_, n_rows, lora, li, valid):
@@ -723,10 +717,10 @@ def mixed_verify_step(
                           ver_valid)
         x_pf = half_tail(x_pf, attn_pf, Lpad, P, lora_pf, li_pf,
                          pf_valid)
-        return (x_ver, x_pf), (k_l, v_l)
+        return (x_ver, x_pf), k_caches, v_caches
 
-    (x_ver, x_pf), (k_caches, v_caches) = jax.lax.scan(
-        layer_fn, (x_ver, x_pf), (params["layers"], k_caches, v_caches)
+    (x_ver, x_pf), k_caches, v_caches = _scan_layers(
+        layer_fn, (x_ver, x_pf), params, k_caches, v_caches
     )
     ver_logits = _unembed(params, cfg, x_ver)  # [R, S, V]
     last = jnp.take_along_axis(
@@ -763,7 +757,6 @@ def prefill_batch_step(
     (EPD encoder outputs) overwrite placeholder-token rows before the first
     layer. Returns (last-token logits [P, V] — or [P, Lpad, V] when
     `all_logits`, the speculative-decoding verify pass — k', v')."""
-    bs = k_caches.shape[3]
     scale = cfg.head_dim**-0.5
     P, Lpad = token_ids.shape
     x = _embed(params, cfg, token_ids, wdtype(params["layers"]["wq"]))
@@ -780,35 +773,31 @@ def prefill_batch_step(
     offsets = jnp.arange(Lpad, dtype=jnp.int32)[None, :]  # [1, Lpad]
     positions = start_pos[:, None] + offsets  # [P, Lpad]
     valid = offsets < true_len[:, None]
-    block_idx = positions // bs
-    blk = jnp.where(
-        valid, jnp.take_along_axis(block_tables, block_idx, axis=1), 0
+    plan = kv_write_ops.write_plan(
+        k_caches, block_tables, start_pos, true_len, Lpad
     )
-    in_block = jnp.where(valid, positions % bs, 0)
-    flat_blk = blk.reshape(P * Lpad)
-    flat_off = in_block.reshape(P * Lpad)
 
     li = lora_idx if lora_idx is not None else jnp.zeros((P,), jnp.int32)
     # Cache slots/attention stay token-count positional; only the q/k
     # ROTATION takes the (t, h, w) streams when M-RoPE positions ride in.
     rp = rope_positions if rope_positions is not None else positions
 
-    def layer_fn(x, scanned):
-        lp, k_l, v_l = scanned
+    def layer_fn(x, lp, layer, k_caches, v_caches):
         h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
         q, k, v = jax.vmap(
             lambda hx, pos, ai: _qkv(
                 lp, cfg, hx, pos, ai if lora_idx is not None else None
             )
         )(h, rp, li)  # q [P, Lpad, Hq, D]
-        k_l, v_l = _scatter_kv(
-            k_l, v_l, flat_blk, flat_off,
+        k_caches, v_caches = kv_write_ops.write_kv(
+            k_caches, v_caches, plan,
             k.reshape(P * Lpad, *k.shape[2:]),
             v.reshape(P * Lpad, *v.shape[2:]),
+            layer,
         )
         attn = prefill_attention(
-            q, k_l, v_l, block_tables, start_pos, true_len, scale,
-            window=cfg.sliding_window,
+            q, k_caches, v_caches, block_tables, start_pos, true_len,
+            scale, window=cfg.sliding_window, layer=layer,
         )  # [P, Lpad, Hq, D] — flash kernel on TPU, blockwise elsewhere
         attn_flat = attn.reshape(P, Lpad, -1)
         o = _row_parallel("plh,he->ple", attn_flat,
@@ -822,10 +811,10 @@ def prefill_batch_step(
         x = x + o
         h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
         x = x + _mlp_block(lp, cfg, h, lora_idx, rows_valid=valid)
-        return x, (k_l, v_l)
+        return x, k_caches, v_caches
 
-    x, (k_caches, v_caches) = jax.lax.scan(
-        layer_fn, x, (params["layers"], k_caches, v_caches)
+    x, k_caches, v_caches = _scan_layers(
+        layer_fn, x, params, k_caches, v_caches
     )
     if all_logits:
         return _unembed(params, cfg, x), k_caches, v_caches  # [P, Lpad, V]

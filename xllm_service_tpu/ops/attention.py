@@ -15,6 +15,14 @@ Cache layout (one layer): k_cache, v_cache `[num_blocks, num_kv_heads,
 block_size, head_dim]` — KV-head-major within a block so the Pallas kernel
 DMAs a [block_size, head_dim] tile per (block, head) with TPU-legal tiling;
 the KV-head axis shards over the `tp` mesh axis.
+
+Every GQA entry point here also takes the STACKED pool `[L, num_blocks,
+...]` with `layer=` (an int32 scalar, traced inside the layer scan): the
+kernels address `[layer, blk, head]` and the fallbacks gather
+`cache[layer, table]`, so no path ever materializes one layer of the pool
+(models/llama.py carries the stack through its scans; docs/KV_CACHE.md).
+Without `layer` the cache is one layer's 4-D array, as the MLA paths and
+the kernel tests pass it.
 """
 
 from __future__ import annotations
@@ -85,12 +93,13 @@ def declared_shard_context():
 
 
 def _cache_shard_spec(cache, axis: str):
-    """shard_map spec pytree for a per-layer cache operand: data
-    [N, Hc, BS, D] and int8 scale [N, Hc, G, BS] both carry the head
-    axis at dim 1."""
+    """shard_map spec pytree for a cache operand: data [(L,) N, Hc, BS, D]
+    and int8 scale [(L,) N, Hc, G, BS] both carry the head axis third
+    from last."""
     from jax.sharding import PartitionSpec as P
 
-    spec = P(None, axis, None, None)
+    lead = kvc.raw(cache).ndim - 3
+    spec = P(*(None,) * lead, axis, None, None)
     if isinstance(cache, kvc.PagedKV):
         return kvc.PagedKV(spec, spec if cache.scale is not None else None)
     return spec
@@ -106,16 +115,23 @@ def _shardable(q: jnp.ndarray, k_cache, ctx) -> bool:
     return q.shape[-2] % n == 0 and kvc.raw(k_cache).shape[-3] % n == 0
 
 
-def _sharded_kernel_call(body, ctx, q_spec_ndim: int, q, k_cache, v_cache,
-                         *rep_args):
-    """Run `body(q, k, v, *rep_args)` once per tp shard via shard_map.
+def _kernel_call(body, ctx, q_spec_ndim: int, q, k_cache, v_cache,
+                 *rep_args, layer=None):
+    """Run `body(q, k, v, *rep_args, layer)`: directly, or once per tp
+    shard via shard_map under a shard context.
 
     `body` receives PER-SHARD operands (Hq/tp query heads, Hc/tp cache
     rows) and must do its own packing (kernel_io_for inside the body sees
-    the per-shard geometry). Tables/lengths/positions replicate; the
-    output's head axis is at `q_spec_ndim - 1` == ndim-2 of q."""
+    the per-shard geometry). Tables/lengths/positions and the layer index
+    replicate; the output's head axis is at `q_spec_ndim - 1` == ndim-2
+    of q."""
     from jax.sharding import PartitionSpec as P
 
+    # One operand list for both routes: a 4-D cache reads "layer 0" of
+    # its own L = 1 stack (pallas/paged_attention.stack_operands).
+    rep_args += (jnp.asarray(0 if layer is None else layer, jnp.int32),)
+    if ctx is None:
+        return body(q, k_cache, v_cache, *rep_args)
     mesh, axis = ctx
     head_ax = q_spec_ndim - 2
     q_spec = P(*(
@@ -195,14 +211,19 @@ def gather_context(
     v_cache,
     block_table: jnp.ndarray,  # [R, max_blocks] int32
     unpack: int = 1,
+    layer=None,
 ):
     """Gather each sequence's context as [R, max_blocks*block_size, Hkv, D].
     Quantized (int8) caches are dequantized after the gather — only the
     sequence's own blocks pay the dequant, not the whole pool. `unpack`
     undoes packed-pair rows (head_dim < 128 layouts) on the gathered
-    slice only."""
-    k_ctx = kvc.unpack_rows(kvc.gather_blocks(k_cache, block_table), unpack)
-    v_ctx = kvc.unpack_rows(kvc.gather_blocks(v_cache, block_table), unpack)
+    slice only. `layer` indexes a stacked pool."""
+    k_ctx = kvc.unpack_rows(
+        kvc.gather_blocks(k_cache, block_table, layer=layer), unpack
+    )
+    v_ctx = kvc.unpack_rows(
+        kvc.gather_blocks(v_cache, block_table, layer=layer), unpack
+    )
     k_ctx = jnp.swapaxes(k_ctx, 2, 3)
     v_ctx = jnp.swapaxes(v_ctx, 2, 3)
     R, MB, BS, H, D = k_ctx.shape
@@ -238,6 +259,7 @@ def paged_attention_gather(
     seq_lens: jnp.ndarray,  # [R] context length INCLUDING current token
     scale: float,
     window: int = 0,
+    layer=None,
 ) -> jnp.ndarray:
     """Decode-step attention: each query attends to its first seq_lens cache
     rows — the LAST `window` of them when sliding-window attention is on
@@ -245,7 +267,7 @@ def paged_attention_gather(
     [R, Hq, D]."""
     k_ctx, v_ctx = gather_context(
         k_cache, v_cache, block_table,
-        unpack=_pack_ratio(k_cache, q.shape[-1]),
+        unpack=_pack_ratio(k_cache, q.shape[-1]), layer=layer,
     )
     Lk = k_ctx.shape[1]
     cols = jnp.arange(Lk, dtype=jnp.int32)[None, :]  # [1, Lk]
@@ -265,6 +287,7 @@ def prefill_attention_gather(
     true_len: jnp.ndarray,  # scalar int32: valid tokens in this chunk
     scale: float,
     window: int = 0,
+    layer=None,
 ) -> jnp.ndarray:
     """Chunked-prefill attention for one sequence: rows are chunk positions
     start_pos..start_pos+L, columns the sequence's cache rows (which already
@@ -274,7 +297,7 @@ def prefill_attention_gather(
     serving path uses prefill_attention_blockwise. Returns [L, Hq, D]."""
     k_ctx, v_ctx = gather_context(
         k_cache, v_cache, block_table[None],
-        unpack=_pack_ratio(k_cache, q.shape[-1]),
+        unpack=_pack_ratio(k_cache, q.shape[-1]), layer=layer,
     )
     L = q.shape[0]
     Lk = k_ctx.shape[1]
@@ -291,13 +314,14 @@ def prefill_attention_gather(
 
 def prefill_attention_blockwise(
     q: jnp.ndarray,  # [L, Hq, D]
-    k_cache: jnp.ndarray,  # [num_blocks, Hkv, BS, D]
+    k_cache: jnp.ndarray,  # [(L,) num_blocks, Hkv, BS, D]
     v_cache: jnp.ndarray,
     block_table: jnp.ndarray,  # [CB] — sliced to the context bound
     start_pos: jnp.ndarray,  # scalar int32
     true_len: jnp.ndarray,  # scalar int32
     scale: float,
     window: int = 0,
+    layer=None,
 ) -> jnp.ndarray:
     """Flash-style prefill: lax.scan over KV blocks with online-softmax
     accumulation. Peak memory is O(L * BS) per step instead of the dense
@@ -322,10 +346,10 @@ def prefill_attention_blockwise(
         m_prev, l_prev, acc = carry
         blk_idx, blk_id = inputs
         k_blk = kvc.unpack_rows(
-            kvc.gather_block(k_cache, blk_id, jnp.float32), pack
+            kvc.gather_block(k_cache, blk_id, jnp.float32, layer), pack
         )  # [Hkv, BS, D]
         v_blk = kvc.unpack_rows(
-            kvc.gather_block(v_cache, blk_id, jnp.float32), pack
+            kvc.gather_block(v_cache, blk_id, jnp.float32, layer), pack
         )
         cols = blk_idx * BS + jnp.arange(BS, dtype=jnp.int32)
         scores = (
@@ -401,6 +425,23 @@ def gqa_kernel_eligible(
     )
 
 
+def cache_kernel_route(cache, interpret: bool = False):
+    """(eligible, ctx) for a Pallas launch over the pool `cache` itself,
+    not through a dispatcher here (the in-place write, ops/kv_write.py):
+    the GQA tile gate on the attached platform and, on a tp mesh, the
+    shard context to launch per shard under (`ctx`, as _kernel_call takes
+    it; None on one device). Not eligible under the XLLM_SHARDED_KERNELS=0
+    hatch, which sends everything back to GSPMD, nor when the cache heads
+    do not split over the shards."""
+    ctx = shard_context()
+    ok = (
+        _gqa_kernel_ok(cache, _on_tpu() or interpret)
+        and (ctx is not None or declared_shard_context() is None)
+        and (ctx is None or kvc.raw(cache).shape[-3] % ctx[0].shape[ctx[1]] == 0)
+    )
+    return ok, ctx
+
+
 def _mla_kernel_ok(c_cache, on: bool) -> bool:
     return _kernel_tile_ok(c_cache, kvc.raw(c_cache).shape[-1], on)
 
@@ -416,6 +457,7 @@ def prefill_attention(
     use_kernel: bool | None = None,
     interpret: bool = False,
     window: int = 0,
+    layer=None,
 ) -> jnp.ndarray:
     """Batched chunked-prefill attention over the paged cache; Pallas
     flash kernel (ops/pallas/flash_prefill.py) on TPU, vmapped blockwise
@@ -465,22 +507,20 @@ def prefill_attention(
 
         seq_lens = jnp.where(true_len > 0, start_pos + 1, 0)
 
-        def mq_body(qq, kk, vv, bt, sl):
+        def mq_body(qq, kk, vv, bt, sl, lyr):
             pack, kv_heads, q_packed = kernel_io_for(kk, qq)
             return unpack_outputs(
                 multiquery_paged_attention_kernel(
                     q_packed, kk, vv, bt, sl, scale,
-                    interpret=interpret, window=window,
+                    interpret=interpret, window=window, layer=lyr,
                 ),
                 pack, kv_heads,
             )
 
-        if ctx is not None:
-            return _sharded_kernel_call(
-                mq_body, ctx, 4, q, k_cache, v_cache, block_tables,
-                seq_lens,
-            )
-        return mq_body(q, k_cache, v_cache, block_tables, seq_lens)
+        return _kernel_call(
+            mq_body, ctx, 4, q, k_cache, v_cache, block_tables, seq_lens,
+            layer=layer,
+        )
 
     env = os.environ.get("XLLM_PREFILL_ATTENTION_KERNEL")
     if use_kernel is None:
@@ -490,27 +530,24 @@ def prefill_attention(
             flash_prefill_kernel,
         )
 
-        def flash_body(qq, kk, vv, bt, sp, tl):
+        def flash_body(qq, kk, vv, bt, sp, tl, lyr):
             pack, kv_heads, q_packed = kernel_io_for(kk, qq)
             return unpack_outputs(
                 flash_prefill_kernel(
                     q_packed, kk, vv, bt, sp, tl, scale,
-                    interpret=interpret, window=window,
+                    interpret=interpret, window=window, layer=lyr,
                 ),
                 pack, kv_heads,
             )
 
-        if ctx is not None:
-            return _sharded_kernel_call(
-                flash_body, ctx, 4, q, k_cache, v_cache, block_tables,
-                start_pos, true_len,
-            )
-        return flash_body(
-            q, k_cache, v_cache, block_tables, start_pos, true_len
+        return _kernel_call(
+            flash_body, ctx, 4, q, k_cache, v_cache, block_tables,
+            start_pos, true_len, layer=layer,
         )
     return jax.vmap(
         lambda qi, ti, sp, tl: prefill_attention_blockwise(
-            qi, k_cache, v_cache, ti, sp, tl, scale, window=window
+            qi, k_cache, v_cache, ti, sp, tl, scale, window=window,
+            layer=layer,
         )
     )(q, block_tables, start_pos, true_len)
 
@@ -707,7 +744,7 @@ def _on_tpu() -> bool:
 def paged_attention(
     q, k_cache, v_cache, block_table, seq_lens, scale,
     use_kernel: bool | None = None, window: int = 0,
-    interpret: bool = False,
+    interpret: bool = False, layer=None,
 ):
     """Decode paged attention; Pallas kernel on TPU, gather fallback elsewhere.
 
@@ -744,26 +781,25 @@ def paged_attention(
         except ImportError:
             use_kernel = False
         else:
-            def body(qq, kk, vv, bt, sl):
+            def body(qq, kk, vv, bt, sl, lyr):
                 # Per-shard packing: kernel_io_for reads the (per-shard,
                 # under shard_map) cache geometry.
                 pack, kv_heads, q_packed = kernel_io_for(kk, qq)
                 return unpack_outputs(
                     paged_attention_kernel(
                         q_packed, kk, vv, bt, sl, scale,
-                        window=window, interpret=interpret,
+                        window=window, interpret=interpret, layer=lyr,
                     ),
                     pack, kv_heads,
                 )
 
-            if ctx is not None:
-                return _sharded_kernel_call(
-                    body, ctx, 3, q, k_cache, v_cache, block_table,
-                    seq_lens,
-                )
-            return body(q, k_cache, v_cache, block_table, seq_lens)
+            return _kernel_call(
+                body, ctx, 3, q, k_cache, v_cache, block_table, seq_lens,
+                layer=layer,
+            )
     return paged_attention_gather(
-        q, k_cache, v_cache, block_table, seq_lens, scale, window=window
+        q, k_cache, v_cache, block_table, seq_lens, scale, window=window,
+        layer=layer,
     )
 
 
@@ -797,6 +833,7 @@ def ragged_attention_blockwise(
     seg_lens: tuple,  # static per-row segment capacities
     scale: float,
     window: int = 0,
+    layer=None,
 ) -> jnp.ndarray:
     """Blockwise oracle for the ragged mixed contract: each row runs the
     chunked-prefill blockwise scan (prefill_attention_blockwise handles
@@ -809,7 +846,7 @@ def ragged_attention_blockwise(
     for b, seg in enumerate(seg_lens):
         out_b = prefill_attention_blockwise(
             q[off:off + seg], k_cache, v_cache, block_tables[b],
-            pos0[b], q_len[b], scale, window=window,
+            pos0[b], q_len[b], scale, window=window, layer=layer,
         )
         # Blockwise emits acc/l with l=0 rows zeroed already; mask the
         # padded tail explicitly so dead segments are deterministic.
@@ -860,6 +897,7 @@ def ragged_paged_attention(
     use_kernel: bool | None = None,
     interpret: bool = False,
     window: int = 0,
+    layer=None,
 ) -> jnp.ndarray:
     """Ragged mixed-batch paged attention: ONE Pallas dispatch over
     prefill + decode rows when the kernel is enabled
@@ -878,25 +916,23 @@ def ragged_paged_attention(
             ragged_paged_attention_kernel,
         )
 
-        def body(qq, kk, vv, bt, ql, p0):
+        def body(qq, kk, vv, bt, ql, p0, lyr):
             pack, kv_heads, q_packed = kernel_io_for(kk, qq)
             return unpack_outputs(
                 ragged_paged_attention_kernel(
                     q_packed, kk, vv, bt, ql, p0, seg_lens, scale,
-                    interpret=interpret, window=window,
+                    interpret=interpret, window=window, layer=lyr,
                 ),
                 pack, kv_heads,
             )
 
-        if ctx is not None:
-            return _sharded_kernel_call(
-                body, ctx, 3, q, k_cache, v_cache, block_tables,
-                q_len, pos0,
-            )
-        return body(q, k_cache, v_cache, block_tables, q_len, pos0)
+        return _kernel_call(
+            body, ctx, 3, q, k_cache, v_cache, block_tables, q_len, pos0,
+            layer=layer,
+        )
     return ragged_attention_blockwise(
         q, k_cache, v_cache, block_tables, q_len, pos0, seg_lens, scale,
-        window=window,
+        window=window, layer=layer,
     )
 
 
@@ -914,6 +950,7 @@ def mixed_attention(
     use_ragged: bool | None = None,
     interpret: bool = False,
     window: int = 0,
+    layer=None,
 ):
     """Attention for one MIXED engine step (models.llama.mixed_step):
     decode slots and chunked-prefill rows against the same paged KV.
@@ -950,6 +987,7 @@ def mixed_attention(
         out = ragged_paged_attention(
             q_flat, k_cache, v_cache, tables, q_len, pos0, seg_lens,
             scale, use_kernel=True, interpret=interpret, window=window,
+            layer=layer,
         )
         return out[:R], out[R:].reshape(q_pf.shape)
     # Reference pair: EXACTLY the split engine's dispatchers. interpret
@@ -959,11 +997,11 @@ def mixed_attention(
     # blockwise, breaking the mixed ≡ split byte-parity contract.
     dec_out = paged_attention(
         q_dec, k_cache, v_cache, dec_tables, dec_seq_lens, scale,
-        window=window,
+        window=window, layer=layer,
     )
     pf_out = prefill_attention(
         q_pf, k_cache, v_cache, pf_tables, pf_start, pf_len, scale,
-        window=window,
+        window=window, layer=layer,
     )
     return dec_out, pf_out
 
@@ -983,6 +1021,7 @@ def mixed_prefill_attention(
     use_ragged: bool | None = None,
     interpret: bool = False,
     window: int = 0,
+    layer=None,
 ):
     """Attention for one fused speculative MIXED step
     (models.llama.mixed_verify_step): TWO prefill-shaped halves — the
@@ -1020,6 +1059,7 @@ def mixed_prefill_attention(
         out = ragged_paged_attention(
             q_flat, k_cache, v_cache, tables, q_len, pos0, seg_lens,
             scale, use_kernel=True, interpret=interpret, window=window,
+            layer=layer,
         )
         return (
             out[: A * La].reshape(q_a.shape),
@@ -1028,11 +1068,11 @@ def mixed_prefill_attention(
     return (
         prefill_attention(
             q_a, k_cache, v_cache, a_tables, a_start, a_len, scale,
-            window=window,
+            window=window, layer=layer,
         ),
         prefill_attention(
             q_b, k_cache, v_cache, b_tables, b_start, b_len, scale,
-            window=window,
+            window=window, layer=layer,
         ),
     )
 

@@ -215,18 +215,40 @@ def scatter_rows(
     blk: jnp.ndarray,  # [T] int32 block ids (0 = garbage block)
     offset: jnp.ndarray,  # [T] int32 in-block offsets
     rows: jnp.ndarray,  # [T, Hkv, D] model-dtype K or V rows
+    layer=None,  # int32 scalar: the cache is the STACK [L, N, Hkv, BS, D]
 ) -> CacheLike:
-    """Write per-token rows into cache slots [N, Hkv, BS, D] (one layer's
-    cache — the layer axis is already sliced off by the caller's scan)."""
+    """Write per-token rows into cache slots. Without `layer` the cache
+    is one layer's [N, Hkv, BS, D] (models/deepseek.py's scans slice the
+    layer axis off); with it the cache is the stacked pool and the rows
+    land at [layer, blk, :, offset, :] — the llama-family layer scans
+    carry the stack and never slice a layer out (docs/KV_CACHE.md).
+
+    Into the stack every LEADING dim is indexed (the heads by an iota)
+    and only trailing dims are windows: the scatter XLA needs no
+    transposed copy of its operand for. Indexed as `[layer, blk, :, off]`
+    the window (heads, D) straddles the scattered `off`, and XLA copies
+    the whole stack into another dim order and back, every layer (read
+    in the CPU's and the chip's HLO, PR 29)."""
+    if layer is None:
+        return set_rows(
+            cache,
+            (blk, slice(None), offset, slice(None)),
+            # Pool scales are [N, H, G, BS]: offset picks the BS lane, the
+            # slices keep heads and groups -> slot [T, H, G], matching the
+            # groups-last quantized values exactly.
+            (blk, slice(None), slice(None), offset),
+            rows,
+            mode="token",
+        )
+    heads = jnp.arange(rows.shape[-2], dtype=jnp.int32)
+    b, h, o = blk[:, None], heads[None, :], offset[:, None]
+    scale_index = None  # read by set_rows for quantized pools only
+    if isinstance(cache, PagedKV) and cache.quantized:
+        # [L, N, H, G, BS] scale planes: slot [T, H, G], every dim indexed.
+        groups = jnp.arange(cache.scale.shape[-2], dtype=jnp.int32)
+        scale_index = (layer, b[..., None], h[..., None], groups, o[..., None])
     return set_rows(
-        cache,
-        (blk, slice(None), offset, slice(None)),
-        # Pool scales are [N, H, G, BS]: offset picks the BS lane, the
-        # slices keep heads and groups -> slot [T, H, G], matching the
-        # groups-last quantized values exactly.
-        (blk, slice(None), slice(None), offset),
-        rows,
-        mode="token",
+        cache, (layer, b, h, o, slice(None)), scale_index, rows, mode="token"
     )
 
 
@@ -275,24 +297,27 @@ def quantize_pool(cache: jnp.ndarray, groups: int = GQA_SCALE_GROUPS) -> PagedKV
     return PagedKV(q, jnp.swapaxes(s, -1, -2))
 
 
-def gather_block(cache: CacheLike, block_id, dtype=jnp.bfloat16):
-    """One block [Hkv, BS, D] dequantized to `dtype` (blockwise prefill)."""
+def gather_block(cache: CacheLike, block_id, dtype=jnp.bfloat16, layer=None):
+    """One block [Hkv, BS, D] dequantized to `dtype` (blockwise prefill).
+    `layer` indexes a stacked pool: the block is gathered, never a layer."""
+    idx = block_id if layer is None else (layer, block_id)
     if isinstance(cache, PagedKV) and cache.quantized:
-        return dequantize_pool(
-            cache.data[block_id], cache.scale[block_id], dtype
-        )
-    return raw(cache)[block_id].astype(dtype)
+        return dequantize_pool(cache.data[idx], cache.scale[idx], dtype)
+    return raw(cache)[idx].astype(dtype)
 
 
-def gather_blocks(cache: CacheLike, block_table: jnp.ndarray, dtype=None):
+def gather_blocks(
+    cache: CacheLike, block_table: jnp.ndarray, dtype=None, layer=None
+):
     """Gather + dequantize blocks via a block table of any shape [...B];
-    returns [...B, Hkv, BS, D]."""
+    returns [...B, Hkv, BS, D]. `layer` indexes a stacked pool
+    (`cache[layer, table]`: blocks are gathered, never a layer)."""
+    idx = block_table if layer is None else (layer, block_table)
     if isinstance(cache, PagedKV) and cache.quantized:
         return dequantize_pool(
-            cache.data[block_table], cache.scale[block_table],
-            dtype or jnp.bfloat16,
+            cache.data[idx], cache.scale[idx], dtype or jnp.bfloat16
         )
-    out = raw(cache)[block_table]
+    out = raw(cache)[idx]
     return out if dtype is None else out.astype(dtype)
 
 

@@ -1,0 +1,219 @@
+"""The serving steps' cache write: a step's new K/V rows into the carried
+stack, in place.
+
+The llama-family step programs carry the STACKED pool through their layer
+scan (models/llama.py `_scan_layers`) and land each layer's new rows in it
+without moving it. Where the rows go is the same for every layer, so it is
+worked out once per step, outside the scan (`write_plan`); each layer then
+calls `write_kv` with its rows and its index.
+
+Two routes, one result outside garbage block 0. Where the attention
+kernels serve the pool (ops/attention.cache_kernel_route: on the chip,
+whole tiles, per shard on a tp mesh) the rows go in through the Pallas
+tile write ops/pallas/kv_write.py; elsewhere through XLA's scatter
+(ops/kv_cache.scatter_rows with a layer index). Both write in place; the
+kernel is there because the chip's scatter walks row by row: against the
+scatter alone it is worth 2.3-3.7 % of `out_tokens_per_s` in decode-batch
+and 11-17 % of `ttft_p50_ms` in chat-steady (PERF.md, PR 29 review round).
+
+This module sits ABOVE ops/kv_cache.py (the pool's layout and its plain
+reads and writes) and ops/attention.py (the kernels' platform, tile and
+shard gate): it imports both, neither imports it.
+"""
+
+from __future__ import annotations
+
+from typing import NamedTuple, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from xllm_service_tpu.ops import attention
+from xllm_service_tpu.ops.kv_cache import (
+    CacheLike,
+    PagedKV,
+    as_paged,
+    pack_rows,
+    quantize_rows,
+    raw,
+    scatter_rows,
+)
+from xllm_service_tpu.ops.pallas.kv_write import kv_write_kernel
+
+
+class _Units(NamedTuple):
+    """Tiles one step's rows touch, at one granularity (docs/KV_CACHE.md,
+    ops/pallas/kv_write.py): `tile` token positions per unit, `count`
+    units per sequence."""
+
+    blk: jnp.ndarray  # [S*count] block id (0 = garbage, for dead units)
+    sub: jnp.ndarray  # [S*count] tile index inside the block
+    lo: jnp.ndarray  # [S*count] first new position of the tile
+    hi: jnp.ndarray  # [S*count] one past the last (lo == hi: dead unit)
+    shift: jnp.ndarray  # [S] where a sequence's first tile starts in its
+    # front-padded rows (see _unit_tiles)
+    tile: int
+    count: int
+
+
+class WritePlan(NamedTuple):
+    """Where one step's new K/V rows go: per token (`blk`, `off`) for the
+    XLA scatter, or per tile (`units`, `scale_units`) for the Pallas
+    write; a plan holds one or the other."""
+
+    blk: Optional[jnp.ndarray]  # [S*W] block id per token (0 = garbage)
+    off: Optional[jnp.ndarray]  # [S*W] in-block offset per token
+    width: int  # W: token positions per sequence in this step
+    units: Optional[_Units] = None
+    scale_units: Optional[_Units] = None  # int8 pools: BS positions a tile
+    ctx: Optional[tuple] = None  # (mesh, axis): one kernel launch per shard
+    interpret: bool = False
+
+
+def _plan_units(tables, start, length, width: int, bs: int, tile: int):
+    count = (width + 2 * tile - 2) // tile  # tiles `width` rows can touch
+    first = (start // tile)[:, None] + jnp.arange(count, dtype=jnp.int32)
+    pos = first * tile  # [S, count] position of each tile's first row
+    lo = jnp.clip(start[:, None] - pos, 0, tile)
+    hi = jnp.clip((start + length)[:, None] - pos, 0, tile)
+    live = hi > lo
+    bi = jnp.minimum(pos // bs, tables.shape[1] - 1)
+    blk = jnp.where(live, jnp.take_along_axis(tables, bi, axis=1), 0)
+    sub = jnp.where(live, (pos % bs) // tile, 0)
+    return _Units(
+        blk.reshape(-1), sub.reshape(-1), lo.reshape(-1), hi.reshape(-1),
+        tile - start % tile, tile, count,
+    )
+
+
+def write_plan(
+    cache: CacheLike,  # a stacked pool, read for its geometry only
+    tables: jnp.ndarray,  # [S, CB] int32 block tables
+    start: jnp.ndarray,  # [S] int32 position of each sequence's first row
+    length: jnp.ndarray,  # [S] int32 rows to write (0 = nothing)
+    width: int,  # W: rows per sequence in the step's [S, W] layout
+    interpret: bool = False,  # tests: the Pallas write, interpreted
+) -> WritePlan:
+    """Plan one step's cache write: sequence s lands rows [0, length[s])
+    of its W at positions start[s].. of its block table. Rows past
+    `length` go nowhere (the scatter route sends them to garbage block 0,
+    offset 0, as it always has).
+
+    The Pallas route is taken where the attention kernels serve the pool
+    (attention.cache_kernel_route); a tile is the dtype's native sublane
+    count (16 rows of bf16)."""
+    data = raw(cache)
+    bs = data.shape[-2]
+    start = start.astype(jnp.int32)
+    length = length.astype(jnp.int32)
+    kernel, ctx = attention.cache_kernel_route(cache, interpret)
+    if kernel:
+        quantized = isinstance(cache, PagedKV) and cache.quantized
+        return WritePlan(
+            None, None, width,
+            _plan_units(
+                tables, start, length, width, bs, 32 // data.dtype.itemsize
+            ),
+            _plan_units(tables, start, length, width, bs, bs)
+            if quantized else None,
+            ctx,
+            interpret,
+        )
+    offs = jnp.arange(width, dtype=jnp.int32)[None, :]
+    pos = start[:, None] + offs
+    valid = offs < length[:, None]
+    blk = jnp.where(valid, jnp.take_along_axis(tables, pos // bs, axis=1), 0)
+    off = jnp.where(valid, pos % bs, 0)
+    return WritePlan(blk.reshape(-1), off.reshape(-1), width)
+
+
+def _unit_tiles(x: jnp.ndarray, units: _Units, lanes: bool) -> jnp.ndarray:
+    """Cut a step's rows x [S, W, Hc, Y] into the tiles its units name:
+    [S*count, Hc, tile, Y], or [S*count, Hc, Y, tile] with the token axis
+    on `lanes` (scale planes). Row j of a sequence's tiles is position
+    first_tile*tile + j, i.e. its row j - start % tile: front-pad by one
+    tile and slice from `shift`. A single row (W == 1) is handed over as
+    it is, [S, Hc, 1, Y]: the kernel broadcasts it over the tile and the
+    unit's lo/hi pick its place."""
+    S, W, Hc, Y = x.shape
+    if W == 1:
+        return x[:, 0, :, :, None] if lanes else x[:, 0, :, None, :]
+    g, C = units.tile, units.count
+    xp = jnp.pad(x, ((0, 0), (g, C * g - W), (0, 0), (0, 0)))
+    t = jax.vmap(
+        lambda r, o: jax.lax.dynamic_slice_in_dim(r, o, C * g, axis=0)
+    )(xp, units.shift)
+    t = jnp.moveaxis(t.reshape(S, C, g, Hc, Y), 2, 4 if lanes else 3)
+    return t.reshape(S * C, *t.shape[2:])
+
+
+def _write_units(caches, rows, units: _Units, layer, lanes, plan):
+    """One Pallas launch over `units` for every (cache, rows) pair; under
+    a tp shard context one launch per shard over its own heads (the pool
+    and the rows both carry the head axis; the units replicate)."""
+    tiles = tuple(_unit_tiles(r, units, lanes) for r in rows)
+
+    def body(caches, tiles, blk, sub, lo, hi, layer):
+        return kv_write_kernel(
+            caches, tiles, blk, sub, lo, hi, layer,
+            tile=caches[0].shape[-2] if lanes else units.tile,
+            axis=-1 if lanes else -2, interpret=plan.interpret,
+        )
+
+    if plan.ctx is not None:
+        mesh, axis = plan.ctx
+        pool, tile = P(None, None, axis), P(None, axis)
+        body = jax.shard_map(
+            body, mesh=mesh,
+            in_specs=((pool,) * len(caches), (tile,) * len(tiles))
+            + (P(),) * 5,
+            out_specs=(pool,) * len(caches), check_vma=False,
+        )
+    return body(
+        tuple(caches), tiles, units.blk, units.sub, units.lo, units.hi,
+        jnp.asarray(layer, jnp.int32),
+    )
+
+
+def write_kv(
+    k_cache: CacheLike,  # stacked pools [L, N, Hc, BS, Dc]
+    v_cache: CacheLike,
+    plan: WritePlan,
+    k: jnp.ndarray,  # [S*W, Hkv, D] this layer's new rows, plan order
+    v: jnp.ndarray,
+    layer,  # int32 scalar
+) -> Tuple[CacheLike, CacheLike]:
+    """Land one layer's new K/V rows in the stacked pools, in place.
+    Packed caches (Hc < Hkv, see kv_pack_factor) take the rows reshaped
+    to the packed layout; int8 caches quantize them on the way.
+
+    The plan says which route (module docstring); neither moves the pool."""
+    k, v = pack_rows(k, k_cache), pack_rows(v, v_cache)
+    if plan.units is None:
+        return (
+            scatter_rows(k_cache, plan.blk, plan.off, k, layer),
+            scatter_rows(v_cache, plan.blk, plan.off, v, layer),
+        )
+    bare = not isinstance(k_cache, PagedKV)
+    k_cache, v_cache = as_paged(k_cache), as_paged(v_cache)
+    seg = lambda x: x.reshape(-1, plan.width, *x.shape[1:])
+    if k_cache.quantized:
+        groups = k_cache.scale.shape[-2]
+        (k, ks), (v, vs) = quantize_rows(k, groups), quantize_rows(v, groups)
+        k_scale, v_scale = _write_units(
+            (k_cache.scale, v_cache.scale), (seg(ks), seg(vs)),
+            plan.scale_units, layer, True, plan,
+        )
+    else:
+        k, v = k.astype(k_cache.dtype), v.astype(v_cache.dtype)
+        k_scale = v_scale = None
+    k_data, v_data = _write_units(
+        (k_cache.data, v_cache.data), (seg(k), seg(v)),
+        plan.units, layer, False, plan,
+    )
+    if bare:
+        return k_data, v_data
+    return PagedKV(k_data, k_scale), PagedKV(v_data, v_scale)
+
+

@@ -26,8 +26,9 @@ kernel's loop structure with a query-tile axis):
     in VMEM via the shared expansion matmul (paged_attention.dequant_tile
     explains why column folding is off the table).
 
-Layouts: q [P, Lpad, Hq, D] (chunk-relative), caches [N, Hkv, BS, D],
-block_table [P, MB] int32, start_pos/true_len [P] int32. Returns
+Layouts: q [P, Lpad, Hq, D] (chunk-relative), caches the stacked pool
+[L, N, Hkv, BS, D] plus a layer index in scalar memory
+(paged_attention.stack_operands; 4-D is the L = 1 case), block_table [P, MB] int32, start_pos/true_len [P] int32. Returns
 [P, Lpad, Hq, D]. Parity oracle: ops/attention.prefill_attention_blockwise
 (tests/test_pallas_kernels.py drives interpret mode on CPU).
 """
@@ -42,7 +43,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from xllm_service_tpu.ops.pallas import mosaic_rules as mosaic
-from xllm_service_tpu.ops.pallas.paged_attention import dequant_tile
+from xllm_service_tpu.ops.pallas.paged_attention import (
+    dequant_tile,
+    stack_operands,
+)
 
 NEG_INF = -1e30
 
@@ -52,11 +56,12 @@ def _prefill_kernel(
     block_table_ref,  # [P, MBp] SMEM
     start_pos_ref,    # [P] SMEM
     true_len_ref,     # [P] SMEM
+    layer_ref,        # [1] SMEM — which layer of the stack to read
     # inputs
     q_ref,            # [1, 1, 1, Rp, D] VMEM (one tile's TQ*G rows)
-    k_hbm,            # [N, Hkv, BS, D] HBM
-    v_hbm,            # [N, Hkv, BS, D] HBM
-    *rest,            # quantized: ks_hbm, vs_hbm [N, Hkv, G, BS] f32; then
+    k_hbm,            # [L, N, Hkv, BS, D] HBM
+    v_hbm,            # [L, N, Hkv, BS, D] HBM
+    *rest,            # quantized: ks_hbm, vs_hbm [L, N, Hkv, G, BS] f32; then
     # o_ref + scratch (quantized scale bufs are [2, C, G, BS] f32)
     block_size: int,
     chunk: int,
@@ -75,6 +80,7 @@ def _prefill_kernel(
     p = pl.program_id(0)
     h = pl.program_id(1)
     t = pl.program_id(2)
+    lyr = layer_ref[0]
     start = start_pos_ref[p]
     n_valid = true_len_ref[p]
     span = chunk * block_size
@@ -96,28 +102,28 @@ def _prefill_kernel(
         off = c_idx * block_size
         out = [
             mosaic.async_copy(
-                mosaic.checked_at(k_hbm, blk, h),
+                mosaic.checked_at(k_hbm, lyr, blk, h),
                 mosaic.checked_at(k_buf, slot, pl.ds(off, block_size)),
                 sems.at[slot, 0, c_idx],
             ),
             mosaic.async_copy(
-                mosaic.checked_at(v_hbm, blk, h),
+                mosaic.checked_at(v_hbm, lyr, blk, h),
                 mosaic.checked_at(v_buf, slot, pl.ds(off, block_size)),
                 sems.at[slot, 1, c_idx],
             ),
         ]
         if quantized:
-            # Head h's [G, BS] scale tile (blk, h on untiled dims).
+            # Head h's [G, BS] scale tile (layer, blk, h on untiled dims).
             out.append(
                 mosaic.async_copy(
-                    mosaic.checked_at(ks_hbm, blk, h),
+                    mosaic.checked_at(ks_hbm, lyr, blk, h),
                     mosaic.checked_at(ks_buf, slot, c_idx),
                     ssems.at[slot, 0, c_idx],
                 )
             )
             out.append(
                 mosaic.async_copy(
-                    mosaic.checked_at(vs_hbm, blk, h),
+                    mosaic.checked_at(vs_hbm, lyr, blk, h),
                     mosaic.checked_at(vs_buf, slot, c_idx),
                     ssems.at[slot, 1, c_idx],
                 )
@@ -225,7 +231,7 @@ def _round_up(x: int, m: int) -> int:
 )
 def flash_prefill_kernel(
     q: jnp.ndarray,            # [P, Lpad, Hq, D]
-    k_cache,                   # [N, Hkv, BS, D] plain array or PagedKV
+    k_cache,                   # [(L,) N, Hkv, BS, D] plain or PagedKV
     v_cache,
     block_table: jnp.ndarray,  # [P, MB] int32
     start_pos: jnp.ndarray,    # [P] int32
@@ -235,16 +241,14 @@ def flash_prefill_kernel(
     chunk: int = 4,
     tile_q: int = 128,
     window: int = 0,
+    layer=None,                # int32 scalar when the caches are stacks
 ) -> jnp.ndarray:
-    from xllm_service_tpu.ops import kv_cache as kvc
-
-    k_cache = kvc.as_paged(k_cache)
-    v_cache = kvc.as_paged(v_cache)
+    k_cache, v_cache, layer = stack_operands(k_cache, v_cache, layer)
     quantized = k_cache.quantized
     k_data, v_data = k_cache.data, v_cache.data
 
     P, Lpad, Hq, D = q.shape
-    N, Hkv, BS, _ = k_data.shape
+    _, N, Hkv, BS, _ = k_data.shape
     MB = block_table.shape[1]
     G = Hq // Hkv
     TQ = min(tile_q, _round_up(Lpad, 8))
@@ -272,13 +276,13 @@ def flash_prefill_kernel(
     hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)
     in_specs = [
         pl.BlockSpec(
-            (1, 1, 1, Rp, D), lambda p, h, t, bt, sp, tl: (p, h, t, 0, 0)
+            (1, 1, 1, Rp, D), lambda p, h, t, *_: (p, h, t, 0, 0)
         ),
         hbm,
         hbm,
     ]
     inputs = [
-        bt, start_pos.astype(jnp.int32), true_len.astype(jnp.int32),
+        bt, start_pos.astype(jnp.int32), true_len.astype(jnp.int32), layer,
         qt, k_data, v_data,
     ]
     scratch = [
@@ -290,7 +294,7 @@ def flash_prefill_kernel(
     kv_bytes_per_row = D * k_data.dtype.itemsize
     if quantized:
         in_specs += [hbm, hbm]
-        # Pool-native [N, Hkv, G, BS] grouped plane (see kv_cache.py).
+        # Pool-native [L, N, Hkv, G, BS] grouped plane (see kv_cache.py).
         inputs += [
             k_cache.scale.astype(jnp.float32),
             v_cache.scale.astype(jnp.float32),
@@ -304,11 +308,11 @@ def flash_prefill_kernel(
         kv_bytes_per_row += 4 * SG
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(P, Hkv, NT),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (1, 1, 1, Rp, D), lambda p, h, t, bt, sp, tl: (p, h, t, 0, 0)
+            (1, 1, 1, Rp, D), lambda p, h, t, *_: (p, h, t, 0, 0)
         ),
         scratch_shapes=scratch,
     )

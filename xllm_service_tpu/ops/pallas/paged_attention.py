@@ -25,9 +25,12 @@ Design (flash-decode, manual double-buffered DMA, chunked blocks):
     zero-padded to Gp = roundup(G, 8) sublanes to satisfy TPU tiling;
     scores are bf16-in/f32-accum on the MXU (the fast path).
 
-Cache layout matches ops/attention.py: k/v `[num_blocks, Hkv, BS, D]`;
-q `[R, Hq, D]`; block_table `[R, MB]` int32; seq_lens `[R]` int32 (context
-length INCLUDING the current token). Returns `[R, Hq, D]`.
+Cache operand: the STACKED pool k/v `[L, num_blocks, Hkv, BS, D]` in HBM
+plus the layer index in scalar memory (`stack_operands`; a 4-D per-layer
+cache is the L = 1 case) — block DMAs address `[layer, blk, h]`, so the
+serving steps never slice a layer out of the pool. q `[R, Hq, D]`;
+block_table `[R, MB]` int32; seq_lens `[R]` int32 (context length
+INCLUDING the current token). Returns `[R, Hq, D]`.
 """
 
 from __future__ import annotations
@@ -75,15 +78,35 @@ def dequant_tile(tile, s_buf, chunk, block_size, scale_groups):
     return (tile.astype(jnp.float32) * s_exp).astype(jnp.bfloat16)
 
 
+def stack_operands(k_cache, v_cache, layer):
+    """(k, v, layer[1]) as every GQA kernel takes them: the caches as
+    STACKED pools [L, N, Hkv, BS, D] (int8: plus [L, N, Hkv, G, BS] scale
+    planes) and the layer as an int32 scalar-prefetch operand. The layer
+    scans of models/llama.py carry the stack and hand it over whole, so
+    no layer is ever sliced out of it; a per-layer 4-D cache is the
+    L = 1 case (`cache[None]`, a bitcast, layer 0) of the same kernel."""
+    from xllm_service_tpu.ops import kv_cache as kvc
+
+    k_cache, v_cache = kvc.as_paged(k_cache), kvc.as_paged(v_cache)
+    if k_cache.data.ndim == 4:
+        k_cache, v_cache = (
+            kvc.PagedKV(*(a if a is None else a[None] for a in c))
+            for c in (k_cache, v_cache)
+        )
+        layer = 0
+    return k_cache, v_cache, jnp.asarray(layer, jnp.int32).reshape(1)
+
+
 def _decode_kernel(
     # scalar prefetch
     block_table_ref,  # [R, MBp] SMEM (padded to a multiple of C with 0s)
     seq_lens_ref,     # [R]      SMEM
+    layer_ref,        # [1]      SMEM — which layer of the stack to read
     # inputs
     q_ref,            # [1, 1, Gp, D] VMEM
-    k_hbm,            # [N, Hkv, BS, D] HBM (pl.ANY) — bf16 or int8
-    v_hbm,            # [N, Hkv, BS, D] HBM (pl.ANY)
-    *rest,            # quantized: ks_hbm, vs_hbm [N, Hkv, G, BS] f32, then
+    k_hbm,            # [L, N, Hkv, BS, D] HBM (pl.ANY) — bf16 or int8
+    v_hbm,            # [L, N, Hkv, BS, D] HBM (pl.ANY)
+    *rest,            # quantized: ks_hbm, vs_hbm [L, N, Hkv, G, BS] f32, then
     # output
     #   o_ref         # [1, 1, Gp, D] VMEM
     # scratch
@@ -106,6 +129,7 @@ def _decode_kernel(
         ks_hbm = vs_hbm = ks_buf = vs_buf = ssems = None
     r = pl.program_id(0)
     h = pl.program_id(1)
+    lyr = layer_ref[0]
     seq_len = seq_lens_ref[r]
     span = chunk * block_size
     # Sliding-window attention: the chunk walk starts at the first chunk
@@ -134,28 +158,28 @@ def _decode_kernel(
         off = c_idx * block_size
         out = [
             mosaic.async_copy(
-                    mosaic.checked_at(k_hbm, blk, h),
+                    mosaic.checked_at(k_hbm, lyr, blk, h),
                     mosaic.checked_at(k_buf, slot, pl.ds(off, block_size)),
                     sems.at[slot, 0, c_idx],
                 ),
             mosaic.async_copy(
-                    mosaic.checked_at(v_hbm, blk, h),
+                    mosaic.checked_at(v_hbm, lyr, blk, h),
                     mosaic.checked_at(v_buf, slot, pl.ds(off, block_size)),
                     sems.at[slot, 1, c_idx],
                 ),
         ]
         if quantized:
-            # Head h's [G, BS] scale tile (blk, h on untiled dims).
+            # Head h's [G, BS] scale tile (layer, blk, h on untiled dims).
             out.append(
                 mosaic.async_copy(
-                    mosaic.checked_at(ks_hbm, blk, h),
+                    mosaic.checked_at(ks_hbm, lyr, blk, h),
                     mosaic.checked_at(ks_buf, slot, c_idx),
                     ssems.at[slot, 0, c_idx],
                 )
             )
             out.append(
                 mosaic.async_copy(
-                    mosaic.checked_at(vs_hbm, blk, h),
+                    mosaic.checked_at(vs_hbm, lyr, blk, h),
                     mosaic.checked_at(vs_buf, slot, c_idx),
                     ssems.at[slot, 1, c_idx],
                 )
@@ -259,7 +283,7 @@ def _round_up(x: int, m: int) -> int:
 )
 def paged_attention_kernel(
     q: jnp.ndarray,            # [R, Hq, D]
-    k_cache,                   # [N, Hkv, BS, D] plain array or PagedKV
+    k_cache,                   # [(L,) N, Hkv, BS, D] plain or PagedKV
     v_cache,
     block_table: jnp.ndarray,  # [R, MB] int32
     seq_lens: jnp.ndarray,     # [R] int32
@@ -267,16 +291,14 @@ def paged_attention_kernel(
     interpret: bool = False,
     chunk: int = 4,
     window: int = 0,
+    layer=None,                # int32 scalar when the caches are stacks
 ) -> jnp.ndarray:
-    from xllm_service_tpu.ops import kv_cache as kvc
-
-    k_cache = kvc.as_paged(k_cache)
-    v_cache = kvc.as_paged(v_cache)
+    k_cache, v_cache, layer = stack_operands(k_cache, v_cache, layer)
     quantized = k_cache.quantized
     k_data, v_data = k_cache.data, v_cache.data
 
     R, Hq, D = q.shape
-    N, Hkv, BS, _ = k_data.shape
+    _, N, Hkv, BS, _ = k_data.shape
     MB = block_table.shape[1]
     G = Hq // Hkv
     Gp = _round_up(G, 8)
@@ -297,11 +319,11 @@ def paged_attention_kernel(
     # for D < 128 (lane-padded tiling); HBM DMA slices are contiguous.
     hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)
     in_specs = [
-        pl.BlockSpec((1, 1, Gp, D), lambda r, h, bt, sl: (r, h, 0, 0)),
+        pl.BlockSpec((1, 1, Gp, D), lambda r, h, *_: (r, h, 0, 0)),
         hbm,
         hbm,
     ]
-    inputs = [bt, seq_lens.astype(jnp.int32), qr, k_data, v_data]
+    inputs = [bt, seq_lens.astype(jnp.int32), layer, qr, k_data, v_data]
     scratch = [
         pltpu.VMEM((2, C * BS, D), k_data.dtype),
         pltpu.VMEM((2, C * BS, D), v_data.dtype),
@@ -311,7 +333,7 @@ def paged_attention_kernel(
     kv_bytes_per_row = D * k_data.dtype.itemsize
     if quantized:
         in_specs += [hbm, hbm]
-        # Pool-native [N, Hkv, G, BS] grouped plane (kv_cache.py) — no
+        # Pool-native [L, N, Hkv, G, BS] grouped plane (kv_cache.py) — no
         # per-call relayout, tile-legal on every tp shard.
         inputs += [
             k_cache.scale.astype(jnp.float32),
@@ -326,11 +348,11 @@ def paged_attention_kernel(
         kv_bytes_per_row += 4 * SG
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(R, Hkv),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (1, 1, Gp, D), lambda r, h, bt, sl: (r, h, 0, 0)
+            (1, 1, Gp, D), lambda r, h, *_: (r, h, 0, 0)
         ),
         scratch_shapes=scratch,
     )
@@ -364,7 +386,7 @@ def paged_attention_kernel(
 )
 def multiquery_paged_attention_kernel(
     q: jnp.ndarray,            # [R, S, Hq, D] — S consecutive query tokens
-    k_cache,                   # [N, Hkv, BS, D] plain array or PagedKV
+    k_cache,                   # [(L,) N, Hkv, BS, D] plain or PagedKV
     v_cache,
     block_table: jnp.ndarray,  # [R, MB] int32
     seq_lens: jnp.ndarray,     # [R] int32 — context INCLUDING the FIRST
@@ -373,21 +395,19 @@ def multiquery_paged_attention_kernel(
     interpret: bool = False,
     chunk: int = 4,
     window: int = 0,
+    layer=None,                # int32 scalar when the caches are stacks
 ) -> jnp.ndarray:
     """Speculative-verify attention: the decode kernel with S query rows
     per sequence. Same HBM traffic as one decode step (each KV row streams
     once), S times the MXU work — the shape speculative decoding wants.
     The S*G query heads of one KV head ride one [S*Gp, D] tile; causal
     masking within the step is by tile-row // Gp. Returns [R, S, Hq, D]."""
-    from xllm_service_tpu.ops import kv_cache as kvc
-
-    k_cache = kvc.as_paged(k_cache)
-    v_cache = kvc.as_paged(v_cache)
+    k_cache, v_cache, layer = stack_operands(k_cache, v_cache, layer)
     quantized = k_cache.quantized
     k_data, v_data = k_cache.data, v_cache.data
 
     R, S, Hq, D = q.shape
-    N, Hkv, BS, _ = k_data.shape
+    _, N, Hkv, BS, _ = k_data.shape
     MB = block_table.shape[1]
     G = Hq // Hkv
     Gp = _round_up(G, 8)
@@ -405,11 +425,11 @@ def multiquery_paged_attention_kernel(
 
     hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)
     in_specs = [
-        pl.BlockSpec((1, 1, S * Gp, D), lambda r, h, bt, sl: (r, h, 0, 0)),
+        pl.BlockSpec((1, 1, S * Gp, D), lambda r, h, *_: (r, h, 0, 0)),
         hbm,
         hbm,
     ]
-    inputs = [bt, seq_lens.astype(jnp.int32), qr, k_data, v_data]
+    inputs = [bt, seq_lens.astype(jnp.int32), layer, qr, k_data, v_data]
     scratch = [
         pltpu.VMEM((2, C * BS, D), k_data.dtype),
         pltpu.VMEM((2, C * BS, D), v_data.dtype),
@@ -419,7 +439,7 @@ def multiquery_paged_attention_kernel(
     kv_bytes_per_row = D * k_data.dtype.itemsize
     if quantized:
         in_specs += [hbm, hbm]
-        # Pool-native [N, Hkv, G, BS] grouped plane (kv_cache.py) — no
+        # Pool-native [L, N, Hkv, G, BS] grouped plane (kv_cache.py) — no
         # per-call relayout, tile-legal on every tp shard.
         inputs += [
             k_cache.scale.astype(jnp.float32),
@@ -434,11 +454,11 @@ def multiquery_paged_attention_kernel(
         kv_bytes_per_row += 4 * SG
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(R, Hkv),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (1, 1, S * Gp, D), lambda r, h, bt, sl: (r, h, 0, 0)
+            (1, 1, S * Gp, D), lambda r, h, *_: (r, h, 0, 0)
         ),
         scratch_shapes=scratch,
     )

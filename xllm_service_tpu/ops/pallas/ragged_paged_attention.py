@@ -53,7 +53,9 @@ with a RAGGED query-tile axis):
 
 Layouts: q [T, Hq_packed, D] (GQA head packing via the
 kernel_io_for/pack_queries contract happens in the ops.attention
-dispatcher), caches [N, Hkv, BS, D], block_tables [B, MB] int32,
+dispatcher), caches the stacked pool [L, N, Hkv, BS, D] plus a layer
+index in scalar memory (paged_attention.stack_operands; 4-D is the
+L = 1 case), block_tables [B, MB] int32,
 q_len/pos0 [B] int32. Returns [T, Hq, D]; dead rows emit zeros.
 Chip validation: scripts/validate_kernel_tpu.py ragged-* cases (opt-in
 XLLM_RAGGED_ATTENTION_KERNEL=1 until PARITY OK per the repo convention);
@@ -71,7 +73,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from xllm_service_tpu.ops.pallas import mosaic_rules as mosaic
-from xllm_service_tpu.ops.pallas.paged_attention import dequant_tile
+from xllm_service_tpu.ops.pallas.paged_attention import (
+    dequant_tile,
+    stack_operands,
+)
 
 NEG_INF = -1e30
 
@@ -84,11 +89,12 @@ def _ragged_kernel(
     q_len_ref,        # [B] SMEM — dynamic valid tokens per row
     pos0_ref,         # [B] SMEM — absolute position of first query token
     bt_ref,           # [B, MBp] SMEM block tables (padded to C multiple)
+    layer_ref,        # [1] SMEM — which layer of the stack to read
     # inputs
     q_ref,            # [1, 1, TQ*G, D] VMEM — one tile's query rows
-    k_hbm,            # [N, Hkv, BS, D] HBM
-    v_hbm,            # [N, Hkv, BS, D] HBM
-    *rest,            # quantized: ks_hbm, vs_hbm [N, Hkv, G, BS] f32; then
+    k_hbm,            # [L, N, Hkv, BS, D] HBM
+    v_hbm,            # [L, N, Hkv, BS, D] HBM
+    *rest,            # quantized: ks_hbm, vs_hbm [L, N, Hkv, G, BS] f32; then
     # o_ref + scratch (k_buf/v_buf [2, C*BS, D], sems; quantized adds
     # [2, C, G, BS] f32 scale bufs + ssems)
     block_size: int,
@@ -107,6 +113,7 @@ def _ragged_kernel(
         ks_hbm = vs_hbm = ks_buf = vs_buf = ssems = None
     t = pl.program_id(0)
     h = pl.program_id(1)
+    lyr = layer_ref[0]
     span = chunk * block_size
     tile_lo = t * tile_q  # first flattened token index of this tile
 
@@ -120,12 +127,12 @@ def _ragged_kernel(
         off = c_idx * block_size
         out = [
             mosaic.async_copy(
-                mosaic.checked_at(k_hbm, blk, h),
+                mosaic.checked_at(k_hbm, lyr, blk, h),
                 mosaic.checked_at(k_buf, slot, pl.ds(off, block_size)),
                 sems.at[slot, 0, c_idx],
             ),
             mosaic.async_copy(
-                mosaic.checked_at(v_hbm, blk, h),
+                mosaic.checked_at(v_hbm, lyr, blk, h),
                 mosaic.checked_at(v_buf, slot, pl.ds(off, block_size)),
                 sems.at[slot, 1, c_idx],
             ),
@@ -133,14 +140,14 @@ def _ragged_kernel(
         if quantized:
             out.append(
                 mosaic.async_copy(
-                    mosaic.checked_at(ks_hbm, blk, h),
+                    mosaic.checked_at(ks_hbm, lyr, blk, h),
                     mosaic.checked_at(ks_buf, slot, c_idx),
                     ssems.at[slot, 0, c_idx],
                 )
             )
             out.append(
                 mosaic.async_copy(
-                    mosaic.checked_at(vs_hbm, blk, h),
+                    mosaic.checked_at(vs_hbm, lyr, blk, h),
                     mosaic.checked_at(vs_buf, slot, c_idx),
                     ssems.at[slot, 1, c_idx],
                 )
@@ -292,7 +299,7 @@ def _tile_row_ranges(seg_lens, tile_q: int, n_tiles: int):
 )
 def ragged_paged_attention_kernel(
     q: jnp.ndarray,            # [T, Hq, D] — flattened ragged queries
-    k_cache,                   # [N, Hkv, BS, D] plain array or PagedKV
+    k_cache,                   # [(L,) N, Hkv, BS, D] plain or PagedKV
     v_cache,
     block_tables: jnp.ndarray,  # [B, MB] int32
     q_len: jnp.ndarray,        # [B] int32 (dynamic; <= seg_lens[b])
@@ -303,16 +310,14 @@ def ragged_paged_attention_kernel(
     chunk: int = 4,
     tile_q: int = 128,
     window: int = 0,
+    layer=None,                # int32 scalar when the caches are stacks
 ) -> jnp.ndarray:
-    from xllm_service_tpu.ops import kv_cache as kvc
-
-    k_cache = kvc.as_paged(k_cache)
-    v_cache = kvc.as_paged(v_cache)
+    k_cache, v_cache, layer = stack_operands(k_cache, v_cache, layer)
     quantized = k_cache.quantized
     k_data, v_data = k_cache.data, v_cache.data
 
     T, Hq, D = q.shape
-    N, Hkv, BS, _ = k_data.shape
+    _, N, Hkv, BS, _ = k_data.shape
     B, MB = block_tables.shape
     assert sum(seg_lens) == T and len(seg_lens) == B, (
         f"seg_lens {seg_lens} inconsistent with q [T={T}] / tables [B={B}]"
@@ -354,6 +359,7 @@ def ragged_paged_attention_kernel(
         q_len.astype(jnp.int32),
         pos0.astype(jnp.int32),
         bt,
+        layer,
         qt, k_data, v_data,
     ]
     scratch = [
@@ -365,7 +371,7 @@ def ragged_paged_attention_kernel(
     kv_bytes_per_row = D * k_data.dtype.itemsize
     if quantized:
         in_specs += [hbm, hbm]
-        # Pool-native [N, Hkv, G, BS] grouped plane (kv_cache.py) — no
+        # Pool-native [L, N, Hkv, G, BS] grouped plane (kv_cache.py) — no
         # per-call relayout, tile-legal on every tp shard.
         inputs += [
             k_cache.scale.astype(jnp.float32),
@@ -379,7 +385,7 @@ def ragged_paged_attention_kernel(
         kv_bytes_per_row += 4 * SG
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
+        num_scalar_prefetch=7,
         grid=(NT, Hkv),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, Rp, D), lambda t, h, *_: (h, t, 0, 0)),

@@ -10,7 +10,11 @@ TPU design points:
   * one compiled decode step for a FIXED batch of R slots — batch
     composition changes never recompile (SURVEY.md §7 hard part 3);
   * prefill lengths are bucketed; each bucket compiles once;
-  * KV caches are donated through every step (in-place update, no HBM copy);
+  * KV caches are donated through every step and ride the layer scan's
+    CARRY: the new rows are written into the stacked pool in place
+    (ops/kv_write.write_kv) and the attention kernels read it by layer
+    index, so no step program copies a layer of it (until PR 29 the scan
+    restacked both pools every step: 74 % of the chip's time, PERF.md);
   * sampling runs on-device inside the same jit — only R int32 tokens +
     R float32 logprobs cross back to the host per step;
   * params/caches carry NamedShardings from parallel/sharding.py; under
@@ -675,14 +679,16 @@ class ModelExecutor:
         n_params = approx_param_count(cfg)
         total_hbm = self._device_bytes_limit()
         tp = self.mesh.shape.get("tp", 1)
-        # Budget for 2x the pool (params count once): the step programs
-        # scan the layers with the caches as scanned inputs AND stacked
-        # outputs, and XLA gives the stacked outputs a buffer of their own
-        # (an AllocateBuffer the size of each cache among the HLO temps)
-        # before the donated arguments are reused. On a v5e (15.75 GiB)
-        # llama3-3b gets 299 blocks this way and runs; built with 400 the
-        # chip's compiler refuses the decode step at 17.23G, with 512 at
-        # 20.50G (PERF.md, PR 26).
+        # Budget for 2x the pool (params count once). Until PR 29 the step
+        # programs held the pool twice (the layer scan stacked its cache
+        # outputs into a buffer of their own: llama3-3b ran with 299
+        # blocks on a v5e's 15.75 GiB and was refused at 400, "17.23G",
+        # PERF.md PR 26). Since PR 29 the caches ride the scan's carry and
+        # the step programs' temporaries are under one layer of one pool
+        # (qwen2.5-3b decode: 4.44 GiB -> 0.3 MiB, compiled for a v5e), so
+        # the second half of this budget is free HBM. The halving stays
+        # for now: un-halving doubles the pool and changes what a cell
+        # serves, so it is its own, separately measured PR (ROADMAP A4b).
         budget = (
             total_hbm * self.engine_cfg.hbm_utilization
             - n_params * param_bytes / tp
