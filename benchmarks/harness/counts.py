@@ -1,6 +1,10 @@
 """Bytes and FLOPs a call NEEDS, computed from shapes, and the table of
-device peaks. Nothing here is read from the compiler (XLA's "bytes
-accessed" counts what its schedule touches, not what the algorithm
+device peaks. The counts are the Llama family's (benchmarks/families/
+llama.py: GQA, SwiGLU, a K and a V cache) and read its configuration
+keys; a later family brings its operations and bytes in a file of its own
+beside the per-layer metrics that read them, and takes only PEAKS, peaks
+and hbm_time_s from here. Nothing here is read from the compiler (XLA's
+"bytes accessed" counts what its schedule touches, not what the algorithm
 needs) or from the program.
 
 All counts are lower bounds on what the chip reads: KV blocks are padded

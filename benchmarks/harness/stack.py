@@ -3,10 +3,12 @@ InstanceServer over real sockets around an InferenceEngine (after
 chip_smoke.Stack; copied, not imported, because later PRs may change the
 smoke).
 
-This file is the benchmark's only dependence on the program's internals:
-  * `model_config` maps the configuration file's HF keys to ModelConfig;
-  * `install_weights` puts the benchmark's own weights (reference.py) in
-    the place of the executor's random-init ones, leaf for leaf;
+The benchmark depends on the program's internals here and in the family
+files (benchmarks/families/: each maps its configuration's keys to
+ModelConfig, its one import from the program):
+  * `install_weights` puts the benchmark's own weights (the family's
+    make_weights) in the place of the executor's random-init ones, leaf
+    for leaf: the guard between a family file and the program's tree;
   * the tap wraps `engine.add_request` and each request's callback,
     read-only, for engine-side timestamps and the served token ids.
 Everything else goes through HTTP: /v1/completions on the master and
@@ -20,33 +22,11 @@ import json
 import time
 from typing import Dict, List, Mapping, Optional
 
-from benchmarks.harness import reference
+from benchmarks.harness.family import seed_key
 from benchmarks.harness.loadgen import request_key  # imports no JAX
 
 class StackError(RuntimeError):
     pass
-
-
-def model_config(name: str, m: Mapping):
-    from xllm_service_tpu.models.configs import ModelConfig
-
-    if m.get("sliding_window") and m.get("use_sliding_window", True):
-        raise StackError("sliding-window configurations are not wired here")
-    return ModelConfig(
-        name=name,
-        vocab_size=m["vocab_size"],
-        hidden_size=m["hidden_size"],
-        intermediate_size=m["intermediate_size"],
-        num_layers=m["num_hidden_layers"],
-        num_heads=m["num_attention_heads"],
-        num_kv_heads=m["num_key_value_heads"],
-        head_dim=reference.head_dim(m),
-        rope_theta=float(m["rope_theta"]),
-        rms_norm_eps=float(m["rms_norm_eps"]),
-        max_position_embeddings=m["max_position_embeddings"],
-        tie_word_embeddings=bool(m.get("tie_word_embeddings", False)),
-        attn_bias=bool(m.get("attention_bias", False)),
-    )
 
 
 def engine_config(name: str, engine: Mapping, cache_dir: str):
@@ -64,23 +44,24 @@ def engine_config(name: str, engine: Mapping, cache_dir: str):
     return EngineConfig(**kw)
 
 
-def place_weights(executor, m: Mapping, seed: int, shardings) -> float:
-    """The benchmark's weights, made on the device in one jitted call with
-    the given shardings, put in the executor's place for parameters."""
+def place_weights(executor, family, m: Mapping, seed: int, shardings) -> float:
+    """The benchmark's weights (the family's make_weights), made on the
+    device in one jitted call with the given shardings, put in the
+    executor's place for parameters."""
     import jax
 
     t0 = time.monotonic()
     make = jax.jit(
-        lambda k: reference.make_weights(m, k, executor.dtype),
+        lambda k: family.make_weights(m, k, executor.dtype),
         out_shardings=shardings,
     )
     with executor.mesh:
-        executor.params = make(reference.seed_key(seed))
+        executor.params = make(seed_key(seed))
     jax.block_until_ready(executor.params)
     return time.monotonic() - t0
 
 
-def install_weights(executor, m: Mapping, seed: int) -> float:
+def install_weights(executor, family, m: Mapping, seed: int) -> float:
     """Replace the executor's random-init parameters by the benchmark's,
     leaf for leaf, with the executor's own shardings. The old leaves are
     freed first: two copies do not fit beside the pool."""
@@ -90,18 +71,17 @@ def install_weights(executor, m: Mapping, seed: int) -> float:
     shardings = jax.tree.map(lambda a: a.sharding, old)
     want = jax.tree.map(lambda a: (tuple(a.shape), str(a.dtype)), old)
     made = jax.eval_shape(
-        lambda k: reference.make_weights(m, k, executor.dtype),
-        reference.seed_key(seed),
+        lambda k: family.make_weights(m, k, executor.dtype), seed_key(seed)
     )
     have = jax.tree.map(lambda a: (tuple(a.shape), str(a.dtype)), made)
     if have != want:
         raise StackError(
-            "the executor's parameter tree is not the reference's: "
-            f"executor {want} reference {have}"
+            f"the executor's parameter tree is not {family.__name__}'s: "
+            f"executor {want} family {have}"
         )
     for leaf in jax.tree.leaves(old):
         leaf.delete()
-    return place_weights(executor, m, seed, shardings)
+    return place_weights(executor, family, m, seed, shardings)
 
 
 def parse_metrics(text: str) -> Dict[str, float]:
@@ -152,8 +132,10 @@ def http_post(addr: str, path: str, body: dict, timeout: float = 600.0):
 
 
 class Stack:
-    def __init__(self, name: str, model: Mapping, engine: Mapping,
-                 seed: int, cache_dir: str):
+    def __init__(self, name: str, family, config: Mapping, seed: int, cache_dir: str,
+                 engine: Optional[Mapping] = None):
+        """`config` is the configuration file as parsed; `engine` replaces
+        its "engine" group (a control that switches a lower precision on)."""
         from xllm_service_tpu.api import Master
         from xllm_service_tpu.api.instance import InstanceServer
         from xllm_service_tpu.common.config import ServiceConfig
@@ -161,8 +143,8 @@ class Stack:
         from xllm_service_tpu.runtime.engine import InferenceEngine
         from xllm_service_tpu.runtime.executor import ModelExecutor
 
-        self.name, self.model = name, dict(model)
-        ecfg = engine_config(name, engine, cache_dir)
+        self.name, self.family, self.config = name, family, config
+        ecfg = engine_config(name, config["engine"] if engine is None else engine, cache_dir)
         self.engine_cfg = ecfg
         self.store = MemoryStore()
         self.master = Master(
@@ -176,10 +158,10 @@ class Stack:
         self.master.start()
         t0 = time.monotonic()
         self.executor = ModelExecutor(
-            ecfg, model_cfg=model_config(name, model), init_seed=0
+            ecfg, model_cfg=family.model_config(name, config), init_seed=0
         )
         self.build_s = time.monotonic() - t0
-        self.weights_s = install_weights(self.executor, model, seed)
+        self.weights_s = install_weights(self.executor, family, config, seed)
         engine_obj = InferenceEngine(ecfg, executor=self.executor)
         self.taps: Dict[str, dict] = {}
         add = engine_obj.add_request

@@ -4,10 +4,12 @@
     python3 benchmarks/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Resolves --workload to benchmarks/cells/<cell>.json, that to its
-configuration (configs/) and traffic mix (traffic/), and the metric names
-of BENCHMARK.json to end_to_end/<name>.py and layer_metrics/<name>.py: all
-by name, so a later PR adds cells, mixes, configurations and metrics as
-new files plus entries in BENCHMARK.json and edits nothing here.
+configuration (configs/) and traffic mix (traffic/), the configuration's
+"family" to families/<family>.py (key mapping, weights, plain reference,
+limits), and the metric names of BENCHMARK.json to end_to_end/<name>.py
+and layer_metrics/<name>.py: all by name, so a later PR adds cells, mixes,
+configurations, families and metrics as new files plus entries in
+BENCHMARK.json and edits nothing here.
 
 One run: set-up (build master + instance + engine in this process; the
 benchmark's weights made on the device from --seed; the correctness
@@ -88,6 +90,15 @@ class Window:
 
     def __init__(self, **kw):
         self.__dict__.update(kw)
+
+    @property
+    def model(self) -> dict:
+        """The configuration as parsed: what a family's counts take."""
+        return self.config
+
+    @property
+    def engine(self) -> dict:
+        return self.config["engine"]
 
     def measured(self) -> list:
         return [r for r in self.records if r.get("measured")]
@@ -247,7 +258,6 @@ def main() -> int:
         cell["rate_per_s"] = args.rate_per_s
     config = load_json("configs", cell["config"] + ".json")
     traffic = load_json("traffic", cell["traffic"] + ".json")
-    model = {k: v for k, v in config.items() if not isinstance(v, (dict, list))}
     chips = int(cell["chips"])
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         seconds = float(args.seconds if args.seconds is not None else json.load(f)["run_seconds"])
@@ -255,6 +265,12 @@ def main() -> int:
 
     if args.rehearse:
         os.environ["JAX_PLATFORMS"] = "cpu"
+    from benchmarks.harness import family as family_mod
+
+    try:  # before any device or engine: a missing name costs no set-up
+        family = family_mod.load(config)
+    except family_mod.FamilyError as e:
+        raise SystemExit(f"run.py: {e}") from None
     threading.Thread(
         target=lambda: (time.sleep(HARD_EXIT_S), os._exit(124)), daemon=True
     ).start()
@@ -274,27 +290,27 @@ def main() -> int:
     )
     log(f"compile cache: {cache_dir}")
 
-    stack = stack_mod.Stack(cell["config"], model, config["engine"], args.seed, cache_dir)
+    stack = stack_mod.Stack(cell["config"], family, config, args.seed, cache_dir)
     try:
         log(f"built: executor {stack.build_s:.2f}s, weights {stack.weights_s:.2f}s, "
             f"num_blocks={stack.executor.num_blocks} kernels={json.dumps(stack.kernel_report())}")
         t0 = time.monotonic()
         warm_threads = stack_mod.warm_shapes(
             stack, traffic.get("warm_shapes") or {"background_prompts": []},
-            model["vocab_size"], args.seed,
+            config["vocab_size"], args.seed,
         )
         log(f"warm shapes: {time.monotonic() - t0:.2f}s, {stack.lowerings()} step programs")
-        check = check_mod.check_correct(stack, model, args.seed)
+        check = check_mod.check_correct(stack, args.seed)
         log("correct: " + json.dumps(check))
         t0 = time.monotonic()
         warm_threads += stack_mod.start_bridge(
-            stack, traffic.get("warm_shapes") or {}, model["vocab_size"], args.seed
+            stack, traffic.get("warm_shapes") or {}, config["vocab_size"], args.seed
         )
         log(f"bridge: decoding after {time.monotonic() - t0:.2f}s, "
             f"{stack.lowerings()} step programs")
         job = {
             "addr": stack.master_addr, "model": cell["config"], "traffic": traffic,
-            "cell": cell, "seed": args.seed, "seconds": seconds, "vocab": model["vocab_size"],
+            "cell": cell, "seed": args.seed, "seconds": seconds, "vocab": config["vocab_size"],
             "t_zero": time.monotonic() + float(traffic.get("warmup_seconds", 0.0)) + 1.0,
         }
         trace_dir = os.path.join(ROOT, ".bench-trace") if args.trace else ""
@@ -313,8 +329,7 @@ def main() -> int:
         with open(args.dump_records, "w") as f:
             json.dump({"records": records, "seconds": seconds, "cell": cell}, f)
     w = Window(
-        cell=cell, config=config, model=model, engine=config["engine"],
-        traffic=traffic, seconds=seconds, records=records, taps=taps,
+        cell=cell, config=config, traffic=traffic, seconds=seconds, records=records, taps=taps,
         t_zero=job["t_zero"], counters_start=got["counters_start"],
         counters_end=got["counters_end"], trace=trace, trace_span=got["trace_span"],
         setup_s=got["setup_s"], device_kind=dev.device_kind, chips=chips, counts=counts,

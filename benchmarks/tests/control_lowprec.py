@@ -34,11 +34,11 @@ def run(config_name: str, mode: str, seeds, rehearse: bool, dtype: str = "") -> 
 
     if rehearse:  # as run.py --rehearse does, and for its reason
         jax.config.update("jax_cpu_enable_async_dispatch", False)
-    from benchmarks.harness import check, stack as stack_mod
+    from benchmarks.harness import check, family as family_mod, stack as stack_mod
 
     with open(os.path.join(ROOT, "benchmarks", "configs", config_name + ".json")) as f:
         config = json.load(f)
-    model = {k: v for k, v in config.items() if not isinstance(v, (dict, list))}
+    family = family_mod.load(config)
     engine = dict(config["engine"])
     if dtype:
         engine["dtype"] = dtype
@@ -48,7 +48,7 @@ def run(config_name: str, mode: str, seeds, rehearse: bool, dtype: str = "") -> 
     dev = jax.devices()[0]
     if dev.platform == "cpu" and not rehearse:
         raise SystemExit("control_lowprec: no accelerator (use --rehearse on the CPU)")
-    stack = stack_mod.Stack(config_name, model, engine, seeds[0], cache_dir)
+    stack = stack_mod.Stack(config_name, family, config, seeds[0], cache_dir, engine=engine)
     out = []
     try:
         ex = stack.executor
@@ -57,17 +57,17 @@ def run(config_name: str, mode: str, seeds, rehearse: bool, dtype: str = "") -> 
         def fresh_weights(seed):
             for leaf in jax.tree.leaves(ex.params):
                 leaf.delete()
-            stack_mod.place_weights(ex, model, seed, shardings)
+            stack_mod.place_weights(ex, family, config, seed, shardings)
 
         for i, seed in enumerate(seeds):
             if i or mode == "w-int8":
                 fresh_weights(seed)
             if mode == "w-int8":
                 ex._quantize_weights(shardings, bits=8)
-            samples = check.serve_sample(stack, model, seed)
+            samples = check.serve_sample(stack, seed)
             if mode == "w-int8":  # the reference reads the unquantized weights
                 fresh_weights(seed)
-            res = check.judge(stack, model, samples)
+            res = check.judge(stack, samples)
             res.update(seed=seed, mode=mode, platform=dev.platform, kind=dev.device_kind)
             print(json.dumps(res), flush=True)
             out.append(res)

@@ -46,6 +46,7 @@ def test_every_name_resolves_to_a_file():
             data = json.load(f)
         assert data["source"] == c["source"] and len(c["source"]) <= 200
         assert data["reduced"] == c["reduced"] and "assumed" in data and "deployment" in data
+        assert os.path.exists(os.path.join(BENCH, "families", data["family"] + ".py"))
         assert any(w["config"] == c["name"] for w in m["workloads"])
     for w in m["workloads"]:
         assert w["chips"] in (1, 4) and len(w["why"]) <= 200 and "\n" not in w["why"]

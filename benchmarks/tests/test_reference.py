@@ -1,10 +1,14 @@
-"""reference.forward_logits against a hand-written two-layer case in
-float64 numpy with explicit loops (no einsum, no vectorised attention)."""
+"""The Llama family's forward_logits (benchmarks/families/llama.py) against
+a hand-written two-layer case in float64 numpy with explicit loops (no
+einsum, no vectorised attention); and check.compare."""
 import math
 
 import numpy as np
 
-from benchmarks.harness import reference
+from benchmarks.harness import check
+from benchmarks.harness.family import load, seed_key
+
+llama = load({"name": "test", "family": "llama"})
 
 M = {
     "hidden_size": 8, "intermediate_size": 12, "num_hidden_layers": 2,
@@ -65,12 +69,12 @@ def test_forward_matches_the_hand_written_case():
     import jax
     import jax.numpy as jnp
 
-    w = jax.jit(lambda k: reference.make_weights(M, k, jnp.float32))(reference.seed_key(2**31 + 9))
+    w = jax.jit(lambda k: llama.make_weights(M, k, jnp.float32))(seed_key(2**31 + 9))
     wn = jax.tree.map(np.asarray, w)
     assert wn["layers"]["bq"].std() > 0.01 and abs(wn["layers"]["attn_norm"].mean() - 1) < 0.2
     tokens = np.array([3, 7, 1, 10, 4, 0, 0, 0], np.int32)  # 5 real + padding
     idx = np.arange(5, dtype=np.int32)
-    got = np.asarray(reference.forward_logits(w, M, jnp.asarray(tokens), jnp.asarray(idx)))
+    got = np.asarray(llama.forward_logits(w, M, jnp.asarray(tokens), jnp.asarray(idx)))
     want = by_hand(wn, M, tokens[:5])
     assert got.shape == (5, 11)
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
@@ -81,9 +85,9 @@ def test_weights_are_a_function_of_the_seed_and_untied_heads_exist():
     import jax.numpy as jnp
 
     m = dict(M, tie_word_embeddings=False, attention_bias=False)
-    a = reference.make_weights(m, reference.seed_key(5), jnp.bfloat16)
-    b = reference.make_weights(m, reference.seed_key(5), jnp.bfloat16)
-    c = reference.make_weights(m, reference.seed_key(6), jnp.bfloat16)
+    a = llama.make_weights(m, seed_key(5), jnp.bfloat16)
+    b = llama.make_weights(m, seed_key(5), jnp.bfloat16)
+    c = llama.make_weights(m, seed_key(6), jnp.bfloat16)
     assert "lm_head" in a and "bq" not in a["layers"]
     assert a["layers"]["wq"].dtype == jnp.bfloat16 and a["final_norm"].dtype == jnp.float32
     assert bool((a["embed"] == b["embed"]).all()) and not bool((a["embed"] == c["embed"]).all())
@@ -95,14 +99,14 @@ def test_compare_separates_sound_from_unsound():
     ids = logits.argmax(-1)
     lp = logits - np.logaddexp.reduce(logits.astype(np.float64), axis=-1, keepdims=True)
     exact = [float(lp[i, t]) for i, t in enumerate(ids)]
-    ok = reference.compare([{"served_ids": ids, "served_logprobs": exact}], [logits])
+    ok = check.compare([{"served_ids": ids, "served_logprobs": exact}], [logits], llama.LIMITS)
     assert ok["ok"] and ok["logprob_mse"] < 1e-12 and ok["argmax_exact"] == 16
     off = [x + 0.2 for x in exact]
-    assert not reference.compare([{"served_ids": ids, "served_logprobs": off}], [logits])["ok"]
+    assert not check.compare([{"served_ids": ids, "served_logprobs": off}], [logits], llama.LIMITS)["ok"]
     wrong = ids.copy()
     wrong[0] = int(logits[0].argmin())
-    bad = reference.compare(
+    bad = check.compare(
         [{"served_ids": wrong, "served_logprobs": [float(lp[i, t]) for i, t in enumerate(wrong)]}],
-        [logits])
+        [logits], llama.LIMITS)
     assert not bad["ok"] and bad["deficit_max"] > 1.0
-    assert not reference.compare([{"served_ids": ids[:3], "served_logprobs": exact}], [logits])["ok"]
+    assert not check.compare([{"served_ids": ids[:3], "served_logprobs": exact}], [logits], llama.LIMITS)["ok"]
