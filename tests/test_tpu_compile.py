@@ -88,8 +88,6 @@ def _assert_pool_still(fn, args, cache, shards=1):
     On a tp mesh the compiled module is one shard's: the pool holds
     1/`shards` of the heads there (a gathered pool would have the whole
     shape, so that is looked for too). Returns the HLO text."""
-    import re
-
     compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(*args).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "kv_write_kernel" in text
@@ -99,6 +97,17 @@ def _assert_pool_still(fn, args, cache, shards=1):
     shapes = {
         ",".join(map(str, s)) for s in (whole, whole[1:], stack, stack[1:])
     }
+    _assert_nothing_moves(text, shapes)
+    layer_bytes = data.dtype.itemsize * NB * (HKV // shards) * BS * D
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+    return text
+
+
+def _assert_nothing_moves(text, shapes):
+    """No op of the compiled program has a result of one of `shapes`
+    (comma-joined dims) but the loop's own plumbing and the kernels."""
+    import re
+
     plumbing = {
         "parameter", "get-tuple-element", "tuple", "bitcast", "while",
         "custom-call",
@@ -110,9 +119,6 @@ def _assert_pool_still(fn, args, cache, shards=1):
         if dims and dims.group(1) in shapes and m.group(2) not in plumbing:
             moved.append(line.strip()[:160])
     assert not moved, "\n".join(moved)
-    layer_bytes = data.dtype.itemsize * NB * (HKV // shards) * BS * D
-    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
-    return text
 
 
 def _kernel_shapes(one_chip):
@@ -346,3 +352,52 @@ def test_chip_smoke_refuses_cpu():
     assert '"ok": true' not in proc.stdout
     assert "no TPU" in proc.stderr
     assert "building" not in proc.stdout
+
+
+# ---- the power-retention family (models/brumby.py), brumby-14b's widths
+
+
+def _brumby_case(one_chip, step, layers=2, slots=16):
+    from xllm_service_tpu.models import brumby
+
+    cfg = dataclasses.replace(get_model_config("brumby-14b"), num_layers=layers)
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        jax.eval_shape(
+            lambda: brumby.init_params(cfg, jax.random.key(0), jnp.bfloat16)
+        ),
+    )
+
+    def s(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    S, z = (s(sh, jnp.float32) for sh in brumby.state_shapes(cfg, slots))
+    dec = (s((slots,)), s((slots,)), s((slots, 1)), s((slots,), jnp.bool_))
+    pf = (s((1, 256)), s((1,)), s((1,)), s((1, 1)))
+    fn, rest = {
+        "decode": (brumby.decode_step, dec),
+        "mixed": (brumby.mixed_step, dec + pf),
+    }[step]
+    return (lambda p, S, z, *a: fn(p, cfg, S, z, *a)), (params, S, z) + rest, S
+
+
+@pytest.mark.parametrize("step", ["decode", "mixed"])
+def test_brumby_step_compiles_and_keeps_the_state_pool_still(
+    one_chip, no_persistent_cache, as_on_tpu, step
+):
+    """The decode and mixed programs of the state-pool family at
+    brumby-14b's widths (2 layers, 16 slots): Mosaic takes both retention
+    kernels, the pool goes in and out through them alone (nothing else
+    has a pool- or layer-shaped result), and the temporaries are under
+    one layer of the pool."""
+    fn, args, S = _brumby_case(one_chip, step)
+    compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "retention_update_kernel" in text
+    assert ("retention_chunk_kernel" in text) == (step == "mixed")
+    whole = tuple(S.shape)
+    _assert_nothing_moves(text, {",".join(map(str, sh)) for sh in (whole, whole[1:])})
+    layer_bytes = 4
+    for n in whole[1:]:
+        layer_bytes *= n
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes

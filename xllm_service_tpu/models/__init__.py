@@ -13,8 +13,13 @@ from xllm_service_tpu.models.configs import ModelConfig
 
 def get_module(cfg: ModelConfig):
     """The model-family module for a config: MLA configs (kv_lora_rank > 0)
-    run models/deepseek.py; everything else (Llama/Qwen2/Mixtral-style
-    GQA + optional MoE) runs models/llama.py."""
+    run models/deepseek.py; power-retention configs (retention_degree > 0)
+    models/brumby.py; everything else (Llama/Qwen2/Mixtral-style GQA +
+    optional MoE) runs models/llama.py."""
+    if cfg.is_retention:
+        from xllm_service_tpu.models import brumby
+
+        return brumby
     if cfg.is_mla:
         from xllm_service_tpu.models import deepseek
 
@@ -25,12 +30,14 @@ def get_module(cfg: ModelConfig):
 
 
 def cache_row_dims(cfg: ModelConfig):
-    """(head_axis, row_dim) of one paged-cache row — delegated to the
-    family module, the single source of truth for its cache layout."""
+    """(head_axis, row_dim) of one paged-cache row (of one state row for
+    a retention family) — delegated to the family module, the single
+    source of truth for its cache layout."""
     return get_module(cfg).cache_row_dims(cfg)
 
 
 def num_caches(cfg: ModelConfig) -> int:
-    """Paged-cache array count: 2 (K + V) for GQA; 1 (latent) for MLA —
-    delegated to the family module."""
+    """Cache array count: 2 (K + V) for GQA; 1 (latent) for MLA; 2 (the
+    state and its normaliser) for retention — delegated to the family
+    module."""
     return get_module(cfg).NUM_CACHES

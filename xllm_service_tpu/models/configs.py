@@ -101,6 +101,15 @@ class ModelConfig:
     # prefix stack and the MoE `layers` suffix stack; each runs its own
     # lax.scan (models/deepseek.py _scan_stack).
     first_k_dense_replace: int = 0
+    # Power retention (arXiv:2507.04239; models/brumby.py): 0 = softmax
+    # attention over a paged cache; p > 0 replaces it by retention of
+    # degree p, whose sequence state is ONE fixed slot of the executor's
+    # state pool (ops/retention.py), not blocks that grow with the context.
+    retention_degree: int = 0
+
+    @property
+    def is_retention(self) -> bool:
+        return self.retention_degree > 0
 
     @property
     def is_moe(self) -> bool:
@@ -440,6 +449,26 @@ register(
 )
 
 register(
+    # Power retention at test scale (models/brumby.py): GQA group of 2,
+    # head_dim 16 (9 feature rows of 16 lanes: one kernel tile).
+    ModelConfig(
+        name="brumby-tiny",
+        vocab_size=512,
+        hidden_size=64,
+        intermediate_size=128,
+        num_layers=2,
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=16,
+        rope_theta=10000.0,
+        rms_norm_eps=1e-6,
+        qk_norm=True,
+        retention_degree=2,
+        max_position_embeddings=4096,
+    )
+)
+
+register(
     ModelConfig(
         name="deepseek-tiny",
         vocab_size=512,
@@ -562,5 +591,27 @@ register(
         rope_beta_slow=1.0,
         rope_mscale=1.0,
         rope_mscale_all_dim=1.0,
+    )
+)
+
+register(
+    # https://huggingface.co/manifestai/Brumby-14B-Base/blob/main/config.json:
+    # Qwen3-14B's block with power-retention layers (degree 2 assumed, as
+    # the retention keys are not in the published file). Random weights
+    # only: runtime/weights.py has no loader for its checkpoint.
+    ModelConfig(
+        name="brumby-14b",
+        vocab_size=151936,
+        hidden_size=5120,
+        intermediate_size=17408,
+        num_layers=40,
+        num_heads=40,
+        num_kv_heads=8,
+        head_dim=128,
+        rope_theta=1000000.0,
+        rms_norm_eps=1e-6,
+        qk_norm=True,
+        retention_degree=2,
+        max_position_embeddings=32768,
     )
 )

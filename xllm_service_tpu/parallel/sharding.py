@@ -92,6 +92,12 @@ def param_shardings(
                     "k_head_norm": ns(None, None),
                 }
             )
+    if cfg.is_retention:
+        # The retention gate is one column a KV head: replicated (the
+        # family is served at tp_size 1 only, runtime/executor.py).
+        layers.update(
+            {"w_ret_gate": ns(None, None, None), "b_ret_gate": ns(None, None)}
+        )
     if cfg.is_moe:
         ep = ep_axis if ep_axis is not None and ep_axis in mesh.shape else None
         e, t = (ep, tp) if ep is not None else (tp, None)

@@ -27,6 +27,12 @@ class OutOfBlocksError(RuntimeError):
     pass
 
 
+class StateFamilyUnsupported(NotImplementedError):
+    """A feature that is not built for a family whose sequence state is a
+    recurrent state slot (power retention, models/brumby.py). Raised by
+    name at engine build or at the request, never served wrongly."""
+
+
 @dataclass
 class _BlockInfo:
     ref_count: int = 0
@@ -252,3 +258,52 @@ class BlockManager:
             self._removed = set()
             self._offloaded = {}
         return ev
+
+
+class StateSlotManager(BlockManager):
+    """Slot ownership for a family whose sequence state is ONE fixed slot
+    of the executor's state pool (ops/retention.py), whatever the
+    context's length. The engine gives such a family blocks as long as
+    `max_seq_len`, so its block arithmetic asks for exactly one block a
+    sequence: that block is the slot (slot = block id - 1; id 0 stays
+    the dead row's). A sequence owns it from admission to finish, cancel
+    or preemption; admission therefore counts slots, not tokens, and a
+    preempted sequence resumes by recomputing its tokens from position 0,
+    which also makes a freed slot clean (a chunk at position 0 ignores
+    what the slot held).
+
+    A state is not addressable by block hash, so the content-addressed
+    half of the interface is inert: nothing is committed, matched or told
+    to the fabric (the engine's build refuses the prefix cache's tiers by
+    name; reusing a prefix needs state snapshots at chunk boundaries,
+    which are not built). Inert and not refused, because the one block is
+    FULL at exactly `max_seq_len` tokens and the engine thread's drains
+    would commit it on the way to finishing the sequence with LENGTH."""
+
+    def __init__(self, slots: int, block_size: int, seed: int = 1024):
+        super().__init__(slots + 1, block_size, seed=seed)
+
+    @property
+    def num_slots(self) -> int:
+        return self.num_blocks - 1
+
+    @property
+    def slots_in_use(self) -> int:
+        return self.num_slots - len(self._free)
+
+    def allocate(self, n: int) -> List[int]:
+        if n != 1:
+            raise StateFamilyUnsupported(
+                f"state pool: a sequence owns exactly one slot, {n} asked "
+                f"(a block is as long as max_seq_len={self.block_size})"
+            )
+        return super().allocate(1)
+
+    def commit_block(self, block_id: int, block_hash: bytes) -> None:
+        pass
+
+    def match_prefix(self, token_ids, hashes=None):
+        return 0, []
+
+    def lookup_hash(self, block_hash: bytes):
+        return None

@@ -52,7 +52,11 @@ from xllm_service_tpu.obs import (
     MetricsRegistry,
 )
 from xllm_service_tpu.ops.sampling import SamplingParams
-from xllm_service_tpu.runtime.block_manager import BlockManager, OutOfBlocksError
+from xllm_service_tpu.runtime.block_manager import (
+    BlockManager,
+    OutOfBlocksError,
+    StateSlotManager,
+)
 from xllm_service_tpu.runtime import compile_cache as compile_cache_mod
 from xllm_service_tpu.runtime.executor import ModelExecutor, SamplingBatch
 
@@ -334,10 +338,26 @@ class InferenceEngine:
         self.max_blocks = self.executor.max_blocks_per_seq
         from xllm_service_tpu.runtime.native_blocks import create_block_manager
 
-        self.block_mgr = create_block_manager(
-            self.executor.num_blocks, self.block_size,
-            seed=engine_cfg.murmur_hash3_seed,
-        )
+        # A state-pool family (power retention, models/brumby.py): the
+        # executor's block is as long as max_seq_len, so the block
+        # arithmetic below gives every sequence exactly ONE block, its
+        # state slot, for its life. The block is full only at exactly
+        # max_seq_len tokens, on the way to LENGTH, and nothing is ever
+        # hashed, committed or matched (_commit_full_blocks returns early;
+        # block_manager.StateSlotManager's content-addressed half is
+        # inert). Preemption frees the slot; the resumed sequence
+        # recomputes its tokens from position 0.
+        self.state_family = bool(getattr(self.executor, "is_state", False))
+        self.state_recomputes = 0
+        if self.state_family:
+            self.block_mgr = StateSlotManager(
+                self.R, self.block_size, seed=engine_cfg.murmur_hash3_seed
+            )
+        else:
+            self.block_mgr = create_block_manager(
+                self.executor.num_blocks, self.block_size,
+                seed=engine_cfg.murmur_hash3_seed,
+            )
         # Host (DRAM) cache tier: committed blocks evicted from HBM are
         # copied to host memory and re-imported on a later prefix match
         # (num_host_blocks=0 disables — reference tier contract proto:47).
@@ -628,6 +648,27 @@ class InferenceEngine:
             "xllm_engine_decode_steps_total", "Decode (or verify) steps "
             "executed",
         )
+        # State-pool families (power retention): the pool and who holds
+        # it. Registered for every engine; zero where there is no pool.
+        self._m_state_in_use = self.metrics.histogram(
+            "xllm_engine_state_slots_in_use",
+            "State slots owned by a sequence (decoding or mid-prefill), "
+            "observed every step", buckets=BATCH_BUCKETS,
+        )
+        self.metrics.gauge(
+            "xllm_engine_state_slots", "Slots of the state pool",
+        ).set_function(lambda: self.R if self.state_family else 0)
+        self.metrics.gauge(
+            "xllm_engine_state_pool_bytes", "Device bytes of the state pool",
+        ).set_function(
+            lambda: getattr(self.executor, "state_pool_bytes", 0)
+            if self.state_family else 0
+        )
+        self.metrics.counter(
+            "xllm_engine_state_recomputes_total",
+            "Preempted sequences of a state-pool family resumed by "
+            "recomputing their tokens from position 0",
+        ).set_function(lambda: self.state_recomputes)
         # Overlapped-pipeline instruments (docs/ENGINE_PIPELINE.md): the
         # host gap is the wall time between finishing one step's host
         # bookkeeping and dispatching the next decode step — the window the
@@ -880,7 +921,18 @@ class InferenceEngine:
 
     # -------------------------------------------------------------- public
 
+    def _observe_batch(self, nactive: int) -> None:
+        self._m_batch.observe(nactive)
+        if self.state_family:
+            self._m_state_in_use.observe(self.R - len(self._free_slots))
+
+    def _no_state_handoff(self) -> None:
+        if self.state_family:
+            self.executor._no_state_handoff()  # raises, by name
+
     def add_request(self, req: EngineRequest) -> None:
+        if req.prefill_only:
+            self._no_state_handoff()
         req.queued_at = time.monotonic()
         with self._lock:
             self._waiting.append(req)
@@ -1420,7 +1472,7 @@ class InferenceEngine:
         self._ps_positions[can] += 1
         self._ps_steps[can] += 1
         self._fresh[can] = False
-        self._m_batch.observe(nactive)
+        self._observe_batch(nactive)
         self._m_steps.inc()
         self.decode_dispatches += 1
         self.collective_overlap_steps += self._overlap_collectives
@@ -1698,6 +1750,8 @@ class InferenceEngine:
             if isinstance(item, _Seq):  # resuming a preempted sequence
                 seq = item
                 seq.slot = self._free_slots.pop()
+                if self.state_family:
+                    self.state_recomputes += 1
             else:
                 seq = _Seq(item, self._free_slots.pop())
             # Prefix-cache match — never the entire context (at least one
@@ -2494,6 +2548,7 @@ class InferenceEngine:
     ) -> None:
         """Decode side: continue a sequence prefilled by a peer. Thread-safe
         entry; the KV landing happens on the engine thread."""
+        self._no_state_handoff()
         with self._lock:
             self._pending_imports.append((req, handoff))
         self._work.set()
@@ -2505,6 +2560,7 @@ class InferenceEngine:
         on the engine thread. The later commit handoff's admission picks
         the blocks up through the ordinary prefix match — a chunk that
         never arrives only costs recompute of its span."""
+        self._no_state_handoff()
         with self._lock:
             self._pending_kv_chunks.append((list(block_hashes), kv))
         self._work.set()
@@ -2863,7 +2919,7 @@ class InferenceEngine:
             nactive = int(active.sum())
             total_ctx = int(self._ps_positions[active].sum()) + nactive
             self._profile_step(nactive, total_ctx, step_ms)
-            self._m_batch.observe(nactive)
+            self._observe_batch(nactive)
             self._m_steps.inc()
             self.decode_dispatches += 1
             self.collective_overlap_steps += self._overlap_collectives
@@ -2964,7 +3020,7 @@ class InferenceEngine:
         self._ps_positions[can] += 1
         self._ps_steps[can] += 1
         self._fresh[can] = False
-        self._m_batch.observe(nactive)
+        self._observe_batch(nactive)
         self._m_steps.inc()
         self.decode_dispatches += 1
         self.collective_overlap_steps += self._overlap_collectives
@@ -3688,7 +3744,7 @@ class InferenceEngine:
             snapshot[int(slot)] = (seq, seq.admit_gen)
         self._ps_pending[can] += 1
         self._fresh[can] = False
-        self._m_batch.observe(nactive)
+        self._observe_batch(nactive)
         self._m_steps.inc()
         self.decode_dispatches += 1
         self.collective_overlap_steps += self._overlap_collectives
@@ -3870,7 +3926,7 @@ class InferenceEngine:
         nactive = int(active.sum())
         total_ctx = int(positions[active].sum()) + nactive
         self._profile_step(nactive, total_ctx, step_ms)
-        self._m_batch.observe(nactive)
+        self._observe_batch(nactive)
         self._m_steps.inc()
         self.decode_dispatches += 1
         self.collective_overlap_steps += self._overlap_collectives
@@ -3935,8 +3991,9 @@ class InferenceEngine:
         """Commit newly filled blocks under their chained hashes. Media
         requests never commit (their KV depends on encoder embeddings the
         token-id hash cannot see) and neither do LoRA-adapter requests
-        (adapter-dependent KV under adapter-blind hashes)."""
-        if seq.req.has_media or seq.req.adapter_idx:
+        (adapter-dependent KV under adapter-blind hashes); a state-pool
+        family has nothing addressable by block hash."""
+        if self.state_family or seq.req.has_media or seq.req.adapter_idx:
             return
         full = len(seq.tokens) // self.block_size
         committed = seq.last_committed_block + 1

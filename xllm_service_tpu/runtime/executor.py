@@ -38,6 +38,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from xllm_service_tpu.common.config import EngineConfig
 from xllm_service_tpu.obs import spans as obs_spans
 from xllm_service_tpu.runtime import compile_cache as compile_cache_mod
+from xllm_service_tpu.runtime.block_manager import StateFamilyUnsupported
 from xllm_service_tpu import models
 from xllm_service_tpu.models.configs import (
     ModelConfig,
@@ -190,6 +191,10 @@ class ModelExecutor:
     # entry points (decode, verify, prefill_batch, prefill_long); the
     # engine hangs its `device_wait` phase here.
     fetch_scope = staticmethod(contextlib.nullcontext)
+    # The state pool of a retention family (models/brumby.py). The only
+    # other value is set by benchmarks/tests/control_brumby.py, for the
+    # control that the benchmark's `correct` must fail.
+    state_dtype = jnp.float32
 
     def _step_keys(self, seeds, steps):
         """Per-row sampling keys: eager device programs (threefry seed,
@@ -320,7 +325,13 @@ class ModelExecutor:
         self.dtype = jnp.bfloat16 if engine_cfg.dtype == "bfloat16" else jnp.float32
         # int8 KV cache: halves decode's HBM traffic (the bound resource);
         # params/activations stay in model dtype.
-        if engine_cfg.kv_cache_dtype not in ("auto", "int8"):
+        if self.cfg.is_retention:
+            if engine_cfg.kv_cache_dtype != "auto":
+                raise StateFamilyUnsupported(
+                    f"kv_cache_dtype={engine_cfg.kv_cache_dtype!r}: a state "
+                    f"pool is float32 ('auto'); no lower dtype is offered"
+                )
+        elif engine_cfg.kv_cache_dtype not in ("auto", "int8"):
             raise ValueError(
                 f"kv_cache_dtype={engine_cfg.kv_cache_dtype!r}: expected "
                 f"'auto' (model dtype) or 'int8'"
@@ -332,8 +343,19 @@ class ModelExecutor:
             )
         self.kv_quantized = engine_cfg.kv_cache_dtype == "int8"
         self.R = engine_cfg.max_running_requests
-        self.block_size = engine_cfg.block_size
-        self.num_blocks = self._decide_num_blocks()
+        # A retention family's sequence state is one slot of a state pool,
+        # not blocks (models/brumby.py): a block is as long as the longest
+        # sequence, so every sequence owns exactly one, and the block id
+        # that rides the block tables is the slot (+ 1).
+        self.is_state = self.cfg.is_retention
+        if self.is_state:
+            self._refuse_for_state_family(tp, ep)
+            self.block_size = engine_cfg.max_seq_len
+            self.state_pool_bytes = self._check_state_pool()
+            self.num_blocks = self.R + 1
+        else:
+            self.block_size = engine_cfg.block_size
+            self.num_blocks = self._decide_num_blocks()
         self.max_blocks_per_seq = math.ceil(
             engine_cfg.max_seq_len / self.block_size
         )
@@ -393,7 +415,18 @@ class ModelExecutor:
                 kv_sharding,
                 scale_sharding if self.kv_quantized else None,
             )
-            if self.num_caches == 2:
+            if self.is_state:
+                # The state S rides the k slot, its normaliser z the v slot.
+                shapes = self.model_mod.state_shapes(self.cfg, self.R)
+                rep_sh = NamedSharding(self.mesh, P())
+                alloc = jax.jit(
+                    lambda: tuple(
+                        jnp.zeros(sh, self.state_dtype) for sh in shapes
+                    ),
+                    out_shardings=(rep_sh, rep_sh),
+                )
+                self.k_cache, self.v_cache = alloc()
+            elif self.num_caches == 2:
                 alloc = jax.jit(
                     lambda: (
                         kvc.alloc_cache(
@@ -461,6 +494,15 @@ class ModelExecutor:
         # Buckets must cover max_seq_len so any admissible suffix fits.
         if not self.prefill_buckets or self.prefill_buckets[-1] < engine_cfg.max_seq_len:
             self.prefill_buckets.append(engine_cfg.max_seq_len)
+        if self.is_state:
+            # No prefix hit shortens a suffix and no chunk passes the
+            # step's budget, so no bucket beyond it is ever dispatched
+            # (and max_seq_len bounds no memory: a 32768-token bucket
+            # would only be a program nobody runs).
+            top = min(engine_cfg.max_prefill_tokens, engine_cfg.max_seq_len)
+            self.prefill_buckets = sorted(
+                {b for b in self.prefill_buckets if b < top} | {top}
+            )
 
         # Grouped-MoE dispatch stats (docs/MOE.md, docs/OBSERVABILITY.md):
         # each grouped dispatch in a jitted step emits its per-layer
@@ -643,6 +685,56 @@ class ModelExecutor:
                 )
                 self.params[stack][name] = qfn(leaf)
 
+    def _refuse_for_state_family(self, tp: int, ep: int) -> None:
+        """What is not built for a state-pool family, by name, at build."""
+        e = self.engine_cfg
+        if tp > 1 or ep > 1 or e.sp_size > 1 or e.dp_size > 1:
+            raise StateFamilyUnsupported(
+                f"tp_size/ep_size/sp_size/dp_size > 1: the state pool of "
+                f"{self.cfg.name} is not sharded (its KV-head axis could "
+                f"be; not built)"
+            )
+        if e.speculative_tokens > 0:
+            raise StateFamilyUnsupported(
+                "speculative_tokens > 0: prompt-lookup speculation needs a "
+                "state roll-back for rejected drafts, which is not built "
+                "for a state-pool family"
+            )
+        if e.num_host_blocks > 0 or e.num_ssd_blocks > 0:
+            raise StateFamilyUnsupported(
+                "prefix cache: num_host_blocks/num_ssd_blocks > 0 asks for "
+                "the prefix cache's host tiers; a state-pool family has no "
+                "prefix cache (state snapshots at chunk boundaries are not "
+                "built)"
+            )
+        if e.checkpoint_path:
+            raise StateFamilyUnsupported(
+                f"checkpoint_path: runtime/weights.py has no loader for "
+                f"{self.cfg.name} (random weights only)"
+            )
+
+    def _check_state_pool(self) -> int:
+        """Bytes of the state pool: `max_running_requests` slots, sized by
+        their bytes and refused here if they do not fit beside the
+        weights (nothing is sized from what HBM is left)."""
+        from xllm_service_tpu.ops import retention as retention_ops
+
+        pool = retention_ops.state_bytes(
+            self.cfg.num_layers, self.R, self.cfg.num_kv_heads,
+            self.cfg.head_dim, jnp.dtype(self.state_dtype).itemsize,
+        )
+        weights = approx_param_count(self.cfg) * self._bytes_per_param()
+        limit = self._device_bytes_limit() * self.engine_cfg.hbm_utilization
+        if weights + pool > limit:
+            raise ValueError(
+                f"state pool: {self.R} slots of {pool / self.R / 2**20:.1f} "
+                f"MiB ({pool / 2**30:.2f} GiB) beside {weights / 2**30:.2f} "
+                f"GiB of weights pass {limit / 2**30:.2f} GiB "
+                f"(hbm_utilization x the device's bytes_limit): lower "
+                f"max_running_requests"
+            )
+        return pool
+
     def _device_bytes_limit(self) -> int:
         """Device memory the pool is sized against. A TPU that reports no
         `bytes_limit` is an error (guessing a size hides the device); the
@@ -659,6 +751,16 @@ class ModelExecutor:
             )
         return 16 * 2**30
 
+    def _bytes_per_param(self) -> float:
+        """Resident bytes a parameter: int8 matmul leaves become 1 byte +
+        per-out-channel scales while embed/lm_head/norms stay full
+        precision (~1.15 blended); int4 packs two weights a byte, and
+        scales (1/group) + the unquantized share blend to ~0.65."""
+        return {"int8": 1.15, "int4": 0.65}.get(
+            self.engine_cfg.weight_dtype,
+            2 if self.engine_cfg.dtype == "bfloat16" else 4,
+        )
+
     def _decide_num_blocks(self) -> int:
         if self.engine_cfg.num_blocks > 0:
             return self.engine_cfg.num_blocks
@@ -666,16 +768,9 @@ class ModelExecutor:
         cfg = self.cfg
         dtype_bytes = 2 if self.engine_cfg.dtype == "bfloat16" else 4
         # Param residency and KV element size are SEPARATE quantities:
-        # int8 weights shrink only the former (matmul leaves become
-        # 1 byte + per-out-channel scales; embed/lm_head/norms stay full
-        # precision — ~1.15 bytes/param blended), while the KV element
-        # size tracks kv_cache_dtype below.
-        param_bytes = {
-            "int8": 1.15,
-            # int4 packs two weights per byte; scales (1/group) + the
-            # unquantized embed/lm_head/norm share blend to ~0.65.
-            "int4": 0.65,
-        }.get(self.engine_cfg.weight_dtype, dtype_bytes)
+        # int8 weights shrink only the former, while the KV element size
+        # tracks kv_cache_dtype below.
+        param_bytes = self._bytes_per_param()
         n_params = approx_param_count(cfg)
         total_hbm = self._device_bytes_limit()
         tp = self.mesh.shape.get("tp", 1)
@@ -1884,6 +1979,15 @@ class ModelExecutor:
         (`shards`) and marks the resolve_kv_packing downgrade as
         `gather-fallback` so a tp that strands the packed layout shows up
         in bench rows and /metrics, not just a log line."""
+        if self.is_state:
+            from xllm_service_tpu.ops import retention as retention_ops
+
+            route = (
+                "retention-pallas"
+                if retention_ops.use_kernels(self.cfg.head_dim)
+                else "retention-xla"
+            )
+            return {"decode": route, "prefill": route, "mixed": route}
         if self.cfg.is_mla:
             from xllm_service_tpu.ops.attention import (
                 resolved_mla_kernel_report,
@@ -2673,10 +2777,19 @@ class ModelExecutor:
                 )[0]
         return out
 
+    def _no_state_handoff(self) -> None:
+        if self.is_state:
+            raise StateFamilyUnsupported(
+                "PD handoff: the state of a power-retention sequence is a "
+                "state slot, not KV blocks; its export and import "
+                "(runtime/transfer.py) are not built"
+            )
+
     def migration_shape(self, n_blocks: int) -> Tuple[int, ...]:
         """Expected KV-handoff payload shape for n_blocks blocks — the PD
         pair compatibility contract (engine validates incoming handoffs
         against it): [num_caches, L, n, cache_heads, BS, row_dim]."""
+        self._no_state_handoff()
         ch, cd = models.cache_row_dims(self.cfg)
         return (
             self.num_caches,
@@ -2710,6 +2823,7 @@ class ModelExecutor:
         export is COMMITTED to migration_sharding (heads per shard), so
         the wire layer (shard_wire.to_host) can read per-shard host
         copies without a cross-shard gather."""
+        self._no_state_handoff()
         ids = jnp.asarray(block_ids, jnp.int32)
 
         def grab(cache):
@@ -2740,6 +2854,7 @@ class ModelExecutor:
         and a no-op placement on 1-device meshes)."""
         from xllm_service_tpu.parallel import shard_wire
 
+        self._no_state_handoff()
         n = len(block_ids)
         P2 = 1
         while P2 < n:

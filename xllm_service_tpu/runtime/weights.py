@@ -253,6 +253,16 @@ def config_from_hf(path: str, name: Optional[str] = None) -> ModelConfig:
         hf = json.load(f)
     archs = hf.get("architectures") or ["LlamaForCausalLM"]
     arch = archs[0]
+    if hf.get("model_type") == "brumby" or arch.startswith("Brumby"):
+        # Its config.json carries Qwen3's keys and none for the power
+        # retention; read as Qwen3 it would load and serve softmax
+        # attention over weights trained for something else.
+        raise ValueError(
+            "model_type 'brumby' (power retention, models/brumby.py): no "
+            "checkpoint loader: the checkpoint's tensor names are not "
+            "confirmed; the presets brumby-14b / brumby-tiny serve random "
+            "weights only"
+        )
     if arch in (
         "Qwen2VLForConditionalGeneration",
         "Qwen2_5_VLForConditionalGeneration",
