@@ -52,26 +52,3 @@ def chunk_kernel_flops(m: Mapping, tokens: int) -> int:
     Hq, Hkv, d = m["num_attention_heads"], m["num_key_value_heads"], m["head_dim"]
     per_token = 2 * true_features(m) * (d + 1) * (Hq + Hkv)
     return m["num_hidden_layers"] * per_token * tokens
-
-
-def live_decode_rows(w) -> int:
-    """Decode rows of the traced span, from the tap: tokens the engine
-    emitted that were not their request's first (that one comes out of
-    the prefill chunk, not out of a decode row), counted over as many
-    seconds as the trace holds (`window_s`), from where the span starts.
-    run.py's `trace_span` ends only when the profile has been written,
-    seconds after the device's last traced event, so its end is not used;
-    the mix is a steady closed loop, so which seconds are counted moves
-    the number by a step's rows at most."""
-    if w.trace_span is None or w.trace is None:
-        return 0
-    a = w.t_zero + w.trace_span[0]
-    b = a + w.trace["window_s"]
-    rows = 0
-    for tap in w.taps.values():
-        seen = 0
-        for t, n in zip(tap["times"], tap["counts"]):
-            if a <= t < b:
-                rows += n - (1 if seen == 0 else 0)
-            seen += n
-    return rows

@@ -24,7 +24,7 @@ import math
 import statistics
 import sys
 import time
-from typing import Dict, List, Mapping
+from typing import Dict, List, Mapping, Optional
 
 import numpy as np
 
@@ -164,6 +164,24 @@ def build_plan(traffic: Mapping, cell: Mapping, seed: int, seconds: float) -> Di
             "loop": traffic["loop"], "seed": int(seed),
             "sampling": dict(traffic.get("sampling", {})),
             "stream": bool(traffic.get("stream", True))}
+
+
+def closed_loop_room(plan: Mapping) -> Optional[int]:
+    """The fewest output tokens any client of a closed loop must receive
+    before it sends its LAST request; None for an open loop. A token takes
+    one engine step, so a client runs out before the window closes only
+    if its requests' mean time per token is under (warm-up + window) /
+    this. The mixes choose `requests_per_client` so that this time lies
+    under the time a step needs to stream the configuration's weights
+    once (benchmarks/tests/test_loadgen.py holds every closed-loop cell of
+    BENCHMARK.json to that): no engine that does the configuration's work
+    exhausts a client, at any speed."""
+    if plan["loop"] != "closed":
+        return None
+    answers: Dict[int, List[int]] = {}
+    for r in plan["requests"]:  # a client's requests in the order it sends them
+        answers.setdefault(r["client"], []).append(int(r["out_len"]))
+    return min(sum(lens[:-1]) for lens in answers.values())
 
 
 def prompt_ids(seed: int, req: Mapping, vocab: int) -> List[int]:

@@ -55,10 +55,16 @@ def program_durations_ms(w, programs):
     ]
 
 
-def traced_token_contexts(w):
-    """Context length of every token the engine emitted inside the traced
-    span: prompt + tokens emitted before it (from the tap)."""
-    if w.trace_span is None:
+def traced_emissions(w):
+    """(tap, tokens of the request emitted before, tokens) of every
+    emission the tap saw while the profiler was RECORDING: run.py's
+    `trace_span` runs from when `start_trace` returned to when
+    `stop_trace` was called, not to when the profile had been written
+    (seconds later: a count over that span held 2.3x the traced steps'
+    tokens, PERF.md PR 31 and PR 33). The one place that joins the tap's
+    clock to the trace; it checks itself against the program's own
+    counter in every traced run that uses it (`check_traced_tokens`)."""
+    if w.trace is None or w.trace_span is None:
         return []
     a, b = (w.t_zero + t for t in w.trace_span)
     out = []
@@ -66,9 +72,51 @@ def traced_token_contexts(w):
         seen = 0
         for t, n in zip(tap["times"], tap["counts"]):
             if a <= t < b:
-                out.extend(tap["prompt_len"] + seen + k for k in range(n))
+                out.append((tap, seen, n))
             seen += n
+    check_traced_tokens(w, out)
     return out
+
+
+def traced_token_contexts(w):
+    """Context length of every token the traced steps emitted: prompt +
+    tokens emitted before it."""
+    return [
+        tap["prompt_len"] + seen + k
+        for tap, seen, n in traced_emissions(w) for k in range(n)
+    ]
+
+
+def _decode_rows(emissions) -> int:
+    return sum(n - (1 if seen == 0 else 0) for _, seen, n in emissions)
+
+
+def traced_decode_rows(w) -> int:
+    """Decode rows of the traced steps: tokens they emitted that were
+    not their request's first (that one comes out of the prefill chunk,
+    not out of a decode row)."""
+    return _decode_rows(traced_emissions(w))
+
+
+TRACED_TOKENS_RANGE = (0.8, 1.25)
+
+
+def check_traced_tokens(w, emissions) -> None:
+    """Decode rows counted from the tap / (step programs in the trace x
+    the counter's mean decode rows a step) has to be about 1: the counter
+    is the whole window's mean and the trace 3 s of it, so a few percent
+    of play are honest; the fault this guards is a factor of 2.3. Written
+    to `w.checks`, which run.py logs and holds to the range."""
+    steps = len(program_durations_ms(w, STEP_PROGRAMS))
+    mean = hist_mean(w, "xllm_engine_decode_batch_size")
+    if not steps or not mean:
+        return
+    rows = _decode_rows(emissions)
+    lo, hi = TRACED_TOKENS_RANGE
+    w.checks["traced_rows_ratio"] = {
+        "value": rows / (steps * mean), "low": lo, "high": hi,
+        "rows_from_tap": rows, "traced_steps": steps, "counter_rows_per_step": mean,
+    }
 
 
 def step_bytes_share(w, programs, with_weights):

@@ -14,5 +14,5 @@ def compute(w):
         return None
     need = len(durs) * counts_brumby.decode_weight_bytes(
         w.model, w.engine.get("dtype", "bfloat16")
-    ) + 2 * counts_brumby.live_decode_rows(w) * counts_brumby.state_bytes_per_row(w.model)
+    ) + 2 * readers.traced_decode_rows(w) * counts_brumby.state_bytes_per_row(w.model)
     return 100.0 * w.counts.hbm_time_s(need, w.device_kind) / (sum(durs) / 1e3)

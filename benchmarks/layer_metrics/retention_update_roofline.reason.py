@@ -5,7 +5,7 @@ request's first, from the tap; bytes from harness/counts_brumby.py, true
 features, the normaliser left out) / peak HBM bandwidth / summed device
 time of the "retention_update_kernel" custom calls. A program without
 the kernel gives nothing."""
-from benchmarks.harness import counts_brumby
+from benchmarks.harness import counts_brumby, readers
 
 KERNEL = "%retention_update_kernel"
 
@@ -14,7 +14,7 @@ def compute(w):
     if w.trace is None:
         return None
     kernel_ns = sum(v for k, v in w.trace["ops"].items() if k.startswith(KERNEL))
-    rows = counts_brumby.live_decode_rows(w)
+    rows = readers.traced_decode_rows(w)
     if not kernel_ns or not rows:
         return None
     need = 2 * rows * counts_brumby.state_bytes_per_row(w.model)

@@ -89,6 +89,7 @@ class Window:
     """What one run measured; the input of every metric reader."""
 
     def __init__(self, **kw):
+        self.checks = {}  # name -> {"value", "low", "high"}: a reader's check of itself
         self.__dict__.update(kw)
 
     @property
@@ -113,6 +114,11 @@ class Window:
     def counter_delta(self, name: str):
         a, b = self.counters_start.get(name), self.counters_end.get(name)
         return None if a is None or b is None else b - a
+
+
+def checks_off(checks: dict) -> list:
+    """Names of the readers' checks of themselves that left their range."""
+    return [n for n, c in checks.items() if not c["low"] <= c["value"] <= c["high"]]
 
 
 def device_memory_peak(devices) -> int:
@@ -166,6 +172,21 @@ def open_devices(chips: int, rehearse: bool):
     return devices
 
 
+def log_room(w, fewest: int, reach_s: float) -> None:
+    """A closed loop's room, for the next reader of this log: a client
+    runs out of requests when the answers to all but its last have ended
+    before the window does, `fewest` tokens at least (loadgen.closed_loop_room)."""
+    tpots = [
+        (r["chunk_times"][-1] - r["chunk_times"][0]) * 1e3 / (r["completion_tokens"] - 1)
+        for r in w.records if w.ok(r) and r["completion_tokens"] >= 2
+    ]
+    mean = f"{sum(tpots) / len(tpots):.2f} ms" if tpots else "not read"
+    log(f"room: fewest tokens before a client's last request {fewest}; the first client "
+        f"runs out under a mean TPOT of {reach_s / fewest * 1e3:.2f} ms "
+        f"({reach_s:g} s of warm-up and window); this run's mean TPOT {mean} "
+        f"over {len(tpots)} requests")
+
+
 def sleep_until(t: float) -> None:
     d = t - time.monotonic()
     if d > 0:
@@ -175,7 +196,11 @@ def sleep_until(t: float) -> None:
 def trace_span(trace_dir: str, t_zero: float, seconds: float) -> tuple:
     """Profile TRACE_SECONDS in the middle of the window, from this
     process (only the one that holds the chip can). The Python tracer
-    stays off: it slows the host it is measuring."""
+    stays off: it slows the host it is measuring. Returns the seconds of
+    the window in which the profiler was recording: from when
+    `start_trace` returned to when `stop_trace` was CALLED. That call
+    writes the profile and returns seconds later; what the engine emits
+    meanwhile is in no traced step (readers.traced_emissions)."""
     import jax
 
     shutil.rmtree(trace_dir, ignore_errors=True)
@@ -183,11 +208,14 @@ def trace_span(trace_dir: str, t_zero: float, seconds: float) -> tuple:
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     opts.host_tracer_level = 2
-    ta = time.monotonic()
     jax.profiler.start_trace(trace_dir, profiler_options=opts)
+    ta = time.monotonic()
     time.sleep(min(TRACE_SECONDS, 0.5 * seconds))
+    tb = time.monotonic()
     jax.profiler.stop_trace()
-    return ta - t_zero, time.monotonic() - t_zero
+    log(f"trace: recording {tb - ta:.3f}s from t={ta - t_zero:+.3f}s, "
+        f"profile written {time.monotonic() - tb:.3f}s after the stop was called")
+    return ta - t_zero, tb - t_zero
 
 
 def drive(stack, job: dict, trace_dir: str) -> dict:
@@ -281,7 +309,7 @@ def main() -> int:
     log(f"device: platform={dev.platform} kind={dev.device_kind} count={len(devices)} "
         f"used={chips}")
 
-    from benchmarks.harness import check as check_mod, counts, stack as stack_mod, stats
+    from benchmarks.harness import check as check_mod, counts, loadgen, stack as stack_mod, stats
 
     if not args.rehearse:
         counts.peaks(dev.device_kind)  # an unknown device is an error now
@@ -349,6 +377,9 @@ def main() -> int:
     shown = ("i", "client", "due", "status", "done", "error", "completion_tokens", "out_len")
     for r in failed[:5]:
         log("failed request: " + json.dumps({k: r[k] for k in shown}))
+    if traffic["loop"] == "closed":
+        plan = loadgen.build_plan(traffic, cell, args.seed, seconds)
+        log_room(w, loadgen.closed_loop_room(plan), plan["warmup_seconds"] + seconds)
     for c in [x["exhausted_client"] for x in got["exhausted"]][:3]:
         log(f"client {c} ran out of requests: " + json.dumps(
             [{k: r[k] for k in shown} for r in records if r.get("client") == c]))
@@ -368,8 +399,17 @@ def main() -> int:
 
     device = {"platform": dev.platform, "kind": dev.device_kind, "count": chips,
               "memory_peak_bytes": memory_peak}
+    # every number compared, beside its limit(s): the served sample's, then
+    # what a reader checked of itself (readers.check_traced_tokens)
+    compared = {k: {"value": check[k], "limit": check[k + "_limit"]}
+                for k in ("logprob_mse", "deficit_max") if k + "_limit" in check}
+    for name, c in w.checks.items():
+        compared[name] = {"value": c["value"], "low": c["low"], "high": c["high"]}
+        log(f"self-check {name}: " + json.dumps(c))
+    off = checks_off(w.checks)
     faults = [why for why, bad in (
         ("the served sample misses the reference's limits", not check["ok"]),
+        (f"a reader's check of itself is outside its range: {', '.join(off)}", off),
         (f"{compiles} step program(s) lowered inside the window", compiles != 0),
         (f"{len(got['exhausted'])} closed-loop client(s) ran out of requests", got["exhausted"]),
         ("a metric is not a finite number", not finite),
@@ -391,6 +431,9 @@ def main() -> int:
         result["breakdown"] = {"device_ops": trace["device_ops"][:10],
                                "idle_gaps": trace["idle_gaps"][:10]}
         log("trace: programs " + json.dumps(trace["programs"][:12]))
+    result["compared"] = compared  # last in the line
+    for name, c in compared.items():  # the last lines on standard error
+        print(f"run.py: compared {name}: " + json.dumps(c), file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0
 

@@ -68,7 +68,7 @@ def test_reason_batch_mix():
     assert cell["clients"] == m["engine"]["max_running_requests"] == 24  # one client a slot
     plan = loadgen.build_plan(t, cell, 2**31 + 9, 45)
     reqs = plan["requests"]
-    assert len(reqs) == 24 * 6 and plan["loop"] == "closed"
+    assert len(reqs) == 24 * 14 and plan["loop"] == "closed"
     lens = sorted(r["prompt_len"] for r in reqs)
     assert lens[0] == 256 and lens[-1] == 4096 and all(n % 256 == 0 for n in lens)
     assert lens[len(lens) // 2] == 1024 and 1250 <= sum(lens) / len(lens) <= 1450

@@ -2,15 +2,33 @@ import json
 import os
 
 import numpy as np
+import pytest
 
-from benchmarks.harness import loadgen
+from benchmarks.harness import counts, counts_brumby, loadgen
 
 HERE = os.path.dirname(os.path.abspath(__file__))
+BENCH = os.path.dirname(HERE)
+# whose decode_weight_bytes gives a family's streaming floor
+FAMILY_COUNTS = {"llama": counts, "brumby": counts_brumby}
+
+
+def load(kind, name):
+    with open(os.path.join(BENCH, kind, name + ".json")) as f:
+        return json.load(f)
 
 
 def mix(name):
-    with open(os.path.join(HERE, "..", "traffic", name + ".json")) as f:
+    return load("traffic", name)
+
+
+def manifest():
+    with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as f:
         return json.load(f)
+
+
+def closed_loop_cells():
+    cells = [w["name"] for w in manifest()["workloads"]]
+    return [c for c in cells if mix(load("cells", c)["traffic"])["loop"] == "closed"]
 
 
 def test_plan_is_a_function_of_the_seed():
@@ -96,3 +114,62 @@ def test_generator_reports_lateness_and_failures():
     for r in recs:
         assert r["error"] and not r["done"]
         assert -0.001 <= r["t_send"] - r["due"] < 0.25
+
+
+def room(cell_name, seed, **override):
+    """(fewest tokens before a client's last request, tokens the rule asks
+    for): a token takes one engine step, and a decode step cannot be
+    shorter than the time to stream the configuration's weights once."""
+    cell = load("cells", cell_name)
+    t = dict(mix(cell["traffic"]), **override)
+    m = load("configs", cell["config"])
+    floor_s = FAMILY_COUNTS[m["family"]].decode_weight_bytes(
+        m, m["engine"].get("dtype", "bfloat16")) / counts.PEAKS["TPU v5e"]["hbm_bytes_per_s"]
+    seconds = manifest()["run_seconds"]
+    plan = loadgen.build_plan(t, cell, seed, seconds)
+    return loadgen.closed_loop_room(plan), (t["warmup_seconds"] + seconds) / floor_s
+
+
+def test_the_cells_that_can_run_out_are_the_two_closed_loops():
+    assert closed_loop_cells() == ["qwen2.5-3b.decode-batch", "brumby-14b.reason-batch"]
+    assert loadgen.closed_loop_room(loadgen.build_plan(mix("chat-steady"), {"rate_per_s": 3.2}, 1, 45)) is None
+
+
+@pytest.mark.parametrize("seed", list(range(1, 21)) + [2**31 + 33])
+@pytest.mark.parametrize("cell", closed_loop_cells())
+def test_no_client_runs_out_above_the_weights_streaming_floor(cell, seed):
+    fewest, needed = room(cell, seed)
+    assert fewest > needed, (cell, seed, fewest, needed)
+
+
+@pytest.mark.parametrize("seed", [1, 7, 20])
+def test_the_rule_sees_the_fault_it_guards(seed):
+    """The parent's six requests a client: the first client is out at a
+    mean TPOT of 49.6 ms, where the cell read 49.1-49.9."""
+    fewest, needed = room("qwen2.5-3b.decode-batch", seed, requests_per_client=6)
+    assert fewest < needed / 5
+    assert 40.0 < 57.0 / fewest * 1e3 < 52.0
+
+
+def exhausted(cell_name, seed, tpot_s, ttft_s=0.15, **override):
+    """Clients that would run out: every request served at one time per
+    token, back to back. As in loadgen.drive, a client is out once it has
+    SENT its last request before the window closes."""
+    cell = load("cells", cell_name)
+    t = dict(mix(cell["traffic"]), **override)
+    plan = loadgen.build_plan(t, cell, seed, 45)
+    sent, free = {}, {}
+    for r in plan["requests"]:
+        sent[r["client"]] = free.get(r["client"], -plan["warmup_seconds"])
+        free[r["client"]] = sent[r["client"]] + ttft_s + tpot_s * r["out_len"]
+    return sum(1 for last_sent in sent.values() if last_sent < plan["seconds"])
+
+
+@pytest.mark.parametrize("seed", [3000000131, 7, 3200000001])
+def test_an_engine_twice_as_fast_exhausts_the_parents_clients_and_none_of_these(seed):
+    cell = "qwen2.5-3b.decode-batch"
+    assert exhausted(cell, seed, 0.050, requests_per_client=6) == 0  # where the cell stood
+    assert exhausted(cell, seed, 0.027, requests_per_client=6) > 100  # device-bound
+    for tpot in (0.050, 0.027, 0.0135, 0.0076):
+        assert exhausted(cell, seed, tpot) == 0
+    assert exhausted("brumby-14b.reason-batch", seed, 0.0084) == 0
