@@ -753,18 +753,14 @@ def check_allreduce(executor) -> None:
     import jax
     import jax.numpy as jnp
 
-    from xllm_service_tpu.ops import sampling as sampling_ops
+    from xllm_service_tpu.runtime.executor import DEC_FIELDS
 
     R = executor.R
     executor._set_shard_ctx()
-    i32 = lambda *s: jnp.zeros(s, jnp.int32)  # noqa: E731
-    f32 = lambda *s: jnp.zeros(s, jnp.float32)  # noqa: E731
-    keys = sampling_ops.make_step_keys(jnp.zeros((R,), jnp.uint32), i32(R))
     lowered = executor._decode_jit.lower(
         executor.k_cache, executor.v_cache, executor.token_counts,
-        executor.params, i32(R), jnp.ones((R,), bool), i32(R), i32(R),
-        i32(R, 8), jnp.zeros((R,), bool), f32(R), i32(R), f32(R) + 1.0,
-        keys, f32(R), f32(R), use_kernel=None,
+        executor.params, jnp.zeros((R, len(DEC_FIELDS) + 8), jnp.int32),
+        jnp.zeros((R,), jnp.int32), use_kernel=None,
     )
     text = lowered.compile().as_text()
     n_ar = text.count("all-reduce(") + text.count("all-reduce-start(")

@@ -698,14 +698,24 @@ def test_encoder_fabric_differential_e2e(monkeypatch):
         encoders[0].crash()
         out6 = _ask(master, img)
         assert out6 == out1
-        # --- escape hatch: legacy synchronous path, byte-identical
+        # --- escape hatch: legacy synchronous path, byte-identical. It
+        # has no re-route: wait until the breaker has taken the crashed
+        # encoder out of the rotation (stale heartbeats), or the
+        # round-robin may still pick it (whether it did hung on how long
+        # the requests above took).
+        from tests.test_api_e2e import wait_until
+
+        assert wait_until(
+            lambda: master.scheduler.instance_mgr.health_state(
+                encoders[0].name
+            ) != "healthy",
+            timeout=10.0,
+        )
         monkeypatch.setenv("XLLM_ENCODER_FABRIC", "0")
         out7 = _ask(master, img)
         assert out7 == out1
         monkeypatch.delenv("XLLM_ENCODER_FABRIC")
         # --- fleet index saw the cached item (heartbeat deltas landed)
-        from tests.test_api_e2e import wait_until
-
         assert wait_until(
             lambda: len(master.scheduler.encoder_fabric) > 0, timeout=5.0
         )

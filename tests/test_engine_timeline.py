@@ -160,9 +160,9 @@ def test_phases_are_exclusive_and_cover_the_loop(flavour, monkeypatch):
     assert all(v > 0 for v in by_phase.values()), by_phase
     assert {"xllm.engine." + p for p in ENGINE_PHASES} <= log.names
     assert {
-        "xllm.executor.step_keys", "xllm.executor.host_inputs",
-        "xllm.executor.launch",
+        "xllm.executor.host_inputs", "xllm.executor.launch",
     } <= log.names
+    assert "xllm.executor.step_keys" not in log.names  # keys are in-graph
     assert all(len(c.tokens) == 6 for _, c in reqs)
 
 
@@ -288,8 +288,8 @@ def test_profiler_records_the_annotations_as_leaves(tmp_path):
     names = {n for _, _, n in mine}
     assert {"xllm.engine.dispatch", "xllm.engine.device_wait",
             "xllm.engine.emit", "xllm.engine.schedule"} <= names
-    assert {"xllm.executor.step_keys", "xllm.executor.host_inputs",
-            "xllm.executor.launch"} <= names
+    assert {"xllm.executor.host_inputs", "xllm.executor.launch"} <= names
+    assert "xllm.executor.step_keys" not in names  # keys are in-graph
     mine.sort()
     for (_, end, a), (start, _, b) in zip(mine, mine[1:]):
         assert start >= end, f"{a} encloses or overlaps {b}"

@@ -29,7 +29,11 @@ import numpy as np
 import pytest
 
 from xllm_service_tpu.common.config import EngineConfig
-from xllm_service_tpu.runtime.executor import ModelExecutor
+from xllm_service_tpu.runtime.executor import (
+    DEC_FIELDS,
+    PF_FIELDS,
+    ModelExecutor,
+)
 
 R, BS, NB, CB = 4, 16, 512, 4  # slots, block size, pool blocks (a layer of
 # a pool, 2 MiB, outweighs every activation), table width
@@ -57,25 +61,24 @@ def _f(*shape):
     return jnp.zeros(shape, jnp.float32)
 
 
-def _keys(*shape):
-    return jnp.zeros((*shape, 2), jnp.uint32)
-
-
 def _decode_half():
-    """fresh_tokens .. frequency of _decode_impl / _mixed_impl."""
-    return (
-        _i(R), jnp.ones((R,), bool), _i(R), _i(R), _i(R, CB),
-        jnp.ones((R,), bool), _f(R), _i(R), _f(R) + 1, _keys(R), _f(R),
-        _f(R),
-    )
+    """The decode rows' pack and the device feedback of _decode_impl /
+    _mixed_impl (every row live: a pack of ones)."""
+    return _i(R, len(DEC_FIELDS) + CB) + 1, _i(R)
 
 
 def _prefill_half():
-    """tokens .. step keys of _prefill_impl / the pf_* of the fused ones."""
+    """tokens .. steps of _prefill_impl."""
     return (
         _i(P, LPAD), _i(P), _i(P) + LPAD, _i(P, CB), _f(P), _i(P),
-        _f(P) + 1, _keys(P),
+        _f(P) + 1, jnp.zeros((P,), jnp.uint32), _i(P),
     )
+
+
+def _prefill_pack():
+    """The prefill rows' pack of the fused programs (its `len` column
+    included: ones)."""
+    return (_i(P, len(PF_FIELDS) + LPAD + CB) + 1,)
 
 
 def _verify_pipe_half():
@@ -88,23 +91,25 @@ def _verify_pipe_half():
 
 
 def _program_args(name):
-    """(takes_counts, arguments after params) for each step program."""
+    """(takes_counts, arguments after params, static keywords) for each
+    step program."""
     if name == "_decode_impl":
-        return True, _decode_half()
+        return True, _decode_half(), {}
     if name == "_prefill_impl":
-        return False, _prefill_half()
+        return False, _prefill_half(), {}
     if name == "_mixed_impl":
-        return True, _decode_half() + _prefill_half()
+        return True, _decode_half() + _prefill_pack(), {"lpad": LPAD}
     if name == "_verify_impl":
         S = K + 1
         return True, (
             _i(R, S), _i(R), _i(R) + S, _i(R, CB), _f(R), _i(R), _f(R) + 1,
-            _keys(R, S), jnp.ones((R,), bool), _f(R), _f(R),
-        )
+            jnp.zeros((R,), jnp.uint32), _i(R), jnp.ones((R,), bool), _f(R),
+            _f(R),
+        ), {}
     if name == "_verify_pipe_impl":
-        return True, _verify_pipe_half()
+        return True, _verify_pipe_half(), {}
     assert name == "_mixed_verify_impl"
-    return True, _verify_pipe_half() + _prefill_half()
+    return True, _verify_pipe_half() + _prefill_pack(), {"lpad": LPAD}
 
 
 PROGRAMS = [
@@ -116,13 +121,16 @@ PROGRAMS = [
 @pytest.mark.parametrize("name", PROGRAMS)
 def test_step_program_keeps_the_pool_still(executor, name):
     ex = executor
-    takes_counts, rest = _program_args(name)
+    takes_counts, rest, static = _program_args(name)
     counts = (ex.token_counts,) if takes_counts else ()
     donate = (0, 1, 2) if takes_counts else (0, 1)  # as the executor's jits
     ex._set_shard_ctx()
     compiled = (
-        jax.jit(getattr(ex, name), donate_argnums=donate)
-        .lower(ex.k_cache, ex.v_cache, *counts, ex.params, *rest)
+        jax.jit(
+            getattr(ex, name), donate_argnums=donate,
+            static_argnames=tuple(static),
+        )
+        .lower(ex.k_cache, ex.v_cache, *counts, ex.params, *rest, **static)
         .compile()
     )
     stack = tuple(ex.k_cache.data.shape)  # [L, N, Hkv, BS, D]

@@ -88,7 +88,7 @@ ENGINE_PHASES = (
 
 # Leaf annotations inside the executor's dispatch entry points
 # ("xllm.executor.<leaf>"): what the host does before a step launches.
-EXECUTOR_LEAVES = ("step_keys", "host_inputs", "launch")
+EXECUTOR_LEAVES = ("host_inputs", "launch")
 
 _TRACE_ANNOTATION = None
 

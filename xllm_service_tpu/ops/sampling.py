@@ -151,8 +151,16 @@ def sample_tokens(
         | (top_p < 1.0)
         | ((min_p > 0) if min_p is not None else False)
     )
+    # Under a vocab-sharded head the filter's collectives (the sort) come
+    # AFTER the argmax's and the log-softmax's, by a data dependence that
+    # holds for every finite row: XLA:CPU runs independent thunks of one
+    # program in any order on each device, and two devices that enter
+    # different collectives first wait for each other until the
+    # rendezvous aborts the process (40 s; the tp>1 engine tests). The
+    # chip runs one stream, in this order anyway.
+    ordered = (greedy_ids[0] >= 0) & (logprobs_full[0, 0] <= 0.0)
     scaled = jax.lax.cond(
-        jnp.any(needs_filter),
+        jnp.any(needs_filter) & ordered,
         lambda x: apply_top_k_top_p(x, top_k, top_p, min_p),
         lambda x: x,
         scaled,

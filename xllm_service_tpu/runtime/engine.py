@@ -284,15 +284,19 @@ class _InFlight:
 
     __slots__ = (
         "tokens", "logprobs", "slots", "t0", "nactive", "total_ctx", "pf",
-        "n_emit", "pf_tok", "pf_lp",
+        "n_emit", "pf_tok", "pf_lp", "feed",
     )
 
     def __init__(
         self, tokens, logprobs, slots, t0, nactive, total_ctx, pf=(),
-        n_emit=None, pf_tok=None, pf_lp=None,
+        n_emit=None, pf_tok=None, pf_lp=None, feed=None,
     ):
         self.tokens = tokens
         self.logprobs = logprobs
+        # What the next dispatch takes as its device-side feedback: the
+        # decode slots' tokens, [R]. A mixed step hands them out on their
+        # own (its `tokens` are [R + P]); everywhere else they ARE `tokens`.
+        self.feed = tokens if feed is None else feed
         self.slots = slots
         self.t0 = t0
         self.nactive = nactive
@@ -648,6 +652,13 @@ class InferenceEngine:
             "xllm_engine_decode_steps_total", "Decode (or verify) steps "
             "executed",
         )
+        self.metrics.counter(
+            "xllm_engine_dispatch_h2d_total",
+            "Host->device puts made by the executor's dispatch entry "
+            "points (per decode step: 1, the per-slot pack; 2 on a mixed "
+            "step, the prefill rows' pack; one more for each optional "
+            "feature that rides)",
+        ).set_function(lambda: getattr(self.executor, "dispatch_h2d", 0))
         # State-pool families (power retention): the pool and who holds
         # it. Registered for every engine; zero where there is no pool.
         self._m_state_in_use = self.metrics.histogram(
@@ -1424,7 +1435,6 @@ class InferenceEngine:
         a mixed batch actually exists."""
         if not items_meta:
             return self._dispatch_decode()
-        R = self.R
         can = (
             self._ps_active
             & (self._ps_gen_count + self._ps_pending < self._ps_max_new)
@@ -1448,10 +1458,10 @@ class InferenceEngine:
         self._observe_host_gap()
         t0 = time.monotonic()
         items, pf_entries = self._build_pf_items(items_meta, t0)
-        prev_tokens = prev.tokens[:R] if prev is not None else None
+        prev_tokens = prev.feed if prev is not None else None
         # annotate=False: the executor's leaf annotations stay leaves
         with self._phases.phase("dispatch", annotate=False):
-            tokens, logprobs = self.executor.mixed_start(
+            tokens, logprobs, feed = self.executor.mixed_start(
                 items,
                 self._ps_last_tok,
                 fresh_mask,
@@ -1486,7 +1496,7 @@ class InferenceEngine:
             self.overlap_steps += 1
         return _InFlight(
             tokens, logprobs, snapshot, t0, nactive, total_ctx,
-            pf=pf_entries,
+            pf=pf_entries, feed=feed,
         )
 
     # ------------------------------------------------------------ admission
@@ -2999,9 +3009,7 @@ class InferenceEngine:
             tokens, logprobs = self.executor.decode_start(
                 self._ps_last_tok,
                 fresh_mask,
-                # A mixed in-flight step's output is [R + P]; the decode
-                # feedback is always the leading R slots.
-                prev.tokens[: self.R] if prev is not None else None,
+                prev.feed if prev is not None else None,
                 self._ps_positions,
                 self._block_tables,
                 can,

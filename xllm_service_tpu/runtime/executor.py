@@ -16,7 +16,10 @@ TPU design points:
     index, so no step program copies a layer of it (until PR 29 the scan
     restacked both pools every step: 74 % of the chip's time, PERF.md);
   * sampling runs on-device inside the same jit — only R int32 tokens +
-    R float32 logprobs cross back to the host per step;
+    R float32 logprobs cross back to the host per step, and one int32
+    pack per half ([R, fields + CB]; the sampling keys are made from it
+    in-graph) crosses to the device (docs/ENGINE_PIPELINE.md "Dispatch
+    contract");
   * params/caches carry NamedShardings from parallel/sharding.py; under
     multi-device meshes XLA emits the TP collectives.
 """
@@ -183,6 +186,60 @@ def _leaf(name: str):
     return obs_spans.annotation("xllm.executor." + name)
 
 
+# The per-row fields of a dispatch's pack, in column order; the block
+# table (and, for prefill rows, the padded chunk before it) follows them
+# (docs/ENGINE_PIPELINE.md "Dispatch contract").
+DEC_FIELDS = (
+    "fresh_tokens", "fresh_mask", "positions", "active", "top_k", "seeds",
+    "steps", "temperature", "top_p", "presence", "frequency",
+)
+PF_FIELDS = (
+    "start", "len", "top_k", "seeds", "steps", "temperature", "top_p",
+)
+_BITS = {
+    "seeds": jnp.uint32, "temperature": jnp.float32, "top_p": jnp.float32,
+    "presence": jnp.float32, "frequency": jnp.float32,
+}
+_FLAGS = ("fresh_mask", "active")
+
+
+def _bits(x, dtype) -> np.ndarray:
+    """A float32 or uint32 host array as its int32 bits (exact)."""
+    return np.asarray(x, dtype).view(np.int32)
+
+
+def pack_rows(columns, *blocks) -> np.ndarray:
+    """ONE newly allocated host int32 array [rows, len(columns) + widths]:
+    the per-row columns (integers as they are, floats and seeds as their
+    bits: `_bits`), then each 2-D block. Fresh by contract: nothing the
+    caller keeps is handed to the runtime, which on XLA:CPU reads a host
+    array in place after the put returns."""
+    n = len(columns)
+    out = np.empty(
+        (len(columns[0]), n + sum(b.shape[1] for b in blocks)), np.int32
+    )
+    for j, col in enumerate(columns):
+        out[:, j] = col
+    for b in blocks:
+        out[:, n:n + b.shape[1]] = b
+        n += b.shape[1]
+    return out
+
+
+def unpack_rows(pack, fields):
+    """In-graph inverse of pack_rows: ({field: [rows] column in its own
+    dtype}, the [rows, rest] block behind the columns)."""
+    cols = {}
+    for j, name in enumerate(fields):
+        col = pack[:, j]
+        if name in _BITS:
+            col = jax.lax.bitcast_convert_type(col, _BITS[name])
+        elif name in _FLAGS:
+            col = col != 0
+        cols[name] = col
+    return cols, pack[:, len(fields):]
+
+
 class ModelExecutor:
     # guided decoding: index of the appended all-True row once
     # set_guided_table runs; a safe default for unguided paths
@@ -196,43 +253,141 @@ class ModelExecutor:
     # control that the benchmark's `correct` must fail.
     state_dtype = jnp.float32
 
-    def _step_keys(self, seeds, steps):
-        """Per-row sampling keys: eager device programs (threefry seed,
-        convert, fold-in) ahead of the step, under their own name."""
-        with _leaf("step_keys"):
-            return sampling_ops.make_step_keys(
-                jnp.asarray(seeds, jnp.uint32), jnp.asarray(steps, jnp.int32)
-            )
+    # Host->device puts made by the dispatch entry points (the engine
+    # exports it as xllm_engine_dispatch_h2d_total).
+    dispatch_h2d = 0
+    _null_feed = None  # _feed
 
-    def _batch_opts(self, batch: "SamplingBatch"):
-        """(presence, frequency, optional keyword arrays) of one decode or
-        verify dispatch: the per-slot sampling features that only ride
-        when some slot uses them (each keys its own compiled variant)."""
-        zeros = np.zeros((self.R,), np.float32)
-        presence = batch.presence if batch.presence is not None else zeros
-        frequency = batch.frequency if batch.frequency is not None else zeros
+    def _put(self, host, dtype=None, fresh=False):
+        """One host->device put of a dispatch entry point, counted.
+        `fresh`: the caller built `host` for this put alone (a pack);
+        anything else is copied first, so no array the engine keeps
+        writing to is ever the runtime's."""
+        self.dispatch_h2d += 1
+        return jax.device_put(host if fresh else np.array(host, dtype=dtype))
+
+    def _batch_opts(self, batch: "SamplingBatch", lora: str = "lora_idx"):
+        """Optional keyword arrays of one decode or verify dispatch: the
+        per-slot sampling features that only ride when some slot uses
+        them (each keys its own compiled variant and costs its own put);
+        `lora` is the adapter rows' name in the program dispatched."""
         opts = {}
         if batch.bias_ids is not None:
             opts = dict(
-                bias_ids=jnp.asarray(batch.bias_ids, jnp.int32),
-                bias_vals=jnp.asarray(batch.bias_vals, jnp.float32),
+                bias_ids=self._put(batch.bias_ids, np.int32),
+                bias_vals=self._put(batch.bias_vals, np.float32),
             )
         if batch.mask_rows is not None:
             opts.update(
-                mask_rows=jnp.asarray(batch.mask_rows, jnp.int32),
+                mask_rows=self._put(batch.mask_rows, np.int32),
                 guided_table=self._flushed_guided_table(),
             )
         if batch.adapter_idx is not None:
-            opts.update(lora_idx=jnp.asarray(batch.adapter_idx, jnp.int32))
+            opts[lora] = self._put(batch.adapter_idx, np.int32)
         if batch.min_p is not None:
-            opts.update(min_p=jnp.asarray(batch.min_p, jnp.float32))
+            opts.update(min_p=self._put(batch.min_p, np.float32))
         if batch.rope_delta is not None:
-            opts.update(rope_delta=jnp.asarray(batch.rope_delta, jnp.int32))
-        return (
-            jnp.asarray(presence, jnp.float32),
-            jnp.asarray(frequency, jnp.float32),
-            opts,
+            opts.update(rope_delta=self._put(batch.rope_delta, np.int32))
+        return opts
+
+    def _dec_pack(self, fresh_tokens, fresh_mask, positions, block_tables,
+                  active, batch: "SamplingBatch"):
+        """The decode rows of one dispatch as ONE device array
+        [R, len(DEC_FIELDS) + CB] (pack_rows; _dec_rows unpacks it in the
+        program); a `fresh_mask` of None is all ones. The block table is
+        sliced to the batch's true context bound (pow2 bucket: <=
+        log2(max_blocks) compiles; the gather fallback otherwise
+        materializes [R, max_blocks*BS] context per layer even when every
+        sequence is short)."""
+        need = 1
+        if active.any():
+            need = int(
+                np.asarray(positions)[np.asarray(active)].max()
+                // self.block_size
+            ) + 1
+        CB = self._pow2_bucket(need, self.max_blocks_per_seq)
+        zeros = np.zeros((self.R,), np.int32)  # 0.0f, and a mask of None
+        return self._put(
+            pack_rows(
+                (
+                    fresh_tokens,
+                    zeros + 1 if fresh_mask is None else fresh_mask,
+                    positions,
+                    active,
+                    batch.top_k,
+                    _bits(batch.seeds, np.uint32),
+                    batch.steps,
+                    _bits(batch.temperature, np.float32),
+                    _bits(batch.top_p, np.float32),
+                    zeros if batch.presence is None
+                    else _bits(batch.presence, np.float32),
+                    zeros if batch.frequency is None
+                    else _bits(batch.frequency, np.float32),
+                ),
+                block_tables[:, :CB],
+            ),
+            fresh=True,
         )
+
+    def _feed(self, fresh_mask, prev_tokens):
+        """(fresh_mask, prev_tokens) as a decode or mixed program takes
+        them. With no step in flight (`prev_tokens` None, or a sync
+        caller's `fresh_mask` None) every row is host-fed: the mask is
+        all ones and the feedback is committed zeros of a step output's
+        replicated sharding, never read. One array type either way, so a
+        step from an idle engine is the SAME program as a pipelined one
+        (a `None` in its place would be a second whole-model compile per
+        shape)."""
+        if fresh_mask is not None and prev_tokens is not None:
+            return fresh_mask, prev_tokens
+        if self._null_feed is None:
+            self._null_feed = jax.device_put(
+                np.zeros((self.R,), np.int32), NamedSharding(self.mesh, P())
+            )
+        return None, self._null_feed
+
+    @staticmethod
+    def _dec_rows(pack, prev_tokens):
+        """First traced lines of a program with decode rows: the pack's
+        columns, the block table and the input ids — the previous step's
+        device-resident sample wherever `fresh_mask` is False."""
+        d, tables = unpack_rows(pack, DEC_FIELDS)
+        token_ids = jnp.where(d["fresh_mask"], d["fresh_tokens"], prev_tokens)
+        return d, tables, token_ids
+
+    @staticmethod
+    def _row_keys(*halves):
+        """Sampling keys of a program's rows, one [rows, 2] array per
+        half, from each half's `seeds` and `steps` columns: ONE call of
+        make_step_keys (the one seed-folding definition) over all of
+        them, because each call is an unrolled threefry in the chip's
+        lowering, a third of a second of set-up per step program."""
+        keys = sampling_ops.make_step_keys(
+            jnp.concatenate([h["seeds"] for h in halves]),
+            jnp.concatenate([h["steps"] for h in halves]),
+        )
+        sizes = np.cumsum([h["seeds"].shape[0] for h in halves])[:-1]
+        return jnp.split(keys, sizes)
+
+    def _penalties(self, batch: "SamplingBatch"):
+        """(presence, frequency) of a verify dispatch, whose per-slot
+        inputs are still separate puts; None = all zeros."""
+        zeros = np.zeros((self.R,), np.float32)
+        return tuple(
+            self._put(zeros if x is None else x, np.float32)
+            for x in (batch.presence, batch.frequency)
+        )
+
+    @staticmethod
+    def _verify_keys(seeds, steps, S: int):
+        """[R, S, 2] keys of a verify step on the sequential schedule:
+        position j uses step base + j, so the emitted stream is
+        bit-identical to the non-speculative path under the same seeds."""
+        steps = steps[:, None] + jnp.arange(S, dtype=jnp.int32)
+        keys = sampling_ops.make_step_keys(
+            jnp.repeat(seeds, S), steps.reshape(-1)
+        )  # one call: _row_keys
+        return keys.reshape(seeds.shape[0], S, 2)
 
     def _fetch(self, *arrays) -> tuple:
         with self.fetch_scope():
@@ -468,9 +623,13 @@ class ModelExecutor:
 
         # Generated-token histogram per slot (presence/frequency penalties).
         # int32 [R, V] — 32 MB at V=128K, R=64; donated through every step.
+        # Replicated over the mesh like the copy every step hands back: an
+        # unplaced first copy made the first step program of a new
+        # executor compile twice.
         with self.mesh:
             self.token_counts = jax.jit(
-                lambda: jnp.zeros((self.R, self.cfg.vocab_size), jnp.int32)
+                lambda: jnp.zeros((self.R, self.cfg.vocab_size), jnp.int32),
+                out_shardings=NamedSharding(self.mesh, P()),
             )()
         self._decode_jit = jax.jit(
             self._decode_impl, donate_argnums=(0, 1, 2),
@@ -840,20 +999,10 @@ class ModelExecutor:
         v_cache,
         counts,  # [R, V] int32 generated-token histogram (donated)
         params,
-        fresh_tokens,  # [R] host-fed input ids (admissions / sync mode)
-        fresh_mask,  # [R] bool — True: input from fresh_tokens
+        pack,  # [R, len(DEC_FIELDS) + CB] int32: _dec_pack
         prev_tokens,  # [R] DEVICE-resident sampled tokens from the prior
         #              step (overlapped pipeline feeds them back without a
-        #              host round-trip); sync callers pass fresh_tokens
-        positions,
-        block_tables,
-        active,
-        temperature,
-        top_k,
-        top_p,
-        step_keys,
-        presence,
-        frequency,
+        #              host round-trip); _feed
         bias_ids=None,
         bias_vals=None,
         mask_rows=None,  # [R] rows into guided_table
@@ -863,7 +1012,9 @@ class ModelExecutor:
         use_kernel=None,
         rope_delta=None,  # [R] M-RoPE position lag (Qwen2-VL image spans)
     ):
-        token_ids = jnp.where(fresh_mask, fresh_tokens, prev_tokens)
+        d, block_tables, token_ids = self._dec_rows(pack, prev_tokens)
+        (step_keys,) = self._row_keys(d)
+        active = d["active"]
         step_kwargs = (
             {"lora_idx": lora_idx} if lora_idx is not None else {}
         )
@@ -875,15 +1026,15 @@ class ModelExecutor:
             k_cache,
             v_cache,
             token_ids,
-            positions,
+            d["positions"],
             block_tables,
             active,
             use_kernel=use_kernel,
             **step_kwargs,
         )
         tokens, logprob, _ = sampling_ops.sample_tokens(
-            logits, temperature, top_k, top_p, step_keys,
-            counts=counts, presence=presence, frequency=frequency,
+            logits, d["temperature"], d["top_k"], d["top_p"], step_keys,
+            counts=counts, presence=d["presence"], frequency=d["frequency"],
             bias_ids=bias_ids, bias_vals=bias_vals,
             allowed=(
                 guided_table[mask_rows] if mask_rows is not None else None
@@ -907,7 +1058,8 @@ class ModelExecutor:
         temperature,  # [P]
         top_k,  # [P]
         top_p,  # [P]
-        step_keys,  # [P]
+        seeds,  # [P] uint32
+        steps,  # [P] int32
         mm_embeds=None,  # [P, M, E] or None
         mm_positions=None,  # [P, M] chunk-relative (pad = Lpad)
         counts=None,  # [P, V] prior-token histogram (penalized items only)
@@ -921,6 +1073,7 @@ class ModelExecutor:
         min_p=None,  # [P]
         rope_positions=None,  # [P, 3, Lpad] M-RoPE streams (image spans)
     ):
+        step_keys = sampling_ops.make_step_keys(seeds, steps)
         step_kwargs = (
             {"lora_idx": lora_idx} if lora_idx is not None else {}
         )
@@ -961,7 +1114,8 @@ class ModelExecutor:
         temperature,
         top_k,
         top_p,
-        step_keys,  # [R, S, 2]
+        seeds,  # [R] uint32
+        steps,  # [R] int32 — generated count before this step
         active,  # [R] bool
         presence,
         frequency,
@@ -979,6 +1133,7 @@ class ModelExecutor:
         for ALL S positions are written; rows past the accepted prefix are
         stale garbage that attention can never read (masked by seq_lens)
         and the next step overwrites."""
+        step_keys = self._verify_keys(seeds, steps, token_ids.shape[1])
         step_kwargs = (
             {"lora_idx": lora_idx} if lora_idx is not None else {}
         )
@@ -1028,21 +1183,6 @@ class ModelExecutor:
             self._verify_jit = jax.jit(
                 self._verify_impl, donate_argnums=(0, 1, 2)
             )
-        S = token_ids.shape[1]
-        # Per-position keys on the sequential schedule: position j uses
-        # step base+j, so the emitted stream is bit-identical to the
-        # non-speculative path under the same seeds.
-        with _leaf("step_keys"):
-            seeds = jnp.asarray(batch.seeds, jnp.uint32)
-            keys = jnp.stack(
-                [
-                    sampling_ops.make_step_keys(
-                        seeds, jnp.asarray(batch.steps, jnp.int32) + j
-                    )
-                    for j in range(S)
-                ],
-                axis=1,
-            )  # [R, S, 2]
         with _leaf("host_inputs"):
             need = 1
             if active.any():
@@ -1052,19 +1192,19 @@ class ModelExecutor:
                     + 1
                 )
             CB = self._pow2_bucket(need, self.max_blocks_per_seq)
-            presence, frequency, opts = self._batch_opts(batch)
+            opts = self._batch_opts(batch)
             args = (
-                jnp.asarray(token_ids, jnp.int32),
-                jnp.asarray(positions, jnp.int32),
-                jnp.asarray(true_len, jnp.int32),
-                jnp.asarray(block_tables[:, :CB], jnp.int32),
-                jnp.asarray(batch.temperature, jnp.float32),
-                jnp.asarray(batch.top_k, jnp.int32),
-                jnp.asarray(batch.top_p, jnp.float32),
-                keys,
-                jnp.asarray(active),
-                presence,
-                frequency,
+                self._put(token_ids, np.int32),
+                self._put(positions, np.int32),
+                self._put(true_len, np.int32),
+                self._put(block_tables[:, :CB], np.int32),
+                self._put(batch.temperature, np.float32),
+                self._put(batch.top_k, np.int32),
+                self._put(batch.top_p, np.float32),
+                self._put(batch.seeds, np.uint32),
+                self._put(batch.steps, np.int32),
+                self._put(active),
+                *self._penalties(batch),
             )
         with _leaf("launch"):
             (
@@ -1138,20 +1278,19 @@ class ModelExecutor:
     def _prefill_group(self, group: List["PrefillItem"]) -> List[Tuple[int, float]]:
         self._set_shard_ctx()
         with _leaf("host_inputs"):
-            args, seeds, steps, mm_args, opts = self._prefill_inputs(group)
-        keys = self._step_keys(seeds, steps)
+            args, mm_args, opts = self._prefill_inputs(group)
         with _leaf("launch"):
             self.k_cache, self.v_cache, toks, lps = self._prefill_jit(
                 self.k_cache, self.v_cache, self.params,
-                *args, keys, *mm_args, **opts,
+                *args, *mm_args, **opts,
             )
         toks, lps = self._fetch(toks, lps)
         return [(int(toks[i]), float(lps[i])) for i in range(len(group))]
 
     def _prefill_inputs(self, group: List["PrefillItem"]):
-        """Pack one prefill group's host inputs and stage them on the
-        device: (positional arrays up to top_p, seeds, steps, media
-        arrays, optional keyword arrays) in _prefill_impl's order."""
+        """Stage one prefill group's host inputs on the device:
+        (positional arrays up to steps, media arrays, optional keyword
+        arrays) in _prefill_impl's order."""
         n_real = len(group)
         P = self._pow2_bucket(n_real, self.PREFILL_GROUP_MAX)
         Lpad = self.bucket_len(max(len(it.token_ids) for it in group))
@@ -1206,7 +1345,10 @@ class ModelExecutor:
                 keep = (rel >= 0) & (rel < len(it.token_ids))
                 positions[i, : mm_counts[i]] = rel[keep]
                 embeds[i, : mm_counts[i]] = np.asarray(it.mm_embeds)[keep]
-            mm_args = (jnp.asarray(embeds), jnp.asarray(positions))
+            mm_args = (
+                self._put(embeds, fresh=True),
+                self._put(positions, fresh=True),
+            )
         # Penalized (re)admissions: ship each item's prior-token histogram
         # so the prefill-sampled token sees the same penalties a decode
         # step would. Gated on PRIOR TOKENS actually existing — a fresh
@@ -1219,7 +1361,8 @@ class ModelExecutor:
         )
         if b_ids is not None:
             pen_kwargs.update(
-                bias_ids=jnp.asarray(b_ids), bias_vals=jnp.asarray(b_vals)
+                bias_ids=self._put(b_ids, fresh=True),
+                bias_vals=self._put(b_vals, fresh=True),
             )
         if any(it.mask_row >= 0 for it in group):
             rows = np.full((P,), self.permissive_row, np.int32)
@@ -1227,22 +1370,22 @@ class ModelExecutor:
                 if it.mask_row >= 0:
                     rows[i] = it.mask_row
             pen_kwargs.update(
-                mask_rows=jnp.asarray(rows),
+                mask_rows=self._put(rows, fresh=True),
                 guided_table=self._flushed_guided_table(),
             )
         if any(it.adapter_idx for it in group):
             pen_kwargs.update(
-                lora_idx=jnp.asarray(
+                lora_idx=self._put(
                     [it.adapter_idx for it in group]
                     + [0] * (P - n_real),
-                    jnp.int32,
+                    np.int32,
                 )
             )
         if any(it.min_p for it in group):
             pen_kwargs.update(
-                min_p=jnp.asarray(
+                min_p=self._put(
                     [it.min_p for it in group] + [0.0] * (P - n_real),
-                    jnp.float32,
+                    np.float32,
                 )
             )
         if any(it.rope_positions is not None for it in group):
@@ -1257,7 +1400,7 @@ class ModelExecutor:
                 elif it is not None:
                     seq = it.start_pos + np.arange(Lpad, dtype=np.int32)
                     rp[i] = seq[None, :]
-            pen_kwargs.update(rope_positions=jnp.asarray(rp))
+            pen_kwargs.update(rope_positions=self._put(rp, fresh=True))
         if any(
             it.prior_tokens is not None and len(it.prior_tokens)
             for it in group
@@ -1273,19 +1416,17 @@ class ModelExecutor:
                         cnts[i], np.asarray(it.prior_tokens, np.int64), 1
                     )
             pen_kwargs.update(
-                counts=jnp.asarray(cnts),
-                presence=jnp.asarray(pres),
-                frequency=jnp.asarray(freq),
+                counts=self._put(cnts, fresh=True),
+                presence=self._put(pres, fresh=True),
+                frequency=self._put(freq, fresh=True),
             )
-        return (
-            jnp.asarray(token_ids),
-            jnp.asarray(start_pos),
-            jnp.asarray(true_len),
-            jnp.asarray(tables),
-            jnp.asarray(temps),
-            jnp.asarray(top_ks),
-            jnp.asarray(top_ps),
-        ), seeds, steps, mm_args, pen_kwargs
+        return tuple(
+            self._put(a, fresh=True)
+            for a in (
+                token_ids, start_pos, true_len, tables, temps, top_ks,
+                top_ps, seeds, steps,
+            )
+        ), mm_args, pen_kwargs
 
     def warmup(self) -> List[Tuple[int, int]]:
         """Compile the common serving shapes against the garbage block, so
@@ -1474,14 +1615,12 @@ class ModelExecutor:
         dispatch — context buckets x step builders x spec variants —
         killing the first-post-idle-recompile class PR 11 measured at
         2.7-4 s/program (ISSUE 18 tentpole b). Beyond warmup()'s split
-        sync shapes this walks the overlap pipeline's device-resident-
-        feedback decode variant (committed replicated prev tokens key a
-        DIFFERENT lowering than the host-fed sync call), the fused
-        mixed prefill+decode family (CBd x (Lpad, CBp), both feedback
-        variants), and the pipelined verify / mixed-verify programs
-        when speculative decoding is configured. With the
-        persistent cache enabled every compile also lands on disk, so a
-        warm restart replays this walk as disk reads.
+        sync shapes (the overlap pipeline's decode steps are the same
+        programs: _feed) this walks the fused mixed prefill+decode
+        family (CBd x (Lpad, CBp)) and the pipelined verify /
+        mixed-verify programs when speculative decoding is configured.
+        With the persistent cache enabled every compile also lands on
+        disk, so a warm restart replays this walk as disk reads.
 
         `p_groups` (default on — a concurrent admission wave is the
         NORMAL case, and its P=2 group recompile is exactly the ambush
@@ -1497,9 +1636,6 @@ class ModelExecutor:
         t0 = _time.perf_counter()
         before = self.lowering_count()
         R = self.R
-        rep = NamedSharding(self.mesh, P())
-        dev_prev = jax.device_put(np.zeros((R,), np.int32), rep)
-        no_fresh = np.zeros((R,), bool)
         tables = np.zeros((R, self.max_blocks_per_seq), np.int32)
         active = np.zeros((R,), bool)
         active[0] = True
@@ -1512,19 +1648,6 @@ class ModelExecutor:
         )
         families: Dict[str, int] = {}
         families["split"] = len(self.warmup())
-
-        # Overlap-pipeline decode: the steady state feeds the next step
-        # from the in-flight device sample (replicated committed arrays).
-        n = 0
-        for CB in self._decode_cb_walk():
-            positions = np.zeros((R,), np.int32)
-            positions[0] = CB * self.block_size - 1
-            self.decode_start(
-                np.zeros((R,), np.int32), no_fresh, dev_prev,
-                positions, tables, active, batch,
-            )
-            n += 1
-        families["decode_pipe"] = n
 
         interp = os.environ.get("XLLM_RAGGED_INTERPRET") == "1"
         p_walk = [1]
@@ -1555,18 +1678,12 @@ class ModelExecutor:
                     for CBd in self._decode_cb_walk():
                         positions = np.zeros((R,), np.int32)
                         positions[0] = CBd * self.block_size - 1
-                        # Both feedback variants: host-fed (first
-                        # dispatch after idle/admission) and device-
-                        # resident (steady state).
-                        for prev, fm in (
-                            (None, None), (dev_prev, no_fresh),
-                        ):
-                            self.mixed_start(
-                                items, np.zeros((R,), np.int32), fm,
-                                prev, positions, tables, active, batch,
-                                interpret=interp,
-                            )
-                            n += 1
+                        self.mixed_start(
+                            items, np.zeros((R,), np.int32), None, None,
+                            positions, tables, active, batch,
+                            interpret=interp,
+                        )
+                        n += 1
             families["mixed"] = n
 
         # Slot-histogram (re)seed: admission calls it with the pow2-
@@ -1636,7 +1753,7 @@ class ModelExecutor:
                     )
                     self.mixed_start(
                         pf_items(n_tok, sp, 1), np.zeros((R,), np.int32),
-                        no_fresh, dev_prev, positions, tables, active,
+                        None, None, positions, tables, active,
                         gbatch, interpret=interp,
                     )
                     n += 1
@@ -1666,7 +1783,7 @@ class ModelExecutor:
         )
 
     def _sp_impl(self, k_cache, v_cache, params, token_ids, true_len,
-                 blk, off, temperature, top_k, top_p, step_key):
+                 blk, off, temperature, top_k, top_p, seed, step):
         # Per-family dispatch — supports_sp already gated on the module
         # actually providing prefill_sp_step. When the serving mesh also
         # carries a tensor axis, the ring COMPOSES with it: params keep
@@ -1693,7 +1810,7 @@ class ModelExecutor:
         v_cache = kvc.set_rows(v_cache, di, si, rows_v)
         tokens, logprob, _ = sampling_ops.sample_tokens(
             logits[None], temperature[None], top_k[None], top_p[None],
-            step_key[None],
+            sampling_ops.make_step_keys(seed[None], step[None]),
         )
         return k_cache, v_cache, tokens[0], logprob[0]
 
@@ -1727,7 +1844,6 @@ class ModelExecutor:
         idx = np.minimum(offsets // self.block_size, len(block_table) - 1)
         blk = np.where(valid, block_table[idx], 0)
         off = np.where(valid, offsets % self.block_size, 0)
-        key = self._step_keys([seed], step)[0]
         if not hasattr(self, "_sp_jit"):
             self._sp_jit = jax.jit(self._sp_impl, donate_argnums=(0, 1))
         with _leaf("launch"), self.mesh:
@@ -1735,14 +1851,15 @@ class ModelExecutor:
                 self.k_cache,
                 self.v_cache,
                 self.params,
-                jnp.asarray(padded),
-                jnp.int32(n),
-                jnp.asarray(blk, jnp.int32),
-                jnp.asarray(off, jnp.int32),
-                jnp.float32(temperature),
-                jnp.int32(top_k),
-                jnp.float32(top_p),
-                key,
+                self._put(padded, fresh=True),
+                np.int32(n),
+                self._put(blk, np.int32),
+                self._put(off, np.int32),
+                np.float32(temperature),
+                np.int32(top_k),
+                np.float32(top_p),
+                np.uint32(seed & 0xFFFFFFFF),
+                np.int32(step),
             )
         tok, lp = self._fetch(tok, lp)
         return int(tok), float(lp)
@@ -1808,55 +1925,20 @@ class ModelExecutor:
         the previous step's device-resident sample — so the overlapped
         pipeline's autoregressive feedback never round-trips the host."""
         self._set_shard_ctx()
-        keys = self._step_keys(batch.seeds, batch.steps)
         with _leaf("host_inputs"):
-            # Slice the block table to the batch's true context bound
-            # (pow2 bucket: <= log2(max_blocks) compiles). The gather
-            # fallback otherwise materializes [R, max_blocks*BS] context
-            # per layer even when every sequence is short.
-            need = 1
-            if active.any():
-                need = int(
-                    (
-                        np.asarray(positions)[np.asarray(active)].max()
-                        // self.block_size
-                    )
-                    + 1
-                )
-            CB = self._pow2_bucket(need, self.max_blocks_per_seq)
-            presence, frequency, opts = self._batch_opts(batch)
-            fresh = jnp.asarray(fresh_tokens, jnp.int32)
-            if fresh_mask is None:
-                mask = jnp.ones((self.R,), bool)
-                prev = fresh
-            else:
-                mask = jnp.asarray(fresh_mask)
-                prev = (
-                    jnp.asarray(prev_tokens, jnp.int32)
-                    if prev_tokens is not None
-                    else fresh
-                )
-            args = (
-                fresh,
-                mask,
-                prev,
-                jnp.asarray(positions, jnp.int32),
-                jnp.asarray(block_tables[:, :CB], jnp.int32),
-                jnp.asarray(active),
-                jnp.asarray(batch.temperature, jnp.float32),
-                jnp.asarray(batch.top_k, jnp.int32),
-                jnp.asarray(batch.top_p, jnp.float32),
-                keys,
-                presence,
-                frequency,
+            fresh_mask, prev_tokens = self._feed(fresh_mask, prev_tokens)
+            pack = self._dec_pack(
+                fresh_tokens, fresh_mask, positions, block_tables, active,
+                batch,
             )
+            opts = self._batch_opts(batch)
         with _leaf("launch"):
             (
                 self.k_cache, self.v_cache, self.token_counts,
                 tokens, logprobs,
             ) = self._decode_jit(
                 self.k_cache, self.v_cache, self.token_counts, self.params,
-                *args, use_kernel=use_kernel, **opts,
+                pack, prev_tokens, use_kernel=use_kernel, **opts,
             )
         return tokens, logprobs
 
@@ -2033,28 +2115,9 @@ class ModelExecutor:
         v_cache,
         counts,  # [R, V] int32 generated-token histogram (donated)
         params,
-        # --- decode half: identical contract to _decode_impl ---
-        fresh_tokens,  # [R]
-        fresh_mask,  # [R] bool
+        pack,  # decode half: _decode_impl's pack, [R, len(DEC_FIELDS) + CB]
         prev_tokens,  # [R] device-resident feedback (overlap pipeline)
-        positions,  # [R]
-        dec_tables,  # [R, CB]
-        active,  # [R] bool
-        temperature,
-        top_k,
-        top_p,
-        step_keys,
-        presence,
-        frequency,
-        # --- prefill half: identical contract to _prefill_impl ---
-        pf_tokens,  # [P, Lpad]
-        pf_start,  # [P]
-        pf_len,  # [P] (0 = padded lane)
-        pf_tables,  # [P, CB]
-        pf_temperature,
-        pf_top_k,
-        pf_top_p,
-        pf_keys,
+        pf_pack,  # prefill half: [P, len(PF_FIELDS) + Lpad + CB], _pf_half
         bias_ids=None,
         bias_vals=None,
         min_p=None,
@@ -2070,6 +2133,7 @@ class ModelExecutor:
         mask_rows=None,  # [R] rows into guided_table (decode slots)
         pf_mask_rows=None,  # [P] rows into guided_table (prefill rows)
         guided_table=None,  # [M+1+D, V] bool
+        lpad=None,  # static: the padded chunk's width inside pf_pack
         use_ragged=None,
         interpret=False,
     ):
@@ -2080,21 +2144,25 @@ class ModelExecutor:
         their split-program shapes (mixed_step docstring), so the
         emitted streams are byte-identical to split stepping
         (tests/test_ragged_attention.py pins it). Output layout: decode
-        slots first ([:R] feeds the next overlapped dispatch
-        device-side), then the P prefill rows."""
-        token_ids = jnp.where(fresh_mask, fresh_tokens, prev_tokens)
+        slots first, then the P prefill rows; the decode slots' tokens
+        once more on their own, as the next overlapped dispatch's
+        device-side feedback."""
+        d, dec_tables, token_ids = self._dec_rows(pack, prev_tokens)
+        active = d["active"]
+        pf, pf_tokens, pf_tables = self._pf_rows(pf_pack, lpad)
+        step_keys, pf_keys = self._row_keys(d, pf)
         dec_logits, pf_logits, k_cache, v_cache = self.model_mod.mixed_step(
             params,
             self.cfg,
             k_cache,
             v_cache,
             token_ids,
-            positions,
+            d["positions"],
             dec_tables,
             active,
             pf_tokens,
-            pf_start,
-            pf_len,
+            pf["start"],
+            pf["len"],
             pf_tables,
             use_ragged=use_ragged,
             lora_dec=lora_dec,
@@ -2103,8 +2171,8 @@ class ModelExecutor:
             interpret=interpret,
         )
         tokens, logprob, _ = sampling_ops.sample_tokens(
-            dec_logits, temperature, top_k, top_p, step_keys,
-            counts=counts, presence=presence, frequency=frequency,
+            dec_logits, d["temperature"], d["top_k"], d["top_p"], step_keys,
+            counts=counts, presence=d["presence"], frequency=d["frequency"],
             bias_ids=bias_ids, bias_vals=bias_vals, min_p=min_p,
             allowed=(
                 guided_table[mask_rows] if mask_rows is not None else None
@@ -2114,7 +2182,7 @@ class ModelExecutor:
             jnp.arange(tokens.shape[0]), tokens
         ].add(active.astype(jnp.int32))
         pf_tokens_out, pf_logprob, _ = sampling_ops.sample_tokens(
-            pf_logits, pf_temperature, pf_top_k, pf_top_p, pf_keys,
+            pf_logits, pf["temperature"], pf["top_k"], pf["top_p"], pf_keys,
             counts=pf_counts, presence=pf_presence, frequency=pf_frequency,
             bias_ids=pf_bias_ids, bias_vals=pf_bias_vals, min_p=pf_min_p,
             allowed=(
@@ -2128,7 +2196,16 @@ class ModelExecutor:
             counts,
             jnp.concatenate([tokens, pf_tokens_out]),
             jnp.concatenate([logprob, pf_logprob]),
+            tokens,
         )
+
+    @staticmethod
+    def _pf_rows(pf_pack, lpad: int):
+        """First traced lines of a fused program's prefill half: the
+        pack's columns, the [P, lpad] chunk and the block table behind
+        it."""
+        pf, rest = unpack_rows(pf_pack, PF_FIELDS)
+        return pf, rest[:, :lpad], rest[:, lpad:]
 
     def mixed_start(
         self,
@@ -2144,135 +2221,68 @@ class ModelExecutor:
         interpret: bool = False,
     ):
         """Dispatch ONE mixed prefill+decode step without fetching results:
-        returns (tokens, logprobs) device arrays of width R + Ppad —
-        decode slots at [:R] (the overlap pipeline's device-resident
-        feedback slice), prefill row j at R + j. The engine's ragged step
+        returns (tokens, logprobs, feed) device arrays — the first two of
+        width R + Ppad, decode slots at [:R], prefill row j at R + j;
+        `feed` the decode slots' tokens alone, [R], what the next
+        overlapped dispatch takes as `prev_tokens`. The engine's ragged step
         builder is the only caller (docs/KERNELS.md); media/M-RoPE items
         never reach here (routed to the split prefill path). Guided
         items DO ride (ISSUE 13): final chunks carry mask_row and the
         decode half takes batch.mask_rows — both applied in-graph."""
         self._set_shard_ctx()
-        keys = self._step_keys(batch.seeds, batch.steps)
         with _leaf("host_inputs"):
-            R = self.R
-            n_pf = len(items)
-            P = self._pow2_bucket(max(n_pf, 1), self.PREFILL_GROUP_MAX)
-            Lpad = self.bucket_len(
-                max((len(it.token_ids) for it in items), default=1)
-            )
-            bs = self.block_size
             # Each half buckets its context width EXACTLY like its split
             # program (decode_start / _prefill_group) — the bucket cadence
             # is part of the byte-parity contract (a different table width
             # means a different compiled program for that half).
-            need_d = 1
-            if active.any():
-                need_d = int(
-                    (np.asarray(positions)[np.asarray(active)].max() // bs)
-                    + 1
-                )
-            CBd = self._pow2_bucket(need_d, self.max_blocks_per_seq)
-            need_p = max(
-                ((it.start_pos + len(it.token_ids) + bs - 1) // bs
-                 for it in items),
-                default=1,
+            fresh_mask, prev_tokens = self._feed(fresh_mask, prev_tokens)
+            pack = self._dec_pack(
+                fresh_tokens, fresh_mask, positions, block_tables, active,
+                batch,
             )
-            CBp = self._pow2_bucket(max(need_p, 1), self.max_blocks_per_seq)
-
-            zeros = np.zeros((R,), np.float32)
-            presence = batch.presence if batch.presence is not None else zeros
-            frequency = (
-                batch.frequency if batch.frequency is not None else zeros
-            )
-
-            pf_args, pf_seeds, pf_steps, pf_opt = self._pf_half(
-                items, P, Lpad, CBp
-            )
-
-            opt = dict(pf_opt)
-            if batch.bias_ids is not None:
-                opt.update(
-                    bias_ids=jnp.asarray(batch.bias_ids, jnp.int32),
-                    bias_vals=jnp.asarray(batch.bias_vals, jnp.float32),
-                )
-            if batch.min_p is not None:
-                opt.update(min_p=jnp.asarray(batch.min_p, jnp.float32))
-            if batch.rope_delta is not None:
-                opt.update(
-                    rope_delta=jnp.asarray(batch.rope_delta, jnp.int32)
-                )
-            # Guided decoding rides per half like the split programs: the
-            # decode half takes the engine's per-slot rows (sync
-            # _decode_once contract), the prefill half the per-item
-            # final-chunk rows (_prefill_group contract). One table
-            # serves both.
-            if batch.mask_rows is not None:
-                opt.update(
-                    mask_rows=jnp.asarray(batch.mask_rows, jnp.int32),
-                    guided_table=self._flushed_guided_table(),
-                )
-            # LoRA rides per half, gated exactly like the split programs
-            # (decode_start keys on batch.adapter_idx, _prefill_group on
-            # any item adapter) — an adapter on one half must not flip the
-            # other half onto the lora-apply path.
-            if batch.adapter_idx is not None:
-                opt.update(
-                    lora_dec=jnp.asarray(batch.adapter_idx, jnp.int32)
-                )
-
-            fresh = jnp.asarray(fresh_tokens, jnp.int32)
-            if fresh_mask is None:
-                mask = jnp.ones((R,), bool)
-                prev = fresh
-            else:
-                mask = jnp.asarray(fresh_mask)
-                prev = (
-                    jnp.asarray(prev_tokens, jnp.int32)
-                    if prev_tokens is not None
-                    else fresh
-                )
-            args = (
-                fresh,
-                mask,
-                prev,
-                jnp.asarray(positions, jnp.int32),
-                jnp.asarray(block_tables[:, :CBd], jnp.int32),
-                jnp.asarray(active),
-                jnp.asarray(batch.temperature, jnp.float32),
-                jnp.asarray(batch.top_k, jnp.int32),
-                jnp.asarray(batch.top_p, jnp.float32),
-                keys,
-                jnp.asarray(presence, jnp.float32),
-                jnp.asarray(frequency, jnp.float32),
-            )
-        pf_keys = self._step_keys(pf_seeds, pf_steps)
+            pf_pack, lpad, pf_opt = self._pf_half(items)
+            # The optional features ride per half, gated exactly like the
+            # split programs (decode_start keys on the batch's arrays,
+            # _prefill_group on the items'): an adapter, a bias or a mask
+            # on one half must not flip the other half's path. One guided
+            # table serves both halves.
+            opt = {**pf_opt, **self._batch_opts(batch, lora="lora_dec")}
         with _leaf("launch"):
             if not hasattr(self, "_mixed_jit"):
                 self._mixed_jit = jax.jit(
                     self._mixed_impl,
                     donate_argnums=(0, 1, 2),
-                    static_argnames=("use_ragged", "interpret"),
+                    static_argnames=("lpad", "use_ragged", "interpret"),
                 )
             (
                 self.k_cache, self.v_cache, self.token_counts,
-                tokens, logprobs,
+                tokens, logprobs, feed,
             ) = self._mixed_jit(
                 self.k_cache, self.v_cache, self.token_counts, self.params,
-                *args, *pf_args, pf_keys,
+                pack, prev_tokens, pf_pack, lpad=lpad,
                 use_ragged=use_ragged, interpret=interpret, **opt,
             )
-        return tokens, logprobs
+        return tokens, logprobs, feed
 
-    def _pf_half(self, items: List["PrefillItem"], P: int, Lpad: int,
-                 CBp: int):
-        """Pack the prefill half of a fused dispatch: the positional
-        arrays (tokens, start, len, tables, temps, top_k, top_p — as jnp
-        arrays, in _mixed_impl/_mixed_verify_impl argument order; the
-        caller appends the keys it makes from the returned seeds and
-        steps) plus the optional pf_* sampling features, gated per item
-        exactly like _prefill_group. Shared by mixed_start and
-        verify_start, inside their `host_inputs` leaf."""
+    def _pf_half(self, items: List["PrefillItem"]):
+        """The prefill half of a fused dispatch: (ONE device array
+        [P, len(PF_FIELDS) + Lpad + CB] — pack_rows; _pf_rows unpacks it
+        in the program —, Lpad, the optional pf_* sampling features, gated
+        per item exactly like _prefill_group). The shapes bucket exactly
+        like _prefill_group's. Shared by mixed_start and verify_start,
+        inside their `host_inputs` leaf."""
         n_pf = len(items)
+        bs = self.block_size
+        P = self._pow2_bucket(max(n_pf, 1), self.PREFILL_GROUP_MAX)
+        Lpad = self.bucket_len(
+            max((len(it.token_ids) for it in items), default=1)
+        )
+        need = max(
+            ((it.start_pos + len(it.token_ids) + bs - 1) // bs
+             for it in items),
+            default=1,
+        )
+        CBp = self._pow2_bucket(max(need, 1), self.max_blocks_per_seq)
         pf_tokens = np.zeros((P, Lpad), np.int32)
         pf_start = np.zeros((P,), np.int32)
         pf_len = np.zeros((P,), np.int32)
@@ -2294,12 +2304,23 @@ class ModelExecutor:
             pf_top_p[i] = it.top_p
             pf_seeds[i] = it.seed & 0xFFFFFFFF
             pf_steps[i] = it.step
+        pf_pack = self._put(
+            pack_rows(
+                (  # PF_FIELDS
+                    pf_start, pf_len, pf_top_k, _bits(pf_seeds, np.uint32),
+                    pf_steps, _bits(pf_temps, np.float32),
+                    _bits(pf_top_p, np.float32),
+                ),
+                pf_tokens, pf_tables,
+            ),
+            fresh=True,
+        )
         opt = {}
         if any(it.adapter_idx for it in items):
             opt.update(
-                lora_pf=jnp.asarray(
+                lora_pf=self._put(
                     [it.adapter_idx for it in items] + [0] * (P - n_pf),
-                    jnp.int32,
+                    np.int32,
                 )
             )
         b_ids, b_vals = sampling_ops.pack_logit_bias(
@@ -2307,14 +2328,14 @@ class ModelExecutor:
         )
         if b_ids is not None:
             opt.update(
-                pf_bias_ids=jnp.asarray(b_ids),
-                pf_bias_vals=jnp.asarray(b_vals),
+                pf_bias_ids=self._put(b_ids, fresh=True),
+                pf_bias_vals=self._put(b_vals, fresh=True),
             )
         if any(it.min_p for it in items):
             opt.update(
-                pf_min_p=jnp.asarray(
+                pf_min_p=self._put(
                     [it.min_p for it in items] + [0.0] * (P - n_pf),
-                    jnp.float32,
+                    np.float32,
                 )
             )
         if any(it.mask_row >= 0 for it in items):
@@ -2326,7 +2347,7 @@ class ModelExecutor:
                 if it.mask_row >= 0:
                     rows[i] = it.mask_row
             opt.update(
-                pf_mask_rows=jnp.asarray(rows),
+                pf_mask_rows=self._put(rows, fresh=True),
                 guided_table=self._flushed_guided_table(),
             )
         if any(
@@ -2344,19 +2365,11 @@ class ModelExecutor:
                         cnts[i], np.asarray(it.prior_tokens, np.int64), 1
                     )
             opt.update(
-                pf_counts=jnp.asarray(cnts),
-                pf_presence=jnp.asarray(pres),
-                pf_frequency=jnp.asarray(freq),
+                pf_counts=self._put(cnts, fresh=True),
+                pf_presence=self._put(pres, fresh=True),
+                pf_frequency=self._put(freq, fresh=True),
             )
-        return (
-            jnp.asarray(pf_tokens),
-            jnp.asarray(pf_start),
-            jnp.asarray(pf_len),
-            jnp.asarray(pf_tables),
-            jnp.asarray(pf_temps),
-            jnp.asarray(pf_top_k),
-            jnp.asarray(pf_top_p),
-        ), pf_seeds, pf_steps, opt
+        return pf_pack, Lpad, opt
 
     # ------------------------------------------- pipelined verify (spec)
 
@@ -2399,13 +2412,7 @@ class ModelExecutor:
         token_ids = jnp.concatenate(
             [last[:, None], drafts.astype(jnp.int32)], axis=1
         )
-        keys = jnp.stack(
-            [
-                sampling_ops.make_step_keys(seeds, steps + j)
-                for j in range(S)
-            ],
-            axis=1,
-        )  # [R, S, 2]
+        keys = self._verify_keys(seeds, steps, S)
         return token_ids, pos, tl, keys, act
 
     def _verify_pipe_impl(
@@ -2494,15 +2501,7 @@ class ModelExecutor:
         top_p,
         presence,
         frequency,
-        # --- prefill half: identical contract to _mixed_impl ---
-        pf_tokens,
-        pf_start,
-        pf_len,
-        pf_tables,
-        pf_temperature,
-        pf_top_k,
-        pf_top_p,
-        pf_keys,
+        pf_pack,  # prefill half: identical contract to _mixed_impl
         bias_ids=None,
         bias_vals=None,
         mask_rows=None,  # [R, S] (verify rows)
@@ -2518,6 +2517,7 @@ class ModelExecutor:
         pf_bias_vals=None,
         pf_min_p=None,
         pf_mask_rows=None,  # [P] (prefill rows)
+        lpad=None,  # static: the padded chunk's width inside pf_pack
         use_ragged=None,
         interpret=False,
     ):
@@ -2532,9 +2532,8 @@ class ModelExecutor:
             drafts, host_last, host_pos, host_steps, fresh_mask,
             prev_tokens, prev_n_emit, seeds, active,
         )
-        ver_rope = None
-        if rope_delta is not None:
-            ver_rope = rope_delta
+        pf, pf_tokens, pf_tables = self._pf_rows(pf_pack, lpad)
+        (pf_keys,) = self._row_keys(pf)
         ver_logits, pf_logits, k_cache, v_cache = (
             self.model_mod.mixed_verify_step(
                 params,
@@ -2546,13 +2545,13 @@ class ModelExecutor:
                 tl,
                 ver_tables,
                 pf_tokens,
-                pf_start,
-                pf_len,
+                pf["start"],
+                pf["len"],
                 pf_tables,
                 use_ragged=use_ragged,
                 lora_ver=lora_idx,
                 lora_pf=lora_pf,
-                ver_rope_delta=ver_rope,
+                ver_rope_delta=rope_delta,
                 interpret=interpret,
             )
         )
@@ -2567,7 +2566,7 @@ class ModelExecutor:
             min_p=min_p,
         )
         pf_tok, pf_lp, _ = sampling_ops.sample_tokens(
-            pf_logits, pf_temperature, pf_top_k, pf_top_p, pf_keys,
+            pf_logits, pf["temperature"], pf["top_k"], pf["top_p"], pf_keys,
             counts=pf_counts, presence=pf_presence, frequency=pf_frequency,
             bias_ids=pf_bias_ids, bias_vals=pf_bias_vals, min_p=pf_min_p,
             allowed=(
@@ -2617,7 +2616,7 @@ class ModelExecutor:
                 )
                 need = min(worst, max_len - 1) // bs + 1
             CB = self._pow2_bucket(max(need, 1), self.max_blocks_per_seq)
-            presence, frequency, opt = self._batch_opts(batch)
+            opt = self._batch_opts(batch)
             if prev_tokens is None:
                 # Committed device zeros with the SAME replicated sharding
                 # a real verify output carries — a host numpy array here
@@ -2626,11 +2625,7 @@ class ModelExecutor:
                 # whole verify program on the first post-idle dispatch.
                 cached = getattr(self, "_null_prev", None)
                 if cached is None or cached[0] != S:
-                    # jax.sharding spelled out: `P` is shadowed by the
-                    # local prefill-group bucket below.
-                    rep = NamedSharding(
-                        self.mesh, jax.sharding.PartitionSpec()
-                    )
+                    rep = NamedSharding(self.mesh, P())
                     self._null_prev = (
                         S,
                         jax.device_put(np.zeros((R, S), np.int32), rep),
@@ -2639,37 +2634,23 @@ class ModelExecutor:
                     cached = self._null_prev
                 prev_tokens, prev_n_emit = cached[1], cached[2]
             args = (
-                jnp.asarray(drafts, jnp.int32),
-                jnp.asarray(host_last, jnp.int32),
-                jnp.asarray(host_pos, jnp.int32),
-                jnp.asarray(host_steps, jnp.int32),
-                jnp.asarray(fresh_mask),
-                jnp.asarray(prev_tokens, jnp.int32),
-                jnp.asarray(prev_n_emit, jnp.int32),
-                jnp.asarray(batch.seeds, jnp.uint32),
-                jnp.asarray(block_tables[:, :CB], jnp.int32),
-                jnp.asarray(active),
-                jnp.asarray(batch.temperature, jnp.float32),
-                jnp.asarray(batch.top_k, jnp.int32),
-                jnp.asarray(batch.top_p, jnp.float32),
-                presence,
-                frequency,
+                self._put(drafts, np.int32),
+                self._put(host_last, np.int32),
+                self._put(host_pos, np.int32),
+                self._put(host_steps, np.int32),
+                self._put(fresh_mask),
+                prev_tokens,
+                prev_n_emit,
+                self._put(batch.seeds, np.uint32),
+                self._put(block_tables[:, :CB], np.int32),
+                self._put(active),
+                self._put(batch.temperature, np.float32),
+                self._put(batch.top_k, np.int32),
+                self._put(batch.top_p, np.float32),
+                *self._penalties(batch),
             )
             if items:
-                P = self._pow2_bucket(len(items), self.PREFILL_GROUP_MAX)
-                Lpad = self.bucket_len(
-                    max(len(it.token_ids) for it in items)
-                )
-                need_p = max(
-                    (it.start_pos + len(it.token_ids) + bs - 1) // bs
-                    for it in items
-                )
-                CBp = self._pow2_bucket(
-                    max(need_p, 1), self.max_blocks_per_seq
-                )
-                pf_args, pf_seeds, pf_steps, pf_opt = self._pf_half(
-                    items, P, Lpad, CBp
-                )
+                pf_pack, lpad, pf_opt = self._pf_half(items)
                 opt = {**pf_opt, **opt}
         if not items:
             with _leaf("launch"):
@@ -2685,20 +2666,19 @@ class ModelExecutor:
                     self.params, *args, **opt,
                 )
             return tokens, logprobs, n_emit, None, None
-        pf_keys = self._step_keys(pf_seeds, pf_steps)
         with _leaf("launch"):
             if not hasattr(self, "_mixed_verify_jit"):
                 self._mixed_verify_jit = jax.jit(
                     self._mixed_verify_impl,
                     donate_argnums=(0, 1, 2),
-                    static_argnames=("use_ragged", "interpret"),
+                    static_argnames=("lpad", "use_ragged", "interpret"),
                 )
             (
                 self.k_cache, self.v_cache, self.token_counts,
                 tokens, logprobs, n_emit, pf_tok, pf_lp,
             ) = self._mixed_verify_jit(
                 self.k_cache, self.v_cache, self.token_counts, self.params,
-                *args, *pf_args, pf_keys, interpret=interpret, **opt,
+                *args, pf_pack, lpad=lpad, interpret=interpret, **opt,
             )
         return tokens, logprobs, n_emit, pf_tok, pf_lp
 

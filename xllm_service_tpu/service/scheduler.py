@@ -464,8 +464,10 @@ class Scheduler:
             target=self._reconcile_run, args=(epoch,),
             name="master-reconcile", daemon=True,
         )
-        self._reconcile_thread = t
+        # published once started: stop() joins what it finds here, and a
+        # join before start() has returned raises
         t.start()
+        self._reconcile_thread = t
 
     def _on_lost(self) -> None:
         """Demoted (lease lost / store partition): stop dispatching NOW.
