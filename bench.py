@@ -187,10 +187,10 @@ def _cpu_regression_guard(line: str) -> "tuple[str, int]":
             g = float(ab["ragged"]["tok_s"])
         except (KeyError, TypeError, ValueError):
             s = g = 0.0
-        # The rows must have RUN the builders they are labeled as — an
-        # XLLM_MIXED_STEP env override wins over the per-run config, and
-        # a split-vs-split comparison stamping "ok" would defeat the
-        # guard's whole purpose.
+        # The rows must have RUN the builders they are labeled as — a
+        # family without a mixed step runs split whatever the per-run
+        # config says, and a split-vs-split comparison stamping "ok"
+        # would defeat the guard's whole purpose.
         builders = (
             ab["split"].get("step_builder"),
             ab["ragged"].get("step_builder"),
@@ -198,7 +198,7 @@ def _cpu_regression_guard(line: str) -> "tuple[str, int]":
         if builders != ("split", "ragged"):
             res["engine_ragged_guard"] = (
                 f"abstained: step_builder {builders[0]}/{builders[1]} — "
-                f"an env override pinned the builder (XLLM_MIXED_STEP?)"
+                f"the engine resolved another builder than the row's"
             )
         elif s <= 0:
             pass
@@ -220,11 +220,9 @@ def _cpu_regression_guard(line: str) -> "tuple[str, int]":
             c = float(sb["composed"]["tok_s"])
         except (KeyError, TypeError, ValueError):
             s = c = 0.0
-        # The rows must have RUN the builders they are labeled as: the
-        # XLLM_SPEC_PIPELINE / XLLM_SYNC_ENGINE / XLLM_MIXED_STEP env
-        # hatches win over the per-run config, and a sync-vs-sync
-        # comparison stamping "ok" would defeat the guard — abstain
-        # loudly on a builder mismatch, like engine_ragged_guard.
+        # The rows must have RUN the builders they are labeled as: a
+        # sync-vs-sync comparison stamping "ok" would defeat the guard —
+        # abstain loudly on a builder mismatch, like engine_ragged_guard.
         builders = (
             sb["composed"].get("step_builder"),
             sb["sync_split"].get("step_builder"),
@@ -233,15 +231,13 @@ def _cpu_regression_guard(line: str) -> "tuple[str, int]":
             # "spec-overlap+split" for the composed row is also a
             # legitimate label: the model family has no
             # mixed_verify_step (MLA), so verify rows pipelined without
-            # prefill fusion — name both causes instead of sending the
-            # operator hunting for hatches that were never set.
+            # prefill fusion — name that cause where it is the one.
             cause = (
                 "the family lacks mixed_verify_step (no spec+mixed "
                 "fusion)"
                 if builders[0] == "spec-overlap+split"
                 and builders[1] == "spec-sync+split"
-                else "an env override pinned the builder "
-                "(XLLM_SPEC_PIPELINE/XLLM_SYNC_ENGINE/XLLM_MIXED_STEP?)"
+                else "the engine resolved another builder than the row's"
             )
             res["engine_spec_guard"] = (
                 f"abstained: step_builder {builders[0]}/{builders[1]} — "
@@ -707,10 +703,8 @@ def _engine_bench(sync: bool, mixed: bool = True, spec: int = 0,
         tp_size=tp,
         sync_engine=sync,
         enable_mixed_step=mixed,
+        # sync=True + spec steps verify at depth 0: the sync+split row.
         speculative_tokens=spec,
-        # Composed path under test iff the engine is NOT pinned sync —
-        # sync=True + spec gives exactly the pre-ISSUE-13 verify loop.
-        enable_spec_pipeline=not sync,
     )
     eng = InferenceEngine(cfg, executor=ModelExecutor(cfg))
     rng = np.random.default_rng(0)
@@ -763,18 +757,15 @@ def _engine_bench(sync: bool, mixed: bool = True, spec: int = 0,
     dt = float(np.median(dts))
     gap_steps = max(eng.host_gap_steps - gsteps0, 1)
     dispatches = max(eng.decode_dispatches - disp0, 1)
-    # The builder the engine actually RAN, not the config knob: sync mode
-    # forces the split path even with mixed enabled, and the env hatches
-    # (XLLM_SYNC_ENGINE/XLLM_SPEC_PIPELINE/XLLM_MIXED_STEP) win over the
-    # per-run config — the guards abstain on a label mismatch.
+    # The builder the engine actually RAN, not the config knob: depth 0
+    # forces the split path even with mixed enabled, and so does a
+    # family without the fused step — the guards abstain on a label
+    # mismatch.
     pipelined = not eng._force_sync
-    mixed_ran = eng.mixed_step_enabled and pipelined
+    mixed_ran = eng.mixed_step_enabled  # the engine's own live decision
     if spec:
-        spec_fuse = mixed_ran and getattr(
-            eng.executor, "supports_spec_mixed", False
-        )
         builder = (
-            "spec-overlap+mixed" if pipelined and spec_fuse
+            "spec-overlap+mixed" if mixed_ran
             else "spec-overlap+split" if pipelined
             else "spec-sync+split"
         )

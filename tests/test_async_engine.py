@@ -237,15 +237,108 @@ def test_one_step_late_stop_discards_exactly_the_extra_token():
     assert len(out[False]) == 5
 
 
-def test_sync_escape_hatch_env(monkeypatch):
-    """XLLM_SYNC_ENGINE=1 forces sync stepping over a default config (and
-    =0 forces overlap over sync_engine=True)."""
-    monkeypatch.setenv("XLLM_SYNC_ENGINE", "1")
+_DEPTH_FLAVOURS = {
+    "decode": dict(enable_mixed_step=False),
+    "mixed": dict(enable_mixed_step=True),
+    "speculative": dict(enable_mixed_step=True, speculative_tokens=3),
+}
+
+
+@pytest.mark.parametrize("seeded", [False, True], ids=["greedy", "seeded"])
+@pytest.mark.parametrize("flavour", sorted(_DEPTH_FLAVOURS))
+def test_depth0_and_depth1_emit_the_same_streams(flavour, seeded):
+    """The engine has ONE step loop; sync_engine only sets its depth.
+    At depth 0 every step is drained before the next dispatch: nothing
+    overlaps, nothing is discarded late, and the streams are depth 1's
+    (which does discard: the early stop below costs it a sample)."""
+    kw = _DEPTH_FLAVOURS[flavour]
+    rng = np.random.RandomState(5)
+    # a repetitive prompt (drafts accept) beside two random ones
+    prompts = [
+        [7, 8, 9, 10] * 6,
+        list(rng.randint(0, 500, size=37)),
+        list(rng.randint(0, 500, size=70)),
+    ]
+    sp = dict(temperature=0.8, top_k=30, seed=13) if seeded else dict(
+        temperature=0.0
+    )
+    ref = _mk(True, **kw)
+    c = C()
+    ref.add_request(EngineRequest(
+        "probe", prompts[1], SamplingParams(max_new_tokens=12, **sp), c,
+    ))
+    _drive(ref)
+    stop_tok = c.tokens[5]  # a token-dependent stop, mid-stream
+
+    streams = {}
+    for sync in (True, False):
+        eng = _mk(sync, **kw)
+        cols = []
+        for i, prompt in enumerate(prompts):
+            cols.append(C())
+            eng.add_request(EngineRequest(
+                f"r{i}", list(prompt),
+                SamplingParams(
+                    max_new_tokens=10 + 4 * i,
+                    stop_token_ids=(stop_tok,) if i == 1 else (),
+                    **sp,
+                ),
+                cols[-1],
+            ))
+            eng.step()  # staggered: later prompts land beside decode rows
+        _drive(eng)
+        assert all(col.done for col in cols)
+        streams[sync] = [col.tokens for col in cols]
+        if sync:
+            assert eng.late_stop_discards == 0
+            assert eng.overlap_steps == 0
+            assert eng.mixed_steps == 0  # depth 0 prefills split
+            assert eng.spec_pipeline_steps == 0
+            assert (eng.spec_sync_steps > 0) == (flavour == "speculative")
+        else:
+            assert eng.overlap_steps > 0
+            assert eng.late_stop_discards >= 1
+            assert eng.spec_sync_steps == 0
+            assert (eng.mixed_steps > 0) == (flavour != "decode")
+    assert streams[True] == streams[False]
+    assert streams[True][1][-1] == stop_tok
+
+
+def test_sync_escape_hatch_env():
+    """sync_engine=True forces depth-0 stepping over a default engine,
+    live (and False re-engages the pipeline over a sync_engine=True
+    one)."""
     eng = _mk(False)
-    assert eng.sync_engine and eng._force_sync
-    monkeypatch.setenv("XLLM_SYNC_ENGINE", "0")
+    assert not eng._force_sync
+    eng.cfg.sync_engine = True
+    assert eng._force_sync
     eng = _mk(True)
-    assert not eng.sync_engine and not eng._force_sync
+    assert eng.cfg.sync_engine and eng._force_sync
+    eng.cfg.sync_engine = False
+    assert not eng._force_sync
+
+
+def test_engine_loop_runs_below_a_frame_that_reserves_its_chunk():
+    """The loop's frames (and JAX's, traced and lowered from it) must not
+    straddle CPython's 16 KiB frame chunks: _loop enters through a frame
+    whose declared operand stack makes the interpreter open one chunk
+    big enough for all of them (engine._on_roomy_stack)."""
+    import sys
+
+    eng = _mk(False)
+    seen = []
+
+    def probe():
+        f = sys._getframe()
+        while f is not None:
+            seen.append((f.f_code.co_name, f.f_code.co_stacksize))
+            f = f.f_back
+
+    eng._loop_owned = probe
+    eng._loop()
+    names = [n for n, _ in seen]
+    assert names[:3] == ["probe", "_on_roomy_stack", "_loop"]
+    assert dict(seen)["_on_roomy_stack"] >= 1 << 16
 
 
 def test_async_engine_fuzz_invariants():

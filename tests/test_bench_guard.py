@@ -166,9 +166,10 @@ def test_ragged_guard_abstains_on_hot_host():
 
 
 def test_ragged_guard_abstains_on_builder_mismatch():
-    # XLLM_MIXED_STEP pins the builder over the per-run config: both rows
-    # ran split, so a passing ratio would be vacuous — the guard must
-    # abstain loudly rather than stamp "ok" on split-vs-split.
+    # A family without a mixed step runs split whatever the per-run
+    # config asks: both rows ran split, so a passing ratio would be
+    # vacuous — the guard must abstain loudly rather than stamp "ok" on
+    # split-vs-split.
     ab = _ab(100.0, 96.0)
     ab["ragged"]["step_builder"] = "split"
     out, rc = bench._cpu_regression_guard(_line(attention_bench=ab))
@@ -226,10 +227,9 @@ def test_spec_guard_abstains_on_hot_host():
 
 
 def test_spec_guard_abstains_on_builder_mismatch():
-    # XLLM_SPEC_PIPELINE=0 (or XLLM_SYNC_ENGINE/XLLM_MIXED_STEP) pins
-    # the builder over the per-run config: the "composed" row actually
-    # ran the sync verify loop, so a passing ratio would be vacuous —
-    # abstain loudly rather than stamp "ok" on sync-vs-sync.
+    # The "composed" row's engine resolved depth 0: it actually ran the
+    # sync verify steps, so a passing ratio would be vacuous — abstain
+    # loudly rather than stamp "ok" on sync-vs-sync.
     sb = _sb(100.0, 96.0)
     sb["composed"]["step_builder"] = "spec-sync+split"
     out, rc = bench._cpu_regression_guard(_line(spec_bench=sb))

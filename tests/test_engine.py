@@ -201,7 +201,7 @@ def test_sync_engine_config_escape_hatch(engine_and_oracle):
         sync_engine=True,
     )
     eng = InferenceEngine(cfg, executor=ModelExecutor(cfg))
-    assert eng.sync_engine and eng._force_sync
+    assert eng.cfg.sync_engine and eng._force_sync
     rng = np.random.RandomState(0)
     prompt = list(rng.randint(0, 500, size=23))
     c = Collector()
@@ -220,7 +220,7 @@ def test_overlap_default_engages_pipeline(engine_and_oracle):
     """The default engine runs the one-step-lookahead pipeline: decode
     steps are dispatched while the previous step is still in flight."""
     eng, oracle = engine_and_oracle
-    assert not eng.sync_engine
+    assert not eng._force_sync
     rng = np.random.RandomState(8)
     prompt = list(rng.randint(0, 500, size=19))
     c = Collector()

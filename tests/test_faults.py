@@ -448,6 +448,13 @@ class TestInjectionSites:
             request_id="r", prompt_token_ids=[1, 2, 3, 4, 5],
             sampling=SamplingParams(max_new_tokens=5), callback=cb,
         ))
+        # Wait for the two tokens before the drop (a loaded machine may
+        # take more than any fixed nap to start the engine's thread),
+        # THEN hold the stream to silence.
+        deadline = time.monotonic() + 30.0
+        while len(got) < 2 and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert got == [5, 4]
         assert not done.wait(0.5)  # stream went silent, never finished
         assert got == [5, 4]
 

@@ -74,11 +74,19 @@ class TestFleetSim:
         assert rep.completed + rep.failed == 150
         # Cycling ALL 4 instances under load can push a stream past its
         # shared max_redispatch budget (default 2) into the designed
-        # fail-fast; that must stay a sliver, not a mode. bench_fleet's
-        # 50-instance guard enforces failed == 0 at real scale.
-        assert rep.failed <= 3
+        # fail-fast; that must stay a sliver, not a mode. How many do is
+        # wall time: the store's watch thread and the lane threads run
+        # beside the sim clock, and the further the watch lags a drain
+        # the more arrivals are routed onto the dead instance and burn a
+        # redispatch (1-6 in 30 runs of this trace in one idle process,
+        # over 3 in half of them; the driver's suite read 2 of 6). A recovery
+        # path that is broken fails every stream a drain touches, and a
+        # run touches far more than that. bench_fleet's 50-instance guard
+        # enforces failed == 0 at real scale.
+        recovered = rep.redispatches + rep.resumes
+        assert rep.failed <= 15 and rep.failed < recovered / 3
         # Restarting under load must exercise the real recovery path.
-        assert rep.redispatches + rep.resumes > 0
+        assert recovered > 0
 
     def test_report_round_trips_to_json(self):
         rep = _run("burst", 10, 5.0, 2, seed=4)

@@ -90,47 +90,50 @@ def _verify_pipe_half():
     )
 
 
-def _program_args(name):
-    """(takes_counts, arguments after params, static keywords) for each
-    step program."""
+def _program_args(ex, name):
+    """(takes_counts, arguments after params, static keywords, optional
+    array keywords) for each step program."""
     if name == "_decode_impl":
-        return True, _decode_half(), {}
+        return True, _decode_half(), {}, {}
     if name == "_prefill_impl":
-        return False, _prefill_half(), {}
+        return False, _prefill_half(), {}, {}
     if name == "_mixed_impl":
-        return True, _decode_half() + _prefill_pack(), {"lpad": LPAD}
-    if name == "_verify_impl":
-        S = K + 1
-        return True, (
-            _i(R, S), _i(R), _i(R) + S, _i(R, CB), _f(R), _i(R), _f(R) + 1,
-            jnp.zeros((R,), jnp.uint32), _i(R), jnp.ones((R,), bool), _f(R),
-            _f(R),
-        ), {}
+        return True, _decode_half() + _prefill_pack(), {"lpad": LPAD}, {}
+    if name == "_verify_pipe_impl+guided":
+        # guided slots under speculation: a mask row per verify position
+        # gathered in-graph in front of the same program
+        return True, _verify_pipe_half(), {}, {
+            "mask_rows": _i(R, K + 1),
+            "guided_table": jnp.ones((3, ex.cfg.vocab_size), bool),
+        }
     if name == "_verify_pipe_impl":
-        return True, _verify_pipe_half(), {}
+        return True, _verify_pipe_half(), {}, {}
     assert name == "_mixed_verify_impl"
-    return True, _verify_pipe_half() + _prefill_pack(), {"lpad": LPAD}
+    return True, _verify_pipe_half() + _prefill_pack(), {"lpad": LPAD}, {}
 
 
 PROGRAMS = [
-    "_decode_impl", "_mixed_impl", "_prefill_impl", "_verify_impl",
-    "_verify_pipe_impl", "_mixed_verify_impl",
+    "_decode_impl", "_mixed_impl", "_prefill_impl", "_verify_pipe_impl",
+    "_verify_pipe_impl+guided", "_mixed_verify_impl",
 ]
 
 
 @pytest.mark.parametrize("name", PROGRAMS)
 def test_step_program_keeps_the_pool_still(executor, name):
     ex = executor
-    takes_counts, rest, static = _program_args(name)
+    takes_counts, rest, static, optional = _program_args(ex, name)
     counts = (ex.token_counts,) if takes_counts else ()
     donate = (0, 1, 2) if takes_counts else (0, 1)  # as the executor's jits
     ex._set_shard_ctx()
     compiled = (
         jax.jit(
-            getattr(ex, name), donate_argnums=donate,
+            getattr(ex, name.partition("+")[0]), donate_argnums=donate,
             static_argnames=tuple(static),
         )
-        .lower(ex.k_cache, ex.v_cache, *counts, ex.params, *rest, **static)
+        .lower(
+            ex.k_cache, ex.v_cache, *counts, ex.params, *rest, **static,
+            **optional,
+        )
         .compile()
     )
     stack = tuple(ex.k_cache.data.shape)  # [L, N, Hkv, BS, D]

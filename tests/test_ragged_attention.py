@@ -17,7 +17,7 @@ Three layers of differential coverage:
    (models/llama.py docstring), so fusing the dispatch cannot change
    what a client receives.
 
-3. HATCHES: XLLM_MIXED_STEP / EngineConfig.enable_mixed_step routing,
+3. HATCHES: EngineConfig.enable_mixed_step routing,
    automatic split fallback for guided + speculative + prefill_only, and
    the XLLM_RAGGED_ATTENTION_KERNEL=1 interpret-mode engine e2e (the
    Pallas branch actually serving an engine run on CPU).
@@ -393,40 +393,42 @@ def test_burst_shares_mixed_dispatches():
 # -------------------------------------------------------------- hatches
 
 
-def test_env_hatch_overrides_config(monkeypatch):
-    monkeypatch.setenv("XLLM_MIXED_STEP", "0")
-    eng = InferenceEngine(
-        _cfg(enable_mixed_step=True),
-        executor=ModelExecutor(_cfg(), init_seed=11),
-    )
-    assert not eng.mixed_step_enabled
-    monkeypatch.setenv("XLLM_MIXED_STEP", "1")
+def test_env_hatch_overrides_config():
+    """enable_mixed_step routes the step builder, read live from the
+    engine's own config (the executor's may differ: the engine's rules)."""
     eng = InferenceEngine(
         _cfg(enable_mixed_step=False),
         executor=ModelExecutor(_cfg(), init_seed=11),
     )
+    assert not eng.mixed_step_enabled
+    eng.cfg.enable_mixed_step = True
+    assert eng.mixed_step_enabled
+    eng = InferenceEngine(
+        _cfg(enable_mixed_step=True),
+        executor=ModelExecutor(_cfg(enable_mixed_step=False), init_seed=11),
+    )
     assert eng.mixed_step_enabled
 
 
-def test_speculative_rides_pipeline(monkeypatch):
+def test_speculative_rides_pipeline():
     """Speculative decoding no longer forces sync stepping (ISSUE 13):
-    the composed path is the default, and XLLM_SPEC_PIPELINE=0 (or
-    enable_spec_pipeline=False) degrades it back to sync verify."""
+    the composed path is the default, and sync_engine=True degrades it
+    back to depth-0 verify steps."""
     eng = InferenceEngine(
         _cfg(speculative_tokens=3),
         executor=ModelExecutor(_cfg(), init_seed=11),
     )
     assert not eng._force_sync
-    monkeypatch.setenv("XLLM_SPEC_PIPELINE", "0")
-    assert eng._force_sync  # live per-step decision: env flip lands
-    monkeypatch.delenv("XLLM_SPEC_PIPELINE")
+    eng.cfg.sync_engine = True
+    assert eng._force_sync  # live per-step decision: the flip lands
+    eng.cfg.sync_engine = False
     eng2 = InferenceEngine(
-        _cfg(speculative_tokens=3, enable_spec_pipeline=False),
+        _cfg(speculative_tokens=3, sync_engine=True),
         executor=ModelExecutor(_cfg(), init_seed=11),
     )
     assert eng2._force_sync
-    monkeypatch.setenv("XLLM_SPEC_PIPELINE", "1")
-    assert not eng2._force_sync  # =1 force-enables over a False config
+    eng2.cfg.sync_engine = False
+    assert not eng2._force_sync  # and back, over a True config
 
 
 def test_guided_request_rides_mixed_batch():

@@ -4,8 +4,8 @@ overlapped mixed ragged pipeline emits BYTE-IDENTICAL token streams to
 the sync+split verify engine — the pre-ISSUE-13 configuration — across
 greedy and seeded sampling, guided and unguided, accept-heavy /
 reject-heavy / mixed-acceptance workloads, cancels and preemptions
-mid-verify, plus the XLLM_SPEC_PIPELINE hatch routing and the live
-mid-run hatch flip (flush-at-transition). Both engines build from the
+mid-verify, plus the depth routing and the live mid-run depth flip
+(flush-at-transition). Both engines build from the
 same init_seed, so any stream divergence is a pipeline bug, not weight
 noise. The soundness argument under test: point-mass speculative
 acceptance makes the emitted stream draft-independent, so the pipelined
@@ -35,7 +35,6 @@ def _cfg(composed=True, spec=3, **kw):
         speculative_tokens=spec,
         sync_engine=not composed,
         enable_mixed_step=composed,
-        enable_spec_pipeline=composed,
     )
     base.update(kw)
     return EngineConfig(**base)
@@ -267,28 +266,28 @@ def test_composed_matches_sync_split_stop_token():
 # ------------------------------------------------------------- hatches
 
 
-def test_spec_pipeline_hatch_routing(monkeypatch):
-    """XLLM_SPEC_PIPELINE=0 degrades a composed config to sync verify
-    stepping; =1 force-enables over enable_spec_pipeline=False; the
-    decision is LIVE (re-read per step, no engine restart)."""
+def test_spec_pipeline_hatch_routing():
+    """sync_engine=True degrades a composed config to depth-0 verify
+    stepping; the decision is LIVE (read per step from the config, no
+    engine restart) and takes the fused prefill with it."""
     eng = _mk(True)
+    assert not eng._force_sync and eng.mixed_step_enabled
+    eng.cfg.sync_engine = True
+    assert eng._force_sync and not eng.mixed_step_enabled
+    eng.cfg.sync_engine = False
     assert not eng._force_sync
-    monkeypatch.setenv("XLLM_SPEC_PIPELINE", "0")
-    assert eng._force_sync
-    monkeypatch.delenv("XLLM_SPEC_PIPELINE")
-    assert not eng._force_sync
-    eng2 = _mk(True, enable_spec_pipeline=False)
+    eng2 = _mk(True, sync_engine=True)
     assert eng2._force_sync
-    monkeypatch.setenv("XLLM_SPEC_PIPELINE", "1")
-    assert not eng2._force_sync
-    # XLLM_SYNC_ENGINE wins over everything, live.
-    monkeypatch.setenv("XLLM_SYNC_ENGINE", "1")
-    assert eng2._force_sync
+    eng2.cfg.sync_engine = False
+    assert not eng2._force_sync and eng2.mixed_step_enabled
+    # the split builder keeps the pipeline: depth and fusing are apart
+    eng3 = _mk(True, enable_mixed_step=False)
+    assert not eng3._force_sync and not eng3.mixed_step_enabled
 
 
-def test_live_hatch_flip_flushes_and_stays_exact(monkeypatch):
-    """Satellite: flip XLLM_SYNC_ENGINE mid-run on a composed engine —
-    the in-flight step is flushed at the transition (the flush-at-
+def test_live_hatch_flip_flushes_and_stays_exact():
+    """Satellite: flip sync_engine mid-run on a composed engine — the
+    in-flight step is flushed at the transition (the flush-at-
     transition path), the stream completes byte-identical to an
     all-sync run, and flipping back re-engages the pipeline."""
     ref = _mk(False)
@@ -308,13 +307,13 @@ def test_live_hatch_flip_flushes_and_stays_exact(monkeypatch):
     for _ in range(4):
         eng.step()
     assert eng._inflight is not None  # pipeline engaged
-    monkeypatch.setenv("XLLM_SYNC_ENGINE", "1")
-    eng.step()  # transition iteration: flushes, then steps sync
+    eng.cfg.sync_engine = True
+    eng.step()  # transition iteration: flushes, then steps at depth 0
     assert eng._inflight is None
     sync_steps_mid = eng.spec_sync_steps
     assert sync_steps_mid > 0
     eng.step()
-    monkeypatch.setenv("XLLM_SYNC_ENGINE", "0")
+    eng.cfg.sync_engine = False
     pipe_before = eng.spec_pipeline_steps
     _drive(eng)
     assert eng.spec_pipeline_steps > pipe_before  # pipeline re-engaged

@@ -218,8 +218,6 @@ def test_verify_accepts_oracle_drafts():
     token_ids[0, 1:] = continuation[1:S]
     positions = np.zeros((R,), np.int32)
     positions[0] = len(prompt)
-    true_len = np.zeros((R,), np.int32)
-    true_len[0] = S
     tables = np.zeros((R, ex2.max_blocks_per_seq), np.int32)
     tables[0] = table
     active = np.zeros((R,), bool)
@@ -234,7 +232,7 @@ def test_verify_accepts_oracle_drafts():
         np.zeros((R,), np.float32),
     )
     tokens, _, n_emit = ex2.verify(
-        token_ids, positions, true_len, tables, active, batch
+        token_ids, positions, tables, active, batch
     )
     assert int(n_emit[0]) == S
     assert list(tokens[0]) == continuation[1: S + 1]
@@ -248,7 +246,7 @@ def test_verify_accepts_oracle_drafts():
     bad[0, 1:] = [0, 0, 0]
     assert continuation[1] != 0  # the draft really is wrong
     tokens, _, n_emit = ex3.verify(
-        bad, positions, true_len, tables, active, batch
+        bad, positions, tables, active, batch
     )
     assert int(n_emit[0]) == 1
     assert int(tokens[0, 0]) == continuation[1]

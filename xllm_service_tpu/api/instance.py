@@ -1048,8 +1048,8 @@ def main(argv=None) -> None:
     )
     parser.add_argument(
         "--sync-engine", action="store_true",
-        help="disable the overlapped decode pipeline (fully synchronous "
-        "stepping; XLLM_SYNC_ENGINE=1|0 overrides either way)",
+        help="run the engine's step loop at pipeline depth 0: every step "
+        "is drained before the next is dispatched (EngineConfig.sync_engine)",
     )
     parser.add_argument(
         "--lora", action="append", default=[], metavar="NAME=PATH",

@@ -244,17 +244,17 @@ class EngineConfig:
     # Sampling defaults.
     max_new_tokens_default: int = 512
 
-    # Engine stepping mode. False (default) = overlapped one-step-lookahead
-    # pipeline: decode step N+1 is dispatched while step N's sampled tokens
-    # are still in flight on the device (they feed step N+1's inputs
-    # device-side; the host drains results one step behind and discards the
-    # single late token a stopped sequence over-produces). True = fully
-    # synchronous stepping (every step fetched + booked before the next
-    # dispatch) — the differential-testing / debugging escape hatch. The
-    # env var XLLM_SYNC_ENGINE=1|0 overrides this field either way, and
-    # the engine re-reads it EVERY step, so a flip takes effect on a
-    # running engine at the next iteration (the in-flight step is
-    # flushed at the transition — docs/ENGINE_PIPELINE.md).
+    # Pipeline depth of the engine's one step loop (docs/ENGINE_PIPELINE.md).
+    # False (default) = depth 1: step N+1 is dispatched while step N's
+    # sampled tokens are still in flight on the device (they feed step
+    # N+1's inputs device-side; the host drains results one step behind
+    # and discards the single late token a stopped sequence
+    # over-produces). True = depth 0: the same loop drains each step
+    # before it returns, so every slot is host-fed, nothing is discarded
+    # late and prefill runs split; differential suites use it as the
+    # reference, and `--sync-engine` sets it. The engine reads the field
+    # EVERY step, so a flip takes effect on a running engine at the next
+    # iteration (what the pipeline held is flushed at the transition).
     sync_engine: bool = False
 
     # Mixed (ragged) stepping. True (default) = the engine step builder
@@ -266,12 +266,11 @@ class EngineConfig:
     # ONE ragged Pallas dispatch or as the split decode+prefill kernels is
     # a separate hatch (XLLM_RAGGED_ATTENTION_KERNEL — opt-in until
     # chip-validated). False = the split-step escape hatch (prefill batch
-    # then decode step, the pre-ISSUE-9 hot loop). Env override
-    # XLLM_MIXED_STEP=1|0 wins either way; sync iterations and MLA
-    # families always run split. Guided requests ride the mixed batch
-    # (their final chunk samples under an in-graph mask row), and
-    # speculative engines fuse verify rows with the due prefill chunks
-    # (mixed_verify_step) when enable_spec_pipeline holds.
+    # then decode step, the pre-ISSUE-9 hot loop). Depth-0 iterations
+    # (sync_engine) and MLA families always run split. Guided requests
+    # ride the mixed batch (their final chunk samples under an in-graph
+    # mask row), and speculative engines fuse verify rows with the due
+    # prefill chunks (mixed_verify_step) where the family has one.
     enable_mixed_step: bool = True
 
     # Speculative decoding (prompt-lookup / n-gram drafting; 0 disables).
@@ -283,7 +282,11 @@ class EngineConfig:
     # non-speculative decoding under the same seeds (ops/sampling.py
     # speculative_sample). Decode is HBM-bound, so verifying k+1 positions
     # reuses the same weight/KV traffic one token would — accepted drafts
-    # are nearly free throughput.
+    # are nearly free throughput. At pipeline depth 1 verify step N+1's
+    # inputs (last accepted token, position, step count) are gathered ON
+    # DEVICE from step N's output, and host-proposed drafts may lag one
+    # step without changing a byte: point-mass acceptance makes the
+    # emitted stream draft-independent (docs/ENGINE_PIPELINE.md).
     speculative_tokens: int = 0
     speculative_ngram_max: int = 3  # longest suffix n-gram to match
     # Legacy scan bound for prompt-lookup drafting. The proposer keeps a
@@ -291,17 +294,6 @@ class EngineConfig:
     # only caps the one-off index build of a long RESUMED history; the
     # index itself covers the full history.
     speculative_lookback: int = 4096
-    # Speculative decoding inside the overlapped pipeline. True (default)
-    # = draft+verify runs as a pipelined unit: verify step N+1 is
-    # dispatched while step N is in flight, with step N+1's inputs (last
-    # accepted token, position, step count) gathered ON DEVICE from step
-    # N's verify output — the variable accepted count never round-trips
-    # the host. Exactness: point-mass acceptance makes the emitted
-    # stream draft-independent, so host-proposed drafts may lag one step
-    # without changing a byte (docs/ENGINE_PIPELINE.md). False = verify
-    # steps run on the sync path (the pre-ISSUE-13 behavior). Env
-    # override XLLM_SPEC_PIPELINE=1|0 wins either way, re-read per step.
-    enable_spec_pipeline: bool = True
 
     # Persistent XLA compilation cache dir ("" disables). First boot of a
     # shape-bucketed engine compiles tens of programs at 20-40 s each on

@@ -654,7 +654,7 @@ def mixed_verify_step(
     )
     # M-RoPE verify rows (media sequences decoding under spec): the
     # generation streams are equal, only the lag vs cache positions
-    # matters — exactly executor._verify_impl's broadcast.
+    # matters — exactly executor._verify_pipe_impl's broadcast.
     if ver_rope_delta is not None:
         base = (ver_start + ver_rope_delta)[:, None] + jnp.arange(
             S, dtype=jnp.int32

@@ -58,7 +58,11 @@ from xllm_service_tpu.runtime.block_manager import (
     StateSlotManager,
 )
 from xllm_service_tpu.runtime import compile_cache as compile_cache_mod
-from xllm_service_tpu.runtime.executor import ModelExecutor, SamplingBatch
+from xllm_service_tpu.runtime.executor import (
+    ModelExecutor,
+    SamplingBatch,
+    fuses_prefill,
+)
 
 
 @dataclass
@@ -327,6 +331,27 @@ PROFILE_SAMPLES = 512
 _QueueItem = "EngineRequest | _Seq"
 
 
+def _on_roomy_stack(fn):
+    """Call `fn()` from a frame that holds room for every call below it.
+
+    CPython keeps a thread's frames in 16 KiB chunks and unmaps a chunk
+    the moment the frame that opened it returns. JAX's tracing and
+    lowering recurse across such a boundary thousands of times a
+    program, an mmap/munmap pair each time: of the 30 s the engine
+    thread spent tracing and lowering chat-steady's 16 step programs,
+    20 s were that, and WHICH calls straddle a boundary follows the
+    locals of every caller, so set-up moved by seconds with edits that
+    touched no program (PERF.md section 6, PR 35). A frame that declares
+    a 64 Ki-word operand stack makes the interpreter open one 1 MiB
+    chunk for it instead; the loop and all it calls live in its rest."""
+    return fn()
+
+
+_on_roomy_stack.__code__ = _on_roomy_stack.__code__.replace(
+    co_stacksize=1 << 16
+)
+
+
 class InferenceEngine:
     def __init__(
         self,
@@ -418,42 +443,13 @@ class InferenceEngine:
         self._thread: Optional[threading.Thread] = None
         self._cancelled: set = set()  # guarded by: self._lock
 
-        # Stepping mode: overlapped one-step-lookahead pipeline by default;
-        # sync_engine=True (or XLLM_SYNC_ENGINE=1) forces fully synchronous
-        # stepping; XLLM_SYNC_ENGINE=0 force-enables overlap over a
-        # sync_engine=True config. Speculative decoding rides the pipeline
-        # too (verify inputs gathered on-device from the in-flight step's
-        # variable accepted counts) unless XLLM_SPEC_PIPELINE=0 /
-        # enable_spec_pipeline=False degrades it to sync verify stepping.
-        # Eligibility is a LIVE per-step decision — the `_force_sync`
-        # property re-reads both hatches every step, so a flip lands on a
-        # running engine at the next iteration (ISSUE 13 satellite); the
-        # attribute below only snapshots the construction-time value for
-        # introspection.
+        # Stepping is ONE loop (step()); what differs between flavours is
+        # data read every iteration, not a code path chosen here: the
+        # pipeline depth (`_force_sync`: cfg.sync_engine), whether due
+        # prefill chunks ride the dispatch (`mixed_step_enabled`:
+        # executor.fuses_prefill) and cfg.speculative_tokens.
         import os as _os
 
-        _env = _os.environ.get("XLLM_SYNC_ENGINE", "")
-        self.sync_engine = (
-            True if _env == "1"
-            else False if _env == "0"
-            else engine_cfg.sync_engine
-        )
-
-        # Mixed (ragged) stepping: the step builder emits ONE batch of
-        # decode slots + due prefill chunks per iteration
-        # (executor.mixed_start -> models.<family>.mixed_step ->
-        # ops.attention.mixed_attention; docs/KERNELS.md) instead of
-        # alternating a prefill step and a decode step. Split stepping is
-        # the escape hatch: enable_mixed_step=False or XLLM_MIXED_STEP=0
-        # (=1 force-enables over a False config); guided/sync/speculative
-        # iterations and model families without a mixed_step (MLA) fall
-        # back to split automatically.
-        _menv = _os.environ.get("XLLM_MIXED_STEP", "")
-        self.mixed_step_enabled = (
-            True if _menv == "1"
-            else False if _menv == "0"
-            else engine_cfg.enable_mixed_step
-        ) and getattr(self.executor, "supports_mixed", False)
         # Test hook: drive the ragged Pallas kernel branch in interpret
         # mode on CPU (the dispatcher convention every kernel follows).
         self._ragged_interpret = (
@@ -571,7 +567,7 @@ class InferenceEngine:
         self.spec_slot_steps = 0
         self.spec_tokens_emitted = 0
         # Composed-path accounting (ISSUE 13): verify steps dispatched
-        # through the overlapped pipeline vs on the sync path, pipelined
+        # at pipeline depth 1 vs drained at depth 0, pipelined
         # dispatches that applied a guided mask row in-graph, and the
         # per-slot guided fallback — host-paced skips (a guided slot held
         # out of one dispatch so its NEXT mask row derives from the exact
@@ -683,8 +679,8 @@ class InferenceEngine:
         # Overlapped-pipeline instruments (docs/ENGINE_PIPELINE.md): the
         # host gap is the wall time between finishing one step's host
         # bookkeeping and dispatching the next decode step — the window the
-        # device would idle through in sync mode; overlap hides it behind
-        # the in-flight step.
+        # device idles through at depth 0; depth 1 hides it behind the
+        # in-flight step.
         self._m_host_gap = self.metrics.histogram(
             "xllm_engine_host_gap_ms",
             "Host bookkeeping gap between one decode step's drain and the "
@@ -693,7 +689,7 @@ class InferenceEngine:
         self.metrics.gauge(
             "xllm_engine_overlap_depth",
             "Decode steps currently in flight on the device (0 = idle or "
-            "sync mode, 1 = one-step lookahead active)",
+            "pipeline depth 0, 1 = one-step lookahead active)",
         ).set_function(lambda: 1 if self._inflight is not None else 0)
         self.metrics.counter(
             "xllm_engine_overlapped_steps_total",
@@ -772,8 +768,8 @@ class InferenceEngine:
         ).set_function(lambda: self.spec_pipeline_steps)
         self.metrics.counter(
             "xllm_engine_spec_sync_steps_total",
-            "Speculative verify steps run on the sync path (hatch or "
-            "transition fallback)",
+            "Speculative verify steps drained at pipeline depth 0 "
+            "(sync_engine)",
         ).set_function(lambda: self.spec_sync_steps)
         self.metrics.counter(
             "xllm_engine_guided_ingraph_steps_total",
@@ -1098,7 +1094,7 @@ class InferenceEngine:
         # checks their call sites statically.
         claim_thread(self, "engine")
         try:
-            self._loop_owned()
+            _on_roomy_stack(self._loop_owned)
         finally:
             release_thread(self, "engine")
 
@@ -1136,46 +1132,39 @@ class InferenceEngine:
 
     @property
     def _force_sync(self) -> bool:
-        """LIVE pipeline-eligibility decision (ISSUE 13 satellite): the
-        XLLM_SYNC_ENGINE and XLLM_SPEC_PIPELINE hatches are re-read on
-        every step, so flipping either on a running engine takes effect
-        at the next iteration — step() flushes the in-flight step at the
-        transition. Guided sequences no longer appear here: they ride
-        the pipeline host-paced (per-slot, see _apply_guided_pacing)."""
-        import os as _os
+        """Pipeline depth 0 (cfg.sync_engine), read LIVE every step: a
+        flip on a running engine takes effect at the next iteration, and
+        step() flushes what the pipeline held at the transition. Guided
+        sequences do not appear here: they ride the pipeline host-paced
+        (per-slot, see _apply_guided_pacing)."""
+        return self.cfg.sync_engine
 
-        _env = _os.environ.get("XLLM_SYNC_ENGINE", "")
-        sync = (
-            True if _env == "1"
-            else False if _env == "0"
-            else self.cfg.sync_engine
-        )
-        if sync:
-            return True
-        if self.cfg.speculative_tokens > 0:
-            _senv = _os.environ.get("XLLM_SPEC_PIPELINE", "")
-            return not (
-                True if _senv == "1"
-                else False if _senv == "0"
-                else self.cfg.enable_spec_pipeline
-            )
-        return False
+    @property
+    def mixed_step_enabled(self) -> bool:
+        """Whether due prefill chunks ride this iteration's dispatch
+        (docs/KERNELS.md) — live, the one decision prewarm shares."""
+        return fuses_prefill(self.cfg, self.executor)
 
     @thread_owned("engine")
     def step(self) -> int:
-        """One engine iteration: land migrated KV, admit + prefill new
-        requests, then one decode (or speculative verify) step. Returns
-        number of tokens produced.
+        """One engine iteration, the only one: land migrated KV, schedule
+        (continue chunks and admit), dispatch one decode or speculative
+        verify step, drain one. Returns number of tokens produced.
 
-        Overlapped mode (default): the dispatch for step N+1 happens
-        BEFORE step N's results are consumed, so host bookkeeping runs
-        while the device computes — for plain decode AND speculative
-        verify (step N+1's verify inputs are gathered on-device from
-        step N's variable accepted counts). Guided sequences ride the
-        pipeline host-paced per slot. Sync mode — the escape hatch, or
-        XLLM_SPEC_PIPELINE=0 degrading speculative engines — fetches and
-        books each step before dispatching the next; the eligibility
-        decision is re-made every step so hatch flips land mid-run."""
+        Depth 1 (default): the dispatch for step N+1 happens BEFORE step
+        N's results are consumed, so host bookkeeping runs while the
+        device computes — for plain decode AND speculative verify (step
+        N+1's verify inputs are gathered on-device from step N's
+        variable accepted counts). Guided sequences ride the pipeline
+        host-paced per slot. Depth 0 (sync_engine) is the same loop
+        draining the step it just dispatched: every slot host-fed, no
+        late-stop discard, prefill split, drafts proposed from current
+        history. With `mixed_step_enabled` the due prefill chunks
+        (continuations first — they hold slots and blocks — then fresh
+        admissions) ride the dispatch FUSED with the decode or verify
+        rows; ineligible admissions (media / SP) and every admission of
+        an unfused iteration prefill through the split path inside
+        _admit (docs/ENGINE_PIPELINE.md + docs/KERNELS.md)."""
         phase = self._phases.phase
         # up to the first phase below: the loop's `housekeeping`
         if not self._running and self._inflight is None:
@@ -1184,42 +1173,33 @@ class InferenceEngine:
         self._drain_export_requests()
         self._drain_cancelled()
         self._maybe_flush_schema_rows()
-        if self._force_sync:
-            # Sync path (hatch / spec-pipeline degrade): flush the
-            # pipeline at the transition (_flush_pipeline_state drains
-            # the in-flight step and requeues mixed-held mid-prefill
-            # seqs into the split midchunk flow).
-            produced0 = self._flush_pipeline_state()
-            with phase("schedule"):
-                admitted = self._admit()
-            produced = self._decode_once()
-            return produced0 + admitted + produced
-        if self.cfg.speculative_tokens > 0:
-            # Pipelined speculative stepping: draft+verify as a
-            # pipelined unit, fused with due prefill chunks when the
-            # model family supports it (docs/ENGINE_PIPELINE.md).
-            return self._step_spec()
-        if self.mixed_step_enabled:
-            # Mixed (ragged) stepping: ONE dispatch carries the decode
-            # batch AND the due prefill chunks (docs/KERNELS.md).
-            return self._step_mixed()
-        produced0 = 0
-        if self._pf_active:
-            # Mode flip mid-prefill (mixed stepping turned off): drain
-            # the in-flight mixed step, requeue the held seqs.
-            produced0 = self._flush_pipeline_state()
+        sync = self._force_sync
+        fuse = self.mixed_step_enabled
+        produced = 0
+        if sync or (self._pf_active and not fuse):
+            # Transition (depth flipped to 0, or fusing turned off with
+            # seqs mid-prefill): drain the in-flight step and requeue
+            # the mixed-held seqs into the split midchunk flow.
+            produced = self._flush_pipeline_state()
+        items_meta: List[tuple] = []
         with phase("schedule"):
-            admitted = self._admit()
-        produced = self._step_overlap()
-        return produced0 + admitted + produced
-
-    @thread_owned("engine")
-    def _step_overlap(self) -> int:
-        """One pipeline iteration: dispatch decode step N+1 (fed from step
-        N's device-resident tokens), THEN drain/book step N while N+1 runs."""
-        with self._phases.phase("dispatch"):
-            nxt = self._dispatch_decode()
-        produced = self._drain_step(self._inflight, nxt)
+            if fuse:
+                budget = self._continue_pf_chunks(
+                    items_meta, self.cfg.max_prefill_tokens
+                )
+                produced += self._admit(
+                    mixed_collect=items_meta, budget=budget
+                )
+            else:
+                produced += self._admit()
+        with phase("dispatch"):
+            if self.cfg.speculative_tokens > 0:
+                nxt = self._dispatch_verify(items_meta)
+            else:
+                nxt = self._dispatch(items_meta)
+        if sync:
+            return produced + self._drain_step(nxt, None)
+        produced += self._drain_step(self._inflight, nxt)
         self._inflight = nxt
         return produced
 
@@ -1236,8 +1216,8 @@ class InferenceEngine:
         """Mode-transition flush: drain the in-flight step AND hand any
         mixed-held mid-prefill seqs back to the split midchunk flow —
         they keep slot + blocks and continue FIRST, like any split-mode
-        mid-chunk seq. One implementation for every transition (sync
-        hatch, mixed-off flip, spec fuse-support flip)."""
+        mid-chunk seq. One implementation for every transition (depth
+        flipped to 0, mixed-off flip, spec fuse-support flip)."""
         produced = self._flush_inflight()
         if self._pf_active:
             with self._lock:
@@ -1248,28 +1228,6 @@ class InferenceEngine:
         return produced
 
     # ------------------------------------------------ mixed (ragged) step
-
-    @thread_owned("engine")
-    def _step_mixed(self) -> int:
-        """One mixed-pipeline iteration: cut the due prefill chunks
-        (continuations first — they hold slots and blocks — then fresh
-        admissions), dispatch them FUSED with decode step N+1, then
-        drain/book step N while N+1 runs. Ineligible admissions (media /
-        guided / SP) prefill through the split path in the same
-        iteration; the overlap contract (device-resident decode
-        feedback, one-step-late stops) is unchanged
-        (docs/ENGINE_PIPELINE.md + docs/KERNELS.md)."""
-        items_meta: List[tuple] = []
-        with self._phases.phase("schedule"):
-            budget = self._continue_pf_chunks(
-                items_meta, self.cfg.max_prefill_tokens
-            )
-            legacy = self._admit(mixed_collect=items_meta, budget=budget)
-        with self._phases.phase("dispatch"):
-            nxt = self._dispatch_mixed(items_meta)
-        produced = self._drain_step(self._inflight, nxt)
-        self._inflight = nxt
-        return legacy + produced
 
     @thread_owned("engine")
     def _continue_pf_chunks(self, items_meta: List[tuple],
@@ -1321,7 +1279,7 @@ class InferenceEngine:
     @thread_owned("engine")
     def _build_pf_items(self, items_meta: List[tuple], t0: float):
         """PrefillItems + drain entries for the due chunks riding a
-        fused dispatch (shared by _dispatch_mixed and _dispatch_verify).
+        fused dispatch (shared by _dispatch and _dispatch_verify).
         Guided seqs' FINAL chunks carry their host-derived mask row —
         exact at dispatch, because a mid-prefill seq has no decode step
         in flight (its automaton state is host truth)."""
@@ -1428,13 +1386,22 @@ class InferenceEngine:
         return rows
 
     @thread_owned("engine")
-    def _dispatch_mixed(self, items_meta: List[tuple]) -> Optional[_InFlight]:
-        """Dispatch decode step N+1 fused with the due prefill chunks as
-        ONE device step (executor.mixed_start). With no due chunks this
-        is exactly _dispatch_decode — the fused shapes only compile when
-        a mixed batch actually exists."""
-        if not items_meta:
-            return self._dispatch_decode()
+    def _dispatch(self, items_meta: List[tuple]) -> Optional[_InFlight]:
+        """Dispatch the next decode step, returning its in-flight record:
+        fused with the due prefill chunks as ONE device step
+        (executor.mixed_start) when `items_meta` holds any — the fused
+        shapes only compile when a mixed batch actually exists — else a
+        plain decode step (executor.decode_start; None when no row can
+        run). Continuing slots feed from the PREVIOUS step's
+        device-resident sampled tokens — the autoregressive feedback
+        never round-trips the host. Freshly admitted/resumed slots, and
+        every slot at depth 0, feed from the host array.
+        Length-predictable stops (max_new_tokens / max_seq_len) are
+        excluded up front; the token-dependent ones (EOS / stop ids)
+        surface at drain, one step late at depth 1, and cost exactly one
+        discarded sample."""
+        if not items_meta and not self._running:
+            return None
         can = (
             self._ps_active
             & (self._ps_gen_count + self._ps_pending < self._ps_max_new)
@@ -1447,31 +1414,67 @@ class InferenceEngine:
         if can.any():
             self._ensure_decode_capacity(1, mask=can)
             can &= self._ps_active  # the capacity pass may have preempted
+        if not items_meta and not can.any():
+            return None
         batch = self._sampling_batch_view()
         rows = self._guided_mask_rows(can)
         if rows is not None:
             batch.mask_rows = rows
             self.guided_ingraph_steps += 1
         prev = self._inflight
+        # Non-dispatched rows read the (defined) host value; dispatched
+        # rows read the device feedback unless freshly (re)admitted.
         fresh_mask = self._fresh | ~can
+        # Invariant: a non-fresh dispatched slot's feed lives in the
+        # in-flight step — with no in-flight step every slot is host-fed.
         assert prev is not None or bool(fresh_mask[can].all())
         self._observe_host_gap()
         t0 = time.monotonic()
         items, pf_entries = self._build_pf_items(items_meta, t0)
         prev_tokens = prev.feed if prev is not None else None
+        feed = None
         # annotate=False: the executor's leaf annotations stay leaves
         with self._phases.phase("dispatch", annotate=False):
-            tokens, logprobs, feed = self.executor.mixed_start(
-                items,
-                self._ps_last_tok,
-                fresh_mask,
-                prev_tokens,
-                self._ps_positions,
-                self._block_tables,
-                can,
-                batch,
-                interpret=self._ragged_interpret,
-            )
+            if items:
+                tokens, logprobs, feed = self.executor.mixed_start(
+                    items,
+                    self._ps_last_tok,
+                    fresh_mask,
+                    prev_tokens,
+                    self._ps_positions,
+                    self._block_tables,
+                    can,
+                    batch,
+                    interpret=self._ragged_interpret,
+                )
+            else:
+                tokens, logprobs = self.executor.decode_start(
+                    self._ps_last_tok,
+                    fresh_mask,
+                    prev_tokens,
+                    self._ps_positions,
+                    self._block_tables,
+                    can,
+                    batch,
+                )
+        snapshot, nactive, total_ctx = self._snapshot_dispatch(
+            can, len(items), "mixed" if items else "decode"
+        )
+        self._ps_positions[can] += 1
+        self._ps_steps[can] += 1
+        return _InFlight(
+            tokens, logprobs, snapshot, t0, nactive, total_ctx,
+            pf=pf_entries, feed=feed,
+        )
+
+    @thread_owned("engine")
+    def _snapshot_dispatch(self, can: np.ndarray, n_pf: int, kernel: str):
+        """What every dispatch (decode, mixed, verify) books once its
+        step is launched: the slot -> (seq, admit_gen) snapshot its drain
+        checks occupancy against, the pending/fresh advance and the
+        counters. Returns (snapshot, nactive, total_ctx); positions and
+        step counts are the caller's (a verify step's advance is
+        variable and re-derived at drain)."""
         nactive = int(can.sum())
         total_ctx = int(self._ps_positions[can].sum()) + nactive
         snapshot = {}
@@ -1479,25 +1482,21 @@ class InferenceEngine:
             seq = self._running[int(slot)]
             snapshot[int(slot)] = (seq, seq.admit_gen)
         self._ps_pending[can] += 1
-        self._ps_positions[can] += 1
-        self._ps_steps[can] += 1
         self._fresh[can] = False
         self._observe_batch(nactive)
         self._m_steps.inc()
         self.decode_dispatches += 1
         self.collective_overlap_steps += self._overlap_collectives
-        self.mixed_steps += 1
-        self._m_mixed_pf_rows.observe(len(items))
-        self._m_mixed_dec_rows.observe(nactive)
+        if n_pf:
+            self.mixed_steps += 1
+            self._m_mixed_pf_rows.observe(n_pf)
+            self._m_mixed_dec_rows.observe(nactive)
         self._m_kernel_dispatch.labels(
-            kernel=self._kernel_names["mixed"]
+            kernel=self._kernel_names[kernel]
         ).inc()
-        if prev is not None:
+        if self._inflight is not None:
             self.overlap_steps += 1
-        return _InFlight(
-            tokens, logprobs, snapshot, t0, nactive, total_ctx,
-            pf=pf_entries, feed=feed,
-        )
+        return snapshot, nactive, total_ctx
 
     # ------------------------------------------------------------ admission
 
@@ -1557,8 +1556,8 @@ class InferenceEngine:
         no guided mask, no SP-ring routing) are appended there (and
         registered in _pf_active) instead of prefilling here; their
         chunks ride the SAME dispatch as the decode batch
-        (_dispatch_mixed). Ineligible requests keep the split prefill
-        path below, in the same iteration."""
+        (_dispatch / _dispatch_verify). Ineligible requests keep the
+        split prefill path below, in the same iteration."""
         if budget is None:
             budget = self.cfg.max_prefill_tokens
         pool_capacity = self.block_mgr.num_blocks - 1
@@ -2880,219 +2879,79 @@ class InferenceEngine:
 
     def _observe_host_gap(self) -> None:
         """Record the host-bookkeeping gap between the previous step's
-        drain and this dispatch — the window sync mode spends with the
-        device idle, and overlap mode hides behind the in-flight step."""
+        drain and this dispatch — the window depth 0 spends with the
+        device idle, and depth 1 hides behind the in-flight step."""
         if self._t_host_free is not None:
             gap = (time.monotonic() - self._t_host_free) * 1000
             self._m_host_gap.observe(gap)
             self.host_gap_ms_sum += gap
             self.host_gap_steps += 1
 
-    @thread_owned("engine")
-    def _decode_once(self) -> int:
-        if self.cfg.speculative_tokens > 0:
-            return self._decode_spec_once()
-        if not self._running:
-            return 0
-        phase = self._phases.phase
-        with phase("dispatch"):
-            self._ensure_decode_capacity(1)
-            if not self._running:
-                return 0
-
-            active = self._ps_active.copy()
-            batch = self._sampling_batch_view()
-            if self._guided_tokens is not None and self._guided_slots:
-                rows = np.full(
-                    (self.R,), self.executor.permissive_row, np.int32
-                )
-                for slot, seq in self._running.items():
-                    rows[slot] = self._guided_row(seq)
-                batch.mask_rows = rows
-
-            self._observe_host_gap()
-            t0 = time.monotonic()
-            # annotate=False: the executor's leaf annotations stay leaves
-            # (its blocking read enters `device_wait`: fetch_scope)
-            with phase("dispatch", annotate=False):
-                tokens, logprobs = self.executor.decode(
-                    self._ps_last_tok,
-                    self._ps_positions,
-                    self._block_tables,
-                    active,
-                    batch,
-                )
-            self._m_kernel_dispatch.labels(
-                kernel=self._kernel_names["decode"]
-            ).inc()
-            step_ms = (time.monotonic() - t0) * 1000
-            nactive = int(active.sum())
-            total_ctx = int(self._ps_positions[active].sum()) + nactive
-            self._profile_step(nactive, total_ctx, step_ms)
-            self._observe_batch(nactive)
-            self._m_steps.inc()
-            self.decode_dispatches += 1
-            self.collective_overlap_steps += self._overlap_collectives
-            self._ps_steps[active] += 1
-            self._ps_positions[active] += 1
-
-        with phase("emit"):
-            produced = 0
-            worst_tbt = 0.0
-            now = time.monotonic()
-            for slot in list(self._running.keys()):
-                seq = self._running[slot]
-                tok, lp = int(tokens[slot]), float(logprobs[slot])
-                tbt_ms = (now - seq.last_token_time) * 1000
-                worst_tbt = max(worst_tbt, tbt_ms)
-                self._m_tbt.observe(tbt_ms)
-                seq.last_token_time = now
-                seq.generated.append((tok, lp))
-                seq.tokens.append(tok)
-                self._ps_last_tok[slot] = tok
-                self._ps_gen_count[slot] += 1
-                self._ps_tok_count[slot] += 1
-                self._fresh[slot] = True
-                self._commit_full_blocks(seq)
-                produced += 1
-                self._emit(seq, finished=self._check_stop(seq))
-            if produced:
-                self._window_append(self._tbt_window, now, worst_tbt)
-            self._t_host_free = time.monotonic()
-        return produced
-
-    # ------------------------------------------------ overlapped pipeline
-
-    @thread_owned("engine")
-    def _dispatch_decode(self) -> Optional[_InFlight]:
-        """Dispatch the next overlapped decode step, returning its in-flight
-        record (None when nothing is dispatchable). Continuing slots feed
-        from the PREVIOUS step's device-resident sampled tokens — the
-        autoregressive feedback never round-trips the host. Freshly
-        admitted/resumed slots feed from the host array. Length-predictable
-        stops (max_new_tokens / max_seq_len) are excluded up front; the
-        token-dependent ones (EOS / stop ids) surface at drain, one step
-        late, and cost exactly one discarded sample."""
-        if not self._running:
-            return None
-        can = (
-            self._ps_active
-            & (self._ps_gen_count + self._ps_pending < self._ps_max_new)
-            & (
-                self._ps_tok_count + self._ps_pending
-                < self.cfg.max_seq_len
-            )
-        )
-        can = self._apply_guided_pacing(can)
-        if not can.any():
-            return None
-        self._ensure_decode_capacity(1, mask=can)
-        can &= self._ps_active  # the capacity pass may have preempted
-        if not can.any():
-            return None
-        batch = self._sampling_batch_view()
-        rows = self._guided_mask_rows(can)
-        if rows is not None:
-            batch.mask_rows = rows
-            self.guided_ingraph_steps += 1
-        prev = self._inflight
-        # Non-dispatched rows read the (defined) host value; dispatched
-        # rows read the device feedback unless freshly (re)admitted.
-        fresh_mask = self._fresh | ~can
-        # Invariant: a non-fresh dispatched slot's feed lives in the
-        # in-flight step — with no in-flight step every slot is host-fed.
-        assert prev is not None or bool(fresh_mask[can].all())
-        self._observe_host_gap()
-        t0 = time.monotonic()
-        # annotate=False: the executor's leaf annotations stay leaves
-        with self._phases.phase("dispatch", annotate=False):
-            tokens, logprobs = self.executor.decode_start(
-                self._ps_last_tok,
-                fresh_mask,
-                prev.feed if prev is not None else None,
-                self._ps_positions,
-                self._block_tables,
-                can,
-                batch,
-            )
-        self._m_kernel_dispatch.labels(
-            kernel=self._kernel_names["decode"]
-        ).inc()
-        nactive = int(can.sum())
-        total_ctx = int(self._ps_positions[can].sum()) + nactive
-        snapshot = {}
-        for slot in np.nonzero(can)[0]:
-            seq = self._running[int(slot)]
-            snapshot[int(slot)] = (seq, seq.admit_gen)
-        self._ps_pending[can] += 1
-        self._ps_positions[can] += 1
-        self._ps_steps[can] += 1
-        self._fresh[can] = False
-        self._observe_batch(nactive)
-        self._m_steps.inc()
-        self.decode_dispatches += 1
-        self.collective_overlap_steps += self._overlap_collectives
-        if prev is not None:
-            self.overlap_steps += 1
-        return _InFlight(tokens, logprobs, snapshot, t0, nactive, total_ctx)
+    # ------------------------------------------------------------- drain
 
     @thread_owned("engine")
     def _drain_step(
         self, flt: Optional[_InFlight], newer: Optional[_InFlight]
     ) -> int:
         """Consume one in-flight step's results (blocks until the device
-        finishes it — while `newer`, if any, already executes behind it).
-        Per-token emit, tracer windows, block commits, and stop checks all
-        live here, off the dispatch path. Late tokens for sequences no
-        longer running are discarded; surviving slots not covered by a
-        newer dispatch return to host feeding."""
+        finishes it — while `newer`, if any, already executes behind it;
+        at depth 0 `flt` is the step just dispatched and `newer` is
+        None). Per-token emit, tracer windows, block commits, and stop
+        checks all live here, off the dispatch path. Late tokens for
+        sequences no longer running are discarded; surviving slots not
+        covered by a newer dispatch return to host feeding."""
         if flt is None:
             return 0
-        if flt.n_emit is not None:
-            return self._drain_spec(flt, newer)
+        n_emit = None
         with self._phases.phase("device_wait"):
             tokens = np.asarray(flt.tokens)
             logprobs = np.asarray(flt.logprobs)
+            if flt.n_emit is not None:
+                n_emit = np.asarray(flt.n_emit)
         with self._phases.phase("emit"):
-            return self._book_step(flt, newer, tokens, logprobs)
+            return self._book_step(flt, newer, tokens, logprobs, n_emit)
 
     @thread_owned("engine")
     def _book_step(self, flt: _InFlight, newer: Optional[_InFlight],
-                   tokens: np.ndarray, logprobs: np.ndarray) -> int:
-        """_drain_step's host half, once the results are on the host."""
+                   tokens: np.ndarray, logprobs: np.ndarray,
+                   n_emit: Optional[np.ndarray]) -> int:
+        """_drain_step's host half, once the results are on the host. A
+        plain decode step emits one token a surviving slot (`tokens` [R]
+        or, mixed, [R + P]); a verify step (`n_emit` given, `tokens`
+        [R, S]) its accepted prefix + the corrected/bonus token, 1..S."""
         step_ms = (time.monotonic() - flt.t0) * 1000
         self._profile_step(flt.nactive, flt.total_ctx, step_ms)
+        spec = n_emit is not None
+        toks, lps = tokens.tolist(), logprobs.tolist()
         produced = 0
-        worst_tbt = 0.0
+        worst_tbt = None
         now = time.monotonic()
         for slot, (seq, gen) in flt.slots.items():
             if self._running.get(slot) is not seq or seq.admit_gen != gen:
                 # The seq stopped/cancelled/was preempted after dispatch
                 # (admit_gen also catches a preempt + re-admission of the
-                # SAME seq into the SAME slot): one-step-late stop —
-                # exactly one over-produced sample to drop (a preempted
-                # seq re-samples it deterministically on resume; same
-                # (seed, step) key, same context).
+                # SAME seq into the SAME slot): one-step-late stop — the
+                # slot's WHOLE row of over-produced samples is dropped (a
+                # preempted seq re-samples it deterministically on
+                # resume; same (seed, step) key, same context).
                 self.late_stop_discards += 1
                 continue
             self._ps_pending[slot] -= 1
-            tok, lp = int(tokens[slot]), float(logprobs[slot])
-            tbt_ms = (now - seq.last_token_time) * 1000
-            worst_tbt = max(worst_tbt, tbt_ms)
-            self._m_tbt.observe(tbt_ms)
-            seq.last_token_time = now
-            seq.generated.append((tok, lp))
-            seq.tokens.append(tok)
-            self._ps_last_tok[slot] = tok
-            self._ps_gen_count[slot] += 1
-            self._ps_tok_count[slot] += 1
-            ent = newer.slots.get(slot) if newer is not None else None
-            if ent is None or ent[0] is not seq or ent[1] != gen:
-                self._fresh[slot] = True
-            self._commit_full_blocks(seq)
-            produced += 1
-            self._emit(seq, finished=self._check_stop(seq))
-        if produced:
+            if spec:
+                n = int(n_emit[slot])
+                self._m_spec_accepted.observe(n)
+                self.spec_tokens_emitted += n
+                row = zip(toks[slot][:n], lps[slot][:n])
+            else:
+                n = 1
+                row = ((toks[slot], lps[slot]),)
+            if n:
+                tbt_ms = (now - seq.last_token_time) * 1000
+                worst_tbt = max(worst_tbt or 0.0, tbt_ms)
+                self._m_tbt.observe(tbt_ms)
+                seq.last_token_time = now
+            produced += self._book_row(slot, seq, gen, newer, row, spec)
+        if worst_tbt is not None:
             self._window_append(self._tbt_window, now, worst_tbt)
         produced += self._drain_pf_rows(flt, tokens, logprobs)
         if self.span_hook is not None and produced:
@@ -3105,6 +2964,38 @@ class InferenceEngine:
             )
         self._t_host_free = time.monotonic()
         return produced
+
+    @thread_owned("engine")
+    def _book_row(self, slot: int, seq: _Seq, gen: int,
+                  newer: Optional[_InFlight], row, spec: bool) -> int:
+        """THE booking of one surviving slot's emitted (token, logprob)
+        pairs (one for a plain decode step): history appends, block
+        commits, the stop check and the callback per token — a finish
+        or a cancel drops the rest of the row — then the slot's host
+        dispatch state. A slot that a newer dispatch does not cover
+        returns to host feeding. Returns the tokens emitted."""
+        emitted = 0
+        for tok, lp in row:
+            seq.generated.append((tok, lp))
+            seq.tokens.append(tok)
+            self._commit_full_blocks(seq)
+            emitted += 1
+            if not self._emit(seq, finished=self._check_stop(seq)):
+                return emitted  # finished or cancelled: slot cleared
+        if spec:
+            # Variable emission: positions and step counts re-derive
+            # from token truth (the device adds the in-flight step's
+            # accepted count itself); a plain step's advanced by one at
+            # dispatch and stand.
+            self._refresh_slot_arrays(slot, seq)
+        else:
+            self._ps_last_tok[slot] = tok
+            self._ps_gen_count[slot] += 1
+            self._ps_tok_count[slot] += 1
+        ent = newer.slots.get(slot) if newer is not None else None
+        if ent is None or ent[0] is not seq or ent[1] != gen:
+            self._fresh[slot] = True
+        return emitted
 
     @thread_owned("engine")
     def _drain_pf_rows(self, flt: _InFlight, tokens, logprobs) -> int:
@@ -3633,76 +3524,39 @@ class InferenceEngine:
         return np.full((k,), toks[-1], np.int32)
 
     @thread_owned("engine")
-    def _step_spec(self) -> int:
-        """One pipelined speculative iteration (docs/ENGINE_PIPELINE.md):
-        cut the due prefill chunks, dispatch verify step N+1 fused with
-        them (the composed path: verify rows are q_len = k+1 ragged rows
-        next to the chunks — docs/KERNELS.md), then drain/book step N
-        while N+1 runs. Step N+1's verify inputs — last accepted token,
-        position and step base — are gathered ON DEVICE from step N's
-        output, so the VARIABLE accepted count never round-trips the
-        host; the host proposes drafts from its one-step-late history,
-        which is sound because point-mass acceptance makes the emitted
-        stream draft-independent (ops/sampling.py)."""
-        items_meta: List[tuple] = []
-        produced0 = 0
-        fuse = self.mixed_step_enabled and getattr(
-            self.executor, "supports_spec_mixed", False
-        )
-        phase = self._phases.phase
-        if fuse:
-            with phase("schedule"):
-                budget = self._continue_pf_chunks(
-                    items_meta, self.cfg.max_prefill_tokens
-                )
-                legacy = self._admit(
-                    mixed_collect=items_meta, budget=budget
-                )
-        else:
-            if self._pf_active:
-                # Mixed support flipped off mid-run: drain and hand the
-                # held seqs to the split midchunk flow.
-                produced0 = self._flush_pipeline_state()
-            with phase("schedule"):
-                legacy = self._admit()
-        with phase("dispatch"):
-            nxt = self._dispatch_verify(items_meta)
-        produced = self._drain_step(self._inflight, nxt)
-        self._inflight = nxt
-        return produced0 + legacy + produced
-
-    @thread_owned("engine")
     def _dispatch_verify(
         self, items_meta: List[tuple]
     ) -> Optional[_InFlight]:
-        """Dispatch speculative verify step N+1 without fetching results
-        (executor.verify_start), optionally fused with due prefill
-        chunks. Guided slots join host-paced (exact automaton state at
-        dispatch — their drafts AND mask rows derive from fully drained
-        history); length-stops surface one step late as discards, and
-        the capacity pass covers TWO steps of worst-case emission
-        because the in-flight step may advance a slot by up to S before
-        this dispatch's writes land."""
+        """Dispatch the next speculative verify step without fetching
+        results (executor.verify_start), fused with the due prefill
+        chunks when `items_meta` holds any (the composed path: verify
+        rows are q_len = k+1 ragged rows next to the chunks —
+        docs/KERNELS.md). The step's verify inputs — last accepted
+        token, position and step base — are gathered ON DEVICE from the
+        in-flight step's output, so the VARIABLE accepted count never
+        round-trips the host; the host proposes drafts from its
+        one-step-late history (current history at depth 0), which is
+        sound because point-mass acceptance makes the emitted stream
+        draft-independent (ops/sampling.py). Guided slots join
+        host-paced (exact automaton state at dispatch — their drafts
+        AND mask rows derive from fully drained history); length-stops
+        surface one step late as discards, and with a step in flight
+        the capacity pass covers TWO steps of worst-case emission,
+        because that step may advance a slot by up to S before this
+        dispatch's writes land."""
         k = self.cfg.speculative_tokens
         S = k + 1
         R = self.R
         can = self._apply_guided_pacing(self._ps_active.copy())
-        # Host-fed slots re-derive their dispatch state from token truth
-        # BEFORE the capacity pass reads positions: the sync verify path
-        # refreshes lazily at the start of its own next step, so a
-        # sync->pipeline hatch flip would otherwise dispatch from arrays
-        # that lag the last sync step's variable emissions.
-        for slot in np.nonzero(can & self._fresh)[0]:
-            seq = self._running.get(int(slot))
-            if seq is not None:
-                self._refresh_slot_arrays(int(slot), seq)
+        prev = self._inflight
         if can.any():
-            self._ensure_decode_capacity(2 * S, mask=can)
+            self._ensure_decode_capacity(
+                S if prev is None else 2 * S, mask=can
+            )
             can &= self._ps_active  # the capacity pass may have preempted
         if not can.any() and not items_meta:
             return None
         batch = self._sampling_batch_view()
-        prev = self._inflight
         fresh_mask = self._fresh | ~can
         assert prev is not None or bool(fresh_mask[can].all())
         drafts = np.zeros((R, k), np.int32)
@@ -3744,205 +3598,20 @@ class InferenceEngine:
                     interpret=self._ragged_interpret,
                 )
             )
-        nactive = int(can.sum())
-        total_ctx = int(self._ps_positions[can].sum()) + nactive
-        snapshot = {}
-        for slot in np.nonzero(can)[0]:
-            seq = self._running[int(slot)]
-            snapshot[int(slot)] = (seq, seq.admit_gen)
-        self._ps_pending[can] += 1
-        self._fresh[can] = False
-        self._observe_batch(nactive)
-        self._m_steps.inc()
-        self.decode_dispatches += 1
-        self.collective_overlap_steps += self._overlap_collectives
+        snapshot, nactive, total_ctx = self._snapshot_dispatch(
+            can, len(items), "mixed" if items else "mq"
+        )
         self.spec_steps += 1
         self.spec_slot_steps += nactive
-        self.spec_pipeline_steps += 1
-        if items:
-            self.mixed_steps += 1
-            self._m_mixed_pf_rows.observe(len(items))
-            self._m_mixed_dec_rows.observe(nactive)
-            self._m_kernel_dispatch.labels(
-                kernel=self._kernel_names["mixed"]
-            ).inc()
+        # a verify step drained at depth 0 is a sync step
+        if self._force_sync:
+            self.spec_sync_steps += 1
         else:
-            self._m_kernel_dispatch.labels(
-                kernel=self._kernel_names["mq"]
-            ).inc()
-        if prev is not None:
-            self.overlap_steps += 1
+            self.spec_pipeline_steps += 1
         return _InFlight(
             tokens, logprobs, snapshot, t0, nactive, total_ctx,
             pf=pf_entries, n_emit=n_emit, pf_tok=pf_tok, pf_lp=pf_lp,
         )
-
-    @thread_owned("engine")
-    def _drain_spec(
-        self, flt: _InFlight, newer: Optional[_InFlight]
-    ) -> int:
-        """Consume one pipelined verify step's results — the speculative
-        twin of _drain_step's decode booking: each surviving slot emits
-        its accepted prefix + the corrected/bonus token (1..S tokens,
-        exactly _decode_spec_once's host loop), one step late. A slot
-        that stopped/cancelled/was preempted after dispatch discards
-        the WHOLE row (the one-step-late stop contract, scaled to
-        variable emission); surviving slots re-derive their host
-        dispatch state from token truth — incremental +1 advances
-        cannot track variable accepted counts."""
-        with self._phases.phase("device_wait"):
-            tokens = np.asarray(flt.tokens)
-            logprobs = np.asarray(flt.logprobs)
-            n_emit = np.asarray(flt.n_emit)
-        with self._phases.phase("emit"):
-            return self._book_spec(flt, newer, tokens, logprobs, n_emit)
-
-    @thread_owned("engine")
-    def _book_spec(self, flt: _InFlight, newer: Optional[_InFlight],
-                   tokens: np.ndarray, logprobs: np.ndarray,
-                   n_emit: np.ndarray) -> int:
-        """_drain_spec's host half, once the results are on the host."""
-        step_ms = (time.monotonic() - flt.t0) * 1000
-        self._profile_step(flt.nactive, flt.total_ctx, step_ms)
-        produced = 0
-        worst_tbt = 0.0
-        now = time.monotonic()
-        for slot, (seq, gen) in flt.slots.items():
-            if self._running.get(slot) is not seq or seq.admit_gen != gen:
-                self.late_stop_discards += 1
-                continue
-            self._ps_pending[slot] -= 1
-            ne = int(n_emit[slot])
-            self._m_spec_accepted.observe(ne)
-            self.spec_tokens_emitted += ne
-            if ne:
-                tbt_ms = (now - seq.last_token_time) * 1000
-                worst_tbt = max(worst_tbt, tbt_ms)
-                self._m_tbt.observe(tbt_ms)
-                seq.last_token_time = now
-            alive = True
-            for i in range(ne):
-                tok, lp = int(tokens[slot, i]), float(logprobs[slot, i])
-                seq.generated.append((tok, lp))
-                seq.tokens.append(tok)
-                self._commit_full_blocks(seq)
-                produced += 1
-                if not self._emit(seq, finished=self._check_stop(seq)):
-                    alive = False  # finished/cancelled: drop the rest
-                    break
-            if alive and self._running.get(slot) is seq:
-                self._refresh_slot_arrays(slot, seq)
-                ent = newer.slots.get(slot) if newer is not None else None
-                if ent is None or ent[0] is not seq or ent[1] != gen:
-                    self._fresh[slot] = True
-        if worst_tbt:
-            self._window_append(self._tbt_window, now, worst_tbt)
-        produced += self._drain_pf_rows(flt, tokens, logprobs)
-        self._t_host_free = time.monotonic()
-        return produced
-
-    @thread_owned("engine")
-    def _decode_spec_once(self) -> int:
-        """Speculative variant of _decode_once: feed [last_token, k drafts]
-        per sequence, verify in one pass, emit the accepted prefix + one
-        corrected/bonus token. Identical output stream to the plain path
-        (see EngineConfig.speculative_tokens), 1..k+1 tokens per step."""
-        if not self._running:
-            return 0
-        phase = self._phases.phase
-        with phase("dispatch"):
-            launched = self._launch_spec_sync()
-        if launched is None:
-            return 0
-        tokens, logprobs, n_emit = launched
-        with phase("emit"):
-            produced = 0
-            worst_tbt = 0.0
-            now = time.monotonic()
-            for slot in list(self._running.keys()):
-                seq = self._running[slot]
-                tbt_ms = (now - seq.last_token_time) * 1000
-                worst_tbt = max(worst_tbt, tbt_ms)
-                self._m_tbt.observe(tbt_ms)
-                seq.last_token_time = now
-                for i in range(int(n_emit[slot])):
-                    tok, lp = int(tokens[slot, i]), float(logprobs[slot, i])
-                    seq.generated.append((tok, lp))
-                    seq.tokens.append(tok)
-                    self._commit_full_blocks(seq)
-                    produced += 1
-                    if not self._emit(seq, finished=self._check_stop(seq)):
-                        break  # finished or cancelled: drop the rest
-            self._window_append(self._tbt_window, now, worst_tbt)
-        return produced
-
-    @thread_owned("engine")
-    def _launch_spec_sync(self):
-        """_decode_spec_once's dispatch half: (tokens, logprobs, n_emit)
-        on the host, or None when the capacity pass emptied the batch."""
-        k = self.cfg.speculative_tokens
-        S = k + 1
-        max_len = self.cfg.max_seq_len
-        # Variable emission counts: re-derive dispatch state from host
-        # truth before the capacity pass reads the position array.
-        for slot, seq in self._running.items():
-            self._refresh_slot_arrays(slot, seq)
-        self._ensure_decode_capacity(S)
-        if not self._running:
-            return None
-
-        token_ids = np.zeros((self.R, S), np.int32)
-        positions = np.zeros((self.R,), np.int32)
-        true_len = np.zeros((self.R,), np.int32)
-        active = np.zeros((self.R,), bool)
-        batch = self._sampling_batch_view()
-        for slot, seq in self._running.items():
-            pos = len(seq.tokens) - 1
-            token_ids[slot, 0] = seq.tokens[-1]
-            token_ids[slot, 1:] = self._propose_drafts(seq, k)
-            positions[slot] = pos
-            true_len[slot] = max(1, min(S, max_len - pos))
-            active[slot] = True
-        if self._guided_tokens is not None and any(
-            s.req.guided for s in self._running.values()
-        ):
-            rows = np.full(
-                (self.R, S), self.executor.permissive_row, np.int32
-            )
-            for slot, seq in self._running.items():
-                rows[slot] = self._guided_rows_spec(
-                    seq, token_ids[slot, 1:], S
-                )
-            batch.mask_rows = rows
-
-        t0 = time.monotonic()
-        self._m_kernel_dispatch.labels(
-            kernel=self._kernel_names["mq"]
-        ).inc()
-        # annotate=False: the executor's leaf annotations stay leaves
-        # (its blocking read enters `device_wait`: fetch_scope)
-        with self._phases.phase("dispatch", annotate=False):
-            tokens, logprobs, n_emit = self.executor.verify(
-                token_ids,
-                positions,
-                true_len,
-                self._block_tables,
-                active,
-                batch,
-            )
-        step_ms = (time.monotonic() - t0) * 1000
-        nactive = int(active.sum())
-        total_ctx = int(positions[active].sum()) + nactive
-        self._profile_step(nactive, total_ctx, step_ms)
-        self._observe_batch(nactive)
-        self._m_steps.inc()
-        self.decode_dispatches += 1
-        self.collective_overlap_steps += self._overlap_collectives
-        self.spec_steps += 1
-        self.spec_sync_steps += 1
-        self.spec_slot_steps += nactive
-        self.spec_tokens_emitted += int(n_emit[active].sum())
-        return tokens, logprobs, n_emit
 
     # ---------------------------------------------------------- preemption
 

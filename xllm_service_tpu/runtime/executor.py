@@ -240,6 +240,25 @@ def unpack_rows(pack, fields):
     return cols, pack[:, len(fields):]
 
 
+def fuses_prefill(engine_cfg: EngineConfig, executor) -> bool:
+    """Whether the due prefill chunks ride the step dispatch of an engine
+    built from `engine_cfg` over `executor` (mixed_start, or the fused
+    half of verify_start on a speculative engine) instead of the split
+    prefill programs. THE decision: the engine's step() reads it every
+    iteration and prewarm_programs walks the fused families by it. Depth
+    0 (sync_engine) never fuses; a family without mixed_step (MLA), or
+    without mixed_verify_step on a speculative engine, runs split."""
+    return bool(
+        not engine_cfg.sync_engine
+        and engine_cfg.enable_mixed_step
+        and getattr(executor, "supports_mixed", False)
+        and (
+            engine_cfg.speculative_tokens == 0
+            or getattr(executor, "supports_spec_mixed", False)
+        )
+    )
+
+
 class ModelExecutor:
     # guided decoding: index of the appended all-True row once
     # set_guided_table runs; a safe default for unguided paths
@@ -1101,120 +1120,7 @@ class ModelExecutor:
         )
         return k_cache, v_cache, tokens, logprob
 
-    def _verify_impl(
-        self,
-        k_cache,
-        v_cache,
-        counts,  # [R, V] int32 (donated)
-        params,
-        token_ids,  # [R, S] — last accepted token then S-1 draft tokens
-        start_pos,  # [R] — position of the first fed token
-        true_len,  # [R] — fed tokens this row may write/emit (0 = inactive)
-        block_tables,  # [R, CB]
-        temperature,
-        top_k,
-        top_p,
-        seeds,  # [R] uint32
-        steps,  # [R] int32 — generated count before this step
-        active,  # [R] bool
-        presence,
-        frequency,
-        bias_ids=None,
-        bias_vals=None,
-        mask_rows=None,  # [R, S] rows into guided_table
-        guided_table=None,
-        lora_idx=None,  # [R] adapter rows (0 = base)
-        min_p=None,  # [R]
-        rope_delta=None,  # [R] M-RoPE position lag (<= 0)
-    ):
-        """Speculative-decoding verify step: one forward pass over S
-        positions per sequence (the prefill machinery with `all_logits`),
-        then point-mass speculative acceptance (ops/sampling.py). KV rows
-        for ALL S positions are written; rows past the accepted prefix are
-        stale garbage that attention can never read (masked by seq_lens)
-        and the next step overwrites."""
-        step_keys = self._verify_keys(seeds, steps, token_ids.shape[1])
-        step_kwargs = (
-            {"lora_idx": lora_idx} if lora_idx is not None else {}
-        )
-        if rope_delta is not None:
-            # generation positions have equal (t, h, w) streams; only the
-            # lag vs cache positions matters
-            S_ = token_ids.shape[1]
-            base = (start_pos + rope_delta)[:, None] + jnp.arange(
-                S_, dtype=jnp.int32
-            )[None]
-            step_kwargs["rope_positions"] = jnp.broadcast_to(
-                base[:, None, :], (base.shape[0], 3, S_)
-            )
-        logits, k_cache, v_cache = self.model_mod.prefill_batch_step(
-            params, self.cfg, k_cache, v_cache, token_ids, start_pos,
-            true_len, block_tables, all_logits=True, **step_kwargs,
-        )  # [R, S, V]
-        drafts = token_ids[:, 1:]
-        tokens, logprobs, n_emit, counts = sampling_ops.speculative_sample(
-            logits, drafts, temperature, top_k, top_p, step_keys,
-            limits=true_len, active=active,
-            counts=counts, presence=presence, frequency=frequency,
-            bias_ids=bias_ids, bias_vals=bias_vals,
-            allowed=(
-                guided_table[mask_rows] if mask_rows is not None else None
-            ),
-            min_p=min_p,
-        )
-        return k_cache, v_cache, counts, tokens, logprobs, n_emit
-
     # ---------------------------------------------------------- public API
-
-    def verify(
-        self,
-        token_ids: np.ndarray,  # [R, S]
-        positions: np.ndarray,  # [R] — position of the first fed token
-        true_len: np.ndarray,  # [R] — <= S; 0 for inactive rows
-        block_tables: np.ndarray,  # [R, max_blocks_per_seq]
-        active: np.ndarray,  # [R] bool
-        batch: SamplingBatch,
-    ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-        """Speculative decode step. Returns (tokens [R, S], logprobs [R, S],
-        n_emit [R]): each active row emits its first n_emit tokens (>= 1 —
-        a verify step subsumes a plain decode step)."""
-        self._set_shard_ctx()
-        if not hasattr(self, "_verify_jit"):
-            self._verify_jit = jax.jit(
-                self._verify_impl, donate_argnums=(0, 1, 2)
-            )
-        with _leaf("host_inputs"):
-            need = 1
-            if active.any():
-                last_pos = np.asarray(positions) + np.asarray(true_len) - 1
-                need = int(
-                    (last_pos[np.asarray(active)].max() // self.block_size)
-                    + 1
-                )
-            CB = self._pow2_bucket(need, self.max_blocks_per_seq)
-            opts = self._batch_opts(batch)
-            args = (
-                self._put(token_ids, np.int32),
-                self._put(positions, np.int32),
-                self._put(true_len, np.int32),
-                self._put(block_tables[:, :CB], np.int32),
-                self._put(batch.temperature, np.float32),
-                self._put(batch.top_k, np.int32),
-                self._put(batch.top_p, np.float32),
-                self._put(batch.seeds, np.uint32),
-                self._put(batch.steps, np.int32),
-                self._put(active),
-                *self._penalties(batch),
-            )
-        with _leaf("launch"):
-            (
-                self.k_cache, self.v_cache, self.token_counts,
-                tokens, logprobs, n_emit,
-            ) = self._verify_jit(
-                self.k_cache, self.v_cache, self.token_counts, self.params,
-                *args, **opts,
-            )
-        return self._fetch(tokens, logprobs, n_emit)
 
     def bucket_len(self, n: int) -> int:
         for b in self.prefill_buckets:
@@ -1479,19 +1385,17 @@ class ModelExecutor:
             )
 
         # Speculative verify shapes ([R, S] over the same pow2 CB buckets)
-        # when the engine runs speculative decoding.
+        # when the engine runs speculative decoding: verify_start's
+        # context bound covers two steps of worst-case emission.
         spec = self.engine_cfg.speculative_tokens
         if spec > 0:
             S = spec + 1
             for CB in self._decode_cb_walk():
                 positions = np.zeros((R,), np.int32)
-                positions[0] = max(CB * self.block_size - S, 0)
-                true_len = np.zeros((R,), np.int32)
-                true_len[0] = S
+                positions[0] = max(CB * self.block_size - 2 * S, 0)
                 self.verify(
                     np.zeros((R, S), np.int32),
                     positions,
-                    true_len,
                     np.zeros((R, self.max_blocks_per_seq), np.int32),
                     active,
                     batch,
@@ -1551,8 +1455,8 @@ class ModelExecutor:
     # Every jit entry point the serving loop can dispatch through —
     # lowering_count() sums their dispatch-cache sizes.
     _JIT_ATTRS = (
-        "_decode_jit", "_prefill_jit", "_import_jit", "_verify_jit",
-        "_sp_jit", "_mixed_jit", "_verify_pipe_jit", "_mixed_verify_jit",
+        "_decode_jit", "_prefill_jit", "_import_jit", "_sp_jit",
+        "_mixed_jit", "_verify_pipe_jit", "_mixed_verify_jit",
         "_seed_counts_jit", "_embed_jit",
     )
 
@@ -1587,27 +1491,6 @@ class ModelExecutor:
             or self.mesh.shape.get("ep", 1) > 1
         )
 
-    def _mixed_step_resolved(self) -> bool:
-        """The engine's mixed-step decision, replicated (XLLM_MIXED_STEP
-        over EngineConfig.enable_mixed_step, gated on family support) —
-        the prewarm must enumerate the builders the ENGINE will run."""
-        env = os.environ.get("XLLM_MIXED_STEP", "")
-        on = (
-            True if env == "1"
-            else False if env == "0"
-            else self.engine_cfg.enable_mixed_step
-        )
-        return bool(on and self.supports_mixed)
-
-    def _spec_pipeline_resolved(self) -> bool:
-        env = os.environ.get("XLLM_SPEC_PIPELINE", "")
-        on = (
-            True if env == "1"
-            else False if env == "0"
-            else self.engine_cfg.enable_spec_pipeline
-        )
-        return bool(on and getattr(self, "supports_spec_mixed", False))
-
     def prewarm_programs(
         self, p_groups: bool = True, guided: bool = False
     ) -> Dict[str, object]:
@@ -1615,10 +1498,11 @@ class ModelExecutor:
         dispatch — context buckets x step builders x spec variants —
         killing the first-post-idle-recompile class PR 11 measured at
         2.7-4 s/program (ISSUE 18 tentpole b). Beyond warmup()'s split
-        sync shapes (the overlap pipeline's decode steps are the same
-        programs: _feed) this walks the fused mixed prefill+decode
-        family (CBd x (Lpad, CBp)) and the pipelined verify /
-        mixed-verify programs when speculative decoding is configured.
+        shapes (decode and verify steps are the same programs at either
+        pipeline depth: _feed) this walks the fused family the engine
+        will dispatch (fuses_prefill): mixed prefill+decode (CBd x
+        (Lpad, CBp)), or mixed-verify when speculative decoding is
+        configured.
         With the persistent cache enabled every compile also lands on
         disk, so a warm restart replays this walk as disk reads.
 
@@ -1670,7 +1554,9 @@ class ModelExecutor:
                 for _ in range(count)
             ]
 
-        if self._mixed_step_resolved():
+        fuse = fuses_prefill(self.engine_cfg, self)
+        spec = self.engine_cfg.speculative_tokens
+        if fuse and not spec:
             n = 0
             for b, CBp, n_tok, sp in self._prefill_shape_family():
                 for Pn in p_walk:
@@ -1701,33 +1587,25 @@ class ModelExecutor:
             pw *= 2
         families["seed_counts"] = n
 
-        spec = self.engine_cfg.speculative_tokens
-        if spec > 0 and self._spec_pipeline_resolved():
+        if fuse and spec:
             S = spec + 1
             n = 0
             for CB in self._decode_cb_walk():
                 host_pos = np.zeros((R,), np.int32)
                 host_pos[0] = max(CB * self.block_size - 2 * S, 0)
-                args = (
-                    np.zeros((R, spec), np.int32),  # drafts
-                    np.zeros((R,), np.int32),  # host_last
-                    host_pos,
-                    np.zeros((R,), np.int32),  # host_steps
-                    np.ones((R,), bool),  # fresh_mask
-                    None, None,  # prev tokens/n_emit (device-nulled)
-                    tables, active, batch,
-                )
-                self.verify_start([], *args, interpret=interp)
-                n += 1
-                if self._mixed_step_resolved():
-                    for b, CBp, n_tok, sp in self._prefill_shape_family():
-                        for Pn in p_walk:
-                            self.verify_start(
-                                pf_items(n_tok, sp, Pn), *args,
-                                interpret=interp,
-                            )
-                            n += 1
-            families["verify_pipe"] = n
+                for b, CBp, n_tok, sp in self._prefill_shape_family():
+                    for Pn in p_walk:
+                        self.verify_start(
+                            pf_items(n_tok, sp, Pn),
+                            np.zeros((R, spec), np.int32),  # drafts
+                            np.zeros((R,), np.int32),  # host_last
+                            host_pos,
+                            np.zeros((R,), np.int32),  # host_steps
+                            None, None, None,  # every row host-fed
+                            tables, active, batch, interpret=interp,
+                        )
+                        n += 1
+            families["mixed_verify"] = n
 
         if guided and getattr(self, "_guided_table", None) is not None:
             gbatch = SamplingBatch(
@@ -1747,7 +1625,7 @@ class ModelExecutor:
                     gbatch,
                 )
                 n += 1
-                if self._mixed_step_resolved():
+                if fuse:
                     b, CBp, n_tok, sp = next(
                         iter(self._prefill_shape_family())
                     )
@@ -2445,9 +2323,13 @@ class ModelExecutor:
         min_p=None,
         rope_delta=None,
     ):
-        """Pipelined speculative verify WITHOUT prefill fusion: the
-        _verify_impl program fed by the in-graph state merge instead of
-        host-resolved inputs (docs/ENGINE_PIPELINE.md)."""
+        """Speculative-decoding verify step WITHOUT prefill fusion: the
+        in-graph state merge, then one forward pass over S positions per
+        sequence (the prefill machinery with `all_logits`) and point-mass
+        speculative acceptance (ops/sampling.py). KV rows for ALL S
+        positions are written; rows past the accepted prefix are stale
+        garbage that attention can never read (masked by seq_lens) and
+        the next step overwrites (docs/ENGINE_PIPELINE.md)."""
         token_ids, pos, tl, keys, act = self._spec_state_merge(
             drafts, host_last, host_pos, host_steps, fresh_mask,
             prev_tokens, prev_n_emit, seeds, active,
@@ -2586,7 +2468,7 @@ class ModelExecutor:
         host_last: np.ndarray,  # [R] int32
         host_pos: np.ndarray,  # [R] int32
         host_steps: np.ndarray,  # [R] int32
-        fresh_mask: np.ndarray,  # [R] bool
+        fresh_mask: Optional[np.ndarray],  # [R] bool; None = all fresh
         prev_tokens,  # device [R, S] from the in-flight verify, or None
         prev_n_emit,  # device [R] accepted counts, or None
         block_tables: np.ndarray,  # [R, max_blocks_per_seq]
@@ -2638,7 +2520,10 @@ class ModelExecutor:
                 self._put(host_last, np.int32),
                 self._put(host_pos, np.int32),
                 self._put(host_steps, np.int32),
-                self._put(fresh_mask),
+                self._put(
+                    np.ones((R,), bool) if fresh_mask is None
+                    else fresh_mask
+                ),
                 prev_tokens,
                 prev_n_emit,
                 self._put(batch.seeds, np.uint32),
@@ -2681,6 +2566,26 @@ class ModelExecutor:
                 *args, pf_pack, lpad=lpad, interpret=interpret, **opt,
             )
         return tokens, logprobs, n_emit, pf_tok, pf_lp
+
+    def verify(
+        self,
+        token_ids: np.ndarray,  # [R, S] — last token then S-1 drafts
+        positions: np.ndarray,  # [R] — position of the first fed token
+        block_tables: np.ndarray,  # [R, max_blocks_per_seq]
+        active: np.ndarray,  # [R] bool
+        batch: SamplingBatch,
+    ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """Synchronous speculative step: dispatch + fetch, every row
+        host-fed and no step before it — to verify_start what decode is
+        to decode_start, and the same compiled program. Returns (tokens
+        [R, S], logprobs [R, S], n_emit [R]): each active row emits its
+        first n_emit tokens (>= 1 — a verify step subsumes a plain
+        decode step)."""
+        tokens, logprobs, n_emit, _, _ = self.verify_start(
+            [], token_ids[:, 1:], token_ids[:, 0], positions, batch.steps,
+            None, None, None, block_tables, active, batch,
+        )
+        return self._fetch(tokens, logprobs, n_emit)
 
     def seed_slot_counts(self, slot: int, generated: "List[int]") -> None:
         """(Re)build one slot's generated-token histogram — on admission
