@@ -554,9 +554,12 @@ def test_unreclaimed_manifests_are_reaped():
             lambda: not srv._srid_map and not srv._srid_info,
             timeout=10.0,
         )
-        assert srv.metrics.get(
-            "xllm_service_orphan_reaped_total"
-        ).get() == 1
+        # (the reap empties the tables first and counts last: wait for it)
+        assert wait_until(
+            lambda: srv.metrics.get(
+                "xllm_service_orphan_reaped_total"
+            ).get() == 1
+        )
         # the engine request was cancelled (work + blocks released)
         assert wait_until(
             lambda: srv.engine.get_load_metrics().waiting_requests_num == 0,

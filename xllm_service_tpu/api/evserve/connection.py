@@ -1,8 +1,9 @@
 """Per-connection state for the event-loop front end.
 
 One Connection owns one accepted socket. The loop thread does all socket
-I/O and selector bookkeeping; scheduler lanes and pool workers only ever
-touch the thread-safe outbox (`enqueue`), which wakes the loop to drain.
+I/O and selector bookkeeping; the scheduler's delivering threads and pool
+workers only ever touch the thread-safe outbox (`enqueue`), which wakes the
+loop to drain (once per batch under `server.deferred_wakes()`).
 
 Exchange lifecycle: the parser may buffer pipelined requests, but at most
 one is in flight — the next starts only after the current response is
@@ -203,8 +204,8 @@ class Connection:
         self.server.update_interest(self, want_write=not empty)
 
     def close(self) -> None:
-        """Loop thread: tear the connection down now. Any later enqueue from
-        a lane returns False, which cancels its stream upstream."""
+        """Loop thread: tear the connection down now. Any later enqueue
+        returns False, which cancels its stream upstream."""
         if self.closed:
             return
         with self._mu:

@@ -8,9 +8,9 @@ same handler functions run on either backend.
 
 The one capability the threaded handler cannot offer: `hold()` without a
 blocked thread. A deferred exchange parks the HTTP exchange on the
-connection; scheduler lanes stream into it and a loop timer enforces the
-request deadline — 1k concurrent SSE streams cost 1k sockets, not 1k
-threads.
+connection; the scheduler's deliveries stream into it and a loop timer
+enforces the request deadline — 1k concurrent SSE streams cost 1k sockets,
+not 1k threads.
 """
 
 from __future__ import annotations
@@ -42,6 +42,9 @@ class _BodyWriter:
 
 class EvHandler(HttpJsonApi):
     protocol_version = "HTTP/1.1"
+    # wfile.write appends to the connection's outbox: it never waits for
+    # the client (QuietHandler's is a socket write and can).
+    writes_can_block = False
     # Grace between the deadline fail() and abandoning the exchange
     # (class attr so tests can compress it).
     grace_s = 5.0
@@ -143,7 +146,7 @@ class EvHandler(HttpJsonApi):
                 stream.abandon()
                 self._complete(close=True)
 
-        # Defer + gauge + timer all under _done_mu: a lane completing the
+        # Defer + gauge + timer all under _done_mu: a delivery completing the
         # exchange concurrently either beats this block (we return — no
         # timer armed, no gauge bump) or _complete() sees the armed handle
         # and cancels it. Arming outside the lock would leak a 600 s timer

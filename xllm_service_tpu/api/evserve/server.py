@@ -1,9 +1,10 @@
 """selectors/epoll event-loop HTTP server for the control plane.
 
 One loop thread owns every socket (accept, read, write readiness, timers,
-idle sweep); a small worker pool runs route handlers; scheduler lanes
-stream SSE tokens by enqueueing into connection outboxes and waking the
-loop through a socketpair. Concurrency therefore scales with open sockets
+idle sweep); a small worker pool runs route handlers; the scheduler's
+deliveries stream SSE tokens by enqueueing into connection outboxes and
+waking the loop through a socketpair (once per pushed batch:
+`deferred_wakes`). Concurrency therefore scales with open sockets
 — the ThreadingHTTPServer backend spends a thread per connection and a
 second blocked thread per in-flight generation, which caps the control
 plane near the thread budget; this backend carries >1k concurrent SSE
@@ -16,6 +17,7 @@ with ProgressiveAttachment streams detached from worker threads
 
 from __future__ import annotations
 
+import contextlib
 import heapq
 import logging
 import selectors
@@ -61,6 +63,14 @@ class TimerHandle:
         self.fn = None
 
 
+class _Deferring(threading.local):
+    """A thread's state under `deferred_wakes()`: off for every thread
+    that never entered one."""
+
+    on = False
+    owed = False
+
+
 class EventLoopHttpServer:
     """Uniform server surface (start/stop/host/port/stats) shared with
     HttpServerThread, selected by ServiceConfig.http_backend."""
@@ -102,6 +112,7 @@ class EventLoopHttpServer:
         self._wake_r, self._wake_w = socket.socketpair()
         self._wake_r.setblocking(False)
         self._wake_w.setblocking(False)
+        self._deferring = _Deferring()
 
         self._mu = threading.Lock()
         self._posted: Deque[Callable[[], None]] = deque()
@@ -178,10 +189,29 @@ class EventLoopHttpServer:
     # ------------------------------------------------------------------ #
 
     def wake(self) -> None:
+        if self._deferring.on:
+            self._deferring.owed = True
+            return
         try:
             self._wake_w.send(b"\0")
         except (BlockingIOError, OSError):
             pass  # wake pipe saturated: loop is already waking
+
+    @contextlib.contextmanager
+    def deferred_wakes(self):
+        """Until the block ends, this thread's wakes of the loop (one per
+        enqueue, post and timer) are owed, and paid as ONE at the end: a
+        thread that delivers a batch of tokens to many connections hands
+        the loop all of them at once instead of trading the interpreter
+        with it once per token. Nothing inside may wait for the loop."""
+        d = self._deferring
+        d.on, d.owed = True, False
+        try:
+            yield
+        finally:
+            d.on = False
+            if d.owed:
+                self.wake()
 
     def post(self, fn: Callable[[], None]) -> None:
         with self._mu:

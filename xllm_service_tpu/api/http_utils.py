@@ -11,6 +11,7 @@ Keep-alive JSON POSTs between tiers reuse an http.client connection per
 
 from __future__ import annotations
 
+import contextlib
 import http.client
 import json
 import random
@@ -95,6 +96,9 @@ class HttpJsonApi:
 
 class QuietHandler(HttpJsonApi, BaseHTTPRequestHandler):
     protocol_version = "HTTP/1.1"
+    # wfile.write is a socket write: it waits for a client that stopped
+    # reading (EvHandler's appends to an outbox and never does).
+    writes_can_block = True
 
     def log_message(self, fmt, *args):  # silence per-request stderr spam
         pass
@@ -106,7 +110,7 @@ class QuietHandler(HttpJsonApi, BaseHTTPRequestHandler):
     def hold(self, stream, timeout_s: float, fail) -> None:
         """Block this handler thread until the scheduler finishes the
         exchange (thread-per-stream semantics). On deadline, `fail()` asks
-        the scheduler to fail the request; if its lane still hasn't run
+        the scheduler to fail the request; if that still hasn't run
         after a 5 s grace, the exchange is abandoned with no response and
         the connection dropped so no late write can reach a reused socket.
         The event backend's EvHandler.hold has the same contract without
@@ -121,7 +125,7 @@ class QuietHandler(HttpJsonApi, BaseHTTPRequestHandler):
 class SseWriter:
     """Server-sent-events writer over a chunked HTTP/1.1 response
     (the ProgressiveAttachment analog, call_data.h:150-193). Thread-safe:
-    scheduler lanes write from their own threads."""
+    the scheduler writes from whichever thread delivers."""
 
     def __init__(
         self,
@@ -222,6 +226,10 @@ class HttpServerThread:
         self.server.shutdown()
         self.server.server_close()
         self._thread.join(timeout=2.0)
+
+    def deferred_wakes(self):
+        """No loop to wake (the event backend's twin coalesces them)."""
+        return contextlib.nullcontext()
 
     def stats(self) -> Dict[str, Any]:
         return {

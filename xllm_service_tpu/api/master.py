@@ -25,7 +25,7 @@ import logging
 import threading
 import time
 from collections import OrderedDict
-from typing import Any, Dict, Optional
+from typing import Any, Dict, List, Optional
 
 from xllm_service_tpu.api.http_utils import (
     HttpJsonApi,
@@ -89,15 +89,21 @@ _HTTP_STATUS = {
 
 
 class HttpClientStream(ClientStream):
-    """Bridges scheduler lanes to one live HTTP exchange; the handler thread
-    blocks on `done` while lane threads write (reference: StreamCallData +
-    the early done->Run SSE trick, call_data.h:83-92)."""
+    """Bridges the scheduler's deliveries to one live HTTP exchange; the
+    handler thread blocks on `done` (threaded backend) or parks the
+    exchange (event backend) while the delivering threads write
+    (reference: StreamCallData + the early done->Run SSE trick,
+    call_data.h:83-92)."""
 
     def __init__(
         self, handler: HttpJsonApi, streaming: bool, x_request_id: str = ""
     ):
         self._handler = handler
         self._streaming = streaming
+        # The backend's answer (a socket write on the threaded one, an
+        # outbox append on the event one): the scheduler keeps a write
+        # that can block off the thread that delivers a batch.
+        self.writes_can_block = getattr(handler, "writes_can_block", True)
         # Echoed on every response — success AND error (reference
         # CallData captures the same header pair; here it round-trips to
         # the client and lands in the request trace for correlation).
@@ -107,7 +113,7 @@ class HttpClientStream(ClientStream):
         self._sse: Optional[SseWriter] = None
         self.done = threading.Event()
         # Set when the handler thread gives up on the exchange (timeout):
-        # any later lane write must be dropped, never land on the socket —
+        # any later write must be dropped, never land on the socket —
         # the connection may be serving another request by then.
         self._abandoned = threading.Event()
 
@@ -1464,13 +1470,16 @@ class Master:
                 etype="not_master",
             )
             return
-        cont: Dict[str, bool] = {}
+        outs: List[RequestOutput] = []
         for j in body.get("gens", []):
             try:
-                out = output_from_json(j)
+                outs.append(output_from_json(j))
             except Exception:
                 continue
-            cont[out.service_request_id] = self.scheduler.handle_generation(out)
+        # The batch is delivered here, on this worker: the client plane's
+        # loop is woken once for all the connections it wrote to.
+        with self.http.deferred_wakes():
+            cont = self.scheduler.handle_generations(outs)
         h.send_json({"cont": cont})
 
 

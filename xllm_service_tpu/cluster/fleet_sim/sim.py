@@ -12,7 +12,7 @@ by instance load and straggler factors). Requests enter through
 tier submits them, and tokens return through
 `scheduler.handle_generation()` exactly as /rpc/generations pushes
 them — so attempt-versioned wire fencing, mid-stream token replay, and
-lane-ordered delivery all run for real at 10k+ concurrent streams.
+strand-ordered delivery all run for real at 10k+ concurrent streams.
 
 Three clocks, deliberately separate:
   * the SIM clock (`self.now`) — advances event-to-event; injected into
@@ -22,7 +22,8 @@ Three clocks, deliberately separate:
     under a GIL stall and the single simulated master stays master
     (kills are explicit store deletes, not lease timeouts);
   * wall time — only the real master loop (idled at a huge interval)
-    and the lane worker threads see it; the sim calls
+    and the store's watch thread (removal listeners: redispatch,
+    resume, fail) see it; the sim calls
     `scheduler.run_master_upkeep()` itself at simulated heartbeat
     cadence.
 
@@ -201,7 +202,6 @@ class FleetSim:
         # The real master loop idles on a huge interval; the sim drives
         # run_master_upkeep() itself at simulated heartbeat cadence.
         cfg.heartbeat_interval_s = 3600.0
-        cfg.num_ordered_output_streams = 32
         cfg.enable_admission_control = admission
         # acquire() must NEVER park the sim thread in a real wait.
         cfg.admission_queue_timeout_s = 0.0
@@ -241,7 +241,8 @@ class FleetSim:
             self._register(inst)
         self._await_registered()
 
-        # Completion accounting (touched from lane threads).
+        # Completion accounting (touched from the sim thread, which
+        # delivers inline, and from the store's watch thread).
         self._amu = threading.Lock()
         self.submitted = 0
         self.terminal = 0
@@ -319,9 +320,10 @@ class FleetSim:
         while True:
             item = self._pop()
             if item is None:
-                # Heap drained; lane threads may still be delivering the
-                # tail — nothing left can create sim work except them.
-                if self._drain_lanes():
+                # Heap drained; the watch thread may still be recovering
+                # or failing the tail — nothing left can create sim work
+                # except it.
+                if self._await_tail():
                     break
                 continue
             t, _, fn = item
@@ -340,7 +342,7 @@ class FleetSim:
         report.wall_s = time.monotonic() - wall0
         return report
 
-    def _drain_lanes(self, timeout: Optional[float] = None) -> bool:
+    def _await_tail(self, timeout: Optional[float] = None) -> bool:
         """True when every submitted stream reached a terminal state (or
         no further progress happens within `timeout` real seconds)."""
         if timeout is None:
@@ -353,7 +355,7 @@ class FleetSim:
                 outstanding = self.submitted - done
             with self._emu:
                 if self._events:
-                    return False  # a lane callback scheduled new work
+                    return False  # a recovery scheduled new work
             if outstanding <= 0:
                 return True
             if done != last:
@@ -555,9 +557,9 @@ class FleetSim:
             )
         self.scheduler.run_master_upkeep()
         # Repush only while OTHER events remain: once arrivals and service
-        # completions drain, the tail is lane-thread delivery (wall time,
-        # no upkeep needed) — repushing on outstanding>0 would race the
-        # lane threads and spin the sim clock forward for nothing.
+        # completions drain, the tail is the watch thread's recoveries
+        # (wall time, no upkeep needed) — repushing on outstanding>0 would
+        # race them and spin the sim clock forward for nothing.
         with self._emu:
             more = len(self._events) > 0
         if more:

@@ -27,7 +27,13 @@ class ServiceConfig:
     # Concurrency (reference defaults 32 threads / 128 concurrency).
     num_threads: int = 32
     max_concurrency: int = 128
-    num_ordered_output_streams: int = 128  # reference: scheduler.h:112
+    # Most threads the scheduler starts (as needed) for deliveries that
+    # must not run on the thread that received the tokens: streams of the
+    # threaded HTTP backend, whose writes can block, and upstream cancels.
+    # The event backend delivers inline and starts none for its streams
+    # (service/ordered_streams.py; the reference's 128 lanes,
+    # scheduler.h:112).
+    num_ordered_output_streams: int = 128
 
     # HTTP front-end backend: "event" = evserve selectors/epoll loop (SSE
     # streams hold sockets, not threads — the >1k-concurrent-streams path);

@@ -1,6 +1,6 @@
 """Service tier: scheduler, request model, response handling, tracing."""
 
-from xllm_service_tpu.service.ordered_streams import OrderedStreams
+from xllm_service_tpu.service.ordered_streams import HopThreads, Strand
 from xllm_service_tpu.service.request import (
     RequestTracer,
     ServiceRequest,
@@ -10,7 +10,8 @@ from xllm_service_tpu.service.response_handler import ClientStream, ResponseHand
 from xllm_service_tpu.service.scheduler import Scheduler
 
 __all__ = [
-    "OrderedStreams",
+    "HopThreads",
+    "Strand",
     "RequestTracer",
     "ServiceRequest",
     "make_service_request_id",

@@ -27,7 +27,13 @@ class ClientStream:
     """Transport seam (reference: StreamCallData/CallData, call_data.h).
 
     write/write_done return False when the client went away — the scheduler
-    uses that to cancel upstream generation."""
+    uses that to cancel upstream generation.
+
+    `writes_can_block`: a write may wait for the client (a socket write).
+    The scheduler then delivers on a thread of its own instead of the one
+    that pushed the tokens, which has other requests' tokens to deliver."""
+
+    writes_can_block = False
 
     def write(self, payload: Dict[str, Any]) -> bool:
         raise NotImplementedError

@@ -3,11 +3,12 @@
 TPU-native redesign of the reference Scheduler
 (reference: xllm_service/scheduler/scheduler.{h,cpp}): owns the tokenizer +
 chat template, the coordination store + master election, the cluster
-managers and routing policy, the request registry, and the ordered output
-lanes. `schedule()` is the request hot path (template -> tokenize -> policy
--> metrics, scheduler.cpp:73-106); `handle_generation()` the token hot path
-(per-request serialized dispatch, :293-336); the master loop replicates
-cluster state every heartbeat period (:113-121).
+managers and routing policy, the request registry, and the per-request
+output strands. `schedule()` is the request hot path (template -> tokenize
+-> policy -> metrics, scheduler.cpp:73-106); `handle_generations()` the
+token hot path (a pushed batch delivered in one pass, serialized per
+request, :293-336); the master loop replicates cluster state every
+heartbeat period (:113-121).
 
 Additions over the reference, per SURVEY.md §5/§7:
   * hybrid online/offline admission — `offline` requests are parked under
@@ -65,7 +66,7 @@ from xllm_service_tpu.obs import (
     SpanRing,
 )
 from xllm_service_tpu.service.admission import AdmissionController
-from xllm_service_tpu.service.ordered_streams import OrderedStreams
+from xllm_service_tpu.service.ordered_streams import HopThreads, Strand
 from xllm_service_tpu.service.request import (
     RequestTracer,
     ServiceRequest,
@@ -111,7 +112,9 @@ class NotMasterError(RuntimeError):
 class _RequestState:
     request: ServiceRequest
     stream: ClientStream
-    lane: int
+    # Serializes this request's deliveries, failure and fences
+    # (service/ordered_streams.py).
+    strand: Strand = field(default_factory=Strand)
     # api-tier hook to propagate cancellation to the engine instance
     cancel_callback: Optional[Callable[[], None]] = None
     # api-tier hook to (re-)forward the request to its routed prefill
@@ -246,6 +249,21 @@ class Scheduler:
             "Mid-stream token-replay resumes completed after instance "
             "death",
         ).set_function(lambda: self.total_resumes)
+        self._m_batch_size = self.metrics.histogram(
+            "xllm_service_generations_batch_size",
+            "Engine outputs per pushed /rpc/generations batch",
+            buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256, 512),
+        )
+        deliveries = self.metrics.counter(
+            "xllm_service_deliveries_total",
+            "Outputs delivered to their client streams, by the thread "
+            "that ran the delivery: inline = the one that pushed it, "
+            "queued = another (the request's strand was busy, or its "
+            "stream's writes can block and took a thread hop)",
+            labelnames=("ran",),
+        )
+        self._m_delivered_inline = deliveries.labels(ran="inline")
+        self._m_delivered_queued = deliveries.labels(ran="queued")
         self.m_cancel_errors = self.metrics.counter(
             "xllm_service_cancel_errors_total",
             "Instance /cancel calls that failed (previously swallowed "
@@ -372,7 +390,11 @@ class Scheduler:
             fabric=self.prefix_fabric,
         )
         self._response_handler = ResponseHandler()
-        self._streams = OrderedStreams(config.num_ordered_output_streams)
+        # Threads for what must not run on a pusher's thread: a strand
+        # whose stream's writes can block, an upstream cancel. None is
+        # started until one is needed (the event backend's streams never
+        # block, so a batch of tokens is delivered by its receiver).
+        self._hop = HopThreads(config.num_ordered_output_streams)
         # Re-dispatch interrupted requests when their instance dies (the
         # reference only promises this — README.md:46; its failure surface
         # is an error-finish, SURVEY.md §3.5 note).
@@ -744,7 +766,7 @@ class Scheduler:
         if t is not None:
             t.join(timeout=2.0)
         self._master_thread.join(timeout=2.0)
-        self._streams.shutdown()
+        self._hop.shutdown()
         self._instance_mgr.close()
         self._kvcache_mgr.close()
         self._election.stop()
@@ -1319,7 +1341,6 @@ class Scheduler:
         state = _RequestState(
             request=request,
             stream=stream,
-            lane=self._streams.assign(),
             cancel_callback=cancel_callback,
             sched_mono=time.monotonic(),
         )
@@ -1382,29 +1403,74 @@ class Scheduler:
     # token hot path
     # ------------------------------------------------------------------ #
 
-    def handle_generation(self, output: RequestOutput) -> bool:
-        """One engine step for one request; serialized per request via its
-        lane (reference: scheduler.cpp:293-336). Returns False when the
-        request is unknown (finished/cancelled) OR the output carries a
-        stale attempt's wire id — both tell the caller to stop the
-        upstream stream. Outputs arrive keyed by the attempt-versioned
-        wire id (`<srid>` or `<srid>#rN`, service/request.py); a replaced
-        attempt's late pushes must not interleave with the live one."""
-        wire = output.service_request_id
-        base, _, _ = wire.partition("#r")
+    def handle_generations(
+        self, outputs: List[RequestOutput]
+    ) -> Dict[str, bool]:
+        """One pushed batch of engine steps (reference:
+        scheduler.cpp:293-336, once per output there). Returns the
+        continue map by wire id: False when the request is unknown
+        (finished/cancelled) OR the output carries a stale attempt's wire
+        id — both tell the pusher to stop the upstream stream. Outputs
+        arrive keyed by the attempt-versioned wire id (`<srid>` or
+        `<srid>#rN`, service/request.py); a replaced attempt's late
+        pushes must not interleave with the live one.
+
+        The admitted outputs are delivered in batch order on the
+        caller's thread, each through its request's strand: a request
+        that another thread is delivering to, and a stream whose writes
+        can block, take theirs on that other thread instead."""
+        self._m_batch_size.observe(len(outputs))
+        bases = [o.service_request_id.partition("#r")[0] for o in outputs]
         with self._mu:
-            state = self._requests.get(base)
-        if state is None or state.done:
-            return False
-        if wire != (state.request.wire_srid or base):
-            return False  # late push from a replaced dispatch attempt
-        self._streams.submit(state.lane, lambda: self._deliver(state, output))
-        return True
+            states = [self._requests.get(b) for b in bases]
+        cont: Dict[str, bool] = {}
+        inline = queued = 0
+        for output, state in zip(outputs, states):
+            wire = output.service_request_id
+            if (
+                state is None
+                or state.done
+                # late push from a replaced dispatch attempt
+                or wire != (
+                    state.request.wire_srid
+                    or state.request.service_request_id
+                )
+            ):
+                cont[wire] = False
+                continue
+            cont[wire] = True
+            if self._submit(
+                state, lambda s=state, o=output: self._deliver(s, o)
+            ):
+                inline += 1
+            else:
+                queued += 1
+        if inline:
+            self._m_delivered_inline.inc(inline)
+        if queued:
+            self._m_delivered_queued.inc(queued)
+        return cont
+
+    def handle_generation(self, output: RequestOutput) -> bool:
+        """A batch of one (the fleet simulator and tests push this way)."""
+        return self.handle_generations([output])[output.service_request_id]
+
+    def _submit(self, state: _RequestState, fn: Callable[[], None]) -> bool:
+        """Run `fn` in the request's strand. True when it has run on this
+        thread; False when another thread runs it: the strand was busy,
+        or the stream's writes can block and must not hold up whatever
+        else this thread has to deliver."""
+        return state.strand.submit(
+            fn,
+            self._hop
+            if getattr(state.stream, "writes_can_block", False)
+            else None,
+        )
 
     def _deliver(self, state: _RequestState, output: RequestOutput) -> None:
         if state.done:
             # finish_request/fail_request won the race while this step sat
-            # queued in the lane — never write after the exchange ended.
+            # queued in the strand — never write after the exchange ended.
             return
         request = state.request
         if output.service_request_id != (
@@ -1591,11 +1657,7 @@ class Scheduler:
             output.finished = True
             # Stop the engine's generation; the finish below is CLEAN
             # (finish_reason stop), not a client cancel.
-            if state.cancel_callback is not None:
-                try:
-                    state.cancel_callback()
-                except Exception:
-                    pass
+            self._cancel_upstream(state)
 
     def _accumulate(self, state: _RequestState, output: RequestOutput) -> None:
         accumulate_sequences(state.acc, output)
@@ -1605,12 +1667,17 @@ class Scheduler:
     def _cancel(self, state: _RequestState) -> None:
         """Client went away mid-stream: unwind metrics + tell the engine
         (reference cancels via the OutputCallback returning false)."""
-        if state.cancel_callback is not None:
-            try:
-                state.cancel_callback()
-            except Exception:
-                pass
+        self._cancel_upstream(state)
         self.finish_request(state.request.service_request_id, cancelled=True)
+
+    def _cancel_upstream(self, state: _RequestState) -> None:
+        """Tell the routed instances to stop generating. That is an RPC
+        (api/master.py `_cancel_on_instance`, seconds when a peer is
+        dead), so it takes a hop thread: the delivery that asked for it
+        is one of a batch, and the instance that pushed the batch waits
+        for its answer."""
+        if state.cancel_callback is not None:
+            self._hop.submit(state.cancel_callback)
 
     def finish_request(self, service_request_id: str, cancelled: bool = False) -> None:
         """Terminal bookkeeping (reference: scheduler.cpp:268-291).
@@ -1677,8 +1744,8 @@ class Scheduler:
             service_request_id, "error", code=int(code), message=msg
         )
         state.failed = True  # finish_request reports outcome="error"
-        self._streams.submit(
-            state.lane,
+        self._submit(
+            state,
             lambda: (
                 state.stream.finish_with_error(code, msg),
                 self.finish_request(service_request_id, cancelled=True),
@@ -1785,12 +1852,13 @@ class Scheduler:
                 f"{state.request.service_request_id}#r{state.attempt}"
             )
 
-    def _drain_lane(self, state: _RequestState) -> None:
-        """Barrier on the request's lane: any delivery admitted BEFORE the
-        attempt bump finishes writing (client + acc) before we snapshot
-        the delivered tokens. Never called from a lane thread."""
+    def _drain_strand(self, state: _RequestState) -> None:
+        """Barrier on the request's strand: any delivery admitted BEFORE
+        the attempt bump finishes writing (client + acc) before we
+        snapshot the delivered tokens. An idle strand runs the fence at
+        once. Never called from inside the strand."""
         fence = threading.Event()
-        self._streams.submit(state.lane, fence.set)
+        self._submit(state, fence.set)
         fence.wait(timeout=5.0)
 
     def redispatch_request(
@@ -1916,11 +1984,11 @@ class Scheduler:
     def _resume_locked_out(
         self, service_request_id, state, request, exclude
     ) -> bool:
-        # Fence the dead attempt FIRST, then drain the lane: deliveries
-        # already queued finish writing into acc, later ones are rejected
-        # — the snapshot below is exactly what the client has.
+        # Fence the dead attempt FIRST, then drain the strand: deliveries
+        # already admitted finish writing into acc, later ones are
+        # rejected — the snapshot below is exactly what the client has.
         self._bump_attempt(state)
-        self._drain_lane(state)
+        self._drain_strand(state)
         with self._mu:
             seq = state.acc.get(0)
             emitted = list(seq.token_ids) if seq is not None else []
