@@ -256,81 +256,12 @@ def _cpu_regression_guard(line: str) -> "tuple[str, int]":
     return json.dumps(res), rc
 
 
-# Grouped-MoE A/B guard (--moe both, ISSUE 15): the grouped ragged
-# expert dispatch must hold at least this fraction of the dense
-# all-experts einsum's decode throughput — the dispatch that exists to
-# make compute track ACTIVE params can never be allowed to regress
-# silently. Armed only when the grouped row actually RESOLVED to the
-# Pallas "grouped" dispatch (docs/MOE.md) — on CPU the row runs the
-# blockwise oracle ("grouped-ref"), whose job is parity, not speed.
-_MOE_MIN_RATIO = float(os.environ.get("XLLM_BENCH_MOE_MIN_RATIO", 0.95))
-
-
-def _moe_guard(line: str) -> "tuple[str, int]":
-    """Exit-3 guard for the --moe A/B rows; abstains LOUDLY on a
-    dispatch mismatch (the engine_spec_guard builder-mismatch
-    pattern)."""
-    try:
-        res = json.loads(line)
-    except ValueError:
-        return line, 0
-    mb = res.get("moe_bench") or {}
-    if not isinstance(mb, dict) or "grouped" not in mb or "dense" not in mb:
-        return line, 0
-    try:
-        d = float(mb["dense"]["tok_s"])
-        g = float(mb["grouped"]["tok_s"])
-    except (KeyError, TypeError, ValueError):
-        d = g = 0.0
-    disp = (
-        mb["grouped"].get("moe_dispatch"),
-        mb["dense"].get("moe_dispatch"),
-    )
-    if disp[0] != "grouped" or str(disp[1] or "").startswith("grouped"):
-        res["engine_moe_guard"] = (
-            f"abstained: moe_dispatch {disp[0]}/{disp[1]} — the grouped "
-            f"row must run the Pallas grouped dispatch and the dense row "
-            f"the all-experts einsum (CPU resolves grouped-ref: parity "
-            f"is tier-1's tests/test_moe_engine.py; the floor arms on "
-            f"TPU)"
-        )
-        return json.dumps(res), 0
-    if mb["grouped"].get("moe_interpret") or mb["dense"].get(
-        "moe_interpret"
-    ):
-        # XLLM_MOE_INTERPRET rows time the Pallas INTERPRETER against
-        # compiled XLA — a guaranteed sub-floor ratio that says nothing
-        # about the chip; a CI host exporting the hook must not fail
-        # the bench.
-        res["engine_moe_guard"] = (
-            "abstained: XLLM_MOE_INTERPRET is set — interpret-mode "
-            "rows measure the interpreter, not the dispatch"
-        )
-        return json.dumps(res), 0
-    if d <= 0 or g <= 0:
-        # Still loud: a harness refactor that loses tok_s must not make
-        # the guard silently vanish.
-        res["engine_moe_guard"] = (
-            f"abstained: unparseable tok_s (grouped={g}, dense={d})"
-        )
-        return json.dumps(res), 0
-    if g >= _MOE_MIN_RATIO * d:
-        res["engine_moe_guard"] = "ok"
-        return json.dumps(res), 0
-    res["engine_moe_guard"] = (
-        f"FAIL: grouped MoE dispatch {g:.1f} tok/s is below "
-        f"{100 * _MOE_MIN_RATIO:.0f}% of the dense all-experts path "
-        f"{d:.1f}"
-    )
-    return json.dumps(res), 3
-
-
 def _overlap_guard(line: str) -> "tuple[str, int]":
     """Exit-3 guards for the --overlap A/B rows and the warm-start host
     gap (ISSUE 18). `engine_overlap_collectives_guard` floors the ring
     collective-matmul row against plain psum and abstains LOUDLY when
     the labeled rows did not actually route the schedule (the
-    engine_moe_guard dispatch-mismatch pattern); `engine_host_gap_guard`
+    engine_mesh_guard dispatch-mismatch pattern); `engine_host_gap_guard`
     ceilings the default engine row's mean host gap so an
     in-serving-loop recompile can never ride into the record as a tok/s
     blip."""
@@ -559,22 +490,6 @@ def main() -> None:
                 f"--spec-mode must be composed|sync|both, got {spec_mode!r}"
             )
 
-    # --moe {grouped,dense,both}: the MoE dispatch A/B (ISSUE 15) — the
-    # grouped ragged expert dispatch vs the dense all-experts einsum on
-    # the MoE tiny model at matched active params. Default "both"
-    # reports the pair and arms the engine_moe_guard.
-    moe_mode = "both"
-    if "--moe" in sys.argv:
-        idx = sys.argv.index("--moe") + 1
-        nxt = sys.argv[idx] if idx < len(sys.argv) else ""
-        if nxt in ("grouped", "dense", "both"):
-            moe_mode = nxt
-        elif nxt and not nxt.startswith("-"):
-            raise SystemExit(
-                f"--moe takes grouped|dense|both, got {nxt!r}"
-            )
-        # bare `--moe` (or followed by another flag) = "both"
-
     # --overlap {on,off,both}: the latency-hiding collectives A/B
     # (ISSUE 18) — the ring collective-matmul schedule
     # (XLLM_OVERLAP_COLLECTIVES=1, docs/SHARDING.md) vs the plain
@@ -596,7 +511,7 @@ def main() -> None:
     rc, out, err = _run_attempt_subprocess(
         dict(kv_cache_dtype="auto", engine_mode=engine_mode,
              attention_mode=attention_mode, spec_mode=spec_mode,
-             moe_mode=moe_mode, overlap_mode=overlap_mode,
+             overlap_mode=overlap_mode,
              mesh=list(mesh), _on_tpu=on_tpu)
     )
     line = ""
@@ -612,9 +527,8 @@ def main() -> None:
         )
     line, guard_rc = _cpu_regression_guard(line)
     line, mesh_rc = _mesh_guard(line)
-    line, moe_rc = _moe_guard(line)
     line, ovl_rc = _overlap_guard(line)
-    guard_rc = guard_rc or mesh_rc or moe_rc or ovl_rc
+    guard_rc = guard_rc or mesh_rc or ovl_rc
     print(line)
     if guard_rc:
         print(
@@ -626,7 +540,6 @@ def main() -> None:
 
 def _engine_bench(sync: bool, mixed: bool = True, spec: int = 0,
                   model: str = "llama3-tiny",
-                  moe: "str | None" = None,
                   overlap: "str | None" = None,
                   tp: int = 1) -> dict:
     """Full-InferenceEngine decode throughput (llama3-tiny, R=8) in one
@@ -639,11 +552,7 @@ def _engine_bench(sync: bool, mixed: bool = True, spec: int = 0,
     kernel the engine's dispatches actually route to. `spec` > 0 runs
     the same harness under speculative decoding (the ISSUE 13 combined
     path: sync/mixed then select composed vs sync+split verify).
-    `moe` pins the MoE dispatch for the --moe A/B (ISSUE 15):
-    "grouped" sets XLLM_MOE_KERNEL=1 around the run, "dense" =0 — the
-    row reports the dispatch the executor actually RESOLVED (the guard
-    abstains when the grouped row ran the oracle, e.g. on CPU).
-    `overlap` pins the collective-matmul schedule the same way for the
+    `overlap` pins the collective-matmul schedule for the
     --overlap A/B (ISSUE 18): "on" sets XLLM_OVERLAP_COLLECTIVES=1,
     "off" =0 — the row reports `overlap_collectives`, whether the ring
     schedule was actually ELIGIBLE (tp>1/ep>1), which the guard keys
@@ -655,24 +564,8 @@ def _engine_bench(sync: bool, mixed: bool = True, spec: int = 0,
     from xllm_service_tpu.runtime.engine import EngineRequest, InferenceEngine
     from xllm_service_tpu.runtime.executor import ModelExecutor
 
-    if moe is not None:
-        # Pin the dispatch around the WHOLE run (env is read at trace
-        # time; later bucket shapes retrace mid-run) and restore — a
-        # later A/B row must not inherit the override.
-        prev_moe_env = os.environ.get("XLLM_MOE_KERNEL")
-        os.environ["XLLM_MOE_KERNEL"] = "1" if moe == "grouped" else "0"
-        try:
-            row = _engine_bench(sync, mixed=mixed, spec=spec, model=model)
-            row["moe_mode"] = moe
-            return row
-        finally:
-            if prev_moe_env is None:
-                os.environ.pop("XLLM_MOE_KERNEL", None)
-            else:
-                os.environ["XLLM_MOE_KERNEL"] = prev_moe_env
-
     if overlap is not None:
-        # Same pin-around-the-WHOLE-run pattern as `moe`: the hatch is
+        # Pin around the WHOLE run: the hatch is
         # read at trace time and later bucket shapes retrace mid-run,
         # so a leaky override would split one row across schedules.
         prev_ovl_env = os.environ.get("XLLM_OVERLAP_COLLECTIVES")
@@ -891,7 +784,6 @@ def _run(on_tpu: bool, kv_cache_dtype: str = "auto",
          engine_mode: str = "both",
          attention_mode: str = "both",
          spec_mode: str = "both",
-         moe_mode: str = "both",
          overlap_mode: str = "both",
          mesh=(1, 1, 1)) -> None:
     import jax
@@ -1208,32 +1100,18 @@ def _run(on_tpu: bool, kv_cache_dtype: str = "auto",
                     spec=3,
                 )
 
-        # MoE dispatch A/B (--moe, ISSUE 15): the grouped ragged expert
-        # dispatch vs the dense all-experts einsum on moe-shard-tiny —
-        # same model, same router, matched active params; only the
-        # dispatch strategy differs. UNLIKE the other engine A/B
-        # sections this also runs on TPU (n_dev == 1): that is the only
-        # backend where the grouped row resolves to the Pallas kernel,
-        # so gating it CPU-only would leave engine_moe_guard permanently
-        # dead on the one backend it exists for. engine_moe_guard
-        # (exit 3) arms on the resolved `grouped` dispatch and abstains
-        # loudly otherwise (CPU runs the grouped-ref oracle — parity is
-        # tier-1's job there — and the interpret hook measures the
-        # interpreter, never the chip).
+        # The expert model's row (moe-shard-tiny through the grouped
+        # ragged expert product, the one path there is; docs/MOE.md):
+        # reported, not guarded. On TPU it is the only row of this file
+        # that runs the grouped kernels.
         moe_bench = None
         if (
             n_dev == 1
             and not os.environ.get("XLLM_BENCH_SKIP_ENGINE_AB")
         ):
-            moe_bench = {}
-            mmodes = (
-                ("grouped", "dense") if moe_mode == "both"
-                else (moe_mode,)
-            )
-            for m in mmodes:
-                moe_bench[m] = _engine_bench(
-                    sync=False, model="moe-shard-tiny", moe=m,
-                )
+            moe_bench = {
+                "grouped": _engine_bench(sync=False, model="moe-shard-tiny")
+            }
 
         # Latency-hiding collectives A/B (--overlap, ISSUE 18): the
         # ring collective-matmul schedule vs the plain psum/einsum
@@ -1343,12 +1221,9 @@ def _run(on_tpu: bool, kv_cache_dtype: str = "auto",
             # docs/ENGINE_PIPELINE.md).
             "spec_bench": spec_bench,
             "spec_mode": spec_mode,
-            # MoE dispatch A/B (--moe): grouped ragged expert dispatch
-            # vs dense all-experts at matched active params —
-            # engine_moe_guard (exit 3) enforces the floor when the
-            # Pallas dispatch actually ran (ISSUE 15, docs/MOE.md).
+            # The expert model's row through the grouped ragged expert
+            # product (docs/MOE.md): reported, not guarded.
             "moe_bench": moe_bench,
-            "moe_mode": moe_mode,
             # Latency-hiding collectives A/B (--overlap): ring
             # collective-matmul combines vs plain psum on the
             # tp-sharded engine — engine_overlap_collectives_guard

@@ -237,91 +237,16 @@ def test_spec_guard_abstains_on_builder_mismatch():
     assert json.loads(out)["engine_spec_guard"].startswith("abstained")
 
 
-# ---- grouped-MoE A/B guard (--moe both; the grouped ragged expert
-# dispatch vs the dense all-experts einsum, ISSUE 15 / docs/MOE.md) ----
+# ---- the grouped-MoE A/B guard went with XLLM_MOE_KERNEL (PR 39): there
+# is one expert product and no dense row to hold it against ----
 
 
-def _mb(dense_tok, grouped_tok, grouped_disp="grouped",
-        dense_disp="dense"):
-    return {
-        "grouped": {"moe_dispatch": grouped_disp, "tok_s": grouped_tok},
-        "dense": {"moe_dispatch": dense_disp, "tok_s": dense_tok},
-    }
-
-
-def _moe_line(**kw):
-    d = {"backend": "tpu", "value": 100.0}
-    d.update(kw)
-    return json.dumps(d)
-
-
-def test_moe_at_parity_passes(monkeypatch):
-    monkeypatch.setattr(bench, "_MOE_MIN_RATIO", 0.95)
-    out, rc = bench._moe_guard(_moe_line(moe_bench=_mb(100.0, 96.0)))
-    assert rc == 0
-    assert json.loads(out)["engine_moe_guard"] == "ok"
-
-
-def test_moe_regression_fails(monkeypatch):
-    monkeypatch.setattr(bench, "_MOE_MIN_RATIO", 0.95)
-    out, rc = bench._moe_guard(_moe_line(moe_bench=_mb(100.0, 80.0)))
-    assert rc == 3
-    assert json.loads(out)["engine_moe_guard"].startswith("FAIL")
-
-
-def test_moe_guard_needs_both_modes():
-    out, rc = bench._moe_guard(
-        _moe_line(moe_bench={"grouped": {"tok_s": 50.0}})
-    )
-    assert rc == 0
-    assert "engine_moe_guard" not in json.loads(out)
-
-
-def test_moe_guard_abstains_on_dispatch_mismatch():
-    # CPU resolves the grouped row to the blockwise oracle
-    # ("grouped-ref"): a passing ratio would compare parity machinery,
-    # not the Pallas dispatch — abstain loudly, like the mesh guard.
-    out, rc = bench._moe_guard(
-        _moe_line(moe_bench=_mb(100.0, 96.0, grouped_disp="grouped-ref"))
-    )
-    assert rc == 0
-    assert json.loads(out)["engine_moe_guard"].startswith("abstained")
-
-
-def test_moe_guard_abstains_when_dense_row_ran_grouped():
-    # An XLLM_MOE_KERNEL env pin can flip the dense row onto the
-    # grouped path: grouped-vs-grouped stamping "ok" would be vacuous.
-    out, rc = bench._moe_guard(
-        _moe_line(moe_bench=_mb(100.0, 96.0, dense_disp="grouped"))
-    )
-    assert rc == 0
-    assert json.loads(out)["engine_moe_guard"].startswith("abstained")
-
-
-def test_moe_guard_abstains_under_interpret_hook():
-    # XLLM_MOE_INTERPRET rows time the Pallas interpreter vs compiled
-    # dense — a guaranteed sub-floor ratio; a CI host exporting the
-    # hook must not fail the bench.
-    mb = _mb(100.0, 2.0)
-    mb["grouped"]["moe_interpret"] = True
-    out, rc = bench._moe_guard(_moe_line(moe_bench=mb))
-    assert rc == 0
-    g = json.loads(out)["engine_moe_guard"]
-    assert g.startswith("abstained") and "INTERPRET" in g
-
-
-def test_moe_guard_abstains_loudly_on_bad_tok_s():
-    # A harness refactor losing tok_s must not make the guard silently
-    # vanish — the line gets a marker either way.
-    mb = _mb(100.0, 96.0)
-    mb["grouped"]["tok_s"] = None
-    out, rc = bench._moe_guard(_moe_line(moe_bench=mb))
-    assert rc == 0
-    assert json.loads(out)["engine_moe_guard"].startswith("abstained")
-
-
-def test_moe_guard_non_json_passes_through():
-    assert bench._moe_guard("not json") == ("not json", 0)
+def test_bench_has_no_moe_ab_left():
+    """bench.py reports the expert model's row and offers no switch and
+    no guard that would need a dense all-experts run."""
+    src = open(bench.__file__).read()
+    assert not hasattr(bench, "_moe_guard")
+    assert "XLLM_MOE_KERNEL" not in src and '"--moe"' not in src
 
 
 # ------------------------------------------------- mesh guard (--mesh)

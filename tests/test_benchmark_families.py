@@ -30,7 +30,8 @@ def configurations():
 
 
 def test_the_benchmark_has_both_families():
-    assert {c["family"] for c in configurations()} == {"llama", "brumby"}
+    # three since PR 39 (the name stays: the driver counts tests by name)
+    assert {c["family"] for c in configurations()} == {"llama", "brumby", "deepseek"}
 
 
 @pytest.mark.parametrize("config", configurations(), ids=lambda c: c["name"])
@@ -110,3 +111,48 @@ def test_the_brumby_family_draws_slow_decays_and_leaves_nothing_skippable():
     for name in ("attn_norm", "mlp_norm", "q_head_norm", "k_head_norm"):
         assert 0.02 < float(jnp.std(lay[name])) < 0.2, name  # gains ~ N(1, 0.1), not 1
     assert float(jnp.abs(lay["b_ret_gate"]).min()) > 1.0  # the bias is not 0
+
+
+def test_the_deepseek_family_holds_a_span_under_a_published_router():
+    """deepseek-v2 as cut: 40 experts held under a router 160 wide, a
+    quarter of the vocabulary, every published width; the tree drawn at
+    the rehearsal size leaves nothing at 0 or 1 and draws each slice of a
+    stacked leaf from its own key."""
+    import jax
+    import jax.numpy as jnp
+
+    with open(os.path.join(BENCH, "configs", "deepseek-v2.json")) as f:
+        big = json.load(f)
+    fam = family_mod.load(big)
+    cfg = fam.model_config(big["name"], big)
+    assert (cfg.num_experts, cfg.held_experts, cfg.vocab_size, cfg.num_layers) == (160, (0, 40), 25600, 5)
+    assert (cfg.hidden_size, cfg.num_heads, cfg.kv_lora_rank, cfg.q_lora_rank) == (5120, 128, 512, 1536)
+    assert (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim, cfg.mla_cache_dim) == (128, 64, 128, 640)
+    assert (cfg.n_group, cfg.topk_group, cfg.num_experts_per_tok, cfg.routed_scaling_factor) == (8, 3, 6, 16.0)
+    assert cfg.rope_scaling_type == "yarn" and cfg.rope_mscale_all_dim == 0.707
+    shapes = fam.weight_shapes(big)
+    assert shapes["layers"]["router"] == (4, 5120, 160)
+    assert shapes["layers"]["w_gate"] == (4, 40, 5120, 1536)
+    assert shapes["dense_layers"]["w_gate"] == (1, 5120, 12288) and shapes["lm_head"] == (5120, 25600)
+    n = sum(int(np.prod(s)) for g in shapes.values() for s in (g.values() if isinstance(g, dict) else [g]))
+    assert 5.16e9 < n < 5.17e9  # ISSUE 39: 5,164 M parameters
+
+    with open(os.path.join(BENCH, "configs", "rehearse-deepseek-tiny.json")) as f:
+        tiny = json.load(f)
+    w = jax.jit(lambda k: fam.make_weights(tiny, k, jnp.float32))(family_mod.seed_key(3))
+    lay = w["layers"]
+    assert lay["w_gate"].shape == (2, 4, 128, 64) and lay["router"].shape == (2, 128, 8)
+    for name in ("attn_norm", "mlp_norm", "q_norm"):
+        assert 0.02 < float(jnp.std(lay[name])) < 0.2, name  # gains ~ N(1, 0.1), not 1
+    # the latent's lanes carry powers of two on their gain and the inverse on their rows of w_uk / w_uv
+    lanes = np.exp2(np.round(np.log2(np.asarray(lay["kv_norm"]))))
+    assert set(np.unique(np.log2(lanes))) == set(range(-fam.LANE_LOG2, fam.LANE_LOG2 + 1))
+    assert 0.02 < float(np.std(np.asarray(lay["kv_norm"]) / lanes)) < 0.2
+    for name in ("w_uk", "w_uv"):
+        rows = np.asarray(lay[name]) * lanes[:, None, :, None]
+        assert abs(float(rows.std()) * np.sqrt(40) - 1.0) < 0.05, name
+    flat = np.asarray(lay["w_gate"]).reshape(8, -1)
+    assert np.abs(np.corrcoef(flat)[np.triu_indices(8, 1)]).max() < 0.1  # a key a slice
+    assert abs(float(jnp.std(lay["w_sh_down"])) * np.sqrt(128) - 1.0) < 0.05  # N(0, 1 / fan_in)
+    # the routed part is drawn small beside the residual (families/deepseek.py says why)
+    assert abs(float(jnp.std(lay["w_down"])) * np.sqrt(64) / fam.ROUTED_OUT_SCALE - 1.0) < 0.05

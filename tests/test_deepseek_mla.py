@@ -54,7 +54,8 @@ def _oracle_tokens(ex, prompt, n):
 
 @pytest.mark.parametrize(
     "model",
-    ["deepseek-tiny", "deepseek-moe-tiny", "deepseek-hetero-tiny"],
+    ["deepseek-tiny", "deepseek-moe-tiny", "deepseek-hetero-tiny",
+     "deepseek-held-tiny"],
 )
 def test_paged_matches_dense_oracle(model):
     """Prefill (blockwise over latent blocks) + absorbed paged decode equal
@@ -443,3 +444,228 @@ def test_deepseek_v2_group_limited_router_matches_hf(tmp_path):
             break
         eng.step()
     assert got == want, (got, want)
+
+
+# ------------------------------------------------------------------------
+# PR 39: the latent stack on the scan's carry, the mixed step, the held
+# span of experts, and the benchmark's plain reference.
+
+import dataclasses
+import importlib.util
+import json
+import os
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BS = 16
+
+
+def _family():
+    path = os.path.join(ROOT, "benchmarks", "families", "deepseek.py")
+    spec = importlib.util.spec_from_file_location("bench_family_deepseek", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _pool(cfg, blocks=24):
+    stack = jnp.zeros((cfg.num_layers, blocks, 1, BS, cfg.mla_cache_dim), jnp.float32)
+    return stack, jnp.zeros((cfg.num_layers, 1, 1, 1, 1), jnp.float32)
+
+
+def _chunk(tokens, lpad=32):
+    out = np.zeros((1, lpad), np.int32)
+    out[0, : len(tokens)] = tokens
+    return jnp.asarray(out)
+
+
+def _i32(*xs):
+    return jnp.asarray(xs, jnp.int32)
+
+
+@pytest.mark.parametrize("model", ["deepseek-hetero-tiny", "deepseek-held-tiny"])
+def test_carried_stack_steps_match_dense(model):
+    """prefill_batch_step in two chunks, mixed_step (sequence A's decode
+    row beside sequence B's whole prompt as one chunk) and decode_step,
+    all over ONE carried latent stack, give forward_dense's logits at
+    every position read: the dense prefix and the expert suffix index the
+    same pool, and a held span (deepseek-held-tiny: experts 4-7 of 16)
+    leaves out the same experts in both."""
+    cfg = get_model_config(model)
+    params = deepseek.init_params(cfg, jax.random.key(3), jnp.float32)
+    rng = np.random.default_rng(8)
+    A, B = rng.integers(1, 500, 40).tolist(), rng.integers(1, 500, 24).tolist()
+    k, v = _pool(cfg)
+    tab_a, tab_b = _i32([1, 2, 3, 4]), _i32([5, 6, 7, 8])
+
+    def dense(seq):
+        return np.asarray(deepseek.forward_dense(params, cfg, jnp.asarray([seq], jnp.int32))[0])
+
+    _, k, v = deepseek.prefill_batch_step(params, cfg, k, v, _chunk(A[:32]), _i32(0), _i32(32), tab_a)
+    lg, k, v = deepseek.prefill_batch_step(params, cfg, k, v, _chunk(A[32:]), _i32(32), _i32(8), tab_a)
+    np.testing.assert_allclose(np.asarray(lg[0]), dense(A)[-1], atol=2e-4)
+    A.append(int(jnp.argmax(lg[0])))
+
+    tables = jnp.zeros((2, 4), jnp.int32).at[0].set(tab_a[0])
+    dec, pf, k, v = deepseek.mixed_step(
+        params, cfg, k, v, _i32(A[-1], 0), _i32(40, 0), tables, jnp.asarray([True, False]),
+        _chunk(B), _i32(0), _i32(24), tab_b,
+    )
+    np.testing.assert_allclose(np.asarray(dec[0]), dense(A)[-1], atol=2e-4)
+    np.testing.assert_allclose(np.asarray(pf[0]), dense(B)[-1], atol=2e-4)
+    A.append(int(jnp.argmax(dec[0])))
+    B.append(int(jnp.argmax(pf[0])))
+
+    tables = tables.at[1].set(tab_b[0])
+    lg, k, v = deepseek.decode_step(
+        params, cfg, k, v, _i32(A[-1], B[-1]), _i32(41, 24), tables, jnp.asarray([True, True]),
+    )
+    np.testing.assert_allclose(np.asarray(lg[0]), dense(A)[-1], atol=2e-4)
+    np.testing.assert_allclose(np.asarray(lg[1]), dense(B)[-1], atol=2e-4)
+    assert v.shape == (cfg.num_layers, 1, 1, 1, 1)  # the dummy, untouched
+
+
+def test_mixed_step_moves_no_layer_of_the_pool():
+    """The compiled mixed step holds less than ONE layer of the pool in
+    temporaries with the stack donated: nothing scans the pool in or
+    stacks it out (what _scan_stack did with up to three scans)."""
+    cfg = get_model_config("deepseek-hetero-tiny")
+    params = deepseek.init_params(cfg, jax.random.key(0), jnp.float32)
+    blocks = 2048
+    k, v = _pool(cfg, blocks)
+    layer_bytes = blocks * BS * cfg.mla_cache_dim * 4
+
+    def step(k, v):
+        return deepseek.mixed_step(
+            params, cfg, k, v, _i32(1, 2), _i32(40, 9), jnp.zeros((2, 4), jnp.int32),
+            jnp.asarray([True, True]), _chunk([3] * 24), _i32(0), _i32(24), _i32([5, 6, 7, 8]),
+        )
+
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(k, v).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+    assert not any(hasattr(deepseek, n) for n in ("_split_stack", "_concat_stack", "_scan_stack"))
+
+
+def _as_mapping(cfg, held):
+    return {
+        "hidden_size": cfg.hidden_size, "n_group": cfg.n_group, "topk_group": cfg.topk_group,
+        "num_experts_per_tok": cfg.num_experts_per_tok, "norm_topk_prob": cfg.norm_topk_prob,
+        "routed_scaling_factor": cfg.routed_scaling_factor, "n_shared_experts": cfg.n_shared_experts,
+        "n_routed_experts": held[1], "n_routed_experts_published": cfg.num_experts,
+        "experts_held": list(held),
+    }
+
+
+def test_the_four_spans_shares_add_up_to_the_uncut_references_layer():
+    """The share test: the routed parts that the four spans of experts
+    give (the program's _mlp_block under experts_held (0,4) .. (12,4)),
+    plus the shared expert counted once, add up to the expert layer of the
+    benchmark's plain reference with every expert held; and a holder's
+    routed part is the reference's for its own span."""
+    from xllm_service_tpu.models import llama
+
+    fam = _family()
+    whole = dataclasses.replace(get_model_config("deepseek-held-tiny"), experts_held=())
+    params = deepseek.init_params(whole, jax.random.key(5), jnp.float32)
+    leaves = params["layers"]
+    h = jax.random.normal(jax.random.key(6), (29, whole.hidden_size), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        want = fam.expert_layer(h, leaves, 1, _as_mapping(whole, (0, 16)))
+    lp = jax.tree.map(lambda a: a[1], leaves)
+    shared = llama._shared_experts(lp, h)
+    routed = []
+    for lo in (0, 4, 8, 12):
+        cfg = dataclasses.replace(whole, experts_held=(lo, 4))
+        part = dict(lp, **{k: lp[k][lo:lo + 4] for k in llama.EXPERT_LEAVES})
+        routed.append(llama._mlp_block(part, cfg, h) - shared)
+        with jax.default_matmul_precision("highest"):
+            ref = fam.expert_layer(h, leaves_slice(leaves, lo), 1, _as_mapping(whole, (lo, 4)), shared=False)
+        np.testing.assert_allclose(np.asarray(routed[-1]), np.asarray(ref), atol=1e-4)
+    np.testing.assert_allclose(np.asarray(sum(routed) + shared), np.asarray(want), atol=2e-4)
+    assert float(jnp.abs(want).max()) > 0.1 and float(jnp.abs(routed[1]).max()) > 0.01
+
+
+def leaves_slice(leaves, lo):
+    from xllm_service_tpu.models.llama import EXPERT_LEAVES
+
+    return dict(leaves, **{k: leaves[k][:, lo:lo + 4] for k in EXPERT_LEAVES})
+
+
+def test_chunked_prefill_then_decode_gives_the_benchmark_references_logits():
+    """Seeded weights of the benchmark's family (rehearse-deepseek-tiny:
+    YaRN, group-limited routing, experts 2-5 of 8 held, 1 dense layer),
+    prefill in two chunks and three decode steps through the cache: every
+    logits row read equals the plain reference's (materialised attention,
+    no cache, nothing of the program) at that position."""
+    fam = _family()
+    with open(os.path.join(ROOT, "benchmarks", "configs", "rehearse-deepseek-tiny.json")) as f:
+        m = json.load(f)
+    cfg = fam.model_config(m["name"], m)
+    weights = fam.make_weights(m, jax.random.key(11), jnp.float32)
+    seq = np.random.default_rng(2).integers(0, m["vocab_size"], 64).tolist()
+    k, v = _pool(cfg)
+    tab = _i32([1, 2, 3, 4, 5])
+    _, k, v = deepseek.prefill_batch_step(weights, cfg, k, v, _chunk(seq[:32]), _i32(0), _i32(32), tab)
+    lg, k, v = deepseek.prefill_batch_step(weights, cfg, k, v, _chunk(seq[32:]), _i32(32), _i32(32), tab)
+    rows = [np.asarray(lg[0])]
+    for _ in range(3):
+        seq.append(int(np.argmax(rows[-1])))
+        lg, k, v = deepseek.decode_step(
+            weights, cfg, k, v, _i32(seq[-1]), _i32(len(seq) - 1), tab, jnp.asarray([True]),
+        )
+        rows.append(np.asarray(lg[0]))
+    toks = np.zeros((96,), np.int32)
+    toks[: len(seq)] = seq
+    ref = np.asarray(fam.forward_logits(weights, m, jnp.asarray(toks), jnp.arange(63, 67)))
+    np.testing.assert_allclose(np.stack(rows), ref, atol=3e-4)
+    assert np.abs(ref).max() > 0.5
+
+
+def test_engine_runs_the_mla_family_on_fused_mixed_steps():
+    """executor.fuses_prefill is true for the family: a request admitted
+    while another decodes rides a mixed step (no split-prefill branch),
+    the expert counts leave with the tokens, and the stream is the dense
+    oracle's."""
+    from xllm_service_tpu.runtime.executor import fuses_prefill
+
+    ecfg = EngineConfig(
+        model="deepseek-held-tiny", dtype="float32", block_size=16, num_blocks=64,
+        max_running_requests=4, max_seq_len=256, prefill_buckets=[32, 64],
+    )
+    ex = ModelExecutor(ecfg, init_seed=11)
+    eng = InferenceEngine(ecfg, executor=ex)
+    assert fuses_prefill(ecfg, ex) and ex.supports_mixed
+    rng = np.random.default_rng(12)
+    outs = {}
+
+    def add(name, n, new):
+        prompt = rng.integers(1, 500, n).tolist()
+        toks, done = [], threading.Event()
+
+        def cb(out):
+            for so in out.outputs:
+                toks.extend(so.token_ids)
+            if out.finished:
+                done.set()
+            return True
+
+        eng.add_request(EngineRequest(name, prompt, SamplingParams(temperature=0.0, max_new_tokens=new), cb))
+        outs[name] = (prompt, toks, done, new)
+
+    add("a", 21, 10)
+    for _ in range(3):
+        eng.step()
+    add("b", 45, 6)
+    for _ in range(200):
+        if not eng.has_work():
+            break
+        eng.step()
+    assert eng.mixed_steps > 0
+    for prompt, toks, done, new in outs.values():
+        assert done.is_set() and toks == _oracle_tokens(ex, prompt, new)
+    stats = ex.moe_stats(drain=True)
+    lo, n = ex.cfg.held_experts
+    assert stats["held"] == int(stats["expert_counts"][lo:lo + n].sum()) > 0
+    assert stats["absent"] > 0 and stats["dropped"] == 0 and stats["touched"] > 0
+    text = eng.metrics.render()
+    assert "xllm_engine_moe_experts_touched_total" in text
+    assert f"xllm_engine_cache_row_bytes {ex.cfg.num_layers * 128 * 4}" in text

@@ -251,6 +251,22 @@ def test_mixed_dispatch_is_two_puts(executor):
     assert np.array_equal(np.asarray(tokens)[:R], np.asarray(feed))
 
 
+def test_a_lone_chunk_runs_the_largest_decode_bucket(executor):
+    """A mixed step none of whose decode rows is live reads no table: it
+    runs the program of the largest decode bucket, not one of its own
+    per prefill bucket (lowered inside a measured window when every
+    sequence of an open loop happened to prefill at once)."""
+    ex = executor
+    tok, mask, fb, pos, tables, active = _decode_args()
+    deep = np.array([3, 9, 1, 255], np.int32)  # 16 blocks: the largest
+    ex.mixed_start(_items(1), tok, mask, fb, deep, tables, active | True,
+                   _batch())
+    lowered = ex.lowering_count()
+    ex.mixed_start(_items(1), tok, mask, fb, pos, tables, active & False,
+                   _batch())
+    assert ex.lowering_count() == lowered
+
+
 def test_an_optional_feature_costs_its_own_put(executor):
     ex = executor
     before = ex.dispatch_h2d

@@ -9,16 +9,17 @@ staggered admission through the mixed ragged step, and the composed
 speculative pipeline). Runs on the conftest virtual 8-device CPU
 platform; ep ∈ {2, 4} divide moe-shard-tiny's 8 experts.
 
-The grouped Pallas dispatch is asserted via kernel_report() — `moe` ==
+The grouped Pallas product is asserted via kernel_report() — `moe` ==
 "grouped" and `moe_shards` == ep under the XLLM_MOE_INTERPRET hook —
-not assumed: the interpret-mode kernel actually launches once per ep
+not assumed: the interpret-mode kernels actually launch once per ep
 shard inside the engine's fused steps and must still match the 1-device
 stream bit for bit.
 
-Ops-level: kernel-vs-oracle fuzz over ragged group sizes (balanced,
-skewed, empty experts, capacity overflow), grouped-vs-dense semantic
-parity at lossless capacity, and the XLLM_MOE_KERNEL hatch routing
-matrix.
+Ops-level: kernel-vs-reference fuzz over ragged group sizes (balanced,
+skewed, empty experts, every token on one expert, a held span that
+starts past expert 0), grouped-vs-dense semantic parity, the share test
+(the four spans of experts add up to the whole), and which product the
+platform resolves to.
 """
 
 import threading
@@ -51,13 +52,12 @@ def _cfg(**kw) -> EngineConfig:
 
 @pytest.fixture(autouse=True)
 def _clear_moe_thread_state():
-    """Engine runs register the executor's stats sink / ep context on
-    this thread (trace-time thread-locals); clear them so ops-level
-    tests never emit into a stale executor accumulator."""
+    """Engine runs register the executor's ep context on this thread (a
+    trace-time thread-local); clear it so ops-level tests never run under
+    a stale mesh."""
     from xllm_service_tpu.ops import moe as moe_ops
 
     yield
-    moe_ops.set_stats_sink(None)
     moe_ops.set_ep_context(None)
 
 
@@ -193,8 +193,7 @@ def test_ep_escape_hatch(cpu_devices, monkeypatch):
     grouped oracle under plain GSPMD (moe_shards resolves to 1) and the
     streams still match — the hatch changes the lowering, never the
     numbers."""
-    monkeypatch.setenv("XLLM_MOE_KERNEL", "1")  # grouped-ref off-TPU
-    ref, ref_eng = _run_workload()
+    ref, ref_eng = _run_workload()  # grouped-ref off-TPU
     assert ref_eng.executor.kernel_report()["moe"] == "grouped-ref"
     monkeypatch.setenv("XLLM_SHARDED_KERNELS", "0")
     streams, eng = _run_workload(ep_size=2)
@@ -210,19 +209,39 @@ def test_moe_stats_and_load_signal(cpu_devices, monkeypatch):
     _, eng = _run_workload()
     stats = eng.executor.moe_stats(drain=True)
     assert stats["assignments"] > 0
-    assert stats["dropped"] == 0  # lossless default capacity
+    assert stats["dropped"] == 0  # no capacity, so nothing to drop
     assert int(stats["expert_counts"].sum()) == stats["assignments"]
+    # every expert is held here: no pair is another holder's
+    assert stats["held"] == stats["assignments"] and stats["absent"] == 0
     assert 1.0 / stats["experts"] <= stats["hot_expert_frac"] <= 1.0
-    assert 0.0 < stats["occupancy_frac"] <= 1.0
     text = eng.metrics.render()
     for name in (
         "xllm_engine_moe_assignments_total",
+        'xllm_engine_moe_pairs_total{where="held"}',
+        'xllm_engine_moe_pairs_total{where="absent"}',
         "xllm_engine_moe_dropped_total",
         "xllm_engine_moe_hot_expert_frac",
-        "xllm_engine_moe_group_occupancy_frac",
         "xllm_engine_moe_expert_load",
+        "xllm_engine_moe_pairs_per_expert_count",
+        "xllm_engine_cache_row_bytes",
     ):
         assert name in text, name
+    # the histogram saw one observation a held expert and drained step
+    from benchmarks.harness.stack import parse_metrics
+
+    m = parse_metrics(text)
+    assert m["xllm_engine_moe_pairs_per_expert_count"] > 0
+    assert m["xllm_engine_moe_pairs_per_expert_count"] % stats["experts"] == 0
+    # ... of its pairs a LAYER (executor.book_moe over cfg.expert_layers)
+    assert eng.executor.cfg.expert_layers == eng.executor.cfg.num_layers
+    assert m["xllm_engine_moe_pairs_per_expert_sum"] == pytest.approx(
+        stats["held"] / eng.executor.cfg.expert_layers
+    )
+    # one latent-free GQA row a token: 2 caches x layers x heads x dim x 4
+    cfg = eng.executor.cfg
+    assert m["xllm_engine_cache_row_bytes"] == (
+        2 * cfg.num_layers * cfg.num_kv_heads * cfg.head_dim * 4
+    )
     lm = eng.get_load_metrics()
     assert lm.moe_hot_expert_frac == pytest.approx(
         stats["hot_expert_frac"]
@@ -260,54 +279,79 @@ def test_moe_stats_and_load_signal(cpu_devices, monkeypatch):
         store.close()
 
 
-def test_capacity_overflow_drops_and_counts(cpu_devices, monkeypatch):
-    """A tight XLLM_MOE_CAPACITY_FACTOR forces capacity overflow: the
-    engine still serves (drop-to-zero semantics, never an error) and
-    the dropped-assignment instrument counts it."""
-    monkeypatch.setenv("XLLM_MOE_INTERPRET", "1")
-    monkeypatch.setenv("XLLM_MOE_CAPACITY_FACTOR", "0.5")
-    _, eng = _run_workload()
-    stats = eng.executor.moe_stats(drain=True)
-    assert stats["dropped"] > 0
-    assert stats["assignments"] > stats["dropped"]
+def test_no_pair_dropped_with_every_token_on_one_expert(cpu_devices):
+    """The worst imbalance there is: every token sends all its pairs to
+    the same K experts (one group as large as the step, the others
+    empty). The grouped product has no capacity, so it equals the dense
+    combine, through the reference and through the kernels."""
+    import jax
+    import jax.numpy as jnp
+
+    from xllm_service_tpu.ops import moe as moe_ops
+
+    rng = np.random.RandomState(23)
+    T, K, X, E, F = 40, 2, 8, 128, 128
+    x, _, w, wg, wu, wd = _rand_problem(rng, T, K, X, E, F)
+    topi = jnp.asarray(np.tile(np.array([[5, 2]], np.int32), (T, 1)))
+    gate = jnp.einsum("te,xef->txf", x, wg)
+    up = jnp.einsum("te,xef->txf", x, wu)
+    eo = jnp.einsum("txf,xfe->txe", jax.nn.silu(gate) * up, wd)
+    dense = w[:, 0, None] * eo[:, 5] + w[:, 1, None] * eo[:, 2]
+    for use_kernel in (False, True):
+        y = moe_ops.grouped_moe(
+            x, topi, w, wg, wu, wd, use_kernel=use_kernel,
+            interpret=use_kernel,
+        )
+        assert float(jnp.max(jnp.abs(dense - y))) < 1e-5, use_kernel
 
 
-# -------------------------------------------------- hatch routing
+# ------------------------------------------ which product, which share
 
 
-def test_moe_hatch_routing(cpu_devices, monkeypatch):
-    """XLLM_MOE_KERNEL resolution matrix off-TPU: unset = dense, =1 =
-    grouped-ref (enabled, kernel ineligible without the interpret
-    hook), interpret hook = grouped, =0 beats the hook (forced off)."""
+def test_moe_dispatch_resolution(cpu_devices, monkeypatch):
+    """What the expert product resolves to off-TPU: the reference
+    (plain XLA) by default, the kernels under the interpret
+    hook where the widths are lane multiples. There is no dense path to
+    resolve to and no switch that picks one."""
     from xllm_service_tpu.ops import moe as moe_ops
 
     E, F = 128, 256
-    monkeypatch.delenv("XLLM_MOE_KERNEL", raising=False)
     monkeypatch.delenv("XLLM_MOE_INTERPRET", raising=False)
-    assert moe_ops.resolved_moe_dispatch(E, F) == "dense"
-    assert not moe_ops.grouped_moe_enabled()
-    monkeypatch.setenv("XLLM_MOE_KERNEL", "1")
     assert moe_ops.resolved_moe_dispatch(E, F) == "grouped-ref"
     monkeypatch.setenv("XLLM_MOE_INTERPRET", "1")
     assert moe_ops.resolved_moe_dispatch(E, F) == "grouped"
     # Ineligible geometry (E not a lane multiple) declines the kernel.
     assert moe_ops.resolved_moe_dispatch(96, 64) == "grouped-ref"
-    monkeypatch.setenv("XLLM_MOE_KERNEL", "0")
-    assert moe_ops.resolved_moe_dispatch(E, F) == "dense (forced-off)"
-    assert not moe_ops.grouped_moe_enabled()
+    assert not hasattr(moe_ops, "grouped_moe_enabled")
+    assert not hasattr(moe_ops, "moe_capacity")
 
 
-def test_moe_hatch_off_is_dense_path(cpu_devices, monkeypatch):
-    """With the hatch off the engine serves the pre-ISSUE-15 dense
-    einsum byte for byte: =0 and unset emit identical streams and
-    kernel_report says dense."""
-    monkeypatch.delenv("XLLM_MOE_KERNEL", raising=False)
-    ref, ref_eng = _run_workload()
-    assert ref_eng.executor.kernel_report()["moe"] == "dense"
-    monkeypatch.setenv("XLLM_MOE_KERNEL", "0")
-    streams, eng = _run_workload()
-    assert eng.executor.kernel_report()["moe"] == "dense (forced-off)"
-    assert streams == ref
+def test_held_spans_add_up_to_the_whole(cpu_devices):
+    """The share test at the op: four holders of two experts each, the
+    same router's choice; the parts they compute add up to what one
+    holder of all eight computes, and a holder's part of a token that
+    chose none of its experts is exactly 0."""
+    import jax.numpy as jnp
+
+    from xllm_service_tpu.ops import moe as moe_ops
+
+    rng = np.random.RandomState(29)
+    T, K, X, E, F = 18, 3, 8, 128, 128
+    x, topi, w, wg, wu, wd = _rand_problem(rng, T, K, X, E, F)
+    whole = moe_ops.grouped_moe(x, topi, w, wg, wu, wd, use_kernel=False)
+    for use_kernel in (False, True):
+        parts = [
+            moe_ops.grouped_moe(
+                x, topi, w, wg[lo:lo + 2], wu[lo:lo + 2], wd[lo:lo + 2],
+                first=lo, num_experts=X, use_kernel=use_kernel,
+                interpret=use_kernel,
+            )
+            for lo in (0, 2, 4, 6)
+        ]
+        assert float(jnp.max(jnp.abs(sum(parts) - whole))) < 1e-5
+        none_here = ~np.isin(np.asarray(topi), (4, 5)).any(axis=1)
+        assert none_here.any()
+        assert bool(jnp.all(parts[2][none_here] == 0))
 
 
 # ------------------------------------------- kernel-vs-oracle fuzz
@@ -329,37 +373,38 @@ def _rand_problem(rng, T, K, X, E, F, experts=None):
 
 
 def test_moe_kernel_vs_oracle_fuzz(cpu_devices):
-    """Interpret-mode kernel vs the blockwise oracle over fuzzed ragged
-    group shapes: balanced, skewed (hot experts), EMPTY experts (a
-    restricted routing pool), and capacity overflow — every case must
-    agree to f32 tolerance, dead rows exactly zero."""
+    """Interpret-mode kernels vs the plain-XLA reference over fuzzed
+    ragged group shapes: balanced, skewed (hot experts), EMPTY experts (a
+    restricted routing pool), more pairs than one row tile, and a held
+    span that starts past expert 0: every case must agree to f32
+    tolerance."""
     import jax.numpy as jnp
 
     from xllm_service_tpu.ops import moe as moe_ops
 
     rng = np.random.RandomState(7)
     cases = [
-        dict(T=16, K=2, X=8, E=128, F=128, cap=None),
-        dict(T=9, K=2, X=4, E=128, F=256, cap=None),
+        dict(T=16, K=2, X=8, E=128, F=128),
+        dict(T=9, K=2, X=4, E=128, F=256),
         # Empty experts: routing restricted to 2 of 8 groups.
-        dict(T=12, K=2, X=8, E=128, F=128, cap=None,
-             experts=[1, 6]),
-        # Capacity overflow: cap below the hot group's occupancy.
-        dict(T=16, K=2, X=4, E=128, F=128, cap=3),
-        dict(T=5, K=1, X=8, E=256, F=128, cap=2, experts=[0, 3]),
+        dict(T=12, K=2, X=8, E=128, F=128, experts=[1, 6]),
+        # 300 pairs: three row tiles, spans that cross them.
+        dict(T=100, K=3, X=4, E=128, F=128),
+        dict(T=5, K=1, X=8, E=256, F=128, experts=[0, 3]),
+        # Experts 3-5 of 8 held: the rest of the pairs are not computed.
+        dict(T=33, K=2, X=8, E=128, F=128, held=(3, 3)),
     ]
     for case in cases:
-        cap = case.pop("cap")
         experts = case.pop("experts", None)
+        lo, n = case.pop("held", (0, case["X"]))
         x, topi, w, wg, wu, wd = _rand_problem(
             rng, experts=experts, **case
         )
-        y_ref = moe_ops.grouped_moe(
-            x, topi, w, wg, wu, wd, cap=cap, use_kernel=False,
-        )
+        kw = dict(first=lo, num_experts=case["X"])
+        held = (wg[lo:lo + n], wu[lo:lo + n], wd[lo:lo + n])
+        y_ref = moe_ops.grouped_moe(x, topi, w, *held, use_kernel=False, **kw)
         y_k = moe_ops.grouped_moe(
-            x, topi, w, wg, wu, wd, cap=cap, use_kernel=True,
-            interpret=True,
+            x, topi, w, *held, use_kernel=True, interpret=True, **kw
         )
         err = float(jnp.max(jnp.abs(y_ref - y_k)))
         assert err < 1e-5, (case, err)
@@ -367,9 +412,9 @@ def test_moe_kernel_vs_oracle_fuzz(cpu_devices):
 
 def test_row_mask_excludes_padding(cpu_devices):
     """Dead rows (padding lanes / inactive slots) under row_mask: their
-    outputs are exactly 0, they hold no expert-load stats, and they
-    consume no capacity — a padding row must never displace a REAL
-    token's expert contribution under a tight capacity factor."""
+    outputs are exactly 0, they make no pair (the recorded counts cover
+    the live rows only), and the live rows' values are what they are
+    without the dead ones."""
     import jax.numpy as jnp
 
     from xllm_service_tpu.ops import moe as moe_ops
@@ -379,45 +424,28 @@ def test_row_mask_excludes_padding(cpu_devices):
     x, topi, w, wg, wu, wd = _rand_problem(rng, T, K, X, E, F)
     mask = np.zeros((T,), bool)
     mask[: T // 2] = True  # rows 6..11 are padding
-    captured = []
-    moe_ops.set_stats_sink(
-        lambda c, d, r: captured.append((c.copy(), d, r))
-    )
-    try:
+    with moe_ops.layer_stats() as stats:
         y = moe_ops.grouped_moe(
             x, topi, w, wg, wu, wd, use_kernel=False,
             row_mask=jnp.asarray(mask),
         )
-        y.block_until_ready()
-        import jax
-
-        jax.effects_barrier()
-    finally:
-        moe_ops.set_stats_sink(None)
-    # Dead rows emit exactly zero; stats cover only live rows.
-    assert bool(jnp.all(y[T // 2:] == 0))
-    assert captured and int(captured[0][0].sum()) == (T // 2) * K
-    # Live rows match the unmasked dispatch restricted to those rows
-    # (their group positions shift, but a row's FFN value is
-    # position-independent).
-    y_full = moe_ops.grouped_moe(x, topi, w, wg, wu, wd, use_kernel=False)
-    assert float(jnp.max(jnp.abs(y[: T // 2] - y_full[: T // 2]))) < 1e-6
-    # Under a tight capacity, masked rows never displace live ones:
-    # cap=1 with 6 live rows drops live overflow only — a full-mask run
-    # at the same cap drops MORE (padding stole capacity first).
-    y_cap = moe_ops.grouped_moe(
-        x, topi, w, wg, wu, wd, cap=6, use_kernel=False,
-        row_mask=jnp.asarray(mask),
+    counts = np.asarray(stats.total())  # [2X]: pairs, then touched
+    assert counts.shape == (2 * X,) and int(counts[:X].sum()) == (T // 2) * K
+    np.testing.assert_array_equal(
+        counts[:X], np.bincount(np.asarray(topi)[: T // 2].ravel(), minlength=X)
     )
-    # Every live row fits in cap=6 groups (at most 6 live assignments
-    # per expert), so masked-capacity output == lossless masked output.
-    assert float(jnp.max(jnp.abs(y_cap - y))) < 1e-6
+    np.testing.assert_array_equal(counts[X:], counts[:X] > 0)
+    assert bool(jnp.all(y[T // 2:] == 0))
+    # outside a layer scope nothing is recorded (no tracer can leak)
+    y_full = moe_ops.grouped_moe(x, topi, w, wg, wu, wd, use_kernel=False)
+    assert moe_ops.layer_stats().total() is None
+    assert float(jnp.max(jnp.abs(y[: T // 2] - y_full[: T // 2]))) < 1e-6
 
 
 def test_grouped_matches_dense_at_lossless_capacity(cpu_devices):
-    """Semantic anchor: at lossless capacity the grouped dispatch
-    computes the dense all-experts combine (same experts, same
-    weights) to f32 accumulation noise."""
+    """Semantic anchor: the grouped product computes the dense
+    all-experts combine (same experts, same weights) to f32
+    accumulation noise."""
     import jax
     import jax.numpy as jnp
 

@@ -179,7 +179,6 @@ def test_engine_ep_parity_overlap(cpu_devices, monkeypatch):
     finally:
         # Engine runs register trace-time thread-locals (the
         # test_moe_engine cleanup pattern).
-        moe_ops.set_stats_sink(None)
         moe_ops.set_ep_context(None)
 
 

@@ -401,3 +401,91 @@ def test_brumby_step_compiles_and_keeps_the_state_pool_still(
     for n in whole[1:]:
         layer_bytes *= n
     assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+
+
+# ---- PR 39: the MLA kernels and the grouped expert kernels at DeepSeek-V2's
+# published widths (128 heads x 640 lanes against a one-head latent row;
+# 40 held experts of 5120 x 1536), and the cut configuration's whole mixed
+# step with its latent stack donated.
+
+MLA_H, MLA_C, MLA_KVR, MLA_L = 128, 640, 512, 5
+
+
+def _mla_shapes(one_chip):
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    return s, s((MLA_L, NB, 1, BS, MLA_C))
+
+
+def test_mla_decode_kernel_compiles_at_published_widths(one_chip, no_persistent_cache):
+    from xllm_service_tpu.ops.pallas.mla_attention import mla_attention_kernel
+
+    s, stack = _mla_shapes(one_chip)
+    text = _compile(
+        lambda q, c, bt, sl, l: mla_attention_kernel(q, c, bt, sl, 0.1, MLA_KVR, layer=l),
+        s((64, MLA_H, MLA_C)), stack, s((64, 64), jnp.int32), s((64,), jnp.int32),
+        s((), jnp.int32),
+    )
+    assert "tpu_custom_call" in text and "mla_paged_attention_kernel" in text
+
+
+def test_mla_prefill_kernel_compiles_at_published_widths(one_chip, no_persistent_cache):
+    from xllm_service_tpu.ops.pallas.mla_prefill import mla_flash_prefill_kernel
+
+    s, stack = _mla_shapes(one_chip)
+    text = _compile(
+        lambda q, c, bt, sp, tl, l: mla_flash_prefill_kernel(
+            q, c, bt, sp, tl, 0.1, MLA_KVR, layer=l),
+        s((1, 512, MLA_H, MLA_C)), stack, s((1, 64), jnp.int32), s((1,), jnp.int32),
+        s((1,), jnp.int32), s((), jnp.int32),
+    )
+    assert "tpu_custom_call" in text and "mla_prefill_kernel" in text
+
+
+@pytest.mark.parametrize("pairs", [384, 3456])
+def test_grouped_expert_kernels_compile_at_published_widths(one_chip, no_persistent_cache, pairs):
+    """64 decode rows x 6 and a 512-token chunk beside them x 6: the
+    layers' stacked leaves and a layer index, no layer sliced out."""
+    from xllm_service_tpu.ops.pallas.moe_dispatch import moe_grouped_kernel
+
+    s, _ = _mla_shapes(one_chip)
+    text = _compile(
+        lambda x, g, a, b, c, l: moe_grouped_kernel(x, g, a, b, c, layer=l),
+        s((pairs, 5120)), s((40,), jnp.int32), s((4, 40, 5120, 1536)),
+        s((4, 40, 5120, 1536)), s((4, 40, 1536, 5120)), s((), jnp.int32),
+    )
+    assert "moe_grouped_kernel" in text and "moe_grouped_down_kernel" in text
+    _assert_nothing_moves(text, {"4,40,5120,1536", "40,5120,1536", "4,40,1536,5120", "40,1536,5120"})
+
+
+def test_deepseek_v2_mixed_step_compiles_and_its_stack_stays(one_chip, no_persistent_cache, as_on_tpu):
+    """DeepSeek-V2 as the benchmark cuts it (5 layers, experts 0-39 of
+    160, 25,600 vocabulary rows): the whole mixed step fits the chip
+    beside 10.33 GB of weights, the latent stack and the expert leaves
+    are read where they lie, and the temporaries stay under 0.5 GB."""
+    from xllm_service_tpu.models import deepseek
+
+    cfg = dataclasses.replace(
+        get_model_config("deepseek-v2"), num_layers=5, vocab_size=25600, experts_held=(0, 40)
+    )
+    s, _ = _mla_shapes(one_chip)
+    params = jax.eval_shape(lambda k: deepseek.init_params(cfg, k, jnp.bfloat16), jax.random.key(0))
+    params = jax.tree.map(lambda a: s(a.shape, a.dtype), params)
+    nb, i32 = 2900, jnp.int32
+    stack, dummy = s((5, nb, 1, BS, MLA_C)), s((5, 1, 1, 1, 1))
+    compiled = jax.jit(
+        lambda p, k, v, *a: deepseek.mixed_step(p, cfg, k, v, *a), donate_argnums=(1, 2)
+    ).lower(
+        params, stack, dummy, s((64,), i32), s((64,), i32), s((64, 64), i32), s((64,), jnp.bool_),
+        s((1, 512), i32), s((1,), i32), s((1,), i32), s((1, 64), i32),
+    ).compile()
+    text = compiled.as_text()
+    for kernel in ("kv_write_kernel", "mla_paged_attention_kernel", "mla_prefill_kernel",
+                   "moe_grouped_kernel", "moe_grouped_down_kernel"):
+        assert kernel in text, kernel
+    _assert_nothing_moves(text, {f"5,{nb},1,128,640", f"{nb},1,128,640", "4,40,5120,1536",
+                                 "40,5120,1536", "4,40,1536,5120", "40,1536,5120"})
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 0.5e9
+    assert 12.0e9 < mem.argument_size_in_bytes < 13.5e9  # weights + a 2.4 GB stack

@@ -95,11 +95,18 @@ class ModelConfig:
     # MoE shared experts (DeepSeek style): dense FFN of
     # n_shared_experts * moe_intermediate_size always active.
     n_shared_experts: int = 0
+    # The span of the `num_experts` routed experts this deployment HOLDS,
+    # (first, count); () = all of them. The router stays `num_experts`
+    # wide and selects over all of them; the expert leaves hold `count`
+    # experts and the layer computes the part of its result they give
+    # (the shared experts and the dense path are whole). What the absent
+    # experts would add is another holder's to compute and to add.
+    experts_held: tuple = ()
     # DeepSeek-V2/V3 heterogeneous stack: the first k layers use a dense
     # SwiGLU of `intermediate_size` instead of the MoE block (HF config
     # first_k_dense_replace). The param pytree splits into a `dense_layers`
-    # prefix stack and the MoE `layers` suffix stack; each runs its own
-    # lax.scan (models/deepseek.py _scan_stack).
+    # prefix stack and the MoE `layers` suffix stack: one lax.scan each,
+    # over the same carried latent pool (models/deepseek.py _run_layers).
     first_k_dense_replace: int = 0
     # Power retention (arXiv:2507.04239; models/brumby.py): 0 = softmax
     # attention over a paged cache; p > 0 replaces it by retention of
@@ -118,6 +125,18 @@ class ModelConfig:
     @property
     def is_mla(self) -> bool:
         return self.kv_lora_rank > 0
+
+    @property
+    def held_experts(self) -> tuple:
+        """(first, count) of the routed experts held (experts_held)."""
+        return tuple(self.experts_held) or (0, self.num_experts)
+
+    @property
+    def expert_layers(self) -> int:
+        """Layers with routed experts: all but the dense prefix."""
+        if self.num_experts <= 0:
+            return 0
+        return self.num_layers - self.first_k_dense_replace
 
     @property
     def mla_row_dim(self) -> int:
@@ -156,7 +175,7 @@ def approx_param_count(cfg: ModelConfig) -> int:
         )
     if cfg.is_moe:
         moe_mlp = 3 * E * (
-            cfg.moe_intermediate_size * cfg.num_experts
+            cfg.moe_intermediate_size * cfg.held_experts[1]
             + cfg.n_shared_experts * cfg.moe_intermediate_size
         ) + E * cfg.num_experts  # router
     else:
@@ -541,6 +560,39 @@ register(
 
 register(
     ModelConfig(
+        # deepseek-hetero-tiny as one of four holders sees it: group-
+        # limited routing over 16 experts in 4 groups, experts 4-7 held
+        # (the share test walks the four spans, tests/test_deepseek_mla.py).
+        name="deepseek-held-tiny",
+        vocab_size=512,
+        hidden_size=128,
+        intermediate_size=256,
+        num_layers=3,
+        num_heads=4,
+        num_kv_heads=4,
+        head_dim=32,
+        kv_lora_rank=40,
+        q_lora_rank=48,
+        qk_nope_head_dim=32,
+        qk_rope_head_dim=16,
+        v_head_dim=24,
+        num_experts=16,
+        num_experts_per_tok=3,
+        moe_intermediate_size=64,
+        n_shared_experts=2,
+        first_k_dense_replace=1,
+        topk_method="group_limited_greedy",
+        n_group=4,
+        topk_group=2,
+        norm_topk_prob=False,
+        routed_scaling_factor=4.0,
+        experts_held=(4, 4),
+        max_position_embeddings=1024,
+    )
+)
+
+register(
+    ModelConfig(
         name="mixtral-8x7b",
         vocab_size=32000,
         hidden_size=4096,
@@ -591,6 +643,49 @@ register(
         rope_beta_slow=1.0,
         rope_mscale=1.0,
         rope_mscale_all_dim=1.0,
+    )
+)
+
+register(
+    ModelConfig(
+        name="deepseek-v2",
+        # https://huggingface.co/deepseek-ai/DeepSeek-V2/blob/main/config.json
+        # (arXiv:2405.04434): 236B total, 21B active; MLA + 160 routed
+        # experts in 8 groups (top 6 of the best 3 groups), 2 shared, the
+        # first layer dense. Published sizes: no chip holds them, a cell
+        # cuts them (benchmarks/configs/deepseek-v2.json).
+        vocab_size=102400,
+        hidden_size=5120,
+        intermediate_size=12288,
+        num_layers=60,
+        num_heads=128,
+        num_kv_heads=128,
+        head_dim=128,
+        kv_lora_rank=512,
+        q_lora_rank=1536,
+        qk_nope_head_dim=128,
+        qk_rope_head_dim=64,
+        v_head_dim=128,
+        rope_theta=10000.0,
+        num_experts=160,
+        num_experts_per_tok=6,
+        moe_intermediate_size=1536,
+        n_shared_experts=2,
+        first_k_dense_replace=1,
+        topk_method="group_limited_greedy",
+        n_group=8,
+        topk_group=3,
+        norm_topk_prob=False,
+        routed_scaling_factor=16.0,
+        rms_norm_eps=1e-6,
+        max_position_embeddings=163840,
+        rope_scaling_type="yarn",
+        rope_scaling_factor=40.0,
+        rope_original_max_position=4096,
+        rope_beta_fast=32.0,
+        rope_beta_slow=1.0,
+        rope_mscale=0.707,
+        rope_mscale_all_dim=0.707,
     )
 )
 

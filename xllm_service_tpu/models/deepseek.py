@@ -14,10 +14,16 @@ decode)" as north-star config 3). TPU-first design choices:
     cached tokens is never materialized — scores are one [Hq, C] x [T, C]
     matmul per sequence, MXU-friendly;
   * the module exports the same function surface as models/llama.py
-    (init_params / decode_step / prefill_batch_step / forward_dense), so
-    the executor, engine, PD migration, and host tiers are unchanged; the
-    latent cache rides the k_cache slot ([L, N, 1, BS, C]) and the v_cache
-    slot is a 1-element dummy (models.get_module() reports num_caches=1).
+    (init_params / decode_step / mixed_step / prefill_batch_step /
+    forward_dense), so the executor, engine, PD migration, and host tiers
+    are unchanged; the latent cache rides the k_cache slot
+    ([L, N, 1, BS, C]) and the v_cache slot is a 1-element dummy
+    (models.get_module() reports num_caches=1);
+  * the STACKED latent pool rides the layer scan's carry
+    (llama._scan_layers) through the dense prefix AND the expert suffix:
+    one carried stack, a layer index into it, rows written in place
+    (ops/kv_write.py) by a plan made once a step; the attention ops take
+    the stack and the layer. No scan takes the pool in or gives it back.
 
 Interface contract mirrored from models/llama.py; MLA math follows the
 DeepSeek-V2 paper (arxiv 2405.04434 §2.1) / V3 (arxiv 2412.19437).
@@ -31,8 +37,13 @@ import jax
 import jax.numpy as jnp
 
 from xllm_service_tpu.models.configs import ModelConfig
-from xllm_service_tpu.models.llama import _mlp, _mlp_block, _unembed
-from xllm_service_tpu.ops import kv_cache as kv_cache_ops
+from xllm_service_tpu.models.llama import (
+    _mlp,
+    _mlp_block,
+    _scan_layers,
+    _unembed,
+)
+from xllm_service_tpu.ops import kv_write as kv_write_ops
 from xllm_service_tpu.ops.attention import (
     mla_paged_attention,
     mla_prefill_attention,
@@ -116,13 +127,16 @@ def _layer_stack(
     else:
         layers["w_q"] = w(keys[5], (n, E, Hq * (dn + dr)), E)
     if moe:
+        # The router is as wide as the published expert count; the
+        # expert leaves hold the span this deployment has (experts_held).
         X, Fm = cfg.num_experts, cfg.moe_intermediate_size
+        Xh = cfg.held_experts[1]
         layers.update(
             {
                 "router": w(keys[6], (n, E, X), E),
-                "w_gate": w(keys[7], (n, X, E, Fm), E),
-                "w_up": w(keys[8], (n, X, E, Fm), E),
-                "w_down": w(keys[9], (n, X, Fm, E), Fm),
+                "w_gate": w(keys[7], (n, Xh, E, Fm), E),
+                "w_up": w(keys[8], (n, Xh, E, Fm), E),
+                "w_down": w(keys[9], (n, Xh, Fm, E), Fm),
             }
         )
         if cfg.topk_method == "noaux_tc":
@@ -152,7 +166,8 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
     """Param pytree. With first_k_dense_replace > 0 (real DeepSeek-V2/V3:
     HF config first_k_dense_replace, the first layers dense) the stack
     splits: `dense_layers` holds the k-layer dense prefix, `layers` the
-    (L - k)-layer MoE suffix — each runs its own lax.scan."""
+    (L - k)-layer MoE suffix: one scan each, over the same carried pool
+    (_run_layers)."""
     E, L = cfg.hidden_size, cfg.num_layers
     kd = cfg.first_k_dense_replace
     k_embed, k_lm, k_stack, k_dense = jax.random.split(key, 4)
@@ -180,42 +195,6 @@ def _dense_cfg(cfg: ModelConfig) -> ModelConfig:
     import dataclasses
 
     return dataclasses.replace(cfg, num_experts=0)
-
-
-def _split_stack(tree, k: int):
-    return (
-        jax.tree_util.tree_map(lambda a: a[:k], tree),
-        jax.tree_util.tree_map(lambda a: a[k:], tree),
-    )
-
-
-def _concat_stack(a, b):
-    return jax.tree_util.tree_map(
-        lambda x, y: jnp.concatenate([x, y], axis=0), a, b
-    )
-
-
-def _scan_stack(params, cfg: ModelConfig, make_layer_fn, x, k_caches, v_caches):
-    """Apply the layer stack: one scan for a homogeneous model, or a
-    dense-prefix scan over cache[:k] followed by the MoE-suffix scan over
-    cache[k:] (first_k_dense_replace). The two cache outputs concatenate
-    back to the [L, ...] layout the executor owns; under donation XLA
-    writes the scan outputs directly into slices of the output buffer."""
-    kd = cfg.first_k_dense_replace if "dense_layers" in params else 0
-    if kd == 0:
-        x, (kc, vc) = jax.lax.scan(
-            make_layer_fn(cfg.is_moe), x, (params["layers"], k_caches, v_caches)
-        )
-        return x, kc, vc
-    kc_pre, kc_suf = _split_stack(k_caches, kd)
-    vc_pre, vc_suf = _split_stack(v_caches, kd)
-    x, (kc1, vc1) = jax.lax.scan(
-        make_layer_fn(False), x, (params["dense_layers"], kc_pre, vc_pre)
-    )
-    x, (kc2, vc2) = jax.lax.scan(
-        make_layer_fn(cfg.is_moe), x, (params["layers"], kc_suf, vc_suf)
-    )
-    return x, _concat_stack(kc1, kc2), _concat_stack(vc1, vc2)
 
 
 def _q_heads(lp, cfg: ModelConfig, h: jnp.ndarray, positions: jnp.ndarray):
@@ -274,10 +253,129 @@ def _attn_out(lp, cfg: ModelConfig, ctx_lat: jnp.ndarray) -> jnp.ndarray:
     return jnp.einsum("...h,he->...e", flat, wt(lp["wo"]))
 
 
+def _embed_rows(params: Params, token_ids) -> jnp.ndarray:
+    return params["embed"][token_ids].astype(wdtype(params["layers"]["w_dkv"]))
+
+
+def _layer(lp, cfg, mcfg, x, positions, valid, attend, c):
+    """One layer over a flat batch of token rows x [T, E] at `positions`
+    [T]: the rows' latents, `attend(q_lat [T, Hq, C], rows [T, C], c) ->
+    (ctx [T, Hq, kvr], c)` (which lands the rows in the carried stack and
+    attends over it), the output projection and the MLP over the rows
+    that are `valid` [T]."""
+    h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+    q_nope, q_pe = _q_heads(lp, cfg, h, positions)
+    rows = _latent_rows(lp, cfg, h, positions)
+    ctx, c = attend(_absorb_q(lp, cfg, q_nope, q_pe), rows, c)
+    x = x + _attn_out(lp, cfg, ctx)
+    h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
+    return x + _mlp_block(lp, mcfg, h, rows_valid=valid), c
+
+
+def _write(plan, layer):
+    """rows [T, C] -> the carried stack, in place, by the step's plan."""
+
+    def write(rows, c):
+        (c,) = kv_write_ops.write_rows((c,), plan, (rows[:, None, :],), layer)
+        return c
+
+    return write
+
+
+def _decode_attend(
+    cfg, plan, tables, seq_lens, layer, use_kernel=None, interpret=False
+):
+    """R decode rows: one latent row a sequence lands in the stack, then
+    absorbed attention over its blocks. Returns (write, read)."""
+
+    def read(q_lat, c):
+        return mla_paged_attention(
+            q_lat, c, tables, seq_lens, mla_softmax_scale(cfg),
+            cfg.kv_lora_rank, use_kernel=use_kernel, interpret=interpret,
+            layer=layer,
+        )
+
+    return _write(plan, layer), read
+
+
+def _prefill_attend(
+    cfg, plan, tables, start, length, Lpad: int, layer,
+    use_kernel=None, interpret=False,
+):
+    """P chunks of Lpad rows each (flat [P*Lpad]): the chunks' latents
+    land in the stack, then causal attention over each chunk's context
+    (flash kernel on TPU). Returns (write, read)."""
+
+    def read(q_lat, c):
+        ctx = mla_prefill_attention(
+            q_lat.reshape(-1, Lpad, *q_lat.shape[1:]), c, tables, start,
+            length, mla_softmax_scale(cfg), cfg.kv_lora_rank,
+            use_kernel=use_kernel, interpret=interpret, layer=layer,
+        )
+        return ctx.reshape(-1, *ctx.shape[2:])
+
+    return _write(plan, layer), read
+
+
+def _attend(write, read):
+    def attend(q_lat, rows, c):
+        c = write(rows, c)
+        return read(q_lat, c), c
+
+    return attend
+
+
+def _chunk_coords(start_pos, true_len, Lpad: int):
+    """Flat positions and validity of P chunks of Lpad rows: [P*Lpad]."""
+    offsets = jnp.arange(Lpad, dtype=jnp.int32)[None, :]
+    return (
+        (start_pos[:, None] + offsets).reshape(-1),
+        (offsets < true_len[:, None]).reshape(-1),
+    )
+
+
+def _last_rows(x: jnp.ndarray, true_len: jnp.ndarray) -> jnp.ndarray:
+    """x [P, Lpad, E] -> each chunk's last valid row [P, E]."""
+    return jnp.take_along_axis(
+        x, jnp.maximum(true_len - 1, 0)[:, None, None], axis=1
+    )[:, 0]
+
+
+def _run_layers(params, cfg, x, k_caches, v_caches, positions, valid,
+                make_attend):
+    """The layer stack over flat token rows x [T, E], over the CARRIED
+    latent pool: one scan for a homogeneous model, or the dense-prefix scan
+    (pool layers 0..k-1) followed by the expert-suffix scan (layers
+    k..L-1) for first_k_dense_replace. Both carry the whole stack and
+    index it by layer (llama._scan_layers): no scan slices the pool in or
+    stacks it out, so no step copies it (what PR 29 took out of the llama
+    family). `make_attend(layer)` gives the layer's attend closure (the
+    plans and tables are the step's, made once outside the scans)."""
+
+    def layer_fn(mcfg):
+        def fn(x, lp, layer, c, v):
+            x, c = _layer(
+                lp, cfg, mcfg, x, positions, valid, make_attend(layer), c
+            )
+            return x, c, v
+
+        return fn
+
+    kd = cfg.first_k_dense_replace if "dense_layers" in params else 0
+    if kd > 0:
+        x, k_caches, v_caches = _scan_layers(
+            layer_fn(_dense_cfg(cfg)), x, params, k_caches, v_caches,
+            stack="dense_layers",
+        )
+    return _scan_layers(
+        layer_fn(cfg), x, params, k_caches, v_caches, first_layer=kd
+    )
+
+
 def decode_step(
     params: Params,
     cfg: ModelConfig,
-    k_caches,  # latent cache [L, N, 1, BS, C] (plain or PagedKV)
+    k_caches,  # latent stack [L, N, 1, BS, C] (plain or PagedKV)
     v_caches,  # unused dummy (NUM_CACHES = 1); returned untouched
     token_ids: jnp.ndarray,  # [R]
     positions: jnp.ndarray,  # [R]
@@ -286,43 +384,98 @@ def decode_step(
     use_kernel: bool | None = None,
 ):
     """One generation step for R sequences; mirrors llama.decode_step."""
-    bs = k_caches.shape[3]
-    scale = mla_softmax_scale(cfg)
-    kvr = cfg.kv_lora_rank
-    x = params["embed"][token_ids].astype(wdtype(params["layers"]["w_dkv"]))
-
-    block_idx = positions // bs
-    offset = jnp.where(active, positions % bs, 0)
-    blk = jnp.take_along_axis(block_tables, block_idx[:, None], axis=1)[:, 0]
-    blk = jnp.where(active, blk, 0)
+    plan = kv_write_ops.write_plan(k_caches, block_tables, positions, active, 1)
     seq_lens = jnp.where(active, positions + 1, 0)
-
-    def make_layer_fn(moe: bool):
-        mcfg = cfg if moe else _dense_cfg(cfg)
-
-        def layer_fn(x, scanned):
-            lp, c_l, v_l = scanned
-            h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
-            q_nope, q_pe = _q_heads(lp, cfg, h, positions)
-            rows = _latent_rows(lp, cfg, h, positions)
-            c_l = kv_cache_ops.scatter_rows(c_l, blk, offset, rows[:, None, :])
-            q_lat = _absorb_q(lp, cfg, q_nope, q_pe)
-            ctx = mla_paged_attention(
-                q_lat, c_l, block_tables, seq_lens, scale, kvr,
-                use_kernel=use_kernel,
-            )
-            x = x + _attn_out(lp, cfg, ctx)
-            h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-            x = x + _mlp_block(lp, mcfg, h, rows_valid=active)
-            return x, (c_l, v_l)
-
-        return layer_fn
-
-    x, k_caches, v_caches = _scan_stack(
-        params, cfg, make_layer_fn, x, k_caches, v_caches
+    x, k_caches, v_caches = _run_layers(
+        params, cfg, _embed_rows(params, token_ids), k_caches, v_caches,
+        positions, active,
+        lambda layer: _attend(*_decode_attend(
+            cfg, plan, block_tables, seq_lens, layer, use_kernel
+        )),
     )
-    logits = _unembed(params, cfg, x)
-    return logits, k_caches, v_caches
+    return _unembed(params, cfg, x), k_caches, v_caches
+
+
+def mixed_step(
+    params: Params,
+    cfg: ModelConfig,
+    k_caches,
+    v_caches,
+    dec_tokens: jnp.ndarray,  # [R] int32 — decode-slot input tokens
+    dec_positions: jnp.ndarray,  # [R] int32
+    dec_tables: jnp.ndarray,  # [R, CBd] int32
+    dec_active: jnp.ndarray,  # [R] bool
+    pf_tokens: jnp.ndarray,  # [P, Lpad] int32 — due prefill chunks
+    pf_start: jnp.ndarray,  # [P] int32 (cached tokens before each chunk)
+    pf_len: jnp.ndarray,  # [P] int32 (valid tokens per chunk; 0 = pad row)
+    pf_tables: jnp.ndarray,  # [P, CBp] int32
+    use_ragged: bool | None = None,  # the two MLA kernels' switch here
+    lora_dec=None,
+    lora_pf=None,
+    rope_delta=None,
+    interpret: bool = False,
+):
+    """ONE compiled step for a MIXED batch: R decode slots and P chunked-
+    prefill rows in a single dispatch over the one carried latent stack,
+    with llama.mixed_step's signature and outputs. Unlike the llama
+    family the two halves are ONE batch of R + P*Lpad token rows for every
+    matmul (the projections, the shared and the dense MLP, and above all
+    the expert product, which then streams each touched expert's weights
+    once a step and not once a half); only the attention is two ops, the
+    decode rows' and the chunks' (no ragged latent kernel; their block
+    tables are disjoint, so both halves' rows are written first).
+
+    Returns (dec_logits [R, V], pf_logits [P, V] of each chunk's LAST
+    valid position, k', v')."""
+    if lora_dec is not None or lora_pf is not None or rope_delta is not None:
+        raise NotImplementedError("MLA family: no adapters, no M-RoPE")
+    R = dec_tokens.shape[0]
+    P, Lpad = pf_tokens.shape
+    dec_plan = kv_write_ops.write_plan(
+        k_caches, dec_tables, dec_positions, dec_active, 1
+    )
+    dec_seq_lens = jnp.where(dec_active, dec_positions + 1, 0)
+    pf_positions, pf_valid = _chunk_coords(pf_start, pf_len, Lpad)
+    pf_plan = kv_write_ops.write_plan(
+        k_caches, pf_tables, pf_start, pf_len, Lpad
+    )
+
+    def make_attend(layer):
+        dec_write, dec_read = _decode_attend(
+            cfg, dec_plan, dec_tables, dec_seq_lens, layer,
+            use_ragged, interpret,
+        )
+        pf_write, pf_read = _prefill_attend(
+            cfg, pf_plan, pf_tables, pf_start, pf_len, Lpad, layer,
+            use_ragged, interpret,
+        )
+
+        def attend(q_lat, rows, c):
+            # Both writes, then both reads of the pool as it then is (as
+            # llama.mixed_step orders them): a read between the writes
+            # would make the compiler keep a copy of the stack.
+            c = pf_write(rows[R:], dec_write(rows[:R], c))
+            ctx = [dec_read(q_lat[:R], c), pf_read(q_lat[R:], c)]
+            return jnp.concatenate(ctx, axis=0), c
+
+        return attend
+
+    x = _embed_rows(
+        params, jnp.concatenate([dec_tokens, pf_tokens.reshape(-1)])
+    )
+    x, k_caches, v_caches = _run_layers(
+        params, cfg, x, k_caches, v_caches,
+        jnp.concatenate([dec_positions, pf_positions]),
+        jnp.concatenate([dec_active, pf_valid]),
+        make_attend,
+    )
+    last = _last_rows(x[R:].reshape(P, Lpad, -1), pf_len)
+    return (
+        _unembed(params, cfg, x[:R]),
+        _unembed(params, cfg, last),
+        k_caches,
+        v_caches,
+    )
 
 
 def prefill_batch_step(
@@ -341,11 +494,8 @@ def prefill_batch_step(
     """Batched chunked prefill; mirrors llama.prefill_batch_step (media
     embedding injection included — the EPD encoder stage is model-family
     agnostic)."""
-    bs = k_caches.shape[3]
-    scale = mla_softmax_scale(cfg)
-    kvr = cfg.kv_lora_rank
     P, Lpad = token_ids.shape
-    x = params["embed"][token_ids].astype(wdtype(params["layers"]["w_dkv"]))
+    x = _embed_rows(params, token_ids)
     if embed_overrides is not None and embed_overrides.shape[1] > 0:
         E = x.shape[-1]
         ext = jnp.concatenate([x, jnp.zeros((P, 1, E), x.dtype)], axis=1)
@@ -353,55 +503,21 @@ def prefill_batch_step(
             jnp.arange(P, dtype=jnp.int32)[:, None], override_positions
         ].set(embed_overrides.astype(x.dtype))
         x = ext[:, :Lpad]
-
-    offsets = jnp.arange(Lpad, dtype=jnp.int32)[None, :]
-    positions = start_pos[:, None] + offsets  # [P, Lpad]
-    valid = offsets < true_len[:, None]
-    block_idx = positions // bs
-    blk = jnp.where(
-        valid, jnp.take_along_axis(block_tables, block_idx, axis=1), 0
+    positions, valid = _chunk_coords(start_pos, true_len, Lpad)
+    plan = kv_write_ops.write_plan(
+        k_caches, block_tables, start_pos, true_len, Lpad
     )
-    in_block = jnp.where(valid, positions % bs, 0)
-    flat_blk = blk.reshape(P * Lpad)
-    flat_off = in_block.reshape(P * Lpad)
-
-    def make_layer_fn(moe: bool):
-        mcfg = cfg if moe else _dense_cfg(cfg)
-
-        def layer_fn(x, scanned):
-            lp, c_l, v_l = scanned
-            h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
-            q_nope, q_pe = jax.vmap(
-                lambda hx, pos: _q_heads(lp, cfg, hx, pos)
-            )(h, positions)  # [P, Lpad, Hq, *]
-            rows = jax.vmap(lambda hx, pos: _latent_rows(lp, cfg, hx, pos))(
-                h, positions
-            )  # [P, Lpad, C]
-            c_l = kv_cache_ops.scatter_rows(
-                c_l, flat_blk, flat_off,
-                rows.reshape(P * Lpad, 1, rows.shape[-1]),
-            )
-            q_lat = _absorb_q(lp, cfg, q_nope, q_pe)  # [P, Lpad, Hq, C]
-            ctx = mla_prefill_attention(
-                q_lat, c_l, block_tables, start_pos, true_len, scale, kvr
-            )  # [P, Lpad, Hq, kvr] — flash kernel on TPU
-            x = x + _attn_out(lp, cfg, ctx)
-            h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-            x = x + _mlp_block(lp, mcfg, h, rows_valid=valid)
-            return x, (c_l, v_l)
-
-        return layer_fn
-
-    x, k_caches, v_caches = _scan_stack(
-        params, cfg, make_layer_fn, x, k_caches, v_caches
+    x, k_caches, v_caches = _run_layers(
+        params, cfg, x.reshape(P * Lpad, -1), k_caches, v_caches,
+        positions, valid,
+        lambda layer: _attend(*_prefill_attend(
+            cfg, plan, block_tables, start_pos, true_len, Lpad, layer
+        )),
     )
+    x = x.reshape(P, Lpad, -1)
     if all_logits:
         return _unembed(params, cfg, x), k_caches, v_caches  # [P, Lpad, V]
-    last = jnp.take_along_axis(
-        x, jnp.maximum(true_len - 1, 0)[:, None, None], axis=1
-    )[:, 0]
-    logits = _unembed(params, cfg, last)
-    return logits, k_caches, v_caches
+    return _unembed(params, cfg, _last_rows(x, true_len)), k_caches, v_caches
 
 
 def forward_dense(
@@ -425,15 +541,16 @@ def hidden_dense(
 ) -> jnp.ndarray:
     """Final-norm hidden states [B, L, E] (the /v1/embeddings path).
     `rows_valid` is accepted for function-surface parity with
-    models/llama.py but unused: this naive forward is the MLA
-    correctness oracle and keeps the dense MoE combine (its vmapped
-    per-sequence body cannot host the grouped dispatch's shard_map)."""
+    models/llama.py but unused: this naive forward is the program's
+    ORACLE for the CPU tests (materialised attention, the dense
+    all-experts combine over the experts held, llama._mlp), sharing
+    nothing with the step functions below the projections."""
     B, L = token_ids.shape
     dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     kvr = cfg.kv_lora_rank
     scale = mla_softmax_scale(cfg)
     positions = jnp.arange(L, dtype=jnp.int32)
-    x = params["embed"][token_ids].astype(wdtype(params["layers"]["w_dkv"]))
+    x = _embed_rows(params, token_ids)
     causal = (
         jnp.arange(L)[None, :] <= jnp.arange(L)[:, None]
     )  # [L, L] True = attend

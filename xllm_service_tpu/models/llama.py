@@ -11,10 +11,10 @@ SURVEY.md §2.3). Design choices:
     sequence. Both scatter new K/V into the cache first, then attend over
     gathered context, which makes fresh prefill, chunked prefill, and
     prefix-cache-hit prefill the same code path.
-  * GQA throughout; SwiGLU MLP; optional MoE block (Mixtral-style top-k
-    router). MoE here computes all experts and combines by router weight —
-    exact and fine at test scale; the expert-parallel ragged-dispatch path
-    lives in parallel/ (later rounds route through it).
+  * GQA throughout; SwiGLU MLP; optional MoE block (top-k router). The
+    steps run the routed experts through ONE grouped, ragged product over
+    the experts held (ops/moe.py); `_mlp`'s all-experts einsum is the
+    dense oracle of the tests.
   * Everything is shape-static: R, bucketed prefill lengths, max_blocks.
 """
 
@@ -199,22 +199,19 @@ def _mlp(
         out = _row_parallel("tf,fe->te", h, wt(lp["w_down"]))
         d = lora_ops.maybe_apply(lp, "w_down", h, lora_idx, 1.0)
         return out + d if d is not None else out
-    # MoE: router scores -> top-k weights; every expert's FFN runs on its
-    # own shard and the top-k combine is a CONTRACTION over the expert
-    # axis. With w_gate/w_up/w_down sharded on X over an `ep` mesh axis
-    # (parallel/sharding.py), the XLA SPMD partitioner keeps each device's
-    # expert compute local and inserts one psum for the combine — the EP
-    # serving path, with no gather that would force an all-gather of
-    # [T, X, E] activations. (The grouped ragged dispatch — compute
-    # tracking ACTIVE params — is the XLLM_MOE_KERNEL path in
-    # _mlp_block; this dense all-experts combine is the default and the
-    # semantic reference, docs/MOE.md.)
+    # MoE, the dense ORACLE: every held expert's FFN on every token,
+    # combined by the router's weight (0 where a token did not choose the
+    # expert). The serving steps go through _mlp_block's grouped product,
+    # whose work follows the pairs; this form is what the tests and the
+    # deepseek oracle hold it against (docs/MOE.md).
     topi, weights = moe_route(lp, cfg, x)
     T, X = x.shape[0], cfg.num_experts
     combine = jnp.zeros((T, X), jnp.float32)
     combine = combine.at[
         jnp.arange(T, dtype=jnp.int32)[:, None], topi
     ].set(weights)  # [T, X]: top-k combine weight or 0
+    lo, n = cfg.held_experts
+    combine = combine[:, lo:lo + n]  # the experts absent here add nothing
     gate = jnp.einsum("te,xef->txf", x, wt(lp["w_gate"]))
     up = jnp.einsum("te,xef->txf", x, wt(lp["w_up"]))
     expert_out = jnp.einsum(
@@ -290,18 +287,26 @@ def _shared_experts(lp, x: jnp.ndarray) -> jnp.ndarray:
     )
 
 
+EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
+
+
 def _moe_grouped(
     lp, cfg: ModelConfig, x: jnp.ndarray, row_mask=None
 ) -> jnp.ndarray:
-    """MoE block via the grouped ragged expert dispatch (ops/moe.py —
-    the XLLM_MOE_KERNEL serving path, ISSUE 15): exact _mlp routing
-    (moe_route), ONE grouped launch per expert slice (shard_map over ep
-    under an executor shard context), dense shared-expert tail."""
+    """MoE block over [T, E] rows: _mlp's routing (moe_route, over the
+    published expert count), ONE grouped ragged product over the experts
+    held (ops/moe.py; per ep shard under an executor shard context),
+    dense shared-expert tail."""
     topi, weights = moe_route(lp, cfg, x)
+    # A layer scan hands the experts as the layers' STACKED leaves and an
+    # index (_scan_layers): the grouped kernels read the layer out of the
+    # stack themselves, so no step slices 3 x Xh matrices out of it.
+    leaves, layer = lp.get("experts") or (lp, None)
     out = moe_ops.grouped_moe(
         x, topi, weights,
-        wt(lp["w_gate"]), wt(lp["w_up"]), wt(lp["w_down"]),
-        act=cfg.mlp_act, row_mask=row_mask,
+        *(wt(leaves[k]) for k in EXPERT_LEAVES),
+        act=cfg.mlp_act, layer=layer, first=cfg.held_experts[0],
+        num_experts=cfg.num_experts, row_mask=row_mask,
     )
     if cfg.n_shared_experts > 0:
         out = out + _shared_experts(lp, x)
@@ -311,24 +316,18 @@ def _moe_grouped(
 def _mlp_block(
     lp, cfg: ModelConfig, h: jnp.ndarray, lora_idx=None, rows_valid=None
 ) -> jnp.ndarray:
-    """MLP over [T, E] or batched [P, L, E] activations — every step
-    function's MLP entry point. Default: EXACTLY the split per-row
-    programs (_mlp direct for 2D, vmapped for 3D — the pre-ISSUE-15
-    jaxprs, byte for byte). With the grouped MoE dispatch enabled
-    (ops.moe.grouped_moe_enabled) the leading axes flatten into one
-    token axis for the routed experts — the flatten is OUTSIDE any
-    vmap, which is what lets the dispatch wrap in shard_map over ep —
-    and the SAME flatten applies in every step family (decode, batched
-    prefill, mixed, verify), so grouped-mode streams stay byte-stable
-    across step builders and mesh sizes (docs/MOE.md).
+    """MLP over [T, E] or batched [P, L, E] activations: every step
+    function's MLP entry point. Dense models run _mlp with exactly the
+    split per-row shapes (direct for 2D, vmapped for 3D). An expert model
+    flattens the leading axes into one token axis for the grouped product
+    (the flatten is OUTSIDE any vmap, which is what lets it wrap in
+    shard_map over ep) and the SAME flatten applies in every step family
+    (decode, batched prefill, mixed, verify).
 
-    `rows_valid` (h's leading shape, bool) marks LIVE rows — every step
-    function already owns this mask (decode `active`, prefill/verify
-    `valid`): padding lanes and inactive slots stay out of the grouped
-    dispatch's routing stats and capacity (ops.moe row_mask docstring).
-    The legacy paths ignore it (dense computes padding rows and
-    discards them downstream, exactly as before)."""
-    if cfg.is_moe and moe_ops.grouped_moe_enabled():
+    `rows_valid` (h's leading shape, bool) marks LIVE rows (decode
+    `active`, prefill/verify `valid`): padding lanes and inactive slots
+    make no pair (ops.moe row_mask)."""
+    if cfg.is_moe:
         lead = h.shape[:-1]
         mask = rows_valid.reshape(-1) if rows_valid is not None else None
         y = _moe_grouped(
@@ -387,26 +386,49 @@ def _qkv(lp, cfg: ModelConfig, x: jnp.ndarray, positions: jnp.ndarray,
     return q, k, v
 
 
-def _scan_layers(layer_fn, x, params, k_caches, v_caches):
+def _scan_layers(layer_fn, x, params, k_caches, v_caches,
+                 stack: str = "layers", first_layer: int = 0):
     """The cache-threading layer scan: the stacked caches ride the CARRY
     (never scanned inputs and stacked outputs, which made every layer of
     every step slice, re-tile and restack its whole pool slice: PERF.md,
     PR 29); the scanned inputs are the layer's parameters and its index.
     `layer_fn(x, lp, layer, k_caches, v_caches) -> (x, k_caches,
-    v_caches)` lands its rows in place (kv_write_ops.write_kv, by a plan
-    made once outside the scan) and hands the whole stack plus `layer`
-    to the attention ops."""
+    v_caches)` lands its rows in place (ops/kv_write.py, by a plan made
+    once outside the scan) and hands the whole stack plus `layer` to the
+    attention ops. `stack` names the parameter stack scanned and
+    `first_layer` the pool layer its first entry writes: a model whose
+    stack splits (models/deepseek.py: a dense prefix, an expert suffix)
+    runs one scan a stack over the SAME carried pool. What the layers'
+    expert blocks recorded leaves as a scan output (ops.moe.layer_stats)."""
+
+    leaves = params[stack]
+    # Expert leaves [n, Xh, ...] stay whole (closed over, not scanned):
+    # the grouped kernels take the stack and the layer's index. Quantized
+    # leaves dequantize a layer at a time and are scanned like the rest.
+    experts = {
+        k: leaves[k] for k in EXPERT_LEAVES
+        if k in leaves and getattr(leaves[k], "ndim", 0) == 4
+    }
+    if len(experts) == len(EXPERT_LEAVES):
+        leaves = {k: v for k, v in leaves.items() if k not in experts}
+    else:
+        experts = None
 
     def body(carry, scanned):
-        lp, layer = scanned
-        return layer_fn(carry[0], lp, layer, *carry[1:]), None
+        lp, i = scanned
+        if experts is not None:
+            lp = {**lp, "experts": (experts, i)}
+        with moe_ops.layer_stats() as stats:
+            out = layer_fn(carry[0], lp, first_layer + i, *carry[1:])
+        return out, stats.total()
 
-    n_layers = kv_cache_ops.raw(k_caches).shape[0]
-    (x, k_caches, v_caches), _ = jax.lax.scan(
+    n = jax.tree_util.tree_leaves(leaves)[0].shape[0]
+    (x, k_caches, v_caches), counts = jax.lax.scan(
         body,
         (x, k_caches, v_caches),
-        (params["layers"], jnp.arange(n_layers, dtype=jnp.int32)),
+        (leaves, jnp.arange(n, dtype=jnp.int32)),
     )
+    moe_ops.add_step(counts)
     return x, k_caches, v_caches
 
 

@@ -570,10 +570,11 @@ def mla_paged_attention_gather(
     seq_lens: jnp.ndarray,  # [R] int32 (INCLUDING current token)
     scale: float,
     kv_rank: int,
+    layer=None,  # int32 scalar: c_cache is the STACK [L, N, 1, BS, C]
 ) -> jnp.ndarray:
     """Decode-step MLA attention. Returns the attention-weighted LATENT
     context [R, Hq, kv_rank] (caller applies W_UV per head)."""
-    ctx = kvc.gather_blocks(c_cache, block_table, jnp.float32)
+    ctx = kvc.gather_blocks(c_cache, block_table, jnp.float32, layer=layer)
     R, MB, _, BS, C = ctx.shape
     ctx = ctx.reshape(R, MB * BS, C)
     scores = (
@@ -588,21 +589,21 @@ def mla_paged_attention_gather(
 
 def mla_paged_attention(
     q_lat, c_cache, block_table, seq_lens, scale, kv_rank,
-    use_kernel: bool | None = None, interpret: bool = False,
+    use_kernel: bool | None = None, interpret: bool = False, layer=None,
 ):
-    """Decode MLA attention; Pallas kernel on TPU (opt-in via
-    XLLM_MLA_ATTENTION_KERNEL=1 until validated on hardware — the GQA
-    kernel went through the same gate in round 1), gather elsewhere.
-    Int8 latent caches ride the kernel too (sub-channel scales stream in
-    a separate plane and dequantize in VMEM); `interpret` lets CI drive
-    the kernel branch on CPU."""
+    """Decode MLA attention over the latent pool (the stack plus `layer`,
+    or one layer's 4-D cache): the Pallas kernel on TPU
+    where the tiles are eligible (XLLM_MLA_ATTENTION_KERNEL=0 is the
+    hatch back to the gather path; nothing forces the kernel where the
+    shapes decline it), the gather elsewhere. Int8 latent caches ride the kernel too (sub-channel
+    scales stream in a separate plane and dequantize in VMEM); `interpret`
+    lets CI drive the kernel branch on CPU."""
     import os
 
     if use_kernel is None:
-        env = os.environ.get("XLLM_MLA_ATTENTION_KERNEL")
         use_kernel = (
-            env == "1"
-            and _mla_kernel_ok(c_cache, _on_tpu() or interpret)
+            _mla_kernel_ok(c_cache, _on_tpu() or interpret)
+            and os.environ.get("XLLM_MLA_ATTENTION_KERNEL") != "0"
         )
     if use_kernel:
         from xllm_service_tpu.ops.pallas.mla_attention import (
@@ -611,10 +612,10 @@ def mla_paged_attention(
 
         return mla_attention_kernel(
             q_lat, c_cache, block_table, seq_lens, scale, kv_rank,
-            interpret=interpret,
+            interpret=interpret, layer=layer,
         )
     return mla_paged_attention_gather(
-        q_lat, c_cache, block_table, seq_lens, scale, kv_rank
+        q_lat, c_cache, block_table, seq_lens, scale, kv_rank, layer=layer
     )
 
 
@@ -628,13 +629,14 @@ def mla_prefill_attention(
     kv_rank: int,
     use_kernel: bool | None = None,
     interpret: bool = False,
+    layer=None,  # int32 scalar: c_cache is the STACK [L, N, 1, BS, C]
 ) -> jnp.ndarray:
-    """Batched MLA chunked-prefill attention; Pallas flash kernel
-    (ops/pallas/mla_prefill.py) on TPU, vmapped blockwise scan elsewhere.
-    Int8 latent caches ride both kernel branches (sub-channel scales
-    stream in their own plane, VMEM dequant); XLLM_MLA_PREFILL_KERNEL=0/1
-    forces the flash path, `interpret` drives the kernel branches in
-    CI."""
+    """Batched MLA chunked-prefill attention in ABSORBED form; Pallas
+    flash kernel (ops/pallas/mla_prefill.py) on TPU, vmapped blockwise
+    scan elsewhere. Int8 latent caches ride both kernel branches
+    (sub-channel scales stream in their own plane, VMEM dequant);
+    XLLM_MLA_PREFILL_KERNEL=0/1 forces the flash path, `interpret` drives
+    the kernel branches in CI."""
     import os
 
     quantized = isinstance(c_cache, kvc.PagedKV) and c_cache.quantized
@@ -656,7 +658,7 @@ def mla_prefill_attention(
         seq_lens = jnp.where(true_len > 0, start_pos + 1, 0)
         return mla_multiquery_attention_kernel(
             q_lat, c_cache, block_tables, seq_lens, scale,
-            kv_rank, interpret=interpret,
+            kv_rank, interpret=interpret, layer=layer,
         )
     if use_kernel is None:
         env = os.environ.get("XLLM_MLA_PREFILL_KERNEL")
@@ -675,11 +677,11 @@ def mla_prefill_attention(
 
         return mla_flash_prefill_kernel(
             q_lat, c_cache, block_tables, start_pos, true_len,
-            scale, kv_rank, interpret=interpret,
+            scale, kv_rank, interpret=interpret, layer=layer,
         )
     return jax.vmap(
         lambda qi, ti, sp, tl: mla_prefill_blockwise(
-            qi, c_cache, ti, sp, tl, scale, kv_rank
+            qi, c_cache, ti, sp, tl, scale, kv_rank, layer=layer
         )
     )(q_lat, block_tables, start_pos, true_len)
 
@@ -692,11 +694,12 @@ def mla_prefill_blockwise(
     true_len: jnp.ndarray,  # scalar int32
     scale: float,
     kv_rank: int,
+    layer=None,  # int32 scalar: c_cache is the STACK [L, N, 1, BS, C]
 ) -> jnp.ndarray:
     """Flash-style causal MLA prefill over latent blocks (online softmax,
     O(Lq * BS) peak score memory). Returns [Lq, Hq, kv_rank]."""
     Lq, Hq, C = q_lat.shape
-    BS = kvc.raw(c_cache).shape[2]
+    BS = kvc.raw(c_cache).shape[-2]
     qf = q_lat.astype(jnp.float32)
     rows = start_pos + jnp.arange(Lq, dtype=jnp.int32)
     valid_row = jnp.arange(Lq, dtype=jnp.int32) < true_len
@@ -708,7 +711,9 @@ def mla_prefill_blockwise(
     def body(carry, inputs):
         m_prev, l_prev, acc = carry
         blk_idx, blk_id = inputs
-        blk = kvc.gather_block(c_cache, blk_id, jnp.float32)[0]  # [BS, C]
+        blk = kvc.gather_block(
+            c_cache, blk_id, jnp.float32, layer=layer
+        )[0]  # [BS, C]
         cols = blk_idx * BS + jnp.arange(BS, dtype=jnp.int32)
         scores = jnp.einsum("qhc,kc->qhk", qf, blk) * scale  # [Lq, Hq, BS]
         mask = (cols[None, :] <= rows[:, None]) & valid_row[:, None]
@@ -1145,8 +1150,8 @@ def resolved_mla_kernel_report(c_cache) -> dict:
     """MLA counterpart of resolved_kernel_report: mirrors the actual
     dispatch decisions of mla_paged_attention / mla_prefill_attention —
     including the _mla_kernel_ok tile/platform gate those dispatchers
-    apply — not just the env vars. MLA families keep split stepping
-    (docs/KERNELS.md)."""
+    apply — not just the env vars. A mixed step of the family runs the
+    decode and the prefill op side by side (no ragged latent kernel)."""
     import os
 
     ok = _mla_kernel_ok(c_cache, _on_tpu())
@@ -1154,8 +1159,8 @@ def resolved_mla_kernel_report(c_cache) -> dict:
     dec_env = os.environ.get("XLLM_MLA_ATTENTION_KERNEL")
     pf_env = os.environ.get("XLLM_MLA_PREFILL_KERNEL")
     mq_env = os.environ.get("XLLM_MQ_ATTENTION_KERNEL")
-    # mla_paged_attention: opt-in (env == "1") AND tile-eligible.
-    dec = "mla" if (dec_env == "1" and ok) else "gather"
+    # mla_paged_attention: on where tile-eligible ("0" is the hatch).
+    dec = "mla" if ok and dec_env != "0" else "gather"
     # mla_prefill_attention: default-on for eligible bf16 latents
     # (kernel_ok = ok and not quantized); env == "1" forces, "0" kills.
     pf_ok = ok and not quantized
@@ -1168,7 +1173,7 @@ def resolved_mla_kernel_report(c_cache) -> dict:
     return {
         "decode": dec,
         "prefill": pf,
-        "mixed": "split",
+        "mixed": f"{dec}+{pf}",
         "mq": "mla-mq" if (ok and mq_env == "1") else "blockwise",
         # MLA's latent cache has no KV-head axis to shard — the kernels
         # stay single-launch (docs/SHARDING.md).

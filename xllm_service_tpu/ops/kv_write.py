@@ -1,9 +1,10 @@
 """The serving steps' cache write: a step's new K/V rows into the carried
 stack, in place.
 
-The llama-family step programs carry the STACKED pool through their layer
-scan (models/llama.py `_scan_layers`) and land each layer's new rows in it
-without moving it. Where the rows go is the same for every layer, so it is
+The step programs carry the STACKED pool through their layer scan
+(models/llama.py `_scan_layers`; the latent stack of models/deepseek.py
+rides the same carry) and land each layer's new rows in it without moving
+it. Where the rows go is the same for every layer, so it is
 worked out once per step, outside the scan (`write_plan`); each layer then
 calls `write_kv` with its rows and its index.
 
@@ -176,6 +177,47 @@ def _write_units(caches, rows, units: _Units, layer, lanes, plan):
     )
 
 
+def write_rows(
+    caches: Tuple[CacheLike, ...],  # stacked pools [L, N, Hc, BS, Dc]
+    plan: WritePlan,
+    rows: Tuple[jnp.ndarray, ...],  # one [S*W, H, D] per pool, plan order
+    layer,  # int32 scalar
+) -> Tuple[CacheLike, ...]:
+    """Land one layer's new rows in the stacked pools, in place: one pool
+    for a one-cache family (the latent stack of models/deepseek.py), K
+    and V together for the llama family (`write_kv`). Packed caches
+    (Hc < H, see kv_pack_factor) take the rows reshaped to the packed
+    layout; int8 caches quantize them on the way.
+
+    The plan says which route (module docstring); neither moves a pool."""
+    rows = tuple(pack_rows(r, c) for r, c in zip(rows, caches))
+    if plan.units is None:
+        return tuple(
+            scatter_rows(c, plan.blk, plan.off, r, layer)
+            for c, r in zip(caches, rows)
+        )
+    bare = not isinstance(caches[0], PagedKV)
+    caches = tuple(as_paged(c) for c in caches)
+    seg = lambda x: x.reshape(-1, plan.width, *x.shape[1:])
+    if caches[0].quantized:
+        groups = caches[0].scale.shape[-2]
+        rows, scales = zip(*(quantize_rows(r, groups) for r in rows))
+        scales = _write_units(
+            tuple(c.scale for c in caches), tuple(seg(x) for x in scales),
+            plan.scale_units, layer, True, plan,
+        )
+    else:
+        rows = tuple(r.astype(c.dtype) for r, c in zip(rows, caches))
+        scales = (None,) * len(caches)
+    data = _write_units(
+        tuple(c.data for c in caches), tuple(seg(r) for r in rows),
+        plan.units, layer, False, plan,
+    )
+    if bare:
+        return tuple(data)
+    return tuple(PagedKV(d, sc) for d, sc in zip(data, scales))
+
+
 def write_kv(
     k_cache: CacheLike,  # stacked pools [L, N, Hc, BS, Dc]
     v_cache: CacheLike,
@@ -184,36 +226,5 @@ def write_kv(
     v: jnp.ndarray,
     layer,  # int32 scalar
 ) -> Tuple[CacheLike, CacheLike]:
-    """Land one layer's new K/V rows in the stacked pools, in place.
-    Packed caches (Hc < Hkv, see kv_pack_factor) take the rows reshaped
-    to the packed layout; int8 caches quantize them on the way.
-
-    The plan says which route (module docstring); neither moves the pool."""
-    k, v = pack_rows(k, k_cache), pack_rows(v, v_cache)
-    if plan.units is None:
-        return (
-            scatter_rows(k_cache, plan.blk, plan.off, k, layer),
-            scatter_rows(v_cache, plan.blk, plan.off, v, layer),
-        )
-    bare = not isinstance(k_cache, PagedKV)
-    k_cache, v_cache = as_paged(k_cache), as_paged(v_cache)
-    seg = lambda x: x.reshape(-1, plan.width, *x.shape[1:])
-    if k_cache.quantized:
-        groups = k_cache.scale.shape[-2]
-        (k, ks), (v, vs) = quantize_rows(k, groups), quantize_rows(v, groups)
-        k_scale, v_scale = _write_units(
-            (k_cache.scale, v_cache.scale), (seg(ks), seg(vs)),
-            plan.scale_units, layer, True, plan,
-        )
-    else:
-        k, v = k.astype(k_cache.dtype), v.astype(v_cache.dtype)
-        k_scale = v_scale = None
-    k_data, v_data = _write_units(
-        (k_cache.data, v_cache.data), (seg(k), seg(v)),
-        plan.units, layer, False, plan,
-    )
-    if bare:
-        return k_data, v_data
-    return PagedKV(k_data, k_scale), PagedKV(v_data, v_scale)
-
-
+    """write_rows for the K and V pools of one layer, one launch."""
+    return write_rows((k_cache, v_cache), plan, (k, v), layer)

@@ -19,10 +19,15 @@ Design (one program per SEQUENCE — no head axis in the grid):
     the caller once per token, outside the kernel, exactly like the
     absorbed gather path.
 
-Cache layout: c_cache [N, 1, BS, C] (ops/attention.py MLA contract);
-q_lat [R, Hq, C]; block_table [R, MB]; seq_lens [R]. Returns
-[R, Hq, kv_rank]. C (576 for V3) need not be a multiple of 128 — Mosaic
-lane-pads the VMEM tiles.
+Cache layout: the STACKED latent pool [L, N, 1, BS, C] plus the layer
+index in scalar memory: block DMAs address `[layer, blk, 0]`, so the
+serving steps never slice a layer out of the pool (a 4-D per-layer cache
+is the L = 1 case, as in ops/pallas/paged_attention.py). q_lat
+[R, Hq, C]; block_table [R, MB]; seq_lens [R]. Returns [R, Hq, kv_rank].
+C is the lane-padded row (640 for the 576 of DeepSeek-V2/V3:
+ModelConfig.mla_cache_dim). At the published widths a row of the grid is
+128 heads x 640 lanes against a one-head latent row: 218 FLOP a cached
+byte beside a v5e's ridge of 240, bound by neither side alone.
 """
 
 from __future__ import annotations
@@ -48,10 +53,11 @@ def _mla_kernel(
     # scalar prefetch
     block_table_ref,  # [R, MBp] SMEM
     seq_lens_ref,     # [R] SMEM
+    layer_ref,        # [1] SMEM — which layer of the stack to read
     # inputs
     q_ref,            # [1, Hqp, C] VMEM
-    c_hbm,            # [N, 1, BS, C] HBM — bf16 or int8
-    *rest,            # quantized: cs_hbm [N, 1, G, BS] f32, then
+    c_hbm,            # [L, N, 1, BS, C] HBM — bf16 or int8
+    *rest,            # quantized: cs_hbm [L, N, 1, G, BS] f32, then
     # output
     #   o_ref         # [1, Hqp, KVR] VMEM
     # scratch
@@ -73,6 +79,7 @@ def _mla_kernel(
         o_ref, c_buf, sems = rest
         cs_hbm = s_buf = ssems = None
     r = pl.program_id(0)
+    lyr = layer_ref[0]
     seq_len = seq_lens_ref[r]
     span = chunk * block_size
     if s_rows == 1:
@@ -89,7 +96,7 @@ def _mla_kernel(
     def dmas(slot, c_idx, blk):
         out = [
             mosaic.async_copy(
-                    mosaic.checked_at(c_hbm, blk, 0),
+                    mosaic.checked_at(c_hbm, lyr, blk, 0),
                     mosaic.checked_at(c_buf, slot, pl.ds(c_idx * block_size, block_size)),
                     sems.at[slot, c_idx],
                 )
@@ -98,7 +105,7 @@ def _mla_kernel(
             # Full-extent [G, BS] scale tile (blk on the untiled dim).
             out.append(
                 mosaic.async_copy(
-                    mosaic.checked_at(cs_hbm, blk, 0),
+                    mosaic.checked_at(cs_hbm, lyr, blk, 0),
                     mosaic.checked_at(s_buf, slot, c_idx),
                     ssems.at[slot, c_idx],
                 )
@@ -177,8 +184,11 @@ def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
-def _mla_common(c_cache):
-    """Split a plain-or-PagedKV latent cache into (data, scales, groups).
+def _mla_common(c_cache, layer=None):
+    """Split a plain-or-PagedKV latent cache into (data, scales, groups,
+    layer[1]): data and scales as STACKS ([L, N, 1, BS, C] and
+    [L, N, 1, G, BS]; a per-layer 4-D cache becomes the L = 1 stack, a
+    bitcast, layer 0) and the layer as the int32 scalar-prefetch operand.
 
     Scales stay in their pool-native [N, 1, G, BS] layout (G groups on
     sublanes, BS on lanes, G a multiple of 8 — kv_cache.mla_scale_groups
@@ -191,17 +201,19 @@ def _mla_common(c_cache):
     from xllm_service_tpu.ops import kv_cache as kvc
 
     c_cache = kvc.as_paged(c_cache)
-    data = c_cache.data
+    data, sc = c_cache.data, c_cache.scale
+    if data.ndim == 4:
+        data, sc, layer = data[None], None if sc is None else sc[None], 0
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
     if not c_cache.quantized:
-        return data, None, 1
-    sc = c_cache.scale
+        return data, None, 1, layer
     if sc.ndim != data.ndim or sc.shape[-2] % 8:
         raise ValueError(
             f"int8 MLA caches need grouped [N, 1, G, BS] scales with "
             f"G % 8 == 0 (got scale shape {sc.shape}); allocate via "
             f"kv_cache.alloc_cache with kv_cache.mla_scale_groups"
         )
-    return data, sc.astype(jnp.float32), sc.shape[-2]
+    return data, sc.astype(jnp.float32), sc.shape[-2], layer
 
 
 @functools.partial(
@@ -209,18 +221,19 @@ def _mla_common(c_cache):
 )
 def mla_attention_kernel(
     q_lat: jnp.ndarray,        # [R, Hq, C]
-    c_cache,                   # [N, 1, BS, C] plain array or PagedKV
+    c_cache,                   # [L, N, 1, BS, C] stack (or one layer's 4-D)
     block_table: jnp.ndarray,  # [R, MB] int32
     seq_lens: jnp.ndarray,     # [R] int32
     scale: float,
     kv_rank: int,
     interpret: bool = False,
     chunk: int = 4,
+    layer=None,                # int32 scalar when the cache is the stack
 ) -> jnp.ndarray:
-    data, scales, G = _mla_common(c_cache)
+    data, scales, G, layer = _mla_common(c_cache, layer)
     quantized = scales is not None
     R, Hq, C = q_lat.shape
-    N, _, BS, _ = data.shape
+    BS = data.shape[-2]
     MB = block_table.shape[1]
     Hqp = _round_up(Hq, 8)
     CH = max(1, min(chunk, MB))
@@ -235,10 +248,10 @@ def mla_attention_kernel(
 
     hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)
     in_specs = [
-        pl.BlockSpec((1, Hqp, C), lambda r, bt, sl: (r, 0, 0)),
+        pl.BlockSpec((1, Hqp, C), lambda r, *_: (r, 0, 0)),
         hbm,
     ]
-    inputs = [bt, seq_lens.astype(jnp.int32), qr, data]
+    inputs = [bt, seq_lens.astype(jnp.int32), layer, qr, data]
     scratch = [
         pltpu.VMEM((2, CH * BS, C), data.dtype),
         pltpu.SemaphoreType.DMA((2, CH)),
@@ -253,10 +266,10 @@ def mla_attention_kernel(
         ]
         row_bytes += 4 * G
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(R,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, Hqp, kv_rank), lambda r, bt, sl: (r, 0, 0)),
+        out_specs=pl.BlockSpec((1, Hqp, kv_rank), lambda r, *_: (r, 0, 0)),
         scratch_shapes=scratch,
     )
     kernel = functools.partial(
@@ -265,7 +278,7 @@ def mla_attention_kernel(
     )
     out = pl.pallas_call(
         kernel,
-        name="mla_attention_kernel",  # op name in the device trace
+        name="mla_paged_attention_kernel",  # op name in the device trace
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R, Hqp, kv_rank), q_lat.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -286,7 +299,7 @@ def mla_attention_kernel(
 )
 def mla_multiquery_attention_kernel(
     q_lat: jnp.ndarray,        # [R, S, Hq, C] — S consecutive query tokens
-    c_cache,                   # [N, 1, BS, C] plain array or PagedKV
+    c_cache,                   # [L, N, 1, BS, C] stack (or one layer's 4-D)
     block_table: jnp.ndarray,  # [R, MB] int32
     seq_lens: jnp.ndarray,     # [R] int32 — context INCLUDING the FIRST
     # query token; row s attends to seq_lens + s rows
@@ -294,15 +307,16 @@ def mla_multiquery_attention_kernel(
     kv_rank: int,
     interpret: bool = False,
     chunk: int = 4,
+    layer=None,                # int32 scalar when the cache is the stack
 ) -> jnp.ndarray:
     """Speculative-verify MLA attention: the decode kernel with S query
     rows per sequence riding one [S*Hqp, C] tile — same latent-cache HBM
     traffic as one decode step, S times the MXU work. Causal masking
     within the step is by tile-row // Hqp. Returns [R, S, Hq, kv_rank]."""
-    data, scales, G = _mla_common(c_cache)
+    data, scales, G, layer = _mla_common(c_cache, layer)
     quantized = scales is not None
     R, S, Hq, C = q_lat.shape
-    N, _, BS, _ = data.shape
+    BS = data.shape[-2]
     MB = block_table.shape[1]
     Hqp = _round_up(Hq, 8)
     CH = max(1, min(chunk, MB))
@@ -318,10 +332,10 @@ def mla_multiquery_attention_kernel(
 
     hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)
     in_specs = [
-        pl.BlockSpec((1, S * Hqp, C), lambda r, bt, sl: (r, 0, 0)),
+        pl.BlockSpec((1, S * Hqp, C), lambda r, *_: (r, 0, 0)),
         hbm,
     ]
-    inputs = [bt, seq_lens.astype(jnp.int32), qr, data]
+    inputs = [bt, seq_lens.astype(jnp.int32), layer, qr, data]
     scratch = [
         pltpu.VMEM((2, CH * BS, C), data.dtype),
         pltpu.SemaphoreType.DMA((2, CH)),
@@ -336,11 +350,11 @@ def mla_multiquery_attention_kernel(
         ]
         row_bytes += 4 * G
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(R,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (1, S * Hqp, kv_rank), lambda r, bt, sl: (r, 0, 0)
+            (1, S * Hqp, kv_rank), lambda r, *_: (r, 0, 0)
         ),
         scratch_shapes=scratch,
     )

@@ -13,9 +13,18 @@ axis, heads ride as sublane rows — double-buffered block DMA bounded by
 each tile's OWN context length, online softmax, causal + ragged masking
 by absolute position.
 
-Layouts: q_lat [P, Lpad, Hq, C] (chunk-relative), cache [N, 1, BS, C],
-block_table [P, CB] int32, start_pos/true_len [P] int32. Returns
-[P, Lpad, Hq, kv_rank]. Oracle: ops/attention.mla_prefill_blockwise.
+Layouts: q_lat [P, Lpad, Hq, C] (chunk-relative), the STACKED latent pool
+[L, N, 1, BS, C] plus the layer index in scalar memory (a 4-D per-layer
+cache is the L = 1 case), block_table [P, CB] int32, start_pos/true_len
+[P] int32. Returns [P, Lpad, Hq, kv_rank]. Oracle:
+ops/attention.mla_prefill_blockwise.
+
+A query tile is TQ positions x Hq heads of rows, held to about
+`rows_cap` rows: at 128 heads that is 8 positions (1024 rows of 640
+lanes, 1.3 MB; scores and the accumulator [1024, 512] float32), so a
+512-token chunk walks its context 64 times, 1,280 bytes a cached
+position each: 0.8 ms a layer at 7680 cached tokens beside 6 ms of MXU
+work (absorbed form: 2 x (640 + 512) FLOP a head, query and position).
 """
 
 from __future__ import annotations
@@ -37,10 +46,11 @@ def _mla_prefill_kernel(
     block_table_ref,  # [P, MBp] SMEM
     start_pos_ref,    # [P] SMEM
     true_len_ref,     # [P] SMEM
+    layer_ref,        # [1] SMEM — which layer of the stack to read
     # inputs
     q_ref,            # [1, 1, Rp, C] VMEM (one tile's TQ*Hq rows)
-    c_hbm,            # [N, 1, BS, C] HBM — bf16 or int8
-    *rest,            # quantized: cs_hbm [N, 1, G, BS] f32, then
+    c_hbm,            # [L, N, 1, BS, C] HBM — bf16 or int8
+    *rest,            # quantized: cs_hbm [L, N, 1, G, BS] f32, then
     # output
     #   o_ref         # [1, 1, Rp, KVR] VMEM
     # scratch
@@ -63,6 +73,7 @@ def _mla_prefill_kernel(
         cs_hbm = s_buf = ssems = None
     p = pl.program_id(0)
     t = pl.program_id(1)
+    lyr = layer_ref[0]
     start = start_pos_ref[p]
     n_valid = true_len_ref[p]
     span = chunk * block_size
@@ -74,7 +85,7 @@ def _mla_prefill_kernel(
     def dmas(slot, c_idx, blk):
         out = [
             mosaic.async_copy(
-                    mosaic.checked_at(c_hbm, blk, 0),
+                    mosaic.checked_at(c_hbm, lyr, blk, 0),
                     mosaic.checked_at(c_buf, slot, pl.ds(c_idx * block_size, block_size)),
                     sems.at[slot, c_idx],
                 )
@@ -84,7 +95,7 @@ def _mla_prefill_kernel(
             # see mla_attention._mla_common for why.
             out.append(
                 mosaic.async_copy(
-                    mosaic.checked_at(cs_hbm, blk, 0),
+                    mosaic.checked_at(cs_hbm, lyr, blk, 0),
                     mosaic.checked_at(s_buf, slot, c_idx),
                     ssems.at[slot, c_idx],
                 )
@@ -173,11 +184,14 @@ def _round_up(x: int, m: int) -> int:
 
 
 @functools.partial(
-    jax.jit, static_argnames=("scale", "kv_rank", "interpret", "chunk", "tile_q")
+    jax.jit,
+    static_argnames=(
+        "scale", "kv_rank", "interpret", "chunk", "tile_q", "rows_cap"
+    ),
 )
 def mla_flash_prefill_kernel(
     q_lat: jnp.ndarray,        # [P, Lpad, Hq, C]
-    c_cache,                   # [N, 1, BS, C] plain array or PagedKV
+    c_cache,                   # [L, N, 1, BS, C] stack (or one layer's 4-D)
     block_table: jnp.ndarray,  # [P, MB] int32
     start_pos: jnp.ndarray,    # [P] int32
     true_len: jnp.ndarray,     # [P] int32
@@ -186,16 +200,18 @@ def mla_flash_prefill_kernel(
     interpret: bool = False,
     chunk: int = 4,
     tile_q: int = 128,
+    layer=None,                # int32 scalar when the cache is the stack
+    rows_cap: int = 1024,      # TQ * Hq rows a query tile, about
 ) -> jnp.ndarray:
     from xllm_service_tpu.ops.pallas.mla_attention import _mla_common
 
-    c_data, scales, G = _mla_common(c_cache)
+    c_data, scales, G, layer = _mla_common(c_cache, layer)
     quantized = scales is not None
     c_cache = c_data
     P, Lpad, Hq, C = q_lat.shape
-    N, _, BS, _ = c_cache.shape
+    BS = c_cache.shape[-2]
     MB = block_table.shape[1]
-    TQ = min(tile_q, _round_up(Lpad, 8))
+    TQ = min(tile_q, _round_up(Lpad, 8), max(8, rows_cap // Hq // 8 * 8))
     while (TQ * Hq) % 8:
         TQ += 1
     Lp = _round_up(Lpad, TQ)
@@ -217,11 +233,11 @@ def mla_flash_prefill_kernel(
 
     hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)
     in_specs = [
-        pl.BlockSpec((1, 1, Rp, C), lambda p, t, bt, sp, tl: (p, t, 0, 0)),
+        pl.BlockSpec((1, 1, Rp, C), lambda p, t, *_: (p, t, 0, 0)),
         hbm,
     ]
     inputs = [
-        bt, start_pos.astype(jnp.int32), true_len.astype(jnp.int32),
+        bt, start_pos.astype(jnp.int32), true_len.astype(jnp.int32), layer,
         qt, c_cache,
     ]
     scratch = [
@@ -238,11 +254,11 @@ def mla_flash_prefill_kernel(
         ]
         row_bytes += 4 * G
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(P, NT),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (1, 1, Rp, kv_rank), lambda p, t, bt, sp, tl: (p, t, 0, 0)
+            (1, 1, Rp, kv_rank), lambda p, t, *_: (p, t, 0, 0)
         ),
         scratch_shapes=scratch,
     )
@@ -252,11 +268,12 @@ def mla_flash_prefill_kernel(
     )
     out = pl.pallas_call(
         kernel,
-        name="mla_flash_prefill_kernel",  # op name in the device trace
+        name="mla_prefill_kernel",  # op name in the device trace
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((P, NT, Rp, kv_rank), q_lat.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=64 * 1024 * 1024,
         ),
         cost_estimate=pl.CostEstimate(
             flops=2 * P * Hq * (C + kv_rank) * Lp * MB * BS // max(NT, 1),

@@ -1,64 +1,38 @@
-"""Pallas TPU grouped ragged MoE expert dispatch: ONE kernel launch over
-variable-size per-expert token groups.
+"""Pallas TPU grouped, ragged expert product: the routed half of an MoE
+layer over the experts THIS holder has, with work that follows the pairs.
 
-The MoE serving shape the xLLM Technical Report's engine (arxiv
-2510.14686) is built around, with the PR-9 ragged-attention design DNA
-(ISSUE 15 tentpole): router top-k produces X token groups of dynamic,
-wildly unequal sizes; instead of X per-expert matmul launches (dispatch
-overhead and dead launches for empty experts) or a dense all-experts
-einsum (compute ∝ total params instead of ACTIVE params), one launch
-walks the grouped token buffer tile by tile and streams only the expert
-weights the live rows in each tile actually need.
+A pair is one (token, chosen expert). ops/moe.py sorts the pairs that fall
+to a held expert by expert, so expert g's rows are the contiguous span
+[off[g], off[g+1]) of one row buffer xs [M, E]; pairs that fall to absent
+experts sort behind them and are never visited. Nothing has a capacity,
+so nothing can be dropped: the buffer holds every pair the step can make
+(M = T*K rounded up to a tile), whatever the imbalance.
 
-Contract (shared with ops.moe.moe_blockwise, the CPU/parity oracle):
+Two launches, the same walk (after the grouped matmul of MegaBlocks,
+arXiv:2211.15841, as jax.experimental.pallas.ops.tpu.megablox lays it
+out for the TPU):
 
-  * tokens ride GROUPED: xg [G, E] is the capacity-padded per-expert
-    layout — expert e's tokens occupy rows [e*cap, e*cap + occ[e]), in
-    router-assignment order; rows past occ[e] (and the padding tail
-    past Xl*cap) are DEAD and emit zeros. `cap` is the STATIC per-group
-    capacity (the seg_lens analog — group offsets e*cap are fixed at
-    trace time), `occ` the dynamic occupancy (the q_len analog;
-    occ[e] == 0 = empty expert). ops.moe builds this layout in-graph
-    from the router output (scatter by expert*cap + rank).
-  * weights ride pre-split on the F axis so every DMA offset is a
-    LEADING-dim index (mosaic_rules rule 2): w_gate/w_up
-    [Xl, NF, E, FT], w_down [Xl, NF, FT, E] with NF*FT == F. The
-    wrapper relayouts from the model's [Xl, E, F]/[Xl, F, E] leaves;
-    a production checkpoint loader can persist this layout and skip
-    the per-call transpose.
+  * `moe_grouped_kernel` (gate and up, fused with the activation):
+    grid (step, k tile). A STEP is one (expert, row tile) meeting: expert
+    g meets the tiles its span touches, so a tile that holds the ends of
+    several spans is met once by each, consecutively, and each meeting
+    stores only its own rows. Steps beyond the live ones stay on the last
+    live step's last blocks (no DMA) and compute nothing. E is tiled on the k
+    axis with float32 accumulators, so a 5120-wide model's weight blocks
+    fit VMEM; the weights are read where the model keeps them, the
+    STACKED leaves [n, Xh, E, F] with the layer in scalar memory (a
+    per-layer [Xh, E, F] leaf is the n = 1 case): no layer's experts are
+    sliced out of the stack for the call (1.9 GB a layer at DeepSeek-V2's
+    widths, read and written once more a step), no relayout.
+  * `moe_grouped_down_kernel`: grid (n tile, step), F whole, E tiled on
+    the output's lanes.
 
-Design (the ragged-attention kernel's structure with expert-weight DMA
-in place of KV-page DMA):
+The cost follows the live steps: at most (row tiles + held experts - 1),
+about one a touched expert for a decode batch (its weights streamed once:
+the HBM floor) and about (pairs / tile + touched experts) for a chunk.
 
-  * grid = (NT,): one program per TT-row tile of the grouped buffer.
-    Tiles freely CROSS group boundaries (cap need not be a TT
-    multiple), so the launch count depends only on G, not on how the
-    router skewed the groups.
-  * per tile, the kernel loops over the experts overlapping it (the
-    range is STATIC — group offsets are static — and rides scalar
-    prefetch like the ragged kernel's tile_start/tile_cnt), and per
-    expert streams that expert's weights HBM→VMEM through a 2-slot
-    double buffer, one [E, FT]+[E, FT]+[FT, E] f-chunk per inner step
-    (F-chunking keeps VMEM residency at 6·E·FT·itemsize regardless of
-    F; E itself is not tiled — DeepSeek-V3-scale E needs an E-tile
-    axis before chip validation, noted in docs/MOE.md).
-  * the whole [TT, E] x [E, FT] gate/up matmuls are ONE MXU issue per
-    chunk; rows not owned by the current expert (other groups, dead
-    capacity tail) mask their activations to 0 before the down-proj
-    accumulation, so the accumulator needs no per-expert state. A
-    tile whose overlap with an expert's LIVE prefix is empty skips
-    that expert's DMA and compute entirely — with a balanced router
-    the streamed/computed work tracks occ (≈ T·K rows, the ACTIVE
-    params), not X·cap.
-  * TPU grid programs run sequentially per core, so serializing a
-    tile's experts costs nothing vs per-expert launches — the fusion
-    buys one launch, expert skipping at tile granularity, and weight
-    DMA overlapped with the previous chunk's matmuls.
-
-Following the repo's opt-in-until-chip-validated convention the kernel
-is NEW silicon surface: XLLM_MOE_KERNEL=1 opts in (XLLM_MOE_INTERPRET=1
-drives it in interpret mode on CPU for CI), queued as moe-* cases for
-the next chip window (docs/KERNELS.md).
+Oracle and CPU path: ops/moe.py `expert_product_reference`
+(plain XLA). `interpret=True` runs the kernels on the CPU for CI.
 """
 
 from __future__ import annotations
@@ -70,228 +44,189 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from xllm_service_tpu.ops.pallas import mosaic_rules as mosaic
+VMEM_LIMIT = 64 * 1024 * 1024  # weight blocks of a 5120-wide model, twice
 
 
-def tile_rows(group_rows: int, tile_q: int = 128) -> int:
-    """Static tile height over the grouped token buffer: TT rows per
-    program, 8-row (sublane) aligned, capped at `tile_q`."""
-    r = (group_rows + 7) // 8 * 8
-    return max(8, min(tile_q, r))
+def tile_rows(rows: int, tile_q: int = 128) -> int:
+    """Static row-tile height over the sorted pair buffer: 16-row (bf16
+    sublane) aligned, capped at `tile_q`."""
+    return max(16, min(tile_q, (rows + 15) // 16 * 16))
 
 
-def f_chunk(F: int, cap: int = 512) -> int:
-    """Static F-axis chunk: the largest 128-multiple divisor of F that is
-    <= cap — one double-buffered [E, FT] weight slice per inner step."""
-    ft = min(F, cap)
-    ft -= ft % 128
-    while F % ft:
-        ft -= 128
-    return ft
+def lane_tile(width: int, cap: int) -> int:
+    """The largest 128-multiple divisor of `width` that is <= cap."""
+    t = min(width, cap)
+    t -= t % 128
+    while width % t:
+        t -= 128
+    return t
 
 
-def _tile_expert_ranges(n_tiles: int, tt: int, cap: int, n_experts: int):
-    """Static per-tile (first_expert, expert_count): group offsets are
-    e*cap, so the experts overlapping tile t form a contiguous static
-    range; tiles wholly in the padding tail carry (0, 0)."""
-    first, cnt = [], []
-    for t in range(n_tiles):
-        lo, hi = t * tt, (t + 1) * tt
-        f = min(lo // cap, n_experts)
-        c = max(0, min(-(-hi // cap), n_experts) - f)
-        first.append(f if c else 0)
-        cnt.append(c)
-    return first, cnt
+def group_steps(group_sizes: jnp.ndarray, m_tiles: int, tm: int):
+    """The walk both kernels share, from the held experts' pair counts:
+    (offsets [Xh+1], step_group [S], step_tile [S], live steps) with
+    S = m_tiles + Xh - 1 static. Expert g meets tiles off[g] // tm ..
+    (off[g+1] - 1) // tm; an empty expert meets none."""
+    sizes = group_sizes.astype(jnp.int32)
+    n = sizes.shape[0]
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    first = starts // tm
+    tiles = jnp.where(sizes > 0, (ends - 1) // tm - first + 1, 0)
+    step_end = jnp.cumsum(tiles)
+    live = step_end[-1]
+    s = jnp.arange(m_tiles + n - 1, dtype=jnp.int32)
+    g = jnp.minimum(
+        jnp.searchsorted(step_end, s, side="right").astype(jnp.int32), n - 1
+    )
+    t = jnp.minimum(first[g] + s - (step_end - tiles)[g], m_tiles - 1)
+    last = jnp.maximum(live - 1, 0)
+    g = jnp.where(s < live, g, g[last])
+    t = jnp.where(s < live, t, t[last])
+    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends])
+    return offsets, g, t, live.reshape(1)
 
 
-def _moe_kernel(
-    # scalar prefetch
-    occ_ref,        # [Xl] SMEM — dynamic live rows per expert group
-    tfirst_ref,     # [NT] SMEM — first expert overlapping each tile
-    tcnt_ref,       # [NT] SMEM — experts overlapping each tile
-    # inputs
-    x_ref,          # [TT, E] VMEM — one tile of grouped token rows
-    wg_hbm,         # [Xl, NF, E, FT] HBM
-    wu_hbm,         # [Xl, NF, E, FT] HBM
-    wd_hbm,         # [Xl, NF, FT, E] HBM
-    # outputs + scratch
-    o_ref,          # [TT, E] VMEM
-    wg_buf,         # [2, E, FT] VMEM
-    wu_buf,         # [2, E, FT] VMEM
-    wd_buf,         # [2, FT, E] VMEM
-    sems,           # DMA sems [2, 3]
-    *,
-    cap: int,
-    tt: int,
-    n_f: int,
-    act: str,
+def _own_rows(off_ref, g, tile, tm: int, width: int):
+    rows = tile * tm + jax.lax.broadcasted_iota(jnp.int32, (tm, width), 0)
+    return (rows >= off_ref[g]) & (rows < off_ref[g + 1])
+
+
+def _gate_up_kernel(
+    off_ref, grp_ref, tile_ref, live_ref, layer_ref,  # scalar prefetch
+    x_ref,   # [tm, tk]
+    wg_ref,  # [tk, F] expert grp[s]'s gate rows of this k tile
+    wu_ref,  # [tk, F]
+    h_ref,   # [tm, F] out: act(gate) * up for the rows this expert owns
+    acc_g, acc_u,  # [tm, F] float32
+    *, tm: int, k_tiles: int, act: str,
 ):
-    # The ONE activation selector (ops/moe.py) — kernel, oracle, and
-    # dense path must stay in lockstep on activation semantics.
     from xllm_service_tpu.ops.moe import _act_fn
 
-    t = pl.program_id(0)
-    x = x_ref[...]  # [TT, E]
-    row0 = t * tt
-    rows = row0 + jax.lax.broadcasted_iota(jnp.int32, (tt, 1), 0)
-    activate = _act_fn(act)
+    s, k = pl.program_id(0), pl.program_id(1)
 
-    def dmas(slot, e, c):
-        return [
-            mosaic.async_copy(
-                mosaic.checked_at(wg_hbm, e, c),
-                mosaic.checked_at(wg_buf, slot),
-                sems.at[slot, 0],
-            ),
-            mosaic.async_copy(
-                mosaic.checked_at(wu_hbm, e, c),
-                mosaic.checked_at(wu_buf, slot),
-                sems.at[slot, 1],
-            ),
-            mosaic.async_copy(
-                mosaic.checked_at(wd_hbm, e, c),
-                mosaic.checked_at(wd_buf, slot),
-                sems.at[slot, 2],
-            ),
-        ]
+    @pl.when(s < live_ref[0])
+    def _():
+        @pl.when(k == 0)
+        def _():
+            acc_g[...] = jnp.zeros_like(acc_g)
+            acc_u[...] = jnp.zeros_like(acc_u)
 
-    def expert_body(bi, acc):
-        e = tfirst_ref[t] + bi
-        lo = e * cap
-        # Overlap of the expert's LIVE prefix with this tile: empty →
-        # the whole f-chunk walk (DMA included) is skipped, which is
-        # what makes compute track occupancy instead of X*cap.
-        s = jnp.maximum(lo, row0)
-        en = jnp.minimum(lo + occ_ref[e], row0 + tt)
-        nc = jnp.where(en > s, n_f, 0)
+        x = x_ref[...]
+        acc_g[...] += jnp.dot(x, wg_ref[...], preferred_element_type=jnp.float32)
+        acc_u[...] += jnp.dot(x, wu_ref[...], preferred_element_type=jnp.float32)
 
-        @pl.when(nc > 0)
-        def _first():
-            for d in dmas(0, e, 0):
-                d.start()
+        @pl.when(k == k_tiles - 1)
+        def _():
+            own = _own_rows(off_ref, grp_ref[s], tile_ref[s], tm, h_ref.shape[1])
+            h = (_act_fn(act)(acc_g[...]) * acc_u[...]).astype(h_ref.dtype)
+            h_ref[...] = jnp.where(own, h, h_ref[...])
 
-        owned = (rows >= s) & (rows < en)  # [TT, 1]
 
-        def f_body(c, acc):
-            slot = jax.lax.rem(c, 2)
+def _down_kernel(
+    off_ref, grp_ref, tile_ref, live_ref, layer_ref,  # scalar prefetch
+    h_ref,   # [tm, F]
+    wd_ref,  # [F, tn]
+    o_ref,   # [tm, tn]
+    *, tm: int,
+):
+    s = pl.program_id(1)
 
-            @pl.when(c + 1 < nc)
-            def _prefetch():
-                for d in dmas(jax.lax.rem(c + 1, 2), e, c + 1):
-                    d.start()
-
-            for d in dmas(slot, e, c):
-                d.wait()
-            gate = jax.lax.dot_general(
-                x, wg_buf[slot],
-                dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # [TT, FT] f32
-            up = jax.lax.dot_general(
-                x, wu_buf[slot],
-                dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            h = activate(gate) * up
-            # Rows owned by OTHER experts (or dead) contribute exactly 0
-            # to the accumulator — groups are disjoint, so each live row
-            # is written by precisely one expert iteration.
-            h = jnp.where(owned, h, 0.0)
-            pv = jnp.dot(
-                h.astype(wd_buf.dtype), wd_buf[slot],
-                preferred_element_type=jnp.float32,
-            )  # [TT, E] f32
-            return acc + pv
-
-        return jax.lax.fori_loop(0, nc, f_body, acc)
-
-    acc0 = jnp.zeros((tt, x.shape[-1]), jnp.float32)
-    acc = jax.lax.fori_loop(0, tcnt_ref[t], expert_body, acc0)
-    o_ref[...] = acc.astype(o_ref.dtype)
+    @pl.when(s < live_ref[0])
+    def _():
+        own = _own_rows(off_ref, grp_ref[s], tile_ref[s], tm, o_ref.shape[1])
+        o = jnp.dot(h_ref[...], wd_ref[...], preferred_element_type=jnp.float32)
+        o_ref[...] = jnp.where(own, o.astype(o_ref.dtype), o_ref[...])
 
 
 @functools.partial(
-    jax.jit,
-    static_argnames=("cap", "act", "interpret", "tile_q", "f_cap"),
+    jax.jit, static_argnames=("act", "interpret", "tile_q", "k_cap", "n_cap")
 )
-def moe_grouped_dispatch_kernel(
-    xg: jnp.ndarray,   # [G, E] grouped token rows (G = Xl*cap padded to TT)
-    occ: jnp.ndarray,  # [Xl] int32 — live rows per expert group (<= cap)
-    w_gate: jnp.ndarray,  # [Xl, E, F]
-    w_up: jnp.ndarray,    # [Xl, E, F]
-    w_down: jnp.ndarray,  # [Xl, F, E]
-    cap: int,
+def moe_grouped_kernel(
+    xs: jnp.ndarray,           # [M, E] pair rows sorted by held expert
+    group_sizes: jnp.ndarray,  # [Xh] int32 pairs of each held expert
+    w_gate: jnp.ndarray,       # [n, Xh, E, F] the stack (or [Xh, E, F])
+    w_up: jnp.ndarray,
+    w_down: jnp.ndarray,       # [n, Xh, F, E]
     act: str = "silu",
     interpret: bool = False,
     tile_q: int = 128,
-    f_cap: int = 512,
+    k_cap: int = 1280,
+    n_cap: int = 1280,
+    layer=None,                # int32 scalar when the leaves are stacks
 ) -> jnp.ndarray:
-    """One grouped ragged expert dispatch. Returns og [G, E] in xg.dtype
-    with dead rows zeroed; the caller scatter-combines per-slot outputs
-    by router weight (ops.moe.grouped_moe)."""
-    G, E = xg.shape
-    Xl, _, F = w_gate.shape
-    TT = tile_rows(Xl * cap, tile_q)
-    assert G % TT == 0 and G >= Xl * cap, (
-        f"grouped buffer [{G}] must cover Xl*cap={Xl * cap} rows padded "
-        f"to the {TT}-row tile (ops.moe builds this layout)"
-    )
-    FT = f_chunk(F, f_cap)
-    NF = F // FT
-    NT = G // TT
-    tfirst, tcnt = _tile_expert_ranges(NT, TT, cap, Xl)
+    """ys [M, E]: row r of expert g's span is SwiGLU_g(xs[r]). Rows past
+    the last span are not written (the caller reads none of them)."""
+    if w_gate.ndim == 3:
+        w_gate, w_up, w_down, layer = w_gate[None], w_up[None], w_down[None], 0
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    M, E = xs.shape
+    _, Xh, _, F = w_gate.shape
+    tm = tile_rows(M, tile_q)
+    assert M % tm == 0, f"pair buffer [{M}] is not whole {tm}-row tiles"
+    m_tiles = M // tm
+    tk, tn = lane_tile(E, k_cap), lane_tile(E, n_cap)
+    k_tiles, n_tiles = E // tk, E // tn
+    meta = group_steps(group_sizes, m_tiles, tm) + (layer,)
+    steps = m_tiles + Xh - 1
+    params = dict(vmem_limit_bytes=VMEM_LIMIT)
+    wbytes = w_gate.dtype.itemsize
 
-    # Leading-dim F split (mosaic rule 2: DMA offsets ride only untiled
-    # leading dims): w_gate/w_up pay one relayout transpose per call —
-    # the production loader can persist this layout — w_down's split is
-    # a free reshape.
-    wg = w_gate.reshape(Xl, E, NF, FT).transpose(0, 2, 1, 3)
-    wu = w_up.reshape(Xl, E, NF, FT).transpose(0, 2, 1, 3)
-    wd = w_down.reshape(Xl, NF, FT, E)
+    def kt(s, k, n):
+        # A dead step stays on the last live step's LAST k block: walking
+        # k there would fetch that expert's weights once more a dead step
+        # (seen on the chip: the gate/up launch 6x the down launch's time).
+        return jnp.where(s < n[0], k, k_tiles - 1)
 
-    hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(NT,),
-        in_specs=[
-            pl.BlockSpec((TT, E), lambda t, *_: (t, 0)),
-            hbm,
-            hbm,
-            hbm,
-        ],
-        out_specs=pl.BlockSpec((TT, E), lambda t, *_: (t, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((2, E, FT), wg.dtype),
-            pltpu.VMEM((2, E, FT), wu.dtype),
-            pltpu.VMEM((2, FT, E), wd.dtype),
-            pltpu.SemaphoreType.DMA((2, 3)),
-        ],
-    )
-    kernel = functools.partial(
-        _moe_kernel, cap=cap, tt=TT, n_f=NF, act=act,
-    )
-    wbytes = wg.dtype.itemsize
-    return pl.pallas_call(
-        kernel,
-        name="moe_grouped_dispatch_kernel",  # op name in the device trace
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((G, E), xg.dtype),
+    h = pl.pallas_call(
+        functools.partial(_gate_up_kernel, tm=tm, k_tiles=k_tiles, act=act),
+        name="moe_grouped_kernel",  # op name in the device trace
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(steps, k_tiles),
+            in_specs=[
+                pl.BlockSpec((tm, tk), lambda s, k, off, g, t, n, l: (t[s], kt(s, k, n))),
+                pl.BlockSpec((None, None, tk, F),
+                             lambda s, k, off, g, t, n, l: (l[0], g[s], kt(s, k, n), 0)),
+                pl.BlockSpec((None, None, tk, F),
+                             lambda s, k, off, g, t, n, l: (l[0], g[s], kt(s, k, n), 0)),
+            ],
+            out_specs=pl.BlockSpec((tm, F), lambda s, k, off, g, t, n, l: (t[s], 0)),
+            scratch_shapes=[pltpu.VMEM((tm, F), jnp.float32)] * 2,
+        ),
+        out_shape=jax.ShapeDtypeStruct((M, F), xs.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",),
+            dimension_semantics=("arbitrary", "arbitrary"), **params
         ),
         cost_estimate=pl.CostEstimate(
-            # Upper bound: every grouped row live (the tile walk skips
-            # dead spans at runtime).
-            flops=6 * G * E * F,
-            bytes_accessed=(
-                2 * G * E * xg.dtype.itemsize + 3 * Xl * E * F * wbytes
-            ),
-            transcendentals=G * F,
+            flops=4 * steps * tm * E * F,
+            bytes_accessed=2 * steps * E * F * wbytes + 2 * M * (E + F),
+            transcendentals=steps * tm * F,
         ),
         interpret=interpret,
-    )(
-        occ.astype(jnp.int32),
-        jnp.asarray(tfirst, jnp.int32),
-        jnp.asarray(tcnt, jnp.int32),
-        xg, wg, wu, wd,
-    )
+    )(*meta, xs, w_gate, w_up)
+
+    return pl.pallas_call(
+        functools.partial(_down_kernel, tm=tm),
+        name="moe_grouped_down_kernel",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(n_tiles, steps),
+            in_specs=[
+                pl.BlockSpec((tm, F), lambda j, s, off, g, t, n, l: (t[s], 0)),
+                pl.BlockSpec((None, None, F, tn),
+                             lambda j, s, off, g, t, n, l: (l[0], g[s], 0, j)),
+            ],
+            out_specs=pl.BlockSpec((tm, tn), lambda j, s, off, g, t, n, l: (t[s], j)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((M, E), xs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"), **params
+        ),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * steps * tm * E * F,
+            bytes_accessed=steps * E * F * wbytes + 2 * M * (E + F),
+            transcendentals=0,
+        ),
+        interpret=interpret,
+    )(*meta, h, w_down)

@@ -288,14 +288,17 @@ class _InFlight:
 
     __slots__ = (
         "tokens", "logprobs", "slots", "t0", "nactive", "total_ctx", "pf",
-        "n_emit", "pf_tok", "pf_lp", "feed",
+        "n_emit", "pf_tok", "pf_lp", "feed", "moe",
     )
 
     def __init__(
         self, tokens, logprobs, slots, t0, nactive, total_ctx, pf=(),
-        n_emit=None, pf_tok=None, pf_lp=None, feed=None,
+        n_emit=None, pf_tok=None, pf_lp=None, feed=None, moe=(),
     ):
         self.tokens = tokens
+        # An expert model's routing counts of this dispatch (device
+        # arrays; executor.take_moe_stats), read when the tokens are.
+        self.moe = moe
         self.logprobs = logprobs
         # What the next dispatch takes as its device-side feedback: the
         # decode slots' tokens, [R]. A mixed step hands them out on their
@@ -655,6 +658,23 @@ class InferenceEngine:
             "step, the prefill rows' pack; one more for each optional "
             "feature that rides)",
         ).set_function(lambda: getattr(self.executor, "dispatch_h2d", 0))
+        # Expert models: pairs that fell to each held expert in one step
+        # and one layer (the step program's own counts over its expert
+        # layers, read with its tokens; the group size the grouped
+        # expert product works at). No observation where no expert is.
+        self._m_moe_pairs = self.metrics.histogram(
+            "xllm_engine_moe_pairs_per_expert",
+            "Routed pairs that fell to a held expert, per step and "
+            "expert layer, one observation a held expert",
+            buckets=BATCH_BUCKETS,
+        )
+        self.metrics.gauge(
+            "xllm_engine_cache_row_bytes",
+            "Bytes one token holds in the paged pool, over every layer "
+            "and cache of it (0 for a state-pool family)",
+        ).set_function(
+            lambda: getattr(self.executor, "cache_row_bytes", 0)
+        )
         # State-pool families (power retention): the pool and who holds
         # it. Registered for every engine; zero where there is no pool.
         self._m_state_in_use = self.metrics.histogram(
@@ -867,10 +887,9 @@ class InferenceEngine:
             if self.host_pool is not None else 0
         )
         # Grouped-MoE dispatch instruments (docs/MOE.md +
-        # docs/OBSERVABILITY.md): expert load, capacity overflow, and
-        # group occupancy for the grouped ragged expert dispatch —
-        # pull-only from the executor's async-callback accumulators, so
-        # the step loop and the overlap pipeline pay nothing. The
+        # docs/OBSERVABILITY.md): expert load and where the pairs fell,
+        # pull-only from the executor's accumulators (booked from the
+        # step programs' own output at each drain). The
         # hot-expert share doubles as the per-instance load signal the
         # master's routing reads next to cache hits
         # (LoadMetrics.moe_hot_expert_frac).
@@ -894,24 +913,35 @@ class InferenceEngine:
 
             self.metrics.counter(
                 "xllm_engine_moe_assignments_total",
-                "Routed (token, expert) assignments dispatched through "
-                "the grouped MoE path, summed over layers",
+                "Routed (token, expert) pairs the router made, summed "
+                "over layers (held and absent experts alike)",
             ).set_function(lambda: _snap()["assignments"])
+            pairs = self.metrics.counter(
+                "xllm_engine_moe_pairs_total",
+                "Routed pairs by where their expert is: held by this "
+                "instance (computed here) or absent (another holder's)",
+                labelnames=("where",),
+            )
+            pairs.labels(where="held").set_function(lambda: _snap()["held"])
+            pairs.labels(where="absent").set_function(
+                lambda: _snap()["absent"]
+            )
+            self.metrics.counter(
+                "xllm_engine_moe_experts_touched_total",
+                "Held experts a layer's tokens touched, summed over "
+                "layers and steps: each is one expert's weights read",
+            ).set_function(lambda: _snap()["touched"])
             self.metrics.counter(
                 "xllm_engine_moe_dropped_total",
-                "Assignments dropped at expert-group capacity "
-                "(XLLM_MOE_CAPACITY_FACTOR overflow)",
+                "Pairs of a held expert that were not computed: 0 by "
+                "construction (the grouped product has no capacity); a "
+                "run in which it moves is a finding",
             ).set_function(lambda: _snap()["dropped"])
             self.metrics.gauge(
                 "xllm_engine_moe_hot_expert_frac",
                 "Hottest expert's share of routed assignments "
                 "(cumulative; 1/num_experts = perfectly balanced)",
             ).set_function(lambda: _snap()["hot_expert_frac"])
-            self.metrics.gauge(
-                "xllm_engine_moe_group_occupancy_frac",
-                "Live rows per grouped-dispatch capacity row "
-                "(cumulative; low = capacity over-provisioned)",
-            ).set_function(lambda: _snap()["occupancy_frac"])
             g = self.metrics.gauge(
                 "xllm_engine_moe_expert_load",
                 "Per-expert share of routed assignments (cumulative)",
@@ -1464,8 +1494,12 @@ class InferenceEngine:
         self._ps_steps[can] += 1
         return _InFlight(
             tokens, logprobs, snapshot, t0, nactive, total_ctx,
-            pf=pf_entries, feed=feed,
+            pf=pf_entries, feed=feed, moe=self._take_moe(),
         )
+
+    def _take_moe(self):
+        take = getattr(self.executor, "take_moe_stats", None)
+        return take() if take is not None else ()
 
     @thread_owned("engine")
     def _snapshot_dispatch(self, can: np.ndarray, n_pf: int, kernel: str):
@@ -2908,6 +2942,9 @@ class InferenceEngine:
             logprobs = np.asarray(flt.logprobs)
             if flt.n_emit is not None:
                 n_emit = np.asarray(flt.n_emit)
+            if flt.moe:
+                for pairs in self.executor.book_moe(flt.moe).tolist():
+                    self._m_moe_pairs.observe(pairs)
         with self._phases.phase("emit"):
             return self._book_step(flt, newer, tokens, logprobs, n_emit)
 
@@ -3611,6 +3648,7 @@ class InferenceEngine:
         return _InFlight(
             tokens, logprobs, snapshot, t0, nactive, total_ctx,
             pf=pf_entries, n_emit=n_emit, pf_tok=pf_tok, pf_lp=pf_lp,
+            moe=self._take_moe(),
         )
 
     # ---------------------------------------------------------- preemption

@@ -29,6 +29,7 @@ from __future__ import annotations
 import contextlib
 import logging
 import math
+import functools
 import os
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
@@ -246,8 +247,8 @@ def fuses_prefill(engine_cfg: EngineConfig, executor) -> bool:
     half of verify_start on a speculative engine) instead of the split
     prefill programs. THE decision: the engine's step() reads it every
     iteration and prewarm_programs walks the fused families by it. Depth
-    0 (sync_engine) never fuses; a family without mixed_step (MLA), or
-    without mixed_verify_step on a speculative engine, runs split."""
+    0 (sync_engine) never fuses; a family without mixed_step, or without
+    mixed_verify_step on a speculative engine (MLA), runs split."""
     return bool(
         not engine_cfg.sync_engine
         and engine_cfg.enable_mixed_step
@@ -317,8 +318,11 @@ class ModelExecutor:
         sliced to the batch's true context bound (pow2 bucket: <=
         log2(max_blocks) compiles; the gather fallback otherwise
         materializes [R, max_blocks*BS] context per layer even when every
-        sequence is short)."""
-        need = 1
+        sequence is short). With no row live (a mixed step whose
+        sequences all prefill) no table is read, and the half takes the
+        largest bucket, which every deployment warms: a lone chunk asks
+        for no program of its own per prefill bucket."""
+        need = self.max_blocks_per_seq
         if active.any():
             need = int(
                 np.asarray(positions)[np.asarray(active)].max()
@@ -410,7 +414,10 @@ class ModelExecutor:
 
     def _fetch(self, *arrays) -> tuple:
         with self.fetch_scope():
-            return tuple(np.asarray(a) for a in arrays)
+            out = tuple(np.asarray(a) for a in arrays)
+        if self._moe_pending:
+            self.book_moe()
+        return out
 
     def __init__(
         self,
@@ -650,11 +657,16 @@ class ModelExecutor:
                 lambda: jnp.zeros((self.R, self.cfg.vocab_size), jnp.int32),
                 out_shardings=NamedSharding(self.mesh, P()),
             )()
-        self._decode_jit = jax.jit(
+        # What the step programs of an expert model hand out beside
+        # their tokens: the router's choice counts, one device array a
+        # dispatch, read when the dispatch's tokens are (_step_jit,
+        # take_moe_stats, book_moe). guarded by: engine thread
+        self._moe_pending: list = []
+        self._decode_jit = self._step_jit(
             self._decode_impl, donate_argnums=(0, 1, 2),
             static_argnames=("use_kernel",)
         )
-        self._prefill_jit = jax.jit(
+        self._prefill_jit = self._step_jit(
             self._prefill_impl, donate_argnums=(0, 1)
         )
         def _import_impl(k, v, blocks, ids):
@@ -682,19 +694,17 @@ class ModelExecutor:
                 {b for b in self.prefill_buckets if b < top} | {top}
             )
 
-        # Grouped-MoE dispatch stats (docs/MOE.md, docs/OBSERVABILITY.md):
-        # each grouped dispatch in a jitted step emits its per-layer
-        # (assignment counts, overflow drops, capacity rows) through an
-        # async jax.debug.callback into _moe_sink — the host accumulators
-        # below feed the engine's obs pull gauges and the master-visible
-        # expert-hotness load signal without ever blocking the device or
-        # the overlap pipeline.
+        # Expert-routing counts (docs/MOE.md, docs/OBSERVABILITY.md):
+        # cumulative choice counts over the PUBLISHED experts, summed
+        # over layers and steps, booked from the step programs' own
+        # output (book_moe). The engine's obs pull gauges and the
+        # master-visible expert-hotness load signal read them.
         self._moe_mu = _threading.Lock()
         self._moe_counts = np.zeros(
             (max(self.cfg.num_experts, 1),), np.int64
         )  # guarded by: self._moe_mu
-        self._moe_dropped = 0  # guarded by: self._moe_mu
-        self._moe_capacity_rows = 0  # guarded by: self._moe_mu
+        # (held expert, layer) meetings: a layer read the expert's weights
+        self._moe_touched = 0  # guarded by: self._moe_mu
 
     # ------------------------------------------------------- multi-LoRA
 
@@ -1825,9 +1835,8 @@ class ModelExecutor:
     @property
     def supports_mixed(self) -> bool:
         """Whether this model family serves the fused mixed prefill+decode
-        step (runtime/engine.py ragged step builder). MLA families keep
-        the split steps until the ragged kernel grows a latent-row mode
-        (docs/KERNELS.md)."""
+        step (runtime/engine.py's one loop): every family with a
+        `mixed_step`."""
         return hasattr(self.model_mod, "mixed_step")
 
     @property
@@ -1862,52 +1871,104 @@ class ModelExecutor:
             None if self.cfg.is_mla else self.mesh
         )
         moe.set_ep_context(self.mesh if self.cfg.is_moe else None)
-        moe.set_stats_sink(self._moe_sink if self.cfg.is_moe else None)
 
-    # ----------------------------------------------- grouped-MoE stats
+    # ----------------------------------------------- expert-routing stats
 
-    def _moe_sink(self, counts, dropped: int, cap_rows: int) -> None:
-        """Per-grouped-dispatch stats landing from JAX's async callback
-        thread (ops.moe.set_stats_sink): one call per MoE layer per
-        step, only when the grouped dispatch is enabled. A foreign
-        emission (a direct ops-level grouped_moe on this thread with a
-        different expert count) is dropped rather than corrupting the
-        accumulators."""
+    def _step_jit(self, impl, **jit_kw):
+        """jax.jit for a step program. For an expert model the program
+        also returns what its expert blocks recorded (ops.moe.step_stats:
+        [2 x num_experts] int32 summed over the layers: the router's
+        choice counts, then in how many layers each expert was touched)
+        as one more small output, kept beside the dispatch
+        (`_moe_pending`) until its tokens are read: no callback, no
+        transfer of its own before that. Other models get the plain jit,
+        their programs as they were."""
+        if not self.cfg.is_moe:
+            return jax.jit(impl, **jit_kw)
+        from xllm_service_tpu.ops import moe
+
+        X = self.cfg.num_experts
+
+        @functools.wraps(impl)  # the trace names a program by its function
+        def with_stats(*a, **kw):
+            with moe.step_stats() as stats:
+                out = impl(*a, **kw)
+            total = stats.total()
+            return out, (
+                jnp.zeros((2 * X,), jnp.int32) if total is None else total
+            )
+
+        jitted = jax.jit(with_stats, **jit_kw)
+
+        def call(*a, **kw):
+            out, counts = jitted(*a, **kw)
+            self._moe_pending.append(counts)
+            return out
+
+        call._cache_size = jitted._cache_size
+        return call
+
+    @property
+    def cache_row_bytes(self) -> int:
+        """Bytes one token holds in the paged pool over every layer and
+        cache (a latent row a layer for an MLA family); 0 for a state
+        pool, which holds none a token."""
+        if self.is_state:
+            return 0
+        data = kvc.raw(self.k_cache)
+        per_layer = data.shape[2] * data.shape[4] * data.dtype.itemsize
+        return int(self.num_caches * data.shape[0] * per_layer)
+
+    def take_moe_stats(self) -> list:
+        """The counts of the dispatches since the last take (device
+        arrays, possibly in flight): the engine keeps them with the
+        in-flight step and books them at its drain."""
+        out, self._moe_pending = self._moe_pending, []
+        return out
+
+    def book_moe(self, pending=None) -> Optional[np.ndarray]:
+        """Read `pending` (default: everything not yet taken; their
+        programs ran before whatever the caller has just read) and add
+        them to the cumulative counts. Returns the step's pairs a held
+        expert and expert layer (its mean over the layers), or None."""
+        if pending is None:
+            pending = self.take_moe_stats()
+        if not pending:
+            return None
+        step = np.sum([np.asarray(c) for c in pending], axis=0)
+        X = self.cfg.num_experts
+        lo, n = self.cfg.held_experts
         with self._moe_mu:
-            if counts.shape != self._moe_counts.shape:
-                return
-            self._moe_counts += counts.astype(np.int64)
-            self._moe_dropped += int(dropped)
-            self._moe_capacity_rows += int(cap_rows)
+            self._moe_counts += step[:X].astype(np.int64)
+            self._moe_touched += int(step[X + lo:X + lo + n].sum())
+        return step[lo:lo + n] / max(1, self.cfg.expert_layers)
 
     def moe_stats(self, drain: bool = False) -> Dict[str, float]:
-        """Cumulative grouped-dispatch stats: per-expert assignment
-        counts (summed over layers and steps), total assignments,
-        capacity-overflow drops, group occupancy, and the hot-expert
-        share — the expert-hotness signal the engine exposes as a load
-        gauge next to cache usage (docs/OBSERVABILITY.md). `drain`
-        synchronizes with any in-flight step first (tests/shutdown);
-        the default read is scrape-safe and never blocks the
-        pipeline."""
+        """Cumulative routing stats: per-expert choice counts over the
+        published experts (summed over layers and steps), how many pairs
+        fell to a held expert and how many to an absent one, and the
+        hot-expert share: the expert-hotness signal the engine exposes
+        as a load gauge next to cache usage (docs/OBSERVABILITY.md).
+        `dropped` is 0 by construction (the grouped product has no
+        capacity; ops/moe.py); the series stays so that a run in which
+        it moves is a finding. `drain` books what is still pending
+        first (tests/shutdown)."""
         if drain:
-            try:
-                jax.effects_barrier()
-            except Exception:  # pragma: no cover — older jax
-                pass
+            self.book_moe()
         with self._moe_mu:
             counts = self._moe_counts.copy()
-            dropped = self._moe_dropped
-            cap_rows = self._moe_capacity_rows
+            touched = self._moe_touched
         total = int(counts.sum())
+        lo, n = self.cfg.held_experts
+        held = int(counts[lo:lo + n].sum())
         return {
             "experts": int(counts.shape[0]),
             "expert_counts": counts,
             "assignments": total,
-            "dropped": dropped,
-            "capacity_rows": cap_rows,
-            "occupancy_frac": (
-                (total - dropped) / cap_rows if cap_rows else 0.0
-            ),
+            "held": held,
+            "absent": total - held,
+            "touched": touched,
+            "dropped": 0,
             "hot_expert_frac": (
                 float(counts.max()) / total if total else 0.0
             ),
@@ -1918,16 +1979,15 @@ class ModelExecutor:
         """How many per-shard grouped-MoE launches one MLP dispatch fans
         into: ep under the shard_map tier, 1 on single-device meshes,
         for non-MoE families, or with the XLLM_SHARDED_KERNELS=0 escape
-        hatch (the grouped oracle then runs under plain GSPMD)."""
-        from xllm_service_tpu.ops import attention, moe
+        hatch (the grouped reference then runs under plain GSPMD)."""
+        from xllm_service_tpu.ops import attention
 
         ep = self.mesh.shape.get("ep", 1)
         if (
             ep <= 1
             or not self.cfg.is_moe
-            or not moe.grouped_moe_enabled()
             or not attention.sharded_kernels_enabled()
-            or self.cfg.num_experts % ep
+            or self.cfg.held_experts[1] % ep
         ):
             return 1
         return ep
@@ -1974,7 +2034,7 @@ class ModelExecutor:
 
     def _add_moe_report(self, rep: Dict[str, str]) -> Dict[str, str]:
         """MoE rows of the resolved report (MoE configs only): `moe` is
-        the dispatch the MLP block takes RIGHT NOW (dense | grouped |
+        the expert product the MLP block takes RIGHT NOW (grouped |
         grouped-ref, docs/MOE.md), `moe_shards` the per-shard launch
         fan-out over ep — asserted (not assumed) by the EP differential
         suite, exactly like attention's `shards`."""
@@ -2127,7 +2187,7 @@ class ModelExecutor:
             opt = {**pf_opt, **self._batch_opts(batch, lora="lora_dec")}
         with _leaf("launch"):
             if not hasattr(self, "_mixed_jit"):
-                self._mixed_jit = jax.jit(
+                self._mixed_jit = self._step_jit(
                     self._mixed_impl,
                     donate_argnums=(0, 1, 2),
                     static_argnames=("lpad", "use_ragged", "interpret"),
@@ -2540,7 +2600,7 @@ class ModelExecutor:
         if not items:
             with _leaf("launch"):
                 if not hasattr(self, "_verify_pipe_jit"):
-                    self._verify_pipe_jit = jax.jit(
+                    self._verify_pipe_jit = self._step_jit(
                         self._verify_pipe_impl, donate_argnums=(0, 1, 2)
                     )
                 (
@@ -2553,7 +2613,7 @@ class ModelExecutor:
             return tokens, logprobs, n_emit, None, None
         with _leaf("launch"):
             if not hasattr(self, "_mixed_verify_jit"):
-                self._mixed_verify_jit = jax.jit(
+                self._mixed_verify_jit = self._step_jit(
                     self._mixed_verify_impl,
                     donate_argnums=(0, 1, 2),
                     static_argnames=("lpad", "use_ragged", "interpret"),
