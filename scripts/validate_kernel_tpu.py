@@ -4,6 +4,7 @@ against their jnp oracles.
 
 Run on a real TPU:  python scripts/validate_kernel_tpu.py            # all cases
                     python scripts/validate_kernel_tpu.py --case 7  # one case
+                    python scripts/validate_kernel_tpu.py --case cell-decode-batch,mq-bf16
                     python scripts/validate_kernel_tpu.py --list
 
 Prints one line per shape: max-abs-err vs oracle, kernel vs oracle time,
@@ -53,7 +54,7 @@ def bench(fn, iters=32):
     return float(np.median(est))
 
 
-def run_case(R, Hq, Hkv, D, BS, MB, ctx, dtype=jnp.bfloat16, chunk=4,
+def run_case(R, Hq, Hkv, D, BS, MB, ctx, dtype=jnp.bfloat16, chunk=None,
              int8=False, window=0):
     rng = np.random.default_rng(0)
     N = R * MB + 1  # block 0 reserved garbage
@@ -96,6 +97,72 @@ def run_case(R, Hq, Hkv, D, BS, MB, ctx, dtype=jnp.bfloat16, chunk=4,
         f"{'int8' if int8 else 'bf16'} "
         f"err={err:.4f} kernel={tk*1e6:8.1f}us gather={tg*1e6:8.1f}us "
         f"speedup={tg/tk:5.2f}x bw={bw:6.1f}GB/s"
+    )
+    return err
+
+
+def run_cell_case(R, Hq, Hkv, D, BS, MB, L, N, live, ctx_lo, ctx_hi,
+                  int8=False, chunk=None):
+    """The decode kernel as a benchmark cell's step program calls it: one
+    launch a layer over an L-layer stacked pool of N blocks, `live` of the
+    R rows holding contexts drawn from [ctx_lo, ctx_hi] (the rest
+    seq_len 0, scattered), table tails at garbage block 0. Prints us a
+    CALL (the L launches of one program / L) and the share of 819 GB/s
+    the live K and V bytes make of it; error against the gather oracle on
+    the first, a middle and the last layer."""
+    from xllm_service_tpu.ops import kv_cache as kvc
+
+    rng = np.random.default_rng(0)
+    kk, kv_, kq = jax.random.split(jax.random.key(0), 3)
+    draw = jax.jit(lambda key: jax.lax.map(  # a layer at a time on the chip
+        lambda k_: jax.random.normal(k_, (N, Hkv, BS, D), jnp.bfloat16),
+        jax.random.split(key, L),
+    ))
+    k, v = draw(kk), draw(kv_)
+    if int8:
+        k, v = kvc.quantize_pool(k), kvc.quantize_pool(v)
+    q = jax.random.normal(kq, (R, Hq, D), jnp.bfloat16)
+    lens = np.zeros(R, np.int32)
+    rows = np.sort(rng.choice(R, live, replace=False))
+    lens[rows] = rng.integers(ctx_lo, ctx_hi + 1, live)
+    bt = np.zeros((R, MB), np.int32)
+    free = iter(1 + rng.permutation(N - 1))
+    for r in rows:
+        nb = -(-int(lens[r]) // BS)
+        bt[r, :nb] = [next(free) for _ in range(nb)]
+    bt, lens_d = jnp.asarray(bt), jnp.asarray(lens)
+    scale = 1.0 / D**0.5
+
+    # (the pools are arguments: a closed-over array is a constant of the
+    # program, and 2 x 2.3 GB of constants do not lower)
+    jstack = jax.jit(
+        lambda q_, k_, v_: jax.lax.map(
+            lambda l: paged_attention_kernel(
+                q_, k_, v_, bt, lens_d, scale, layer=l, chunk=chunk
+            ),
+            jnp.arange(L, dtype=jnp.int32),
+        )
+    )
+    stack = lambda q_: jstack(q_, k, v)
+    out = np.asarray(stack(q).astype(jnp.float32))
+    assert np.all(np.isfinite(out)) and not out[:, lens == 0].any()
+    err = 0.0
+    for l in sorted({0, L // 2, L - 1}):
+        at = lambda c: jax.tree.map(lambda a: a[l], c)
+        ref = paged_attention_gather(q, at(k), at(v), bt, lens_d, scale)
+        ref = np.asarray(ref.astype(jnp.float32))
+        err = max(err, float(np.max(np.abs(out[l] - ref)[lens > 0])))
+    tk = bench(lambda: stack(q), iters=8) / L
+    row_bytes = D * (1 if int8 else 2) + (32 if int8 else 0)
+    kv_bytes = 2 * float(lens.sum()) * Hkv * row_bytes
+    print(
+        f"CELL R={R} live={live} Hq={Hq} Hkv={Hkv} D={D} BS={BS} MB={MB} "
+        f"L={L} N={N} chunk={chunk} ctx={ctx_lo}-{ctx_hi} "
+        f"(mean {lens[rows].mean():.0f}) "
+        f"{'int8' if int8 else 'bf16'} err={err:.4f} "
+        f"call={tk*1e6:8.1f}us step={tk*1e6/(R*Hkv):6.3f}us/grid-step "
+        f"bw={kv_bytes/tk/1e9:6.1f}GB/s "
+        f"hbm_share={100*kv_bytes/tk/819e9:5.1f}%"
     )
     return err
 
@@ -495,6 +562,16 @@ def run_ragged_case(R, P, Lcap, Hq, Hkv, D, BS, MB, dtype=jnp.bfloat16,
 # block slice below one 128-lane tile (tpu.memref_slice verify failure
 # on-chip); ops/attention.py falls back to gather there.
 CASES = [
+    # The decode kernel at the benchmark cells' own shapes (PERF.md, PR 40):
+    # qwen2.5-3b.decode-batch (125 of 128 rows live, 256-768 tokens, table
+    # bucket 8) and qwen2.5-3b.chat-steady (10 live, 256-2048, bucket 16),
+    # each over the 36-layer stack of the cell's 958-block pool.
+    ("cell-decode-batch", run_cell_case,
+     dict(R=128, Hq=16, Hkv=2, D=128, BS=128, MB=8, L=36, N=958, live=125,
+          ctx_lo=256, ctx_hi=768)),
+    ("cell-chat-steady", run_cell_case,
+     dict(R=128, Hq=16, Hkv=2, D=128, BS=128, MB=16, L=36, N=958, live=10,
+          ctx_lo=256, ctx_hi=2048)),
     # Unified ragged mixed-batch kernel (ISSUE 9, docs/KERNELS.md) — the
     # engine's fused prefill+decode dispatch; never chip-validated, so it
     # heads the queue. Geometry: llama-8B-class serving mix (decode slots
@@ -581,13 +658,19 @@ def main(argv):
         return
     sel = range(len(CASES))
     if "--case" in argv:
+        # one index or name, or several joined by commas
+        names = [name for name, _, _ in CASES]
         try:
-            i = int(argv[argv.index("--case") + 1])
-        except (IndexError, ValueError):
-            sys.exit(f"usage: --case N with 0 <= N < {len(CASES)}")
-        if not 0 <= i < len(CASES):
-            sys.exit(f"usage: --case N with 0 <= N < {len(CASES)}")
-        sel = [i]
+            sel = [
+                int(a) if a.isdigit() else names.index(a)
+                for a in argv[argv.index("--case") + 1].split(",")
+            ]
+            assert all(0 <= i < len(CASES) for i in sel)
+        except (IndexError, ValueError, AssertionError):
+            sys.exit(
+                f"usage: --case N[,N...] with 0 <= N < {len(CASES)}, or names "
+                f"of --list"
+            )
     print(f"backend={jax.default_backend()} device={jax.devices()[0]}",
           flush=True)
     assert jax.default_backend() == "tpu"

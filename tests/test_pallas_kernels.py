@@ -1074,3 +1074,158 @@ def test_kv_write_kernel_per_shard():
         attn_ops.set_shard_context(None)
     for r, o in zip(jax.tree.leaves(ref), jax.tree.leaves(out)):
         np.testing.assert_array_equal(np.asarray(o), np.asarray(r))
+
+
+# ------------------------------------- the decode kernel's DMA schedule
+# paged_attention.py fetches a row's live blocks only and hands its DMA
+# pipeline from one live (row, KV head) grid step to the next (slot parity
+# and "first chunk in flight" in SMEM, the successor through `next_live`).
+# The patterns below put dead, one-chunk and many-chunk steps next to one
+# another in every order, for every variant of the one body.
+
+from xllm_service_tpu.ops.pallas.paged_attention import (
+    multiquery_paged_attention_kernel,
+)
+
+# z dead, a 1, b BS, c C*BS-1, d C*BS, e C*BS+1, f the table's full width
+_HANDOVER = {
+    # a de Bruijn walk over {dead, one chunk, many chunks}: all nine pairs
+    "every-order": "zzazebcfez",
+    "dead-first": "zzzade",
+    "dead-last": "deazzz",
+    "interleaved": "zbzczfza",
+    "runs": "aazzzeezzd",
+    "all-dead": "zzzz",
+    "one-live": "zzfz",
+    "edges": "abcdef",
+}
+_MQ_S = 4
+
+
+def _schedule_case(variant, pattern, seed=0):
+    """(run, oracle, seq_lens, reached, pools): `run(k, v)` launches the
+    variant's kernel in interpret mode, `oracle()` is its plain twin on
+    the clean pools, `reached[n]` says whether any query of the case can
+    see block n. Tables are whole (distinct blocks in every column, so the
+    columns past a row's context point at blocks nobody reaches)."""
+    rng = np.random.default_rng(seed)
+    int8 = variant == "int8"
+    BS, C, MB = (128, 2, 5) if int8 else (16, 4, 10)  # MB % C != 0
+    Hq, Hkv, D = 4, 2, 128
+    sym = {"z": 0, "a": 1, "b": BS, "c": C * BS - 1, "d": C * BS,
+           "e": C * BS + 1, "f": MB * BS}
+    lens = np.asarray([sym[s] for s in _HANDOVER[pattern]], np.int32)
+    R = len(lens)
+    N = R * MB + 1
+    S = _MQ_S if variant == "multiquery" else 1
+    window = BS + 3 if variant == "window" else 0
+    mk = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    k, v = mk(N, Hkv, BS, D), mk(N, Hkv, BS, D)
+    if int8:
+        k, v = kvc.quantize_pool(k), kvc.quantize_pool(v)
+    bt = 1 + rng.permutation(N - 1).reshape(R, MB).astype(np.int32)
+    q = mk(R, S, Hq, D) if S > 1 else mk(R, Hq, D)
+    scale = D ** -0.5
+    seq_lens, table = jnp.asarray(lens), jnp.asarray(bt)
+
+    reached = np.zeros(N, bool)
+    for r, n in enumerate(lens):
+        if n:
+            hi = min(-(-(n + S - 1) // BS), MB)
+            lo = max(n - window, 0) // BS if window else 0
+            reached[bt[r, lo:hi]] = True
+
+    if S > 1:
+        run = lambda k_, v_: multiquery_paged_attention_kernel(
+            q, k_, v_, table, seq_lens, scale, interpret=True, chunk=C
+        )
+        oracle = lambda: _mq_oracle(q, k, v, table, seq_lens, S, scale)
+    else:
+        run = lambda k_, v_: paged_attention_kernel(
+            q, k_, v_, table, seq_lens, scale, interpret=True, chunk=C,
+            window=window,
+        )
+        oracle = lambda: paged_attention_gather(
+            q, k, v, table, seq_lens, scale, window=window
+        )
+    return run, oracle, lens, reached, (k, v), (S, BS * MB)
+
+
+@pytest.mark.parametrize("pattern", list(_HANDOVER))
+@pytest.mark.parametrize("variant", ["decode", "multiquery", "int8", "window"])
+def test_decode_schedule_hands_over(variant, pattern):
+    run, oracle, lens, _, (k, v), (S, width) = _schedule_case(variant, pattern)
+    out = np.asarray(run(k, v), np.float32)
+    ref = np.asarray(oracle(), np.float32)
+    tol = 2e-2 if variant == "int8" else 3e-5
+    for r, n in enumerate(lens):
+        if n == 0:
+            assert np.all(out[r] == 0)
+        elif S > 1:
+            # the table-edge clamp: query rows past the table are garbage
+            # the sampler never emits
+            real = min(S, width - n + 1)
+            np.testing.assert_allclose(
+                out[r, :real], ref[r, :real], atol=tol, rtol=tol
+            )
+        else:
+            np.testing.assert_allclose(out[r], ref[r], atol=tol, rtol=tol)
+    assert np.all(np.isfinite(out))
+
+
+@pytest.mark.parametrize("pattern", ["every-order", "edges"])
+@pytest.mark.parametrize("variant", ["decode", "multiquery", "int8", "window"])
+def test_decode_kernel_never_reads_a_dead_block(variant, pattern):
+    """NaN in garbage block 0 and in every block no query can see changes
+    no output and leaves every output finite: such a block is not fetched,
+    and what a slot holds in its place is finite (p is exactly 0 there, but
+    0 * NaN is NaN in p @ v). An int8 pool takes the NaN in its scales."""
+    run, _, _, reached, (k, v), _ = _schedule_case(variant, pattern, seed=1)
+    dead = jnp.asarray(~reached)  # block 0 is in no table
+
+    def poison(cache):
+        if variant == "int8":
+            return kvc.PagedKV(
+                jnp.where(dead[:, None, None, None], 127, cache.data),
+                jnp.where(dead[:, None, None, None], jnp.nan, cache.scale),
+            )
+        return jnp.where(dead[:, None, None, None], jnp.nan, cache)
+
+    clean = np.asarray(run(k, v))
+    dirty = np.asarray(run(poison(k), poison(v)))
+    assert np.all(np.isfinite(dirty))
+    np.testing.assert_array_equal(dirty, clean)
+
+
+@pytest.mark.parametrize("cache_kind", ["bf16", "int8"])
+def test_two_launches_in_one_program_leave_nothing_behind(cache_kind):
+    """Two calls in one jit on different layers of a stack give what each
+    gives alone: the last live step of a launch prefetches nothing, so no
+    DMA, semaphore or SMEM word of one launch reaches the next."""
+    rng = np.random.default_rng(23)
+    Hq, R = 4, 4
+    D, BS, k, v, bt = _stacked_case(rng, cache_kind, Hq=Hq, R=R, MB=2, N=12)
+    q = jnp.asarray(rng.standard_normal((2, R, Hq, D)), jnp.bfloat16)
+    lens = jnp.asarray([[2 * BS, 0, 1, BS + 1], [0, BS, 2 * BS, 0]], jnp.int32)
+    call = lambda i, layer: paged_attention_kernel(
+        q[i], k, v, bt, lens[i], D ** -0.5, interpret=True, chunk=1,
+        layer=jnp.int32(layer),
+    )
+    both = jax.jit(lambda: (call(0, 0), call(1, 2)))()
+    for got, (i, layer) in zip(both, [(0, 0), (1, 2)]):
+        # (to a bf16 ulp: XLA:CPU fuses the interpreted body differently
+        # when two launches share a program)
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(call(i, layer), np.float32),
+            atol=8e-3, rtol=8e-3,
+        )
+        ref = paged_attention_gather(
+            q[i], _layer_of(k, layer), _layer_of(v, layer), bt, lens[i],
+            D ** -0.5,
+        )
+        live = np.asarray(lens[i]) > 0  # a dead row is zeros, not the oracle's
+        assert not np.asarray(got, np.float32)[~live].any()
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32)[live], np.asarray(ref, np.float32)[live],
+            atol=3e-2, rtol=3e-2,
+        )

@@ -129,18 +129,47 @@ def _kernel_shapes(one_chip):
     return s, cache
 
 
-def test_decode_kernel_compiles(one_chip, no_persistent_cache):
+def _kernel_calls(text, name):
+    """Custom calls of the compiled program whose Pallas name is `name`."""
+    import re
+
+    return len(re.findall(rf"%{name}(?:\.\d+)? = [^\n]* custom-call\(", text))
+
+
+@pytest.mark.parametrize(
+    "rows,hq,hkv,table,quantized",
+    [(R, HQ, HKV, 16, False), (128, 16, 2, 8, False), (128, 16, 2, 16, False),
+     (R, HQ, HKV, 16, True), (R, HQ, 1, 4, False)],
+    ids=["llama3-3b", "decode-batch-cell", "chat-steady-cell", "int8",
+         "one-head-shard"],
+)
+def test_decode_kernel_compiles(
+    one_chip, no_persistent_cache, rows, hq, hkv, table, quantized
+):
+    """Mosaic takes the decode kernel's schedule (SMEM hand-over scratch,
+    both grid axes "arbitrary", two heads a descriptor, DMAs under
+    `pl.when`) at llama3-3b's widths, at the two qwen2.5-3b cells' own
+    (128 rows, 16/2 heads, table buckets 8 and 16), on an int8 pool and on
+    a shard that holds one KV head (no fold)."""
     from xllm_service_tpu.ops.pallas.paged_attention import (
         paged_attention_kernel,
     )
 
-    s, cache = _kernel_shapes(one_chip)
+    s, _ = _kernel_shapes(one_chip)
+    if quantized:
+        cache = kvc.PagedKV(
+            s((NB, hkv, BS, D), jnp.int8),
+            s((NB, hkv, kvc.GQA_SCALE_GROUPS, BS), jnp.float32),
+        )
+    else:
+        cache = s((NB, hkv, BS, D))
     text = _compile(
         lambda q, k, v, bt, sl: paged_attention_kernel(q, k, v, bt, sl, SCALE),
-        s((R, HQ, D)), cache, cache,
-        s((R, 16), jnp.int32), s((R,), jnp.int32),
+        s((rows, hq, D)), cache, cache,
+        s((rows, table), jnp.int32), s((rows,), jnp.int32),
     )
     assert "tpu_custom_call" in text
+    assert _kernel_calls(text, "paged_attention_kernel") == 1
 
 
 def test_multiquery_verify_kernel_compiles(one_chip, no_persistent_cache):
@@ -285,7 +314,11 @@ def test_step_compiles_and_keeps_the_pool_still(
             s((*cache.shape[:3], kvc.GQA_SCALE_GROUPS, BS), jnp.float32),
         )
     fn, rest = _step_case(step, cfg, s)
-    _assert_pool_still(fn, (params, cache, cache) + rest, cache)
+    text = _assert_pool_still(fn, (params, cache, cache) + rest, cache)
+    if step == "decode":
+        # one launch a scanned layer: the device trace's readers
+        # (`paged_attention_roofline.batch`) find the kernel by this name
+        assert _kernel_calls(text, "paged_attention_kernel") == 1
 
 
 def _tp4_shapes(topo, layers=2):

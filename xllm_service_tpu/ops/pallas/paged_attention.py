@@ -4,23 +4,52 @@ The engine's hottest op (SURVEY.md §7 hard part #1; the reference's CUDA
 analog lives in the absent engine submodule). One query token per running
 sequence attends to that sequence's paged KV context.
 
-Design (flash-decode, manual double-buffered DMA, chunked blocks):
-  * grid = (R, Hkv): one program per (sequence, KV head). The K/V caches
-    stay in HBM (`pl.ANY`); the kernel streams this sequence's blocks
-    through a 2-slot VMEM buffer with `make_async_copy`, overlapping the
-    next chunk's DMA with the current chunk's compute.
+Design (flash-decode; the DMA schedule is the kernel, PR 40):
+  * grid = (R, Hkv / HF), both axes "arbitrary": one program per sequence
+    and HF KV heads (two where the heads pair up, else one; never more:
+    an 8x head-unrolled body stalls the Mosaic compiler). The heads'
+    matmul -> reduce -> exp -> matmul chains are independent and hide each
+    other's latencies, and a block's rows for both heads come in ONE
+    descriptor ([L, N, :, BS, D] is contiguous over the heads). The K/V
+    caches stay in HBM (`pl.ANY`); the kernel streams a sequence's blocks
+    through a 2-slot VMEM buffer with `make_async_copy`.
   * each inner iteration processes a CHUNK of `C` consecutive block-table
-    entries as one [C*BS, D] tile -> a single [Gp, C*BS] score matmul.
-    Shape search on real hardware: one-block-per-grid-step (4096 programs)
-    and one-block-per-iteration (16 iters of ~10 ns MXU work) are both
-    loop-latency-bound (~300 ns/step floor), and an 8x head-unrolled body
-    stalls the Mosaic compiler; C=4 chunking cuts iteration count 4x with
-    no code-size growth.
-  * the block table and sequence lengths ride in scalar-prefetch SMEM; the
-    inner `fori_loop` bound is the sequence's true chunk count, so no
-    bandwidth is spent on other sequences' blocks. Padding entries within
-    a live chunk DMA the reserved garbage block and are masked out of the
-    softmax by column index.
+    entries as one [C*BS, D] tile a head -> a single [Gp, C*BS] score
+    matmul. An iteration costs about 0.4 us of issue, wait and reduce
+    latency whatever its width (chip readings, docs/KERNELS.md), so C is
+    8 for a plain pool and 4 where the tile is dequantized or a window
+    masks most of it, and never wider than the table.
+  * LIVE BLOCKS ONLY. The block table, sequence lengths and `next_live`
+    ride in scalar-prefetch SMEM. A chunk starts and awaits a DMA only
+    for the table entries in [first in-window block, cdiv(context, BS)),
+    one loop over the same bounds for both (`for_live_blocks`; a slot is
+    [C, HF, BS, D], so the block of a chunk rides an untiled dim and needs
+    no unrolling): a semaphore that is signalled and not consumed would
+    satisfy a later wait early. The garbage block, table tails and blocks
+    below a window never move.
+  * THE PIPELINE CROSSES GRID STEPS. While a step computes its last chunk
+    it starts the first chunk of the next LIVE step (the row's next heads,
+    else row `next_live[r]`, a reversed running minimum over seq_lens made
+    in the wrapper, so dead rows cost no search) into the other slot; that
+    step begins with a wait on DMAs that have been in flight for a whole
+    chunk's compute. A 2-word SMEM scratch carries the hand-over: the slot
+    the next live step starts in, and whether its first chunk is already
+    in flight (0 for the first live step of a launch, which fetches for
+    itself). The last live step prefetches nothing, so nothing outlives a
+    launch. This is why the axes are "arbitrary": the steps must run in
+    order on one core (a v5e has one TensorCore; a tp mesh launches once
+    a shard through shard_map).
+  * STALE ROWS. Columns of a chunk that were not fetched hold whatever the
+    slot held. Their scores are masked to NEG_INF by column index (p is
+    exactly 0), but 0 * NaN is NaN in p @ v: the V slots (and an int8
+    pool's V scale slots) are ZEROED ONCE at a launch's first grid step,
+    after which an unfetched row holds zeros or an earlier live block's
+    finite rows. Zeroing once costs 0.1 us a launch; selecting V rows to
+    zero would cost a [C*BS, D] select a chunk.
+  * the q and o tiles of RB rows (all their heads) are ONE VMEM block:
+    fetched once, written back once, so a grid step, dead rows' too,
+    moves nothing but its own K and V. A dead row's step is a length
+    read, a branch and a zero store.
   * GQA: the G = Hq//Hkv query heads of one KV head are processed together,
     zero-padded to Gp = roundup(G, 8) sublanes to satisfy TPU tiling;
     scores are bf16-in/f32-accum on the MXU (the fast path).
@@ -102,180 +131,367 @@ def _decode_kernel(
     block_table_ref,  # [R, MBp] SMEM (padded to a multiple of C with 0s)
     seq_lens_ref,     # [R]      SMEM
     layer_ref,        # [1]      SMEM — which layer of the stack to read
+    next_live_ref,    # [R]      SMEM — next row after r with seq_len > 0, R if none
     # inputs
-    q_ref,            # [1, 1, Gp, D] VMEM
+    q_ref,            # [RB, Hkv, Gp, D] VMEM: the tiles of RB rows, fetched
+    #                   once for their RB * Hkv / HF grid steps
     k_hbm,            # [L, N, Hkv, BS, D] HBM (pl.ANY) — bf16 or int8
     v_hbm,            # [L, N, Hkv, BS, D] HBM (pl.ANY)
     *rest,            # quantized: ks_hbm, vs_hbm [L, N, Hkv, G, BS] f32, then
     # output
-    #   o_ref         # [1, 1, Gp, D] VMEM
+    #   o_ref         # [RB, Hkv, Gp, D] VMEM, written back once a row block
     # scratch
-    #   k_buf, v_buf  # [2, C*BS, D] VMEM (cache dtype)
+    #   k_buf, v_buf  # [2, C, HF, BS, D] VMEM (cache dtype): the block of a
+    #                 #   chunk rides an untiled dim, so one loop serves them
     #   sems          # [2, 2, C] DMA semaphores
-    #   (quantized)   ks_buf, vs_buf [2, C, G, BS] f32 + ssems [2, 2, C]
+    #   handover      # [2] SMEM int32: (slot of the next live step's first
+    #                 #   chunk, 1 if that chunk is already in flight)
+    #   (quantized)   ks_buf, vs_buf [2, C, HF, G, BS] f32 + ssems [2, 2, C]
     block_size: int,
     chunk: int,
     scale: float,
     quantized: bool,
+    table_blocks: int,
     s_rows: int = 1,
     gp: int = 0,
     scale_groups: int = 8,
     window: int = 0,
 ):
     if quantized:
-        ks_hbm, vs_hbm, o_ref, k_buf, v_buf, sems, ks_buf, vs_buf, ssems = rest
+        (ks_hbm, vs_hbm, o_ref, k_buf, v_buf, sems, handover,
+         ks_buf, vs_buf, ssems) = rest
     else:
-        o_ref, k_buf, v_buf, sems = rest
+        o_ref, k_buf, v_buf, sems, handover = rest
         ks_hbm = vs_hbm = ks_buf = vs_buf = ssems = None
     r = pl.program_id(0)
-    h = pl.program_id(1)
+    hb = pl.program_id(1)
+    rows, head_blocks = pl.num_programs(0), pl.num_programs(1)
+    fold = k_buf.shape[2]  # HF: the KV heads of one grid step
     lyr = layer_ref[0]
-    seq_len = seq_lens_ref[r]
     span = chunk * block_size
-    # Sliding-window attention: the chunk walk starts at the first chunk
-    # holding any in-window position (earliest window start across the
-    # s_rows queries is seq_len - window) — blocks wholly below it never
-    # stream, so SWA decode bandwidth is O(window), not O(context).
-    c_lo = (
-        jnp.maximum(seq_len - window, 0) // span if window > 0 else 0
-    )
-    if s_rows == 1:
-        nc = pl.cdiv(seq_len, span)  # chunks to process
-    else:
+
+    def walk(seq_len):
+        """(first chunk, chunk bound, first live block, live block bound)
+        of a row: the blocks [b_lo, nb) hold every position some query of
+        the row's grid steps can see, and nothing else is fetched."""
         # Multi-query (speculative verify): query row s attends to context
-        # seq_len + s, so the chunk walk must cover the LAST row's context;
-        # inactive slots (seq_len = 0) still process no chunks. Clamp to
-        # the table width: near max_seq_len the caller may have sized the
-        # table for fewer than S extra rows (true_len < S) — rows past
-        # that bound are garbage the sampler never emits, and walking
-        # beyond the table would read out-of-bounds SMEM block ids.
-        nc = jnp.minimum(
-            jnp.where(seq_len == 0, 0, pl.cdiv(seq_len + s_rows - 1, span)),
-            block_table_ref.shape[1] // chunk,
+        # seq_len + s, so the walk covers the LAST row's context; inactive
+        # slots (seq_len = 0) have no block. Clamp to the table's true
+        # width: near max_seq_len the caller may have sized the table for
+        # fewer than S extra rows (true_len < S) — rows past that bound
+        # are garbage the sampler never emits, and walking beyond the
+        # table would read out-of-bounds SMEM block ids.
+        nb = jnp.where(
+            seq_len == 0, 0,
+            jnp.minimum(pl.cdiv(seq_len + s_rows - 1, block_size), table_blocks),
         )
+        # Sliding-window attention: the walk starts at the first block
+        # holding any in-window position (earliest window start across the
+        # s_rows queries is seq_len - window) — blocks wholly below it never
+        # stream, so SWA decode bandwidth is O(window), not O(context).
+        b_lo = (
+            jnp.maximum(seq_len - window, 0) // block_size if window > 0 else 0
+        )
+        return b_lo // chunk, pl.cdiv(nb, chunk), b_lo, nb
 
-    def dmas(slot, c_idx, blk):
-        off = c_idx * block_size
-        out = [
-            mosaic.async_copy(
-                    mosaic.checked_at(k_hbm, lyr, blk, h),
-                    mosaic.checked_at(k_buf, slot, pl.ds(off, block_size)),
-                    sems.at[slot, 0, c_idx],
-                ),
-            mosaic.async_copy(
-                    mosaic.checked_at(v_hbm, lyr, blk, h),
-                    mosaic.checked_at(v_buf, slot, pl.ds(off, block_size)),
-                    sems.at[slot, 1, c_idx],
-                ),
-        ]
+    def dmas(slot, c_idx, blk, h0):
+        # One descriptor takes a block's rows for all HF heads of the step:
+        # [L, N, :, BS, D] is contiguous over the heads (layer, blk and the
+        # heads ride untiled dims). An int8 pool adds the heads' [G, BS]
+        # scale tiles.
+        planes = [(k_hbm, k_buf, sems, 0), (v_hbm, v_buf, sems, 1)]
         if quantized:
-            # Head h's [G, BS] scale tile (layer, blk, h on untiled dims).
-            out.append(
-                mosaic.async_copy(
-                    mosaic.checked_at(ks_hbm, lyr, blk, h),
-                    mosaic.checked_at(ks_buf, slot, c_idx),
-                    ssems.at[slot, 0, c_idx],
-                )
+            planes += [(ks_hbm, ks_buf, ssems, 0), (vs_hbm, vs_buf, ssems, 1)]
+        return [
+            mosaic.async_copy(
+                mosaic.checked_at(src, lyr, blk, pl.ds(h0, fold)),
+                mosaic.checked_at(buf, slot, c_idx),
+                sem.at[slot, kv, c_idx],
             )
-            out.append(
-                mosaic.async_copy(
-                    mosaic.checked_at(vs_hbm, lyr, blk, h),
-                    mosaic.checked_at(vs_buf, slot, c_idx),
-                    ssems.at[slot, 1, c_idx],
-                )
-            )
-        return out
+            for src, buf, sem, kv in planes
+        ]
 
-    def start_chunk(slot, c):
-        for c_idx in range(chunk):  # static, small
-            blk = block_table_ref[r, c * chunk + c_idx]
-            for d in dmas(slot, c_idx, blk):
+    def for_live_blocks(c, b_lo, nb, fn):
+        """fn(c_idx, table column) for the chunk's entries in [b_lo, nb).
+        A chunk is STARTED and AWAITED through these same bounds, so every
+        semaphore that is signalled is consumed: one left over would satisfy
+        a later grid step's (or launch's) wait early."""
+        first = c * chunk
+        lo = jnp.maximum(first, b_lo) if window > 0 else first
+        hi = jnp.minimum(first + chunk, nb)
+
+        def each(j, _):
+            fn(j - first, j)
+
+        jax.lax.fori_loop(lo, hi, each, None)
+
+    def start_chunk(slot, c, row, h0, b_lo, nb):
+        def start(c_idx, j):
+            for d in dmas(slot, c_idx, block_table_ref[row, j], h0):
                 d.start()
 
-    def wait_chunk(slot, c):
-        for c_idx in range(chunk):
-            blk = block_table_ref[r, c * chunk + c_idx]
-            for d in dmas(slot, c_idx, blk):
+        for_live_blocks(c, b_lo, nb, start)
+
+    def wait_chunk(slot, c, b_lo, nb):
+        def wait(c_idx, j):
+            # A wait reads its descriptor's size and semaphore alone.
+            for d in dmas(slot, c_idx, 0, 0):
                 d.wait()
 
-    # Inactive decode slots carry seq_len = 0: issue no DMAs (their
-    # semaphores would never be awaited and could satisfy a later grid
-    # step's wait early) and emit zeros.
-    @pl.when(nc > c_lo)
-    def _first():
-        start_chunk(jax.lax.rem(c_lo, 2), c_lo)
+        for_live_blocks(c, b_lo, nb, wait)
 
-    q = q_ref[0, 0]  # [Gp, D], model dtype (bf16 on TPU)
-
-    def body(c, carry):
-        m_prev, l_prev, acc = carry
-        slot = jax.lax.rem(c, 2)
-
-        @pl.when(c + 1 < nc)
-        def _prefetch():
-            start_chunk(jax.lax.rem(c + 1, 2), c + 1)
-
-        wait_chunk(slot, c)
-        k_tile = k_buf[slot]
+    @pl.when((r == 0) & (hb == 0))
+    def _launch_begins():
+        handover[0] = 0
+        handover[1] = 0
+        # Rows of a slot that no DMA of this launch has written yet are
+        # masked to p = 0, and 0 * NaN is NaN in p @ v: give V (and its
+        # scales) finite rows once. After that a row that was not fetched
+        # holds zeros or an earlier live block's rows.
+        v_buf[...] = jnp.zeros_like(v_buf)
         if quantized:
-            k_tile = dequant_tile(
-                k_tile, ks_buf[slot], chunk, block_size, scale_groups
-            )
-        scores = (
-            jax.lax.dot_general(
-                q, k_tile,
-                dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            * scale
-        )  # [Gp, C*BS] f32
-        col = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-        if s_rows == 1:
-            valid = c * span + col < seq_len
-            if window > 0:
-                valid &= c * span + col >= seq_len - window
-        else:
-            # q tile rows are [S, Gp] flattened: row // gp is the query's
-            # offset from the first fed position (causal within the step).
-            row = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
-            valid = c * span + col < seq_len + row // gp
-            if window > 0:
-                valid &= c * span + col >= seq_len + row // gp - window
-        scores = jnp.where(valid, scores, NEG_INF)
+            vs_buf[...] = jnp.zeros_like(vs_buf)
 
-        m_cur = jnp.max(scores, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(scores - m_new)
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        if quantized:
-            v_tile = dequant_tile(
-                v_buf[slot], vs_buf[slot], chunk, block_size, scale_groups
-            )
-            pv = jnp.dot(
-                p.astype(jnp.bfloat16), v_tile,
-                preferred_element_type=jnp.float32,
-            )  # [Gp, D] f32
-        else:
-            pv = jnp.dot(
-                p.astype(k_buf.dtype), v_buf[slot],
-                preferred_element_type=jnp.float32,
-            )
-        return m_new, l_new, acc * alpha + pv
+    seq_len = seq_lens_ref[r]
+    r_in = jax.lax.rem(r, q_ref.shape[0])  # this row within its q / o block
+    h0 = hb * fold
 
-    Gp, D = q_ref.shape[2], q_ref.shape[3]
-    m0 = jnp.full((Gp, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((Gp, 1), jnp.float32)
-    a0 = jnp.zeros((Gp, D), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(c_lo, nc, body, (m0, l0, a0))
-    # an active slot always has seq_len >= 1 (l > 0); inactive slots get 0
-    o_ref[0, 0] = jnp.where(
-        nc > c_lo, acc / jnp.maximum(l, 1e-30), 0.0
-    ).astype(o_ref.dtype)
+    # Inactive decode slots carry seq_len = 0: they start nothing, touch
+    # neither the slots nor the hand-over, and emit zeros.
+    @pl.when(seq_len <= 0)
+    def _dead():
+        o_ref[r_in, pl.ds(h0, fold)] = jnp.zeros(
+            (fold, *o_ref.shape[2:]), o_ref.dtype
+        )
+
+    @pl.when(seq_len > 0)
+    def _live():
+        c_lo, nc, b_lo, nb = walk(seq_len)
+        slot0 = handover[0]
+
+        # The first live step of a launch fetches for itself; every other
+        # one finds its first chunk started by the live step before it.
+        @pl.when(handover[1] == 0)
+        def _first():
+            start_chunk(slot0, c_lo, r, h0, b_lo, nb)
+
+        # The live grid step after this one: the row's next KV heads, else
+        # the next live row's first (the dead rows between are skipped).
+        wraps = hb + 1 == head_blocks
+        r_nx = jnp.where(wraps, next_live_ref[r], r)
+        h0_nx = jnp.where(wraps, 0, h0 + fold)
+        has_nx = r_nx < rows
+        c_lo_nx, _, b_lo_nx, nb_nx = walk(
+            seq_lens_ref[jnp.minimum(r_nx, rows - 1)]
+        )
+
+        # [HF, Gp, D], model dtype (bf16 on TPU)
+        qs = [q_ref[r_in, h0 + i] for i in range(fold)]
+
+        def body(c, carry):
+            slot = jax.lax.rem(slot0 + c - c_lo, 2)
+
+            # Behind this chunk's compute goes this step's next chunk or,
+            # with the last chunk, the first chunk of the next live step.
+            more = c + 1 < nc
+            pick = lambda mine, theirs: jnp.where(more, mine, theirs)
+
+            @pl.when(more | has_nx)
+            def _prefetch():
+                start_chunk(
+                    1 - slot, pick(c + 1, c_lo_nx), pick(r, r_nx),
+                    pick(h0, h0_nx), pick(b_lo, b_lo_nx), pick(nb, nb_nx),
+                )
+
+            wait_chunk(slot, c, b_lo, nb)
+            shape = (qs[0].shape[0], span)
+            col = c * span + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+            if s_rows == 1:
+                valid = col < seq_len
+                if window > 0:
+                    valid &= col >= seq_len - window
+            else:
+                # q tile rows are [S, Gp] flattened: row // gp is the query's
+                # offset from the first fed position (causal within the step).
+                row = jax.lax.broadcasted_iota(jnp.int32, shape, 0) // gp
+                valid = col < seq_len + row
+                if window > 0:
+                    valid &= col >= seq_len + row - window
+            # The heads' chains are independent: written side by side so
+            # that one's MXU and reduce latencies hide behind the other's.
+            return tuple(
+                _online_softmax_step(i, slot, valid, *carry[i])
+                for i in range(fold)
+            )
+
+        def _online_softmax_step(i, slot, valid, m_prev, l_prev, acc):
+            k_tile = k_buf[slot, :, i].reshape(span, -1)  # [C*BS, D]
+            if quantized:
+                k_tile = dequant_tile(
+                    k_tile, ks_buf[slot, :, i], chunk, block_size, scale_groups
+                )
+            scores = (
+                jax.lax.dot_general(
+                    qs[i], k_tile,
+                    dimension_numbers=(((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                * scale
+            )  # [Gp, C*BS] f32
+            # Columns of blocks that were not fetched hold stale K rows:
+            # never valid, so they leave here as NEG_INF whatever they scored.
+            scores = jnp.where(valid, scores, NEG_INF)
+
+            m_cur = jnp.max(scores, axis=-1, keepdims=True)
+            m_new = jnp.maximum(m_prev, m_cur)
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(scores - m_new)
+            l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+            if quantized:
+                v_tile = dequant_tile(
+                    v_buf[slot, :, i].reshape(span, -1), vs_buf[slot, :, i],
+                    chunk, block_size, scale_groups,
+                )
+                pv = jnp.dot(
+                    p.astype(jnp.bfloat16), v_tile,
+                    preferred_element_type=jnp.float32,
+                )  # [Gp, D] f32
+            else:
+                pv = jnp.dot(
+                    p.astype(k_buf.dtype), v_buf[slot, :, i].reshape(span, -1),
+                    preferred_element_type=jnp.float32,
+                )
+            return m_new, l_new, acc * alpha + pv
+
+        Gp, D = q_ref.shape[2], q_ref.shape[3]
+        m0 = jnp.full((Gp, 1), NEG_INF, jnp.float32)
+        l0 = jnp.zeros((Gp, 1), jnp.float32)
+        a0 = jnp.zeros((Gp, D), jnp.float32)
+        out = jax.lax.fori_loop(c_lo, nc, body, ((m0, l0, a0),) * fold)
+
+        handover[0] = jax.lax.rem(slot0 + nc - c_lo, 2)
+        handover[1] = has_nx.astype(jnp.int32)
+        # a live row has seq_len >= 1, so l > 0 for every real query row
+        for i, (_, l, acc) in enumerate(out):
+            o_ref[r_in, h0 + i] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
+
+
+def _row_block(rows: int, row_bytes: int) -> int:
+    """Rows a q / o block: the largest divisor of `rows` within 512 KB."""
+    cap = max(1, min(rows, 512 * 1024 // row_bytes))
+    return next(rb for rb in range(cap, 0, -1) if rows % rb == 0)
+
+
+def _next_live(seq_lens):
+    """next_live[r]: the first row after r with seq_len > 0, R if none (a
+    reversed running minimum): the kernel's successor without a search."""
+    R = seq_lens.shape[0]
+    ids = jnp.where(seq_lens > 0, jnp.arange(R, dtype=jnp.int32), R)
+    after = jax.lax.cummin(ids, reverse=True)[1:]
+    return jnp.concatenate([after, jnp.full((1,), R, jnp.int32)])
+
+
+def _launch(name, qr, k_cache, v_cache, layer, block_table, seq_lens, *,
+            scale, chunk, window, interpret, s_rows, gp):
+    """One `pallas_call` of `_decode_kernel` over q tiles [R, Hkv, T, D]
+    (T = s_rows * gp query rows a KV head); returns the same shape."""
+    quantized = k_cache.quantized
+    k_data, v_data = k_cache.data, v_cache.data
+    R, Hkv, T, D = qr.shape
+    BS = k_data.shape[3]
+    # KV heads a grid step: two where the heads pair up. Their chains of
+    # matmul, reduce and exp are independent and hide each other's
+    # latencies, and a block's rows come in one descriptor for both. Never
+    # more: an 8x head-unrolled body stalls the Mosaic compiler.
+    HF = 2 if Hkv % 2 == 0 else 1
+    MB = block_table.shape[1]
+    if chunk is None:
+        # A chunk pays about 0.4 us of issue, wait and reduce latency
+        # whatever its width, so a plain pool takes the table in chunks of
+        # 8 blocks; dequantizing or window-masking a wider tile costs more
+        # than the iterations it saves (docs/KERNELS.md has the readings).
+        chunk = 4 if quantized or window > 0 else 8
+    C = max(1, min(chunk, MB))
+    MBp = _round_up(MB, C)
+    bt = block_table.astype(jnp.int32)
+    if MBp != MB:
+        # Chunk-tail entries are never fetched (the walk stops at MB);
+        # they only keep the table's SMEM reads in bounds.
+        bt = jnp.pad(bt, ((0, 0), (0, MBp - MB)))
+    seq_lens = seq_lens.astype(jnp.int32)
+
+    # Pin the caches to HBM explicitly: under pl.ANY the compiler may place
+    # a small cache in VMEM, where the [BS, D] per-block slice is illegal
+    # for D < 128 (lane-padded tiling); HBM DMA slices are contiguous.
+    hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)
+    # The q and o tiles of RB rows ride ONE block: a block whose index does
+    # not change between grid steps is neither fetched nor written back, so
+    # a grid step costs no DMA of the block pipeline's (a dead row's step was
+    # 0.3 us of nothing else), only its own K and V.
+    RB = _row_block(R, Hkv * T * D * qr.dtype.itemsize)
+    tile = pl.BlockSpec((RB, Hkv, T, D), lambda r, h, *_: (r // RB, 0, 0, 0))
+    in_specs = [tile, hbm, hbm]
+    inputs = [bt, seq_lens, layer, _next_live(seq_lens), qr, k_data, v_data]
+    scratch = [
+        pltpu.VMEM((2, C, HF, BS, D), k_data.dtype),
+        pltpu.VMEM((2, C, HF, BS, D), v_data.dtype),
+        pltpu.SemaphoreType.DMA((2, 2, C)),
+        pltpu.SMEM((2,), jnp.int32),
+    ]
+    SG = k_cache.scale.shape[-2] if quantized else 8  # sub-channel groups
+    kv_bytes_per_row = D * k_data.dtype.itemsize
+    if quantized:
+        in_specs += [hbm, hbm]
+        # Pool-native [L, N, Hkv, G, BS] grouped plane (kv_cache.py) — no
+        # per-call relayout, tile-legal on every tp shard.
+        inputs += [
+            k_cache.scale.astype(jnp.float32),
+            v_cache.scale.astype(jnp.float32),
+        ]
+        scratch += [
+            pltpu.VMEM((2, C, HF, SG, BS), jnp.float32),
+            pltpu.VMEM((2, C, HF, SG, BS), jnp.float32),
+            pltpu.SemaphoreType.DMA((2, 2, C)),
+        ]
+        # Per-block scale tile is [G, BS] f32: 4*G bytes per row.
+        kv_bytes_per_row += 4 * SG
+
+    kernel = functools.partial(
+        _decode_kernel, block_size=BS, chunk=C, scale=scale,
+        quantized=quantized, table_blocks=MB, s_rows=s_rows, gp=gp,
+        scale_groups=SG, window=window,
+    )
+    return pl.pallas_call(
+        kernel,
+        name=name,  # op name in the device trace
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(R, Hkv // HF),
+            in_specs=in_specs,
+            out_specs=tile,
+            scratch_shapes=scratch,
+        ),
+        out_shape=jax.ShapeDtypeStruct(qr.shape, qr.dtype),
+        compiler_params=pltpu.CompilerParams(
+            # The DMA pipeline crosses grid steps (slot parity and the
+            # chunk in flight ride SMEM from one step to the next), so the
+            # steps run in order on one core. A v5e has one TensorCore; a
+            # tp mesh launches once a shard through shard_map.
+            dimension_semantics=("arbitrary", "arbitrary"),
+        ),
+        cost_estimate=pl.CostEstimate(
+            flops=4 * R * Hkv * T * D * MB * BS,  # qk + pv
+            bytes_accessed=(
+                R * Hkv * T * D * 4 + 2 * R * MB * BS * Hkv * kv_bytes_per_row
+            ),
+            transcendentals=R * Hkv * T * MB * BS,
+        ),
+        interpret=interpret,
+    )(*inputs)
 
 
 @functools.partial(
@@ -289,95 +505,24 @@ def paged_attention_kernel(
     seq_lens: jnp.ndarray,     # [R] int32
     scale: float,
     interpret: bool = False,
-    chunk: int = 4,
+    chunk: int | None = None,  # blocks a chunk; None: by pool and window
     window: int = 0,
     layer=None,                # int32 scalar when the caches are stacks
 ) -> jnp.ndarray:
     k_cache, v_cache, layer = stack_operands(k_cache, v_cache, layer)
-    quantized = k_cache.quantized
-    k_data, v_data = k_cache.data, v_cache.data
-
     R, Hq, D = q.shape
-    _, N, Hkv, BS, _ = k_data.shape
-    MB = block_table.shape[1]
+    Hkv = k_cache.data.shape[2]
     G = Hq // Hkv
     Gp = _round_up(G, 8)
-    C = max(1, min(chunk, MB))
 
     qr = q.reshape(R, Hkv, G, D)
     if Gp != G:
         qr = jnp.pad(qr, ((0, 0), (0, 0), (0, Gp - G), (0, 0)))
-    MBp = _round_up(MB, C)
-    bt = block_table.astype(jnp.int32)
-    if MBp != MB:
-        # Chunk-tail entries point at the reserved garbage block 0; their
-        # columns are masked out by seq_len anyway.
-        bt = jnp.pad(bt, ((0, 0), (0, MBp - MB)))
-
-    # Pin the caches to HBM explicitly: under pl.ANY the compiler may place
-    # a small cache in VMEM, where the [BS, D] per-block slice is illegal
-    # for D < 128 (lane-padded tiling); HBM DMA slices are contiguous.
-    hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)
-    in_specs = [
-        pl.BlockSpec((1, 1, Gp, D), lambda r, h, *_: (r, h, 0, 0)),
-        hbm,
-        hbm,
-    ]
-    inputs = [bt, seq_lens.astype(jnp.int32), layer, qr, k_data, v_data]
-    scratch = [
-        pltpu.VMEM((2, C * BS, D), k_data.dtype),
-        pltpu.VMEM((2, C * BS, D), v_data.dtype),
-        pltpu.SemaphoreType.DMA((2, 2, C)),
-    ]
-    SG = k_cache.scale.shape[-2] if quantized else 8  # sub-channel groups
-    kv_bytes_per_row = D * k_data.dtype.itemsize
-    if quantized:
-        in_specs += [hbm, hbm]
-        # Pool-native [L, N, Hkv, G, BS] grouped plane (kv_cache.py) — no
-        # per-call relayout, tile-legal on every tp shard.
-        inputs += [
-            k_cache.scale.astype(jnp.float32),
-            v_cache.scale.astype(jnp.float32),
-        ]
-        scratch += [
-            pltpu.VMEM((2, C, SG, BS), jnp.float32),
-            pltpu.VMEM((2, C, SG, BS), jnp.float32),
-            pltpu.SemaphoreType.DMA((2, 2, C)),
-        ]
-        # Per-block scale tile is [G, BS] f32: 4*G bytes per row.
-        kv_bytes_per_row += 4 * SG
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(R, Hkv),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, 1, Gp, D), lambda r, h, *_: (r, h, 0, 0)
-        ),
-        scratch_shapes=scratch,
+    out = _launch(
+        "paged_attention_kernel", qr, k_cache, v_cache, layer, block_table,
+        seq_lens, scale=scale, chunk=chunk, window=window,
+        interpret=interpret, s_rows=1, gp=Gp,
     )
-    kernel = functools.partial(
-        _decode_kernel, block_size=BS, chunk=C, scale=scale,
-        quantized=quantized,
-        scale_groups=SG, window=window,
-    )
-    out = pl.pallas_call(
-        kernel,
-        name="paged_attention_kernel",  # op name in the device trace
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((R, Hkv, Gp, D), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel"),
-        ),
-        cost_estimate=pl.CostEstimate(
-            flops=4 * R * Hkv * Gp * D * MB * BS,  # qk + pv
-            bytes_accessed=(
-                R * Hq * D * 4 + 2 * R * MB * BS * Hkv * kv_bytes_per_row
-            ),
-            transcendentals=R * Hkv * Gp * MB * BS,
-        ),
-        interpret=interpret,
-    )(*inputs)
     return out[:, :, :G, :].reshape(R, Hq, D)
 
 
@@ -393,7 +538,7 @@ def multiquery_paged_attention_kernel(
     # query token; row s of a sequence attends to seq_lens + s rows
     scale: float,
     interpret: bool = False,
-    chunk: int = 4,
+    chunk: int | None = None,  # blocks a chunk; None: by pool and window
     window: int = 0,
     layer=None,                # int32 scalar when the caches are stacks
 ) -> jnp.ndarray:
@@ -403,87 +548,20 @@ def multiquery_paged_attention_kernel(
     The S*G query heads of one KV head ride one [S*Gp, D] tile; causal
     masking within the step is by tile-row // Gp. Returns [R, S, Hq, D]."""
     k_cache, v_cache, layer = stack_operands(k_cache, v_cache, layer)
-    quantized = k_cache.quantized
-    k_data, v_data = k_cache.data, v_cache.data
-
     R, S, Hq, D = q.shape
-    _, N, Hkv, BS, _ = k_data.shape
-    MB = block_table.shape[1]
+    Hkv = k_cache.data.shape[2]
     G = Hq // Hkv
     Gp = _round_up(G, 8)
-    C = max(1, min(chunk, MB))
 
     # [R, S, Hkv, G, D] -> [R, Hkv, S, Gp, D] -> [R, Hkv, S*Gp, D]
     qr = jnp.swapaxes(q.reshape(R, S, Hkv, G, D), 1, 2)
     if Gp != G:
         qr = jnp.pad(qr, ((0, 0), (0, 0), (0, 0), (0, Gp - G), (0, 0)))
-    qr = qr.reshape(R, Hkv, S * Gp, D)
-    MBp = _round_up(MB, C)
-    bt = block_table.astype(jnp.int32)
-    if MBp != MB:
-        bt = jnp.pad(bt, ((0, 0), (0, MBp - MB)))
-
-    hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)
-    in_specs = [
-        pl.BlockSpec((1, 1, S * Gp, D), lambda r, h, *_: (r, h, 0, 0)),
-        hbm,
-        hbm,
-    ]
-    inputs = [bt, seq_lens.astype(jnp.int32), layer, qr, k_data, v_data]
-    scratch = [
-        pltpu.VMEM((2, C * BS, D), k_data.dtype),
-        pltpu.VMEM((2, C * BS, D), v_data.dtype),
-        pltpu.SemaphoreType.DMA((2, 2, C)),
-    ]
-    SG = k_cache.scale.shape[-2] if quantized else 8  # sub-channel groups
-    kv_bytes_per_row = D * k_data.dtype.itemsize
-    if quantized:
-        in_specs += [hbm, hbm]
-        # Pool-native [L, N, Hkv, G, BS] grouped plane (kv_cache.py) — no
-        # per-call relayout, tile-legal on every tp shard.
-        inputs += [
-            k_cache.scale.astype(jnp.float32),
-            v_cache.scale.astype(jnp.float32),
-        ]
-        scratch += [
-            pltpu.VMEM((2, C, SG, BS), jnp.float32),
-            pltpu.VMEM((2, C, SG, BS), jnp.float32),
-            pltpu.SemaphoreType.DMA((2, 2, C)),
-        ]
-        # Per-block scale tile is [G, BS] f32: 4*G bytes per row.
-        kv_bytes_per_row += 4 * SG
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(R, Hkv),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, 1, S * Gp, D), lambda r, h, *_: (r, h, 0, 0)
-        ),
-        scratch_shapes=scratch,
+    out = _launch(
+        "multiquery_paged_attention_kernel", qr.reshape(R, Hkv, S * Gp, D),
+        k_cache, v_cache, layer, block_table, seq_lens, scale=scale,
+        chunk=chunk, window=window, interpret=interpret, s_rows=S, gp=Gp,
     )
-    kernel = functools.partial(
-        _decode_kernel, block_size=BS, chunk=C, scale=scale,
-        quantized=quantized, s_rows=S, gp=Gp,
-        scale_groups=SG, window=window,
-    )
-    out = pl.pallas_call(
-        kernel,
-        name="multiquery_paged_attention_kernel",  # op name in the device trace
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((R, Hkv, S * Gp, D), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel"),
-        ),
-        cost_estimate=pl.CostEstimate(
-            flops=4 * R * Hkv * S * Gp * D * MB * BS,
-            bytes_accessed=(
-                R * S * Hq * D * 4 + 2 * R * MB * BS * Hkv * kv_bytes_per_row
-            ),
-            transcendentals=R * Hkv * S * Gp * MB * BS,
-        ),
-        interpret=interpret,
-    )(*inputs)
     # [R, Hkv, S*Gp, D] -> [R, Hkv, S, Gp, D] -> [R, S, Hq, D]
     out = out.reshape(R, Hkv, S, Gp, D)[:, :, :, :G, :]
     return jnp.swapaxes(out, 1, 2).reshape(R, S, Hq, D)
