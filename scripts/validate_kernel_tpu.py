@@ -557,6 +557,116 @@ def run_ragged_case(R, P, Lcap, Hq, Hkv, D, BS, MB, dtype=jnp.bfloat16,
 # Ordered so the never-yet-chip-validated kernels come first (round 3
 # queue: int8 scale-DMA decode, MLA decode, flash prefill) — the bf16
 # decode cases at the tail were already chip-validated in round 2.
+def _mamba_inputs(rows, Lc, H, P, N, seed=0):
+    """Scan inputs in the band the benchmark's weights give: steps
+    log-uniform in 1e-3..1e-1, A in 1..16."""
+    ks = jax.random.split(jax.random.key(seed), 6)
+    lead = (rows,) if Lc is None else (rows, Lc)
+    x = jax.random.normal(ks[0], lead + (H, P), jnp.float32)
+    dt = jnp.exp(jax.random.uniform(ks[1], lead + (H,), jnp.float32, np.log(1e-3), np.log(1e-1)))
+    A = -jax.random.uniform(ks[2], (H,), jnp.float32, 1.0, 16.0)
+    B = jax.random.normal(ks[3], lead + (1, N), jnp.float32)
+    C = jax.random.normal(ks[4], lead + (1, N), jnp.float32)
+    return x, dt, A, B, C, jnp.ones((H,), jnp.float32)
+
+
+def run_mamba_update_case(R, live, L, H, P, N, tile=None):
+    """mamba_update_kernel as granite-4.0-h-small's decode program calls
+    it: one launch a Mamba layer over the L-layer state pool of R slots,
+    `live` of the R rows active (scattered). us a CALL (the L launches of
+    one program / L), the share of 819 GB/s the live rows' state bytes
+    (read and written) make of it, and the largest error of y and of the
+    state against the jax.numpy route on the first and the last layer."""
+    from xllm_service_tpu.ops import mamba as mo
+    from xllm_service_tpu.ops.pallas import mamba as pm
+
+    if tile:
+        pm.HEAD_TILE = tile
+    rng = np.random.default_rng(0)
+    act = np.zeros(R, bool)
+    act[rng.choice(R, live, replace=False)] = True
+    act = jnp.asarray(act)
+    x, dt, A, B, C, D = _mamba_inputs(R, None, H, P, N)
+    shape = mo.state_shapes(L, R, H, P, N, 4, H * P + 2 * N)[0]
+    S = jax.jit(lambda k: jax.lax.map(
+        lambda k_: jax.random.normal(k_, shape[1:], jnp.float32), jax.random.split(k, L)
+    ))(jax.random.key(1))
+
+    def step(use):
+        def run(S_):
+            def body(S_, l):
+                y, S_ = mo.decode_update(S_, l, act, x, dt, A, B, C, D, use_kernel=use)
+                return S_, y
+            return jax.lax.scan(body, S_, jnp.arange(L, dtype=jnp.int32))
+        return jax.jit(run, donate_argnums=0)
+
+    kern, ref = step(True), step(False)
+    S_k, y_k = kern(S + 0.0)
+    S_r, y_r = ref(S + 0.0)
+    err_y = float(jnp.abs(y_k - y_r).max())
+    err_s = max(float(jnp.abs(S_k[l] - S_r[l]).max()) for l in (0, L - 1))
+    del S_r, y_r
+    state = [S_k]
+
+    def once():
+        state[0], y = kern(state[0])
+        return y
+
+    tk = bench(once, iters=8) / L
+    need = 2 * live * H * P * N * 4
+    print(
+        f"MAMBA-UPDATE R={R} live={live} L={L} H={H} P={P} N={N} "
+        f"tile={pm.head_tile(shape[2])} err_y={err_y:.2e} err_S={err_s:.2e} "
+        f"call={tk*1e6:8.1f}us row={tk*1e6/live:6.2f}us/live-row "
+        f"bw={need/tk/1e9:6.1f}GB/s hbm_share={100*need/tk/819e9:5.1f}%"
+    )
+    return max(err_y, err_s) * 1e-2  # float32: the parity bound is main's 0.05
+
+
+def run_mamba_chunk_case(Lc, L, H, P, N, slots=8):
+    """The chunk form (plain XLA, ops/mamba.py chunk_update) over an
+    L-layer pool: one chunk of Lc tokens from a FRESH state, then the next
+    from the CARRIED one, against the token-by-token recurrence; us a
+    layer's call for each."""
+    from xllm_service_tpu.ops import mamba as mo
+
+    x, dt, A, B, C, D = _mamba_inputs(1, 2 * Lc, H, P, N, seed=3)
+    y_ref, S_ref = jax.jit(mo.recurrent_form)(x[0], dt[0], A, B[0], C[0], D)
+    shape = mo.state_shapes(L, slots, H, P, N, 4, H * P + 2 * N)[0]
+    S = jnp.full(shape, 2.0, jnp.float32)
+    slot = jnp.array([3], jnp.int32)
+
+    def make(start):
+        sl = slice(start, start + Lc)
+        def run(S_):
+            def body(S_, l):
+                y, S_ = mo.chunk_update(S_, l, slot, jnp.array([start]), jnp.array([Lc]),
+                                        x[:, sl], dt[:, sl], A, B[:, sl], C[:, sl], D)
+                return S_, y
+            return jax.lax.scan(body, S_, jnp.arange(L, dtype=jnp.int32))
+        return jax.jit(run, donate_argnums=0)
+
+    fresh, carried = make(0), make(Lc)
+    S, y0 = fresh(S)
+    S, y1 = carried(S)
+    k = mo.pack_factor(H, P)
+    err = max(float(jnp.abs(y0[L - 1, 0] - y_ref[:Lc]).max()),
+              float(jnp.abs(y1[0, 0] - y_ref[Lc:]).max()),
+              float(jnp.abs(mo.from_pool(S[L - 1, 3], k) - S_ref).max()))
+    state = [S]
+
+    def timed(fn):
+        def once():
+            state[0], y = fn(state[0])
+            return y
+        return bench(once, iters=8) / L
+
+    t0, t1 = timed(fresh), timed(carried)
+    print(f"MAMBA-CHUNK Lc={Lc} L={L} H={H} P={P} N={N} err={err:.2e} "
+          f"fresh={t0*1e6:8.1f}us carried={t1*1e6:8.1f}us a layer")
+    return err * 1e-2
+
+
 # llama-8B-class: Hq=32 Hkv=8 D=128; llama-70B-class: Hq=64 Hkv=8 D=128.
 # NOTE: D=64 decode is NOT included — Mosaic rejects the lane-padded HBM
 # block slice below one 128-lane tile (tpu.memref_slice verify failure
@@ -572,6 +682,17 @@ CASES = [
     ("cell-chat-steady", run_cell_case,
      dict(R=128, Hq=16, Hkv=2, D=128, BS=128, MB=16, L=36, N=958, live=10,
           ctx_lo=256, ctx_hi=2048)),
+    # The Mamba-2 state pool at granite-4.0-h-small's widths (PERF.md, PR
+    # 42): the decode update over the cell's 9-layer pool of 64 slots with
+    # 44 rows live, and one 256-token chunk from a fresh and from a carried
+    # state (the chunk form is plain XLA).
+    ("mamba-update-cell", run_mamba_update_case,
+     dict(R=64, live=44, L=9, H=128, P=64, N=128)),
+    ("mamba-update-tile8", run_mamba_update_case,
+     dict(R=64, live=44, L=9, H=128, P=64, N=128, tile=8)),
+    ("mamba-update-tile32", run_mamba_update_case,
+     dict(R=64, live=44, L=9, H=128, P=64, N=128, tile=32)),
+    ("mamba-chunk", run_mamba_chunk_case, dict(Lc=256, L=9, H=128, P=64, N=128)),
     # Unified ragged mixed-batch kernel (ISSUE 9, docs/KERNELS.md) — the
     # engine's fused prefill+decode dispatch; never chip-validated, so it
     # heads the queue. Geometry: llama-8B-class serving mix (decode slots

@@ -30,8 +30,8 @@ def configurations():
 
 
 def test_the_benchmark_has_both_families():
-    # three since PR 39 (the name stays: the driver counts tests by name)
-    assert {c["family"] for c in configurations()} == {"llama", "brumby", "deepseek"}
+    # four since PR 42 (the name stays: the driver counts tests by name)
+    assert {c["family"] for c in configurations()} == {"llama", "brumby", "deepseek", "granite"}
 
 
 @pytest.mark.parametrize("config", configurations(), ids=lambda c: c["name"])
@@ -156,3 +156,58 @@ def test_the_deepseek_family_holds_a_span_under_a_published_router():
     assert abs(float(jnp.std(lay["w_sh_down"])) * np.sqrt(128) - 1.0) < 0.05  # N(0, 1 / fan_in)
     # the routed part is drawn small beside the residual (families/deepseek.py says why)
     assert abs(float(jnp.std(lay["w_down"])) * np.sqrt(64) / fam.ROUTED_OUT_SCALE - 1.0) < 0.05
+
+
+def test_the_granite_family_draws_a_trained_models_decays_and_leaves_nothing_skippable():
+    """granite-4.0-h-small as cut: 36 experts held under a router 72 wide,
+    half the vocabulary, the first period of the published pattern; and at
+    the rehearsal size the draws: Mamba-2's own steps under decays that
+    outlast the check's 832 tokens, a standing component on the x and B
+    lanes alone, the embedding and the final gain that undo each other, no
+    gain at 1, no bias at 0."""
+    import jax
+    import jax.numpy as jnp
+
+    with open(os.path.join(BENCH, "configs", "granite-4.0-h-small.json")) as f:
+        config = json.load(f)
+    fam = family_mod.load(config)
+    cfg = fam.model_config(config["name"], config)
+    assert (cfg.num_experts, cfg.held_experts, cfg.vocab_size, cfg.num_layers) == (72, (0, 36), 50176, 10)
+    assert len(config["layer_types"]) == 40 and cfg.layer_types == tuple(config["layer_types"][:10])
+    assert cfg.num_mamba_layers == 9 and cfg.num_attention_layers == 1
+    shapes = fam.weight_shapes(config)
+    assert shapes["layers"]["router"] == (10, 4096, 72) and shapes["layers"]["w_gate"] == (10, 36, 4096, 768)
+    assert shapes["mamba"]["w_in"] == (9, 4096, 8192 + 8448 + 128) and shapes["attn"]["wk"] == (1, 4096, 1024)
+    assert shapes["embed"] == (50176, 4096) and "lm_head" not in shapes
+
+    with open(os.path.join(BENCH, "configs", "rehearse-granite-tiny.json")) as f:
+        tiny = json.load(f)
+    w = jax.jit(lambda k: fam.make_weights(tiny, k, jnp.float32))(family_mod.seed_key(3))
+    mam = w["mamba"]
+    dt0 = jax.nn.softplus(mam["dt_bias"])  # the step at a zero projection
+    assert 0.9e-3 < float(dt0.min()) and float(dt0.max()) < 1.1e-1
+    A = jnp.exp(mam["A_log"])
+    assert 1e-3 <= float(A.min()) and float(A.max()) <= 1e-1
+    decay = jnp.exp(-A * dt0)
+    assert 0.989 < float(decay.min()) and float(decay.max()) < 1.0
+    assert float(jnp.mean(decay > 1 - 1 / 832)) > 0.5  # most heads remember past the check
+    H, P, G, N, d_in, conv = fam.dims(tiny)
+    lanes = mam["conv_b"].mean(0)  # [x | B | C]
+    assert abs(float(lanes[:d_in + G * N].mean()) - 2.0) < 0.05 and abs(float(lanes[d_in + G * N:].mean())) < 0.05
+    assert abs(float(w["final_norm"].mean()) * fam.EMBED_SCALE - 16.0) < 0.5
+    E = tiny["hidden_size"]
+    assert 0.8 < float(jnp.var(w["embed"])) * E / fam.EMBED_SCALE ** 2 < 1.25
+    assert 0.7 < float(jnp.var(mam["w_out"])) * (H * P) / fam.MAMBA_OUT_SCALE ** 2 < 1.4
+    assert 0.8 < float(jnp.var(w["layers"]["w_down"])) * tiny["intermediate_size"] / fam.ROUTED_OUT_SCALE ** 2 < 1.25
+    # scores x attention_multiplier at unit scale: q and k entries of variance qk_gain
+    u = jax.random.normal(jax.random.key(0), (2048, tiny["hidden_size"]))
+    q = u @ w["attn"]["wq"][0]
+    assert 0.8 < float(jnp.var(q)) / fam.qk_gain(tiny) < 1.25 and fam.qk_gain(tiny) == 1 / (0.0625 * 4)
+    assert abs(fam.qk_gain(config) - 128 ** 0.5) < 1e-9
+    for stack, name in (("layers", "attn_norm"), ("layers", "mlp_norm"), ("mamba", "gate_norm"),
+                        ("mamba", "D")):
+        assert 0.02 < float(jnp.std(w[stack][name])) < 0.2, name  # ~ N(1, 0.1), not 1
+    assert float(jnp.abs(mam["conv_b"]).min()) > 1e-5 and float(jnp.std(mam["conv_b"][:, -G * N:])) > 0.05  # no bias at 0
+    for name in fam.FLOAT32_LEAVES:
+        assert mam[name].dtype == jnp.float32, name
+

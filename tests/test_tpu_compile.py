@@ -522,3 +522,49 @@ def test_deepseek_v2_mixed_step_compiles_and_its_stack_stays(one_chip, no_persis
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 0.5e9
     assert 12.0e9 < mem.argument_size_in_bytes < 13.5e9  # weights + a 2.4 GB stack
+
+
+def test_granite_mixed_step_compiles_and_its_pools_stay(one_chip, no_persistent_cache, as_on_tpu, monkeypatch):
+    """granite-4.0-h-small as the benchmark cuts it (9 Mamba-2 layers + 1
+    GQA layer, experts 0-35 of 72, 50,176 vocabulary rows): the whole mixed
+    step fits the chip beside 9.51 GB of weights and a 64-slot state pool,
+    with the update kernel, the K/V write, both attention kernels and the
+    two grouped expert kernels in it; the SSM pool, the convolution pool,
+    the K/V stacks and the expert leaves are read and written where they
+    lie, and the temporaries stay under 0.5 GB."""
+    from xllm_service_tpu.models import granite
+
+    monkeypatch.setenv("XLLM_RAGGED_ATTENTION_KERNEL", "0")  # the cell's route: the pair of kernels
+    cfg = get_model_config("granite-4.0-h-small")
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.eval_shape(lambda k: granite.init_params(cfg, k, jnp.bfloat16), jax.random.key(0))
+    params = jax.tree.map(lambda a: s(a.shape, a.dtype), params)
+    R, nb, i32 = 64, 2000, jnp.int32
+    ssm, conv = granite.state_shapes(cfg, R)
+    kv = s((1, nb, 8, BS, 128))
+    compiled = jax.jit(
+        lambda p, k, v, *a: granite.mixed_step(p, cfg, k, v, *a), donate_argnums=(1, 2)
+    ).lower(
+        params, (kv, s(ssm, jnp.float32)), (kv, s(conv, jnp.float32)),
+        s((R,), i32), s((R,), i32), s((R, 32), i32), s((R,), jnp.bool_),
+        s((1, 256), i32), s((1,), i32), s((1,), i32), s((1, 17), i32),
+    ).compile()
+    text = compiled.as_text()
+    for kernel in ("mamba_update_kernel", "kv_write_kernel", "paged_attention_kernel",
+                   "moe_grouped_kernel", "moe_grouped_down_kernel"):
+        assert kernel in text, kernel
+    import re
+
+    # dynamic-update-slice writes a row in place; a COPY of a pool or of an
+    # expert stack is what must not be there
+    pools = {",".join(map(str, sh)) for sh in (ssm, conv, (1, nb, 8, BS, 128),
+                                               (10, 36, 4096, 768), (10, 36, 768, 4096))}
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if (m := re.match(r"\s*%?[\w.\-]+ = \w+\[([\d,]*)\]\S* copy\(", line)) and m.group(1) in pools]
+    assert not copies, "\n".join(copies)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 0.5e9
+    assert 12.5e9 < mem.argument_size_in_bytes < 13.5e9  # weights + state pool + 1.05 GB of K and V

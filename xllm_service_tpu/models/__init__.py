@@ -14,12 +14,17 @@ from xllm_service_tpu.models.configs import ModelConfig
 def get_module(cfg: ModelConfig):
     """The model-family module for a config: MLA configs (kv_lora_rank > 0)
     run models/deepseek.py; power-retention configs (retention_degree > 0)
-    models/brumby.py; everything else (Llama/Qwen2/Mixtral-style GQA +
+    models/brumby.py; a stack whose layer pattern is data (layer_types:
+    Mamba-2 beside GQA layers) models/granite.py; everything else (Llama/Qwen2/Mixtral-style GQA +
     optional MoE) runs models/llama.py."""
     if cfg.is_retention:
         from xllm_service_tpu.models import brumby
 
         return brumby
+    if cfg.is_hybrid:
+        from xllm_service_tpu.models import granite
+
+        return granite
     if cfg.is_mla:
         from xllm_service_tpu.models import deepseek
 
@@ -37,7 +42,7 @@ def cache_row_dims(cfg: ModelConfig):
 
 
 def num_caches(cfg: ModelConfig) -> int:
-    """Cache array count: 2 (K + V) for GQA; 1 (latent) for MLA; 2 (the
-    state and its normaliser) for retention — delegated to the family
-    module."""
+    """Cache array count: 2 (K + V) for GQA and for a hybrid stack's
+    attention layers; 1 (latent) for MLA; 2 (the state and its
+    normaliser) for retention — delegated to the family module."""
     return get_module(cfg).NUM_CACHES

@@ -113,10 +113,66 @@ class ModelConfig:
     # degree p, whose sequence state is ONE fixed slot of the executor's
     # state pool (ops/retention.py), not blocks that grow with the context.
     retention_degree: int = 0
+    # A hybrid stack (models/granite.py): the mixer of every layer, in
+    # order, "mamba" (a Mamba-2 state-space mixer whose sequence memory is
+    # one slot of a state pool, ops/mamba.py) or "attention" (GQA over the
+    # paged K/V pool, which then holds the attention layers alone).
+    # () = every layer attends. The pattern is data of the configuration.
+    layer_types: tuple = ()
+    mamba_d_state: int = 0
+    mamba_d_conv: int = 0
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_n_groups: int = 1
+    # Granite's four multipliers (HF GraniteMoeHybridConfig): the token
+    # embedding is scaled by `embedding_multiplier`, attention scores by
+    # `attention_multiplier` (0 = head_dim**-0.5), each block's addition
+    # to the residual stream by `residual_multiplier`, and the logits are
+    # DIVIDED by `logits_scaling`.
+    embedding_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
 
     @property
     def is_retention(self) -> bool:
         return self.retention_degree > 0
+
+    @property
+    def is_hybrid(self) -> bool:
+        return bool(self.layer_types)
+
+    @property
+    def has_state_pool(self) -> bool:
+        """A sequence owns one fixed slot of a state pool for its life."""
+        return self.is_retention or "mamba" in self.layer_types
+
+    @property
+    def has_paged_cache(self) -> bool:
+        """A sequence owns blocks of a paged pool that grow with it."""
+        return not self.is_retention and (
+            not self.layer_types or "attention" in self.layer_types
+        )
+
+    @property
+    def num_attention_layers(self) -> int:
+        """Layers that hold rows of the paged pool."""
+        if self.layer_types:
+            return self.layer_types.count("attention")
+        return 0 if self.is_retention else self.num_layers
+
+    @property
+    def num_mamba_layers(self) -> int:
+        return self.layer_types.count("mamba")
+
+    @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_n_heads * self.mamba_d_head
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """Lanes of the convolution: x, B and C side by side."""
+        return self.mamba_d_inner + 2 * self.mamba_n_groups * self.mamba_d_state
 
     @property
     def is_moe(self) -> bool:
@@ -159,7 +215,9 @@ def approx_param_count(cfg: ModelConfig) -> int:
     sizes the KV pool with it and __graft_entry__'s dress rehearsal
     checks serving layouts against it."""
     E, L = cfg.hidden_size, cfg.num_layers
-    if cfg.is_mla:
+    if cfg.is_hybrid:
+        attn = _hybrid_mixer_params(cfg) / L  # the pattern's mean a layer
+    elif cfg.is_mla:
         dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
         kvr, qr, Hq = cfg.kv_lora_rank, cfg.q_lora_rank, cfg.num_heads
         attn = (
@@ -187,6 +245,15 @@ def approx_param_count(cfg: ModelConfig) -> int:
         + L * attn
         + mlp_total
     )
+
+
+def _hybrid_mixer_params(cfg: ModelConfig) -> int:
+    """Mixer matrices of a hybrid stack over all its layers."""
+    E = cfg.hidden_size
+    d_in, conv = cfg.mamba_d_inner, cfg.mamba_conv_dim
+    mamba = E * (d_in + conv + cfg.mamba_n_heads) + d_in * E + conv * cfg.mamba_d_conv
+    gqa = 2 * E * (cfg.num_heads + cfg.num_kv_heads) * cfg.head_dim
+    return cfg.num_mamba_layers * mamba + cfg.num_attention_layers * gqa
 
 
 _REGISTRY: Dict[str, ModelConfig] = {}
@@ -686,6 +753,79 @@ register(
         rope_beta_slow=1.0,
         rope_mscale=0.707,
         rope_mscale_all_dim=0.707,
+    )
+)
+
+_GRANITE_PERIOD = ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
+
+register(
+    # A hybrid stack at test scale (models/granite.py): two runs of Mamba-2
+    # layers around one NoPE GQA layer, 8 heads of 16 lanes (one 128-lane
+    # row of the state pool), 4 of 8 experts held beside a shared MLP.
+    ModelConfig(
+        name="granite-tiny",
+        vocab_size=512,
+        hidden_size=64,
+        intermediate_size=32,
+        num_layers=4,
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=16,
+        rms_norm_eps=1e-5,
+        tie_word_embeddings=True,
+        num_experts=8,
+        num_experts_per_tok=3,
+        moe_intermediate_size=32,
+        n_shared_experts=2,
+        layer_types=("mamba", "attention", "mamba", "mamba"),
+        mamba_d_state=16,
+        mamba_d_conv=4,
+        mamba_n_heads=8,
+        mamba_d_head=16,
+        embedding_multiplier=12.0,
+        attention_multiplier=1.0 / 16,
+        residual_multiplier=0.22,
+        logits_scaling=16.0,
+        max_position_embeddings=4096,
+    )
+)
+
+register(
+    # https://huggingface.co/ibm-granite/granite-4.0-h-small/blob/main/config.json
+    # (model_type granitemoehybrid), 32B-A9B, as ONE CHIP'S SHARE of it at
+    # every published width: the first period of the layer pattern (10 of
+    # 40 layers: 9 Mamba-2, 1 NoPE GQA), experts 0-35 of the 72 a layer
+    # (top 10; the router stays 72 wide), vocabulary rows 0-50,175 of
+    # 100,352 (benchmarks/configs/granite-4.0-h-small.json has the
+    # deployment). Random weights only: runtime/weights.py has no loader.
+    ModelConfig(
+        name="granite-4.0-h-small",
+        vocab_size=50176,
+        hidden_size=4096,
+        intermediate_size=768,
+        num_layers=10,
+        num_heads=32,
+        num_kv_heads=8,
+        head_dim=128,
+        rope_theta=10000.0,
+        rms_norm_eps=1e-5,
+        tie_word_embeddings=True,
+        num_experts=72,
+        experts_held=(0, 36),
+        num_experts_per_tok=10,
+        moe_intermediate_size=768,
+        n_shared_experts=2,  # one shared MLP of 1536 = 2 x 768
+        layer_types=_GRANITE_PERIOD,
+        mamba_d_state=128,
+        mamba_d_conv=4,
+        mamba_n_heads=128,
+        mamba_d_head=64,
+        mamba_n_groups=1,
+        embedding_multiplier=12.0,
+        attention_multiplier=0.0078125,
+        residual_multiplier=0.22,
+        logits_scaling=16.0,
+        max_position_embeddings=131072,
     )
 )
 

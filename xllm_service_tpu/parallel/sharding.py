@@ -44,6 +44,8 @@ def param_shardings(
         return NamedSharding(mesh, P(*spec))
 
     tp = tp_axis if tp_axis in mesh.shape else None
+    if cfg.is_hybrid:
+        return _hybrid_param_shardings(ns)
     layers: Dict[str, Any] = {
         "attn_norm": ns(None, None),
         "mlp_norm": ns(None, None),
@@ -157,6 +159,30 @@ def param_shardings(
     if not cfg.tie_word_embeddings:
         out["lm_head"] = ns(None, tp)
     return out
+
+
+def _hybrid_param_shardings(ns) -> Dict[str, Any]:
+    """models/granite.py's tree, every leaf replicated: the family is
+    served at tp_size 1 only (its state pool is not sharded;
+    runtime/executor.py refuses the rest by name)."""
+
+    def rep(names, ndim):
+        return {k: ns(*(None,) * ndim) for k in names}
+
+    return {
+        "embed": ns(None, None),
+        "final_norm": ns(None),
+        "layers": {
+            **rep(("attn_norm", "mlp_norm"), 2),
+            **rep(("router", "w_sh_gate", "w_sh_up", "w_sh_down"), 3),
+            **rep(("w_gate", "w_up", "w_down"), 4),
+        },
+        "mamba": {
+            **rep(("conv_b", "dt_bias", "A_log", "D", "gate_norm"), 2),
+            **rep(("w_in", "conv_w", "w_out"), 3),
+        },
+        "attn": rep(("wq", "wk", "wv", "wo"), 3),
+    }
 
 
 def kv_cache_sharding(mesh: Mesh) -> NamedSharding:

@@ -260,7 +260,40 @@ class BlockManager:
         return ev
 
 
-class StateSlotManager(BlockManager):
+class _NoPrefixReuse:
+    """The content-addressed half of the block manager, inert: for a
+    family whose sequences carry a recurrent state. A state is not
+    addressable by block hash, and a prefix hit on the K/V blocks of such
+    a family would skip tokens whose state was never computed, so nothing
+    is committed, matched or told to the fabric (the engine's build
+    refuses the prefix cache's tiers by name; reusing a prefix needs state
+    snapshots at chunk boundaries, which are not built). Inert and not
+    refused, because the engine thread's drains commit full blocks on the
+    way to finishing a sequence."""
+
+    def commit_block(self, block_id: int, block_hash: bytes) -> None:
+        pass
+
+    def match_prefix(self, token_ids, hashes=None):
+        return 0, []
+
+    def lookup_hash(self, block_hash: bytes):
+        return None
+
+
+class HybridBlockManager(_NoPrefixReuse, BlockManager):
+    """Blocks for a family with BOTH kinds of sequence memory
+    (models/granite.py): K/V blocks of its attention layers that grow with
+    the context, allocated and freed here as for any family, beside one
+    state slot for the sequence's life, which is the sequence's row of the
+    engine's running rows and so is owned and freed with it
+    (`InferenceEngine._free_slots`): admission needs a free row AND blocks,
+    and finish, cancel and preemption return both. A preempted sequence
+    resumes by recomputing its tokens from position 0 (nothing of it is
+    matched), which rewrites its blocks and makes its new slot clean."""
+
+
+class StateSlotManager(_NoPrefixReuse, BlockManager):
     """Slot ownership for a family whose sequence state is ONE fixed slot
     of the executor's state pool (ops/retention.py), whatever the
     context's length. The engine gives such a family blocks as long as
@@ -272,12 +305,8 @@ class StateSlotManager(BlockManager):
     which also makes a freed slot clean (a chunk at position 0 ignores
     what the slot held).
 
-    A state is not addressable by block hash, so the content-addressed
-    half of the interface is inert: nothing is committed, matched or told
-    to the fabric (the engine's build refuses the prefix cache's tiers by
-    name; reusing a prefix needs state snapshots at chunk boundaries,
-    which are not built). Inert and not refused, because the one block is
-    FULL at exactly `max_seq_len` tokens and the engine thread's drains
+    The content-addressed half is inert (`_NoPrefixReuse`): the one block
+    is FULL at exactly `max_seq_len` tokens and the engine thread's drains
     would commit it on the way to finishing the sequence with LENGTH."""
 
     def __init__(self, slots: int, block_size: int, seed: int = 1024):
@@ -298,12 +327,3 @@ class StateSlotManager(BlockManager):
                 f"(a block is as long as max_seq_len={self.block_size})"
             )
         return super().allocate(1)
-
-    def commit_block(self, block_id: int, block_hash: bytes) -> None:
-        pass
-
-    def match_prefix(self, token_ids, hashes=None):
-        return 0, []
-
-    def lookup_hash(self, block_hash: bytes):
-        return None

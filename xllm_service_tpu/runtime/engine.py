@@ -55,6 +55,7 @@ from xllm_service_tpu.ops.sampling import SamplingParams
 from xllm_service_tpu.runtime.block_manager import (
     BlockManager,
     OutOfBlocksError,
+    HybridBlockManager,
     StateSlotManager,
 )
 from xllm_service_tpu.runtime import compile_cache as compile_cache_mod
@@ -379,9 +380,20 @@ class InferenceEngine:
         # block_manager.StateSlotManager's content-addressed half is
         # inert). Preemption frees the slot; the resumed sequence
         # recomputes its tokens from position 0.
-        self.state_family = bool(getattr(self.executor, "is_state", False))
+        # A family with a state pool BESIDE a paged cache
+        # (models/granite.py) keeps ordinary blocks for its K/V and the
+        # state in the sequence's row (`seq.slot`, which every PrefillItem
+        # carries): the same rules, with blocks that grow.
+        self.state_family = bool(
+            getattr(self.executor, "has_state_pool", False)
+        )
         self.state_recomputes = 0
-        if self.state_family:
+        if self.state_family and self.executor.has_paged_cache:
+            self.block_mgr = HybridBlockManager(
+                self.executor.num_blocks, self.block_size,
+                seed=engine_cfg.murmur_hash3_seed,
+            )
+        elif self.state_family:
             self.block_mgr = StateSlotManager(
                 self.R, self.block_size, seed=engine_cfg.murmur_hash3_seed
             )
@@ -689,6 +701,13 @@ class InferenceEngine:
             "xllm_engine_state_pool_bytes", "Device bytes of the state pool",
         ).set_function(
             lambda: getattr(self.executor, "state_pool_bytes", 0)
+            if self.state_family else 0
+        )
+        self.metrics.gauge(
+            "xllm_engine_state_slot_bytes",
+            "Device bytes of ONE state slot, over every layer that has one",
+        ).set_function(
+            lambda: getattr(self.executor, "state_slot_bytes", 0)
             if self.state_family else 0
         )
         self.metrics.counter(
@@ -1332,6 +1351,7 @@ class InferenceEngine:
                 token_ids=np.asarray(seq.tokens[start:start + n], np.int32),
                 start_pos=start,
                 block_table=table,
+                slot=seq.slot,
                 temperature=s.temperature,
                 top_k=s.top_k,
                 top_p=s.top_p,
@@ -2025,6 +2045,7 @@ class InferenceEngine:
                     ),
                     start_pos=start,
                     block_table=table,
+                    slot=seq.slot,
                     temperature=s.temperature,
                     top_k=s.top_k,
                     top_p=s.top_p,

@@ -124,6 +124,10 @@ class PrefillItem:
     # tokens, [3, n] (None = standard 1D positions). Cache slots stay
     # token-count-based; only the q/k rotation reads these.
     rope_positions: Optional[np.ndarray] = None
+    # The sequence's row of the R running rows. A family with a state
+    # pool beside its paged cache keeps the sequence's state in that slot
+    # (ModelExecutor.slot_column); nobody else reads it.
+    slot: int = -1
 
 
 _COMPILATION_CACHE_DIR: Optional[str] = None
@@ -506,11 +510,12 @@ class ModelExecutor:
         self.dtype = jnp.bfloat16 if engine_cfg.dtype == "bfloat16" else jnp.float32
         # int8 KV cache: halves decode's HBM traffic (the bound resource);
         # params/activations stay in model dtype.
-        if self.cfg.is_retention:
+        if self.cfg.has_state_pool:
             if engine_cfg.kv_cache_dtype != "auto":
                 raise StateFamilyUnsupported(
                     f"kv_cache_dtype={engine_cfg.kv_cache_dtype!r}: a state "
-                    f"pool is float32 ('auto'); no lower dtype is offered"
+                    f"pool is float32 ('auto'); no lower dtype is offered "
+                    f"(nor an int8 cache beside one)"
                 )
         elif engine_cfg.kv_cache_dtype not in ("auto", "int8"):
             raise ValueError(
@@ -524,19 +529,28 @@ class ModelExecutor:
             )
         self.kv_quantized = engine_cfg.kv_cache_dtype == "int8"
         self.R = engine_cfg.max_running_requests
-        # A retention family's sequence state is one slot of a state pool,
-        # not blocks (models/brumby.py): a block is as long as the longest
-        # sequence, so every sequence owns exactly one, and the block id
-        # that rides the block tables is the slot (+ 1).
-        self.is_state = self.cfg.is_retention
-        if self.is_state:
+        # Two kinds of sequence memory, and a family has one or both:
+        # blocks of a paged pool that grow with the context, and ONE slot
+        # of a state pool for the sequence's life. A family with a state
+        # pool alone (power retention, models/brumby.py) gets blocks as
+        # long as the longest sequence, so every sequence owns exactly
+        # one, and the block id that rides the block tables is the slot
+        # (+ 1). A family with both (models/granite.py) keeps the block
+        # tables for its K/V blocks; a decode row's slot is the row, and a
+        # prefill row's rides one more column of its table (slot_column).
+        self.has_state_pool = self.cfg.has_state_pool
+        self.has_paged_cache = self.cfg.has_paged_cache
+        self.slot_column = self.has_state_pool and self.has_paged_cache
+        self.state_pool_bytes = 0
+        if self.has_state_pool:
             self._refuse_for_state_family(tp, ep)
-            self.block_size = engine_cfg.max_seq_len
             self.state_pool_bytes = self._check_state_pool()
-            self.num_blocks = self.R + 1
-        else:
+        if self.has_paged_cache:
             self.block_size = engine_cfg.block_size
             self.num_blocks = self._decide_num_blocks()
+        else:
+            self.block_size = engine_cfg.max_seq_len
+            self.num_blocks = self.R + 1
         self.max_blocks_per_seq = math.ceil(
             engine_cfg.max_seq_len / self.block_size
         )
@@ -581,7 +595,7 @@ class ModelExecutor:
             # latent row per token instead: [L, N, 1, BS, C].
             cache_heads, cache_dim = models.cache_row_dims(self.cfg)
             cache_shape = (
-                self.cfg.num_layers,
+                self.cfg.num_attention_layers,
                 self.num_blocks,
                 cache_heads,
                 self.block_size,
@@ -596,15 +610,23 @@ class ModelExecutor:
                 kv_sharding,
                 scale_sharding if self.kv_quantized else None,
             )
-            if self.is_state:
-                # The state S rides the k slot, its normaliser z the v slot.
+            if self.has_state_pool:
+                # The family's two state arrays (state_shapes) ride the k
+                # and the v slot: alone (retention: S and its normaliser
+                # z), or each as the second of a pair behind the paged K
+                # and V stacks of the attention layers (a hybrid stack).
                 shapes = self.model_mod.state_shapes(self.cfg, self.R)
                 rep_sh = NamedSharding(self.mesh, P())
+
+                def one(sh):
+                    state = jnp.zeros(sh, self.state_dtype)
+                    if not self.has_paged_cache:
+                        return state
+                    return kvc.alloc_cache(cache_shape, self.dtype, False), state
+
                 alloc = jax.jit(
-                    lambda: tuple(
-                        jnp.zeros(sh, self.state_dtype) for sh in shapes
-                    ),
-                    out_shardings=(rep_sh, rep_sh),
+                    lambda: tuple(one(sh) for sh in shapes),
+                    out_shardings=rep_sh,  # every leaf: the pools are not sharded
                 )
                 self.k_cache, self.v_cache = alloc()
             elif self.num_caches == 2:
@@ -684,7 +706,7 @@ class ModelExecutor:
         # Buckets must cover max_seq_len so any admissible suffix fits.
         if not self.prefill_buckets or self.prefill_buckets[-1] < engine_cfg.max_seq_len:
             self.prefill_buckets.append(engine_cfg.max_seq_len)
-        if self.is_state:
+        if self.has_state_pool:
             # No prefix hit shortens a suffix and no chunk passes the
             # step's budget, so no bucket beyond it is ever dispatched
             # (and max_seq_len bounds no memory: a 32768-token bucket
@@ -832,7 +854,7 @@ class ModelExecutor:
                 f"weight_dtype=int{bits}: model family "
                 f"{self.model_mod.__name__} has no quantizable-leaf map"
             )
-        for stack in ("layers", "dense_layers"):
+        for stack in ("layers", "dense_layers", "mamba", "attn"):
             if stack not in self.params:
                 continue
             for name in names:
@@ -879,7 +901,7 @@ class ModelExecutor:
         if tp > 1 or ep > 1 or e.sp_size > 1 or e.dp_size > 1:
             raise StateFamilyUnsupported(
                 f"tp_size/ep_size/sp_size/dp_size > 1: the state pool of "
-                f"{self.cfg.name} is not sharded (its KV-head axis could "
+                f"{self.cfg.name} is not sharded (its head axis could "
                 f"be; not built)"
             )
         if e.speculative_tokens > 0:
@@ -905,12 +927,7 @@ class ModelExecutor:
         """Bytes of the state pool: `max_running_requests` slots, sized by
         their bytes and refused here if they do not fit beside the
         weights (nothing is sized from what HBM is left)."""
-        from xllm_service_tpu.ops import retention as retention_ops
-
-        pool = retention_ops.state_bytes(
-            self.cfg.num_layers, self.R, self.cfg.num_kv_heads,
-            self.cfg.head_dim, jnp.dtype(self.state_dtype).itemsize,
-        )
+        pool = self.R * self.state_slot_bytes
         weights = approx_param_count(self.cfg) * self._bytes_per_param()
         limit = self._device_bytes_limit() * self.engine_cfg.hbm_utilization
         if weights + pool > limit:
@@ -922,6 +939,18 @@ class ModelExecutor:
                 f"max_running_requests"
             )
         return pool
+
+    @property
+    def state_slot_bytes(self) -> int:
+        """Bytes of ONE sequence's state slot over every layer that has
+        one (0 where the family has no state pool): the family's
+        `state_shapes` at one slot, in `state_dtype`."""
+        if not self.cfg.has_state_pool:
+            return 0
+        shapes = self.model_mod.state_shapes(self.cfg, 1)
+        return sum(int(np.prod(sh)) for sh in shapes) * jnp.dtype(
+            self.state_dtype
+        ).itemsize
 
     def _device_bytes_limit(self) -> int:
         """Device memory the pool is sized against. A TPU that reports no
@@ -975,6 +1004,7 @@ class ModelExecutor:
         budget = (
             total_hbm * self.engine_cfg.hbm_utilization
             - n_params * param_bytes / tp
+            - self.state_pool_bytes  # a hybrid stack's pool, sized first
         ) / 2
         cache_heads, cache_dim = models.cache_row_dims(self.cfg)
         # int8 cache: 1 byte/element + 4-byte f32 scale per sub-channel
@@ -1002,7 +1032,7 @@ class ModelExecutor:
         )
         block_bytes = (
             models.num_caches(self.cfg)
-            * self.cfg.num_layers
+            * self.cfg.num_attention_layers
             * self.block_size
             * heads_per_dev
             * cache_dim
@@ -1203,6 +1233,19 @@ class ModelExecutor:
         toks, lps = self._fetch(toks, lps)
         return [(int(toks[i]), float(lps[i])) for i in range(len(group))]
 
+    def _pf_tables(self, items: List["PrefillItem"], P: int, CB: int):
+        """[P, CB] block tables of a prefill group, cut to its context
+        bucket; with `slot_column` one more column, the row's state slot
+        + 1 (0 on a padding row), which the family's step functions split
+        off again (models/granite.py)."""
+        tables = np.zeros((P, CB + int(self.slot_column)), np.int32)
+        for i, it in enumerate(items):
+            m = min(CB, len(it.block_table))
+            tables[i, :m] = np.asarray(it.block_table[:m], np.int32)
+            if self.slot_column:
+                tables[i, CB] = it.slot + 1
+        return tables
+
     def _prefill_inputs(self, group: List["PrefillItem"]):
         """Stage one prefill group's host inputs on the device:
         (positional arrays up to steps, media arrays, optional keyword
@@ -1219,7 +1262,7 @@ class ModelExecutor:
         token_ids = np.zeros((P, Lpad), np.int32)
         start_pos = np.zeros((P,), np.int32)
         true_len = np.zeros((P,), np.int32)
-        tables = np.zeros((P, CB), np.int32)
+        tables = self._pf_tables(group, P, CB)
         temps = np.zeros((P,), np.float32)
         top_ks = np.zeros((P,), np.int32)
         top_ps = np.ones((P,), np.float32)
@@ -1230,8 +1273,6 @@ class ModelExecutor:
             token_ids[i, :n] = it.token_ids
             start_pos[i] = it.start_pos
             true_len[i] = n
-            m = min(CB, len(it.block_table))
-            tables[i, :m] = np.asarray(it.block_table[:m], np.int32)
             temps[i] = it.temperature
             top_ks[i] = it.top_k
             top_ps[i] = it.top_p
@@ -1913,11 +1954,16 @@ class ModelExecutor:
         """Bytes one token holds in the paged pool over every layer and
         cache (a latent row a layer for an MLA family); 0 for a state
         pool, which holds none a token."""
-        if self.is_state:
+        if not self.has_paged_cache:
             return 0
-        data = kvc.raw(self.k_cache)
+        data = kvc.raw(self._paged(self.k_cache))
         per_layer = data.shape[2] * data.shape[4] * data.dtype.itemsize
         return int(self.num_caches * data.shape[0] * per_layer)
+
+    def _paged(self, cache):
+        """The paged stack of a cache slot (the first of the pair where a
+        state pool rides beside it)."""
+        return cache[0] if self.slot_column else cache
 
     def take_moe_stats(self) -> list:
         """The counts of the dispatches since the last take (device
@@ -1999,7 +2045,7 @@ class ModelExecutor:
         (`shards`) and marks the resolve_kv_packing downgrade as
         `gather-fallback` so a tp that strands the packed layout shows up
         in bench rows and /metrics, not just a log line."""
-        if self.is_state:
+        if self.cfg.is_retention:
             from xllm_service_tpu.ops import retention as retention_ops
 
             route = (
@@ -2008,6 +2054,20 @@ class ModelExecutor:
                 else "retention-xla"
             )
             return {"decode": route, "prefill": route, "mixed": route}
+        if self.cfg.is_hybrid:
+            from xllm_service_tpu.ops import mamba as mamba_ops
+            from xllm_service_tpu.ops.attention import resolved_kernel_report
+
+            rep = resolved_kernel_report(
+                self._paged(self.k_cache), self.cfg.head_dim, shards=1
+            )
+            ssm = self.k_cache[1]
+            rep["state"] = (
+                "mamba-pallas"
+                if mamba_ops.kernel_eligible(ssm, self.cfg.mamba_n_groups)
+                else "mamba-xla"
+            )
+            return self._add_moe_report(rep)
         if self.cfg.is_mla:
             from xllm_service_tpu.ops.attention import (
                 resolved_mla_kernel_report,
@@ -2224,7 +2284,7 @@ class ModelExecutor:
         pf_tokens = np.zeros((P, Lpad), np.int32)
         pf_start = np.zeros((P,), np.int32)
         pf_len = np.zeros((P,), np.int32)
-        pf_tables = np.zeros((P, CBp), np.int32)
+        pf_tables = self._pf_tables(items, P, CBp)
         pf_temps = np.zeros((P,), np.float32)
         pf_top_k = np.zeros((P,), np.int32)
         pf_top_p = np.ones((P,), np.float32)
@@ -2235,8 +2295,6 @@ class ModelExecutor:
             pf_tokens[i, :n] = it.token_ids
             pf_start[i] = it.start_pos
             pf_len[i] = n
-            m = min(CBp, len(it.block_table))
-            pf_tables[i, :m] = np.asarray(it.block_table[:m], np.int32)
             pf_temps[i] = it.temperature
             pf_top_k[i] = it.top_k
             pf_top_p[i] = it.top_p
@@ -2723,11 +2781,12 @@ class ModelExecutor:
         return out
 
     def _no_state_handoff(self) -> None:
-        if self.is_state:
+        if self.has_state_pool:
             raise StateFamilyUnsupported(
-                "PD handoff: the state of a power-retention sequence is a "
-                "state slot, not KV blocks; its export and import "
-                "(runtime/transfer.py) are not built"
+                "PD handoff: a sequence of a state-pool family holds a "
+                "state slot (beside its KV blocks, if it has any); a "
+                "slot's export and import (runtime/transfer.py) are not "
+                "built"
             )
 
     def migration_shape(self, n_blocks: int) -> Tuple[int, ...]:
