@@ -244,13 +244,47 @@ _DEPTH_FLAVOURS = {
 }
 
 
+def _handed_over(eng, cols):
+    """An owner that takes a step's outputs at the step listener, as the
+    instance's push callbacks do: the callbacks only collect (inside a
+    step whose end hands over), the listener delivers the list in booking
+    order. Returns the callbacks to give the requests."""
+    held = []
+
+    def collecting(col):
+        def cb(out):
+            assert eng.step_open()  # every callback runs inside a step
+            held.append((col, out))
+            return True
+
+        return cb
+
+    def hand_over():
+        batch = held[:]
+        del held[:]
+        for col, out in batch:
+            col(out)
+
+    eng.add_step_listener(hand_over)
+    # nothing waits for a later step: empty whenever step() has returned
+    step = eng.step
+    eng.step = lambda: (step(), held == [] or pytest.fail("held"))[0]
+    return [collecting(col) for col in cols]
+
+
+@pytest.mark.parametrize(
+    "handed", [False, True], ids=["callback", "handed-over"]
+)
 @pytest.mark.parametrize("seeded", [False, True], ids=["greedy", "seeded"])
 @pytest.mark.parametrize("flavour", sorted(_DEPTH_FLAVOURS))
-def test_depth0_and_depth1_emit_the_same_streams(flavour, seeded):
+def test_depth0_and_depth1_emit_the_same_streams(flavour, seeded, handed):
     """The engine has ONE step loop; sync_engine only sets its depth.
     At depth 0 every step is drained before the next dispatch: nothing
     overlaps, nothing is discarded late, and the streams are depth 1's
-    (which does discard: the early stop below costs it a sample)."""
+    (which does discard: the early stop below costs it a sample). An
+    owner that collects in its callbacks and delivers at the step
+    listener (`handed`) sees the same streams: plain, mixed and
+    speculative (several tokens a row) steps, at both depths."""
     kw = _DEPTH_FLAVOURS[flavour]
     rng = np.random.RandomState(5)
     # a repetitive prompt (drafts accept) beside two random ones
@@ -273,9 +307,9 @@ def test_depth0_and_depth1_emit_the_same_streams(flavour, seeded):
     streams = {}
     for sync in (True, False):
         eng = _mk(sync, **kw)
-        cols = []
+        cols = [C() for _ in prompts]
+        cbs = _handed_over(eng, cols) if handed else cols
         for i, prompt in enumerate(prompts):
-            cols.append(C())
             eng.add_request(EngineRequest(
                 f"r{i}", list(prompt),
                 SamplingParams(
@@ -283,7 +317,7 @@ def test_depth0_and_depth1_emit_the_same_streams(flavour, seeded):
                     stop_token_ids=(stop_tok,) if i == 1 else (),
                     **sp,
                 ),
-                cols[-1],
+                cbs[i],
             ))
             eng.step()  # staggered: later prompts land beside decode rows
         _drive(eng)

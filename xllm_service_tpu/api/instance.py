@@ -36,6 +36,7 @@ from xllm_service_tpu.common.types import (
 )
 from xllm_service_tpu.runtime import compile_cache
 from xllm_service_tpu.obs import (
+    BATCH_BUCKETS,
     FlightRecorder,
     MetricsRegistry,
     SpanRing,
@@ -278,8 +279,29 @@ class InstanceServer(
             if self._master
             else None
         )
-        # decode->service push pipeline
-        self._push_q: "queue.Queue[Optional[RequestOutput]]" = queue.Queue()
+        # decode->service push pipeline: every item is a LIST of outputs
+        # (a step's, where the engine marks the end of a step's booking;
+        # one output otherwise), None stops the loop
+        self._push_q: "queue.Queue[Optional[List[RequestOutput]]]" = (
+            queue.Queue()
+        )
+        # One hand-over a step (instance_serving._hand_over): the push
+        # callbacks of an engine that has step listeners only collect
+        # here, [(output, the request's detokenizers)] in booking order;
+        # the listener empties it where the booking ends. Engine thread
+        # only, and only inside a step (`engine.step_open()`): no lock.
+        self._pending_outs: list = []
+        self._step_open = None
+        self._m_handover = self.metrics.histogram(
+            "xllm_engine_handover_outputs",
+            "Outputs the engine thread hands to the push queue at one "
+            "step boundary (one put; a step's booked rows)",
+            buckets=BATCH_BUCKETS,
+        )
+        add_listener = getattr(self.engine, "add_step_listener", None)
+        if add_listener is not None:
+            add_listener(self._hand_over)
+            self._step_open = self.engine.step_open
         self._push_thread = threading.Thread(
             target=self._push_loop, name=f"gen-push-{self.name}", daemon=True
         )
@@ -480,12 +502,12 @@ class InstanceServer(
 
     def _push_loop(self) -> None:
         while True:
-            out = self._push_q.get()
-            if out is None:
+            batch = self._push_q.get()
+            if batch is None:
                 return
             if getattr(self, "_crashed", False):
                 continue  # crashed instances push nothing (fault injection)
-            batch = [out]
+            batch = list(batch)
             # micro-batch whatever else is queued (DisaggStreamGenerations
             # carries a list for the same reason)
             while True:
@@ -496,7 +518,7 @@ class InstanceServer(
                 if nxt is None:
                     self._push_q.put(None)
                     break
-                batch.append(nxt)
+                batch.extend(nxt)
             # Partition by destination: master push (default topology) vs
             # relay through the request's prefill instance (alternate
             # topology — service.h:61-71). The master group goes FIRST and

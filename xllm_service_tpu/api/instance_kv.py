@@ -701,9 +701,14 @@ class KVHandoffMixin:
                 with self._srid_mu:
                     self._srid_map.pop(srid, None)
                     self._srid_forget_locked(srid)
-                self._push_q.put(out)
+                self._push_q.put([out])
 
         def send(handoff) -> None:
+            # Engine thread, in mid-step: the first token's output is
+            # still collected. Hand it over BEFORE the transfer is queued:
+            # the decode peer continues this side's detokenizer, and the
+            # first token is on the push queue before the peer can push.
+            self._hand_over()
             t_pf_done = time.monotonic()  # prefill just finished
             self._span(
                 srid, "handoff_send",
@@ -1094,7 +1099,7 @@ class KVHandoffMixin:
                 "handoff names adapter %r this instance does not serve; "
                 "rejecting", lora_name,
             )
-            self._push_q.put(RequestOutput(
+            self._push_q.put([RequestOutput(
                 request_id=header.get("service_request_id", ""),
                 service_request_id=srid,
                 status=Status(
@@ -1102,7 +1107,7 @@ class KVHandoffMixin:
                     f"decode instance does not serve adapter {lora_name!r}",
                 ),
                 finished=True,
-            ))
+            )])
             return ""
         rid = generate_uuid(16)
         with self._srid_mu:

@@ -17,7 +17,7 @@ from typing import Any, Dict, List, Optional, Tuple
 from xllm_service_tpu.api.http_utils import HttpJsonApi, SseWriter
 from xllm_service_tpu.api.protocol import parse_prompt_field, sampling_from_body
 from xllm_service_tpu.common.shortuuid import generate_uuid
-from xllm_service_tpu.common.types import RequestOutput, StatusCode
+from xllm_service_tpu.common.types import RequestOutput, Status, StatusCode
 from xllm_service_tpu.ops.sampling import SamplingParams
 from xllm_service_tpu.service.request import ServiceRequest
 from xllm_service_tpu.service.response_handler import accumulate_sequences
@@ -35,31 +35,98 @@ class ServingMixin:
     ):
         if detoks is None:
             detoks = {}
+        step_open = self._step_open
+        pending = self._pending_outs
 
         def callback(out: RequestOutput) -> bool:
             out.service_request_id = srid
-            self._detokenize(out, detoks)
-            self._srid_note_delivered(
-                srid, sum(len(s.token_ids) for s in out.outputs)
-            )
-            if out.finished:
-                with self._srid_mu:
-                    self._srid_map.pop(srid, None)
-                    self._srid_forget_locked(srid)
-                # A prefill_only request that finishes on its first token
-                # (EOS / max_tokens=1 / reject / cancel) never runs its
-                # handoff — reap the ack event here or it leaks forever.
-                with self._push_acked_mu:
-                    self._push_acked.pop(srid, None)
-                # Same for the streamed-media handle: without this, a
-                # finished request's embedding arrays stay pinned in
-                # _mm_streams until the NEXT mm request triggers the TTL
-                # reap — indefinitely on an instance gone text-only.
-                self._mm_stream_discard(srid)
-            self._push_q.put(out)
+            if step_open is not None and step_open():
+                # Engine thread, inside a step whose end runs
+                # _hand_over: collect, and leave the rest to it.
+                pending.append((out, detoks))
+            else:
+                # No boundary to wait for (FakeEngine, a foreign thread,
+                # a call outside a step): all of it, now.
+                self._push_outputs([(out, detoks)])
             return True
 
         return callback
+
+    def _hand_over(self) -> None:
+        """The engine's step listener (engine thread): the outputs the
+        push callbacks collected since the last boundary leave as ONE
+        list. If that raises, what it held is not dropped in silence: it
+        is logged and the requests are cancelled, as a raising callback
+        cancels its own."""
+        held = self._pending_outs[:]
+        if not held:
+            return
+        del self._pending_outs[:]
+        self._m_handover.observe(len(held))
+        try:
+            self._push_outputs(held)
+        except Exception:
+            logger.exception(
+                "hand-over of %d outputs failed; cancelling their requests",
+                len(held),
+            )
+            for out, _ in held:
+                self.engine.cancel(out.request_id)
+
+    def _push_outputs(self, held) -> None:
+        """Everything a pushed output needs besides its row's booking,
+        once for the list: text, the reconcile manifest's delivered
+        counts under one `_srid_mu`, the reaping of finished requests,
+        ONE queue put. `held` is [(output, the request's detokenizers)]
+        in booking order; an output whose detokenization raises fails
+        its own request and no other."""
+        outs: List[RequestOutput] = []
+        failed = set()
+        for out, detoks in held:
+            if out.request_id in failed:
+                continue  # a finished output is its request's last
+            try:
+                self._detokenize(out, detoks)
+            except Exception:
+                logger.exception(
+                    "detokenization failed for %s; failing the request",
+                    out.service_request_id,
+                )
+                failed.add(out.request_id)
+                self.engine.cancel(out.request_id)
+                out = RequestOutput(
+                    request_id=out.request_id,
+                    service_request_id=out.service_request_id,
+                    status=Status(
+                        StatusCode.UNKNOWN, "detokenization failed"
+                    ),
+                    finished=True,
+                )
+            outs.append(out)
+        finished = []
+        with self._srid_mu:
+            for out in outs:
+                srid = out.service_request_id
+                info = self._srid_info.get(srid)
+                if info is not None:
+                    for s in out.outputs:
+                        info["delivered"] += len(s.token_ids)
+                if out.finished:
+                    self._srid_map.pop(srid, None)
+                    self._srid_forget_locked(srid)
+                    finished.append(srid)
+        for srid in finished:
+            # A prefill_only request that finishes on its first token
+            # (EOS / max_tokens=1 / reject / cancel) never runs its
+            # handoff — reap the ack event here or it leaks forever.
+            with self._push_acked_mu:
+                self._push_acked.pop(srid, None)
+            # Same for the streamed-media handle: without this, a
+            # finished request's embedding arrays stay pinned in
+            # _mm_streams until the NEXT mm request triggers the TTL
+            # reap — indefinitely on an instance gone text-only.
+            self._mm_stream_discard(srid)
+        self._push_q.put(outs)
 
     def _serve_fanout_forwarded(
         self,
@@ -122,7 +189,7 @@ class ServingMixin:
                     for other in others:
                         self.engine.cancel(other)
                     out.finished = True
-                    self._push_q.put(out)
+                    self._push_q.put([out])
                     return False
                 if state["buffered"] is not None:
                     # best_of: hold everything until all children finish.
@@ -148,7 +215,7 @@ class ServingMixin:
                     with self._srid_mu:
                         self._srid_map.pop(srid, None)
                         self._srid_forget_locked(srid)
-                self._push_q.put(out)
+                self._push_q.put([out])
                 return True
 
             return cb
@@ -217,7 +284,7 @@ class ServingMixin:
         with self._srid_mu:
             self._srid_map.pop(srid, None)
             self._srid_forget_locked(srid)
-        self._push_q.put(final)
+        self._push_q.put([final])
 
     def _prompt_tokens(self, body: Dict[str, Any], chat: bool) -> List[int]:
         # Forwarded traffic arrives pre-tokenized (the injection contract,

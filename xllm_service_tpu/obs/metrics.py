@@ -220,6 +220,16 @@ class _HistChild:
             self.sum += v
             self.count += 1
 
+    def observe_many(self, vs: Sequence[float]) -> None:
+        """`observe` for each of `vs` under ONE acquisition (a step's
+        values at once): the same buckets, sum and count."""
+        idxs = [bisect.bisect_left(self._bounds, v) for v in vs]
+        with self._lock:
+            for idx, v in zip(idxs, vs):
+                self.counts[idx] += 1
+                self.sum += v
+            self.count += len(idxs)
+
     def snapshot(self) -> Tuple[List[int], float, int]:
         with self._lock:
             return list(self.counts), self.sum, self.count
@@ -272,6 +282,9 @@ class Histogram(_Metric):
 
     def observe(self, v: float) -> None:
         self._only().observe(v)
+
+    def observe_many(self, vs: Sequence[float]) -> None:
+        self._only().observe_many(vs)
 
     def percentile(self, q: float) -> Optional[float]:
         return self._only().percentile(q)

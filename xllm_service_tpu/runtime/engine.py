@@ -450,6 +450,13 @@ class InferenceEngine:
         # set by the instance layer ONLY when tracing is enabled — None
         # keeps the token path free of any per-step tracing work.
         self.span_hook = None
+        # Step listeners (add_step_listener): run on the stepping thread
+        # where a step's booking ends and once more where step() ends, if
+        # a callback has run since the last time (`_unhanded`). An owner
+        # whose callbacks only collect hands a step over there, once.
+        self._step_listeners: List = []
+        self._step_thread: Optional[int] = None  # ident, while in step()
+        self._unhanded = False
         self._running: Dict[int, _Seq] = {}  # slot -> seq
         self._free_slots = list(range(self.R - 1, -1, -1))
         self._lock = threading.Lock()
@@ -1005,6 +1012,23 @@ class InferenceEngine:
             self._cancelled.add(request_id)
         self._work.set()
 
+    def add_step_listener(self, fn) -> None:
+        """Register `fn()`, to run on the stepping thread after a step's
+        booking has made its last callback (`_book_step`, the prefill
+        rows of a mixed step included) and at the end of every `step()`
+        in which a callback ran outside a booking (a reject at admission,
+        a cancel notice, the split prefill's first tokens): no output
+        waits for a later step. Register before `start()`. A listener
+        that raises is logged and the loop lives; what it held is its
+        owner's to account for (docs/ENGINE_PIPELINE.md)."""
+        self._step_listeners.append(fn)
+
+    def step_open(self) -> bool:
+        """Whether the caller is the stepping thread inside a `step()`
+        whose end runs the listeners: what a callback that only collects
+        asks before it leaves the rest to its listener."""
+        return self._step_thread == threading.get_ident()
+
     def has_work(self) -> bool:
         return bool(
             self._waiting
@@ -1214,6 +1238,33 @@ class InferenceEngine:
         rows; ineligible admissions (media / SP) and every admission of
         an unfused iteration prefill through the split path inside
         _admit (docs/ENGINE_PIPELINE.md + docs/KERNELS.md)."""
+        self._step_thread = threading.get_ident()
+        try:
+            return self._step()
+        finally:
+            # A raise in mid-step hands over what was booked before it.
+            self._step_boundary()
+            self._step_thread = None
+
+    @thread_owned("engine")
+    def _step_boundary(self) -> None:
+        """Run the step listeners if any callback ran since they last
+        did: the end of a booking, and the end of step()."""
+        if not self._unhanded:
+            return
+        self._unhanded = False
+        for fn in self._step_listeners:
+            try:
+                fn()
+            except Exception:  # as a raising callback: the loop lives
+                logging.getLogger(__name__).exception(
+                    "engine step listener failed"
+                )
+
+    @thread_owned("engine")
+    def _step(self) -> int:
+        """step()'s body; step() marks the stepping thread around it and
+        runs the step listeners once more when it is done."""
         phase = self._phases.phase
         # up to the first phase below: the loop's `housekeeping`
         if not self._running and self._inflight is None:
@@ -2755,6 +2806,7 @@ class InferenceEngine:
             status=Status(code, msg),
             finished=True,
         )
+        self._unhanded = True
         try:
             req.callback(out)
         except Exception:
@@ -2768,6 +2820,7 @@ class InferenceEngine:
             cancelled=True,
             status=Status(StatusCode.CANCELLED, "cancelled"),
         )
+        self._unhanded = True
         try:
             req.callback(out)
         except Exception:
@@ -2982,7 +3035,7 @@ class InferenceEngine:
         spec = n_emit is not None
         toks, lps = tokens.tolist(), logprobs.tolist()
         produced = 0
-        worst_tbt = None
+        tbts: List[float] = []
         now = time.monotonic()
         for slot, (seq, gen) in flt.slots.items():
             if self._running.get(slot) is not seq or seq.admit_gen != gen:
@@ -3004,13 +3057,12 @@ class InferenceEngine:
                 n = 1
                 row = ((toks[slot], lps[slot]),)
             if n:
-                tbt_ms = (now - seq.last_token_time) * 1000
-                worst_tbt = max(worst_tbt or 0.0, tbt_ms)
-                self._m_tbt.observe(tbt_ms)
+                tbts.append((now - seq.last_token_time) * 1000)
                 seq.last_token_time = now
             produced += self._book_row(slot, seq, gen, newer, row, spec)
-        if worst_tbt is not None:
-            self._window_append(self._tbt_window, now, worst_tbt)
+        if tbts:
+            self._m_tbt.observe_many(tbts)
+            self._window_append(self._tbt_window, now, max(tbts))
         produced += self._drain_pf_rows(flt, tokens, logprobs)
         if self.span_hook is not None and produced:
             # One span per drained STEP BATCH (never per token): the
@@ -3020,6 +3072,8 @@ class InferenceEngine:
                 nactive=flt.nactive, produced=produced,
                 step_ms=round(step_ms, 3),
             )
+        # The step's last callback has run: its outputs leave together.
+        self._step_boundary()
         self._t_host_free = time.monotonic()
         return produced
 
@@ -3735,10 +3789,8 @@ class InferenceEngine:
         committed = seq.last_committed_block + 1
         if full <= committed:
             return
-        hashes = prefix_block_hashes(
-            seq.tokens[: full * self.block_size], self.block_size,
-            seed=self.block_mgr.seed,
-        )
+        # one block from its parent's hash, not the prefix over again
+        hashes = self._stream_prefix_hashes(seq, full)
         for i in range(committed, full):
             self.block_mgr.commit_block(seq.block_ids[i], hashes[i])
         seq.last_committed_block = full - 1
@@ -3781,6 +3833,7 @@ class InferenceEngine:
             finished=finished is not None,
         )
         keep_going = True
+        self._unhanded = True
         try:
             keep_going = seq.req.callback(out)
         except Exception:  # callback errors must not kill the engine loop
@@ -3821,6 +3874,7 @@ class InferenceEngine:
                 cancelled=True,
                 status=Status(StatusCode.CANCELLED, "cancelled"),
             )
+            self._unhanded = True
             try:
                 seq.req.callback(out)
             except Exception:

@@ -208,36 +208,74 @@ class IncrementalDetokenizer:
 
     Decoding each step's token ids independently corrupts characters whose
     bytes span token boundaries (routine for byte-level and BPE
-    byte-fallback vocabularies). This keeps the full id history, re-decodes,
-    and emits only the newly *stable* text — a trailing run of U+FFFD
-    replacement chars is held back until later tokens complete the
-    sequence (vLLM-style prefix-diff detokenization)."""
+    byte-fallback vocabularies). This emits only the newly *stable* text
+    of the sequence's whole decode — a trailing run of U+FFFD replacement
+    chars is held back until later tokens complete the sequence — and the
+    deltas are, one for one, those of re-decoding the whole id history on
+    every push. What a push decodes is a WINDOW of recent ids, so a token
+    costs the window and not the history (vLLM's prefix / read offsets).
+
+    The window is `ids[_prefix:]`, and `_read` splits it: the text of
+    `ids[:_read]` did not end in U+FFFD when `_read` was set (a CLEAN
+    cut: every tokenizer here ends in one lossy UTF-8 decode of
+    concatenated bytes, and a text that does not end in U+FFFD ends on
+    a whole character, so the ids after the cut decode as they do in the
+    whole). A cut is taken after every push that ends clean, and
+    `_prefix` follows to the cut before it: the window is then the last
+    clean chunk plus what has come since, a few ids whatever the history.
+    The chunk `ids[_prefix:_read]` is decoded WITH the new ids (`_base`
+    is where its text begins in the whole) because `decode` is not a pure
+    function of a suffix: SentencePiece drops the leading space of what
+    it is handed (the dummy prefix), and HF's clean-up looks at the
+    characters on both sides of a joint. What a window cannot see is what
+    lies before its first id, so it starts one clean chunk back: the
+    chunk's text takes the leading-space rule and stands on the far side
+    of every joint the new ids have. `_prefix` does not move onto a chunk
+    that decodes to nothing (ids a decode skips: specials, ids outside
+    the table), which would hand the rule to the first new id. A sequence
+    that never ends clean (a run of invalid bytes) keeps its window
+    growing until it does: the held-back run has to be seen whole."""
 
     def __init__(self, tokenizer: Tokenizer):
         self._tok = tokenizer
         self._ids: List[int] = []
-        self._emitted = 0
+        self._emitted = 0  # characters of the WHOLE text handed out
+        self._prefix = 0  # ids[_prefix:] is what a push decodes
+        self._read = 0  # a clean cut, _prefix <= _read <= len(_ids)
+        self._base = 0  # characters of the whole text before the window
 
     def push(self, ids: Sequence[int]) -> str:
         self._ids.extend(int(i) for i in ids)
-        text = self._tok.decode(self._ids)
-        stable_end = len(text)
-        while stable_end > self._emitted and text[stable_end - 1] == "�":
+        text, base = self._tok.decode(self._ids[self._prefix:]), self._base
+        end = base + len(text)
+        stable_end = end
+        while stable_end > self._emitted and text[stable_end - 1 - base] == "�":
             stable_end -= 1
-        delta = text[self._emitted:stable_end]
+        delta = text[max(self._emitted - base, 0):max(stable_end - base, 0)]
         self._emitted = stable_end
+        if stable_end == end and not text.endswith("�"):
+            # The whole text (`end` characters) ends clean: take the cut,
+            # and move the window's start to the cut before it when the
+            # chunk between the two decodes to text.
+            if self._read > self._prefix:
+                chunk = self._tok.decode(self._ids[self._read:])
+                if chunk:
+                    self._prefix, self._base = self._read, end - len(chunk)
+            self._read = len(self._ids)
         return delta
 
     def flush(self) -> str:
         """Emit whatever is still held back (end of stream)."""
-        text = self._tok.decode(self._ids)
-        delta = text[self._emitted:]
-        self._emitted = len(text)
+        text = self._tok.decode(self._ids[self._prefix:])
+        delta = text[max(self._emitted - self._base, 0):]
+        self._emitted = self._base + len(text)
         return delta
 
     # State carry-over across a PD handoff: the decode peer must continue
     # the prefill peer's byte/char position or the streamed text diverges
-    # from a colocated run.
+    # from a colocated run. The wire form is (ids, emitted); the offsets
+    # are derived: an imported history starts as one window (its first
+    # two clean pushes decode it whole) and the cuts follow as above.
     def export_state(self) -> "tuple[List[int], int]":
         return list(self._ids), self._emitted
 
