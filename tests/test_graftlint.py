@@ -538,7 +538,9 @@ class TestSpanStages:
          "sampling"),
         ('with _leaf("launch"):\n    pass\nwith _leaf("fetch"):\n    pass\n',
          "fetch"),
-    ], ids=["engine-phase", "executor-leaf"])
+        ('with region("ffn"):\n    pass\n@obs_spans.region("mlp")\ndef f():\n    pass\n',
+         "mlp"),
+    ], ids=["engine-phase", "executor-leaf", "device-region"])
     def test_off_vocabulary_phase_or_leaf_trips(self, src, bad):
         fs = run_one(self._pass(), src)
         assert len(fs) == 1 and fs[0].line in (2, 3)
@@ -550,6 +552,8 @@ class TestSpanStages:
             'moon._phase("waxing")\n'
             'with phase(name):\n    pass\n'
             'def phase(self, name):\n    pass\n'
+            'window_region("nowhere")\n'
+            'with region(MIXER_REGIONS[kind]):\n    pass\n'
         )
         assert run_one(self._pass(), src) == []
 

@@ -1,6 +1,6 @@
 """Span-stages pass: distributed-tracing vocabulary + plane coverage.
 
-Three layers, mirroring the fault-points registry idiom
+Four layers, mirroring the fault-points registry idiom
 (docs/OBSERVABILITY.md "Distributed tracing", "Engine step timeline"):
 
 * VOCABULARY — scan the package plus the bench entry points for every
@@ -17,6 +17,11 @@ Three layers, mirroring the fault-points registry idiom
   (`_leaf(...)`) in `obs.spans.EXECUTOR_LEAVES`: a phase outside the
   vocabulary has no `xllm_engine_loop_seconds_total` child, and the
   benchmark's readers key on the names.
+
+* DEVICE REGIONS — every literal handed to `region(...)` (a model's
+  `jax.named_scope`, docs/OBSERVABILITY.md "Device regions") must be in
+  `obs.spans.DEVICE_REGIONS`: `region()` refuses another name only when
+  the code is traced, and a branch no test traces would fail on the chip.
 
 * TRACE PLANES — a registry of RPC-client call sites (one row per
   cross-process plane: dispatch, PD handoff commit, KV stream OPEN,
@@ -48,6 +53,8 @@ EMIT_RE = re.compile(
 # `_leaf("launch")`.
 PHASE_RE = re.compile(r"(?<![A-Za-z0-9_])phase\(\s*[\"']([a-z_]+)[\"']")
 LEAF_RE = re.compile(r"(?<![A-Za-z0-9_])_leaf\(\s*[\"']([a-z_]+)[\"']")
+# A device region: `region("ffn")`, `@obs_spans.region("sample")`.
+REGION_RE = re.compile(r"(?<![A-Za-z0-9_])region\(\s*[\"']([a-z_]+)[\"']")
 
 # Contractual trace-context forwarding sites, one row per RPC plane:
 # (repo-relative file, verbatim needle, plane). The needle is the exact
@@ -87,6 +94,7 @@ class SpanStagesPass(LintPass):
         planes: Optional[Sequence[Tuple[str, str, str]]] = None,
         phases: Optional[Sequence[str]] = None,
         leaves: Optional[Sequence[str]] = None,
+        regions: Optional[Sequence[str]] = None,
     ):
         # Injectable for fixture tests; the repo run uses the canonical
         # vocabularies and the plane registry above.
@@ -94,6 +102,7 @@ class SpanStagesPass(LintPass):
         self.planes = TRACE_PLANES if planes is None else tuple(planes)
         self._phases = phases
         self._leaves = leaves
+        self._regions = regions
 
     @property
     def vocab(self) -> frozenset:
@@ -105,24 +114,30 @@ class SpanStagesPass(LintPass):
 
     @property
     def phase_vocabs(self) -> tuple:
-        """(pattern, names, what, vocabulary's name) per name family."""
+        """(pattern, names, what, vocabulary's name, what a name outside
+        it costs) per name family."""
         from xllm_service_tpu.obs import spans
 
-        phases, leaves = self._phases, self._leaves
+        unread = "it would have no counter child and no reader"
+        phases, leaves, regions = self._phases, self._leaves, self._regions
+        if regions is None:
+            regions = spans.DEVICE_REGIONS
         if phases is None:
             phases = spans.ENGINE_PHASES
         if leaves is None:
             leaves = spans.EXECUTOR_LEAVES
         return (
-            (PHASE_RE, frozenset(phases), "engine phase", "ENGINE_PHASES"),
-            (LEAF_RE, frozenset(leaves), "executor leaf", "EXECUTOR_LEAVES"),
+            (PHASE_RE, frozenset(phases), "engine phase", "ENGINE_PHASES", unread),
+            (LEAF_RE, frozenset(leaves), "executor leaf", "EXECUTOR_LEAVES", unread),
+            (REGION_RE, frozenset(regions), "device region", "DEVICE_REGIONS",
+             "region() refuses it when the code is traced"),
         )
 
     def run(self, project: Project) -> List[Finding]:
         findings: List[Finding] = []
         vocab = self.vocab
         for src in project.all_lintable():
-            for pattern, names, what, where in self.phase_vocabs:
+            for pattern, names, what, where, cost in self.phase_vocabs:
                 for m in pattern.finditer(src.text):
                     if m.group(1) in names:
                         continue
@@ -130,7 +145,7 @@ class SpanStagesPass(LintPass):
                     findings.append(Finding(
                         self.id, src.rel, line,
                         f"{what} {m.group(1)!r} is not in obs.spans.{where}"
-                        f" — it would have no counter child and no reader",
+                        f" — {cost}",
                     ))
             for m in EMIT_RE.finditer(src.text):
                 stage = m.group(1)

@@ -29,8 +29,9 @@ import jax.numpy as jnp
 
 from xllm_service_tpu.models import llama
 from xllm_service_tpu.models.configs import ModelConfig
+from xllm_service_tpu.obs.spans import region
 from xllm_service_tpu.ops import retention as retention_ops
-from xllm_service_tpu.ops.norms import rms_norm
+from xllm_service_tpu.ops.norms import block_norm, rms_norm
 from xllm_service_tpu.ops import rope as rope_ops
 from xllm_service_tpu.ops.quant import wdtype, wt
 
@@ -70,6 +71,7 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
     return params
 
 
+@region("state_mixer")
 def _gate(lp, h: jnp.ndarray) -> jnp.ndarray:
     """h [..., E] -> log decay [..., Hkv] float32, always <= 0."""
     logit = jnp.einsum(
@@ -78,6 +80,7 @@ def _gate(lp, h: jnp.ndarray) -> jnp.ndarray:
     return jax.nn.log_sigmoid(logit)
 
 
+@region("attn_proj")
 def _qkv(lp, cfg: ModelConfig, x: jnp.ndarray, positions: jnp.ndarray):
     """llama.py's `_qkv` for this family (no bias, QK-norm, RoPE) with
     float32 results: x [T, E] -> q [T, Hq, D], k, v [T, Hkv, D]. The
@@ -104,10 +107,12 @@ def _qkv(lp, cfg: ModelConfig, x: jnp.ndarray, positions: jnp.ndarray):
 
 def _out_mlp(lp, cfg: ModelConfig, x, y, rows_valid):
     """x + W_o y, then the MLP block; y [..., Hq, D] float32."""
-    flat = y.reshape(*y.shape[:-2], -1).astype(x.dtype)
-    x = x + jnp.einsum("...h,he->...e", flat, wt(lp["wo"]).reshape(-1, cfg.hidden_size))
-    h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-    return x + llama._mlp_block(lp, cfg, h, rows_valid=rows_valid)
+    with region("attn_proj"):
+        flat = y.reshape(*y.shape[:-2], -1).astype(x.dtype)
+        x = x + jnp.einsum("...h,he->...e", flat, wt(lp["wo"]).reshape(-1, cfg.hidden_size))
+    h = block_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
+    with region("ffn"):
+        return x + llama._mlp_block(lp, cfg, h, rows_valid=rows_valid)
 
 
 def _slots(block_tables: jnp.ndarray) -> jnp.ndarray:
@@ -115,7 +120,7 @@ def _slots(block_tables: jnp.ndarray) -> jnp.ndarray:
 
 
 def _dec_layer(cfg, lp, layer, S, z, x, positions, slots, active, use_kernel):
-    h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+    h = block_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
     q, k, v = _qkv(lp, cfg, h, positions)
     y, S, z = retention_ops.decode_update(
         S, z, layer, slots, active, q, k, v, _gate(lp, h), use_kernel=use_kernel,
@@ -125,7 +130,7 @@ def _dec_layer(cfg, lp, layer, S, z, x, positions, slots, active, use_kernel):
 
 def _pf_layer(cfg, lp, layer, S, z, x, positions, slots, start, length, valid,
               use_kernel):
-    h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+    h = block_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
     q, k, v = jax.vmap(lambda hx, pos: _qkv(lp, cfg, hx, pos))(h, positions)
     y, S, z = retention_ops.chunk_update(
         S, z, layer, slots, start, length, q, k, v, _gate(lp, h),
@@ -181,10 +186,7 @@ def prefill_batch_step(
                          true_len, valid, use_kernel)
 
     x, S, z = llama._scan_layers(layer_fn, x, params, S, z)
-    last = jnp.take_along_axis(
-        x, jnp.maximum(true_len - 1, 0)[:, None, None], axis=1
-    )[:, 0]
-    return llama._unembed(params, cfg, last), S, z
+    return llama._unembed(params, cfg, llama._last_rows(x, true_len)), S, z
 
 
 def mixed_step(
@@ -218,9 +220,7 @@ def mixed_step(
         return (x_dec, x_pf), S, z
 
     (x_dec, x_pf), S, z = llama._scan_layers(layer_fn, (x_dec, x_pf), params, S, z)
-    last = jnp.take_along_axis(
-        x_pf, jnp.maximum(pf_len - 1, 0)[:, None, None], axis=1
-    )[:, 0]
+    last = llama._last_rows(x_pf, pf_len)
     return llama._unembed(params, cfg, x_dec), llama._unembed(params, cfg, last), S, z
 
 
@@ -232,7 +232,7 @@ def hidden_dense(params: Params, cfg: ModelConfig, token_ids, rows_valid=None):
     positions = jnp.arange(L, dtype=jnp.int32)
 
     def layer_fn(x, lp):
-        h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+        h = block_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
 
         def one_seq(hx):
             q, k, v = _qkv(lp, cfg, hx, positions)

@@ -38,18 +38,20 @@ import jax.numpy as jnp
 
 from xllm_service_tpu.models.configs import ModelConfig
 from xllm_service_tpu.models.llama import (
+    _last_rows,
     _mlp,
     _mlp_block,
     _scan_layers,
     _unembed,
 )
+from xllm_service_tpu.obs.spans import region
 from xllm_service_tpu.ops import kv_write as kv_write_ops
 from xllm_service_tpu.ops.attention import (
     mla_paged_attention,
     mla_prefill_attention,
 )
 from xllm_service_tpu.ops import rope as rope_ops
-from xllm_service_tpu.ops.norms import rms_norm
+from xllm_service_tpu.ops.norms import block_norm, rms_norm
 from xllm_service_tpu.ops.quant import wdtype, wt
 
 Params = Dict[str, Any]
@@ -197,6 +199,7 @@ def _dense_cfg(cfg: ModelConfig) -> ModelConfig:
     return dataclasses.replace(cfg, num_experts=0)
 
 
+@region("attn_proj")
 def _q_heads(lp, cfg: ModelConfig, h: jnp.ndarray, positions: jnp.ndarray):
     """h [T, E] -> (q_nope [T, Hq, dn], q_pe [T, Hq, dr] roped)."""
     T = h.shape[0]
@@ -223,6 +226,7 @@ def _pad_lanes(x: jnp.ndarray, width: int) -> jnp.ndarray:
     return jnp.pad(x, pad)
 
 
+@region("attn_proj")
 def _latent_rows(lp, cfg: ModelConfig, h: jnp.ndarray, positions: jnp.ndarray):
     """h [T, E] -> cache rows [T, C]: concat(normed c_kv, roped k_pe),
     lane-padded to cfg.mla_cache_dim."""
@@ -237,6 +241,7 @@ def _latent_rows(lp, cfg: ModelConfig, h: jnp.ndarray, positions: jnp.ndarray):
     )
 
 
+@region("attn_proj")
 def _absorb_q(lp, cfg: ModelConfig, q_nope, q_pe) -> jnp.ndarray:
     """Project q_nope into the latent space and append q_pe: [.., Hq, C]
     (lane-padded to match the cache rows)."""
@@ -246,6 +251,7 @@ def _absorb_q(lp, cfg: ModelConfig, q_nope, q_pe) -> jnp.ndarray:
     )
 
 
+@region("attn_proj")
 def _attn_out(lp, cfg: ModelConfig, ctx_lat: jnp.ndarray) -> jnp.ndarray:
     """ctx_lat [..., Hq, kvr] -> hidden [..., E] via W_UV then W_O."""
     o = jnp.einsum("...hk,hkv->...hv", ctx_lat, wt(lp["w_uv"]))
@@ -253,6 +259,7 @@ def _attn_out(lp, cfg: ModelConfig, ctx_lat: jnp.ndarray) -> jnp.ndarray:
     return jnp.einsum("...h,he->...e", flat, wt(lp["wo"]))
 
 
+@region("embed")
 def _embed_rows(params: Params, token_ids) -> jnp.ndarray:
     return params["embed"][token_ids].astype(wdtype(params["layers"]["w_dkv"]))
 
@@ -263,13 +270,15 @@ def _layer(lp, cfg, mcfg, x, positions, valid, attend, c):
     (ctx [T, Hq, kvr], c)` (which lands the rows in the carried stack and
     attends over it), the output projection and the MLP over the rows
     that are `valid` [T]."""
-    h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+    h = block_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
     q_nope, q_pe = _q_heads(lp, cfg, h, positions)
     rows = _latent_rows(lp, cfg, h, positions)
     ctx, c = attend(_absorb_q(lp, cfg, q_nope, q_pe), rows, c)
-    x = x + _attn_out(lp, cfg, ctx)
-    h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-    return x + _mlp_block(lp, mcfg, h, rows_valid=valid), c
+    with region("attn_proj"):
+        x = x + _attn_out(lp, cfg, ctx)
+    h = block_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
+    with region("ffn"):
+        return x + _mlp_block(lp, mcfg, h, rows_valid=valid), c
 
 
 def _write(plan, layer):
@@ -332,13 +341,6 @@ def _chunk_coords(start_pos, true_len, Lpad: int):
         (start_pos[:, None] + offsets).reshape(-1),
         (offsets < true_len[:, None]).reshape(-1),
     )
-
-
-def _last_rows(x: jnp.ndarray, true_len: jnp.ndarray) -> jnp.ndarray:
-    """x [P, Lpad, E] -> each chunk's last valid row [P, E]."""
-    return jnp.take_along_axis(
-        x, jnp.maximum(true_len - 1, 0)[:, None, None], axis=1
-    )[:, 0]
 
 
 def _run_layers(params, cfg, x, k_caches, v_caches, positions, valid,
@@ -455,8 +457,9 @@ def mixed_step(
             # llama.mixed_step orders them): a read between the writes
             # would make the compiler keep a copy of the stack.
             c = pf_write(rows[R:], dec_write(rows[:R], c))
-            ctx = [dec_read(q_lat[:R], c), pf_read(q_lat[R:], c)]
-            return jnp.concatenate(ctx, axis=0), c
+            with region("attn"):  # the halves cut apart and joined again
+                ctx = [dec_read(q_lat[:R], c), pf_read(q_lat[R:], c)]
+                return jnp.concatenate(ctx, axis=0), c
 
         return attend
 

@@ -57,6 +57,7 @@ import jax.numpy as jnp
 
 from xllm_service_tpu.models import llama
 from xllm_service_tpu.models.configs import ModelConfig
+from xllm_service_tpu.obs.spans import region
 from xllm_service_tpu.ops import kv_write as kv_write_ops
 from xllm_service_tpu.ops import mamba as mamba_ops
 from xllm_service_tpu.ops import moe as moe_ops
@@ -65,7 +66,7 @@ from xllm_service_tpu.ops.attention import (
     paged_attention,
     prefill_attention,
 )
-from xllm_service_tpu.ops.norms import rms_norm
+from xllm_service_tpu.ops.norms import block_norm, rms_norm
 from xllm_service_tpu.ops.quant import wdtype, wt
 
 Params = Dict
@@ -73,6 +74,8 @@ Params = Dict
 NUM_CACHES = 2  # K and V (each paired with a state pool on the carry)
 QUANTIZABLE_WEIGHT_LEAVES = llama.QUANTIZABLE_WEIGHT_LEAVES + ("w_in", "w_out")
 MIXER_STACKS = {"mamba": "mamba", "attention": "attn"}
+# the device region of a mixer's residual add (obs.spans.DEVICE_REGIONS)
+MIXER_REGIONS = {"mamba": "state_mixer", "attention": "attn_proj"}
 
 
 def cache_row_dims(cfg: ModelConfig) -> Tuple[int, int]:
@@ -163,11 +166,13 @@ def _wd(params: Params):
     return wdtype(params["layers"]["w_sh_gate"])
 
 
+@region("embed")
 def _embed(params: Params, cfg: ModelConfig, token_ids) -> jnp.ndarray:
     x = params["embed"][token_ids].astype(jnp.float32) * cfg.embedding_multiplier
     return x.astype(_wd(params))
 
 
+@region("head")
 def _unembed(params: Params, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
     return llama._unembed(params, cfg, x) / cfg.logits_scaling
 
@@ -212,6 +217,7 @@ def _split_tables(block_tables):
     return block_tables[:, :-1], block_tables[:, -1].astype(jnp.int32) - 1
 
 
+@region("state_mixer")
 def _mamba_mixer(lp, cfg: ModelConfig, h, m, S, conv, dec: Optional[_Dec],
                  pf: Optional[_Pf]):
     """The Mamba-2 mixer over flat rows h [T, E] (decode rows first, then
@@ -254,6 +260,7 @@ def _mamba_mixer(lp, cfg: ModelConfig, h, m, S, conv, dec: Optional[_Dec],
     return _gated_out(lp, cfg, y, z), S, conv
 
 
+@region("state_mixer")
 def _gated_out(lp, cfg: ModelConfig, y, z):
     """rms_g(y * silu(z)) over each group's lanes, then W_out."""
     G = cfg.mamba_n_groups
@@ -264,6 +271,7 @@ def _gated_out(lp, cfg: ModelConfig, y, z):
     return jnp.einsum("tf,fe->te", g.astype(w_out.dtype), w_out)
 
 
+@region("attn_proj")
 def _qkv(lp, cfg: ModelConfig, h):
     """h [T, E] -> q [T, Hq, D], k, v [T, Hkv, D]: no bias, no rotary."""
     T = h.shape[0]
@@ -301,17 +309,21 @@ def _attn_mixer(lp, cfg: ModelConfig, h, a, K, V, dec: Optional[_Dec],
             q_pf, K, V, pf.tables, pf.start, pf.length, scale, layer=a,
         )
         o = o.reshape(-1, *o.shape[2:])
-    flat = o.reshape(o.shape[0], -1).astype(h.dtype)
-    return jnp.einsum("th,he->te", flat, wt(lp["wo"])), K, V
+    with region("attn_proj"):
+        flat = o.reshape(o.shape[0], -1).astype(h.dtype)
+        return jnp.einsum("th,he->te", flat, wt(lp["wo"])), K, V
 
 
-def _layer(lp, cfg: ModelConfig, x, valid, mix, caches):
+def _layer(lp, cfg: ModelConfig, x, valid, kind, mix, caches):
     """ONE layer body for both kinds: `mix(normed rows, caches) ->
-    (mixer output, caches)` is the layer's mixer over the carried pools."""
-    y, caches = mix(rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps), caches)
-    x = _add(cfg, x, y)
-    u = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-    return _add(cfg, x, llama._mlp_block(lp, cfg, u, rows_valid=valid)), caches
+    (mixer output, caches)` is the layer's mixer, of `kind`, over the
+    carried pools."""
+    y, caches = mix(block_norm(x, lp["attn_norm"], cfg.rms_norm_eps), caches)
+    with region(MIXER_REGIONS[kind]):
+        x = _add(cfg, x, y)
+    u = block_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
+    with region("ffn"):
+        return _add(cfg, x, llama._mlp_block(lp, cfg, u, rows_valid=valid)), caches
 
 
 def _run_layers(params, cfg: ModelConfig, x, k_caches, v_caches, valid,
@@ -357,11 +369,13 @@ def _run_layers(params, cfg: ModelConfig, x, k_caches, v_caches, valid,
                 lp["experts"] = (experts, seg.first + i)
             with moe_ops.layer_stats() as stats:
                 x, (kc, vc) = _layer(
-                    lp, cfg, x, valid, mixer(seg.kind, lp, seg.kind_first + i), (kc, vc)
+                    lp, cfg, x, valid, seg.kind,
+                    mixer(seg.kind, lp, seg.kind_first + i), (kc, vc),
                 )
             return (x, kc, vc), stats.total()
 
-        carry, counts = jax.lax.scan(body, carry, jnp.arange(seg.n, dtype=jnp.int32))
+        with region("stack_slice"):  # as llama._scan_layers
+            carry, counts = jax.lax.scan(body, carry, jnp.arange(seg.n, dtype=jnp.int32))
         moe_ops.add_step(counts)
     return carry
 
@@ -384,12 +398,6 @@ def _pf_half(k_caches, block_tables, start, length, P, Lpad) -> Tuple[_Pf, jnp.n
     )
     valid = jnp.arange(Lpad, dtype=jnp.int32)[None, :] < length[:, None]
     return pf, valid.reshape(-1)
-
-
-def _last_rows(x, true_len):
-    return jnp.take_along_axis(
-        x, jnp.maximum(true_len - 1, 0)[:, None, None], axis=1
-    )[:, 0]
 
 
 def decode_step(
@@ -430,7 +438,7 @@ def prefill_batch_step(
         params, cfg, _embed(params, cfg, token_ids.reshape(-1)), k_caches,
         v_caches, valid, None, pf,
     )
-    last = _last_rows(x.reshape(P, Lpad, -1), true_len)
+    last = llama._last_rows(x.reshape(P, Lpad, -1), true_len)
     return _unembed(params, cfg, last), k_caches, v_caches
 
 
@@ -459,7 +467,7 @@ def mixed_step(
         params, cfg, x, k_caches, v_caches,
         jnp.concatenate([dec_active, pf_valid]), dec, pf, use_ragged, interpret,
     )
-    last = _last_rows(x[R:].reshape(P, Lpad, -1), pf_len)
+    last = llama._last_rows(x[R:].reshape(P, Lpad, -1), pf_len)
     return (
         _unembed(params, cfg, x[:R]), _unembed(params, cfg, last),
         k_caches, v_caches,

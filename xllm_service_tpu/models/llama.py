@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from xllm_service_tpu.models.configs import ModelConfig
+from xllm_service_tpu.obs.spans import region
 from xllm_service_tpu.ops import kv_cache as kv_cache_ops
 from xllm_service_tpu.ops import kv_write as kv_write_ops
 from xllm_service_tpu.ops.attention import (
@@ -35,7 +36,7 @@ from xllm_service_tpu.ops.attention import (
     prefill_attention,
 )
 from xllm_service_tpu.ops import collective_matmul as cm_ops
-from xllm_service_tpu.ops.norms import rms_norm
+from xllm_service_tpu.ops.norms import block_norm, rms_norm
 from xllm_service_tpu.ops import lora as lora_ops
 from xllm_service_tpu.ops import moe as moe_ops
 from xllm_service_tpu.ops.quant import wdtype, wt
@@ -141,12 +142,22 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
     return params
 
 
+@region("head")
+def _last_rows(x: jnp.ndarray, true_len: jnp.ndarray) -> jnp.ndarray:
+    """x [P, Lpad, E] -> each chunk's last valid row [P, E]."""
+    return jnp.take_along_axis(
+        x, jnp.maximum(true_len - 1, 0)[:, None, None], axis=1
+    )[:, 0]
+
+
+@region("head")
 def _unembed(params: Params, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
     return _project(
         params, cfg, rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     )
 
 
+@region("head")
 def _project(params: Params, cfg: ModelConfig, h: jnp.ndarray) -> jnp.ndarray:
     """Vocab projection of ALREADY-final-normed hidden states."""
     if cfg.tie_word_embeddings:
@@ -174,6 +185,7 @@ def _act(cfg: ModelConfig):
     return moe_ops._act_fn(cfg.mlp_act)
 
 
+@region("embed")
 def _embed(params: Params, cfg: ModelConfig, token_ids, wd) -> jnp.ndarray:
     """Token embeddings in weight dtype; Gemma scales by sqrt(E) (HF
     computes the normalizer in model dtype)."""
@@ -183,6 +195,7 @@ def _embed(params: Params, cfg: ModelConfig, token_ids, wd) -> jnp.ndarray:
     return x
 
 
+@region("ffn")
 def _mlp(
     lp: Dict[str, jnp.ndarray], cfg: ModelConfig, x: jnp.ndarray,
     lora_idx=None,
@@ -223,6 +236,7 @@ def _mlp(
     return out
 
 
+@region("moe_route")
 def moe_route(lp, cfg: ModelConfig, x: jnp.ndarray):
     """Router top-k selection + combine weights, x [T, E] ->
     (topi [T, k] int32, weights [T, k] f32). THE routing semantics —
@@ -313,6 +327,7 @@ def _moe_grouped(
     return out
 
 
+@region("ffn")
 def _mlp_block(
     lp, cfg: ModelConfig, h: jnp.ndarray, lora_idx=None, rows_valid=None
 ) -> jnp.ndarray:
@@ -347,6 +362,7 @@ def _mlp_block(
     )(h, li)
 
 
+@region("attn_proj")
 def _qkv(lp, cfg: ModelConfig, x: jnp.ndarray, positions: jnp.ndarray,
          lora_idx=None):
     """x: [T, E] -> q [T, Hq, D], k/v [T, Hkv, D] with RoPE applied."""
@@ -386,6 +402,26 @@ def _qkv(lp, cfg: ModelConfig, x: jnp.ndarray, positions: jnp.ndarray,
     return q, k, v
 
 
+def _out_mlp_rows(lp, cfg: ModelConfig, x, attn, lora, li, valid):
+    """The tail of a layer over batched rows x [P, L, E] (a prefill or
+    verify half): x + W_o attn, then the MLP block. attn [P, L, Hq, D];
+    `lora` the rows' adapters or None (`li` its stand-in for the vmap)."""
+    with region("attn_proj"):
+        attn_flat = attn.reshape(*x.shape[:2], -1)
+        o = _row_parallel("plh,he->ple", attn_flat,
+                          wt(lp["wo"]).reshape(-1, cfg.hidden_size))
+        if lora is not None and lp.get("lora_wo_a") is not None:
+            o = o + jax.vmap(
+                lambda af, ai: lora_ops.apply(
+                    af, lp["lora_wo_a"], lp["lora_wo_b"], ai
+                )
+            )(attn_flat, li)
+        x = x + o
+    h = block_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
+    with region("ffn"):
+        return x + _mlp_block(lp, cfg, h, lora, rows_valid=valid)
+
+
 def _scan_layers(layer_fn, x, params, k_caches, v_caches,
                  stack: str = "layers", first_layer: int = 0):
     """The cache-threading layer scan: the stacked caches ride the CARRY
@@ -423,11 +459,14 @@ def _scan_layers(layer_fn, x, params, k_caches, v_caches,
         return out, stats.total()
 
     n = jax.tree_util.tree_leaves(leaves)[0].shape[0]
-    (x, k_caches, v_caches), counts = jax.lax.scan(
-        body,
-        (x, k_caches, v_caches),
-        (leaves, jnp.arange(n, dtype=jnp.int32)),
-    )
+    # what the scan does outside an inner region: the layer's leaves
+    # sliced out of the stacks, the carry handed on
+    with region("stack_slice"):
+        (x, k_caches, v_caches), counts = jax.lax.scan(
+            body,
+            (x, k_caches, v_caches),
+            (leaves, jnp.arange(n, dtype=jnp.int32)),
+        )
     moe_ops.add_step(counts)
     return x, k_caches, v_caches
 
@@ -462,7 +501,7 @@ def decode_step(
     seq_lens = jnp.where(active, positions + 1, 0)
 
     def layer_fn(x, lp, layer, k_caches, v_caches):
-        h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+        h = block_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
         q, k, v = _qkv(lp, cfg, h, rope_pos, lora_idx)
         k_caches, v_caches = kv_write_ops.write_kv(
             k_caches, v_caches, plan, k, v, layer
@@ -471,13 +510,15 @@ def decode_step(
             q, k_caches, v_caches, block_tables, seq_lens, scale,
             use_kernel=use_kernel, window=cfg.sliding_window, layer=layer,
         )
-        attn_flat = attn.reshape(attn.shape[0], -1)
-        o = _row_parallel("rh,he->re", attn_flat,
-                          wt(lp["wo"]).reshape(-1, cfg.hidden_size))
-        d = lora_ops.maybe_apply(lp, "wo", attn_flat, lora_idx, 1.0)
-        x = x + (o + d if d is not None else o)
-        h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-        x = x + _mlp_block(lp, cfg, h, lora_idx, rows_valid=active)
+        with region("attn_proj"):
+            attn_flat = attn.reshape(attn.shape[0], -1)
+            o = _row_parallel("rh,he->re", attn_flat,
+                              wt(lp["wo"]).reshape(-1, cfg.hidden_size))
+            d = lora_ops.maybe_apply(lp, "wo", attn_flat, lora_idx, 1.0)
+            x = x + (o + d if d is not None else o)
+        h = block_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
+        with region("ffn"):
+            x = x + _mlp_block(lp, cfg, h, lora_idx, rows_valid=active)
         return x, k_caches, v_caches
 
     x, k_caches, v_caches = _scan_layers(
@@ -553,10 +594,10 @@ def mixed_step(
     def layer_fn(x, lp, layer, k_caches, v_caches):
         x_dec, x_pf = x
         # Decode half QKV: decode_step's [R, E] shapes.
-        h_dec = rms_norm(x_dec, lp["attn_norm"], cfg.rms_norm_eps)
+        h_dec = block_norm(x_dec, lp["attn_norm"], cfg.rms_norm_eps)
         q_dec, k_dec, v_dec = _qkv(lp, cfg, h_dec, dec_rope, lora_dec)
         # Prefill half QKV: prefill_batch_step's vmapped [Lpad, E] rows.
-        h_pf = rms_norm(x_pf, lp["attn_norm"], cfg.rms_norm_eps)
+        h_pf = block_norm(x_pf, lp["attn_norm"], cfg.rms_norm_eps)
         q_pf, k_pf, v_pf = jax.vmap(
             lambda hx, pos, ai: _qkv(
                 lp, cfg, hx, pos, ai if lora_pf is not None else None
@@ -579,40 +620,25 @@ def mixed_step(
             window=cfg.sliding_window, layer=layer,
         )
         # Output projection + MLP, per half, split-step shapes.
-        attn_dec_flat = attn_dec.reshape(attn_dec.shape[0], -1)
-        o = _row_parallel("rh,he->re", attn_dec_flat,
-                          wt(lp["wo"]).reshape(-1, cfg.hidden_size))
-        d = lora_ops.maybe_apply(lp, "wo", attn_dec_flat, lora_dec, 1.0)
-        x_dec = x_dec + (o + d if d is not None else o)
-        h_dec = rms_norm(x_dec, lp["mlp_norm"], cfg.rms_norm_eps)
-        x_dec = x_dec + _mlp_block(
-            lp, cfg, h_dec, lora_dec, rows_valid=dec_active
-        )
-
-        attn_pf_flat = attn_pf.reshape(P, Lpad, -1)
-        o = _row_parallel("plh,he->ple", attn_pf_flat,
-                          wt(lp["wo"]).reshape(-1, cfg.hidden_size))
-        if lora_pf is not None and lp.get("lora_wo_a") is not None:
-            o = o + jax.vmap(
-                lambda af, ai: lora_ops.apply(
-                    af, lp["lora_wo_a"], lp["lora_wo_b"], ai
-                )
-            )(attn_pf_flat, li)
-        x_pf = x_pf + o
-        h_pf = rms_norm(x_pf, lp["mlp_norm"], cfg.rms_norm_eps)
-        x_pf = x_pf + _mlp_block(
-            lp, cfg, h_pf, lora_pf, rows_valid=pf_valid
-        )
+        with region("attn_proj"):
+            attn_dec_flat = attn_dec.reshape(attn_dec.shape[0], -1)
+            o = _row_parallel("rh,he->re", attn_dec_flat,
+                              wt(lp["wo"]).reshape(-1, cfg.hidden_size))
+            d = lora_ops.maybe_apply(lp, "wo", attn_dec_flat, lora_dec, 1.0)
+            x_dec = x_dec + (o + d if d is not None else o)
+        h_dec = block_norm(x_dec, lp["mlp_norm"], cfg.rms_norm_eps)
+        with region("ffn"):
+            x_dec = x_dec + _mlp_block(
+                lp, cfg, h_dec, lora_dec, rows_valid=dec_active
+            )
+        x_pf = _out_mlp_rows(lp, cfg, x_pf, attn_pf, lora_pf, li, pf_valid)
         return (x_dec, x_pf), k_caches, v_caches
 
     (x_dec, x_pf), k_caches, v_caches = _scan_layers(
         layer_fn, (x_dec, x_pf), params, k_caches, v_caches
     )
     dec_logits = _unembed(params, cfg, x_dec)  # [R, V]
-    last = jnp.take_along_axis(
-        x_pf, jnp.maximum(pf_len - 1, 0)[:, None, None], axis=1
-    )[:, 0]  # [P, E]
-    pf_logits = _unembed(params, cfg, last)  # [P, V]
+    pf_logits = _unembed(params, cfg, _last_rows(x_pf, pf_len))  # [P, V]
     return dec_logits, pf_logits, k_caches, v_caches
 
 
@@ -689,13 +715,13 @@ def mixed_verify_step(
 
     def layer_fn(x, lp, layer, k_caches, v_caches):
         x_ver, x_pf = x
-        h_ver = rms_norm(x_ver, lp["attn_norm"], cfg.rms_norm_eps)
+        h_ver = block_norm(x_ver, lp["attn_norm"], cfg.rms_norm_eps)
         q_ver, k_v, v_v = jax.vmap(
             lambda hx, pos, ai: _qkv(
                 lp, cfg, hx, pos, ai if lora_ver is not None else None
             )
         )(h_ver, ver_rp, li_ver)  # q_ver [R, S, Hq, D]
-        h_pf = rms_norm(x_pf, lp["attn_norm"], cfg.rms_norm_eps)
+        h_pf = block_norm(x_pf, lp["attn_norm"], cfg.rms_norm_eps)
         q_pf, k_p, v_p = jax.vmap(
             lambda hx, pos, ai: _qkv(
                 lp, cfg, hx, pos, ai if lora_pf is not None else None
@@ -721,34 +747,17 @@ def mixed_verify_step(
             window=cfg.sliding_window, layer=layer,
         )
 
-        def half_tail(x, attn, L_, n_rows, lora, li, valid):
-            attn_flat = attn.reshape(n_rows, L_, -1)
-            o = _row_parallel("plh,he->ple", attn_flat,
-                              wt(lp["wo"]).reshape(-1, cfg.hidden_size))
-            if lora is not None and lp.get("lora_wo_a") is not None:
-                o = o + jax.vmap(
-                    lambda af, ai: lora_ops.apply(
-                        af, lp["lora_wo_a"], lp["lora_wo_b"], ai
-                    )
-                )(attn_flat, li)
-            x = x + o
-            h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-            return x + _mlp_block(lp, cfg, h, lora, rows_valid=valid)
-
-        x_ver = half_tail(x_ver, attn_ver, S, R, lora_ver, li_ver,
-                          ver_valid)
-        x_pf = half_tail(x_pf, attn_pf, Lpad, P, lora_pf, li_pf,
-                         pf_valid)
+        x_ver = _out_mlp_rows(
+            lp, cfg, x_ver, attn_ver, lora_ver, li_ver, ver_valid
+        )
+        x_pf = _out_mlp_rows(lp, cfg, x_pf, attn_pf, lora_pf, li_pf, pf_valid)
         return (x_ver, x_pf), k_caches, v_caches
 
     (x_ver, x_pf), k_caches, v_caches = _scan_layers(
         layer_fn, (x_ver, x_pf), params, k_caches, v_caches
     )
     ver_logits = _unembed(params, cfg, x_ver)  # [R, S, V]
-    last = jnp.take_along_axis(
-        x_pf, jnp.maximum(pf_len - 1, 0)[:, None, None], axis=1
-    )[:, 0]
-    pf_logits = _unembed(params, cfg, last)  # [P, V]
+    pf_logits = _unembed(params, cfg, _last_rows(x_pf, pf_len))  # [P, V]
     return ver_logits, pf_logits, k_caches, v_caches
 
 
@@ -805,7 +814,7 @@ def prefill_batch_step(
     rp = rope_positions if rope_positions is not None else positions
 
     def layer_fn(x, lp, layer, k_caches, v_caches):
-        h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+        h = block_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
         q, k, v = jax.vmap(
             lambda hx, pos, ai: _qkv(
                 lp, cfg, hx, pos, ai if lora_idx is not None else None
@@ -821,18 +830,7 @@ def prefill_batch_step(
             q, k_caches, v_caches, block_tables, start_pos, true_len,
             scale, window=cfg.sliding_window, layer=layer,
         )  # [P, Lpad, Hq, D] — flash kernel on TPU, blockwise elsewhere
-        attn_flat = attn.reshape(P, Lpad, -1)
-        o = _row_parallel("plh,he->ple", attn_flat,
-                          wt(lp["wo"]).reshape(-1, cfg.hidden_size))
-        if lora_idx is not None and lp.get("lora_wo_a") is not None:
-            o = o + jax.vmap(
-                lambda af, ai: lora_ops.apply(
-                    af, lp["lora_wo_a"], lp["lora_wo_b"], ai
-                )
-            )(attn_flat, li)
-        x = x + o
-        h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-        x = x + _mlp_block(lp, cfg, h, lora_idx, rows_valid=valid)
+        x = _out_mlp_rows(lp, cfg, x, attn, lora_idx, li, valid)
         return x, k_caches, v_caches
 
     x, k_caches, v_caches = _scan_layers(
@@ -840,10 +838,7 @@ def prefill_batch_step(
     )
     if all_logits:
         return _unembed(params, cfg, x), k_caches, v_caches  # [P, Lpad, V]
-    last = jnp.take_along_axis(
-        x, jnp.maximum(true_len - 1, 0)[:, None, None], axis=1
-    )[:, 0]  # [P, E]
-    logits = _unembed(params, cfg, last)  # [P, V]
+    logits = _unembed(params, cfg, _last_rows(x, true_len))  # [P, V]
     return logits, k_caches, v_caches
 
 
@@ -898,7 +893,7 @@ def prefill_sp_step(
     x = x[None]  # [1, Lsp, E] — ring_attention is batched
 
     def layer_fn(x, lp):
-        h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+        h = block_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
         q, k, v = _qkv(lp, cfg, h[0], positions)
         attn = ring_attention(
             q[None], k[None], v[None], mesh, sp_axis=sp_axis,
@@ -909,7 +904,7 @@ def prefill_sp_step(
             attn.reshape(1, Lsp, -1),
             wt(lp["wo"]).reshape(-1, cfg.hidden_size),
         )
-        h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
+        h = block_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
         x = x + _mlp_block(
             lp, cfg, h[0],
             rows_valid=jnp.arange(Lsp, dtype=jnp.int32) < true_len,
@@ -956,7 +951,7 @@ def hidden_dense(
         )
 
     def layer_fn(x, lp):
-        h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+        h = block_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
 
         def one_seq(hx):
             q, k, v = _qkv(lp, cfg, hx, positions)
@@ -971,7 +966,7 @@ def hidden_dense(
 
         attn = jax.vmap(one_seq)(h)  # [B, L, Hq*D]
         x = x + jnp.einsum("blh,he->ble", attn, wt(lp["wo"]).reshape(-1, cfg.hidden_size))
-        h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
+        h = block_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
         x = x + _mlp_block(lp, cfg, h, rows_valid=rows_valid)
         return x, None
 

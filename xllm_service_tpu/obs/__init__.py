@@ -24,6 +24,7 @@ from xllm_service_tpu.obs.metrics import (
 from xllm_service_tpu.obs.flight import FlightRecorder, SpanRing
 from xllm_service_tpu.obs.spans import (
     ALL_SPAN_STAGES,
+    DEVICE_REGIONS,
     ENGINE_PHASES,
     EXECUTOR_LEAVES,
     INSTANCE_SPAN_STAGES,
@@ -35,6 +36,7 @@ from xllm_service_tpu.obs.spans import (
     blame_stages,
     build_timeline,
     load_spans,
+    region,
     to_chrome_trace,
     trace_to_chrome,
 )
@@ -50,6 +52,7 @@ __all__ = [
     "parse_exposition",
     "render_families",
     "ALL_SPAN_STAGES",
+    "DEVICE_REGIONS",
     "ENGINE_PHASES",
     "EXECUTOR_LEAVES",
     "INSTANCE_SPAN_STAGES",
@@ -57,6 +60,7 @@ __all__ = [
     "ClockSync",
     "EnginePhases",
     "annotation",
+    "region",
     "FlightRecorder",
     "SpanRing",
     "assemble_trace",

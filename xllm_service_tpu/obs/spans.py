@@ -90,7 +90,32 @@ ENGINE_PHASES = (
 # ("xllm.executor.<leaf>"): what the host does before a step launches.
 EXECUTOR_LEAVES = ("host_inputs", "launch")
 
+# Device-region vocabulary (docs/OBSERVABILITY.md "Device regions"): which
+# part of a step program a compiled op belongs to. A model wraps its code
+# in `region(name)`, a `jax.named_scope("xllm.<region>")` that the
+# optimized HLO keeps in every op's `op_name`; obs/regions.py reads it back
+# out of the compiled text and joins a profile's device ops against it.
+# An op's region is the INNERMOST one of its `op_name`. One vocabulary for
+# every family: the span-stages lint pass rejects a literal outside it.
+DEVICE_REGIONS = (
+    "step_io",      # a step program's packed inputs unpacked, its outputs packed
+    "embed",        # the token rows out of the embedding table
+    "norm",         # a block's pre-mixer and pre-MLP RMSNorm
+    "attn_proj",    # q/k/v/o, biases, RoPE, QK-norm; MLA's down/up-projections, the absorb
+    "attn",         # the paged, flash and MLA attention kernels and their plain twins
+    "cache_write",  # the write plan, kv_write_kernel, latent rows
+    "state_mixer",  # power retention; Mamba-2's convolution, scan, update, gated output
+    "ffn",          # dense gate/up/down, the shared experts
+    "moe_route",    # router, top-k, grouping, the counts output
+    "moe_experts",  # the grouped expert kernels, their twins, the combine
+    "head",         # final norm + unembedding
+    "sample",       # keys, penalties, sample_tokens, the logprob gather, the counts' update
+    "stack_slice",  # the layer scan outside an inner region: a layer's leaves, caches and state sliced out of the stacks and written back
+)
+REGION_SCOPE = "xllm."
+
 _TRACE_ANNOTATION = None
+_NAMED_SCOPE = None
 
 
 def annotation(name: str):
@@ -104,6 +129,21 @@ def annotation(name: str):
 
         _TRACE_ANNOTATION = TraceAnnotation
     return _TRACE_ANNOTATION(name)
+
+
+def region(name: str):
+    """A `jax.named_scope("xllm.<name>")` for one of DEVICE_REGIONS: a
+    context manager, and a decorator where a function is one region.
+    Metadata only: the compiled program is the same with and without it.
+    JAX is resolved on first use, as in `annotation`."""
+    global _NAMED_SCOPE
+    if name not in DEVICE_REGIONS:
+        raise ValueError(f"{name!r} is not one of DEVICE_REGIONS")
+    if _NAMED_SCOPE is None:
+        from jax import named_scope
+
+        _NAMED_SCOPE = named_scope
+    return _NAMED_SCOPE(REGION_SCOPE + name)
 
 
 class _PhaseScope:

@@ -33,6 +33,7 @@ import threading
 import jax
 import jax.numpy as jnp
 
+from xllm_service_tpu.obs.spans import region
 from xllm_service_tpu.ops import kv_cache as kvc
 
 NEG_INF = -1e30
@@ -446,6 +447,7 @@ def _mla_kernel_ok(c_cache, on: bool) -> bool:
     return _kernel_tile_ok(c_cache, kvc.raw(c_cache).shape[-1], on)
 
 
+@region("attn")
 def prefill_attention(
     q: jnp.ndarray,  # [P, Lpad, Hq, D] — the batched chunk's queries
     k_cache,
@@ -587,6 +589,7 @@ def mla_paged_attention_gather(
     return out.astype(q_lat.dtype)
 
 
+@region("attn")
 def mla_paged_attention(
     q_lat, c_cache, block_table, seq_lens, scale, kv_rank,
     use_kernel: bool | None = None, interpret: bool = False, layer=None,
@@ -619,6 +622,7 @@ def mla_paged_attention(
     )
 
 
+@region("attn")
 def mla_prefill_attention(
     q_lat: jnp.ndarray,  # [P, Lpad, Hq, C] — the batched chunk's queries
     c_cache,
@@ -746,6 +750,7 @@ def _on_tpu() -> bool:
     return jax.devices()[0].platform == "tpu"
 
 
+@region("attn")
 def paged_attention(
     q, k_cache, v_cache, block_table, seq_lens, scale,
     use_kernel: bool | None = None, window: int = 0,
@@ -941,6 +946,7 @@ def ragged_paged_attention(
     )
 
 
+@region("attn")
 def mixed_attention(
     q_dec: jnp.ndarray,  # [R, Hq, D] — decode slots (some inactive)
     q_pf: jnp.ndarray,  # [P, Lpad, Hq, D] — prefill chunk rows
@@ -1011,6 +1017,7 @@ def mixed_attention(
     return dec_out, pf_out
 
 
+@region("attn")
 def mixed_prefill_attention(
     q_a: jnp.ndarray,  # [A, La, Hq, D] — speculative verify rows (q_len<=La)
     q_b: jnp.ndarray,  # [B, Lb, Hq, D] — chunked-prefill rows

@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from xllm_service_tpu.obs.spans import region
 from xllm_service_tpu.ops import attention
 from xllm_service_tpu.ops.kv_cache import (
     CacheLike,
@@ -88,6 +89,7 @@ def _plan_units(tables, start, length, width: int, bs: int, tile: int):
     )
 
 
+@region("cache_write")
 def write_plan(
     cache: CacheLike,  # a stacked pool, read for its geometry only
     tables: jnp.ndarray,  # [S, CB] int32 block tables
@@ -177,6 +179,7 @@ def _write_units(caches, rows, units: _Units, layer, lanes, plan):
     )
 
 
+@region("cache_write")
 def write_rows(
     caches: Tuple[CacheLike, ...],  # stacked pools [L, N, Hc, BS, Dc]
     plan: WritePlan,

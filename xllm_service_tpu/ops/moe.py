@@ -46,6 +46,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from xllm_service_tpu.obs.spans import region
+
 
 # ------------------------------------------------------------ hatches
 
@@ -237,12 +239,13 @@ def _held_product(
 
     S = loc_e.shape[0]
     Xh = w_gate.shape[-3]
-    key = jnp.where(held, loc_e, Xh)  # absent and dead pairs sort last
-    order = jnp.argsort(key, stable=True).astype(jnp.int32)
-    sizes = jnp.zeros((Xh + 1,), jnp.int32).at[key].add(1)[:Xh]
-    M = _round_up(S, tile_rows(S))
-    rows = jnp.pad(order // K, (0, M - S))
-    xs = x[rows]
+    with region("moe_route"):  # the grouping
+        key = jnp.where(held, loc_e, Xh)  # absent and dead pairs sort last
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        sizes = jnp.zeros((Xh + 1,), jnp.int32).at[key].add(1)[:Xh]
+        M = _round_up(S, tile_rows(S))
+        rows = jnp.pad(order // K, (0, M - S))
+        xs = x[rows]
     if use_kernel:
         ys = moe_grouped_kernel(
             xs, sizes, w_gate, w_up, w_down, act=act, interpret=interpret,
@@ -258,6 +261,7 @@ def _held_product(
     return jnp.where(held[:, None], ys[pos].astype(jnp.float32), 0.0)
 
 
+@region("moe_experts")
 def grouped_moe(
     x: jnp.ndarray,        # [T, E]
     topi: jnp.ndarray,     # [T, K] int32 router top-k ids, of num_experts
@@ -300,18 +304,19 @@ def grouped_moe(
             # partitionable reference instead.
             use_kernel = False
 
-    flat_e = topi.reshape(T * K).astype(jnp.int32)
-    live = (
-        jnp.ones((T * K,), bool) if row_mask is None
-        else jnp.repeat(row_mask.reshape(T), K)
-    )
-    counts = (
-        jnp.zeros((X + 1,), jnp.int32)
-        .at[jnp.where(live, flat_e, X)].add(1)[:X]
-    )
-    # [2X]: pairs an expert, then 1 where the layer touched it at all
-    # (summed over layers: in how many layers its weights were read).
-    _record(jnp.concatenate([counts, (counts > 0).astype(jnp.int32)]))
+    with region("moe_route"):  # the counts output
+        flat_e = topi.reshape(T * K).astype(jnp.int32)
+        live = (
+            jnp.ones((T * K,), bool) if row_mask is None
+            else jnp.repeat(row_mask.reshape(T), K)
+        )
+        counts = (
+            jnp.zeros((X + 1,), jnp.int32)
+            .at[jnp.where(live, flat_e, X)].add(1)[:X]
+        )
+        # [2X]: pairs an expert, then 1 where the layer touched it at all
+        # (summed over layers: in how many layers its weights were read).
+        _record(jnp.concatenate([counts, (counts > 0).astype(jnp.int32)]))
 
     ctx = ep_context()
     n_shards = ctx[0].shape[ctx[1]] if ctx is not None else 1

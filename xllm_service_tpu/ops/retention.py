@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from xllm_service_tpu.obs.spans import region
 from xllm_service_tpu.ops.pallas.retention import (
     retention_chunk_kernel,
     retention_update_kernel,
@@ -120,6 +121,7 @@ def _units(live, slots):
     return n_live, slots[rows], rows
 
 
+@region("state_mixer")
 def decode_update(
     S, z, layer, slots, active, q, k, v, gamma,
     use_kernel: Optional[bool] = None, interpret: bool = False,
@@ -193,6 +195,7 @@ def _chunk_terms(q, k, v, gamma, start, length):
     return qf, kf, vf, b, bL, w, y_intra, den_intra
 
 
+@region("state_mixer")
 def chunk_update(
     S, z, layer, slots, start, length, q, k, v, gamma,
     use_kernel: Optional[bool] = None, interpret: bool = False,

@@ -15,6 +15,8 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
+from xllm_service_tpu.obs.spans import region
+
 NEG_INF = -1e30
 
 
@@ -104,6 +106,7 @@ def apply_penalties(
     return jax.lax.cond(jnp.any(active), apply, lambda x: x, logits)
 
 
+@region("sample")
 def sample_tokens(
     logits: jnp.ndarray,  # [R, V] float32
     temperature: jnp.ndarray,  # [R] float32; <=0 means greedy
@@ -178,6 +181,7 @@ def sample_tokens(
     return token_ids, chosen_logprob, logprobs_full
 
 
+@region("sample")
 def speculative_sample(
     logits: jnp.ndarray,  # [R, S, V] — verify-pass logits, position-major
     drafts: jnp.ndarray,  # [R, S-1] int32 — proposed tokens d_1..d_k
@@ -284,6 +288,7 @@ def pack_logit_bias(rows, n_rows: int):
     return ids, vals
 
 
+@region("sample")
 def make_step_keys(base_seeds: jnp.ndarray, steps: jnp.ndarray) -> jnp.ndarray:
     """Per-request keys folded with the generation step index: [R] -> [R, 2].
 

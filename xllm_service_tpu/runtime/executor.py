@@ -31,6 +31,7 @@ import logging
 import math
 import functools
 import os
+import time
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
@@ -40,6 +41,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from xllm_service_tpu.common.config import EngineConfig
+from xllm_service_tpu.obs import regions as obs_regions
 from xllm_service_tpu.obs import spans as obs_spans
 from xllm_service_tpu.runtime import compile_cache as compile_cache_mod
 from xllm_service_tpu.runtime.block_manager import StateFamilyUnsupported
@@ -181,6 +183,29 @@ def _setup_compilation_cache(cache_dir: str) -> None:
         "jax_persistent_cache_min_compile_time_secs",
         float(os.environ.get("XLLM_COMPILE_CACHE_MIN_COMPILE_S", "0.5")),
     )
+
+
+def _abstract(tree):
+    """A call's arguments without their buffers: every array leaf as a
+    `jax.ShapeDtypeStruct`, anything else (None, a static argument) as it
+    is. A COMMITTED array keeps its sharding; an uncommitted one, a host
+    array or a scalar gets none, as the call itself saw it: lowered again
+    from these the program is the same module, so its ahead-of-time
+    compile finds the executable the call left in the persistent cache
+    (a sharding the call did not see is one more attribute in the module,
+    another cache key, and a whole compilation)."""
+
+    def leaf(x):
+        if isinstance(x, jax.Array):
+            return jax.ShapeDtypeStruct(
+                x.shape, x.dtype, weak_type=x.weak_type,
+                sharding=x.sharding if x.committed else None,
+            )
+        if isinstance(x, (np.ndarray, np.generic)):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype)
+        return x
+
+    return jax.tree.map(leaf, tree)
 
 
 def _leaf(name: str):
@@ -374,6 +399,7 @@ class ModelExecutor:
         return None, self._null_feed
 
     @staticmethod
+    @obs_spans.region("step_io")
     def _dec_rows(pack, prev_tokens):
         """First traced lines of a program with decode rows: the pack's
         columns, the block table and the input ids — the previous step's
@@ -383,6 +409,7 @@ class ModelExecutor:
         return d, tables, token_ids
 
     @staticmethod
+    @obs_spans.region("sample")
     def _row_keys(*halves):
         """Sampling keys of a program's rows, one [rows, 2] array per
         half, from each half's `seeds` and `steps` columns: ONE call of
@@ -406,6 +433,7 @@ class ModelExecutor:
         )
 
     @staticmethod
+    @obs_spans.region("sample")
     def _verify_keys(seeds, steps, S: int):
         """[R, S, 2] keys of a verify step on the sequential schedule:
         position j uses step base + j, so the emitted stream is
@@ -684,6 +712,12 @@ class ModelExecutor:
         # dispatch, read when the dispatch's tokens are (_step_jit,
         # take_moe_stats, book_moe). guarded by: engine thread
         self._moe_pending: list = []
+        # program -> [(jitted, abstract args, abstract kwargs)] per shape
+        # it lowered, and the region maps made of them (program_regions).
+        # guarded by: engine thread (appends); read after the fact
+        self._step_signatures: Dict[str, list] = {}
+        self._region_maps: Dict[tuple, Dict[str, str]] = {}
+        obs_regions.register(self)
         self._decode_jit = self._step_jit(
             self._decode_impl, donate_argnums=(0, 1, 2),
             static_argnames=("use_kernel",)
@@ -1100,9 +1134,10 @@ class ModelExecutor:
             ),
             min_p=min_p,
         )
-        counts = counts.at[
-            jnp.arange(tokens.shape[0]), tokens
-        ].add(active.astype(jnp.int32))
+        with obs_spans.region("sample"):
+            counts = counts.at[
+                jnp.arange(tokens.shape[0]), tokens
+            ].add(active.astype(jnp.int32))
         return k_cache, v_cache, counts, tokens, logprob
 
     def _prefill_impl(
@@ -1922,32 +1957,93 @@ class ModelExecutor:
         choice counts, then in how many layers each expert was touched)
         as one more small output, kept beside the dispatch
         (`_moe_pending`) until its tokens are read: no callback, no
-        transfer of its own before that. Other models get the plain jit,
-        their programs as they were."""
-        if not self.cfg.is_moe:
-            return jax.jit(impl, **jit_kw)
-        from xllm_service_tpu.ops import moe
+        transfer of its own before that. Other models' programs are as
+        they were.
 
-        X = self.cfg.num_experts
+        Either way the call keeps, for each shape the program lowered,
+        the abstract signature it was called with (`_step_signatures`:
+        shapes, dtypes, shardings, static arguments, no buffer), which
+        `program_regions()` lowers again on demand. A call that lowers
+        nothing pays one comparison for it."""
+        moe_model = self.cfg.is_moe
+        fn = impl
+        if moe_model:
+            from xllm_service_tpu.ops import moe
 
-        @functools.wraps(impl)  # the trace names a program by its function
-        def with_stats(*a, **kw):
-            with moe.step_stats() as stats:
-                out = impl(*a, **kw)
-            total = stats.total()
-            return out, (
-                jnp.zeros((2 * X,), jnp.int32) if total is None else total
-            )
+            X = self.cfg.num_experts
 
-        jitted = jax.jit(with_stats, **jit_kw)
+            @functools.wraps(impl)  # the trace names a program by its function
+            def fn(*a, **kw):
+                with moe.step_stats() as stats:
+                    out = impl(*a, **kw)
+                total = stats.total()
+                return out, (
+                    jnp.zeros((2 * X,), jnp.int32) if total is None else total
+                )
+
+        jitted = jax.jit(fn, **jit_kw)
+        signatures = self._step_signatures.setdefault(impl.__name__, [])
+        lowered = 0
 
         def call(*a, **kw):
-            out, counts = jitted(*a, **kw)
-            self._moe_pending.append(counts)
+            nonlocal lowered
+            out = jitted(*a, **kw)
+            if jitted._cache_size() != lowered:
+                # a donated argument keeps its shape, dtype and sharding
+                lowered = jitted._cache_size()
+                signatures.append((jitted,) + _abstract((a, kw)))
+            if moe_model:
+                out, counts = out
+                self._moe_pending.append(counts)
             return out
 
-        call._cache_size = jitted._cache_size
+        # Python functions, not the jit object's bound methods: those are
+        # invisible to the cycle collector, and a wrapper that holds one
+        # keeps its executor (weights, pools, executables) alive for good.
+        call._cache_size = lambda: jitted._cache_size()
+        call.lower = lambda *a, **kw: jitted.lower(*a, **kw)
         return call
+
+    def program_regions(
+        self, budget_s: Optional[float] = None
+    ) -> Dict[str, List[Dict[str, str]]]:
+        """{step program's function name: one {op key: device region} per
+        shape it lowered} (obs/regions.py, docs/OBSERVABILITY.md "Device
+        regions"): each kept signature lowered and compiled ahead of time,
+        the compiled text parsed, the map memoized. The signature is the
+        call's own (`_abstract`), so the compile finds the call's
+        executable (in this process, or in the persistent cache: about a
+        second); where it does not, or where that executable predates the
+        scopes, it COMPILES, at what the first call cost. `budget_s` bounds that: once it is spent no further program
+        is compiled, and the programs left out have no map in the result
+        (their ops read `unnamed`). For a profile's reader after the
+        fact, never the serving path, set-up or /metrics; works on a
+        stopped engine (no buffer is touched) and leaves
+        `lowering_count()` where it was (ahead-of-time lowering goes past
+        the dispatch caches)."""
+        self._set_shard_ctx()
+        t0 = time.monotonic()
+        out: Dict[str, List[Dict[str, str]]] = {}
+        for program, signatures in self._step_signatures.items():
+            for i, (jitted, args, kwargs) in enumerate(signatures):
+                if (program, i) not in self._region_maps:
+                    if budget_s is not None and time.monotonic() - t0 > budget_s:
+                        continue
+                    lowered = jitted.lower(*args, **kwargs)
+                    text = lowered.compile().as_text()
+                    if obs_spans.REGION_SCOPE not in text:
+                        # the persistent cache's key leaves metadata out:
+                        # this executable was compiled before the scopes
+                        # were there. An option that changes nothing of
+                        # the program (the CPU backend keeps its LLVM IR,
+                        # the TPU's ignores it) is another key, in the
+                        # process and on disk: compile once more.
+                        text = lowered.compile(
+                            compiler_options={"xla_embed_ir_in_executable": True}
+                        ).as_text()
+                    self._region_maps[program, i] = obs_regions.parse_regions(text)
+                out.setdefault(program, []).append(self._region_maps[program, i])
+        return out
 
     @property
     def cache_row_bytes(self) -> int:
@@ -2176,9 +2272,10 @@ class ModelExecutor:
                 guided_table[mask_rows] if mask_rows is not None else None
             ),
         )
-        counts = counts.at[
-            jnp.arange(tokens.shape[0]), tokens
-        ].add(active.astype(jnp.int32))
+        with obs_spans.region("sample"):
+            counts = counts.at[
+                jnp.arange(tokens.shape[0]), tokens
+            ].add(active.astype(jnp.int32))
         pf_tokens_out, pf_logprob, _ = sampling_ops.sample_tokens(
             pf_logits, pf["temperature"], pf["top_k"], pf["top_p"], pf_keys,
             counts=pf_counts, presence=pf_presence, frequency=pf_frequency,
@@ -2188,16 +2285,13 @@ class ModelExecutor:
                 if pf_mask_rows is not None else None
             ),
         )
-        return (
-            k_cache,
-            v_cache,
-            counts,
-            jnp.concatenate([tokens, pf_tokens_out]),
-            jnp.concatenate([logprob, pf_logprob]),
-            tokens,
-        )
+        with obs_spans.region("step_io"):
+            all_tokens = jnp.concatenate([tokens, pf_tokens_out])
+            all_logprobs = jnp.concatenate([logprob, pf_logprob])
+        return k_cache, v_cache, counts, all_tokens, all_logprobs, tokens
 
     @staticmethod
+    @obs_spans.region("step_io")
     def _pf_rows(pf_pack, lpad: int):
         """First traced lines of a fused program's prefill half: the
         pack's columns, the [P, lpad] chunk and the block table behind
@@ -2377,6 +2471,7 @@ class ModelExecutor:
         the ragged kernel grows a latent-row mode (docs/KERNELS.md)."""
         return hasattr(self.model_mod, "mixed_verify_step")
 
+    @obs_spans.region("step_io")
     def _spec_state_merge(
         self, drafts, host_last, host_pos, host_steps, fresh_mask,
         prev_tokens, prev_n_emit, seeds, active,
