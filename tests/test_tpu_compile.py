@@ -568,3 +568,97 @@ def test_granite_mixed_step_compiles_and_its_pools_stay(one_chip, no_persistent_
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 0.5e9
     assert 12.5e9 < mem.argument_size_in_bytes < 13.5e9  # weights + state pool + 1.05 GB of K and V
+
+
+# ---- PR 45: a weight is read where it lies. The layer scan hands a step
+# its layer's leaves by index; a product on a leaf must take that slice as a
+# bitcast inside its own fusion. What it must not do is what brumby's `_qkv`
+# and deepseek's `_q_heads` did: copy the leaf out of the stack and transpose
+# it, every layer of every step, for a head-major dot.
+
+# (XLA's own prefetch of a small leaf into faster memory is an async pair in
+# the leaf's own layout, overlapped with compute: not a move of this kind)
+_PLUMBING = {"parameter", "get-tuple-element", "tuple", "bitcast", "while", "custom-call",
+             "copy-start", "copy-done", "slice-start", "slice-done"}
+
+
+def _weight_leaves_moved(text, params, names, stacks=("layers", "dense_layers")):
+    """Instructions of the compiled program, outside its fused
+    computations, whose RESULT is one layer of a stacked weight leaf of
+    `names` or a re-laying of it: the leaf's element count with the leaf's
+    input width among the dims (`[1, in, out]`, `[heads, head_dim, in]`,
+    any order or layout; a dynamic-slice INSIDE a product's fusion is the
+    leaf read in place and is not looked at). A copy, a transpose, a
+    `*dynamic-slice*` fusion, a fusion of any other name that materialises
+    it: all count."""
+    import math
+    import re
+
+    leaves = {}  # (a layer's element count, its input width) -> the leaves of that size
+    for stack in stacks:
+        for name, leaf in (params.get(stack) or {}).items():
+            if name in names:
+                leaves.setdefault((math.prod(leaf.shape[1:]), leaf.shape[1]), set()).add(name)
+    fused = set(re.findall(r"calls=%([\w.\-]+)", text))
+    moved, inside = [], None
+    for line in text.splitlines():
+        if m := re.match(r"\s*(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{\s*$", line):
+            inside = m.group(1)
+            continue
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\]\S* ([\w\-]+)\(", line)
+        if not m or inside in fused or m.group(2) in _PLUMBING:
+            continue
+        dims = [int(d) for d in m.group(1).split(",") if d]
+        for (count, width), which in leaves.items():
+            if math.prod(dims) == count and width in dims:
+                moved.append(f"{'|'.join(sorted(which))}: {line.strip()[:150]}")
+    return moved
+
+
+def _in_place_case(one_chip, case):
+    """(step function, abstract arguments, the stacked leaves looked for)
+    of one of the benchmark's step shapes with 2 scanned layers."""
+    i32 = jnp.int32
+
+    def s(shape, dtype=i32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def dec(rows, width):
+        return s((rows,)), s((rows,)), s((rows, width)), s((rows,), jnp.bool_)
+
+    if case == "brumby-decode-24":
+        fn, args, _ = _brumby_case(one_chip, "decode", slots=24)
+        return fn, args, ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+    from xllm_service_tpu.models import deepseek
+
+    cfg = dataclasses.replace(  # 1 dense layer beside the scan of 2
+        get_model_config("deepseek-v2"), num_layers=3, vocab_size=25600, experts_held=(0, 40)
+    )
+    params = jax.eval_shape(lambda k: deepseek.init_params(cfg, k, jnp.bfloat16), jax.random.key(0))
+    params = jax.tree.map(lambda a: s(a.shape, a.dtype), params)
+    pools = (s((3, 600, 1, BS, MLA_C), jnp.bfloat16), s((3, 1, 1, 1, 1), jnp.bfloat16))
+    # (not w_dkv: at 576 rows its [5120, 576] is an activation's shape)
+    names = ("w_dq", "w_uq", "w_uk", "w_uv", "wo", "w_gate", "w_up", "w_down",
+             "w_sh_gate", "w_sh_up", "w_sh_down")
+    if case == "deepseek-decode-3":
+        fn = lambda p, k, v, *a: deepseek.decode_step(p, cfg, k, v, *a)  # noqa: E731
+        return fn, (params, *pools, *dec(3, 64)), names
+    pf = (s((1, 512)), s((1,)), s((1,)), s((1, 64)))
+    fn = lambda p, k, v, *a: deepseek.mixed_step(p, cfg, k, v, *a)  # noqa: E731
+    return fn, (params, *pools, *dec(64, 64), *pf), names
+
+
+@pytest.mark.parametrize("case", ["brumby-decode-24", "deepseek-decode-3", "deepseek-mixed-576"])
+def test_step_reads_every_weight_leaf_where_it_lies(one_chip, no_persistent_cache, as_on_tpu, case):
+    """The brumby decode step at reason-batch's 24 rows and the deepseek
+    decode (3 rows) and mixed (64 + 512 rows) steps of doc-steady, at the
+    benchmark configurations' widths: no instruction of the compiled
+    program has a whole layer of a weight leaf, or a re-laying of one, as
+    its result (`bf16[1,5120,5120]`, `[1,5120,1024]`, `[1,1536,24576]`,
+    `[128,192,1536]`...). At 576 rows `q_lat` `[128,512,576]` has `w_uq`'s
+    element count by chance: it holds no 1536, and is an activation."""
+    fn, args, names = _in_place_case(one_chip, case)
+    text = jax.jit(fn, donate_argnums=(1, 2)).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text  # the kernels' branch, as on the chip
+    moved = _weight_leaves_moved(text, args[0], names)
+    assert not moved, "\n".join(moved)

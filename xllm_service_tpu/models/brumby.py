@@ -93,7 +93,7 @@ def _qkv(lp, cfg: ModelConfig, x: jnp.ndarray, positions: jnp.ndarray):
         y = jnp.einsum(
             "te,eh->th", x, wt(lp[name]), preferred_element_type=jnp.float32
         )
-        return y.reshape(T, heads, cfg.head_dim)
+        return llama._plain_product(y).reshape(T, heads, cfg.head_dim)
 
     q, k, v = proj("wq", cfg.num_heads), proj("wk", cfg.num_kv_heads), proj("wv", cfg.num_kv_heads)
     q = rms_norm(q, lp["q_head_norm"], cfg.rms_norm_eps)

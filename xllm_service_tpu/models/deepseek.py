@@ -41,6 +41,7 @@ from xllm_service_tpu.models.llama import (
     _last_rows,
     _mlp,
     _mlp_block,
+    _plain_product,
     _scan_layers,
     _unembed,
 )
@@ -210,7 +211,7 @@ def _q_heads(lp, cfg: ModelConfig, h: jnp.ndarray, positions: jnp.ndarray):
         q = jnp.einsum("tq,qh->th", cq, wt(lp["w_uq"]))
     else:
         q = jnp.einsum("te,eh->th", h, wt(lp["w_q"]))
-    q = q.reshape(T, cfg.num_heads, dn + dr)
+    q = _plain_product(q).reshape(T, cfg.num_heads, dn + dr)
     q_nope, q_pe = q[..., :dn], q[..., dn:]
     q_pe = rope_ops.apply_rope_scaled(q_pe, positions, cfg)
     return q_nope, q_pe

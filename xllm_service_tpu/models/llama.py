@@ -422,6 +422,21 @@ def _out_mlp_rows(lp, cfg: ModelConfig, x, attn, lora, li, valid):
         return x + _mlp_block(lp, cfg, h, lora, rows_valid=valid)
 
 
+def _plain_product(y: jnp.ndarray) -> jnp.ndarray:
+    """Fence `y`, the result of a `[T, in] x [in, out]` product on a stored
+    weight leaf, so that XLA keeps that product a plain matmul and reads
+    the leaf where it lies in its stack. Unfenced, XLA:TPU merges a
+    product whose result is reshaped to heads and fed to a head-batched
+    consumer (a per-head norm, `...hd,hkd->...hk`) into one head-major
+    dot, and that dot wants the WEIGHT head-major: every layer of every
+    step then copies the leaf out of the stack and transposes it (brumby
+    wq/wk/wv, deepseek w_uq: 1.4-1.9 ms a step, PERF.md, PR 45). Behind
+    the fence the consumer re-lays out the ACTIVATION instead, which is
+    rows x out and not in x out (docs/MOE.md "A weight is read where it
+    lies"; tests/test_tpu_compile.py holds the compiled text to it)."""
+    return jax.lax.optimization_barrier(y)
+
+
 def _scan_layers(layer_fn, x, params, k_caches, v_caches,
                  stack: str = "layers", first_layer: int = 0):
     """The cache-threading layer scan: the stacked caches ride the CARRY
