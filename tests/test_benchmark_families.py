@@ -30,8 +30,8 @@ def configurations():
 
 
 def test_the_benchmark_has_both_families():
-    # four since PR 42 (the name stays: the driver counts tests by name)
-    assert {c["family"] for c in configurations()} == {"llama", "brumby", "deepseek", "granite"}
+    # five since PR 46 (the name stays: the driver counts tests by name)
+    assert {c["family"] for c in configurations()} == {"llama", "brumby", "deepseek", "granite", "solar"}
 
 
 @pytest.mark.parametrize("config", configurations(), ids=lambda c: c["name"])
@@ -56,7 +56,8 @@ def test_model_config_is_the_family_the_program_dispatches_on(config):
 
     cfg = family_mod.load(config).model_config(config["name"], config)
     module = models.get_module(cfg).__name__.rsplit(".", 1)[-1]
-    assert module == config["family"]
+    # both hybrids are ONE stack: models/granite.py, the layer kinds as data
+    assert module == {"solar": "granite"}.get(config["family"], config["family"])
     assert cfg.is_retention == (config["family"] == "brumby")
 
 
@@ -174,7 +175,7 @@ def test_the_granite_family_draws_a_trained_models_decays_and_leaves_nothing_ski
     cfg = fam.model_config(config["name"], config)
     assert (cfg.num_experts, cfg.held_experts, cfg.vocab_size, cfg.num_layers) == (72, (0, 36), 50176, 10)
     assert len(config["layer_types"]) == 40 and cfg.layer_types == tuple(config["layer_types"][:10])
-    assert cfg.num_mamba_layers == 9 and cfg.num_attention_layers == 1
+    assert cfg.num_state_layers == 9 and cfg.num_attention_layers == 1
     shapes = fam.weight_shapes(config)
     assert shapes["layers"]["router"] == (10, 4096, 72) and shapes["layers"]["w_gate"] == (10, 36, 4096, 768)
     assert shapes["mamba"]["w_in"] == (9, 4096, 8192 + 8448 + 128) and shapes["attn"]["wk"] == (1, 4096, 1024)
@@ -211,3 +212,56 @@ def test_the_granite_family_draws_a_trained_models_decays_and_leaves_nothing_ski
     for name in fam.FLOAT32_LEAVES:
         assert mam[name].dtype == jnp.float32, name
 
+
+
+def test_the_solar_family_draws_slow_channel_decays_and_leaves_nothing_skippable():
+    """solar-open2-250b as cut: 20 experts held under a router 320 wide, an
+    eighth of the vocabulary, two periods of the published pattern; and at
+    the rehearsal size the draws: decays a channel from ten to ten thousand
+    tokens, a head's sink (key lane 0: a large bias on k, q shut, the
+    slowest decay), a standing component on the v lanes and none on the
+    other k lanes, an untied head, no gain at 1, no bias at 0."""
+    import jax
+    import jax.numpy as jnp
+
+    with open(os.path.join(BENCH, "configs", "solar-open2-250b.json")) as f:
+        config = json.load(f)
+    fam = family_mod.load(config)
+    cfg = fam.model_config(config["name"], config)
+    assert (cfg.num_experts, cfg.held_experts, cfg.vocab_size, cfg.num_layers) == (320, (0, 20), 24576, 8)
+    assert len(config["gqa_layers"]) == 12 and cfg.layer_types == ("attention", "kda", "kda", "kda") * 2
+    assert cfg.num_state_layers == 6 and cfg.num_attention_layers == 2 and cfg.state_layer_kind == "kda"
+    assert (cfg.kda_n_heads, cfg.kda_d_head, cfg.kda_d_conv, cfg.kda_gate_rank) == (64, 128, 4, 128)
+    assert cfg.attn_gate and cfg.kda_neg_eigval and not cfg.tie_word_embeddings
+    shapes = fam.weight_shapes(config)
+    assert shapes["layers"]["router"] == (8, 4096, 320) and shapes["layers"]["w_gate"] == (8, 20, 4096, 1280)
+    assert shapes["kda"]["wq"] == (6, 4096, 8192) and shapes["kda"]["w_f1"] == (6, 4096, 128)
+    assert shapes["kda"]["w_g2"] == (6, 128, 8192) and shapes["kda"]["conv_w"] == (6, 4, 24576)
+    assert shapes["attn"]["wk"] == (2, 4096, 1024) and shapes["attn"]["w_ogate"] == (2, 4096, 8192)
+    assert shapes["embed"] == (24576, 4096) and shapes["lm_head"] == (4096, 24576)
+
+    with open(os.path.join(BENCH, "configs", "rehearse-solar-tiny.json")) as f:
+        tiny = json.load(f)
+    w = jax.jit(lambda k: fam.make_weights(tiny, k, jnp.float32))(family_mod.seed_key(3))
+    kda = w["kda"]
+    step = jax.nn.softplus(kda["dt_bias"])
+    assert fam.DT_RANGE[0] * 0.99 <= float(step.min()) and float(step.max()) <= fam.DT_RANGE[1] * 1.01
+    rate = jnp.exp(kda["A_log"])
+    assert fam.A_RANGE[0] * 0.99 <= float(rate.min()) and float(rate.max()) <= fam.A_RANGE[1] * 1.01
+    assert float(jnp.exp(-rate.max() * step.max())) > 0.9  # the fastest channel at a zero projection
+    H, d = 4, 16
+    sink = np.tile(np.arange(d) < fam.SINK_LANES, H)
+    assert fam.SINK_LANES == 1 and int(sink.sum()) == H
+    assert np.allclose(np.asarray(step)[:, sink], fam.DT_RANGE[0], rtol=1e-3)  # the slowest channel
+    bias = np.asarray(kda["conv_b"]).reshape(3, 3, H * d)  # [layer, q | k | v, lanes]
+    assert abs(float(bias[:, 0][:, ~sink].mean()) - fam.Q_BIAS_MEAN) < 0.05
+    assert abs(float(bias[:, 1][:, ~sink].mean()) - fam.K_BIAS_MEAN) < 0.05
+    assert abs(float(bias[:, 0][:, sink].mean()) - fam.Q_SINK_BIAS) < 0.1
+    assert abs(float(bias[:, 1][:, sink].mean()) - fam.K_SINK_BIAS) < 0.1
+    assert abs(float(bias[:, 2].mean()) - fam.V_BIAS_MEAN) < 0.05
+    for leaf in jax.tree.leaves(w):  # nothing at a value that lets a path skip it
+        assert float(jnp.abs(leaf.astype(jnp.float32)).min()) > 0.0 or leaf.size > 1000
+        assert float(jnp.std(leaf.astype(jnp.float32))) > 0.0
+    assert abs(float(jnp.std(kda["wo"])) * np.sqrt(64) / fam.KDA_OUT_SCALE - 1.0) < 0.1
+    assert abs(float(jnp.std(w["layers"]["w_down"])) * np.sqrt(32) / fam.ROUTED_OUT_SCALE - 1.0) < 0.1
+    assert abs(float(jnp.std(w["lm_head"])) * np.sqrt(64) - 1.0) < 0.05

@@ -271,7 +271,7 @@ def test_both_kinds_of_memory_are_counted_and_nothing_is_cached(served):
                  "xllm_engine_state_recomputes_total", "xllm_engine_cache_row_bytes",
                  "xllm_engine_moe_pairs_per_expert"):
         assert name in text, name
-    slot = (H * P * N + (K - 1) * CONV) * 4 * CFG.num_mamba_layers
+    slot = (H * P * N + (K - 1) * CONV) * 4 * CFG.num_state_layers
     assert ex.state_slot_bytes == slot
     assert ex.state_pool_bytes == 4 * slot
     # K and V of the ONE attention layer: 2 KV heads of 16 lanes, float32

@@ -2,9 +2,13 @@
 config 5; the reference carries an `offline` flag it never consumes —
 request.h:38): an online burst preempts RUNNING offline decodes
 (recompute-style) instead of queueing behind them, and the offline work
-resumes and completes once the burst drains."""
+resumes and completes once the burst drains. Every case runs for a
+paged-cache family and for a family with a state slot BESIDE its K/V
+blocks (`solar-tiny`: a preempted sequence gives back its slot and its
+blocks, and recomputes its state when it resumes)."""
 
 import numpy as np
+import pytest
 
 from xllm_service_tpu.common.config import EngineConfig
 from xllm_service_tpu.ops.sampling import SamplingParams
@@ -12,13 +16,25 @@ from xllm_service_tpu.runtime.engine import EngineRequest, InferenceEngine
 from xllm_service_tpu.runtime.executor import ModelExecutor
 
 
-def _engine(R=4, num_blocks=64):
+MODELS = ["llama3-tiny", "solar-tiny"]
+
+
+def _engine(model, R=4, num_blocks=64):
     cfg = EngineConfig(
-        model="llama3-tiny", dtype="float32", block_size=16,
+        model=model, dtype="float32", block_size=16,
         num_blocks=num_blocks, max_running_requests=R, max_seq_len=256,
         prefill_buckets=[32, 64, 128],
+        # a state family's engines step synchronously, as tests/test_granite.py's
+        sync_engine=model != "llama3-tiny",
     )
     return InferenceEngine(cfg, executor=ModelExecutor(cfg))
+
+
+def _all_given_back(eng):
+    """No row, no state slot and no block is held (a state family's rows
+    ARE its slots)."""
+    assert len(eng._free_slots) == eng.R
+    assert eng.block_mgr.num_referenced_blocks == 0
 
 
 def _req(rid, outs, offline=False, max_new=64, prompt=None):
@@ -39,12 +55,13 @@ def _req(rid, outs, offline=False, max_new=64, prompt=None):
     )
 
 
-def test_online_burst_preempts_running_offline():
+@pytest.mark.parametrize("model", MODELS)
+def test_online_burst_preempts_running_offline(model):
     """Fill every slot with long offline decodes, then burst online work:
     online requests get slots via preemption (first tokens within a few
     steps, NOT after the offline work drains), and the preempted offline
     sequences resume and run to completion afterwards."""
-    eng = _engine(R=4)
+    eng = _engine(model, R=4)
     outs = {}
     for i in range(4):
         eng.add_request(_req(f"off{i}", outs, offline=True, max_new=60))
@@ -84,15 +101,20 @@ def test_online_burst_preempts_running_offline():
     assert {f"off{i}" for i in range(4)} <= finished
     for i in range(4):
         assert len(outs[f"off{i}"]) == 60, len(outs[f"off{i}"])
+    assert eng.preemptions >= 1
+    _all_given_back(eng)
+    if eng.executor.has_state_pool:
+        assert eng.state_recomputes >= 1
 
 
-def test_preempted_offline_resume_is_exact():
+@pytest.mark.parametrize("model", MODELS)
+def test_preempted_offline_resume_is_exact(model):
     """A preempted-then-resumed offline sequence emits the same greedy
     continuation as an undisturbed run (recompute preserves history)."""
     prompt = list(np.random.default_rng(5).integers(1, 400, 12))
 
     ref_outs = {}
-    eng = _engine(R=4)
+    eng = _engine(model, R=4)
     eng.add_request(_req("solo", ref_outs, offline=True, max_new=40,
                          prompt=prompt))
     for _ in range(200):
@@ -101,7 +123,7 @@ def test_preempted_offline_resume_is_exact():
         eng.step()
 
     outs = {}
-    eng2 = _engine(R=4)
+    eng2 = _engine(model, R=4)
     eng2.add_request(_req("victim", outs, offline=True, max_new=40,
                           prompt=prompt))
     for _ in range(6):
@@ -114,12 +136,14 @@ def test_preempted_offline_resume_is_exact():
             break
         eng2.step()
     assert outs["victim"] == ref_outs["solo"]
+    _all_given_back(eng2)
 
 
-def test_offline_admits_behind_online_queue():
+@pytest.mark.parametrize("model", MODELS)
+def test_offline_admits_behind_online_queue(model):
     """With both classes waiting, online admits first regardless of
     arrival order."""
-    eng = _engine(R=1, num_blocks=16)
+    eng = _engine(model, R=1, num_blocks=16)
     outs = {}
     eng.add_request(_req("off", outs, offline=True, max_new=4))
     eng.add_request(_req("on", outs, offline=False, max_new=4))

@@ -72,6 +72,10 @@ def as_on_tpu(monkeypatch):
     steer them onto the kernel branch the chip would take."""
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
     monkeypatch.setenv("XLLM_RAGGED_ATTENTION_KERNEL", "1")
+    # a tp > 1 executor built earlier on this worker's thread leaves its
+    # mesh declared (the context is per thread and read at trace time); the
+    # programs here are one chip's unless a test declares its own
+    attention.set_shard_context(None)
 
 
 def _compile(fn, *args):
@@ -582,7 +586,7 @@ _PLUMBING = {"parameter", "get-tuple-element", "tuple", "bitcast", "while", "cus
              "copy-start", "copy-done", "slice-start", "slice-done"}
 
 
-def _weight_leaves_moved(text, params, names, stacks=("layers", "dense_layers")):
+def _weight_leaves_moved(text, params, names, stacks=("layers", "dense_layers"), dtype=None):
     """Instructions of the compiled program, outside its fused
     computations, whose RESULT is one layer of a stacked weight leaf of
     `names` or a re-laying of it: the leaf's element count with the leaf's
@@ -590,7 +594,8 @@ def _weight_leaves_moved(text, params, names, stacks=("layers", "dense_layers"))
     any order or layout; a dynamic-slice INSIDE a product's fusion is the
     leaf read in place and is not looked at). A copy, a transpose, a
     `*dynamic-slice*` fusion, a fusion of any other name that materialises
-    it: all count."""
+    it: all count. `dtype` ("bf16") keeps to results of the leaves' own
+    type, where a float32 pool has a leaf's element count by chance."""
     import math
     import re
 
@@ -607,6 +612,8 @@ def _weight_leaves_moved(text, params, names, stacks=("layers", "dense_layers"))
             continue
         m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\]\S* ([\w\-]+)\(", line)
         if not m or inside in fused or m.group(2) in _PLUMBING:
+            continue
+        if dtype and not re.match(rf"\s*(?:ROOT )?%?[\w.\-]+ = {dtype}\[", line):
             continue
         dims = [int(d) for d in m.group(1).split(",") if d]
         for (count, width), which in leaves.items():
@@ -629,6 +636,26 @@ def _in_place_case(one_chip, case):
     if case == "brumby-decode-24":
         fn, args, _ = _brumby_case(one_chip, "decode", slots=24)
         return fn, args, ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+    if case.startswith("solar"):
+        from xllm_service_tpu.models import granite
+
+        cfg = dataclasses.replace(  # one GQA layer and a scan of two KDA layers
+            get_model_config("solar-open2-250b"), num_layers=3,
+            layer_types=("attention", "kda", "kda"), vocab_size=8192,
+        )
+        params = jax.eval_shape(lambda k: granite.init_params(cfg, k, jnp.bfloat16), jax.random.key(0))
+        params = jax.tree.map(lambda a: s(a.shape, a.dtype), params)
+        state, conv = granite.state_shapes(cfg, 96)
+        kv = s((1, 600, 8, BS, 128), jnp.bfloat16)
+        pools = ((kv, s(state, jnp.float32)), (kv, s(conv, jnp.float32)))
+        names = ("wq", "wk", "wv", "wo", "w_f1", "w_f2", "w_g1", "w_g2", "w_ogate",
+                 "w_gate", "w_up", "w_down", "w_sh_gate", "w_sh_up", "w_sh_down")
+        if case == "solar-decode-96":
+            fn = lambda p, k, v, *a: granite.decode_step(p, cfg, k, v, *a)  # noqa: E731
+            return fn, (params, *pools, *dec(96, 64)), names
+        pf = (s((1, 512)), s((1,)), s((1,)), s((1, 33)))
+        fn = lambda p, k, v, *a: granite.mixed_step(p, cfg, k, v, *a)  # noqa: E731
+        return fn, (params, *pools, *dec(96, 64), *pf), names
     from xllm_service_tpu.models import deepseek
 
     cfg = dataclasses.replace(  # 1 dense layer beside the scan of 2
@@ -648,7 +675,8 @@ def _in_place_case(one_chip, case):
     return fn, (params, *pools, *dec(64, 64), *pf), names
 
 
-@pytest.mark.parametrize("case", ["brumby-decode-24", "deepseek-decode-3", "deepseek-mixed-576"])
+@pytest.mark.parametrize("case", ["brumby-decode-24", "deepseek-decode-3", "deepseek-mixed-576",
+                                  "solar-decode-96", "solar-mixed-608"])
 def test_step_reads_every_weight_leaf_where_it_lies(one_chip, no_persistent_cache, as_on_tpu, case):
     """The brumby decode step at reason-batch's 24 rows and the deepseek
     decode (3 rows) and mixed (64 + 512 rows) steps of doc-steady, at the
@@ -656,9 +684,73 @@ def test_step_reads_every_weight_leaf_where_it_lies(one_chip, no_persistent_cach
     program has a whole layer of a weight leaf, or a re-laying of one, as
     its result (`bf16[1,5120,5120]`, `[1,5120,1024]`, `[1,1536,24576]`,
     `[128,192,1536]`...). At 576 rows `q_lat` `[128,512,576]` has `w_uq`'s
-    element count by chance: it holds no 1536, and is an activation."""
+    element count by chance: it holds no 1536, and is an activation. The
+    KDA hybrid's decode (96 rows) and mixed (96 + 512 rows) steps at
+    solar-open2-250b's widths: `wq`/`wk`/`wv` of the KDA layers, the two
+    low-rank pairs and the GQA layer's gate, whose consumers are all
+    head-batched."""
     fn, args, names = _in_place_case(one_chip, case)
+    if case.startswith("solar"):  # the cell's route: the pair of attention kernels
+        os.environ["XLLM_RAGGED_ATTENTION_KERNEL"] = "0"  # (as_on_tpu's monkeypatch restores it)
     text = jax.jit(fn, donate_argnums=(1, 2)).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text  # the kernels' branch, as on the chip
-    moved = _weight_leaves_moved(text, args[0], names)
+    if case.startswith("solar"):
+        # (a head tile of the float32 state pool, [1, 64, 128, 128], has w_f2's element count)
+        moved = _weight_leaves_moved(text, args[0], names, ("layers", "kda", "attn"), dtype="bf16")
+    else:
+        moved = _weight_leaves_moved(text, args[0], names)
     assert not moved, "\n".join(moved)
+    if case.startswith("solar"):
+        assert "kda_update_kernel" in text
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill", "mixed"])
+def test_solar_tiny_steps_compile_and_neither_pool_is_laid_out_again(one_chip, no_persistent_cache, as_on_tpu, step):
+    """The three step programs of `solar-tiny` for a described v5e, its KDA
+    heads widened to 128 lanes so that the state is whole tiles as the
+    benchmark's is (2 heads of 128 x 128; at 16 lanes XLA re-tiles any
+    small array): the delta-rule pool `[3, 8, 2, 128, 128]` and the
+    convolution pool `[3, 8, 2304]` go in and come out in the layout they
+    are held in: no copy and no transpose of either on the way into or
+    out of the program or of its layer scans, and the decode update is the
+    kernel."""
+    import re
+
+    from xllm_service_tpu.models import granite
+
+    cfg = dataclasses.replace(get_model_config("solar-tiny"), kda_n_heads=2, kda_d_head=128)
+    i32 = jnp.int32
+
+    def s(shape, dtype=i32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.eval_shape(lambda k: granite.init_params(cfg, k, jnp.float32), jax.random.key(0))
+    params = jax.tree.map(lambda a: s(a.shape, a.dtype), params)
+    state, conv = granite.state_shapes(cfg, 8)
+    assert state == (3, 8, 2, 128, 128) and conv == (3, 8, 2304)
+    kv = s((1, 40, 2, 16, 16), jnp.float32)
+    pools = ((kv, s(state, jnp.float32)), (kv, s(conv, jnp.float32)))
+    dec = (s((8,)), s((8,)), s((8, 8)), s((8,), jnp.bool_))
+    pf = (s((2, 32)), s((2,)), s((2,)), s((2, 9)))
+    fn, args = {
+        "decode": (granite.decode_step, dec), "prefill": (granite.prefill_batch_step, pf),
+        "mixed": (granite.mixed_step, dec + pf),
+    }[step]
+    kw = {"use_kernel": False} if step == "decode" else {}  # (the paged kernel wants 128-lane K/V)
+    text = jax.jit(lambda p, k, v, *a: fn(p, cfg, k, v, *a, **kw), donate_argnums=(1, 2)).lower(
+        params, *pools, *args).compile().as_text()
+    # a pool-shaped copy or transpose in any layout but the one the pools are
+    # held in (row-major, (8, 128) tiles) is a re-layout; XLA's prefetch of
+    # so small a pool into its faster memory space (`S(1)`) keeps the layout
+    shapes = {",".join(map(str, sh)) for sh in (state, conv)}
+    held = {len(sh): ",".join(map(str, reversed(range(len(sh))))) for sh in (state, conv)}
+    moved = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\]\{([\d,]*):T\(8,128\)(?:S\(\d\))?\} "
+                     r"(copy|transpose)\(", line)
+        generic = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\]\S* (copy|transpose)\(", line)
+        if generic and generic.group(1) in shapes:
+            if not m or m.group(2) != held[generic.group(1).count(",") + 1] or generic.group(2) == "transpose":
+                moved.append(line.strip()[:160])
+    assert not moved, "\n".join(moved)
+    assert ("kda_update_kernel" in text) == (step == "mixed")

@@ -11,6 +11,10 @@ from dataclasses import dataclass, field
 from typing import Dict, Optional
 
 
+# Layer kinds of a hybrid stack whose sequence memory is a state slot.
+STATE_LAYER_KINDS = ("mamba", "kda")
+
+
 @dataclass(frozen=True)
 class ModelConfig:
     name: str
@@ -114,9 +118,11 @@ class ModelConfig:
     # state pool (ops/retention.py), not blocks that grow with the context.
     retention_degree: int = 0
     # A hybrid stack (models/granite.py): the mixer of every layer, in
-    # order, "mamba" (a Mamba-2 state-space mixer whose sequence memory is
-    # one slot of a state pool, ops/mamba.py) or "attention" (GQA over the
-    # paged K/V pool, which then holds the attention layers alone).
+    # order: a STATE-LAYER kind, whose sequence memory is one slot of a
+    # state pool ("mamba": a Mamba-2 state-space mixer, ops/mamba.py;
+    # "kda": a gated delta rule with a decay per channel, ops/kda.py), or
+    # "attention" (GQA over the paged K/V pool, which then holds the
+    # attention layers alone). A stack has one state-layer kind at most.
     # () = every layer attends. The pattern is data of the configuration.
     layer_types: tuple = ()
     mamba_d_state: int = 0
@@ -124,6 +130,20 @@ class ModelConfig:
     mamba_n_heads: int = 0
     mamba_d_head: int = 0
     mamba_n_groups: int = 1
+    # Kimi Delta Attention layers ("kda"): heads of one key and value
+    # width, a causal convolution of `kda_d_conv` taps over q, k and v,
+    # the decay's and the output gate's projections low-rank pairs of
+    # rank `kda_gate_rank`, beta in (0, 2) where
+    # `kda_neg_eigval` (the transition's eigenvalues may be negative)
+    # and in (0, 1) where not.
+    kda_n_heads: int = 0
+    kda_d_head: int = 0
+    kda_d_conv: int = 0
+    kda_gate_rank: int = 0
+    kda_neg_eigval: bool = True
+    # A hybrid stack's attention layers gate their output per lane,
+    # `W_o [sigmoid(W_gate x) * attn]`.
+    attn_gate: bool = False
     # Granite's four multipliers (HF GraniteMoeHybridConfig): the token
     # embedding is scaled by `embedding_multiplier`, attention scores by
     # `attention_multiplier` (0 = head_dim**-0.5), each block's addition
@@ -143,9 +163,22 @@ class ModelConfig:
         return bool(self.layer_types)
 
     @property
+    def state_layer_kind(self) -> str:
+        """The stack's layer kind with a state slot ("" = none)."""
+        kinds = [k for k in STATE_LAYER_KINDS if k in self.layer_types]
+        if len(kinds) > 1:
+            raise ValueError(f"layer_types holds two state-layer kinds: {kinds}")
+        return kinds[0] if kinds else ""
+
+    @property
+    def num_state_layers(self) -> int:
+        """Layers whose sequence memory is a slot of the state pool."""
+        return sum(self.layer_types.count(k) for k in STATE_LAYER_KINDS)
+
+    @property
     def has_state_pool(self) -> bool:
         """A sequence owns one fixed slot of a state pool for its life."""
-        return self.is_retention or "mamba" in self.layer_types
+        return self.is_retention or self.num_state_layers > 0
 
     @property
     def has_paged_cache(self) -> bool:
@@ -162,10 +195,6 @@ class ModelConfig:
         return 0 if self.is_retention else self.num_layers
 
     @property
-    def num_mamba_layers(self) -> int:
-        return self.layer_types.count("mamba")
-
-    @property
     def mamba_d_inner(self) -> int:
         return self.mamba_n_heads * self.mamba_d_head
 
@@ -173,6 +202,15 @@ class ModelConfig:
     def mamba_conv_dim(self) -> int:
         """Lanes of the convolution: x, B and C side by side."""
         return self.mamba_d_inner + 2 * self.mamba_n_groups * self.mamba_d_state
+
+    @property
+    def kda_d_inner(self) -> int:
+        return self.kda_n_heads * self.kda_d_head
+
+    @property
+    def kda_conv_dim(self) -> int:
+        """Lanes of the convolution: q, k and v side by side."""
+        return 3 * self.kda_d_inner
 
     @property
     def is_moe(self) -> bool:
@@ -250,10 +288,29 @@ def approx_param_count(cfg: ModelConfig) -> int:
 def _hybrid_mixer_params(cfg: ModelConfig) -> int:
     """Mixer matrices of a hybrid stack over all its layers."""
     E = cfg.hidden_size
-    d_in, conv = cfg.mamba_d_inner, cfg.mamba_conv_dim
-    mamba = E * (d_in + conv + cfg.mamba_n_heads) + d_in * E + conv * cfg.mamba_d_conv
     gqa = 2 * E * (cfg.num_heads + cfg.num_kv_heads) * cfg.head_dim
-    return cfg.num_mamba_layers * mamba + cfg.num_attention_layers * gqa
+    if cfg.attn_gate:
+        gqa += E * cfg.num_heads * cfg.head_dim
+    kind = cfg.state_layer_kind
+    state = _STATE_MIXER_PARAMS[kind](cfg) if kind else 0
+    return cfg.num_state_layers * state + cfg.num_attention_layers * gqa
+
+
+def _mamba_mixer_params(cfg: ModelConfig) -> int:
+    E, d_in, conv = cfg.hidden_size, cfg.mamba_d_inner, cfg.mamba_conv_dim
+    return E * (d_in + conv + cfg.mamba_n_heads) + d_in * E + conv * cfg.mamba_d_conv
+
+
+def _kda_mixer_params(cfg: ModelConfig) -> int:
+    E, d_in, r = cfg.hidden_size, cfg.kda_d_inner, cfg.kda_gate_rank
+    return (
+        4 * E * d_in + 2 * (E * r + r * d_in) + E * cfg.kda_n_heads
+        + cfg.kda_conv_dim * cfg.kda_d_conv
+    )
+
+
+# matrices of one state layer's mixer, by kind
+_STATE_MIXER_PARAMS = {"mamba": _mamba_mixer_params, "kda": _kda_mixer_params}
 
 
 _REGISTRY: Dict[str, ModelConfig] = {}
@@ -826,6 +883,71 @@ register(
         residual_multiplier=0.22,
         logits_scaling=16.0,
         max_position_embeddings=131072,
+    )
+)
+
+_SOLAR_PERIOD = ("attention", "kda", "kda", "kda")
+
+register(
+    # The other hybrid at test scale (models/granite.py): one period of a
+    # gated NoPE GQA layer and three KDA layers (4 heads of 16 x 16 state,
+    # a convolution of 4 taps, gate rank 8), 4 of 8 experts held beside one
+    # shared expert, an untied head, every multiplier 1.
+    ModelConfig(
+        name="solar-tiny",
+        vocab_size=512,
+        hidden_size=64,
+        intermediate_size=32,
+        num_layers=4,
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=16,
+        rms_norm_eps=1e-5,
+        num_experts=8,
+        experts_held=(0, 4),
+        num_experts_per_tok=2,
+        moe_intermediate_size=32,
+        n_shared_experts=1,
+        layer_types=_SOLAR_PERIOD,
+        kda_n_heads=4,
+        kda_d_head=16,
+        kda_d_conv=4,
+        kda_gate_rank=8,
+        attn_gate=True,
+        max_position_embeddings=4096,
+    )
+)
+
+register(
+    # https://huggingface.co/upstage/Solar-Open2-250B/blob/main/config.json
+    # (model_type solar_open2), 250B-A15B, as ONE CHIP'S SHARE of it at
+    # every published width: two periods of the layer pattern (8 of 48
+    # layers: 2 gated NoPE GQA, 6 KDA), experts 0-19 of the 320 a layer
+    # (top 8; the router stays 320 wide), vocabulary rows 0-24,575 of
+    # 196,608 (benchmarks/configs/solar-open2-250b.json has the
+    # deployment). Random weights only: runtime/weights.py has no loader.
+    ModelConfig(
+        name="solar-open2-250b",
+        vocab_size=24576,
+        hidden_size=4096,
+        intermediate_size=1280,
+        num_layers=8,
+        num_heads=64,
+        num_kv_heads=8,
+        head_dim=128,
+        rms_norm_eps=1e-5,
+        num_experts=320,
+        experts_held=(0, 20),
+        num_experts_per_tok=8,
+        moe_intermediate_size=1280,
+        n_shared_experts=1,
+        layer_types=_SOLAR_PERIOD * 2,
+        kda_n_heads=64,
+        kda_d_head=128,
+        kda_d_conv=4,
+        kda_gate_rank=128,
+        attn_gate=True,
+        max_position_embeddings=1048576,
     )
 )
 

@@ -1,16 +1,20 @@
-"""Granite 4.0-H family (HF `model_type: granitemoehybrid`): Mamba-2
-state-space layers and GQA layers in ONE stack, a top-k expert block
-beside a shared MLP in every layer, over the same runtime as the other
-families.
+"""The hybrid stack: state layers and GQA layers in ONE stack, a top-k
+expert block beside a shared MLP in every layer, over the same runtime as
+the other families. Two published families run on it, and the difference
+between them is DATA of the configuration (`layer_types`, the multipliers,
+the head): Granite 4.0-H (HF `model_type: granitemoehybrid`: Mamba-2 state
+layers, a tied head, four multipliers) and Solar-Open2 (`solar_open2`:
+KDA state layers, a gate on the GQA layers, an untied head, every
+multiplier 1).
 
 With `h` the residual stream, `rms` RMSNorm with a learned gain, and the
-four multipliers of the configuration:
+four multipliers of the configuration (each 1 by default):
 
     h0 = embedding_multiplier * E[token]
     every layer l:  h <- h + residual_multiplier * mixer_l(rms_1(h))
                     u  = rms_2(h)
                     h <- h + residual_multiplier * (moe(u) + shared(u))
-    logits = (rms_f(h_L) @ E^T) / logits_scaling          (tied head)
+    logits = (rms_f(h_L) @ head) / logits_scaling   (head = E^T where tied)
 
 `mixer_l` is what `cfg.layer_types[l]` names; the pattern is DATA.
 
@@ -20,9 +24,18 @@ four multipliers of the configuration:
   layout); gate THEN norm, `y = rms_g(y * silu(z))` over each group's
   d_inner / G lanes; out `= W_out y`. No projection bias, a convolution
   bias.
-* "attention": GQA, no bias, NO rotary (the family is NoPE by construction),
-  scores scaled by `attention_multiplier` (not head_dim**-0.5), causal,
-  full, over the paged K/V pool.
+* "kda": `q~, k~, v~ = W_q u, W_k u, W_v u`, side by side through the same
+  depthwise causal convolution and silu; per head `q = l2norm(q~) /
+  sqrt(d)`, `k = l2norm(k~)`; the decay per channel of the key `g =
+  -exp(A_log) softplus(W_f2 W_f1 u + dt_bias)`, `beta = 2 sigmoid(w_b u)`;
+  the gated delta rule over `(q, k, v, g, beta)` (ops/kda.py has it, in
+  its recurrent and its chunk form); out `= W_o [sigmoid(W_g2 W_g1 u) *
+  rms_o(o)]`, `rms_o` over a head's value lanes with one gain vector.
+* "attention": GQA, no bias, NO rotary (both families are NoPE by
+  construction), scores scaled by `attention_multiplier` (0 =
+  head_dim**-0.5), causal, full, over the paged K/V pool; with
+  `cfg.attn_gate` the output is gated per lane before `W_o`,
+  `W_o [sigmoid(W_gate u) * attn]`.
 * experts: `llama.moe_route` (softmax, top-k, renormalised: the softmax
   over the chosen logits) and the grouped product over the experts HELD
   (`cfg.experts_held`), the shared MLP always on (`llama._mlp_block`).
@@ -30,18 +43,24 @@ four multipliers of the configuration:
 **A sequence's two kinds of memory.** The carried caches are a pair of
 pairs, `k_caches = (K, S)` and `v_caches = (V, conv)`: K and V stacks
 `[La, N, Hkv, BS, D]` over the ATTENTION layers only, in paged blocks that
-grow with the context, and the SSM and convolution state pools over the
-MAMBA layers, one slot a sequence for its life (`state_shapes`). All four
-ride the carry of every segment's scan (llama.py `_scan_layers`' rule: no
-scan slices a pool in or stacks it out). A decode row's slot is its row
-index; a prefill row names its slot in the LAST column of its block table
-(slot + 1; 0 = a padding row), which the executor appends for a family
-that has both kinds (runtime/executor.py `slot_column`).
+grow with the context, and the state and convolution pools over the
+STATE layers (of either kind), one slot a sequence for its life
+(`state_shapes`). All four ride the carry of every segment's scan
+(llama.py `_scan_layers`' rule: no scan slices a pool in or stacks it
+out). A decode row's slot is its row index; a prefill row names its slot
+in the LAST column of its block table (slot + 1; 0 = a padding row),
+which the executor appends for a family that has both kinds
+(runtime/executor.py `slot_column`).
 
-Runs of equal layer kind are one scan each (`_segments`); the parameter
+Runs of equal layer kind are one scan each (`_segments`), and a pattern
+that repeats is one scan over its period (`_period`); the parameter
 tree keeps what every layer has under `layers` (norms, router, experts,
-shared MLP: L entries), the mixers under `mamba` (Lm entries) and `attn`
-(La entries), each scan indexing the stacks it needs.
+shared MLP: L entries) and the mixers under their kind's stack
+(`MIXER_STACKS`: `mamba`, `kda`, `attn`), each scan indexing the stacks it
+needs. There is ONE layer body (`_layer`) and ONE segment scan
+(`_run_layers`) for every kind and both families; what differs between
+the state-layer kinds is one row of `STATE_KINDS` each (mixer, pool
+shapes, kernel eligibility, parameter stack).
 
 Same step surface as llama.py. The mixed step runs ONE batch of
 R + P*Lpad token rows through every matmul (as models/deepseek.py), so a
@@ -50,7 +69,8 @@ touched expert streams once a step.
 
 from __future__ import annotations
 
-from typing import Dict, List, NamedTuple, Optional, Tuple
+import itertools
+from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -58,6 +78,7 @@ import jax.numpy as jnp
 from xllm_service_tpu.models import llama
 from xllm_service_tpu.models.configs import ModelConfig
 from xllm_service_tpu.obs.spans import region
+from xllm_service_tpu.ops import kda as kda_ops
 from xllm_service_tpu.ops import kv_write as kv_write_ops
 from xllm_service_tpu.ops import mamba as mamba_ops
 from xllm_service_tpu.ops import moe as moe_ops
@@ -72,10 +93,12 @@ from xllm_service_tpu.ops.quant import wdtype, wt
 Params = Dict
 
 NUM_CACHES = 2  # K and V (each paired with a state pool on the carry)
-QUANTIZABLE_WEIGHT_LEAVES = llama.QUANTIZABLE_WEIGHT_LEAVES + ("w_in", "w_out")
-MIXER_STACKS = {"mamba": "mamba", "attention": "attn"}
+QUANTIZABLE_WEIGHT_LEAVES = llama.QUANTIZABLE_WEIGHT_LEAVES + ("w_in", "w_out", "w_ogate")
+# a layer kind's stack of the parameter tree
+MIXER_STACKS = {"mamba": "mamba", "kda": "kda", "attention": "attn"}
 # the device region of a mixer's residual add (obs.spans.DEVICE_REGIONS)
-MIXER_REGIONS = {"mamba": "state_mixer", "attention": "attn_proj"}
+MIXER_REGIONS = {"mamba": "state_mixer", "kda": "state_mixer", "attention": "attn_proj"}
+L2_EPS = 1e-6  # under the root of KDA's q and k normalisation
 
 
 def cache_row_dims(cfg: ModelConfig) -> Tuple[int, int]:
@@ -84,15 +107,21 @@ def cache_row_dims(cfg: ModelConfig) -> Tuple[int, int]:
 
 
 def state_shapes(cfg: ModelConfig, slots: int):
-    """(SSM pool shape, convolution pool shape): ops/mamba.py's layout."""
-    return mamba_ops.state_shapes(
-        cfg.num_mamba_layers, slots, cfg.mamba_n_heads, cfg.mamba_d_head,
-        cfg.mamba_d_state, cfg.mamba_d_conv, cfg.mamba_conv_dim,
-    )
+    """(state pool shape, convolution pool shape) over the stack's state
+    layers, in their kind's layout (ops/mamba.py, ops/kda.py)."""
+    return STATE_KINDS[cfg.state_layer_kind].shapes(cfg, slots)
+
+
+def state_route(cfg: ModelConfig, state) -> str:
+    """Which route the decode update of `state` (the state pool) takes:
+    "<kind>-pallas" (the kind's update kernel) or "<kind>-xla"."""
+    kind = cfg.state_layer_kind
+    on_kernel = STATE_KINDS[kind].on_kernel(cfg, state)
+    return f"{kind}-{'pallas' if on_kernel else 'xla'}"
 
 
 class Segment(NamedTuple):
-    kind: str  # "mamba" | "attention"
+    kind: str  # one of MIXER_STACKS
     first: int  # the run's first layer, of all layers
     kind_first: int  # ... and of the layers of its kind
     n: int
@@ -100,10 +129,10 @@ class Segment(NamedTuple):
 
 def _segments(cfg: ModelConfig) -> List[Segment]:
     out: List[Segment] = []
-    seen = {"mamba": 0, "attention": 0}
+    seen = dict.fromkeys(MIXER_STACKS, 0)
     for l, kind in enumerate(cfg.layer_types):
         if kind not in seen:
-            raise ValueError(f"layer_types[{l}] = {kind!r}: 'mamba' or 'attention'")
+            raise ValueError(f"layer_types[{l}] = {kind!r}: one of {sorted(seen)}")
         if out and out[-1].kind == kind:
             out[-1] = out[-1]._replace(n=out[-1].n + 1)
         else:
@@ -112,25 +141,41 @@ def _segments(cfg: ModelConfig) -> List[Segment]:
     return out
 
 
+def _period(segs: List[Segment]) -> Tuple[List[Segment], int]:
+    """(the runs of one period, how often it repeats): the shortest
+    prefix of `segs` that the whole list repeats, kind by kind and length
+    by length (1: no repeat). A pattern that repeats is ONE scan over its
+    period, so a step program holds each kind's layer body once and not
+    once a repeat: that is its size in the compile cache and its seconds
+    to compile."""
+    shape = [(s.kind, s.n) for s in segs]
+    for m in range(1, len(segs) // 2 + 1):
+        if len(segs) % m == 0 and shape == shape[:m] * (len(segs) // m):
+            return segs[:m], len(segs) // m
+    return segs, 1
+
+
 def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
-    if len(cfg.layer_types) != cfg.num_layers or not cfg.tie_word_embeddings:
-        raise ValueError("granite: one layer type a layer and a tied head")
+    if len(cfg.layer_types) != cfg.num_layers:
+        raise ValueError("hybrid stack: one layer type a layer")
     if not cfg.is_moe or cfg.n_shared_experts <= 0:
-        raise ValueError("granite: every layer routes beside a shared MLP")
+        raise ValueError("hybrid stack: every layer routes beside a shared MLP")
     E, L = cfg.hidden_size, cfg.num_layers
-    Lm, La = cfg.num_mamba_layers, cfg.num_attention_layers
-    H, d_in, conv = cfg.mamba_n_heads, cfg.mamba_d_inner, cfg.mamba_conv_dim
+    Ls, La = cfg.num_state_layers, cfg.num_attention_layers
     Hq, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     X, Xh, Fm = cfg.num_experts, cfg.held_experts[1], cfg.moe_intermediate_size
     Fs = cfg.n_shared_experts * Fm
-    keys = iter(jax.random.split(key, 20))
+    # (the first twenty keys and their order are Granite's draw since PR 42)
+    keys = itertools.chain(
+        jax.random.split(key, 20), jax.random.split(jax.random.fold_in(key, 20), 20)
+    )
 
     def w(shape, fan_in):
         z = jax.random.normal(next(keys), shape, jnp.float32)
         return (z / jnp.sqrt(fan_in)).astype(dtype)
 
     ones = lambda shape: jnp.ones(shape, jnp.float32)
-    return {
+    params = {
         "embed": w((cfg.vocab_size, E), E),
         "final_norm": ones((E,)),
         "layers": {
@@ -141,25 +186,20 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
             "w_sh_gate": w((L, E, Fs), E), "w_sh_up": w((L, E, Fs), E),
             "w_sh_down": w((L, Fs, E), Fs),
         },
-        "mamba": {
-            "w_in": w((Lm, E, d_in + conv + H), E),
-            "conv_w": w((Lm, cfg.mamba_d_conv, conv), cfg.mamba_d_conv).astype(jnp.float32),
-            "conv_b": jnp.zeros((Lm, conv), jnp.float32),
-            # softplus(dt_bias) about 0.01-0.1 and A = -exp(A_log) in
-            # -1..-16 (Mamba-2's own init): slow decays
-            "dt_bias": jnp.full((Lm, H), -3.0, jnp.float32),
-            "A_log": jnp.broadcast_to(
-                jnp.log(jnp.linspace(1.0, 16.0, H, dtype=jnp.float32)), (Lm, H)
-            ),
-            "D": ones((Lm, H)),
-            "gate_norm": ones((Lm, d_in)),
-            "w_out": w((Lm, d_in, E), d_in),
-        },
-        "attn": {
-            "wq": w((La, E, Hq * D), E), "wk": w((La, E, Hkv * D), E),
-            "wv": w((La, E, Hkv * D), E), "wo": w((La, Hq * D, E), Hq * D),
-        },
     }
+    if cfg.state_layer_kind:
+        params[MIXER_STACKS[cfg.state_layer_kind]] = STATE_KINDS[cfg.state_layer_kind].init(
+            cfg, w, ones, Ls
+        )
+    params["attn"] = {
+        "wq": w((La, E, Hq * D), E), "wk": w((La, E, Hkv * D), E),
+        "wv": w((La, E, Hkv * D), E), "wo": w((La, Hq * D, E), Hq * D),
+    }
+    if cfg.attn_gate:
+        params["attn"]["w_ogate"] = w((La, E, Hq * D), E)
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = w((E, cfg.vocab_size), E)
+    return params
 
 
 def _wd(params: Params):
@@ -271,14 +311,99 @@ def _gated_out(lp, cfg: ModelConfig, y, z):
     return jnp.einsum("tf,fe->te", g.astype(w_out.dtype), w_out)
 
 
+def _kda_inputs(lp, cfg: ModelConfig, h):
+    """What a KDA layer projects from normed rows h [T, E]: (the
+    convolution's input [q~ | k~ | v~] [T, 3 H d], the log decay g
+    [T, H, d] <= 0, beta [T, H], the output gate [T, H, d]), float32.
+    Every product is fenced (llama._plain_product): its consumers are
+    head-batched."""
+    T = h.shape[0]
+    H, d = cfg.kda_n_heads, cfg.kda_d_head
+    f32 = jnp.float32
+
+    def through(*names):  # h through a leaf, or through a low-rank pair
+        y = h
+        for n in names:
+            y = llama._plain_product(jnp.einsum(
+                "te,ef->tf", y.astype(h.dtype), wt(lp[n]), preferred_element_type=f32
+            ))
+        return y
+
+    qkv = jnp.concatenate([through("wq"), through("wk"), through("wv")], axis=-1)
+    g = jax.nn.softplus(through("w_f1", "w_f2") + lp["dt_bias"]).reshape(T, H, d)
+    g = -jnp.exp(lp["A_log"].astype(f32))[:, None] * g
+    beta = (2.0 if cfg.kda_neg_eigval else 1.0) * jax.nn.sigmoid(through("w_beta"))
+    gate = jax.nn.sigmoid(through("w_g1", "w_g2")).reshape(T, H, d)
+    return qkv, g, beta, gate
+
+
+def _kda_heads(cfg: ModelConfig, c):
+    """The convolution's output [..., 3 H d] -> q (l2-normalised, scaled
+    by d**-0.5), k (l2-normalised), v, each [..., H, d]."""
+    H, d = cfg.kda_n_heads, cfg.kda_d_head
+    q, k, v = (c[..., i * H * d:(i + 1) * H * d].reshape(*c.shape[:-1], H, d) for i in range(3))
+    l2 = lambda x: x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + L2_EPS)
+    return l2(q) * d ** -0.5, l2(k), v
+
+
+@region("state_mixer")
+def _kda_out(lp, cfg: ModelConfig, o, gate):
+    """W_o [gate * rms_o(o)]: o, gate [T, H, d]."""
+    y = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + cfg.rms_norm_eps)
+    y = (gate * y * lp["o_norm"]).reshape(o.shape[0], -1)
+    wo = wt(lp["wo"])
+    return jnp.einsum("tf,fe->te", y.astype(wo.dtype), wo)
+
+
+@region("state_mixer")
+def _kda_mixer(lp, cfg: ModelConfig, h, m, S, conv, dec: Optional[_Dec],
+               pf: Optional[_Pf]):
+    """The KDA mixer over flat rows h [T, E] (decode rows first, then the
+    chunks' rows), KDA layer `m`: returns (out [T, E], S', conv')."""
+    H, d, Cd = cfg.kda_n_heads, cfg.kda_d_head, cfg.kda_conv_dim
+    qkv, g, beta, gate = _kda_inputs(lp, cfg, h)
+    R = dec.R if dec is not None else 0
+    os = []
+    if dec is not None:
+        c, conv = mamba_ops.conv_decode(conv, m, dec.active, qkv[:R], lp["conv_w"], lp["conv_b"])
+        o, S = kda_ops.decode_update(
+            S, m, dec.active, *_kda_heads(cfg, c), g[:R], beta[:R], use_kernel=dec.use_kernel
+        )
+        os.append(o)
+    if pf is not None:
+        c, conv = mamba_ops.conv_chunk(
+            conv, m, pf.slots, pf.start, pf.length,
+            qkv[R:].reshape(pf.P, pf.Lpad, Cd), lp["conv_w"], lp["conv_b"],
+        )
+        o, S = kda_ops.chunk_update(
+            S, m, pf.slots, pf.start, pf.length, *_kda_heads(cfg, c),
+            g[R:].reshape(pf.P, pf.Lpad, H, d), beta[R:].reshape(pf.P, pf.Lpad, H),
+        )
+        os.append(o.reshape(pf.P * pf.Lpad, H, d))
+    o = jnp.concatenate(os, axis=0) if len(os) > 1 else os[0]
+    return _kda_out(lp, cfg, o, gate), S, conv
+
+
 @region("attn_proj")
 def _qkv(lp, cfg: ModelConfig, h):
     """h [T, E] -> q [T, Hq, D], k, v [T, Hkv, D]: no bias, no rotary."""
     T = h.shape[0]
-    q = jnp.einsum("te,eh->th", h, wt(lp["wq"])).reshape(T, cfg.num_heads, cfg.head_dim)
-    k = jnp.einsum("te,eh->th", h, wt(lp["wk"])).reshape(T, cfg.num_kv_heads, cfg.head_dim)
-    v = jnp.einsum("te,eh->th", h, wt(lp["wv"])).reshape(T, cfg.num_kv_heads, cfg.head_dim)
+    fence = llama._plain_product  # the attention kernels and the K/V write take heads
+    q = fence(jnp.einsum("te,eh->th", h, wt(lp["wq"]))).reshape(T, cfg.num_heads, cfg.head_dim)
+    k = fence(jnp.einsum("te,eh->th", h, wt(lp["wk"]))).reshape(T, cfg.num_kv_heads, cfg.head_dim)
+    v = fence(jnp.einsum("te,eh->th", h, wt(lp["wv"]))).reshape(T, cfg.num_kv_heads, cfg.head_dim)
     return q, k, v
+
+
+def _gated(lp, cfg: ModelConfig, h, o):
+    """The attention output o [T, Hq D] through the layer's gate,
+    sigmoid(W_gate h) per lane, where the configuration has one."""
+    if not cfg.attn_gate:
+        return o
+    gate = llama._plain_product(jnp.einsum(
+        "te,eh->th", h, wt(lp["w_ogate"]), preferred_element_type=jnp.float32
+    ))
+    return jax.nn.sigmoid(gate) * o
 
 
 def _attn_mixer(lp, cfg: ModelConfig, h, a, K, V, dec: Optional[_Dec],
@@ -310,8 +435,84 @@ def _attn_mixer(lp, cfg: ModelConfig, h, a, K, V, dec: Optional[_Dec],
         )
         o = o.reshape(-1, *o.shape[2:])
     with region("attn_proj"):
-        flat = o.reshape(o.shape[0], -1).astype(h.dtype)
+        flat = _gated(lp, cfg, h, o.reshape(o.shape[0], -1)).astype(h.dtype)
         return jnp.einsum("th,he->te", flat, wt(lp["wo"])), K, V
+
+
+def _mamba_stack(cfg: ModelConfig, w, ones, Ls):
+    E = cfg.hidden_size
+    H, d_in, conv = cfg.mamba_n_heads, cfg.mamba_d_inner, cfg.mamba_conv_dim
+    return {
+        "w_in": w((Ls, E, d_in + conv + H), E),
+        "conv_w": w((Ls, cfg.mamba_d_conv, conv), cfg.mamba_d_conv).astype(jnp.float32),
+        "conv_b": jnp.zeros((Ls, conv), jnp.float32),
+        # softplus(dt_bias) about 0.01-0.1 and A = -exp(A_log) in
+        # -1..-16 (Mamba-2's own init): slow decays
+        "dt_bias": jnp.full((Ls, H), -3.0, jnp.float32),
+        "A_log": jnp.broadcast_to(
+            jnp.log(jnp.linspace(1.0, 16.0, H, dtype=jnp.float32)), (Ls, H)
+        ),
+        "D": ones((Ls, H)),
+        "gate_norm": ones((Ls, d_in)),
+        "w_out": w((Ls, d_in, E), d_in),
+    }
+
+
+def _kda_stack(cfg: ModelConfig, w, ones, Ls):
+    E = cfg.hidden_size
+    H, d, d_in, conv = cfg.kda_n_heads, cfg.kda_d_head, cfg.kda_d_inner, cfg.kda_conv_dim
+    r = cfg.kda_gate_rank
+    if r <= 0:
+        raise ValueError("kda: the decay's and the gate's projections are low-rank pairs, "
+                         "kda_gate_rank > 0")
+    return {
+        "wq": w((Ls, E, d_in), E), "wk": w((Ls, E, d_in), E), "wv": w((Ls, E, d_in), E),
+        "conv_w": w((Ls, cfg.kda_d_conv, conv), cfg.kda_d_conv).astype(jnp.float32),
+        "conv_b": jnp.zeros((Ls, conv), jnp.float32),
+        "w_f1": w((Ls, E, r), E), "w_f2": w((Ls, r, d_in), r),
+        # per-token decays exp(-exp(A_log) softplus(dt_bias)) of
+        # about 0.99 to 0.9 a channel: a memory of tens of tokens
+        "dt_bias": jnp.full((Ls, d_in), -3.0, jnp.float32),
+        "A_log": jnp.broadcast_to(
+            jnp.log(jnp.linspace(0.2, 2.0, H, dtype=jnp.float32)), (Ls, H)
+        ),
+        "w_beta": w((Ls, E, H), E),
+        "w_g1": w((Ls, E, r), E), "w_g2": w((Ls, r, d_in), r),
+        "o_norm": ones((Ls, d)),
+        "wo": w((Ls, d_in, E), d_in),
+    }
+
+
+class StateKind(NamedTuple):
+    """What the stack asks of a layer kind with a state slot."""
+
+    # (lp, cfg, rows, layer of its kind, state pool, convolution pool,
+    # decode half, prefill half) -> (out, S', conv')
+    mixer: Callable
+    shapes: Callable  # (cfg, slots) -> (state pool shape, convolution pool shape)
+    on_kernel: Callable  # (cfg, state pool) -> the decode update takes the kind's kernel
+    init: Callable  # (cfg, w, ones, layers of the kind) -> the kind's parameter stack
+
+
+STATE_KINDS = {
+    "mamba": StateKind(
+        _mamba_mixer,
+        lambda cfg, slots: mamba_ops.state_shapes(
+            cfg.num_state_layers, slots, cfg.mamba_n_heads, cfg.mamba_d_head,
+            cfg.mamba_d_state, cfg.mamba_d_conv, cfg.mamba_conv_dim,
+        ),
+        lambda cfg, state: mamba_ops.kernel_eligible(state, cfg.mamba_n_groups),
+        _mamba_stack,
+    ),
+    "kda": StateKind(
+        _kda_mixer,
+        lambda cfg, slots: kda_ops.state_shapes(
+            cfg.num_state_layers, slots, cfg.kda_n_heads, cfg.kda_d_head, cfg.kda_d_conv
+        ),
+        lambda cfg, state: kda_ops.kernel_eligible(state),
+        _kda_stack,
+    ),
+}
 
 
 def _layer(lp, cfg: ModelConfig, x, valid, kind, mix, caches):
@@ -335,10 +536,10 @@ def _run_layers(params, cfg: ModelConfig, x, k_caches, v_caches, valid,
     def mixer(kind, lp, i):
         def mix(h, caches):
             (K, S), (V, conv) = caches
-            if kind == "mamba":
-                y, S, conv = _mamba_mixer(lp, cfg, h, i, S, conv, dec, pf)
-            else:
+            if kind == "attention":
                 y, K, V = _attn_mixer(lp, cfg, h, i, K, V, dec, pf, use_ragged, interpret)
+            else:
+                y, S, conv = STATE_KINDS[kind].mixer(lp, cfg, h, i, S, conv, dec, pf)
             return y, ((K, S), (V, conv))
 
         return mix
@@ -358,25 +559,47 @@ def _run_layers(params, cfg: ModelConfig, x, k_caches, v_caches, valid,
             lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False), tree
         )
 
-    carry = (x, k_caches, v_caches)
-    for seg in _segments(cfg):
+    def run_segment(carry, seg: Segment, first, kind_first):
+        """One scan over a run's layers: `first` the run's first layer of
+        all layers, `kind_first` of the layers of its kind."""
         stack = params[MIXER_STACKS[seg.kind]]
 
-        def body(carry, i, seg=seg, stack=stack):
+        def body(carry, i):
             x, kc, vc = carry
-            lp = {**at(common, seg.first + i), **at(stack, seg.kind_first + i)}
+            lp = {**at(common, first + i), **at(stack, kind_first + i)}
             if experts is not None:
-                lp["experts"] = (experts, seg.first + i)
+                lp["experts"] = (experts, first + i)
             with moe_ops.layer_stats() as stats:
                 x, (kc, vc) = _layer(
                     lp, cfg, x, valid, seg.kind,
-                    mixer(seg.kind, lp, seg.kind_first + i), (kc, vc),
+                    mixer(seg.kind, lp, kind_first + i), (kc, vc),
                 )
             return (x, kc, vc), stats.total()
 
         with region("stack_slice"):  # as llama._scan_layers
-            carry, counts = jax.lax.scan(body, carry, jnp.arange(seg.n, dtype=jnp.int32))
-        moe_ops.add_step(counts)
+            return jax.lax.scan(body, carry, jnp.arange(seg.n, dtype=jnp.int32))
+
+    period, reps = _period(_segments(cfg))
+    of_kind = {kind: sum(s.n for s in period if s.kind == kind) for kind in MIXER_STACKS}
+    layers = sum(of_kind.values())
+
+    def run_period(carry, p):  # p: which repeat of the period (0 where none)
+        counts = []
+        for seg in period:
+            carry, c = run_segment(
+                carry, seg, p * layers + seg.first, p * of_kind[seg.kind] + seg.kind_first
+            )
+            counts.append(c)
+        return carry, counts
+
+    carry = (x, k_caches, v_caches)
+    if reps == 1:
+        carry, counts = run_period(carry, 0)
+    else:
+        with region("stack_slice"):
+            carry, counts = jax.lax.scan(run_period, carry, jnp.arange(reps, dtype=jnp.int32))
+    for c in counts:  # [n, 2X] a run, [reps, n, 2X] where the period repeats
+        moe_ops.add_step(None if c is None else c.reshape(-1, c.shape[-1]))
     return carry
 
 
@@ -479,7 +702,8 @@ def mixed_step(
 
 def hidden_dense(params: Params, cfg: ModelConfig, token_ids, rows_valid=None):
     """Final-norm hidden states [B, L, E] of a plain causal forward: the
-    scan as ONE chunk from an empty state, materialised attention, the
+    Mamba-2 scan as ONE chunk from an empty state, the delta rule as its
+    token-by-token recurrence, materialised attention, the
     all-experts combine (llama._mlp): the oracle of the step programs.
     No pool, no cache, no kernel."""
     B, L = token_ids.shape
@@ -501,20 +725,27 @@ def hidden_dense(params: Params, cfg: ModelConfig, token_ids, rows_valid=None):
         )
         return _gated_out(lp, cfg, y.reshape(L, d_in), z)
 
+    def kda(lp, h):  # the recurrence, token by token
+        qkv, g, beta, gate = _kda_inputs(lp, cfg, h)
+        c = mamba_ops.conv_dense(qkv, lp["conv_w"], lp["conv_b"])
+        o, _ = kda_ops.recurrent_form(*_kda_heads(cfg, c), g, beta)
+        return _kda_out(lp, cfg, o, gate)
+
     def attention(lp, h):
         q, k, v = (t.astype(f32) for t in _qkv(lp, cfg, h))
         g = cfg.num_heads // cfg.num_kv_heads
         s = jnp.einsum("qhgd,khd->hgqk", q.reshape(L, -1, g, cfg.head_dim), k) * _scale(cfg)
         p = jax.nn.softmax(jnp.where(causal[None, None], s, -1e30), axis=-1)
         o = jnp.einsum("hgqk,khd->qhgd", p, v).reshape(L, -1)
-        return jnp.einsum("th,he->te", o.astype(h.dtype), wt(lp["wo"]))
+        return jnp.einsum("th,he->te", _gated(lp, cfg, h, o).astype(h.dtype), wt(lp["wo"]))
 
-    li = {"mamba": 0, "attention": 0}
+    mixers = {"mamba": mamba, "kda": kda, "attention": attention}
+    li = dict.fromkeys(mixers, 0)
     for l, kind in enumerate(cfg.layer_types):
         lp = {k: v[l] for k, v in params["layers"].items()}
         lp.update({k: v[li[kind]] for k, v in params[MIXER_STACKS[kind]].items()})
         li[kind] += 1
-        mix = mamba if kind == "mamba" else attention
+        mix = mixers[kind]
         h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
         x = _add(cfg, x, jax.vmap(lambda hx: mix(lp, hx))(h))
         u = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)

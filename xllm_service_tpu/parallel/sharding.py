@@ -45,7 +45,7 @@ def param_shardings(
 
     tp = tp_axis if tp_axis in mesh.shape else None
     if cfg.is_hybrid:
-        return _hybrid_param_shardings(ns)
+        return _hybrid_param_shardings(cfg, ns)
     layers: Dict[str, Any] = {
         "attn_norm": ns(None, None),
         "mlp_norm": ns(None, None),
@@ -161,15 +161,26 @@ def param_shardings(
     return out
 
 
-def _hybrid_param_shardings(ns) -> Dict[str, Any]:
-    """models/granite.py's tree, every leaf replicated: the family is
-    served at tp_size 1 only (its state pool is not sharded;
+def _hybrid_param_shardings(cfg: ModelConfig, ns) -> Dict[str, Any]:
+    """models/granite.py's tree, every leaf replicated: the hybrid stack
+    is served at tp_size 1 only (its state pool is not sharded;
     runtime/executor.py refuses the rest by name)."""
 
     def rep(names, ndim):
         return {k: ns(*(None,) * ndim) for k in names}
 
-    return {
+    state_stacks = {
+        "mamba": {
+            **rep(("conv_b", "dt_bias", "A_log", "D", "gate_norm"), 2),
+            **rep(("w_in", "conv_w", "w_out"), 3),
+        },
+        "kda": {
+            **rep(("conv_b", "dt_bias", "A_log", "o_norm"), 2),
+            **rep(("wq", "wk", "wv", "wo", "conv_w", "w_f1", "w_f2", "w_beta",
+                   "w_g1", "w_g2"), 3),
+        },
+    }
+    tree = {
         "embed": ns(None, None),
         "final_norm": ns(None),
         "layers": {
@@ -177,12 +188,13 @@ def _hybrid_param_shardings(ns) -> Dict[str, Any]:
             **rep(("router", "w_sh_gate", "w_sh_up", "w_sh_down"), 3),
             **rep(("w_gate", "w_up", "w_down"), 4),
         },
-        "mamba": {
-            **rep(("conv_b", "dt_bias", "A_log", "D", "gate_norm"), 2),
-            **rep(("w_in", "conv_w", "w_out"), 3),
-        },
-        "attn": rep(("wq", "wk", "wv", "wo"), 3),
+        "attn": rep(("wq", "wk", "wv", "wo") + (("w_ogate",) if cfg.attn_gate else ()), 3),
     }
+    if cfg.state_layer_kind:
+        tree[cfg.state_layer_kind] = state_stacks[cfg.state_layer_kind]
+    if not cfg.tie_word_embeddings:
+        tree["lm_head"] = ns(None, None)
+    return tree
 
 
 def kv_cache_sharding(mesh: Mesh) -> NamedSharding:

@@ -888,7 +888,8 @@ class ModelExecutor:
                 f"weight_dtype=int{bits}: model family "
                 f"{self.model_mod.__name__} has no quantizable-leaf map"
             )
-        for stack in ("layers", "dense_layers", "mamba", "attn"):
+        mixers = tuple(getattr(self.model_mod, "MIXER_STACKS", {}).values())
+        for stack in ("layers", "dense_layers") + mixers:
             if stack not in self.params:
                 continue
             for name in names:
@@ -2151,18 +2152,12 @@ class ModelExecutor:
             )
             return {"decode": route, "prefill": route, "mixed": route}
         if self.cfg.is_hybrid:
-            from xllm_service_tpu.ops import mamba as mamba_ops
             from xllm_service_tpu.ops.attention import resolved_kernel_report
 
             rep = resolved_kernel_report(
                 self._paged(self.k_cache), self.cfg.head_dim, shards=1
             )
-            ssm = self.k_cache[1]
-            rep["state"] = (
-                "mamba-pallas"
-                if mamba_ops.kernel_eligible(ssm, self.cfg.mamba_n_groups)
-                else "mamba-xla"
-            )
+            rep["state"] = self.model_mod.state_route(self.cfg, self.k_cache[1])
             return self._add_moe_report(rep)
         if self.cfg.is_mla:
             from xllm_service_tpu.ops.attention import (
