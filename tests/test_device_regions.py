@@ -16,6 +16,7 @@ Three layers of tests:
 
 import dataclasses
 import os
+import re
 import subprocess
 import sys
 import threading
@@ -372,7 +373,7 @@ def _family_case(family: str, one_chip):
     return cfg, mod, jax.tree.map(lambda a: s(a.shape, a.dtype), params), caches, s
 
 
-def _compile_step(family: str, step: str, one_chip) -> str:
+def _compile_step(family: str, step: str, one_chip, R: int = R) -> str:
     """The optimized text of the EXECUTOR's decode or mixed step program
     (the model's step, the sampler and what the program does around
     them), for the described chip."""
@@ -453,3 +454,41 @@ def test_every_compiled_op_of_a_step_program_has_a_region(
     # the weights' matmuls are where the floors of PERF.md hold them
     named = Counter(got.values())
     assert named["ffn"] and named["attn_proj"] and named["head"] and named["sample"]
+
+
+def test_the_sampler_writes_no_array_of_the_batchs_rows_by_vocabulary(
+    one_chip, no_persistent_cache, as_on_tpu
+):
+    """ops/sampling.py's work follows the rows (ISSUE 48): in the decode
+    step program no op of the `sample` region PRODUCES a float32 [R, V]
+    array (the scaled logits written out as a conditional's operand and
+    the pass-through branch's copy of them were 0.43 ms of a 128-slot
+    step), and no conditional anywhere hands one back. The logits
+    themselves are the `head` region's, and the loops' and branches'
+    tuples only pass them on by reference. R = 40 here: wider than the
+    sampler's widest block, so a block is not mistaken for the batch."""
+    from xllm_service_tpu.models.configs import get_model_config
+    from xllm_service_tpu.ops import sampling
+
+    rows = 40
+    assert rows > sampling.BLOCK_ROWS
+    text = _compile_step("llama", "decode", one_chip, R=rows)
+    whole = ("f32", f"{rows},{get_model_config('llama3-3b').vocab_size}")
+    got = regions.parse_regions(text)
+    comps, entry = regions._computations(text)
+    plumbing = ("tuple", "get-tuple-element", "parameter", "while", "bitcast")
+    sample_ops, writers, handed_back = 0, [], []
+    for body in regions._executed(comps, entry):
+        for ins in body.values():
+            # every (dtype, dims) of the result, a tuple's elements too
+            is_whole = whole in re.findall(r"\b(\w+)\[([\d,]*)\]", ins.key.split(" ", 1)[1])
+            if ins.opcode == "conditional" and is_whole:
+                handed_back.append(ins.key)
+            if got.get(ins.key) != "sample":
+                continue
+            sample_ops += 1
+            if ins.opcode not in plumbing and is_whole:
+                writers.append(ins.key)
+    assert sample_ops > 10
+    assert not writers, writers
+    assert not handed_back, handed_back

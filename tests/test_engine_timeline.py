@@ -255,6 +255,38 @@ def test_prefill_counts_are_prompt_tokens_less_cached_ones(mixed):
     assert m["xllm_engine_prefill_chunks_total"] == 3 + 1
 
 
+@pytest.mark.parametrize("mixed", [False, True], ids=["split", "mixed"])
+def test_sample_rows_count_slots_live_and_drawn_rows(mixed):
+    """xllm_engine_sample_rows_total{kind}: what the sampler had to do,
+    booked once a dispatched step from the arrays the pack is built
+    from. Three requests of four slots, one of them greedy."""
+    eng = _mk(enable_mixed_step=mixed)
+    reqs = _requests(3, 20, 6)
+    for i, (req, _) in enumerate(reqs):
+        if i:
+            req.sampling = SamplingParams(
+                temperature=0.8, seed=i, max_new_tokens=6
+            )
+        eng.add_request(req)
+    for _ in range(200):
+        if not eng.has_work():
+            break
+        eng.step()
+    assert all(c.done.is_set() for _, c in reqs)
+    m = _series(eng)
+    rows = {
+        k: m[f'xllm_engine_sample_rows_total{{kind="{k}"}}']
+        for k in ("slots", "live", "drawn")
+    }
+    steps = m["xllm_engine_decode_steps_total"]
+    assert rows["slots"] == 4 * steps
+    assert rows["live"] == m["xllm_engine_decode_batch_size_sum"]
+    # every decode row of the two drawing requests, none of the greedy one
+    assert 0 < rows["drawn"] < rows["live"] < rows["slots"]
+    greedy_rows = len(reqs[0][1].tokens) - 1  # its first token is prefill's
+    assert rows["live"] - rows["drawn"] == greedy_rows
+
+
 def test_profiler_records_the_annotations_as_leaves(tmp_path):
     """A real profiler session on the CPU backend: the host plane holds the
     engine's and the executor's annotations, on the profiler's clock, and

@@ -1,7 +1,9 @@
 """Sampling op tests: filtering semantics + determinism."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from xllm_service_tpu.ops import sampling
 
@@ -18,16 +20,24 @@ def _sample(logits, temp, top_k, top_p, seeds, step=0):
     )
 
 
+def _log_softmax(logits):
+    x = np.asarray(logits, np.float64)
+    x = x - x.max(-1, keepdims=True)
+    return x - np.log(np.exp(x).sum(-1, keepdims=True))
+
+
 def test_greedy_picks_argmax():
     rng = np.random.RandomState(0)
     logits = rng.randn(4, 64).astype(np.float32)
-    ids, lp, full = _sample(logits, [0.0] * 4, [0] * 4, [1.0] * 4, [1, 2, 3, 4])
+    ids, lp, _ = _sample(logits, [0.0] * 4, [0] * 4, [1.0] * 4, [1, 2, 3, 4])
     np.testing.assert_array_equal(np.asarray(ids), logits.argmax(-1))
     # Chosen logprob == log_softmax at chosen index.
     np.testing.assert_allclose(
         np.asarray(lp),
-        np.take_along_axis(np.asarray(full), logits.argmax(-1)[:, None], 1)[:, 0],
-        rtol=1e-6,
+        np.take_along_axis(
+            _log_softmax(logits), logits.argmax(-1)[:, None], 1
+        )[:, 0],
+        rtol=1e-5,
     )
 
 
@@ -111,3 +121,250 @@ def test_min_p_parses_from_body():
     sp = sampling_from_body({"min_p": 0.25}, EngineConfig())
     assert sp.min_p == 0.25
     assert sampling_from_body({}, EngineConfig()).min_p == 0.0
+
+
+# ---------------------------------------------------------------------------
+# The sampler's work follows the rows that need it (ISSUE 48): every live
+# row's token is the one a plain per-row reference gives, bit for bit,
+# whatever the other rows of the batch are.
+
+
+def _reference_rows(
+    logits, temp, top_k, top_p, min_p, keys, active,
+    counts=None, presence=None, frequency=None,
+    bias_ids=None, bias_vals=None, allowed=None,
+):
+    """One row at a time, each from its own key: bias, penalties, mask,
+    then argmax (greedy) or scale, the row's own filter and
+    `jax.random.categorical`. A dead row is (0, 0.0)."""
+    R, V = logits.shape
+    toks = np.zeros((R,), np.int32)
+    lps = np.zeros((R,), np.float32)
+    for r in range(R):
+        if not active[r]:
+            continue
+        x = jnp.asarray(logits[r], jnp.float32)
+        if bias_ids is not None:
+            x = x.at[jnp.asarray(bias_ids[r])].add(jnp.asarray(bias_vals[r]))
+        if counts is not None:
+            c = jnp.asarray(counts[r])
+            x = (
+                x
+                - jnp.float32(presence[r]) * (c > 0).astype(jnp.float32)
+                - jnp.float32(frequency[r]) * c.astype(jnp.float32)
+            )
+        if allowed is not None:
+            x = jnp.where(jnp.asarray(allowed[r]), x, sampling.NEG_INF)
+        if temp[r] > 0:
+            row = (x / jnp.float32(temp[r]))[None]
+            if (0 < top_k[r] < V) or top_p[r] < 1.0 or min_p[r] > 0:
+                row = sampling.apply_top_k_top_p(
+                    row,
+                    jnp.asarray([top_k[r]], jnp.int32),
+                    jnp.asarray([top_p[r]], jnp.float32),
+                    jnp.asarray([min_p[r]], jnp.float32),
+                )
+            tok = int(
+                jax.random.categorical(
+                    jax.random.wrap_key_data(keys[r]), row[0]
+                )
+            )
+        else:
+            tok = int(jnp.argmax(x))
+        toks[r] = tok
+        lps[r] = _log_softmax(np.asarray(x))[tok]
+    return toks, lps
+
+
+def _mix(rng, R, V):
+    """A random batch: dead rows, greedy rows, drawing rows with and
+    without each filter."""
+    return dict(
+        logits=(rng.randn(R, V) * 3).astype(np.float32),
+        active=rng.rand(R) < 0.7,
+        temp=np.where(rng.rand(R) < 0.4, 0.0, rng.uniform(0.5, 1.5, R))
+        .astype(np.float32),
+        top_k=np.where(rng.rand(R) < 0.3, rng.randint(1, 20, R), 0)
+        .astype(np.int32),
+        top_p=np.where(rng.rand(R) < 0.3, rng.uniform(0.3, 0.95, R), 1.0)
+        .astype(np.float32),
+        min_p=np.where(rng.rand(R) < 0.2, rng.uniform(0.01, 0.2, R), 0.0)
+        .astype(np.float32),
+        seeds=rng.randint(0, 2**31, R).astype(np.uint32),
+    )
+
+
+def _run(m, step=3, rows=None, **extra):
+    """sample_tokens over the mix `m` (or over its rows `rows`)."""
+    take = (lambda a: a) if rows is None else (lambda a: a[rows])
+    keys = sampling.make_step_keys(
+        jnp.asarray(take(m["seeds"])), jnp.int32(step)
+    )
+    tok, lp, _ = jax.jit(sampling.sample_tokens)(
+        jnp.asarray(take(m["logits"])), jnp.asarray(take(m["temp"])),
+        jnp.asarray(take(m["top_k"])), jnp.asarray(take(m["top_p"])),
+        keys, min_p=jnp.asarray(take(m["min_p"])),
+        active=jnp.asarray(take(m["active"])),
+        **{k: jnp.asarray(take(v)) for k, v in extra.items()},
+    )
+    return np.asarray(tok), np.asarray(lp), np.asarray(keys)
+
+
+def _check(m, **extra):
+    tok, lp, keys = _run(m, **extra)
+    want_tok, want_lp = _reference_rows(
+        m["logits"], m["temp"], m["top_k"], m["top_p"], m["min_p"], keys,
+        m["active"], **extra,
+    )
+    np.testing.assert_array_equal(tok, want_tok)
+    np.testing.assert_allclose(lp, want_lp, atol=1e-5)
+    return tok, lp
+
+
+@pytest.mark.parametrize("R", [32, 96], ids=["one-block", "three-blocks"])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_every_live_row_matches_the_per_row_reference(seed, R):
+    rng = np.random.RandomState(100 + seed)
+    m = _mix(rng, R, 257)
+    tok, lp = _check(m)
+    # dead rows: token 0, logprob 0.0
+    assert not tok[~m["active"]].any() and not lp[~m["active"]].any()
+
+
+def test_a_subset_of_rows_gives_the_subsets_tokens():
+    rng = np.random.RandomState(7)
+    m = _mix(rng, 32, 193)
+    tok, lp, _ = _run(m)
+    rows = np.array([30, 2, 3, 17, 9, 31, 0, 11, 12, 25, 5])
+    sub_tok, sub_lp, _ = _run(m, rows=rows)
+    np.testing.assert_array_equal(sub_tok, tok[rows])
+    np.testing.assert_allclose(sub_lp, lp[rows], atol=1e-5)
+
+
+@pytest.mark.parametrize(
+    "n_draw", [0, 1, 8, 11, 19, 32], ids=lambda n: f"{n}-of-32-draw"
+)
+def test_any_count_of_drawing_rows(n_draw):
+    """No row draws, one does, all do, and counts that are no multiple
+    of eight: the drawing rows scattered over the slots, the rest
+    greedy."""
+    rng = np.random.RandomState(n_draw)
+    R, V = 32, 131
+    m = _mix(rng, R, V)
+    m["active"] = np.ones(R, bool)
+    m["temp"] = np.zeros(R, np.float32)
+    m["temp"][rng.permutation(R)[:n_draw]] = 0.8
+    tok, _ = _check(m)
+    greedy = m["temp"] <= 0
+    np.testing.assert_array_equal(
+        tok[greedy], m["logits"].argmax(-1)[greedy]
+    )
+
+
+@pytest.mark.parametrize("R", [1, 3, 8, 13, 40, 70])
+def test_any_batch_width(R):
+    """Batches narrower than a block, and ones whose last block starts
+    before the one ahead of it ends (its rows are met twice)."""
+    _check(_mix(np.random.RandomState(R), R, 97))
+
+
+@pytest.mark.parametrize(
+    "live_blocks", [(0,), (2,), (0, 2), (1, 2)], ids=str
+)
+def test_blocks_of_slots_with_no_live_row_are_left_alone(live_blocks):
+    """Three blocks of slots, live rows (drawing and greedy) in some of
+    them only: the others' rows come back as dead rows do."""
+    rng = np.random.RandomState(sum(live_blocks))
+    B = sampling.BLOCK_ROWS
+    m = _mix(rng, 3 * B, 89)
+    inside = np.zeros(3 * B, bool)
+    for b in live_blocks:
+        inside[b * B:(b + 1) * B] = True
+    m["active"] &= inside
+    assert m["active"].any()
+    tok, lp = _check(m)
+    assert not tok[~inside].any() and not lp[~inside].any()
+
+
+@pytest.mark.parametrize("draws", [False, True], ids=["greedy", "drawn"])
+def test_bias_penalties_and_mask_in_the_last_partly_filled_block(draws):
+    """40 slots: the last block starts at slot 8 and only slots 32-39
+    are its own. A row there carries a bias that would force a token, a
+    penalty that takes its greedy choice away and a mask that forbids
+    both: bias, penalties, then the mask, which nothing resurrects.
+    Eleven rows carry a penalty at all; the others come through the
+    counts bit for bit."""
+    rng = np.random.RandomState(5)
+    R, V, K = 40, 101, 4
+    m = _mix(rng, R, V)
+    m["active"] = np.ones(R, bool)
+    m["top_k"][:] = 0
+    m["top_p"][:] = 1.0
+    m["min_p"][:] = 0.0
+    m["temp"] = np.full(R, 0.9 if draws else 0.0, np.float32)
+    row = 37
+    penalized = np.append(rng.permutation(R - 8)[:10], row)
+    presence = np.zeros(R, np.float32)
+    frequency = np.zeros(R, np.float32)
+    presence[penalized] = 0.5
+    frequency[row] = 50.0
+    counts = rng.randint(0, 2, (R, V)).astype(np.int32)
+    first = int(m["logits"][row].argmax())
+    counts[row, first] = 3
+    bias_ids = np.zeros((R, K), np.int32)
+    bias_vals = np.zeros((R, K), np.float32)
+    forced = (first + 1) % V
+    bias_ids[row, 0], bias_vals[row, 0] = forced, 100.0
+    allowed = np.ones((R, V), bool)
+    allowed[row, forced] = False
+    allowed[row, first] = False
+    tok, _ = _check(
+        m, counts=counts, presence=presence, frequency=frequency,
+        bias_ids=bias_ids, bias_vals=bias_vals, allowed=allowed,
+    )
+    assert tok[row] not in (forced, first)
+
+
+def test_dead_rows_return_zero():
+    rng = np.random.RandomState(9)
+    m = _mix(rng, 16, 64)
+    m["active"] = np.zeros(16, bool)
+    tok, lp, _ = _run(m)
+    assert not tok.any() and not lp.any()
+
+
+def test_a_second_program_traces_no_block_of_the_sampler_again(monkeypatch):
+    """The sampler is in every step program and a warm process still
+    traces and lowers each one (`setup_s`): what a block does with its
+    altered logits is a jit of its own, so the second program that holds
+    the sampler at the same shapes runs none of that Python again. Shapes
+    no other test uses, so the first program does trace."""
+    calls = []
+    real = sampling._altered
+
+    def counted(*args):
+        calls.append(1)
+        return real(*args)
+
+    monkeypatch.setattr(sampling, "_altered", counted)
+    R, V = 37, 211
+    s = jax.ShapeDtypeStruct
+    sig = (
+        s((R, V), jnp.float32), s((R,), jnp.float32), s((R,), jnp.int32),
+        s((R,), jnp.float32), s((R, 2), jnp.uint32), s((R, V), jnp.int32),
+        s((R,), jnp.float32), s((R,), jnp.float32), s((R,), jnp.bool_),
+    )
+
+    def program():
+        def step(logits, temp, top_k, top_p, keys, counts, pres, freq, live):
+            return sampling.sample_tokens(
+                logits, temp, top_k, top_p, keys, counts=counts,
+                presence=pres, frequency=freq, active=live,
+            )[:2]
+
+        jax.jit(step).lower(*sig)
+        return len(calls)
+
+    first = program()
+    assert first > 0
+    assert program() == first

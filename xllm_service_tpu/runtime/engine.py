@@ -670,6 +670,21 @@ class InferenceEngine:
             "xllm_engine_decode_steps_total", "Decode (or verify) steps "
             "executed",
         )
+        # What the sampler had to do this step (ops/sampling.py: its work
+        # follows the rows): the decode half's slots, the live ones of
+        # them, and the live ones that draw (temperature > 0); drawn /
+        # slots is the share of a whole-batch draw that is still made.
+        sample_rows = self.metrics.counter(
+            "xllm_engine_sample_rows_total",
+            "Decode rows handed to the sampler, by kind: slots (every "
+            "row of the fixed batch), live (dispatched rows), drawn "
+            "(live rows with temperature > 0)",
+            labelnames=("kind",),
+        )
+        self._sample_rows_inc = tuple(
+            sample_rows.labels(kind=k).inc
+            for k in ("slots", "live", "drawn")
+        )
         self.metrics.counter(
             "xllm_engine_dispatch_h2d_total",
             "Host->device puts made by the executor's dispatch entry "
@@ -1590,6 +1605,10 @@ class InferenceEngine:
         self._fresh[can] = False
         self._observe_batch(nactive)
         self._m_steps.inc()
+        slots, live, drawn = self._sample_rows_inc
+        slots(self.R)
+        live(nactive)
+        drawn(int(np.count_nonzero(can & (self._ps_temps > 0))))
         self.decode_dispatches += 1
         self.collective_overlap_steps += self._overlap_collectives
         if n_pf:

@@ -1133,7 +1133,7 @@ class ModelExecutor:
             allowed=(
                 guided_table[mask_rows] if mask_rows is not None else None
             ),
-            min_p=min_p,
+            min_p=min_p, active=active,
         )
         with obs_spans.region("sample"):
             counts = counts.at[
@@ -2266,6 +2266,7 @@ class ModelExecutor:
             allowed=(
                 guided_table[mask_rows] if mask_rows is not None else None
             ),
+            active=active,
         )
         with obs_spans.region("sample"):
             counts = counts.at[
