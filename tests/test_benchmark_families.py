@@ -30,8 +30,9 @@ def configurations():
 
 
 def test_the_benchmark_has_both_families():
-    # five since PR 46 (the name stays: the driver counts tests by name)
-    assert {c["family"] for c in configurations()} == {"llama", "brumby", "deepseek", "granite", "solar"}
+    # six since PR 49 (the name stays: the driver counts tests by name)
+    assert {c["family"] for c in configurations()} == {
+        "llama", "brumby", "deepseek", "granite", "solar", "mimo"}
 
 
 @pytest.mark.parametrize("config", configurations(), ids=lambda c: c["name"])
@@ -56,8 +57,8 @@ def test_model_config_is_the_family_the_program_dispatches_on(config):
 
     cfg = family_mod.load(config).model_config(config["name"], config)
     module = models.get_module(cfg).__name__.rsplit(".", 1)[-1]
-    # both hybrids are ONE stack: models/granite.py, the layer kinds as data
-    assert module == {"solar": "granite"}.get(config["family"], config["family"])
+    # the three hybrids are ONE stack: models/granite.py, the layer kinds as data
+    assert module == {"solar": "granite", "mimo": "granite"}.get(config["family"], config["family"])
     assert cfg.is_retention == (config["family"] == "brumby")
 
 
@@ -263,5 +264,54 @@ def test_the_solar_family_draws_slow_channel_decays_and_leaves_nothing_skippable
         assert float(jnp.abs(leaf.astype(jnp.float32)).min()) > 0.0 or leaf.size > 1000
         assert float(jnp.std(leaf.astype(jnp.float32))) > 0.0
     assert abs(float(jnp.std(kda["wo"])) * np.sqrt(64) / fam.KDA_OUT_SCALE - 1.0) < 0.1
+    assert abs(float(jnp.std(w["layers"]["w_down"])) * np.sqrt(32) / fam.ROUTED_OUT_SCALE - 1.0) < 0.1
+    assert abs(float(jnp.std(w["lm_head"])) * np.sqrt(64) - 1.0) < 0.05
+
+
+def test_the_mimo_family_is_the_cut_and_draws_sinks_that_take_their_share():
+    """mimo-v2-flash as cut: 16 experts held under a router 256 wide, an
+    eighth of the vocabulary, published layer 0 and one period of the
+    pattern (read from the published lists at `layers_held`); and at the
+    rehearsal size the draws: sinks inside SINK_RANGE (a fifth to three
+    quarters of a window head's mass), a selection bias a few score spacings wide,
+    float32 where the family says, no gain at 1, nothing at 0."""
+    import jax
+    import jax.numpy as jnp
+
+    with open(os.path.join(BENCH, "configs", "mimo-v2-flash.json")) as f:
+        config = json.load(f)
+    fam = family_mod.load(config)
+    cfg = fam.model_config(config["name"], config)
+    assert (cfg.num_experts, cfg.held_experts, cfg.vocab_size, cfg.num_layers) == (256, (0, 16), 19072, 7)
+    assert len(config["hybrid_layer_pattern"]) == len(config["moe_layer_freq"]) == 48
+    assert config["layers_held"] == [0, 6, 7, 8, 9, 10, 11]
+    assert cfg.layer_types == ("attention",) + ("window",) * 5 + ("attention",)
+    assert cfg.first_k_dense_replace == 1 and cfg.n_shared_experts == 0
+    assert (cfg.num_kv_heads, cfg.window_kv_heads, cfg.head_dim, cfg.value_head_dim) == (4, 8, 192, 128)
+    assert (cfg.rotary_dim, cfg.sliding_window, cfg.attn_value_scale) == (64, 128, 0.707)
+    assert (cfg.rope_theta, cfg.window_rope_theta, cfg.window_sink) == (5e6, 1e4, True)
+    assert (cfg.scoring_func, cfg.topk_method, cfg.norm_topk_prob) == ("sigmoid", "noaux_tc", True)
+    shapes = fam.weight_shapes(config)
+    assert shapes["layers"]["router"] == (6, 4096, 256) and shapes["layers"]["w_gate"] == (6, 16, 4096, 2048)
+    assert shapes["dense_layers"]["w_gate"] == (1, 4096, 16384)
+    assert shapes["attn"]["wk"] == (2, 4096, 4 * 192) and shapes["attn"]["wv"] == (2, 4096, 4 * 128)
+    assert shapes["attn_w"]["wk"] == (5, 4096, 8 * 192) and shapes["attn_w"]["wo"] == (5, 64 * 128, 4096)
+    assert shapes["attn_w"]["sink"] == (5, 64) and shapes["lm_head"] == (4096, 19072)
+    sizes = jax.tree.leaves(shapes, is_leaf=lambda x: isinstance(x, tuple))
+    norms = 2 * 7 * 4096 + 4096 + 5 * 64 + 6 * 256  # gains, sinks, selection bias
+    assert sum(int(np.prod(s)) for s in sizes) - norms == 3_429_892_096
+
+    with open(os.path.join(BENCH, "configs", "rehearse-mimo-tiny.json")) as f:
+        tiny = json.load(f)
+    w = jax.jit(lambda k: fam.make_weights(tiny, k, jnp.float32))(family_mod.seed_key(3))
+    sink = w["attn_w"]["sink"]
+    assert fam.SINK_RANGE[0] <= float(sink.min()) and float(sink.max()) <= fam.SINK_RANGE[1]
+    share = jnp.exp(sink) / (jnp.exp(sink) + 128 * np.exp(0.5))  # scores ~ N(0, 1) over 128 keys
+    assert 0.19 < float(share.min()) and float(share.max()) < 0.76
+    assert abs(float(jnp.std(w["layers"]["router_bias"])) * 8 / fam.ROUTER_BIAS_SPACINGS - 1.0) < 0.3
+    for name in fam.FLOAT32_LEAVES:
+        assert all(g[name].dtype == jnp.float32 for g in w.values() if isinstance(g, dict) and name in g)
+    for leaf in jax.tree.leaves(w):  # nothing at a value that lets a path skip it
+        assert float(jnp.std(leaf.astype(jnp.float32))) > 0.0
     assert abs(float(jnp.std(w["layers"]["w_down"])) * np.sqrt(32) / fam.ROUTED_OUT_SCALE - 1.0) < 0.1
     assert abs(float(jnp.std(w["lm_head"])) * np.sqrt(64) - 1.0) < 0.05

@@ -5,7 +5,9 @@ request.h:38): an online burst preempts RUNNING offline decodes
 resumes and completes once the burst drains. Every case runs for a
 paged-cache family and for a family with a state slot BESIDE its K/V
 blocks (`solar-tiny`: a preempted sequence gives back its slot and its
-blocks, and recomputes its state when it resumes)."""
+blocks, and recomputes its state when it resumes) and for a family with a
+SECOND paged pool (`mimo-tiny`: a preempted sequence gives back the blocks
+of both)."""
 
 import numpy as np
 import pytest
@@ -16,7 +18,7 @@ from xllm_service_tpu.runtime.engine import EngineRequest, InferenceEngine
 from xllm_service_tpu.runtime.executor import ModelExecutor
 
 
-MODELS = ["llama3-tiny", "solar-tiny"]
+MODELS = ["llama3-tiny", "solar-tiny", "mimo-tiny"]
 
 
 def _engine(model, R=4, num_blocks=64):
@@ -35,6 +37,8 @@ def _all_given_back(eng):
     ARE its slots)."""
     assert len(eng._free_slots) == eng.R
     assert eng.block_mgr.num_referenced_blocks == 0
+    window = getattr(eng.block_mgr, "window", None)  # a window family's second pool
+    assert window is None or window.num_referenced_blocks == 0
 
 
 def _req(rid, outs, offline=False, max_new=64, prompt=None):

@@ -132,12 +132,13 @@ def test_big_matmul_leaves_actually_shard(cpu_devices, tp):
     assert has_tp(kv_scale_sharding(mesh).spec)
 
 
-# A family with a state pool is served at tp_size = ep_size = 1 only
-# (runtime/executor.py refuses the rest by name; its tree is replicated:
+# A family of the hybrid stack (a state pool, or a window pool beside the
+# full one) is served at tp_size = ep_size = 1 only (runtime/executor.py
+# refuses the rest by name; its tree is replicated:
 # parallel/sharding._hybrid_param_shardings), so it has no expert-axis rule.
 MOE_CONFIGS = [
     n for n in list_model_configs()
-    if get_model_config(n).is_moe and not get_model_config(n).has_state_pool
+    if get_model_config(n).is_moe and not get_model_config(n).is_hybrid
 ]
 
 

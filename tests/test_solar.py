@@ -431,7 +431,7 @@ def test_the_state_layer_properties_name_a_kind_not_mamba():
     with pytest.raises(ValueError, match="two state-layer kinds"):
         both.state_layer_kind
     with pytest.raises(ValueError, match="layer_types"):
-        granite._segments(dataclasses.replace(CFG, layer_types=("kda", "window", "kda", "kda")))
+        granite._segments(dataclasses.replace(CFG, layer_types=("kda", "ring", "kda", "kda")))
 
 
 def test_every_state_layer_kind_is_one_row_of_one_table():
@@ -444,7 +444,7 @@ def test_every_state_layer_kind_is_one_row_of_one_table():
     from xllm_service_tpu.models import configs
 
     assert tuple(granite.STATE_KINDS) == configs.STATE_LAYER_KINDS == tuple(configs._STATE_MIXER_PARAMS)
-    assert set(granite.MIXER_STACKS) == set(granite.STATE_KINDS) | {"attention"} == set(granite.MIXER_REGIONS)
+    assert set(granite.MIXER_STACKS) == set(granite.STATE_KINDS) | {"attention", "window"} == set(granite.MIXER_REGIONS)
     assert all(len(row) == 4 and all(callable(f) for f in row) for row in granite.STATE_KINDS.values())
     for name in ("solar-tiny", "granite-tiny"):
         c = get_model_config(name)

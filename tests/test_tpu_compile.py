@@ -656,6 +656,24 @@ def _in_place_case(one_chip, case):
         pf = (s((1, 512)), s((1,)), s((1,)), s((1, 33)))
         fn = lambda p, k, v, *a: granite.mixed_step(p, cfg, k, v, *a)  # noqa: E731
         return fn, (params, *pools, *dec(96, 64), *pf), names
+    if case.startswith("mimo"):
+        from xllm_service_tpu.models import granite
+
+        cfg = dataclasses.replace(  # the dense full layer, a scan of two window layers, a full one
+            get_model_config("mimo-v2-flash"), num_layers=4,
+            layer_types=("attention", "window", "window", "attention"), vocab_size=8192,
+        )
+        params = jax.eval_shape(lambda k: granite.init_params(cfg, k, jnp.bfloat16), jax.random.key(0))
+        params = jax.tree.map(lambda a: s(a.shape, a.dtype), params)
+        (kf, vf), (kw, vw) = granite.pool_shapes(cfg, 600, 201, BS)
+        pools = tuple((s(full, jnp.bfloat16), s(win, jnp.bfloat16)) for full, win in ((kf, kw), (vf, vw)))
+        names = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+        if case == "mimo-decode-64":
+            fn = lambda p, k, v, *a: granite.decode_step(p, cfg, k, v, *a)  # noqa: E731
+            return fn, (params, *pools, *dec(64, 256)), names
+        pf = (s((1, 512)), s((1,)), s((1,)), s((1, 256)))
+        fn = lambda p, k, v, *a: granite.mixed_step(p, cfg, k, v, *a)  # noqa: E731
+        return fn, (params, *pools, *dec(64, 256), *pf), names
     from xllm_service_tpu.models import deepseek
 
     cfg = dataclasses.replace(  # 1 dense layer beside the scan of 2
@@ -676,7 +694,8 @@ def _in_place_case(one_chip, case):
 
 
 @pytest.mark.parametrize("case", ["brumby-decode-24", "deepseek-decode-3", "deepseek-mixed-576",
-                                  "solar-decode-96", "solar-mixed-608"])
+                                  "solar-decode-96", "solar-mixed-608", "mimo-decode-64",
+                                  "mimo-mixed-576"])
 def test_step_reads_every_weight_leaf_where_it_lies(one_chip, no_persistent_cache, as_on_tpu, case):
     """The brumby decode step at reason-batch's 24 rows and the deepseek
     decode (3 rows) and mixed (64 + 512 rows) steps of doc-steady, at the
@@ -688,15 +707,30 @@ def test_step_reads_every_weight_leaf_where_it_lies(one_chip, no_persistent_cach
     KDA hybrid's decode (96 rows) and mixed (96 + 512 rows) steps at
     solar-open2-250b's widths: `wq`/`wk`/`wv` of the KDA layers, the two
     low-rank pairs and the GQA layer's gate, whose consumers are all
-    head-batched."""
+    head-batched. The window family's decode (64 rows) and mixed (64 + 512
+    rows) steps at mimo-v2-flash's widths, every table 128 blocks wide:
+    `wq`/`wk`/`wv`/`wo` of both kinds of attention layer (key heads of 192
+    lanes, value heads of 128), the dense first layer and the experts,
+    with all four attention launches of the cell in the program."""
     fn, args, names = _in_place_case(one_chip, case)
-    if case.startswith("solar"):  # the cell's route: the pair of attention kernels
+    if case.startswith(("solar", "mimo")):  # the cell's route: the pair of attention kernels
         os.environ["XLLM_RAGGED_ATTENTION_KERNEL"] = "0"  # (as_on_tpu's monkeypatch restores it)
     text = jax.jit(fn, donate_argnums=(1, 2)).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text  # the kernels' branch, as on the chip
     if case.startswith("solar"):
         # (a head tile of the float32 state pool, [1, 64, 128, 128], has w_f2's element count)
         moved = _weight_leaves_moved(text, args[0], names, ("layers", "kda", "attn"), dtype="bf16")
+    elif case.startswith("mimo"):
+        # (not the full layers' wv: its [4096, 512] is the shape of a chunk's rows)
+        moved = _weight_leaves_moved(
+            text, args[0], names, ("layers", "dense_layers", "attn_w"), dtype="bf16"
+        ) + _weight_leaves_moved(
+            text, args[0], tuple(n for n in names if n != "wv"), ("attn",), dtype="bf16")
+        launches = ["paged_attention_kernel", "window_paged_attention_kernel", "kv_write_kernel"]
+        launches += ["flash_prefill_kernel", "window_flash_prefill_kernel"] * (case == "mimo-mixed-576")
+        for name in launches:
+            assert f'"{name}"' in text or f"{name}" in text, name
+        assert ("window_flash_prefill_kernel" in text) == (case == "mimo-mixed-576")
     else:
         moved = _weight_leaves_moved(text, args[0], names)
     assert not moved, "\n".join(moved)

@@ -144,6 +144,21 @@ class ModelConfig:
     # A hybrid stack's attention layers gate their output per lane,
     # `W_o [sigmoid(W_gate x) * attn]`.
     attn_gate: bool = False
+    # A hybrid stack's WINDOW layers ("window" in layer_types: GQA over
+    # the last `sliding_window` positions, in a paged pool of their own
+    # whose blocks are freed behind the sequence) have their own KV head
+    # count and rope theta and, with `window_sink`, a learned logit a
+    # query head in the softmax's denominator whose mass is dropped. For
+    # both attention kinds of the stack: `attn_v_head_dim` is the value
+    # head's width (0 = head_dim), `rotary_dim` the lanes of a head,
+    # from lane 0, that rotate (0 = no rotary: both older families are
+    # NoPE), `attn_value_scale` multiplies the values.
+    window_kv_heads: int = 0
+    window_rope_theta: float = 10000.0
+    window_sink: bool = False
+    attn_v_head_dim: int = 0
+    rotary_dim: int = 0
+    attn_value_scale: float = 1.0
     # Granite's four multipliers (HF GraniteMoeHybridConfig): the token
     # embedding is scaled by `embedding_multiplier`, attention scores by
     # `attention_multiplier` (0 = head_dim**-0.5), each block's addition
@@ -186,6 +201,18 @@ class ModelConfig:
         return not self.is_retention and (
             not self.layer_types or "attention" in self.layer_types
         )
+
+    @property
+    def num_window_layers(self) -> int:
+        """Layers that hold rows of the window pool, a second paged pool
+        whose blocks a sequence frees once they are `sliding_window`
+        behind it."""
+        return self.layer_types.count("window")
+
+    @property
+    def value_head_dim(self) -> int:
+        """Width of a value head of a hybrid stack's attention layers."""
+        return self.attn_v_head_dim or self.head_dim
 
     @property
     def num_attention_layers(self) -> int:
@@ -287,13 +314,20 @@ def approx_param_count(cfg: ModelConfig) -> int:
 
 def _hybrid_mixer_params(cfg: ModelConfig) -> int:
     """Mixer matrices of a hybrid stack over all its layers."""
-    E = cfg.hidden_size
-    gqa = 2 * E * (cfg.num_heads + cfg.num_kv_heads) * cfg.head_dim
+    E, D, Dv = cfg.hidden_size, cfg.head_dim, cfg.value_head_dim
+
+    def gqa(kv_heads):  # q and k at D lanes a head, v and o at Dv
+        return E * (cfg.num_heads + kv_heads) * (D + Dv)
+
+    full = gqa(cfg.num_kv_heads)
     if cfg.attn_gate:
-        gqa += E * cfg.num_heads * cfg.head_dim
+        full += E * cfg.num_heads * cfg.head_dim
     kind = cfg.state_layer_kind
     state = _STATE_MIXER_PARAMS[kind](cfg) if kind else 0
-    return cfg.num_state_layers * state + cfg.num_attention_layers * gqa
+    return (
+        cfg.num_state_layers * state + cfg.num_attention_layers * full
+        + cfg.num_window_layers * gqa(cfg.window_kv_heads)
+    )
 
 
 def _mamba_mixer_params(cfg: ModelConfig) -> int:
@@ -948,6 +982,82 @@ register(
         kda_gate_rank=128,
         attn_gate=True,
         max_position_embeddings=1048576,
+    )
+)
+
+register(
+    # MiMo-V2-Flash's stack at a test's size (tests/test_mimo.py): full
+    # GQA layers (1 KV head) beside window layers (2 KV heads, the last 8
+    # positions, a sink logit a head) in the hybrid stack, key heads of
+    # 24 lanes (the first 8 rotate, theta by layer kind) and value heads
+    # of 16, a dense first layer, then 4 of 8 sigmoid top-2 experts and
+    # no shared one, an untied head.
+    ModelConfig(
+        name="mimo-tiny",
+        vocab_size=512,
+        hidden_size=64,
+        intermediate_size=96,
+        num_layers=6,
+        num_heads=4,
+        num_kv_heads=1,
+        head_dim=24,
+        rope_theta=5000000.0,
+        rms_norm_eps=1e-5,
+        num_experts=8,
+        experts_held=(0, 4),
+        num_experts_per_tok=2,
+        moe_intermediate_size=32,
+        scoring_func="sigmoid",
+        topk_method="noaux_tc",
+        first_k_dense_replace=1,
+        layer_types=("attention", "window", "window") * 2,
+        sliding_window=8,
+        window_kv_heads=2,
+        window_rope_theta=10000.0,
+        window_sink=True,
+        attn_v_head_dim=16,
+        rotary_dim=8,
+        attn_value_scale=0.707,
+        max_position_embeddings=4096,
+    )
+)
+
+register(
+    # https://huggingface.co/XiaomiMiMo/MiMo-V2-Flash/blob/main/config.json
+    # (model_type mimo_v2_flash), 309B-A15B, as ONE CHIP'S SHARE of it at
+    # every published width: published layer 0 (full attention, a dense
+    # MLP) and one period of the layer pattern, published layers 6-11
+    # (five window layers, one full), experts 0-15 of the 256 a layer
+    # (sigmoid scores, top 8; the router stays 256 wide), vocabulary rows
+    # 0-19,071 of 152,576 (benchmarks/configs/mimo-v2-flash.json has the
+    # deployment). Random weights only: runtime/weights.py has no loader.
+    ModelConfig(
+        name="mimo-v2-flash",
+        vocab_size=19072,
+        hidden_size=4096,
+        intermediate_size=16384,
+        num_layers=7,
+        num_heads=64,
+        num_kv_heads=4,
+        head_dim=192,
+        rope_theta=5000000.0,
+        rms_norm_eps=1e-5,
+        num_experts=256,
+        experts_held=(0, 16),
+        num_experts_per_tok=8,
+        moe_intermediate_size=2048,
+        scoring_func="sigmoid",
+        topk_method="noaux_tc",
+        first_k_dense_replace=1,
+        layer_types=("attention",) + ("window",) * 5 + ("attention",),
+        sliding_window=128,
+        window_kv_heads=8,
+        window_rope_theta=10000.0,
+        window_sink=True,
+        attn_v_head_dim=128,
+        rotary_dim=64,
+        attn_value_scale=0.707,
+        max_position_embeddings=262144,
     )
 )
 

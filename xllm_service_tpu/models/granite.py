@@ -1,11 +1,14 @@
-"""The hybrid stack: state layers and GQA layers in ONE stack, a top-k
-expert block beside a shared MLP in every layer, over the same runtime as
-the other families. Two published families run on it, and the difference
+"""The hybrid stack: state layers, GQA layers and window layers in ONE
+stack, a top-k expert block (beside a shared MLP where the family has
+one) in every layer behind a dense prefix, over the same runtime as the
+other families. Three published families run on it, and the difference
 between them is DATA of the configuration (`layer_types`, the multipliers,
 the head): Granite 4.0-H (HF `model_type: granitemoehybrid`: Mamba-2 state
-layers, a tied head, four multipliers) and Solar-Open2 (`solar_open2`:
-KDA state layers, a gate on the GQA layers, an untied head, every
-multiplier 1).
+layers, a tied head, four multipliers), Solar-Open2 (`solar_open2`: KDA
+state layers, a gate on the GQA layers, an untied head, every multiplier
+1) and MiMo-V2-Flash (`mimo_v2_flash`: window layers beside full GQA
+layers, rotary on part of a head, key heads wider than value heads, a
+dense first layer, sigmoid-scored experts and no shared one).
 
 With `h` the residual stream, `rms` RMSNorm with a learned gain, and the
 four multipliers of the configuration (each 1 by default):
@@ -31,14 +34,23 @@ four multipliers of the configuration (each 1 by default):
   the gated delta rule over `(q, k, v, g, beta)` (ops/kda.py has it, in
   its recurrent and its chunk form); out `= W_o [sigmoid(W_g2 W_g1 u) *
   rms_o(o)]`, `rms_o` over a head's value lanes with one gain vector.
-* "attention": GQA, no bias, NO rotary (both families are NoPE by
-  construction), scores scaled by `attention_multiplier` (0 =
-  head_dim**-0.5), causal, full, over the paged K/V pool; with
+* "attention": GQA, no bias, scores scaled by `attention_multiplier` (0
+  = head_dim**-0.5), causal, full, over the paged K/V pool; with
   `cfg.attn_gate` the output is gated per lane before `W_o`,
-  `W_o [sigmoid(W_gate u) * attn]`.
-* experts: `llama.moe_route` (softmax, top-k, renormalised: the softmax
-  over the chosen logits) and the grouped product over the experts HELD
-  (`cfg.experts_held`), the shared MLP always on (`llama._mlp_block`).
+  `W_o [sigmoid(W_gate u) * attn]`. Rotary on lanes [0, `rotary_dim`) of
+  every q and k head (0: none; Granite and Solar-Open2 are NoPE by
+  construction), theta `rope_theta`; values `attn_value_scale * W_v u`,
+  `attn_v_head_dim` lanes a head where that is set.
+* "window": the same mixer with `window_kv_heads` KV heads, theta
+  `window_rope_theta`, over the last `sliding_window` positions
+  (j > i - window) and, with `window_sink`, a learned logit a query head
+  in the softmax's denominator whose mass is dropped, over a paged pool
+  of its own.
+* experts: `llama.moe_route` (softmax or sigmoid scores, top-k,
+  renormalised) and the grouped product over the experts HELD
+  (`cfg.experts_held`), the shared MLP where `n_shared_experts` > 0
+  (`llama._mlp_block`); the first `first_k_dense_replace` layers have a
+  dense SwiGLU of `intermediate_size` in the block's place.
 
 **A sequence's two kinds of memory.** The carried caches are a pair of
 pairs, `k_caches = (K, S)` and `v_caches = (V, conv)`: K and V stacks
@@ -47,7 +59,17 @@ grow with the context, and the state and convolution pools over the
 STATE layers (of either kind), one slot a sequence for its life
 (`state_shapes`). All four ride the carry of every segment's scan
 (llama.py `_scan_layers`' rule: no scan slices a pool in or stacks it
-out). A decode row's slot is its row index; a prefill row names its slot
+out). A stack with WINDOW layers carries their K and V stacks
+`[Lw, Nw, Hkv_w, BS, .]` in the state pools' places (it has no state
+layers): a second paged pool with a block table of its own, which rides
+the step's tables behind the full layers' columns (`_split_windows`) and
+is indexed by position // BS like them; the engine frees a window block
+once every position in it is `sliding_window` behind the sequence and
+zeroes its entry, and the kernels' walk starts at the first in-window
+block, so a freed entry is never read. Key rows wider than a 128-lane
+tile are padded with zero lanes to whole tiles in the pool
+(`key_lanes`), value rows keep their own width. A decode row's slot is
+its row index; a prefill row names its slot
 in the LAST column of its block table (slot + 1; 0 = a padding row),
 which the executor appends for a family that has both kinds
 (runtime/executor.py `slot_column`).
@@ -57,8 +79,10 @@ that repeats is one scan over its period (`_period`); the parameter
 tree keeps what every layer has under `layers` (norms, router, experts,
 shared MLP: L entries) and the mixers under their kind's stack
 (`MIXER_STACKS`: `mamba`, `kda`, `attn`), each scan indexing the stacks it
-needs. There is ONE layer body (`_layer`) and ONE segment scan
-(`_run_layers`) for every kind and both families; what differs between
+needs; the expert leaves (and the router's) have an entry a ROUTED layer
+and the dense prefix its own stack (`dense_layers`). There is ONE layer body
+(`_layer`) and ONE segment scan (`_run_layers`) for every kind and every
+family; what differs between
 the state-layer kinds is one row of `STATE_KINDS` each (mixer, pool
 shapes, kernel eligibility, parameter stack).
 
@@ -69,6 +93,7 @@ touched expert streams once a step.
 
 from __future__ import annotations
 
+import dataclasses
 import itertools
 from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
@@ -79,10 +104,13 @@ from xllm_service_tpu.models import llama
 from xllm_service_tpu.models.configs import ModelConfig
 from xllm_service_tpu.obs.spans import region
 from xllm_service_tpu.ops import kda as kda_ops
+from xllm_service_tpu.ops import kv_cache as kvc
 from xllm_service_tpu.ops import kv_write as kv_write_ops
 from xllm_service_tpu.ops import mamba as mamba_ops
 from xllm_service_tpu.ops import moe as moe_ops
+from xllm_service_tpu.ops import rope as rope_ops
 from xllm_service_tpu.ops.attention import (
+    cache_kernel_route,
     mixed_attention,
     paged_attention,
     prefill_attention,
@@ -95,15 +123,43 @@ Params = Dict
 NUM_CACHES = 2  # K and V (each paired with a state pool on the carry)
 QUANTIZABLE_WEIGHT_LEAVES = llama.QUANTIZABLE_WEIGHT_LEAVES + ("w_in", "w_out", "w_ogate")
 # a layer kind's stack of the parameter tree
-MIXER_STACKS = {"mamba": "mamba", "kda": "kda", "attention": "attn"}
+MIXER_STACKS = {"mamba": "mamba", "kda": "kda", "attention": "attn", "window": "attn_w"}
 # the device region of a mixer's residual add (obs.spans.DEVICE_REGIONS)
-MIXER_REGIONS = {"mamba": "state_mixer", "kda": "state_mixer", "attention": "attn_proj"}
+MIXER_REGIONS = {"mamba": "state_mixer", "kda": "state_mixer", "attention": "attn_proj",
+                 "window": "attn_proj"}
 L2_EPS = 1e-6  # under the root of KDA's q and k normalisation
 
 
+def key_lanes(cfg: ModelConfig) -> int:
+    """Lanes of a key row in the pool: the head's width, padded with zero
+    lanes to whole 128-lane tiles where it is wider than one (a DMA of
+    the kernels moves whole tiles, and the chip lays a 192-lane row out
+    in 256 anyway)."""
+    D = cfg.head_dim
+    return D if D <= 128 else -(-D // 128) * 128
+
+
 def cache_row_dims(cfg: ModelConfig) -> Tuple[int, int]:
-    """(heads, row_dim) of one paged-cache row of an attention layer."""
-    return cfg.num_kv_heads, cfg.head_dim
+    """(heads, row_dim) of one paged-cache KEY row of an attention layer."""
+    return cfg.num_kv_heads, key_lanes(cfg)
+
+
+def pool_shapes(cfg: ModelConfig, blocks: int, window_blocks: int, block_size: int):
+    """((K, V) of the full layers, (K, V) of the window layers): the four
+    paged stacks of a stack with window layers."""
+    def pair(layers, n, heads):
+        return ((layers, n, heads, block_size, key_lanes(cfg)),
+                (layers, n, heads, block_size, cfg.value_head_dim))
+
+    return (pair(cfg.num_attention_layers, blocks, cfg.num_kv_heads),
+            pair(cfg.num_window_layers, window_blocks, cfg.window_kv_heads))
+
+
+def window_route(cfg: ModelConfig, k_window) -> str:
+    """Which route the window layers' attention takes over their pool:
+    "window-pallas" (the decode and flash kernels, launched under their
+    window names) or "window-xla" (the gather and blockwise twins)."""
+    return f"window-{'pallas' if cache_kernel_route(k_window)[0] else 'xla'}"
 
 
 def state_shapes(cfg: ModelConfig, slots: int):
@@ -125,18 +181,21 @@ class Segment(NamedTuple):
     first: int  # the run's first layer, of all layers
     kind_first: int  # ... and of the layers of its kind
     n: int
+    dense: bool = False  # a run of the dense prefix: no experts
 
 
 def _segments(cfg: ModelConfig) -> List[Segment]:
+    """Runs of equal layer kind; the dense prefix ends a run."""
     out: List[Segment] = []
     seen = dict.fromkeys(MIXER_STACKS, 0)
     for l, kind in enumerate(cfg.layer_types):
         if kind not in seen:
             raise ValueError(f"layer_types[{l}] = {kind!r}: one of {sorted(seen)}")
-        if out and out[-1].kind == kind:
+        dense = l < cfg.first_k_dense_replace
+        if out and out[-1].kind == kind and out[-1].dense == dense:
             out[-1] = out[-1]._replace(n=out[-1].n + 1)
         else:
-            out.append(Segment(kind, l, seen[kind], 1))
+            out.append(Segment(kind, l, seen[kind], 1, dense))
         seen[kind] += 1
     return out
 
@@ -148,7 +207,7 @@ def _period(segs: List[Segment]) -> Tuple[List[Segment], int]:
     period, so a step program holds each kind's layer body once and not
     once a repeat: that is its size in the compile cache and its seconds
     to compile."""
-    shape = [(s.kind, s.n) for s in segs]
+    shape = [(s.kind, s.n, s.dense) for s in segs]
     for m in range(1, len(segs) // 2 + 1):
         if len(segs) % m == 0 and shape == shape[:m] * (len(segs) // m):
             return segs[:m], len(segs) // m
@@ -158,13 +217,15 @@ def _period(segs: List[Segment]) -> Tuple[List[Segment], int]:
 def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
     if len(cfg.layer_types) != cfg.num_layers:
         raise ValueError("hybrid stack: one layer type a layer")
-    if not cfg.is_moe or cfg.n_shared_experts <= 0:
-        raise ValueError("hybrid stack: every layer routes beside a shared MLP")
-    E, L = cfg.hidden_size, cfg.num_layers
-    Ls, La = cfg.num_state_layers, cfg.num_attention_layers
-    Hq, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    if not cfg.is_moe:
+        raise ValueError("hybrid stack: every layer behind the dense prefix routes")
+    if cfg.num_window_layers and cfg.state_layer_kind:
+        raise ValueError("hybrid stack: window layers' pools ride in the state pools' places")
+    E, L, kd = cfg.hidden_size, cfg.num_layers, cfg.first_k_dense_replace
+    Ls, La, Lw = cfg.num_state_layers, cfg.num_attention_layers, cfg.num_window_layers
+    Hq, Hkv, D, Dv = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim, cfg.value_head_dim
     X, Xh, Fm = cfg.num_experts, cfg.held_experts[1], cfg.moe_intermediate_size
-    Fs = cfg.n_shared_experts * Fm
+    Fs, Lm = cfg.n_shared_experts * Fm, L - kd
     # (the first twenty keys and their order are Granite's draw since PR 42)
     keys = itertools.chain(
         jax.random.split(key, 20), jax.random.split(jax.random.fold_in(key, 20), 20)
@@ -180,30 +241,48 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
         "final_norm": ones((E,)),
         "layers": {
             "attn_norm": ones((L, E)), "mlp_norm": ones((L, E)),
-            "router": w((L, E, X), E),
-            "w_gate": w((L, Xh, E, Fm), E), "w_up": w((L, Xh, E, Fm), E),
-            "w_down": w((L, Xh, Fm, E), Fm),
-            "w_sh_gate": w((L, E, Fs), E), "w_sh_up": w((L, E, Fs), E),
-            "w_sh_down": w((L, Fs, E), Fs),
+            "router": w((Lm, E, X), E),
+            "w_gate": w((Lm, Xh, E, Fm), E), "w_up": w((Lm, Xh, E, Fm), E),
+            "w_down": w((Lm, Xh, Fm, E), Fm),
         },
     }
+    if Fs:
+        params["layers"].update({
+            "w_sh_gate": w((Lm, E, Fs), E), "w_sh_up": w((Lm, E, Fs), E),
+            "w_sh_down": w((Lm, Fs, E), Fs),
+        })
     if cfg.state_layer_kind:
         params[MIXER_STACKS[cfg.state_layer_kind]] = STATE_KINDS[cfg.state_layer_kind].init(
             cfg, w, ones, Ls
         )
-    params["attn"] = {
-        "wq": w((La, E, Hq * D), E), "wk": w((La, E, Hkv * D), E),
-        "wv": w((La, E, Hkv * D), E), "wo": w((La, Hq * D, E), Hq * D),
-    }
+
+    def gqa(layers, kv_heads):
+        return {
+            "wq": w((layers, E, Hq * D), E), "wk": w((layers, E, kv_heads * D), E),
+            "wv": w((layers, E, kv_heads * Dv), E), "wo": w((layers, Hq * Dv, E), Hq * Dv),
+        }
+
+    params["attn"] = gqa(La, Hkv)
     if cfg.attn_gate:
         params["attn"]["w_ogate"] = w((La, E, Hq * D), E)
+    if Lw:
+        params["attn_w"] = gqa(Lw, cfg.window_kv_heads)
+        if cfg.window_sink:
+            params["attn_w"]["sink"] = w((Lw, Hq), 1.0).astype(jnp.float32)
+    if cfg.topk_method == "noaux_tc":  # the router's selection bias
+        params["layers"]["router_bias"] = w((Lm, X), 100.0).astype(jnp.float32)
+    if kd:
+        F = cfg.intermediate_size
+        params["dense_layers"] = {
+            "w_gate": w((kd, E, F), E), "w_up": w((kd, E, F), E), "w_down": w((kd, F, E), F),
+        }
     if not cfg.tie_word_embeddings:
         params["lm_head"] = w((E, cfg.vocab_size), E)
     return params
 
 
 def _wd(params: Params):
-    return wdtype(params["layers"]["w_sh_gate"])
+    return wdtype(params["attn"]["wo"])
 
 
 @region("embed")
@@ -238,6 +317,9 @@ class _Dec(NamedTuple):
     active: jnp.ndarray  # [R] bool
     plan: object  # where the rows' K/V go
     use_kernel: Optional[bool]
+    positions: Optional[jnp.ndarray] = None  # [R]: the rows' positions (rotary)
+    tables_w: Optional[jnp.ndarray] = None  # [R, CB]: the window pool's table
+    plan_w: object = None  # ... and where the rows' K/V go in it
 
 
 class _Pf(NamedTuple):
@@ -250,11 +332,22 @@ class _Pf(NamedTuple):
     start: jnp.ndarray  # [P]
     length: jnp.ndarray  # [P]
     plan: object
+    positions: Optional[jnp.ndarray] = None  # [P * Lpad]: the rows' positions (rotary)
+    tables_w: Optional[jnp.ndarray] = None  # [P, CB]: the window pool's table
+    plan_w: object = None
 
 
 def _split_tables(block_tables):
     """A prefill row's table -> (its KV blocks' columns, its state slot)."""
     return block_tables[:, :-1], block_tables[:, -1].astype(jnp.int32) - 1
+
+
+def _split_windows(block_tables):
+    """A row's table of a stack with window layers -> (its full layers'
+    columns, its window layers' columns): two tables of one width, each
+    indexed by position // BS (runtime/executor.py `window_tables`)."""
+    CB = block_tables.shape[1] // 2
+    return block_tables[:, :CB], block_tables[:, CB:]
 
 
 @region("state_mixer")
@@ -385,14 +478,32 @@ def _kda_mixer(lp, cfg: ModelConfig, h, m, S, conv, dec: Optional[_Dec],
 
 
 @region("attn_proj")
-def _qkv(lp, cfg: ModelConfig, h):
-    """h [T, E] -> q [T, Hq, D], k, v [T, Hkv, D]: no bias, no rotary."""
+def _qkv(lp, cfg: ModelConfig, h, kind="attention", positions=None):
+    """h [T, E] -> q [T, Hq, D], k [T, Hkv, D], v [T, Hkv, Dv] of an
+    attention layer of `kind`: no bias; rotary on the first `rotary_dim`
+    lanes of q and k at `positions` [T], theta by kind (0 lanes: none);
+    the values times `attn_value_scale`."""
     T = h.shape[0]
+    window = kind == "window"
+    Hkv = cfg.window_kv_heads if window else cfg.num_kv_heads
     fence = llama._plain_product  # the attention kernels and the K/V write take heads
     q = fence(jnp.einsum("te,eh->th", h, wt(lp["wq"]))).reshape(T, cfg.num_heads, cfg.head_dim)
-    k = fence(jnp.einsum("te,eh->th", h, wt(lp["wk"]))).reshape(T, cfg.num_kv_heads, cfg.head_dim)
-    v = fence(jnp.einsum("te,eh->th", h, wt(lp["wv"]))).reshape(T, cfg.num_kv_heads, cfg.head_dim)
+    k = fence(jnp.einsum("te,eh->th", h, wt(lp["wk"]))).reshape(T, Hkv, cfg.head_dim)
+    v = fence(jnp.einsum("te,eh->th", h, wt(lp["wv"]))).reshape(T, Hkv, cfg.value_head_dim)
+    if cfg.rotary_dim:
+        theta = cfg.window_rope_theta if window else cfg.rope_theta
+        q = rope_ops.apply_partial_rope(q, positions, theta, cfg.rotary_dim)
+        k = rope_ops.apply_partial_rope(k, positions, theta, cfg.rotary_dim)
+    if cfg.attn_value_scale != 1.0:
+        v = (v.astype(jnp.float32) * cfg.attn_value_scale).astype(v.dtype)
     return q, k, v
+
+
+def _pad_lanes(x, lanes: int):
+    """Zero lanes behind a head's own, up to the pool's key row."""
+    if x.shape[-1] == lanes:
+        return x
+    return jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, lanes - x.shape[-1]),))
 
 
 def _gated(lp, cfg: ModelConfig, h, o):
@@ -407,31 +518,51 @@ def _gated(lp, cfg: ModelConfig, h, o):
 
 
 def _attn_mixer(lp, cfg: ModelConfig, h, a, K, V, dec: Optional[_Dec],
-                pf: Optional[_Pf], use_ragged=None, interpret=False):
-    """The GQA mixer over flat rows h [T, E], attention layer `a`: every
-    row's K/V lands in the stacks first, then each half attends."""
-    q, k, v = _qkv(lp, cfg, h)
+                pf: Optional[_Pf], use_ragged=None, interpret=False, kind="attention"):
+    """The GQA mixer over flat rows h [T, E], layer `a` of the attention
+    layers of `kind`, K and V that kind's stacks: every row's K/V lands
+    in the stacks first, then each half attends (a window layer through
+    its own table, over its window, with its sink)."""
     R = dec.R if dec is not None else 0
     scale = _scale(cfg)
+    if kind == "window":
+        # the window pool's table and plan, the window and the sink as
+        # keywords: a full layer's calls stay what they were
+        at = lambda half: (half.tables_w, half.plan_w)
+        kw = {"window": cfg.sliding_window}
+        if cfg.window_sink:
+            kw["sinks"] = lp["sink"]
+    else:
+        at = lambda half: (half.tables, half.plan)
+        kw = {}
+    positions = None
+    if cfg.rotary_dim:
+        positions = [half.positions for half in (dec, pf) if half is not None]
+        positions = jnp.concatenate(positions) if len(positions) > 1 else positions[0]
+    q, k, v = _qkv(lp, cfg, h, kind, positions)
+    lanes = kvc.raw(K).shape[-1]  # the pool's key row: key_lanes(cfg)
+    q, k = _pad_lanes(q, lanes), _pad_lanes(k, lanes)
     if dec is not None:
-        K, V = kv_write_ops.write_kv(K, V, dec.plan, k[:R], v[:R], a)
+        dec_tables, dec_plan = at(dec)
+        K, V = kv_write_ops.write_kv(K, V, dec_plan, k[:R], v[:R], a)
     if pf is not None:
-        K, V = kv_write_ops.write_kv(K, V, pf.plan, k[R:], v[R:], a)
+        pf_tables, pf_plan = at(pf)
+        K, V = kv_write_ops.write_kv(K, V, pf_plan, k[R:], v[R:], a)
         q_pf = q[R:].reshape(pf.P, pf.Lpad, *q.shape[1:])
     if dec is not None and pf is not None:
         o_dec, o_pf = mixed_attention(
-            q[:R], q_pf, K, V, dec.tables, dec.seq_lens, pf.tables, pf.start,
-            pf.length, scale, use_ragged=use_ragged, interpret=interpret, layer=a,
+            q[:R], q_pf, K, V, dec_tables, dec.seq_lens, pf_tables, pf.start,
+            pf.length, scale, use_ragged=use_ragged, interpret=interpret, layer=a, **kw,
         )
         o = jnp.concatenate([o_dec, o_pf.reshape(-1, *o_pf.shape[2:])], axis=0)
     elif dec is not None:
         o = paged_attention(
-            q, K, V, dec.tables, dec.seq_lens, scale,
-            use_kernel=dec.use_kernel, layer=a,
+            q, K, V, dec_tables, dec.seq_lens, scale,
+            use_kernel=dec.use_kernel, layer=a, **kw,
         )
     else:
         o = prefill_attention(
-            q_pf, K, V, pf.tables, pf.start, pf.length, scale, layer=a,
+            q_pf, K, V, pf_tables, pf.start, pf.length, scale, layer=a, **kw,
         )
         o = o.reshape(-1, *o.shape[2:])
     with region("attn_proj"):
@@ -515,16 +646,24 @@ STATE_KINDS = {
 }
 
 
-def _layer(lp, cfg: ModelConfig, x, valid, kind, mix, caches):
-    """ONE layer body for both kinds: `mix(normed rows, caches) ->
+def _dense_cfg(cfg: ModelConfig) -> ModelConfig:
+    """cfg with the experts off: sends llama's MLP to its dense SwiGLU,
+    for a layer of the dense prefix (as models/deepseek.py does)."""
+    return dataclasses.replace(cfg, num_experts=0)
+
+
+def _layer(lp, cfg: ModelConfig, x, valid, kind, mix, caches, dense=False):
+    """ONE layer body for every kind: `mix(normed rows, caches) ->
     (mixer output, caches)` is the layer's mixer, of `kind`, over the
-    carried pools."""
+    carried pools; `dense`: a layer of the dense prefix, whose `lp` has
+    the dense MLP's leaves and no router."""
     y, caches = mix(block_norm(x, lp["attn_norm"], cfg.rms_norm_eps), caches)
     with region(MIXER_REGIONS[kind]):
         x = _add(cfg, x, y)
     u = block_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
     with region("ffn"):
-        return _add(cfg, x, llama._mlp_block(lp, cfg, u, rows_valid=valid)), caches
+        ffn = llama._mlp_block(lp, _dense_cfg(cfg) if dense else cfg, u, rows_valid=valid)
+        return _add(cfg, x, ffn), caches
 
 
 def _run_layers(params, cfg: ModelConfig, x, k_caches, v_caches, valid,
@@ -535,9 +674,15 @@ def _run_layers(params, cfg: ModelConfig, x, k_caches, v_caches, valid,
 
     def mixer(kind, lp, i):
         def mix(h, caches):
+            # the second of each pair: the state kind's pools, or the
+            # window layers' K and V stacks
             (K, S), (V, conv) = caches
             if kind == "attention":
                 y, K, V = _attn_mixer(lp, cfg, h, i, K, V, dec, pf, use_ragged, interpret)
+            elif kind == "window":
+                y, S, conv = _attn_mixer(
+                    lp, cfg, h, i, S, conv, dec, pf, use_ragged, interpret, kind
+                )
             else:
                 y, S, conv = STATE_KINDS[kind].mixer(lp, cfg, h, i, S, conv, dec, pf)
             return y, ((K, S), (V, conv))
@@ -553,11 +698,24 @@ def _run_layers(params, cfg: ModelConfig, x, k_caches, v_caches, valid,
         common = {k: v for k, v in common.items() if k not in experts}
     else:
         experts = None  # quantized: a layer at a time, like the rest
+    # behind a dense prefix of kd layers the norms have an entry a layer
+    # and the rest of `layers` one a ROUTED layer: layer l's is entry l - kd
+    kd = cfg.first_k_dense_replace
+    norms = {k: common[k] for k in ("attn_norm", "mlp_norm")}
+    routed = {k: v for k, v in common.items() if k not in norms}
 
     def at(tree, i):
         return jax.tree.map(
             lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False), tree
         )
+
+    def layer_leaves(l, dense):
+        """Layer l's leaves of `layers` (of `dense_layers` in the prefix)."""
+        if not kd:  # every layer routes: one index for every leaf
+            return at(common, l)
+        if dense:
+            return {**at(norms, l), **at(params["dense_layers"], l)}
+        return {**at(norms, l), **at(routed, l - kd)}
 
     def run_segment(carry, seg: Segment, first, kind_first):
         """One scan over a run's layers: `first` the run's first layer of
@@ -566,13 +724,13 @@ def _run_layers(params, cfg: ModelConfig, x, k_caches, v_caches, valid,
 
         def body(carry, i):
             x, kc, vc = carry
-            lp = {**at(common, first + i), **at(stack, kind_first + i)}
-            if experts is not None:
-                lp["experts"] = (experts, first + i)
+            lp = {**layer_leaves(first + i, seg.dense), **at(stack, kind_first + i)}
+            if experts is not None and not seg.dense:
+                lp["experts"] = (experts, first + i - kd if kd else first + i)
             with moe_ops.layer_stats() as stats:
                 x, (kc, vc) = _layer(
                     lp, cfg, x, valid, seg.kind,
-                    mixer(seg.kind, lp, kind_first + i), (kc, vc),
+                    mixer(seg.kind, lp, kind_first + i), (kc, vc), seg.dense,
                 )
             return (x, kc, vc), stats.total()
 
@@ -606,20 +764,41 @@ def _run_layers(params, cfg: ModelConfig, x, k_caches, v_caches, valid,
 # ---------------------------------------------------------------- steps
 
 
-def _dec_half(k_caches, positions, tables, active, use_kernel) -> _Dec:
+def _dec_half(cfg: ModelConfig, k_caches, positions, tables, active, use_kernel) -> _Dec:
+    more = {}
+    if cfg.rotary_dim:
+        more["positions"] = positions
+    if cfg.num_window_layers:
+        tables, tables_w = _split_windows(tables)
+        more.update(tables_w=tables_w, plan_w=kv_write_ops.write_plan(
+            k_caches[1], tables_w, positions, active, 1
+        ))
     return _Dec(
         positions.shape[0], tables, jnp.where(active, positions + 1, 0), active,
         kv_write_ops.write_plan(k_caches[0], tables, positions, active, 1), use_kernel,
+        **more,
     )
 
 
-def _pf_half(k_caches, block_tables, start, length, P, Lpad) -> Tuple[_Pf, jnp.ndarray]:
-    tables, slots = _split_tables(block_tables)
+def _pf_half(cfg: ModelConfig, k_caches, block_tables, start, length, P, Lpad
+             ) -> Tuple[_Pf, jnp.ndarray]:
+    lane = lambda: jnp.arange(Lpad, dtype=jnp.int32)[None, :]
+    more = {}
+    if cfg.rotary_dim:
+        more["positions"] = (start[:, None] + lane()).reshape(-1)
+    if cfg.num_window_layers:  # two tables and no state slot
+        tables, tables_w = _split_windows(block_tables)
+        slots = None
+        more.update(tables_w=tables_w, plan_w=kv_write_ops.write_plan(
+            k_caches[1], tables_w, start, length, Lpad
+        ))
+    else:
+        tables, slots = _split_tables(block_tables)
     pf = _Pf(
         P, Lpad, tables, slots, start, length,
-        kv_write_ops.write_plan(k_caches[0], tables, start, length, Lpad),
+        kv_write_ops.write_plan(k_caches[0], tables, start, length, Lpad), **more,
     )
-    valid = jnp.arange(Lpad, dtype=jnp.int32)[None, :] < length[:, None]
+    valid = lane() < length[:, None]
     return pf, valid.reshape(-1)
 
 
@@ -627,12 +806,13 @@ def decode_step(
     params: Params, cfg: ModelConfig, k_caches, v_caches,
     token_ids,  # [R] int32
     positions,  # [R] int32 (where the attention layers' K/V rows go)
-    block_tables,  # [R, CB] int32: KV blocks (a row's state slot is the row)
+    block_tables,  # [R, CB] int32: KV blocks (a row's state slot is the row;
+    #                a stack with window layers: [R, 2 CB], their table second)
     active,  # [R] bool
     use_kernel: bool | None = None,
 ):
     """One generation step for R rows. Returns (logits [R, V], k', v')."""
-    dec = _dec_half(k_caches, positions, block_tables, active, use_kernel)
+    dec = _dec_half(cfg, k_caches, positions, block_tables, active, use_kernel)
     x, k_caches, v_caches = _run_layers(
         params, cfg, _embed(params, cfg, token_ids), k_caches, v_caches,
         active, dec, None,
@@ -645,7 +825,8 @@ def prefill_batch_step(
     token_ids,  # [P, Lpad] int32
     start_pos,  # [P] int32: tokens already in the state and the cache
     true_len,  # [P] int32 (0 = padding row)
-    block_tables,  # [P, CB + 1] int32: KV blocks, then slot + 1
+    block_tables,  # [P, CB + 1] int32: KV blocks, then slot + 1 (a stack
+    #                with window layers: [P, 2 CB], their table second)
     embed_overrides=None, override_positions=None,  # media: not built
     lora_idx=None, rope_positions=None,  # not built
 ):
@@ -656,7 +837,7 @@ def prefill_batch_step(
             "granite: no media embeddings, no LoRA and no M-RoPE on this family"
         )
     P, Lpad = token_ids.shape
-    pf, valid = _pf_half(k_caches, block_tables, start_pos, true_len, P, Lpad)
+    pf, valid = _pf_half(cfg, k_caches, block_tables, start_pos, true_len, P, Lpad)
     x, k_caches, v_caches = _run_layers(
         params, cfg, _embed(params, cfg, token_ids.reshape(-1)), k_caches,
         v_caches, valid, None, pf,
@@ -683,8 +864,8 @@ def mixed_step(
         raise NotImplementedError("granite: no LoRA and no M-RoPE on this family")
     R = dec_tokens.shape[0]
     P, Lpad = pf_tokens.shape
-    dec = _dec_half(k_caches, dec_positions, dec_tables, dec_active, None)
-    pf, pf_valid = _pf_half(k_caches, pf_tables, pf_start, pf_len, P, Lpad)
+    dec = _dec_half(cfg, k_caches, dec_positions, dec_tables, dec_active, None)
+    pf, pf_valid = _pf_half(cfg, k_caches, pf_tables, pf_start, pf_len, P, Lpad)
     x = _embed(params, cfg, jnp.concatenate([dec_tokens, pf_tokens.reshape(-1)]))
     x, k_caches, v_caches = _run_layers(
         params, cfg, x, k_caches, v_caches,
@@ -731,25 +912,44 @@ def hidden_dense(params: Params, cfg: ModelConfig, token_ids, rows_valid=None):
         o, _ = kda_ops.recurrent_form(*_kda_heads(cfg, c), g, beta)
         return _kda_out(lp, cfg, o, gate)
 
-    def attention(lp, h):
-        q, k, v = (t.astype(f32) for t in _qkv(lp, cfg, h))
-        g = cfg.num_heads // cfg.num_kv_heads
-        s = jnp.einsum("qhgd,khd->hgqk", q.reshape(L, -1, g, cfg.head_dim), k) * _scale(cfg)
-        p = jax.nn.softmax(jnp.where(causal[None, None], s, -1e30), axis=-1)
+    pos = jnp.arange(L, dtype=jnp.int32)
+
+    def attention(lp, h, kind="attention"):
+        q, k, v = (t.astype(f32) for t in _qkv(lp, cfg, h, kind, pos))
+        Hkv = k.shape[1]
+        s = jnp.einsum("qhgd,khd->hgqk", q.reshape(L, Hkv, -1, cfg.head_dim), k) * _scale(cfg)
+        seen = causal
+        if kind == "window":
+            seen = seen & (pos[None, :] > pos[:, None] - cfg.sliding_window)
+        s = jnp.where(seen[None, None], s, -1e30)
+        if kind == "window" and cfg.window_sink:  # one logit more, its mass dropped
+            sink = jnp.broadcast_to(lp["sink"].reshape(Hkv, -1, 1, 1), (*s.shape[:3], 1))
+            p = jax.nn.softmax(jnp.concatenate([s, sink], axis=-1), axis=-1)[..., :-1]
+        else:
+            p = jax.nn.softmax(s, axis=-1)
         o = jnp.einsum("hgqk,khd->qhgd", p, v).reshape(L, -1)
         return jnp.einsum("th,he->te", _gated(lp, cfg, h, o).astype(h.dtype), wt(lp["wo"]))
 
-    mixers = {"mamba": mamba, "kda": kda, "attention": attention}
+    mixers = {"mamba": mamba, "kda": kda, "attention": attention,
+              "window": lambda lp, h: attention(lp, h, "window")}
     li = dict.fromkeys(mixers, 0)
+    kd = cfg.first_k_dense_replace
+    norms = ("attn_norm", "mlp_norm")
     for l, kind in enumerate(cfg.layer_types):
-        lp = {k: v[l] for k, v in params["layers"].items()}
+        lp = {k: params["layers"][k][l] for k in norms}
+        if l < kd:
+            lp.update({k: v[l] for k, v in params["dense_layers"].items()})
+            mcfg = _dense_cfg(cfg)
+        else:
+            lp.update({k: v[l - kd] for k, v in params["layers"].items() if k not in norms})
+            mcfg = cfg
         lp.update({k: v[li[kind]] for k, v in params[MIXER_STACKS[kind]].items()})
         li[kind] += 1
         mix = mixers[kind]
         h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
         x = _add(cfg, x, jax.vmap(lambda hx: mix(lp, hx))(h))
         u = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-        x = _add(cfg, x, jax.vmap(lambda ux: llama._mlp(lp, cfg, ux))(u))
+        x = _add(cfg, x, jax.vmap(lambda ux: llama._mlp(lp, mcfg, ux))(u))
     return rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
 
 

@@ -228,7 +228,10 @@ def gather_context(
     k_ctx = jnp.swapaxes(k_ctx, 2, 3)
     v_ctx = jnp.swapaxes(v_ctx, 2, 3)
     R, MB, BS, H, D = k_ctx.shape
-    return k_ctx.reshape(R, MB * BS, H, D), v_ctx.reshape(R, MB * BS, H, D)
+    return (
+        k_ctx.reshape(R, MB * BS, H, D),
+        v_ctx.reshape(R, MB * BS, H, v_ctx.shape[-1]),  # value rows may be narrower
+    )
 
 
 def _sdpa(
@@ -237,7 +240,11 @@ def _sdpa(
     v: jnp.ndarray,  # [R, Lk, Hkv, D]
     mask: jnp.ndarray,  # [R, Lq, Lk] bool (True = attend)
     scale: float,
+    sinks: jnp.ndarray | None = None,  # [Hq] f32: a logit more a head
 ) -> jnp.ndarray:
+    """Value rows may be narrower than key rows (v [.., Dv]). `sinks`
+    adds exp(sink) to each head's softmax denominator and nothing to
+    its output: a sink's mass is dropped."""
     R, Lq, Hq, D = q.shape
     Hkv = k.shape[2]
     groups = Hq // Hkv
@@ -247,9 +254,15 @@ def _sdpa(
     # [R, Hkv, groups, Lq, Lk]
     scores = jnp.einsum("rqhgd,rkhd->rhgqk", qf, kf) * scale
     scores = jnp.where(mask[:, None, None, :, :], scores, NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1)
+    if sinks is None:
+        probs = jax.nn.softmax(scores, axis=-1)
+    else:
+        sink = sinks.astype(jnp.float32).reshape(1, Hkv, groups, 1, 1)
+        m = jnp.maximum(jnp.max(scores, axis=-1, keepdims=True), sink)
+        e = jnp.exp(scores - m)
+        probs = e / (jnp.sum(e, axis=-1, keepdims=True) + jnp.exp(sink - m))
     out = jnp.einsum("rhgqk,rkhd->rqhgd", probs, vf)
-    return out.reshape(R, Lq, Hq, D).astype(q.dtype)
+    return out.reshape(R, Lq, Hq, vf.shape[-1]).astype(q.dtype)
 
 
 def paged_attention_gather(
@@ -261,6 +274,7 @@ def paged_attention_gather(
     scale: float,
     window: int = 0,
     layer=None,
+    sinks=None,
 ) -> jnp.ndarray:
     """Decode-step attention: each query attends to its first seq_lens cache
     rows — the LAST `window` of them when sliding-window attention is on
@@ -275,7 +289,7 @@ def paged_attention_gather(
     mask = cols < seq_lens[:, None]  # [R, Lk]
     if window > 0:
         mask = mask & (cols >= seq_lens[:, None] - window)
-    out = _sdpa(q[:, None], k_ctx, v_ctx, mask[:, None, :], scale)
+    out = _sdpa(q[:, None], k_ctx, v_ctx, mask[:, None, :], scale, sinks)
     return out[:, 0]
 
 
@@ -289,6 +303,7 @@ def prefill_attention_gather(
     scale: float,
     window: int = 0,
     layer=None,
+    sinks=None,
 ) -> jnp.ndarray:
     """Chunked-prefill attention for one sequence: rows are chunk positions
     start_pos..start_pos+L, columns the sequence's cache rows (which already
@@ -309,7 +324,7 @@ def prefill_attention_gather(
         causal = causal & (cols[None, :] > rows[:, None] - window)
     valid_row = jnp.arange(L, dtype=jnp.int32) < true_len
     mask = causal & valid_row[:, None]
-    out = _sdpa(q[None], k_ctx, v_ctx, mask[None], scale)
+    out = _sdpa(q[None], k_ctx, v_ctx, mask[None], scale, sinks)
     return out[0]
 
 
@@ -323,6 +338,7 @@ def prefill_attention_blockwise(
     scale: float,
     window: int = 0,
     layer=None,
+    sinks=None,  # [Hq] f32: a logit more a head, its mass dropped
 ) -> jnp.ndarray:
     """Flash-style prefill: lax.scan over KV blocks with online-softmax
     accumulation. Peak memory is O(L * BS) per step instead of the dense
@@ -339,9 +355,14 @@ def prefill_attention_blockwise(
     valid_row = jnp.arange(L, dtype=jnp.int32) < true_len
 
     # One [L, Hkv, G, *] layout throughout the carry.
+    Dv = kvc.raw(v_cache).shape[-1] // pack  # value rows may be narrower
     m0 = jnp.full((L, Hkv, G, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((L, Hkv, G, 1), jnp.float32)
-    a0 = jnp.zeros((L, Hkv, G, D), jnp.float32)
+    if sinks is not None:
+        # The sink is the softmax's first logit, with no value row.
+        m0 = jnp.broadcast_to(sinks.astype(jnp.float32).reshape(Hkv, G, 1), m0.shape)
+        l0 = jnp.ones_like(l0)
+    a0 = jnp.zeros((L, Hkv, G, Dv), jnp.float32)
 
     def body(carry, inputs):
         m_prev, l_prev, acc = carry
@@ -378,7 +399,7 @@ def prefill_attention_blockwise(
         (jnp.arange(CB, dtype=jnp.int32), block_table.astype(jnp.int32)),
     )
     out = acc / jnp.maximum(l, 1e-30)
-    return out.reshape(L, Hq, D).astype(q.dtype)
+    return out.reshape(L, Hq, Dv).astype(q.dtype)
 
 
 
@@ -460,6 +481,7 @@ def prefill_attention(
     interpret: bool = False,
     window: int = 0,
     layer=None,
+    sinks=None,  # [Hq] f32: a logit more a head (not on the verify shapes)
 ) -> jnp.ndarray:
     """Batched chunked-prefill attention over the paged cache; Pallas
     flash kernel (ops/pallas/flash_prefill.py) on TPU, vmapped blockwise
@@ -497,6 +519,7 @@ def prefill_attention(
     if (
         use_kernel is None
         and S <= 8
+        and sinks is None
         and kernel_ok
         and (mq_env == "1" if kq_mq else mq_env != "0")
         # The function-wide kill switch keeps covering EVERY kernel path
@@ -538,6 +561,7 @@ def prefill_attention(
                 flash_prefill_kernel(
                     q_packed, kk, vv, bt, sp, tl, scale,
                     interpret=interpret, window=window, layer=lyr,
+                    sinks=sinks,
                 ),
                 pack, kv_heads,
             )
@@ -549,7 +573,7 @@ def prefill_attention(
     return jax.vmap(
         lambda qi, ti, sp, tl: prefill_attention_blockwise(
             qi, k_cache, v_cache, ti, sp, tl, scale, window=window,
-            layer=layer,
+            layer=layer, sinks=sinks,
         )
     )(q, block_tables, start_pos, true_len)
 
@@ -754,9 +778,11 @@ def _on_tpu() -> bool:
 def paged_attention(
     q, k_cache, v_cache, block_table, seq_lens, scale,
     use_kernel: bool | None = None, window: int = 0,
-    interpret: bool = False, layer=None,
+    interpret: bool = False, layer=None, sinks=None,
 ):
     """Decode paged attention; Pallas kernel on TPU, gather fallback elsewhere.
+    `sinks` [Hq] f32 is a logit more a head in the softmax's denominator
+    (its mass dropped); value rows may be narrower than key rows.
 
     The kernel is the DEFAULT on TPU since round 2: validated on a real v5e
     chip (scripts/validate_kernel_tpu.py — max |err| vs the gather oracle
@@ -799,6 +825,7 @@ def paged_attention(
                     paged_attention_kernel(
                         q_packed, kk, vv, bt, sl, scale,
                         window=window, interpret=interpret, layer=lyr,
+                        sinks=sinks,
                     ),
                     pack, kv_heads,
                 )
@@ -809,7 +836,7 @@ def paged_attention(
             )
     return paged_attention_gather(
         q, k_cache, v_cache, block_table, seq_lens, scale, window=window,
-        layer=layer,
+        layer=layer, sinks=sinks,
     )
 
 
@@ -844,6 +871,7 @@ def ragged_attention_blockwise(
     scale: float,
     window: int = 0,
     layer=None,
+    sinks=None,
 ) -> jnp.ndarray:
     """Blockwise oracle for the ragged mixed contract: each row runs the
     chunked-prefill blockwise scan (prefill_attention_blockwise handles
@@ -857,6 +885,7 @@ def ragged_attention_blockwise(
         out_b = prefill_attention_blockwise(
             q[off:off + seg], k_cache, v_cache, block_tables[b],
             pos0[b], q_len[b], scale, window=window, layer=layer,
+            sinks=sinks,
         )
         # Blockwise emits acc/l with l=0 rows zeroed already; mask the
         # padded tail explicitly so dead segments are deterministic.
@@ -866,6 +895,15 @@ def ragged_attention_blockwise(
         outs.append(jnp.where(valid, out_b, 0).astype(q.dtype))
         off += seg
     return jnp.concatenate(outs, axis=0)
+
+
+def _ragged_serves(k_cache, v_cache, sinks) -> bool:
+    """The ragged kernel has no sink logit and takes key and value rows
+    of one width: a launch with either goes to the decode and the flash
+    kernel side by side (or to the blockwise oracle), which have both."""
+    return sinks is None and (
+        kvc.raw(k_cache).shape[-1] == kvc.raw(v_cache).shape[-1]
+    )
 
 
 def ragged_kernel_enabled(
@@ -908,6 +946,7 @@ def ragged_paged_attention(
     interpret: bool = False,
     window: int = 0,
     layer=None,
+    sinks=None,
 ) -> jnp.ndarray:
     """Ragged mixed-batch paged attention: ONE Pallas dispatch over
     prefill + decode rows when the kernel is enabled
@@ -919,7 +958,7 @@ def ragged_paged_attention(
     scales."""
     ctx = shard_context() if _shardable(q, k_cache, shard_context()) else None
     shards = ctx[0].shape[ctx[1]] if ctx is not None else 1
-    if ragged_kernel_enabled(
+    if _ragged_serves(k_cache, v_cache, sinks) and ragged_kernel_enabled(
         k_cache, q.shape[-1], use_kernel, interpret, shards=shards
     ):
         from xllm_service_tpu.ops.pallas.ragged_paged_attention import (
@@ -942,7 +981,7 @@ def ragged_paged_attention(
         )
     return ragged_attention_blockwise(
         q, k_cache, v_cache, block_tables, q_len, pos0, seg_lens, scale,
-        window=window, layer=layer,
+        window=window, layer=layer, sinks=sinks,
     )
 
 
@@ -962,6 +1001,7 @@ def mixed_attention(
     interpret: bool = False,
     window: int = 0,
     layer=None,
+    sinks=None,
 ):
     """Attention for one MIXED engine step (models.llama.mixed_step):
     decode slots and chunked-prefill rows against the same paged KV.
@@ -978,7 +1018,7 @@ def mixed_attention(
     the kernel's context bound never walks."""
     R = q_dec.shape[0]
     P, Lpad = q_pf.shape[0], q_pf.shape[1]
-    if ragged_kernel_enabled(
+    if _ragged_serves(k_cache, v_cache, sinks) and ragged_kernel_enabled(
         k_cache, q_dec.shape[-1], use_ragged, interpret
     ):
         seg_lens = (1,) * R + (Lpad,) * P
@@ -998,9 +1038,9 @@ def mixed_attention(
         out = ragged_paged_attention(
             q_flat, k_cache, v_cache, tables, q_len, pos0, seg_lens,
             scale, use_kernel=True, interpret=interpret, window=window,
-            layer=layer,
+            layer=layer, sinks=sinks,
         )
-        return out[:R], out[R:].reshape(q_pf.shape)
+        return out[:R], out[R:].reshape(*q_pf.shape[:-1], out.shape[-1])
     # Reference pair: EXACTLY the split engine's dispatchers. interpret
     # is deliberately NOT forwarded — it is the ragged-branch CI hook,
     # and leaking it here would flip the prefill half onto the
@@ -1008,11 +1048,11 @@ def mixed_attention(
     # blockwise, breaking the mixed ≡ split byte-parity contract.
     dec_out = paged_attention(
         q_dec, k_cache, v_cache, dec_tables, dec_seq_lens, scale,
-        window=window, layer=layer,
+        window=window, layer=layer, sinks=sinks,
     )
     pf_out = prefill_attention(
         q_pf, k_cache, v_cache, pf_tables, pf_start, pf_len, scale,
-        window=window, layer=layer,
+        window=window, layer=layer, sinks=sinks,
     )
     return dec_out, pf_out
 

@@ -60,9 +60,11 @@ def _prefill_kernel(
     # inputs
     q_ref,            # [1, 1, 1, Rp, D] VMEM (one tile's TQ*G rows)
     k_hbm,            # [L, N, Hkv, BS, D] HBM
-    v_hbm,            # [L, N, Hkv, BS, D] HBM
-    *rest,            # quantized: ks_hbm, vs_hbm [L, N, Hkv, G, BS] f32; then
-    # o_ref + scratch (quantized scale bufs are [2, C, G, BS] f32)
+    v_hbm,            # [L, N, Hkv, BS, Dv] HBM: Dv <= D lanes
+    *rest,            # has_sink: sink_ref [1, Rp, 128] f32 VMEM (each row's
+    # sink logit on every lane); then
+    # quantized: ks_hbm, vs_hbm [L, N, Hkv, G, BS] f32; then
+    # o_ref [.., Rp, Dv] + scratch (quantized scale bufs are [2, C, G, BS] f32)
     block_size: int,
     chunk: int,
     tile_q: int,
@@ -71,7 +73,11 @@ def _prefill_kernel(
     quantized: bool,
     scale_groups: int = 8,
     window: int = 0,
+    has_sink: bool = False,
 ):
+    sink_ref = None
+    if has_sink:
+        sink_ref, *rest = rest
     if quantized:
         ks_hbm, vs_hbm, o_ref, k_buf, v_buf, sems, ks_buf, vs_buf, ssems = rest
     else:
@@ -212,9 +218,14 @@ def _prefill_kernel(
             )
         return m_new, l_new, acc * alpha + pv
 
-    m0 = jnp.full((Rp, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((Rp, 1), jnp.float32)
-    a0 = jnp.zeros((Rp, D), jnp.float32)
+    if has_sink:
+        # The sink is the softmax's first logit and has no value row.
+        m0 = sink_ref[0][:, :1]
+        l0 = jnp.ones((Rp, 1), jnp.float32)
+    else:
+        m0 = jnp.full((Rp, 1), NEG_INF, jnp.float32)
+        l0 = jnp.zeros((Rp, 1), jnp.float32)
+    a0 = jnp.zeros((Rp, o_ref.shape[-1]), jnp.float32)
     m, l, acc = jax.lax.fori_loop(c0, nc, body, (m0, l0, a0))
     o_ref[0, 0, 0] = jnp.where(
         l > 0, acc / jnp.maximum(l, 1e-30), 0.0
@@ -242,13 +253,17 @@ def flash_prefill_kernel(
     tile_q: int = 128,
     window: int = 0,
     layer=None,                # int32 scalar when the caches are stacks
+    sinks=None,                # [Hq] f32: a sink logit a head, or None
 ) -> jnp.ndarray:
+    """Returns [P, Lpad, Hq, Dv], Dv the value pool's lanes. A launch with
+    a window is "window_flash_prefill_kernel" in the trace."""
     k_cache, v_cache, layer = stack_operands(k_cache, v_cache, layer)
     quantized = k_cache.quantized
     k_data, v_data = k_cache.data, v_cache.data
 
     P, Lpad, Hq, D = q.shape
     _, N, Hkv, BS, _ = k_data.shape
+    Dv = v_data.shape[-1]
     MB = block_table.shape[1]
     G = Hq // Hkv
     TQ = min(tile_q, _round_up(Lpad, 8))
@@ -285,9 +300,14 @@ def flash_prefill_kernel(
         bt, start_pos.astype(jnp.int32), true_len.astype(jnp.int32), layer,
         qt, k_data, v_data,
     ]
+    if sinks is not None:
+        # row r of a tile is query head r % G of the KV head's group
+        rows = jnp.tile(sinks.astype(jnp.float32).reshape(Hkv, 1, G), (1, TQ, 1))
+        in_specs.append(pl.BlockSpec((1, Rp, 128), lambda p, h, t, *_: (h, 0, 0)))
+        inputs.append(jnp.broadcast_to(rows.reshape(Hkv, Rp, 1), (Hkv, Rp, 128)))
     scratch = [
         pltpu.VMEM((2, C * BS, D), k_data.dtype),
-        pltpu.VMEM((2, C * BS, D), v_data.dtype),
+        pltpu.VMEM((2, C * BS, Dv), v_data.dtype),
         pltpu.SemaphoreType.DMA((2, 2, C)),
     ]
     SG = k_cache.scale.shape[-2] if quantized else 8  # sub-channel groups
@@ -312,20 +332,21 @@ def flash_prefill_kernel(
         grid=(P, Hkv, NT),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (1, 1, 1, Rp, D), lambda p, h, t, *_: (p, h, t, 0, 0)
+            (1, 1, 1, Rp, Dv), lambda p, h, t, *_: (p, h, t, 0, 0)
         ),
         scratch_shapes=scratch,
     )
     kernel = functools.partial(
         _prefill_kernel, block_size=BS, chunk=C, tile_q=TQ, groups=G,
         scale=scale, quantized=quantized,
-        scale_groups=SG, window=window,
+        scale_groups=SG, window=window, has_sink=sinks is not None,
     )
     out = pl.pallas_call(
         kernel,
-        name="flash_prefill_kernel",  # op name in the device trace
+        # op name in the device trace
+        name=("window_" if window > 0 else "") + "flash_prefill_kernel",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((P, Hkv, NT, Rp, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((P, Hkv, NT, Rp, Dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
@@ -342,5 +363,5 @@ def flash_prefill_kernel(
         interpret=interpret,
     )(*inputs)
     # [P, Hkv, NT, TQ*G, D] -> [P, Lp, Hq, D] -> slice chunk rows.
-    out = out.reshape(P, Hkv, NT, TQ, G, D).transpose(0, 2, 3, 1, 4, 5)
-    return out.reshape(P, Lp, Hq, D)[:, :Lpad]
+    out = out.reshape(P, Hkv, NT, TQ, G, Dv).transpose(0, 2, 3, 1, 4, 5)
+    return out.reshape(P, Lp, Hq, Dv)[:, :Lpad]

@@ -88,13 +88,17 @@ def kv_write_kernel(
     (the same buffers: every cache operand is aliased to its output)."""
     n = len(caches)
     U = blk.shape[0]
-    Hc, _, Y = caches[0].shape[2:]
+    Hc = caches[0].shape[2]
     pre = 5  # scalar-prefetch operands ahead of the tensor inputs
 
     def pool_map(u, blk, sub, lo, hi, layer):
         return layer[0], blk[u], 0, sub[u], 0
 
-    pool_spec = pl.BlockSpec((None, None, Hc, tile, Y), pool_map)
+    # one spec a pool: the K and V rows of a family may differ in lanes
+    pool_specs = [
+        pl.BlockSpec((None, None, Hc, tile, c.shape[-1]), pool_map)
+        for c in caches
+    ]
     tile_specs = [
         pl.BlockSpec((None,) + t.shape[1:], lambda u, *_: (u, 0, 0, 0))
         for t in tiles
@@ -105,8 +109,8 @@ def kv_write_kernel(
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=pre,
             grid=(U,),
-            in_specs=tile_specs + [pool_spec] * n,
-            out_specs=[pool_spec] * n,
+            in_specs=tile_specs + pool_specs,
+            out_specs=pool_specs,
         ),
         out_shape=[jax.ShapeDtypeStruct(c.shape, c.dtype) for c in caches],
         input_output_aliases={pre + n + i: i for i in range(n)},
@@ -117,7 +121,8 @@ def kv_write_kernel(
             flops=0,
             transcendentals=0,
             bytes_accessed=sum(
-                2 * U * Hc * tile * Y * c.dtype.itemsize for c in caches
+                2 * U * Hc * tile * c.shape[-1] * c.dtype.itemsize
+                for c in caches
             ),
         ),
         interpret=interpret,

@@ -136,13 +136,15 @@ def _decode_kernel(
     q_ref,            # [RB, Hkv, Gp, D] VMEM: the tiles of RB rows, fetched
     #                   once for their RB * Hkv / HF grid steps
     k_hbm,            # [L, N, Hkv, BS, D] HBM (pl.ANY) — bf16 or int8
-    v_hbm,            # [L, N, Hkv, BS, D] HBM (pl.ANY)
-    *rest,            # quantized: ks_hbm, vs_hbm [L, N, Hkv, G, BS] f32, then
+    v_hbm,            # [L, N, Hkv, BS, Dv] HBM (pl.ANY): Dv <= D lanes
+    *rest,            # has_sink: sink_ref [Hkv, Gp, 128] f32 VMEM (a head's
+    #                 #   sink logit on every lane), fetched once; then
+    #                 # quantized: ks_hbm, vs_hbm [L, N, Hkv, G, BS] f32, then
     # output
-    #   o_ref         # [RB, Hkv, Gp, D] VMEM, written back once a row block
+    #   o_ref         # [RB, Hkv, Gp, Dv] VMEM, written back once a row block
     # scratch
-    #   k_buf, v_buf  # [2, C, HF, BS, D] VMEM (cache dtype): the block of a
-    #                 #   chunk rides an untiled dim, so one loop serves them
+    #   k_buf, v_buf  # [2, C, HF, BS, D | Dv] VMEM (cache dtype): the block
+    #                 #   of a chunk rides an untiled dim, so one loop serves them
     #   sems          # [2, 2, C] DMA semaphores
     #   handover      # [2] SMEM int32: (slot of the next live step's first
     #                 #   chunk, 1 if that chunk is already in flight)
@@ -156,7 +158,11 @@ def _decode_kernel(
     gp: int = 0,
     scale_groups: int = 8,
     window: int = 0,
+    has_sink: bool = False,
 ):
+    sink_ref = None
+    if has_sink:
+        sink_ref, *rest = rest
     if quantized:
         (ks_hbm, vs_hbm, o_ref, k_buf, v_buf, sems, handover,
          ks_buf, vs_buf, ssems) = rest
@@ -363,11 +369,17 @@ def _decode_kernel(
                 )
             return m_new, l_new, acc * alpha + pv
 
-        Gp, D = q_ref.shape[2], q_ref.shape[3]
-        m0 = jnp.full((Gp, 1), NEG_INF, jnp.float32)
-        l0 = jnp.zeros((Gp, 1), jnp.float32)
-        a0 = jnp.zeros((Gp, D), jnp.float32)
-        out = jax.lax.fori_loop(c_lo, nc, body, ((m0, l0, a0),) * fold)
+        Gp, Dv = o_ref.shape[2], o_ref.shape[3]
+        a0 = jnp.zeros((Gp, Dv), jnp.float32)
+        if has_sink:
+            # The sink is the softmax's first logit and has no value row:
+            # the running maximum starts at it and the running sum at 1.
+            l0 = jnp.ones((Gp, 1), jnp.float32)
+            init = tuple((sink_ref[h0 + i][:, :1], l0, a0) for i in range(fold))
+        else:
+            m0 = jnp.full((Gp, 1), NEG_INF, jnp.float32)
+            init = ((m0, jnp.zeros((Gp, 1), jnp.float32), a0),) * fold
+        out = jax.lax.fori_loop(c_lo, nc, body, init)
 
         handover[0] = jax.lax.rem(slot0 + nc - c_lo, 2)
         handover[1] = has_nx.astype(jnp.int32)
@@ -396,12 +408,18 @@ def _next_live(seq_lens):
 
 
 def _launch(name, qr, k_cache, v_cache, layer, block_table, seq_lens, *,
-            scale, chunk, window, interpret, s_rows, gp):
+            scale, chunk, window, interpret, s_rows, gp, sinks=None):
     """One `pallas_call` of `_decode_kernel` over q tiles [R, Hkv, T, D]
-    (T = s_rows * gp query rows a KV head); returns the same shape."""
+    (T = s_rows * gp query rows a KV head); returns [R, Hkv, T, Dv], Dv
+    the value pool's lanes. `sinks` [Hkv, T] f32: a logit more a query
+    row in the softmax's denominator. A launch with a window carries a
+    name of its own in the trace ("window_" + name)."""
     quantized = k_cache.quantized
     k_data, v_data = k_cache.data, v_cache.data
     R, Hkv, T, D = qr.shape
+    Dv = v_data.shape[-1]
+    if window > 0:
+        name = "window_" + name
     BS = k_data.shape[3]
     # KV heads a grid step: two where the heads pair up. Their chains of
     # matmul, reduce and exp are independent and hide each other's
@@ -434,11 +452,17 @@ def _launch(name, qr, k_cache, v_cache, layer, block_table, seq_lens, *,
     # 0.3 us of nothing else), only its own K and V.
     RB = _row_block(R, Hkv * T * D * qr.dtype.itemsize)
     tile = pl.BlockSpec((RB, Hkv, T, D), lambda r, h, *_: (r // RB, 0, 0, 0))
+    o_tile = pl.BlockSpec((RB, Hkv, T, Dv), lambda r, h, *_: (r // RB, 0, 0, 0))
     in_specs = [tile, hbm, hbm]
     inputs = [bt, seq_lens, layer, _next_live(seq_lens), qr, k_data, v_data]
+    if sinks is not None:
+        in_specs.append(pl.BlockSpec((Hkv, T, 128), lambda r, h, *_: (0, 0, 0)))
+        inputs.append(jnp.broadcast_to(
+            sinks.astype(jnp.float32)[:, :, None], (Hkv, T, 128)
+        ))
     scratch = [
         pltpu.VMEM((2, C, HF, BS, D), k_data.dtype),
-        pltpu.VMEM((2, C, HF, BS, D), v_data.dtype),
+        pltpu.VMEM((2, C, HF, BS, Dv), v_data.dtype),
         pltpu.SemaphoreType.DMA((2, 2, C)),
         pltpu.SMEM((2,), jnp.int32),
     ]
@@ -463,7 +487,7 @@ def _launch(name, qr, k_cache, v_cache, layer, block_table, seq_lens, *,
     kernel = functools.partial(
         _decode_kernel, block_size=BS, chunk=C, scale=scale,
         quantized=quantized, table_blocks=MB, s_rows=s_rows, gp=gp,
-        scale_groups=SG, window=window,
+        scale_groups=SG, window=window, has_sink=sinks is not None,
     )
     return pl.pallas_call(
         kernel,
@@ -472,10 +496,10 @@ def _launch(name, qr, k_cache, v_cache, layer, block_table, seq_lens, *,
             num_scalar_prefetch=4,
             grid=(R, Hkv // HF),
             in_specs=in_specs,
-            out_specs=tile,
+            out_specs=o_tile,
             scratch_shapes=scratch,
         ),
-        out_shape=jax.ShapeDtypeStruct(qr.shape, qr.dtype),
+        out_shape=jax.ShapeDtypeStruct((R, Hkv, T, Dv), qr.dtype),
         compiler_params=pltpu.CompilerParams(
             # The DMA pipeline crosses grid steps (slot parity and the
             # chunk in flight ride SMEM from one step to the next), so the
@@ -484,9 +508,10 @@ def _launch(name, qr, k_cache, v_cache, layer, block_table, seq_lens, *,
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
-            flops=4 * R * Hkv * T * D * MB * BS,  # qk + pv
+            flops=2 * R * Hkv * T * (D + Dv) * MB * BS,  # qk + pv
             bytes_accessed=(
-                R * Hkv * T * D * 4 + 2 * R * MB * BS * Hkv * kv_bytes_per_row
+                R * Hkv * T * D * 4
+                + R * MB * BS * Hkv * kv_bytes_per_row * (D + Dv) // D
             ),
             transcendentals=R * Hkv * T * MB * BS,
         ),
@@ -508,7 +533,10 @@ def paged_attention_kernel(
     chunk: int | None = None,  # blocks a chunk; None: by pool and window
     window: int = 0,
     layer=None,                # int32 scalar when the caches are stacks
+    sinks=None,                # [Hq] f32: a sink logit a head, or None
 ) -> jnp.ndarray:
+    """Returns [R, Hq, Dv], Dv the value pool's lanes (D where the pools
+    are alike)."""
     k_cache, v_cache, layer = stack_operands(k_cache, v_cache, layer)
     R, Hq, D = q.shape
     Hkv = k_cache.data.shape[2]
@@ -518,12 +546,14 @@ def paged_attention_kernel(
     qr = q.reshape(R, Hkv, G, D)
     if Gp != G:
         qr = jnp.pad(qr, ((0, 0), (0, 0), (0, Gp - G), (0, 0)))
+    if sinks is not None:
+        sinks = jnp.pad(sinks.reshape(Hkv, G), ((0, 0), (0, Gp - G)))
     out = _launch(
         "paged_attention_kernel", qr, k_cache, v_cache, layer, block_table,
         seq_lens, scale=scale, chunk=chunk, window=window,
-        interpret=interpret, s_rows=1, gp=Gp,
+        interpret=interpret, s_rows=1, gp=Gp, sinks=sinks,
     )
-    return out[:, :, :G, :].reshape(R, Hq, D)
+    return out[:, :, :G, :].reshape(R, Hq, out.shape[-1])
 
 
 @functools.partial(
@@ -563,5 +593,6 @@ def multiquery_paged_attention_kernel(
         chunk=chunk, window=window, interpret=interpret, s_rows=S, gp=Gp,
     )
     # [R, Hkv, S*Gp, D] -> [R, Hkv, S, Gp, D] -> [R, S, Hq, D]
-    out = out.reshape(R, Hkv, S, Gp, D)[:, :, :, :G, :]
-    return jnp.swapaxes(out, 1, 2).reshape(R, S, Hq, D)
+    Dv = out.shape[-1]
+    out = out.reshape(R, Hkv, S, Gp, Dv)[:, :, :, :G, :]
+    return jnp.swapaxes(out, 1, 2).reshape(R, S, Hq, Dv)

@@ -254,3 +254,17 @@ def apply_rope_scaled(
     inv_freq, scale = rope_parameters(head_dim, cfg)
     angles = pos * inv_freq
     return _rotate(x, angles, scale)
+
+
+def apply_partial_rope(
+    x: jnp.ndarray,  # [..., num_heads, head_dim]
+    positions: jnp.ndarray,  # [...] int32, broadcastable to x's batch dims
+    theta: float,
+    rotary_dim: int,
+) -> jnp.ndarray:
+    """apply_rope on lanes [0, rotary_dim) of every head (frequencies over
+    `rotary_dim`, split-half pairs inside it); the other lanes pass."""
+    if rotary_dim >= x.shape[-1]:
+        return apply_rope(x, positions, theta)
+    rot = apply_rope(x[..., :rotary_dim], positions, theta)
+    return jnp.concatenate([rot, x[..., rotary_dim:]], axis=-1)

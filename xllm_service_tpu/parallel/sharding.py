@@ -185,13 +185,22 @@ def _hybrid_param_shardings(cfg: ModelConfig, ns) -> Dict[str, Any]:
         "final_norm": ns(None),
         "layers": {
             **rep(("attn_norm", "mlp_norm"), 2),
-            **rep(("router", "w_sh_gate", "w_sh_up", "w_sh_down"), 3),
+            **rep(("router",), 3),
+            **rep(("w_sh_gate", "w_sh_up", "w_sh_down") if cfg.n_shared_experts else (), 3),
+            **rep(("router_bias",) if cfg.topk_method == "noaux_tc" else (), 2),
             **rep(("w_gate", "w_up", "w_down"), 4),
         },
         "attn": rep(("wq", "wk", "wv", "wo") + (("w_ogate",) if cfg.attn_gate else ()), 3),
     }
     if cfg.state_layer_kind:
         tree[cfg.state_layer_kind] = state_stacks[cfg.state_layer_kind]
+    if cfg.num_window_layers:
+        tree["attn_w"] = {
+            **rep(("wq", "wk", "wv", "wo"), 3),
+            **rep(("sink",) if cfg.window_sink else (), 2),
+        }
+    if cfg.first_k_dense_replace:
+        tree["dense_layers"] = rep(("w_gate", "w_up", "w_down"), 3)
     if not cfg.tie_word_embeddings:
         tree["lm_head"] = ns(None, None)
     return tree

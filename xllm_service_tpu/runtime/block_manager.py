@@ -327,3 +327,68 @@ class StateSlotManager(_NoPrefixReuse, BlockManager):
                 f"(a block is as long as max_seq_len={self.block_size})"
             )
         return super().allocate(1)
+
+
+class WindowFamilyUnsupported(NotImplementedError):
+    """A feature that is not built for a family with window layers, whose
+    sequences own blocks of TWO paged pools (models/granite.py). Raised by
+    name at engine build or at the request, never served wrongly."""
+
+
+class WindowBlockManager(_NoPrefixReuse, BlockManager):
+    """Blocks for a family whose attention layers are of two kinds
+    (models/granite.py): the FULL layers' K/V in this manager's pool,
+    allocated and freed here as for any family, and the WINDOW layers' K/V
+    in a second pool (`self.window`, a BlockManager of its own ids) whose
+    blocks a sequence holds only while some position in them is within
+    `sliding_window` of its next one.
+
+    A sequence's blocks are the engine's `seq.block_ids`, full-pool ids
+    indexed by position // block_size. The window block of logical block
+    j, while it lives, is PAIRED with the full block at j (`_beside`):
+    `slide` makes the pairs of a range live and frees the ones behind it,
+    and `free` returns the window block of every full block it is given,
+    so finish, abort, preemption and recompute return both pools through
+    the calls the engine already makes. Every block has one owner (the
+    content-addressed half is inert: a hit on the full pool's blocks would
+    skip tokens whose window rows were never written; matching the window
+    blocks too is not built), which is what makes the pairing sound."""
+
+    def __init__(self, num_blocks: int, window_blocks: int, block_size: int,
+                 seed: int = 1024):
+        super().__init__(num_blocks, block_size, seed=seed)
+        self.window = BlockManager(window_blocks, block_size, seed=seed)
+        self._beside: Dict[int, int] = {}  # full block id -> its window block
+        self.window_blocks_freed = 0  # behind a sequence (not at its end)
+
+    @property
+    def window_blocks_live(self) -> int:
+        return len(self._beside)
+
+    def slide(self, block_ids: Sequence[int], was_lo: int, lo: int, hi: int,
+              row) -> int:
+        """Move a sequence's window to logical blocks [lo, hi): free the
+        window blocks of [was_lo, lo) (the caller's last `lo`), make those
+        of [lo, hi) live, and write the window table into `row` (window
+        block ids at [lo, hi), 0 at [was_lo, lo): a freed entry is the
+        garbage block's, and no walk of a kernel reaches it). Returns
+        max(was_lo, lo), the caller's next `was_lo`."""
+        behind = [
+            w for w in (self._beside.pop(b, 0) for b in block_ids[was_lo:lo]) if w
+        ]
+        if behind:
+            self.window.free(behind)
+            self.window_blocks_freed += len(behind)
+        row[was_lo:lo] = 0
+        for j in range(lo, min(hi, len(block_ids))):
+            w = self._beside.get(block_ids[j])
+            if w is None:
+                w = self._beside[block_ids[j]] = self.window.allocate(1)[0]
+            row[j] = w
+        return max(was_lo, lo)
+
+    def free(self, block_ids: Sequence[int]) -> None:
+        beside = [w for w in (self._beside.pop(b, 0) for b in block_ids) if w]
+        if beside:
+            self.window.free(beside)
+        super().free(block_ids)

@@ -57,6 +57,7 @@ from xllm_service_tpu.runtime.block_manager import (
     OutOfBlocksError,
     HybridBlockManager,
     StateSlotManager,
+    WindowBlockManager,
 )
 from xllm_service_tpu.runtime import compile_cache as compile_cache_mod
 from xllm_service_tpu.runtime.executor import (
@@ -208,7 +209,7 @@ class _Seq:
         "json_state", "json_upto", "schema_spec",
         "rope_pos3", "rope_delta", "admit_gen", "streamed_blocks",
         "stream_hashes", "admit_hashes", "pf_dispatched",
-        "spec_ngrams", "spec_idx_upto",
+        "spec_ngrams", "spec_idx_upto", "window_lo",
     )
 
     def __init__(self, req: EngineRequest, slot: int):
@@ -216,6 +217,9 @@ class _Seq:
         self.slot = slot
         self.tokens: List[int] = list(req.prompt_token_ids)
         self.block_ids: List[int] = []
+        # A window family: the first logical block that may still hold a
+        # block of the window pool (block_manager.WindowBlockManager.slide).
+        self.window_lo = 0
         self.num_cached = 0
         self.generated: List[Tuple[int, float]] = []  # (token, logprob)
         self.last_committed_block = -1  # index into block_ids
@@ -388,7 +392,18 @@ class InferenceEngine:
             getattr(self.executor, "has_state_pool", False)
         )
         self.state_recomputes = 0
-        if self.state_family and self.executor.has_paged_cache:
+        # A family with window layers (models/granite.py) has a second
+        # paged pool: a sequence's table into it rides behind the full
+        # pool's columns of every table the engine builds (`_slide_window`)
+        # and its blocks are freed behind the sequence.
+        self.window_family = bool(getattr(self.executor, "window_tables", False))
+        if self.window_family:
+            self.window = self.executor.cfg.sliding_window
+            self.block_mgr = WindowBlockManager(
+                self.executor.num_blocks, self.executor.window_blocks,
+                self.block_size, seed=engine_cfg.murmur_hash3_seed,
+            )
+        elif self.state_family and self.executor.has_paged_cache:
             self.block_mgr = HybridBlockManager(
                 self.executor.num_blocks, self.block_size,
                 seed=engine_cfg.murmur_hash3_seed,
@@ -489,7 +504,10 @@ class InferenceEngine:
         # gone from the hot loop. `_ps_gen` bumps on every slot mutation and
         # keys the packed logit-bias cache.
         R = self.R
-        self._block_tables = np.zeros((R, self.max_blocks), np.int32)
+        # (a window family: the window pool's columns behind the full pool's)
+        self._block_tables = np.zeros(
+            (R, self.max_blocks * (2 if self.window_family else 1)), np.int32
+        )
         self._ps_gen = 0
         self._ps_temps = np.zeros((R,), np.float32)
         self._ps_top_k = np.zeros((R,), np.int32)
@@ -731,6 +749,40 @@ class InferenceEngine:
         ).set_function(
             lambda: getattr(self.executor, "state_slot_bytes", 0)
             if self.state_family else 0
+        )
+        # The two paged pools of a window family (0 and absent labels for
+        # every other family: its one pool is xllm_engine_kv_cache_usage's).
+        if self.window_family:
+            live = self.metrics.gauge(
+                "xllm_engine_kv_blocks_live",
+                "Blocks of a paged pool owned by a sequence, by pool: the "
+                "full layers' grow with the context, the window layers' "
+                "are freed behind it",
+                labelnames=("pool",),
+            )
+            live.labels(pool="full").set_function(
+                lambda: self.block_mgr.num_referenced_blocks
+            )
+            live.labels(pool="window").set_function(
+                lambda: self.block_mgr.window_blocks_live
+            )
+            size = self.metrics.gauge(
+                "xllm_engine_kv_block_bytes",
+                "Device bytes of one block of a paged pool over its layers, by pool",
+                labelnames=("pool",),
+            )
+            size.labels(pool="full").set_function(
+                lambda: self.executor.cache_row_bytes * self.block_size
+            )
+            size.labels(pool="window").set_function(
+                lambda: self.executor._window_block_bytes()
+            )
+        self.metrics.counter(
+            "xllm_engine_window_blocks_freed_total",
+            "Window-pool blocks freed behind a running sequence (every "
+            "position in them more than sliding_window behind its next)",
+        ).set_function(
+            lambda: getattr(self.block_mgr, "window_blocks_freed", 0)
         )
         self.metrics.counter(
             "xllm_engine_state_recomputes_total",
@@ -1005,8 +1057,34 @@ class InferenceEngine:
             self._m_state_in_use.observe(self.R - len(self._free_slots))
 
     def _no_state_handoff(self) -> None:
-        if self.state_family:
+        if self.state_family or self.window_family:
             self.executor._no_state_handoff()  # raises, by name
+
+    @thread_owned("engine")
+    def _table(self, seq: _Seq, first: int, end: int) -> np.ndarray:
+        """A sequence's block table as a PrefillItem takes it, for a step
+        that writes positions [first, end). A window family's is twice as
+        wide: the window pool's columns behind the full pool's, slid to
+        the blocks this step's queries can see (`_slide_window`)."""
+        table = np.zeros((self._block_tables.shape[1],), np.int32)
+        table[: len(seq.block_ids)] = seq.block_ids
+        if self.window_family:
+            self._slide_window(seq, first, end, table[self.max_blocks:])
+        return table
+
+    @thread_owned("engine")
+    def _slide_window(self, seq: _Seq, first: int, end: int, row) -> None:
+        """Window family: make live the window-pool blocks that a step
+        writing positions [first, end) of `seq` reads or writes (a query
+        at p sees j > p - window), free the ones wholly behind them, and
+        write the sequence's window table into `row`. The window pool is
+        sized so that this cannot run out (executor._decide_window_blocks)."""
+        bs = self.block_size
+        lo = max(0, first - self.window + 1) // bs
+        hi = (max(end, first + 1) - 1) // bs + 1
+        seq.window_lo = self.block_mgr.slide(
+            seq.block_ids, seq.window_lo, lo, hi, row
+        )
 
     def add_request(self, req: EngineRequest) -> None:
         if req.prefill_only:
@@ -1404,8 +1482,7 @@ class InferenceEngine:
         pf_entries = []
         for j, (seq, start, n) in enumerate(items_meta):
             s = seq.req.sampling
-            table = np.zeros((self.max_blocks,), np.int32)
-            table[: len(seq.block_ids)] = seq.block_ids
+            table = self._table(seq, start, start + n)
             final = start + n >= len(seq.tokens)
             # First chunk: TTFT base. The unset check (0.0 = never set)
             # covers a deferred first chunk whose start moved past
@@ -1902,6 +1979,7 @@ class InferenceEngine:
                 )
             seq.num_cached = num_cached
             seq.block_ids = list(cached_blocks)
+            seq.window_lo = 0
             seq.last_committed_block = len(cached_blocks) - 1
             new_blocks = need_total - len(cached_blocks)
             try:
@@ -2086,17 +2164,17 @@ class InferenceEngine:
         with phase("emit"):
             return self._book_split_prefill(batch, items, outs)
 
+    @thread_owned("engine")
     def _split_pf_items(self, batch: List[_Seq]) -> list:
         """PrefillItems for the split path's due chunks."""
         from xllm_service_tpu.runtime.executor import PrefillItem
 
         items = []
         for seq in batch:
-            table = np.zeros((self.max_blocks,), np.int32)
-            table[: len(seq.block_ids)] = seq.block_ids
             s = seq.req.sampling
             start = seq.prefilled
             n = seq.chunk_len or (len(seq.tokens) - start)
+            table = self._table(seq, start, start + n)
             # Media embeddings for this chunk: final arrays, or — on a
             # still-streaming handoff — whatever items have landed (the
             # admission gate guaranteed in-chunk coverage; the executor
@@ -2879,6 +2957,11 @@ class InferenceEngine:
                         break
                     self._preempt(victim)
             else:
+                if self.window_family:
+                    self._slide_window(
+                        seq, pos, pos + tl,
+                        self._block_tables[slot, self.max_blocks:],
+                    )
                 continue
 
     # ------------------------------------------- persistent batch state

@@ -44,7 +44,10 @@ from xllm_service_tpu.common.config import EngineConfig
 from xllm_service_tpu.obs import regions as obs_regions
 from xllm_service_tpu.obs import spans as obs_spans
 from xllm_service_tpu.runtime import compile_cache as compile_cache_mod
-from xllm_service_tpu.runtime.block_manager import StateFamilyUnsupported
+from xllm_service_tpu.runtime.block_manager import (
+    StateFamilyUnsupported,
+    WindowFamilyUnsupported,
+)
 from xllm_service_tpu import models
 from xllm_service_tpu.models.configs import (
     ModelConfig,
@@ -357,7 +360,7 @@ class ModelExecutor:
                 np.asarray(positions)[np.asarray(active)].max()
                 // self.block_size
             ) + 1
-        CB = self._pow2_bucket(need, self.max_blocks_per_seq)
+        CB = self._ctx_bucket(need)
         zeros = np.zeros((self.R,), np.int32)  # 0.0f, and a mask of None
         return self._put(
             pack_rows(
@@ -376,7 +379,7 @@ class ModelExecutor:
                     zeros if batch.frequency is None
                     else _bits(batch.frequency, np.float32),
                 ),
-                block_tables[:, :CB],
+                self._with_window(block_tables, CB),
             ),
             fresh=True,
         )
@@ -573,8 +576,21 @@ class ModelExecutor:
         if self.has_state_pool:
             self._refuse_for_state_family(tp, ep)
             self.state_pool_bytes = self._check_state_pool()
+        # A family with WINDOW layers (models/granite.py) has a second
+        # paged pool and a second block table a sequence: the window
+        # table rides every table the step programs take, behind the full
+        # layers' columns (`_with_window`), and the window pool is sized
+        # first, by what its sequences can hold at once (`window_blocks`).
+        self.window_tables = self.cfg.num_window_layers > 0
+        self.window_blocks = self.window_pool_bytes = 0
         if self.has_paged_cache:
             self.block_size = engine_cfg.block_size
+            if self.window_tables:
+                self._refuse_for_window_family(tp, ep)
+                self.window_blocks = self._decide_window_blocks()
+                self.window_pool_bytes = (
+                    self.window_blocks * self._window_block_bytes()
+                )
             self.num_blocks = self._decide_num_blocks()
         else:
             self.block_size = engine_cfg.max_seq_len
@@ -638,7 +654,27 @@ class ModelExecutor:
                 kv_sharding,
                 scale_sharding if self.kv_quantized else None,
             )
-            if self.has_state_pool:
+            if self.window_tables:
+                # Four paged stacks: K and V of the full layers, and K
+                # and V of the window layers behind them in each slot
+                # (their head counts differ, and key rows are wider than
+                # value rows: models/granite.py pool_shapes).
+                shapes = self.model_mod.pool_shapes(
+                    self.cfg, self.num_blocks, self.window_blocks,
+                    self.block_size,
+                )
+                alloc = jax.jit(
+                    lambda: tuple(
+                        tuple(
+                            kvc.alloc_cache(shapes[pool][kv], self.dtype, False)
+                            for pool in (0, 1)
+                        )
+                        for kv in (0, 1)
+                    ),
+                    out_shardings=NamedSharding(self.mesh, P()),
+                )
+                self.k_cache, self.v_cache = alloc()
+            elif self.has_state_pool:
                 # The family's two state arrays (state_shapes) ride the k
                 # and the v slot: alone (retention: S and its normaliser
                 # z), or each as the second of a pair behind the paged K
@@ -958,6 +994,70 @@ class ModelExecutor:
                 f"{self.cfg.name} (random weights only)"
             )
 
+    def _refuse_for_window_family(self, tp: int, ep: int) -> None:
+        """What is not built for a family with window layers, by name, at
+        build."""
+        e = self.engine_cfg
+        if tp > 1 or ep > 1 or e.sp_size > 1 or e.dp_size > 1:
+            raise WindowFamilyUnsupported(
+                f"tp_size/ep_size/sp_size/dp_size > 1: the two pools of "
+                f"{self.cfg.name} differ in KV heads and the window "
+                f"layers' kernels take their sink whole (a launch a shard "
+                f"is not built)"
+            )
+        if e.kv_cache_dtype != "auto":
+            raise WindowFamilyUnsupported(
+                f"kv_cache_dtype={e.kv_cache_dtype!r}: the window family's "
+                f"pools hold key rows wider than value rows in the model's "
+                f"dtype; an int8 layout for them is not built"
+            )
+        if e.speculative_tokens > 0:
+            raise WindowFamilyUnsupported(
+                "speculative_tokens > 0: the verify launch has no sink "
+                "logit and no window table"
+            )
+        if e.num_host_blocks > 0 or e.num_ssd_blocks > 0:
+            raise WindowFamilyUnsupported(
+                "prefix cache: num_host_blocks/num_ssd_blocks > 0 asks for "
+                "the prefix cache's host tiers; a window family has no "
+                "prefix cache (the window pool's blocks are not matched)"
+            )
+        if e.checkpoint_path:
+            raise WindowFamilyUnsupported(
+                f"checkpoint_path: runtime/weights.py has no loader for "
+                f"{self.cfg.name} (random weights only)"
+            )
+
+    def _window_block_bytes(self) -> int:
+        """Bytes of one block of the window pool over its layers."""
+        _, window = self.model_mod.pool_shapes(self.cfg, 1, 1, self.block_size)
+        return sum(math.prod(sh) for sh in window) * jnp.dtype(self.dtype).itemsize
+
+    def _decide_window_blocks(self) -> int:
+        """Blocks of the window pool: what its sequences can hold at once,
+        whatever their contexts. At rest a sequence holds the blocks that
+        cover its last `sliding_window` positions (`rest`: two at a window
+        of one block), one more while a step writes into a new one, and a
+        step's chunks hold the blocks they write besides:
+        1 (garbage) + R (rest + 1) + 2 ceil(max_prefill_tokens / BS)."""
+        bs = self.block_size
+        rest = -(-(self.cfg.sliding_window - 1) // bs) + 1
+        chunk = -(-self.engine_cfg.max_prefill_tokens // bs)
+        return 1 + self.R * (rest + 1) + 2 * chunk
+
+    def _with_window(self, block_tables: np.ndarray, CB: int) -> np.ndarray:
+        """The tables a step program takes, cut to its context bucket:
+        [., CB], or for a window family [., 2 CB], the window pool's
+        columns (which the engine keeps behind `max_blocks_per_seq`)
+        second (models/granite.py `_split_windows`)."""
+        if not self.window_tables:
+            return block_tables[:, :CB]
+        M = self.max_blocks_per_seq
+        full, window = block_tables[:, :CB], block_tables[:, M:M + CB]
+        if block_tables.shape[1] <= M:  # a warm-up's table: no block is live
+            window = np.zeros_like(full)
+        return np.concatenate([full, window], axis=1)
+
     def _check_state_pool(self) -> int:
         """Bytes of the state pool: `max_running_requests` slots, sized by
         their bytes and refused here if they do not fit beside the
@@ -1040,6 +1140,7 @@ class ModelExecutor:
             total_hbm * self.engine_cfg.hbm_utilization
             - n_params * param_bytes / tp
             - self.state_pool_bytes  # a hybrid stack's pool, sized first
+            - self.window_pool_bytes  # ... or its window layers' pool
         ) / 2
         cache_heads, cache_dim = models.cache_row_dims(self.cfg)
         # int8 cache: 1 byte/element + 4-byte f32 scale per sub-channel
@@ -1215,6 +1316,19 @@ class ModelExecutor:
             b *= 2
         return min(b, cap)
 
+    def _ctx_bucket(self, need: int) -> int:
+        """Blocks a table of a step whose rows need `need`: the next power
+        of two, so a family's step programs are one per bucket. A window
+        family takes the WHOLE table at every context: its launches are
+        the Pallas kernels, whose walk is bounded by a row's context and
+        not by its table, so a wide table costs its bytes in scalar memory
+        and nothing else, and one program serves every context (with two
+        kinds of attention launch a program is twice the size in the
+        compile cache; a grid of buckets would not fit it)."""
+        if self.window_tables:
+            return self.max_blocks_per_seq
+        return self._pow2_bucket(need, self.max_blocks_per_seq)
+
     def prefill_groups(
         self, items: List["PrefillItem"]
     ) -> List[List[int]]:
@@ -1274,6 +1388,11 @@ class ModelExecutor:
         bucket; with `slot_column` one more column, the row's state slot
         + 1 (0 on a padding row), which the family's step functions split
         off again (models/granite.py)."""
+        if self.window_tables:  # [P, 2 CB]: the window pool's columns second
+            rows = np.zeros((P, 2 * self.max_blocks_per_seq), np.int32)
+            for i, it in enumerate(items):  # (a warm-up's table: the full half alone)
+                rows[i, :len(it.block_table)] = it.block_table
+            return self._with_window(rows, CB)
         tables = np.zeros((P, CB + int(self.slot_column)), np.int32)
         for i, it in enumerate(items):
             m = min(CB, len(it.block_table))
@@ -1293,7 +1412,7 @@ class ModelExecutor:
         need_blocks = max(
             (it.start_pos + len(it.token_ids) + bs - 1) // bs for it in group
         )
-        CB = self._pow2_bucket(max(need_blocks, 1), self.max_blocks_per_seq)
+        CB = self._ctx_bucket(max(need_blocks, 1))
 
         token_ids = np.zeros((P, Lpad), np.int32)
         start_pos = np.zeros((P,), np.int32)
@@ -2053,6 +2172,10 @@ class ModelExecutor:
         pool, which holds none a token."""
         if not self.has_paged_cache:
             return 0
+        if self.window_tables:  # the full layers' rows: the pool that grows
+            k, v = (kvc.raw(c[0]) for c in (self.k_cache, self.v_cache))
+            lanes = k.shape[4] + v.shape[4]
+            return int(k.shape[0] * k.shape[2] * lanes * k.dtype.itemsize)
         data = kvc.raw(self._paged(self.k_cache))
         per_layer = data.shape[2] * data.shape[4] * data.dtype.itemsize
         return int(self.num_caches * data.shape[0] * per_layer)
@@ -2060,7 +2183,7 @@ class ModelExecutor:
     def _paged(self, cache):
         """The paged stack of a cache slot (the first of the pair where a
         state pool rides beside it)."""
-        return cache[0] if self.slot_column else cache
+        return cache[0] if self.slot_column or self.window_tables else cache
 
     def take_moe_stats(self) -> list:
         """The counts of the dispatches since the last take (device
@@ -2157,7 +2280,10 @@ class ModelExecutor:
             rep = resolved_kernel_report(
                 self._paged(self.k_cache), self.cfg.head_dim, shards=1
             )
-            rep["state"] = self.model_mod.state_route(self.cfg, self.k_cache[1])
+            if self.window_tables:
+                rep["window"] = self.model_mod.window_route(self.cfg, self.k_cache[1])
+            else:
+                rep["state"] = self.model_mod.state_route(self.cfg, self.k_cache[1])
             return self._add_moe_report(rep)
         if self.cfg.is_mla:
             from xllm_service_tpu.ops.attention import (
@@ -2370,7 +2496,7 @@ class ModelExecutor:
              for it in items),
             default=1,
         )
-        CBp = self._pow2_bucket(max(need, 1), self.max_blocks_per_seq)
+        CBp = self._ctx_bucket(max(need, 1))
         pf_tokens = np.zeros((P, Lpad), np.int32)
         pf_start = np.zeros((P,), np.int32)
         pf_len = np.zeros((P,), np.int32)
@@ -2872,6 +2998,12 @@ class ModelExecutor:
         return out
 
     def _no_state_handoff(self) -> None:
+        if self.window_tables:
+            raise WindowFamilyUnsupported(
+                "PD handoff: a sequence of a window family holds blocks of "
+                "two pools; the export and import of the second "
+                "(runtime/transfer.py) are not built"
+            )
         if self.has_state_pool:
             raise StateFamilyUnsupported(
                 "PD handoff: a sequence of a state-pool family holds a "

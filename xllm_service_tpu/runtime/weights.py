@@ -140,7 +140,15 @@ def _hf_sliding_window(hf: dict) -> int:
     (0 < mwl < num_layers with use_sliding_window=true) can't be
     represented by the scanned uniform layers and serving it as full
     attention would diverge from HF beyond the window — fail LOUDLY
-    instead (same principle as the unsupported-rope_scaling reject)."""
+    instead (same principle as the unsupported-rope_scaling reject).
+
+    What runs and what loads are two things since the hybrid stack has a
+    window kind of layer (models/granite.py: `layer_types` mixes "window"
+    and "attention", each kind with a paged pool of its own): a mixed
+    stack RUNS there on random weights (the presets `mimo-v2-flash`,
+    `mimo-tiny`), but no checkpoint of one LOADS, because this loader maps
+    a checkpoint onto the uniform llama tree alone and the tensor names of
+    a `hybrid_layer_pattern` checkpoint are unconfirmed offline."""
     window = int(hf.get("sliding_window") or 0)
     if not window:
         return 0
@@ -154,8 +162,11 @@ def _hf_sliding_window(hf: dict) -> int:
     raise NotImplementedError(
         f"mixed sliding-window stack (max_window_layers={mwl} of "
         f"{hf['num_hidden_layers']} layers, use_sliding_window=true) is "
-        "not representable by the uniform scanned stack; refusing to "
-        "serve it as full attention"
+        "not representable by the uniform scanned stack this loader "
+        "fills; refusing to serve it as full attention. Window and full "
+        "layers in one stack run on the hybrid stack (models/granite.py, "
+        "layer_types with \"window\": random weights only); a checkpoint "
+        "loader for that tree is not built"
     )
 
 
