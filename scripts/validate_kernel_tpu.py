@@ -54,6 +54,31 @@ def bench(fn, iters=32):
     return float(np.median(est))
 
 
+def device_us(fn, name, calls=4):
+    """(us, launches): the mean device time of the ops whose HLO name
+    starts with `name` in a profiler trace of `calls` runs of `fn`: a
+    kernel's own time, without the XLA ops and the loop around it, as the
+    benchmark's `breakdown.device_ops` counts it."""
+    import glob
+    import tempfile
+
+    from benchmarks.harness.trace_reduce import OPS_LINE, read_planes
+
+    float(fn().sum())
+    with tempfile.TemporaryDirectory() as d:
+        with jax.profiler.trace(d):
+            for _ in range(calls):
+                out = fn()
+            float(out.sum())
+        (path,) = glob.glob(d + "/plugins/profile/*/*.xplane.pb")
+        durs = [
+            dur for plane in read_planes(path)
+            for ev, _, dur in plane["lines"].get(OPS_LINE, [])
+            if ev.startswith("%" + name)
+        ]
+    return float(np.mean(durs)) / 1e3, len(durs)
+
+
 def run_case(R, Hq, Hkv, D, BS, MB, ctx, dtype=jnp.bfloat16, chunk=None,
              int8=False, window=0):
     rng = np.random.default_rng(0)
@@ -102,23 +127,26 @@ def run_case(R, Hq, Hkv, D, BS, MB, ctx, dtype=jnp.bfloat16, chunk=None,
 
 
 def run_cell_case(R, Hq, Hkv, D, BS, MB, L, N, live, ctx_lo, ctx_hi,
-                  int8=False, chunk=None):
+                  int8=False, chunk=None, window=0, Dv=None, sink=False):
     """The decode kernel as a benchmark cell's step program calls it: one
     launch a layer over an L-layer stacked pool of N blocks, `live` of the
     R rows holding contexts drawn from [ctx_lo, ctx_hi] (the rest
     seq_len 0, scattered), table tails at garbage block 0. Prints us a
     CALL (the L launches of one program / L) and the share of 819 GB/s
-    the live K and V bytes make of it; error against the gather oracle on
-    the first, a middle and the last layer."""
+    the live K and V bytes make of it (inside `window`, where there is
+    one); error against the gather oracle on the first, a middle and the
+    last layer. `Dv` value lanes and a `sink` logit a head: the window
+    layers of mimo-v2-flash."""
     from xllm_service_tpu.ops import kv_cache as kvc
 
     rng = np.random.default_rng(0)
     kk, kv_, kq = jax.random.split(jax.random.key(0), 3)
-    draw = jax.jit(lambda key: jax.lax.map(  # a layer at a time on the chip
-        lambda k_: jax.random.normal(k_, (N, Hkv, BS, D), jnp.bfloat16),
-        jax.random.split(key, L),
-    ))
-    k, v = draw(kk), draw(kv_)
+    draw = lambda key, lanes: jax.jit(lambda: jax.lax.map(
+        lambda k_: jax.random.normal(k_, (N, Hkv, BS, lanes), jnp.bfloat16),
+        jax.random.split(key, L),  # a layer at a time on the chip
+    ))()
+    k, v = draw(kk, D), draw(kv_, Dv or D)
+    sinks = jnp.linspace(-1.0, 1.0, Hq) if sink else None
     if int8:
         k, v = kvc.quantize_pool(k), kvc.quantize_pool(v)
     q = jax.random.normal(kq, (R, Hq, D), jnp.bfloat16)
@@ -138,7 +166,8 @@ def run_cell_case(R, Hq, Hkv, D, BS, MB, L, N, live, ctx_lo, ctx_hi,
     jstack = jax.jit(
         lambda q_, k_, v_: jax.lax.map(
             lambda l: paged_attention_kernel(
-                q_, k_, v_, bt, lens_d, scale, layer=l, chunk=chunk
+                q_, k_, v_, bt, lens_d, scale, layer=l, chunk=chunk,
+                window=window, sinks=sinks,
             ),
             jnp.arange(L, dtype=jnp.int32),
         )
@@ -149,20 +178,85 @@ def run_cell_case(R, Hq, Hkv, D, BS, MB, L, N, live, ctx_lo, ctx_hi,
     err = 0.0
     for l in sorted({0, L // 2, L - 1}):
         at = lambda c: jax.tree.map(lambda a: a[l], c)
-        ref = paged_attention_gather(q, at(k), at(v), bt, lens_d, scale)
+        ref = paged_attention_gather(
+            q, at(k), at(v), bt, lens_d, scale, window=window, sinks=sinks
+        )
         ref = np.asarray(ref.astype(jnp.float32))
         err = max(err, float(np.max(np.abs(out[l] - ref)[lens > 0])))
     tk = bench(lambda: stack(q), iters=8) / L
-    row_bytes = D * (1 if int8 else 2) + (32 if int8 else 0)
-    kv_bytes = 2 * float(lens.sum()) * Hkv * row_bytes
+    name = "window_" * bool(window) + "paged_attention_kernel"
+    td, n = device_us(lambda: stack(q), name)
+    row_bytes = (D + (Dv or D)) * (1 if int8 else 2) + (64 if int8 else 0)
+    seen = np.minimum(lens, window) if window else lens
+    kv_bytes = float(seen.sum()) * Hkv * row_bytes
     print(
         f"CELL R={R} live={live} Hq={Hq} Hkv={Hkv} D={D} BS={BS} MB={MB} "
-        f"L={L} N={N} chunk={chunk} ctx={ctx_lo}-{ctx_hi} "
+        f"L={L} N={N} chunk={chunk} window={window} ctx={ctx_lo}-{ctx_hi} "
         f"(mean {lens[rows].mean():.0f}) "
         f"{'int8' if int8 else 'bf16'} err={err:.4f} "
-        f"call={tk*1e6:8.1f}us step={tk*1e6/(R*Hkv):6.3f}us/grid-step "
+        f"call={tk*1e6:8.1f}us kernel={td:7.2f}us (x{n}) "
+        f"row={td/live:6.3f}us/live-row "
         f"bw={kv_bytes/tk/1e9:6.1f}GB/s "
         f"hbm_share={100*kv_bytes/tk/819e9:5.1f}%"
+    )
+    return err
+
+
+def run_kv_write_case(S, live, L, N, Hc, D, BS, CB=16, ctx=1024):
+    """`kv_write_kernel` as a decode program calls it (ops/kv_write.py:
+    one plan a step, one launch a layer): one new row of K and of V for
+    `live` of the S slots (scattered; the rest write nothing) into the
+    L-layer stacked pools of N blocks, which ride a layer scan's carry.
+    us a CALL (the L launches / L) and a live unit; the pools against
+    the rows placed by one plain scatter, outside garbage block 0."""
+    from xllm_service_tpu.ops import kv_write as kvw
+
+    rng = np.random.default_rng(0)
+    length = np.zeros(S, np.int32)
+    rows = np.sort(rng.choice(S, live, replace=False))
+    length[rows] = 1
+    start = np.where(length > 0, rng.integers(1, ctx, S), 0).astype(np.int32)
+    tables = np.zeros((S, CB), np.int32)
+    free = iter(1 + rng.permutation(N - 1))
+    for r in rows:
+        tables[r, : start[r] // BS + 1] = [
+            next(free) for _ in range(start[r] // BS + 1)
+        ]
+    tables, start, length = map(jnp.asarray, (tables, start, length))
+    new = jax.random.normal(jax.random.key(2), (2, L, S, Hc, D), jnp.bfloat16)
+    pool = lambda: jnp.zeros((L, N, Hc, BS, D), jnp.bfloat16)
+
+    def run(k, v, new):
+        plan = kvw.write_plan(k, tables, start, length, 1)
+        assert plan.units is not None  # the Pallas route, not the scatter
+
+        def body(c, l):
+            return kvw.write_kv(*c, plan, new[0, l], new[1, l], l), None
+
+        return jax.lax.scan(body, (k, v), jnp.arange(L, dtype=jnp.int32))[0]
+
+    kern = jax.jit(run, donate_argnums=(0, 1))
+    got = kern(pool(), pool(), new)
+    blk, off = tables[rows, start[rows] // BS], start[rows] % BS
+    err = 0.0
+    for g, rows_new in zip(got, new):  # each live row placed by hand
+        for l in sorted({0, L // 2, L - 1}):
+            want = jnp.zeros_like(g[l]).at[blk, :, off].set(rows_new[l, rows])
+            err = max(err, float(jnp.abs(
+                (g[l, 1:] - want[1:]).astype(jnp.float32)
+            ).max()))
+    state = [got]
+
+    def once():
+        state[0] = kern(*state[0], new)
+        return state[0][0][0, 0, 0, 0]
+
+    tk = bench(once, iters=8) / L
+    td, n = device_us(once, "kv_write_kernel")
+    print(
+        f"KV-WRITE S={S} live={live} L={L} N={N} Hc={Hc} D={D} BS={BS} "
+        f"err={err:.4f} call={tk*1e6:8.1f}us kernel={td:7.2f}us (x{n}) "
+        f"unit={td/max(live, 1):6.3f}us/live-unit"
     )
     return err
 
@@ -682,6 +776,18 @@ CASES = [
     ("cell-chat-steady", run_cell_case,
      dict(R=128, Hq=16, Hkv=2, D=128, BS=128, MB=16, L=36, N=958, live=10,
           ctx_lo=256, ctx_hi=2048)),
+    # The window launch of mimo-v2-flash.longmix-steady (PR 50): 18 of 64
+    # rows live, 8 KV heads of 256 (192 padded) | 128 lanes, window 128 with a sink, over
+    # the cut's 6 window layers; and the step's cache write at the two
+    # qwen2.5-3b cells' own shapes: one new row for 9 and for 125 of 128
+    # slots into the 36-layer pool.
+    ("cell-longmix-window", run_cell_case,
+     dict(R=64, Hq=64, Hkv=8, D=256, Dv=128, BS=128, MB=32, L=6, N=700,
+          live=18, ctx_lo=512, ctx_hi=4096, window=128, sink=True)),
+    ("kv-write-chat-steady", run_kv_write_case,
+     dict(S=128, live=9, L=36, N=958, Hc=2, D=128, BS=128)),
+    ("kv-write-decode-batch", run_kv_write_case,
+     dict(S=128, live=125, L=36, N=958, Hc=2, D=128, BS=128)),
     # The Mamba-2 state pool at granite-4.0-h-small's widths (PERF.md, PR
     # 42): the decode update over the cell's 9-layer pool of 64 slots with
     # 44 rows live, and one 256-token chunk from a fresh and from a carried

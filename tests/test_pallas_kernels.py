@@ -1047,6 +1047,131 @@ def test_write_kv_lands_rows_in_the_stack(
             )
 
 
+def _fresh(kernel, static=("scale", "interpret", "chunk", "window")):
+    """A jit of its own over a copy of a kernel's wrapper: what a test has
+    patched in the kernel's module is traced, not served from the trace an
+    earlier test left (jit keeps its traces by the function under it, so
+    the copy, not the wrapper's own function, goes under this one)."""
+    import functools
+
+    inner = kernel.__wrapped__
+
+    @functools.wraps(inner)
+    def again(*args, **kwargs):
+        return inner(*args, **kwargs)
+
+    return jax.jit(again, static_argnames=static)
+
+
+_LIVE = {
+    "none": [0, 0, 0, 0, 0, 0],
+    "one-last": [0, 0, 0, 0, 0, 1],
+    "interleaved": [0, 1, 0, 1, 1, 0],
+    "all": [1, 1, 1, 1, 1, 1],
+}
+
+
+def _live_case(cache_kind, width, pattern, seed=5):
+    """Six slots, those `_LIVE[pattern]` marks writing `width` rows (the
+    chunk plans: a whole chunk, a part of one, or one row) from unaligned
+    starts; the rows nobody writes are NaN."""
+    rng = np.random.default_rng(seed)
+    live = np.asarray(_LIVE[pattern], np.int32)
+    S = len(live)
+    BS, kc, vc, tables = _write_case(rng, cache_kind, S, N=2 * S + 1)
+    start = np.asarray([3, 40, 7, 31, 17, 5], np.int32) * (BS // 32)
+    length = live * np.asarray([width, 1, width, max(width // 2, 1), width, 1])
+    length = np.minimum(length, width).astype(np.int32)
+    rows = rng.standard_normal((S, width, 2, 128)).astype(np.float32)
+    rows[np.arange(width)[None, :] >= length[:, None]] = np.nan
+    rows = jnp.asarray(rows.reshape(S * width, 2, 128), jnp.bfloat16)
+    return kc, vc, tables, jnp.asarray(start), jnp.asarray(length), rows
+
+
+@pytest.mark.parametrize("pattern", list(_LIVE))
+@pytest.mark.parametrize("width", [1, 40], ids=["decode", "chunk"])
+@pytest.mark.parametrize("cache_kind", ["bf16", "int8"])
+def test_write_kv_follows_the_live_rows(cache_kind, width, pattern):
+    """The launch walks the live units alone (they stand first in the
+    plan and take their new rows through `order`): whichever slots are
+    live, the pools equal the scatter route's outside garbage block 0 and
+    are finite EVERYWHERE, though every row nobody writes is NaN (the
+    scatter parks those in block 0; the kernel writes nothing there)."""
+    kc, vc, tables, start, length, rows = _live_case(cache_kind, width, pattern)
+    write = lambda plan: jax.jit(
+        lambda k, v: kvw.write_kv(k, v, plan, rows, rows * 2, jnp.int32(1))
+    )(kc, vc)
+    plan = kvw.write_plan(kc, tables, start, length, width, interpret=True)
+    units = plan.units
+    assert int(units.live) == int(np.sum(np.asarray(units.hi > units.lo)))
+    assert np.all(np.asarray(units.hi > units.lo)[: int(units.live)])
+    assert sorted(np.asarray(units.order)) == list(range(len(units.order)))
+    got = write(plan)
+    want = write(kvw.write_plan(kc, tables, start, length, width))
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g)[:, 1:], np.asarray(w)[:, 1:])
+        assert np.all(np.isfinite(np.asarray(g, np.float32)))
+    if pattern != "none":
+        assert not np.array_equal(np.asarray(got[0].data), np.asarray(kc.data))
+
+
+def _tallied(monkeypatch, module, body_name):
+    """Patch a kernel's body to note each grid step it runs at (interpret
+    mode: `jax.debug.callback` inside the interpreted grid loop); returns
+    the list the steps land in."""
+    from jax.experimental import pallas as pl
+
+    steps, body = [], getattr(module, body_name)
+
+    def counted(*refs, **kw):
+        jax.debug.callback(lambda i: steps.append(int(i)), pl.program_id(0))
+        return body(*refs, **kw)
+
+    monkeypatch.setattr(module, body_name, counted)
+    return steps
+
+
+@pytest.mark.parametrize("pattern", list(_LIVE))
+def test_kv_write_grid_is_the_live_units(pattern, monkeypatch):
+    """The work follows the rows: the launch runs one grid step a LIVE
+    unit (one, and it dead, where nothing is live), not one a slot."""
+    from xllm_service_tpu.ops.pallas import kv_write as kvp
+
+    steps = _tallied(monkeypatch, kvp, "_kv_write_kernel")
+    monkeypatch.setattr(kvw, "kv_write_kernel", _fresh(
+        kvp.kv_write_kernel, ("tile", "axis", "interpret")
+    ))
+    kc, vc, tables, start, length, rows = _live_case("bf16", 1, pattern)
+    plan = kvw.write_plan(kc, tables, start, length, 1, interpret=True)
+    jax.block_until_ready(
+        kvw.write_kv(kc, vc, plan, rows, rows, jnp.int32(0))
+    )
+    jax.effects_barrier()
+    live = sum(_LIVE[pattern])
+    assert sorted(steps) == list(range(max(live, 1)))
+
+
+@pytest.mark.parametrize("rows_a_block", [None, 2], ids=["one-block", "blocks-of-2"])
+@pytest.mark.parametrize("pattern", ["every-order", "all-dead", "one-live", "edges"])
+def test_decode_grid_is_the_live_rows(pattern, rows_a_block, monkeypatch):
+    """The decode kernel's row axis runs a grid step a LIVE row, and one
+    for the first row of each block of rows where that row is dead (the
+    block's zeros)."""
+    from xllm_service_tpu.ops.pallas import paged_attention as pa
+
+    steps = _tallied(monkeypatch, pa, "_decode_kernel")
+    if rows_a_block:
+        monkeypatch.setattr(pa, "_row_block", lambda rows, _: rows_a_block)
+    run, _, lens, _, (k, v), _ = _schedule_case("decode", pattern, fresh=True)
+    jax.block_until_ready(run(k, v))
+    jax.effects_barrier()
+    first = np.arange(len(lens)) % (rows_a_block or len(lens)) == 0
+    visits = int(((lens > 0) | first).sum())
+    # (Hkv 2: both KV heads ride one grid step, so a row is one step)
+    assert sorted(steps) == list(range(visits))
+    assert visits < len(lens) or pattern == "edges"
+
+
 def test_kv_write_kernel_per_shard():
     """Under a tp shard context the write launches once per shard over its
     own heads (shard_map), like the attention kernels."""
@@ -1102,12 +1227,13 @@ _HANDOVER = {
 _MQ_S = 4
 
 
-def _schedule_case(variant, pattern, seed=0):
+def _schedule_case(variant, pattern, seed=0, fresh=False):
     """(run, oracle, seq_lens, reached, pools): `run(k, v)` launches the
-    variant's kernel in interpret mode, `oracle()` is its plain twin on
-    the clean pools, `reached[n]` says whether any query of the case can
-    see block n. Tables are whole (distinct blocks in every column, so the
-    columns past a row's context point at blocks nobody reaches)."""
+    variant's kernel in interpret mode (`fresh`: traced anew), `oracle()`
+    is its plain twin on the clean pools, `reached[n]` says whether any
+    query of the case can see block n. Tables are whole (distinct blocks
+    in every column, so the columns past a row's context point at blocks
+    nobody reaches)."""
     rng = np.random.default_rng(seed)
     int8 = variant == "int8"
     BS, C, MB = (128, 2, 5) if int8 else (16, 4, 10)  # MB % C != 0
@@ -1135,13 +1261,16 @@ def _schedule_case(variant, pattern, seed=0):
             lo = max(n - window, 0) // BS if window else 0
             reached[bt[r, lo:hi]] = True
 
+    launch = multiquery_paged_attention_kernel if S > 1 else paged_attention_kernel
+    if fresh:
+        launch = _fresh(launch)
     if S > 1:
-        run = lambda k_, v_: multiquery_paged_attention_kernel(
+        run = lambda k_, v_: launch(
             q, k_, v_, table, seq_lens, scale, interpret=True, chunk=C
         )
         oracle = lambda: _mq_oracle(q, k, v, table, seq_lens, S, scale)
     else:
-        run = lambda k_, v_: paged_attention_kernel(
+        run = lambda k_, v_: launch(
             q, k_, v_, table, seq_lens, scale, interpret=True, chunk=C,
             window=window,
         )
@@ -1153,8 +1282,21 @@ def _schedule_case(variant, pattern, seed=0):
 
 @pytest.mark.parametrize("pattern", list(_HANDOVER))
 @pytest.mark.parametrize("variant", ["decode", "multiquery", "int8", "window"])
-def test_decode_schedule_hands_over(variant, pattern):
-    run, oracle, lens, _, (k, v), (S, width) = _schedule_case(variant, pattern)
+@pytest.mark.parametrize("rows_a_block", [None, 2], ids=["one-block", "blocks-of-2"])
+def test_decode_schedule_hands_over(variant, pattern, rows_a_block, monkeypatch):
+    """Every pattern with the rows' q and o tiles in ONE block (these
+    shapes' own) and in blocks of two rows (every pattern has an even
+    number), as rows wider than `_row_block`'s budget are cut (mimo's
+    window layers: 16 of 64): the grid walks the live rows and each
+    block's first row, so a block with no live row, first, last or in
+    between, is visited by that one dead row, for its zeros alone."""
+    if rows_a_block:
+        from xllm_service_tpu.ops.pallas import paged_attention as pa
+
+        monkeypatch.setattr(pa, "_row_block", lambda rows, _: rows_a_block)
+    run, oracle, lens, _, (k, v), (S, width) = _schedule_case(
+        variant, pattern, fresh=bool(rows_a_block)
+    )
     out = np.asarray(run(k, v), np.float32)
     ref = np.asarray(oracle(), np.float32)
     tol = 2e-2 if variant == "int8" else 3e-5

@@ -133,6 +133,21 @@ def _kernel_shapes(one_chip):
     return s, cache
 
 
+def _scan_body_lists(text, kernel):
+    """Lines of the compiled program's computation that launches `kernel`
+    (the layer scan's body) whose op makes a list: a sort, a running sum
+    (`reduce-window`) or a scatter."""
+    import re
+
+    for m in re.finditer(r"^%?[\w.\-]+ \([^\n]*\{\n(.*?)^\}", text, re.S | re.M):
+        if re.search(rf"%{kernel}(?:\.\d+)? = [^\n]* custom-call\(", m.group(1)):
+            return [
+                line.strip()[:120] for line in m.group(1).splitlines()
+                if re.search(r"[\])}] (sort|reduce-window|scatter)\(", line)
+            ]
+    raise AssertionError(f"no computation launches {kernel}")
+
+
 def _kernel_calls(text, name):
     """Custom calls of the compiled program whose Pallas name is `name`."""
     import re
@@ -174,6 +189,30 @@ def test_decode_kernel_compiles(
     )
     assert "tpu_custom_call" in text
     assert _kernel_calls(text, "paged_attention_kernel") == 1
+
+
+def test_window_decode_kernel_compiles_at_longmix_widths(
+    one_chip, no_persistent_cache
+):
+    """The window launch of mimo-v2-flash.longmix-steady: 64 rows of 8 KV
+    heads, key rows of 256 lanes (192 padded) beside value rows of 128, a
+    window of 128 tokens with a sink a head, tables 128 blocks wide. Its
+    q and o tiles ride blocks of 16 rows, so the index maps read the list
+    of walked rows (a dynamic grid bound AND a scalar-prefetch read in an
+    index map are what Mosaic has to take)."""
+    from xllm_service_tpu.ops.pallas import paged_attention as pa
+
+    s, _ = _kernel_shapes(one_chip)
+    assert pa._row_block(64, 8 * 8 * 256 * 2) == 16
+    text = _compile(
+        lambda q, k, v, bt, sl, sinks: pa.paged_attention_kernel(
+            q, k, v, bt, sl, SCALE, window=128, layer=jnp.int32(3),
+            sinks=sinks,
+        ),
+        s((64, 64, 256)), s((6, 700, 8, BS, 256)), s((6, 700, 8, BS, 128)),
+        s((64, 128), jnp.int32), s((64,), jnp.int32), s((64,), jnp.float32),
+    )
+    assert _kernel_calls(text, "window_paged_attention_kernel") == 1
 
 
 def test_multiquery_verify_kernel_compiles(one_chip, no_persistent_cache):
@@ -222,6 +261,42 @@ def test_ragged_kernel_compiles(one_chip, no_persistent_cache):
         s((B, 16), jnp.int32), s((B,), jnp.int32), s((B,), jnp.int32),
     )
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize(
+    "pools,rows,width",
+    [
+        (((36, 958, 2, BS, D),) * 2, 128, 1),
+        (((5, 2900, 1, BS, 640),), 64, 1),
+        (((5, 2900, 1, BS, 640),), 1, 512),
+        (((6, 700, 8, BS, 256), (6, 700, 8, BS, 128)), 64, 1),
+    ],
+    ids=["qwen-cells-decode", "latent-decode", "latent-chunk",
+         "window-pools-decode"],
+)
+def test_kv_write_kernel_compiles_at_the_cells_shapes(
+    one_chip, no_persistent_cache, as_on_tpu, pools, rows, width
+):
+    """The write as the benchmark's cells launch it: 128 slots into the
+    qwen2.5-3b cells' K and V stacks, 64 slots and one 512-token chunk
+    into deepseek-v2's one latent stack (640 lanes, one head), 64 slots
+    into mimo-v2-flash's window pools (256 | 128 lanes, 8 heads). The grid
+    is bounded by the live units and the new rows' tiles are indexed
+    through the plan's order: Mosaic takes both for every shape."""
+    s, _ = _kernel_shapes(one_chip)
+    caches = tuple(s(shape) for shape in pools)
+
+    def write(caches, tables, start, length, new):
+        plan = kvw.write_plan(caches[0], tables, start, length, width)
+        assert plan.units is not None
+        return kvw.write_rows(caches, plan, new, jnp.int32(1))
+
+    new = tuple(s((rows * width, sh[2], sh[4])) for sh in pools)
+    text = jax.jit(write, donate_argnums=0).lower(
+        caches, s((rows, 64), jnp.int32), s((rows,), jnp.int32),
+        s((rows,), jnp.int32), new,
+    ).compile().as_text()
+    assert _kernel_calls(text, "kv_write_kernel") == 1
 
 
 @pytest.mark.parametrize(
@@ -323,6 +398,11 @@ def test_step_compiles_and_keeps_the_pool_still(
         # one launch a scanned layer: the device trace's readers
         # (`paged_attention_roofline.batch`) find the kernel by this name
         assert _kernel_calls(text, "paged_attention_kernel") == 1
+    # The lists the two launches walk (the plan's live-first order, the
+    # decode kernel's rows) are made once a step: nothing of a sort, a
+    # running sum or a scatter rides the layer scan's body.
+    listed = _scan_body_lists(text, "kv_write_kernel")
+    assert not listed, "\n".join(listed)
 
 
 def _tp4_shapes(topo, layers=2):

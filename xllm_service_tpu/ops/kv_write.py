@@ -47,12 +47,16 @@ from xllm_service_tpu.ops.pallas.kv_write import kv_write_kernel
 class _Units(NamedTuple):
     """Tiles one step's rows touch, at one granularity (docs/KV_CACHE.md,
     ops/pallas/kv_write.py): `tile` token positions per unit, `count`
-    units per sequence."""
+    units per sequence. The units stand LIVE FIRST (a stable partition,
+    made once a step): the launch walks the first `live` of them."""
 
     blk: jnp.ndarray  # [S*count] block id (0 = garbage, for dead units)
     sub: jnp.ndarray  # [S*count] tile index inside the block
     lo: jnp.ndarray  # [S*count] first new position of the tile
     hi: jnp.ndarray  # [S*count] one past the last (lo == hi: dead unit)
+    order: jnp.ndarray  # [S*count] the unit's place in sequence order,
+    # s*count + c: which of _unit_tiles' tiles it takes
+    live: jnp.ndarray  # [] how many units write anything
     shift: jnp.ndarray  # [S] where a sequence's first tile starts in its
     # front-padded rows (see _unit_tiles)
     tile: int
@@ -83,9 +87,10 @@ def _plan_units(tables, start, length, width: int, bs: int, tile: int):
     bi = jnp.minimum(pos // bs, tables.shape[1] - 1)
     blk = jnp.where(live, jnp.take_along_axis(tables, bi, axis=1), 0)
     sub = jnp.where(live, (pos % bs) // tile, 0)
+    order = jnp.argsort(~live.reshape(-1), stable=True).astype(jnp.int32)
     return _Units(
-        blk.reshape(-1), sub.reshape(-1), lo.reshape(-1), hi.reshape(-1),
-        tile - start % tile, tile, count,
+        *(a.reshape(-1)[order] for a in (blk, sub, lo, hi)), order,
+        jnp.sum(live, dtype=jnp.int32), tile - start % tile, tile, count,
     )
 
 
@@ -157,9 +162,9 @@ def _write_units(caches, rows, units: _Units, layer, lanes, plan):
     and the rows both carry the head axis; the units replicate)."""
     tiles = tuple(_unit_tiles(r, units, lanes) for r in rows)
 
-    def body(caches, tiles, blk, sub, lo, hi, layer):
+    def body(caches, tiles, blk, sub, lo, hi, order, live, layer):
         return kv_write_kernel(
-            caches, tiles, blk, sub, lo, hi, layer,
+            caches, tiles, blk, sub, lo, hi, order, live, layer,
             tile=caches[0].shape[-2] if lanes else units.tile,
             axis=-1 if lanes else -2, interpret=plan.interpret,
         )
@@ -170,12 +175,12 @@ def _write_units(caches, rows, units: _Units, layer, lanes, plan):
         body = jax.shard_map(
             body, mesh=mesh,
             in_specs=((pool,) * len(caches), (tile,) * len(tiles))
-            + (P(),) * 5,
+            + (P(),) * 7,
             out_specs=(pool,) * len(caches), check_vma=False,
         )
     return body(
         tuple(caches), tiles, units.blk, units.sub, units.lo, units.hi,
-        jnp.asarray(layer, jnp.int32),
+        units.order, units.live, jnp.asarray(layer, jnp.int32),
     )
 
 

@@ -22,14 +22,18 @@ all local heads: `[Hc, tile, D]`, 16 rows for bf16 — described by a UNIT:
   lo, hi     the rows (data) or lanes (int8 scale planes, where the
              token axis lies on lanes) of the tile that are new
 
-The grid walks the units; Pallas pipelines the tile in, the body selects
-`lo <= i < hi ? new : old`, and the tile goes back to where it came from.
-Units are made outside the layer scan, once per step
-(ops/kv_write.write_plan); a unit with `lo == hi` writes nothing and
-points at the reserved garbage block 0. No two units of one call name the
-same live tile (a sequence's rows are consecutive positions and sequences
-own their blocks), so the pipelined read of the next tile never races a
-write.
+The grid walks the LIVE units; Pallas pipelines the tile in, the body
+selects `lo <= i < hi ? new : old`, and the tile goes back to where it
+came from. Units are made outside the layer scan, once per step
+(ops/kv_write.write_plan), live ones first: the grid's bound is their
+count (a dynamic grid dimension), so a slot that holds no sequence costs
+no grid step, and `order[u]` says which of the step's new tiles unit u
+takes (read in the index map: the rows are not gathered). A unit with
+`lo == hi` writes nothing and points at the reserved garbage block 0; the
+one such unit a launch visits when nothing is live puts that block's tile
+back as it was. No two units of one call name the same live tile (a
+sequence's rows are consecutive positions and sequences own their
+blocks), so the pipelined read of the next tile never races a write.
 
 One body serves data tiles (mask along sublanes) and int8 scale tiles
 `[Hc, G, BS]` (mask along lanes), K and V in one launch.
@@ -51,6 +55,7 @@ def _kv_write_kernel(
     sub_ref,    # [U] SMEM — tile index inside the block (index maps only)
     lo_ref,     # [U] SMEM — first new row/lane of the tile
     hi_ref,     # [U] SMEM — one past the last new row/lane
+    order_ref,  # [U] SMEM — which new tile the unit takes (index maps only)
     layer_ref,  # [1] SMEM — which layer of the stack (index maps only)
     *refs,      # n new tiles [Hc, tile|1, D], n old tiles [Hc, tile, D],
     # then the n output tiles (the old tiles' home in the aliased pool)
@@ -75,10 +80,12 @@ def _kv_write_kernel(
 def kv_write_kernel(
     caches,   # tuple of stacked pools [L, N, Hc, X, Y] (K and V, or scales)
     tiles,    # tuple of new tiles [U, Hc, x, y], x/y the tile's or 1
-    blk: jnp.ndarray,    # [U] int32
+    blk: jnp.ndarray,    # [U] int32, live units first
     sub: jnp.ndarray,    # [U] int32
     lo: jnp.ndarray,     # [U] int32
     hi: jnp.ndarray,     # [U] int32
+    order: jnp.ndarray,  # [U] int32: unit u takes tiles[order[u]]
+    n_live,              # int32 scalar: the units before this one are live
     layer,               # int32 scalar
     tile: int,           # rows per tile along X (X itself for scale planes)
     axis: int,
@@ -89,10 +96,13 @@ def kv_write_kernel(
     n = len(caches)
     U = blk.shape[0]
     Hc = caches[0].shape[2]
-    pre = 5  # scalar-prefetch operands ahead of the tensor inputs
+    pre = 6  # scalar-prefetch operands ahead of the tensor inputs
 
-    def pool_map(u, blk, sub, lo, hi, layer):
+    def pool_map(u, blk, sub, lo, hi, order, layer):
         return layer[0], blk[u], 0, sub[u], 0
+
+    def tile_map(u, blk, sub, lo, hi, order, layer):
+        return order[u], 0, 0, 0
 
     # one spec a pool: the K and V rows of a family may differ in lanes
     pool_specs = [
@@ -100,15 +110,17 @@ def kv_write_kernel(
         for c in caches
     ]
     tile_specs = [
-        pl.BlockSpec((None,) + t.shape[1:], lambda u, *_: (u, 0, 0, 0))
-        for t in tiles
+        pl.BlockSpec((None,) + t.shape[1:], tile_map) for t in tiles
     ]
+    # The grid's bound follows the step: the live units, and one (dead)
+    # unit where there is none, so that the launch is never empty.
+    visited = jnp.maximum(jnp.asarray(n_live, jnp.int32), 1)
     out = pl.pallas_call(
         functools.partial(_kv_write_kernel, n=n, axis=axis),
         name="kv_write_kernel",  # op name in the device trace
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=pre,
-            grid=(U,),
+            grid=(visited,),
             in_specs=tile_specs + pool_specs,
             out_specs=pool_specs,
         ),
@@ -128,7 +140,7 @@ def kv_write_kernel(
         interpret=interpret,
     )(
         blk.astype(jnp.int32), sub.astype(jnp.int32),
-        lo.astype(jnp.int32), hi.astype(jnp.int32),
+        lo.astype(jnp.int32), hi.astype(jnp.int32), order.astype(jnp.int32),
         jnp.asarray(layer, jnp.int32).reshape(1),
         *tiles, *caches,
     )

@@ -5,7 +5,10 @@ analog lives in the absent engine submodule). One query token per running
 sequence attends to that sequence's paged KV context.
 
 Design (flash-decode; the DMA schedule is the kernel, PR 40):
-  * grid = (R, Hkv / HF), both axes "arbitrary": one program per sequence
+  * grid = (steps, Hkv / HF), both axes "arbitrary": one program per LIVE
+    sequence (the row axis walks a scalar-prefetch list of the step's
+    live rows under a dynamic bound, `_visits`: a slot that holds no
+    sequence costs no grid step)
     and HF KV heads (two where the heads pair up, else one; never more:
     an 8x head-unrolled body stalls the Mosaic compiler). The heads'
     matmul -> reduce -> exp -> matmul chains are independent and hide each
@@ -47,9 +50,10 @@ Design (flash-decode; the DMA schedule is the kernel, PR 40):
     finite rows. Zeroing once costs 0.1 us a launch; selecting V rows to
     zero would cost a [C*BS, D] select a chunk.
   * the q and o tiles of RB rows (all their heads) are ONE VMEM block:
-    fetched once, written back once, so a grid step, dead rows' too,
-    moves nothing but its own K and V. A dead row's step is a length
-    read, a branch and a zero store.
+    fetched once, written back once, so a grid step moves nothing but
+    its own K and V. Dead rows are not visited and read exact zeros all
+    the same: a block's first row always is, and its step zeroes the
+    whole o block before the live steps write their rows.
   * GQA: the G = Hq//Hkv query heads of one KV head are processed together,
     zero-padded to Gp = roundup(G, 8) sublanes to satisfy TPU tiling;
     scores are bf16-in/f32-accum on the MXU (the fast path).
@@ -132,6 +136,8 @@ def _decode_kernel(
     seq_lens_ref,     # [R]      SMEM
     layer_ref,        # [1]      SMEM — which layer of the stack to read
     next_live_ref,    # [R]      SMEM — next row after r with seq_len > 0, R if none
+    visit_ref,        # [R]      SMEM — the row of each step of the grid's row
+    #                   axis (index maps too): see _visits
     # inputs
     q_ref,            # [RB, Hkv, Gp, D] VMEM: the tiles of RB rows, fetched
     #                   once for their RB * Hkv / HF grid steps
@@ -169,9 +175,10 @@ def _decode_kernel(
     else:
         o_ref, k_buf, v_buf, sems, handover = rest
         ks_hbm = vs_hbm = ks_buf = vs_buf = ssems = None
-    r = pl.program_id(0)
+    step = pl.program_id(0)
     hb = pl.program_id(1)
-    rows, head_blocks = pl.num_programs(0), pl.num_programs(1)
+    r = visit_ref[step]
+    rows, head_blocks = seq_lens_ref.shape[0], pl.num_programs(1)
     fold = k_buf.shape[2]  # HF: the KV heads of one grid step
     lyr = layer_ref[0]
     span = chunk * block_size
@@ -246,7 +253,7 @@ def _decode_kernel(
 
         for_live_blocks(c, b_lo, nb, wait)
 
-    @pl.when((r == 0) & (hb == 0))
+    @pl.when((step == 0) & (hb == 0))
     def _launch_begins():
         handover[0] = 0
         handover[1] = 0
@@ -262,13 +269,15 @@ def _decode_kernel(
     r_in = jax.lax.rem(r, q_ref.shape[0])  # this row within its q / o block
     h0 = hb * fold
 
-    # Inactive decode slots carry seq_len = 0: they start nothing, touch
-    # neither the slots nor the hand-over, and emit zeros.
-    @pl.when(seq_len <= 0)
-    def _dead():
-        o_ref[r_in, pl.ds(h0, fold)] = jnp.zeros(
-            (fold, *o_ref.shape[2:]), o_ref.dtype
-        )
+    # Inactive decode slots carry seq_len = 0 and emit zeros, yet the grid
+    # does not visit them: the first row of a block of rows is always
+    # visited, live or not, and its step zeroes the whole o block before
+    # the live steps write their rows over that. (A dead first row does
+    # this alone: it starts nothing and touches neither the slots nor the
+    # hand-over.)
+    @pl.when((r_in == 0) & (hb == 0))
+    def _block_begins():
+        o_ref[...] = jnp.zeros_like(o_ref)
 
     @pl.when(seq_len > 0)
     def _live():
@@ -407,6 +416,28 @@ def _next_live(seq_lens):
     return jnp.concatenate([after, jnp.full((1,), R, jnp.int32)])
 
 
+def _visits(seq_lens, row_block: int):
+    """(steps, visit): the rows the grid's row axis walks, in order, and
+    how many: the live rows, and the FIRST row of every block of
+    `row_block` rows whether it lives or not, whose step zeroes the
+    block's output (so a block with no live row has its zeros, and there
+    is always a step). Past `steps` the list repeats its last row, so an
+    index map that looks ahead finds a block that is there.
+
+    A stable partition by one sort, as the state kernels' unit order is
+    made (ops/mamba.py `_units`). In a step program's compiled text XLA
+    moves the sort out of the layer scan and keeps the making of the
+    scalar `steps`, whatever it is made of, in the scan's body: so the
+    count is one reduce over a mask that is elementwise in `seq_lens`
+    (tests/test_tpu_compile.py holds the body to that)."""
+    R = seq_lens.shape[0]
+    rows = jnp.arange(R, dtype=jnp.int32)
+    walked = (seq_lens > 0) | (rows % row_block == 0)
+    steps = jnp.sum(walked, dtype=jnp.int32)
+    order = jnp.argsort(~walked, stable=True).astype(jnp.int32)
+    return steps, order[jnp.minimum(rows, steps - 1)]
+
+
 def _launch(name, qr, k_cache, v_cache, layer, block_table, seq_lens, *,
             scale, chunk, window, interpret, s_rows, gp, sinks=None):
     """One `pallas_call` of `_decode_kernel` over q tiles [R, Hkv, T, D]
@@ -448,15 +479,22 @@ def _launch(name, qr, k_cache, v_cache, layer, block_table, seq_lens, *,
     hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)
     # The q and o tiles of RB rows ride ONE block: a block whose index does
     # not change between grid steps is neither fetched nor written back, so
-    # a grid step costs no DMA of the block pipeline's (a dead row's step was
-    # 0.3 us of nothing else), only its own K and V.
+    # a grid step costs no DMA of the block pipeline's, only its own K and V.
     RB = _row_block(R, Hkv * T * D * qr.dtype.itemsize)
-    tile = pl.BlockSpec((RB, Hkv, T, D), lambda r, h, *_: (r // RB, 0, 0, 0))
-    o_tile = pl.BlockSpec((RB, Hkv, T, Dv), lambda r, h, *_: (r // RB, 0, 0, 0))
+    # The row axis of the grid walks the step's live rows (a dynamic
+    # bound): a slot that holds no sequence costs no grid step.
+    steps, visit = _visits(seq_lens, RB)
+    if RB == R:
+        block_of = lambda i, h, *pre: (0, 0, 0, 0)
+    else:
+        block_of = lambda i, h, *pre: (pre[-1][i] // RB, 0, 0, 0)
+    tile = pl.BlockSpec((RB, Hkv, T, D), block_of)
+    o_tile = pl.BlockSpec((RB, Hkv, T, Dv), block_of)
     in_specs = [tile, hbm, hbm]
-    inputs = [bt, seq_lens, layer, _next_live(seq_lens), qr, k_data, v_data]
+    inputs = [bt, seq_lens, layer, _next_live(seq_lens), visit, qr, k_data,
+              v_data]
     if sinks is not None:
-        in_specs.append(pl.BlockSpec((Hkv, T, 128), lambda r, h, *_: (0, 0, 0)))
+        in_specs.append(pl.BlockSpec((Hkv, T, 128), lambda i, h, *_: (0, 0, 0)))
         inputs.append(jnp.broadcast_to(
             sinks.astype(jnp.float32)[:, :, None], (Hkv, T, 128)
         ))
@@ -493,8 +531,8 @@ def _launch(name, qr, k_cache, v_cache, layer, block_table, seq_lens, *,
         kernel,
         name=name,  # op name in the device trace
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(R, Hkv // HF),
+            num_scalar_prefetch=5,
+            grid=(steps, Hkv // HF),
             in_specs=in_specs,
             out_specs=o_tile,
             scratch_shapes=scratch,
