@@ -1,0 +1,237 @@
+"""One context bucket wherever attention runs as the kernels
+(`ModelExecutor._ctx_bucket`, docs/KV_CACHE.md "Context buckets"): a step
+takes a table as wide as it needs. The Pallas kernels walk a row's context
+and not its table, so there that is the WHOLE table and one decode and one
+mixed program serve every context; the gather and blockwise fallbacks read
+every column, so there it is the next power of two. What is pinned here,
+on the CPU:
+
+  * the rule, from what `kernel_report()` resolves to (the platform seam
+    `ops.attention._on_tpu`, and stubs of the report for the routes a CPU
+    executor cannot build), and a window family's whole table under either;
+  * a seeded mixed workload served with the whole table and with the grid
+    gives the same tokens and logprobs, on a GQA and on a hybrid stack
+    (the gather reads the wider table; its masked columns weigh nothing);
+  * with the whole table `lowering_count()` stays flat over a workload
+    that crosses every boundary of the old grid, after the first decode
+    and the first mixed step; with the grid it grows.
+
+The programs themselves, lowered for a described v5e, are in
+tests/test_tpu_compile.py.
+"""
+
+import types
+
+import numpy as np
+import pytest
+
+from xllm_service_tpu.common.config import EngineConfig
+from xllm_service_tpu.ops import attention
+from xllm_service_tpu.ops.sampling import SamplingParams
+from xllm_service_tpu.runtime.engine import EngineRequest, InferenceEngine
+from xllm_service_tpu.runtime.executor import ModelExecutor
+
+BS = 16
+
+
+def _cfg(model, **kw):
+    base = dict(
+        model=model, dtype="float32", block_size=BS, num_blocks=80,
+        max_running_requests=4, max_seq_len=256, max_prefill_tokens=32,
+        prefill_buckets=[32],
+    )
+    base.update(kw)
+    return EngineConfig(**base)
+
+
+# ------------------------------------------------------------------ the rule
+
+
+def _stub(report, spec=0, paged=True, window=False):
+    """An executor as far as the rule reads it: no weights, no pools."""
+    ex = object.__new__(ModelExecutor)
+    ex.has_paged_cache = paged
+    ex.window_tables = window
+    ex.max_blocks_per_seq = 16 if paged else 1
+    ex.engine_cfg = types.SimpleNamespace(speculative_tokens=spec)
+    ex.kernel_report = lambda: dict(report)
+    ex.whole_table = ex._table_costs_nothing()
+    return ex
+
+
+GRID = [1, 2, 4, 4, 8, 8, 8, 8, 16, 16, 16, 16, 16, 16, 16, 16]
+
+
+@pytest.mark.parametrize(
+    "report,spec,whole",
+    [
+        ({"decode": "paged", "prefill": "flash", "mq": "mq"}, 0, True),
+        ({"decode": "mla", "prefill": "mla-flash", "mq": "blockwise"}, 0, True),
+        ({"decode": "paged", "prefill": "flash", "mixed": "ragged"}, 0, True),
+        ({"decode": "gather", "prefill": "blockwise", "mq": "blockwise"}, 0, False),
+        ({"decode": "gather (forced-off)", "prefill": "flash"}, 0, False),
+        ({"decode": "gather-fallback", "prefill": "flash"}, 0, False),
+        ({"decode": "paged", "prefill": "blockwise (forced-off)"}, 0, False),
+        ({"decode": "mla", "prefill": "blockwise"}, 0, False),  # an int8 latent pool
+        # the verify shapes ride a launch of their own where the engine speculates
+        ({"decode": "paged", "prefill": "flash", "mq": "mq"}, 3, True),
+        ({"decode": "paged", "prefill": "flash", "mq": "blockwise"}, 3, False),
+        ({"decode": "mla", "prefill": "mla-flash", "mq": "mla-mq"}, 3, True),
+        ({"decode": "mla", "prefill": "mla-flash", "mq": "blockwise"}, 3, False),
+    ],
+    ids=["gqa-kernels", "mla-kernels", "ragged", "fallback", "decode-forced-off",
+         "unpacked-at-tp", "prefill-forced-off", "mla-int8-prefill", "spec-mq",
+         "spec-no-mq", "spec-mla-mq", "spec-mla-no-mq"],
+)
+def test_the_table_is_whole_where_every_launch_is_a_kernel(report, spec, whole):
+    ex = _stub(report, spec)
+    assert ex.whole_table is whole
+    buckets = [ex._ctx_bucket(need) for need in range(1, 17)]
+    assert buckets == ([16] * 16 if whole else GRID)
+    assert list(ex._decode_cb_walk()) == ([16] if whole else [1, 2, 4, 8, 16])
+
+
+@pytest.mark.parametrize("decode", ["paged", "gather"])
+def test_a_window_family_takes_the_whole_table_under_either(decode):
+    ex = _stub({"decode": decode, "prefill": "blockwise", "window": "window-xla"},
+               window=True)
+    assert ex.whole_table and {ex._ctx_bucket(n) for n in range(1, 17)} == {16}
+
+
+def test_a_state_pool_alone_has_one_column_and_asks_no_report():
+    ex = _stub(None, paged=False)  # (a report of None: never read)
+    assert not ex.whole_table and ex._ctx_bucket(1) == 1
+
+
+@pytest.mark.parametrize(
+    "model,on_chip,whole",
+    [
+        ("llama3-shard-tiny", False, False),
+        ("llama3-shard-tiny", True, True),  # 128-lane rows, 16-row blocks: the kernels
+        ("llama3-tiny", True, False),  # 64-lane rows: the gather on the chip too
+        ("deepseek-tiny", False, False),
+        ("deepseek-tiny", True, True),
+        ("granite-tiny", True, False),  # 16-lane K/V beside the state pool
+        ("mimo-tiny", False, True),  # a window family: on every backend
+        ("mimo-tiny", True, True),
+        ("brumby-tiny", True, False),  # a state pool alone: one column
+    ],
+)
+def test_an_executor_decides_at_build_time_from_its_own_report(
+    cpu_devices, monkeypatch, model, on_chip, whole
+):
+    """The platform seam: what the dispatchers would do on the attached
+    backend decides, once, when the executor is built (no kernel runs
+    here: nothing is dispatched)."""
+    monkeypatch.setattr(attention, "_on_tpu", lambda: on_chip)
+    ex = ModelExecutor(_cfg(model), init_seed=0)
+    rep = ex.kernel_report()
+    kernels = (rep["decode"], rep["prefill"]) in (("paged", "flash"), ("mla", "mla-flash"))
+    assert ex.whole_table is whole and (kernels or ex.window_tables) is whole
+    MB = ex.max_blocks_per_seq
+    grid = [ModelExecutor._pow2_bucket(n, MB) for n in range(1, MB + 1)]
+    buckets = [ex._ctx_bucket(n) for n in range(1, MB + 1)]
+    assert buckets == ([MB] * MB if whole else grid)
+    # the prewarm walks enumerate the family the rule leaves, by themselves
+    assert list(ex._decode_cb_walk()) == sorted(set(buckets))
+    assert {cb for _, cb, _, _ in ex._prefill_shape_family()} <= set(buckets)
+    if whole:
+        assert [b for b, _, _, _ in ex._prefill_shape_family()] == ex.prefill_buckets
+
+
+# ------------------------------------- the same tokens, and a flat count
+
+
+def _req(rid, outs, prompt, sampling):
+    def cb(o):
+        for s in o.outputs:
+            outs.setdefault(rid, []).extend(s.token_ids)
+            outs.setdefault(rid + "/lp", []).extend(lp.data.logprob for lp in s.logprobs)
+        return True
+
+    return EngineRequest(request_id=rid, prompt_token_ids=list(prompt),
+                         sampling=sampling, callback=cb)
+
+
+def _sp(max_new, **kw):
+    return SamplingParams(max_new_tokens=max_new, logprobs=True, ignore_eos=True, **kw)
+
+
+def _serve(model, whole_table):
+    """One seeded mixed workload (greedy, seeded, penalized; chunked
+    prompts of 1 to 10 blocks admitted while others decode; answers that
+    carry a row across the 1-, 2-, 4- and 8-block boundaries) on a CPU
+    executor, whose launches are the gather and the blockwise scan, with
+    the grid it takes by itself or with the whole table a kernel executor
+    takes. Returns (outs, lowerings after the first request's second
+    token, lowerings at the end)."""
+    cfg = _cfg(model)
+    ex = ModelExecutor(cfg, init_seed=0)
+    assert not ex.whole_table and ex.max_blocks_per_seq == 16
+    ex.whole_table = whole_table
+    eng = InferenceEngine(cfg, executor=ex)
+    rng = np.random.default_rng(51)
+    outs = {}
+
+    def add(rid, n, sampling):
+        eng.add_request(_req(rid, outs, rng.integers(0, 500, n), sampling))
+
+    # 11 -> 71 tokens: 1, 2, 4, 8 blocks (penalized, so that the admission's
+    # scatter over the slot's histogram is met here too)
+    add("first", 11, _sp(60, temperature=0.0, presence_penalty=0.1))
+    while len(outs.get("first", ())) < 2:
+        eng.step()
+    after_first = ex.lowering_count()
+    add("seeded", 40, _sp(30, temperature=0.9, top_k=20, seed=5))  # 3 -> 5 blocks
+    for _ in range(3):
+        eng.step()
+    add("long", 150, _sp(12, temperature=0.0))  # five chunks, 2 -> 10 blocks, then 11
+    for _ in range(7):  # (one prompt a step: a group of two is a program of its own)
+        eng.step()
+    add("penal", 100, _sp(40, temperature=0.6, seed=11, presence_penalty=0.4,
+                          frequency_penalty=0.2))  # 7 -> 9 blocks
+    for _ in range(2000):
+        if not eng.has_work():
+            break
+        eng.step()
+    assert not eng.has_work()
+    assert {r: len(outs[r]) for r in ("first", "seeded", "long", "penal")} == {
+        "first": 60, "seeded": 30, "long": 12, "penal": 40}
+    return outs, after_first, ex.lowering_count()
+
+
+@pytest.fixture(scope="module", params=["llama3-shard-tiny", "granite-tiny"])
+def served_both_ways(request, cpu_devices):
+    return _serve(request.param, False), _serve(request.param, True)
+
+
+def test_the_whole_table_serves_the_grids_tokens_and_logprobs(served_both_ways):
+    (grid, _, _), (whole, _, _) = served_both_ways
+    for rid in ("first", "seeded", "long", "penal"):
+        assert whole[rid] == grid[rid], rid
+        np.testing.assert_allclose(whole[rid + "/lp"], grid[rid + "/lp"], atol=2e-5, err_msg=rid)
+
+
+def test_lowerings_stay_flat_across_every_old_bucket_boundary(served_both_ways):
+    (_, grid_first, grid_end), (_, whole_first, whole_end) = served_both_ways
+    # one decode and one mixed program (and the admission's histogram
+    # scatter) were there after the first request's second token; nothing
+    # the rest of the workload met was new
+    assert whole_end == whole_first == 3
+    # the grid met a program at (nearly) every boundary it crossed
+    assert grid_end >= grid_first + 6
+
+
+@pytest.mark.parametrize(
+    "model", ["llama3-shard-tiny", "deepseek-tiny", "granite-tiny", "mimo-tiny", "brumby-tiny"]
+)
+def test_every_array_a_step_donates_is_placed_at_build(cpu_devices, model):
+    """A step hands its pools and the histogram back COMMITTED to the mesh;
+    an unplaced first copy is another signature, and the first step program
+    of a new executor compiled twice (the MLA family's one-element V dummy
+    until PR 51: a second whole-model mixed program in doc-steady)."""
+    import jax
+
+    ex = ModelExecutor(_cfg(model), init_seed=0)
+    leaves = jax.tree.leaves((ex.k_cache, ex.v_cache, ex.token_counts))
+    assert leaves and all(x.committed for x in leaves)

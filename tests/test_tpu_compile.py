@@ -868,3 +868,102 @@ def test_solar_tiny_steps_compile_and_neither_pool_is_laid_out_again(one_chip, n
                 moved.append(line.strip()[:160])
     assert not moved, "\n".join(moved)
     assert ("kda_update_kernel" in text) == (step == "mixed")
+
+
+# ---- PR 51: one context bucket wherever attention runs as the kernels
+# (`ModelExecutor._ctx_bucket`). The executors below are built on the CPU
+# with the dispatchers steered as on the chip, so their report resolves to
+# the kernels; no kernel can run here, so every step program is a stand-in
+# that lowers and compiles each NEW signature for the described chip and
+# hands back zeros of the program's outputs.
+
+
+def _described_step_programs(monkeypatch, one_chip):
+    """Patch `ModelExecutor._step_jit`: {program: {signature: out_info}} of
+    what the executors built afterwards dispatch."""
+    import numpy as np
+
+    from xllm_service_tpu.runtime.executor import ModelExecutor
+
+    seen = {}
+    real_step_jit = ModelExecutor._step_jit
+
+    def step_jit(self, impl, **jit_kw):
+        real = real_step_jit(self, impl, **jit_kw)
+        mine = seen.setdefault(impl.__name__, {})
+
+        def call(*a, **kw):
+            a, kw = jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+                if isinstance(x, (jax.Array, np.ndarray, np.generic)) else x,
+                (a, kw),
+            )
+            key = str((a, kw))
+            if key not in mine:
+                lowered = real.lower(*a, **kw)
+                assert "tpu_custom_call" in lowered.compile().as_text()
+                mine[key] = lowered.out_info
+            out = jax.tree.map(lambda o: jnp.zeros(o.shape, o.dtype), mine[key])
+            return out[0] if self.cfg.is_moe else out  # (the router's counts beside it)
+
+        call._cache_size = lambda: len(mine)
+        return call
+
+    monkeypatch.setattr(ModelExecutor, "_step_jit", step_jit)
+    return seen
+
+
+@pytest.mark.parametrize("model", ["llama3-shard-tiny", "granite-tiny"])
+def test_one_decode_and_one_mixed_program_serve_every_context(
+    one_chip, no_persistent_cache, as_on_tpu, monkeypatch, model
+):
+    """On a v5e the tiny llama and the tiny hybrid executors (K/V heads of
+    128 lanes, so that the launches are the kernels) lower exactly ONE
+    decode and ONE mixed program over contexts from one block to
+    `max_seq_len`, each of which the chip's compiler takes with the whole
+    table as its scalar operand; `prewarm_programs()` enumerates that
+    smaller family by itself: a program a prefill bucket (and group size,
+    left out here), none a context bucket."""
+    import numpy as np
+
+    from xllm_service_tpu.common.config import EngineConfig
+    from xllm_service_tpu.runtime.executor import ModelExecutor, PrefillItem, SamplingBatch
+
+    monkeypatch.setenv("XLLM_RAGGED_ATTENTION_KERNEL", "0")  # the cells' route: the pair of kernels
+    seen = _described_step_programs(monkeypatch, one_chip)
+    R, bs, MB = 4, 16, 16
+    ecfg = EngineConfig(
+        model=model, dtype="bfloat16", block_size=bs, num_blocks=80, max_running_requests=R,
+        max_seq_len=bs * MB, max_prefill_tokens=32, prefill_buckets=[32],
+    )
+    ex = ModelExecutor(ecfg, model_cfg=dataclasses.replace(get_model_config(model), head_dim=128))
+    try:
+        rep = ex.kernel_report()
+        assert (rep["decode"], rep["prefill"]) == ("paged", "flash") and ex.whole_table
+        assert ex.max_blocks_per_seq == MB
+        batch = SamplingBatch(
+            temperature=np.zeros(R, np.float32), top_k=np.zeros(R, np.int32),
+            top_p=np.ones(R, np.float32), seeds=np.zeros(R, np.uint32), steps=np.zeros(R, np.int32),
+        )
+        tables = np.zeros((R, MB), np.int32)
+        active = np.array([True, True, False, False])
+        zeros = np.zeros((R,), np.int32)
+        for blocks in range(1, MB + 1):  # the longest row's context, in blocks
+            positions = np.array([blocks * bs - 1, 3, 0, 0], np.int32)
+            ex.decode_start(zeros, None, None, positions, tables, active, batch)
+            n = 32 if blocks > 1 else 9  # a chunk that ends in block `blocks`
+            item = PrefillItem(token_ids=np.zeros((n,), np.int32), start_pos=blocks * bs - n - 1,
+                               block_table=np.zeros((MB,), np.int32), slot=2)
+            ex.mixed_start([item], zeros, None, None, positions[::-1].copy(), tables, active[::-1].copy(), batch)
+        assert {p: len(sigs) for p, sigs in seen.items() if sigs} == {"_decode_impl": 1, "_mixed_impl": 1}
+        assert ex.lowering_count() == 2
+
+        # the whole family: the split prefill and the mixed step of every
+        # prefill bucket at one row, and the decode step
+        report = ex.prewarm_programs(p_groups=False)
+        assert report["families"]["split"] == report["families"]["mixed"] == len(ex.prefill_buckets)
+        assert {p: len(sigs) for p, sigs in seen.items() if sigs} == {
+            "_decode_impl": 1, "_prefill_impl": len(ex.prefill_buckets),
+            "_mixed_impl": len(ex.prefill_buckets)}
+    finally:
+        attention.set_shard_context(None)

@@ -347,13 +347,15 @@ class ModelExecutor:
         """The decode rows of one dispatch as ONE device array
         [R, len(DEC_FIELDS) + CB] (pack_rows; _dec_rows unpacks it in the
         program); a `fresh_mask` of None is all ones. The block table is
-        sliced to the batch's true context bound (pow2 bucket: <=
-        log2(max_blocks) compiles; the gather fallback otherwise
-        materializes [R, max_blocks*BS] context per layer even when every
-        sequence is short). With no row live (a mixed step whose
-        sequences all prefill) no table is read, and the half takes the
-        largest bucket, which every deployment warms: a lone chunk asks
-        for no program of its own per prefill bucket."""
+        cut to `_ctx_bucket` of the batch's true context bound: the whole
+        table where the attention launches are the kernels (CB is always
+        max_blocks_per_seq, one program), the next power of two where
+        they are the gather fallback (<= log2(max_blocks) compiles; the
+        gather otherwise materializes [R, max_blocks*BS] context per layer
+        even when every sequence is short). With no row live (a mixed
+        step whose sequences all prefill) no table is read, and the half
+        takes the largest bucket, which every deployment warms: a lone
+        chunk asks for no program of its own per prefill bucket."""
         need = self.max_blocks_per_seq
         if active.any():
             need = int(
@@ -726,13 +728,22 @@ class ModelExecutor:
                     out_shardings=cache_sharding,
                 )
                 self.k_cache = alloc()
+                # Placed like the copy every step hands back: an unplaced
+                # dummy made the first step program compile twice (a
+                # second mixed program in doc-steady, PERF.md PR 51).
                 self.v_cache = kvc.PagedKV(
-                    jnp.zeros(
-                        (self.cfg.num_layers, 1, 1, 1, 1), self.dtype
+                    jax.device_put(
+                        jnp.zeros(
+                            (self.cfg.num_layers, 1, 1, 1, 1), self.dtype
+                        ),
+                        NamedSharding(self.mesh, P()),
                     ),
                     None,
                 )
 
+        # One context bucket or a grid of them (`_ctx_bucket`), decided
+        # once, from what kernel_report() says of the pools just built.
+        self.whole_table = self._table_costs_nothing()
         # Generated-token histogram per slot (presence/frequency penalties).
         # int32 [R, V] — 32 MB at V=128K, R=64; donated through every step.
         # Replicated over the mesh like the copy every step hands back: an
@@ -1316,16 +1327,43 @@ class ModelExecutor:
             b *= 2
         return min(b, cap)
 
-    def _ctx_bucket(self, need: int) -> int:
-        """Blocks a table of a step whose rows need `need`: the next power
-        of two, so a family's step programs are one per bucket. A window
-        family takes the WHOLE table at every context: its launches are
-        the Pallas kernels, whose walk is bounded by a row's context and
-        not by its table, so a wide table costs its bytes in scalar memory
-        and nothing else, and one program serves every context (with two
-        kinds of attention launch a program is twice the size in the
-        compile cache; a grid of buckets would not fit it)."""
+    def _table_costs_nothing(self) -> bool:
+        """Whether a step may take the whole block table at every context
+        (`_ctx_bucket`): a window family (on every backend), and wherever
+        every attention launch of the step programs resolves to a Pallas
+        kernel (kernel_report(): decode `paged` or `mla`, prefill `flash`
+        or `mla-flash`, and the verify shapes' `mq` where the engine
+        speculates). Their walks are bounded by a row's context (`nb =
+        cdiv(seq_len, block_size)`), not by its table; the gather and
+        blockwise fallbacks read every column they are given."""
         if self.window_tables:
+            return True
+        if not self.has_paged_cache:  # a one-column table either way
+            return False
+        rep = self.kernel_report()
+        launches = [
+            rep.get("decode") in ("paged", "mla"),
+            rep.get("prefill") in ("flash", "mla-flash"),
+        ]
+        if self.engine_cfg.speculative_tokens > 0:
+            launches.append(rep.get("mq") in ("mq", "mla-mq"))
+        return all(launches)
+
+    def _ctx_bucket(self, need: int) -> int:
+        """Blocks a table of a step whose rows need `need`: a table as wide
+        as the step needs. Where the attention launches are the Pallas
+        kernels (`whole_table`, decided at build time) that is the WHOLE
+        table at every context: their walk is bounded by a row's context
+        and not by its table, so a wide table costs its bytes in scalar
+        memory and nothing else, and ONE decode and ONE mixed program
+        serve every context (a grid of buckets was 15-20 whole-model
+        programs a deployment, most of its set-up and of its compile
+        cache). A window family takes the whole table on every backend
+        (with two kinds of attention launch a program is twice the size).
+        The gather and blockwise fallbacks read every column of the table,
+        so there a step takes the next power of two: one program per
+        bucket, log2(max_blocks) of them."""
+        if self.whole_table:
             return self.max_blocks_per_seq
         return self._pow2_bucket(need, self.max_blocks_per_seq)
 
@@ -1548,8 +1586,9 @@ class ModelExecutor:
         Prefill shapes are (P, Lpad, CB); this warms EVERY reachable
         (Lpad, CB) pair at P=1 — CB is decoupled from Lpad because a
         prefix-cache hit raises start_pos, so a short suffix can carry any
-        context width up to max_blocks_per_seq. Group shapes P>1 are left
-        to first contact (at most log2(PREFILL_GROUP_MAX) extra compiles
+        context width up to max_blocks_per_seq (where every step takes
+        the whole table, `_ctx_bucket`, that is one CB). Group shapes P>1
+        are left to first contact (at most log2(PREFILL_GROUP_MAX) extra compiles
         per bucket over the process lifetime, hit only under concurrent
         admission bursts). Returns the (Lpad, CB) pairs warmed."""
         warmed: List[Tuple[int, int]] = []
@@ -1576,9 +1615,10 @@ class ModelExecutor:
             seeds=np.zeros(R, np.uint32),
             steps=np.zeros(R, np.int32),
         )
-        # Every pow2 context-width bucket decode can hit (decode() slices
-        # the table to the batch's true block bound, one compile per
-        # bucket) — positions drive the bucket; writes land in block 0.
+        # Every context-width bucket decode can hit (decode() cuts the
+        # table to `_ctx_bucket` of the batch's true block bound, one
+        # compile per bucket) — positions drive the bucket; writes land
+        # in block 0.
         for CB in self._decode_cb_walk():
             positions = np.zeros((R,), np.int32)
             positions[0] = CB * self.block_size - 1
@@ -1590,7 +1630,7 @@ class ModelExecutor:
                 batch,
             )
 
-        # Speculative verify shapes ([R, S] over the same pow2 CB buckets)
+        # Speculative verify shapes ([R, S] over the same CB buckets)
         # when the engine runs speculative decoding: verify_start's
         # context bound covers two steps of worst-case emission.
         spec = self.engine_cfg.speculative_tokens
@@ -1625,9 +1665,7 @@ class ModelExecutor:
             # CB floor matches _prefill_group's need_blocks for the
             # SHORTEST prompt in this bucket (ceil(n/bs), no +1 — the
             # next-token block is allocated by the engine, not attended).
-            CB = self._pow2_bucket(
-                max(1, (n_min + bs - 1) // bs), self.max_blocks_per_seq
-            )
+            CB = self._ctx_bucket(max(1, (n_min + bs - 1) // bs))
             while True:
                 if CB * bs <= n_full:
                     # Natural shape: a prompt of exactly CB blocks, no
@@ -1649,9 +1687,10 @@ class ModelExecutor:
                 CB = min(CB * 2, self.max_blocks_per_seq)
 
     def _decode_cb_walk(self):
-        """Every pow2 context-width bucket a decode/verify dispatch can
-        land in (1, 2, 4, ... max_blocks_per_seq)."""
-        CB = 1
+        """Every context-width bucket a decode/verify dispatch can land
+        in: 1, 2, 4, ... max_blocks_per_seq, or that last one alone where
+        every step takes the whole table (`_ctx_bucket`)."""
+        CB = self._ctx_bucket(1)
         while True:
             yield CB
             if CB >= self.max_blocks_per_seq:
@@ -1708,7 +1747,10 @@ class ModelExecutor:
         pipeline depth: _feed) this walks the fused family the engine
         will dispatch (fuses_prefill): mixed prefill+decode (CBd x
         (Lpad, CBp)), or mixed-verify when speculative decoding is
-        configured.
+        configured. Where every step takes the whole table
+        (`_ctx_bucket`: the attention launches are the kernels) CBd and
+        CBp are one value each, and the walks below enumerate that
+        smaller family by themselves.
         With the persistent cache enabled every compile also lands on
         disk, so a warm restart replays this walk as disk reads.
 
@@ -2832,7 +2874,7 @@ class ModelExecutor:
                     + 2 * S - 1
                 )
                 need = min(worst, max_len - 1) // bs + 1
-            CB = self._pow2_bucket(max(need, 1), self.max_blocks_per_seq)
+            CB = self._ctx_bucket(max(need, 1))
             opt = self._batch_opts(batch)
             if prev_tokens is None:
                 # Committed device zeros with the SAME replicated sharding
