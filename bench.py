@@ -668,10 +668,9 @@ def _engine_bench(sync: bool, mixed: bool = True, spec: int = 0,
         "mode": "sync" if sync else "overlap",
         "step_builder": builder,
         # The dispatch decision the engine RESOLVED for the step builder
-        # it actually ran — the fused step's kernel (ragged vs the
-        # mixed[<decode>+<prefill>] reference pair), or the split
-        # builder's separate pair — not the raw env var (ISSUE 9
-        # satellite).
+        # it actually ran — the fused step's pair of launches
+        # (<decode>+<prefill>), or the split builder's separate pair —
+        # not the raw env var (ISSUE 9 satellite).
         "kernel": (
             eng._kernel_names["mixed"] if mixed_ran
             else f"split[{eng._kernel_names['decode']}+"
@@ -855,7 +854,7 @@ def _run(on_tpu: bool, kv_cache_dtype: str = "auto",
         ex._set_shard_ctx()
         bs = ex.block_size
         # The dispatch decisions the serving paths RESOLVE for this
-        # cache/geometry (ops.attention.resolved_kernel_report) — the
+        # cache/geometry (ops.attention.attention_routes) — the
         # record gets which kernel actually runs, not the raw env var.
         kernel_rep = (
             ex.kernel_report() if hasattr(ex, "kernel_report") else {}

@@ -585,14 +585,14 @@ def prompts_for(seed: int) -> dict:
 
 
 def check_kernels(executor, on_chip: bool, shards: int) -> None:
-    # ops.attention.resolved_kernel_report for this executor's cache,
-    # head_dim and shard fan-out: what the dispatchers would take right now.
+    # ops.attention.attention_routes for this executor's cache, head_dim
+    # and shard fan-out: the decision the dispatchers take their branch from.
     rep = executor.kernel_report()
     log(f"kernels: {json.dumps(rep)}")
     if on_chip:
         check(
             (rep["decode"], rep["prefill"], rep["mixed"])
-            == ("paged", "flash", "ragged"),
+            == ("paged", "flash", "paged+flash"),
             f"the chip is not served by the Pallas kernels: {rep}",
         )
     else:
@@ -615,10 +615,6 @@ def run_one_chip(args, meter: CompileMeter, cache_dir: str) -> None:
             f"{stack.master.http_address}, instance registered")
         device_report(ex)
         check_kernels(ex, not args.rehearse_cpu, 1)
-        check(
-            stack.engine._ragged_interpret is False,
-            "the engine would run the ragged kernel in interpret mode",
-        )
         results = serve_traffic(stack, prompts_for(args.seed))
         log(f"compile: after serving {json.dumps(meter.snapshot())}; "
             f"{ex.lowering_count()} whole-model step programs")
@@ -703,7 +699,7 @@ def run_four_chips(args, meter: CompileMeter, cache_dir: str) -> None:
     # What it is compared with: the same weights and pool, Pallas kernels
     # forced off (plain GSPMD gather/blockwise path), same prompts.
     for var in ("XLLM_PAGED_ATTENTION_KERNEL", "XLLM_PREFILL_ATTENTION_KERNEL",
-                "XLLM_RAGGED_ATTENTION_KERNEL", "XLLM_MQ_ATTENTION_KERNEL"):
+                "XLLM_MQ_ATTENTION_KERNEL"):
         os.environ[var] = "0"
     jax.clear_caches()
     ref_stack = Stack(ecfg, mcfg, args.seed, executor=ex)
@@ -713,7 +709,7 @@ def run_four_chips(args, meter: CompileMeter, cache_dir: str) -> None:
         check(
             rep["decode"].startswith("gather")
             and rep["prefill"].startswith("blockwise")
-            and rep["mixed"].startswith("split"),
+            and rep["mq"].startswith("blockwise"),
             f"the reference engine still runs kernels: {rep}",
         )
         off = serve_traffic(ref_stack, prompts)
@@ -790,10 +786,6 @@ def main() -> int:
                 os.environ.get("XLA_FLAGS", "")
                 + f" --xla_force_host_platform_device_count={args.chips}"
             )
-    # The ragged mixed kernel is opt-in in the package (docs/KERNELS.md);
-    # the smoke serves with it.
-    os.environ.setdefault("XLLM_RAGGED_ATTENTION_KERNEL", "1")
-
     threading.Thread(
         target=lambda: (time.sleep(DEADLINE_S), os._exit(124)), daemon=True
     ).start()

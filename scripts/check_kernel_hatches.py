@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Env-hatch documentation lint — thin shim over graftlint's
 hatch-registry pass (xllm_service_tpu/analysis/hatch_registry.py; run in
-tests via tests/test_ragged_attention.py). ISSUE 10 widened the PR-9
+tests via tests/test_mixed_step.py). ISSUE 10 widened the PR-9
 `XLLM_*_KERNEL` check to EVERY `XLLM_*` env hatch read by the package
 or the bench entry points: each must have a row (with a stated default)
 in docs/ARCHITECTURE.md's hatch tables, and every row must still match

@@ -557,97 +557,6 @@ def run_mla_prefill_case(P, Lpad, Hq, kvr, dr, BS, MB, dtype=jnp.bfloat16,
     return err
 
 
-def run_ragged_case(R, P, Lcap, Hq, Hkv, D, BS, MB, dtype=jnp.bfloat16,
-                    int8=False, tile_q=128, window=0):
-    """Unified ragged mixed-batch kernel (ISSUE 9): R decode singletons +
-    P ragged prefill segments (capacity Lcap, random valid lengths and
-    absolute starts) through ONE dispatch, vs the blockwise oracle. The
-    split decode+prefill launch pair is also timed — the fusion is only
-    worth its default flip if one launch beats two on the same work."""
-    from xllm_service_tpu.ops.attention import (
-        paged_attention,
-        prefill_attention,
-        ragged_attention_blockwise,
-    )
-    from xllm_service_tpu.ops.pallas.ragged_paged_attention import (
-        ragged_paged_attention_kernel,
-    )
-
-    rng = np.random.default_rng(0)
-    seg_lens = (1,) * R + (Lcap,) * P
-    B = len(seg_lens)
-    T = sum(seg_lens)
-    N = B * MB + 1
-    q = jnp.asarray(rng.standard_normal((T, Hq, D)), dtype)
-    k = jnp.asarray(rng.standard_normal((N, Hkv, BS, D)), dtype)
-    v = jnp.asarray(rng.standard_normal((N, Hkv, BS, D)), dtype)
-    if int8:
-        from xllm_service_tpu.ops import kv_cache as kvc
-
-        k = kvc.quantize_pool(k)
-        v = kvc.quantize_pool(v)
-    bt = jnp.asarray(1 + np.arange(B * MB).reshape(B, MB) % (N - 1),
-                     jnp.int32)
-    q_len = np.ones(B, np.int32)
-    pos0 = np.zeros(B, np.int32)
-    for b in range(B):
-        cap = seg_lens[b]
-        if cap > 1:
-            q_len[b] = rng.integers(cap // 2, cap + 1)
-        pos0[b] = rng.integers(0, MB * BS - q_len[b] + 1)
-    q_len = jnp.asarray(q_len)
-    pos0 = jnp.asarray(pos0)
-    scale = 1.0 / D**0.5
-
-    ker = lambda: ragged_paged_attention_kernel(
-        q, k, v, bt, q_len, pos0, seg_lens, scale, tile_q=tile_q,
-        window=window,
-    )
-    jorc = jax.jit(
-        lambda q_, bt_, ln_, p0_: ragged_attention_blockwise(
-            q_, k, v, bt_, ln_, p0_, seg_lens, scale, window=window
-        )
-    )
-    orc = lambda: jorc(q, bt, q_len, pos0)
-
-    ok = np.asarray(ker().astype(jnp.float32))
-    og = np.asarray(orc().astype(jnp.float32))
-    # Compare each row's VALID tokens only (ragged tails are zeroed).
-    err, off = 0.0, 0
-    for b, cap in enumerate(seg_lens):
-        ln = int(q_len[b])
-        err = max(err, float(np.max(np.abs(
-            ok[off:off + ln] - og[off:off + ln]
-        ))))
-        off += cap
-    tk, tg = bench(ker), bench(orc)
-
-    # Split-launch comparison on the SAME work: the decode kernel over the
-    # R singleton rows + the flash prefill kernel over the P segments.
-    q_dec = q[:R]
-    dec_lens = (pos0[:R] + 1).astype(jnp.int32)
-    q_pf = q[R:].reshape(P, Lcap, Hq, D)
-    jsplit = jax.jit(
-        lambda qd, qp: paged_attention(
-            qd, k, v, bt[:R], dec_lens, scale, use_kernel=True,
-            window=window,
-        ).sum() + prefill_attention(
-            qp, k, v, bt[R:], pos0[R:], q_len[R:], scale,
-            use_kernel=True, window=window,
-        ).sum()
-    )
-    ts = bench(lambda: jsplit(q_dec, q_pf))
-    tok = R + float(np.sum(np.asarray(q_len[R:])))
-    print(
-        f"RAGGED R={R} P={P} Lcap={Lcap} Hq={Hq} Hkv={Hkv} D={D} BS={BS} "
-        f"MB={MB} {'int8' if int8 else 'bf16'} err={err:.4f} "
-        f"kernel={tk*1e6:8.1f}us blockwise={tg*1e6:8.1f}us "
-        f"split={ts*1e6:8.1f}us fused/split={ts/tk:5.2f}x "
-        f"tok/s={tok/tk:,.0f}"
-    )
-    return err
-
-
 # Ordered so the never-yet-chip-validated kernels come first (round 3
 # queue: int8 scale-DMA decode, MLA decode, flash prefill) — the bf16
 # decode cases at the tail were already chip-validated in round 2.
@@ -799,18 +708,6 @@ CASES = [
     ("mamba-update-tile32", run_mamba_update_case,
      dict(R=64, live=44, L=9, H=128, P=64, N=128, tile=32)),
     ("mamba-chunk", run_mamba_chunk_case, dict(Lc=256, L=9, H=128, P=64, N=128)),
-    # Unified ragged mixed-batch kernel (ISSUE 9, docs/KERNELS.md) — the
-    # engine's fused prefill+decode dispatch; never chip-validated, so it
-    # heads the queue. Geometry: llama-8B-class serving mix (decode slots
-    # + due chunked-prefill rows, production block size).
-    ("ragged-bf16", run_ragged_case,
-     dict(R=32, P=4, Lcap=512, Hq=32, Hkv=8, D=128, BS=128, MB=16)),
-    ("ragged-int8", run_ragged_case,
-     dict(R=32, P=4, Lcap=512, Hq=32, Hkv=8, D=128, BS=128, MB=16,
-          int8=True)),
-    ("ragged-swa", run_ragged_case,
-     dict(R=32, P=4, Lcap=512, Hq=32, Hkv=8, D=128, BS=128, MB=16,
-          window=512)),
     # int8 KV cache (scale DMA + column folding) at production block size
     ("dec-int8-a", run_case,
      dict(R=64, Hq=32, Hkv=8, D=128, BS=128, MB=16, ctx=2048, int8=True)),

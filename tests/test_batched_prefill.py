@@ -84,7 +84,7 @@ def test_burst_shares_compiled_steps():
     # Split stepping: this test counts _prefill_group calls, i.e. the
     # SPLIT batched-prefill plumbing (the escape hatch since ISSUE 9).
     # The mixed-step equivalent (a burst riding few fused dispatches) is
-    # covered in tests/test_ragged_attention.py.
+    # covered in tests/test_mixed_step.py.
     eng = InferenceEngine(_cfg(enable_mixed_step=False), executor=exe)
     done = []
     rng = np.random.default_rng(7)

@@ -6,8 +6,9 @@ mixed program serve every context; the gather and blockwise fallbacks read
 every column, so there it is the next power of two. What is pinned here,
 on the CPU:
 
-  * the rule, from what `kernel_report()` resolves to (the platform seam
-    `ops.attention._on_tpu`, and stubs of the report for the routes a CPU
+  * the rule, from the decision the dispatchers take their branch from
+    (`ops.attention.attention_routes` behind the platform seam
+    `ops.attention._on_tpu`, over pools given by shape for the routes a CPU
     executor cannot build), and a window family's whole table under either;
   * a seeded mixed workload served with the whole table and with the grid
     gives the same tokens and logprobs, on a GQA and on a hybrid stack
@@ -20,13 +21,16 @@ The programs themselves, lowered for a described v5e, are in
 tests/test_tpu_compile.py.
 """
 
-import types
+import re
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from xllm_service_tpu.common.config import EngineConfig
 from xllm_service_tpu.ops import attention
+from xllm_service_tpu.ops import kv_cache as kvc
 from xllm_service_tpu.ops.sampling import SamplingParams
 from xllm_service_tpu.runtime.engine import EngineRequest, InferenceEngine
 from xllm_service_tpu.runtime.executor import ModelExecutor
@@ -47,14 +51,14 @@ def _cfg(model, **kw):
 # ------------------------------------------------------------------ the rule
 
 
-def _stub(report, spec=0, paged=True, window=False):
-    """An executor as far as the rule reads it: no weights, no pools."""
+def _stub(routes, paged=True, window=False):
+    """An executor as far as the rule reads it: no weights, no pools, the
+    decisions (ops.attention.Routes) for the launches over its pools."""
     ex = object.__new__(ModelExecutor)
     ex.has_paged_cache = paged
     ex.window_tables = window
     ex.max_blocks_per_seq = 16 if paged else 1
-    ex.engine_cfg = types.SimpleNamespace(speculative_tokens=spec)
-    ex.kernel_report = lambda: dict(report)
+    ex._attention_routes = lambda: routes
     ex.whole_table = ex._table_costs_nothing()
     return ex
 
@@ -62,44 +66,61 @@ def _stub(report, spec=0, paged=True, window=False):
 GRID = [1, 2, 4, 4, 8, 8, 8, 8, 16, 16, 16, 16, 16, 16, 16, 16]
 
 
+def _pool(lanes=128, block=16, int8=False):
+    """A pool as far as the decision reads it: its shape and whether it
+    is quantized."""
+    data = jax.ShapeDtypeStruct((2, 8, 4, block, lanes), jnp.int8 if int8 else jnp.bfloat16)
+    scale = jax.ShapeDtypeStruct((2, 8, 4, 8, block), jnp.float32) if int8 else None
+    return kvc.PagedKV(data, scale)
+
+
 @pytest.mark.parametrize(
-    "report,spec,whole",
+    "pools,env,whole",
     [
-        ({"decode": "paged", "prefill": "flash", "mq": "mq"}, 0, True),
-        ({"decode": "mla", "prefill": "mla-flash", "mq": "blockwise"}, 0, True),
-        ({"decode": "paged", "prefill": "flash", "mixed": "ragged"}, 0, True),
-        ({"decode": "gather", "prefill": "blockwise", "mq": "blockwise"}, 0, False),
-        ({"decode": "gather (forced-off)", "prefill": "flash"}, 0, False),
-        ({"decode": "gather-fallback", "prefill": "flash"}, 0, False),
-        ({"decode": "paged", "prefill": "blockwise (forced-off)"}, 0, False),
-        ({"decode": "mla", "prefill": "blockwise"}, 0, False),  # an int8 latent pool
-        # the verify shapes ride a launch of their own where the engine speculates
-        ({"decode": "paged", "prefill": "flash", "mq": "mq"}, 3, True),
-        ({"decode": "paged", "prefill": "flash", "mq": "blockwise"}, 3, False),
-        ({"decode": "mla", "prefill": "mla-flash", "mq": "mla-mq"}, 3, True),
-        ({"decode": "mla", "prefill": "mla-flash", "mq": "blockwise"}, 3, False),
+        ([dict()], {}, True),
+        ([dict(latent=True)], {}, True),
+        ([dict(), dict(sinks=True)], {}, True),  # a second pool, with a sink
+        ([dict(on=False)], {}, False),
+        ([dict()], {"XLLM_PAGED_ATTENTION_KERNEL": "0"}, False),
+        ([dict(lanes=64)], {}, False),  # unpacked narrow rows: the gather on the chip too
+        ([dict()], {"XLLM_PREFILL_ATTENTION_KERNEL": "0"}, False),
+        ([dict(latent=True, int8=True, block=128)], {}, False),  # blockwise chunks
+        # the verify shapes ride the multi-query kernel or go as the chunks
+        # do: a speculative engine's table is whole under either
+        ([dict()], {"XLLM_MQ_ATTENTION_KERNEL": "1"}, True),
+        ([dict()], {"XLLM_MQ_ATTENTION_KERNEL": "0"}, True),
+        ([dict(latent=True)], {"XLLM_MQ_ATTENTION_KERNEL": "1"}, True),
+        ([dict(), dict(lanes=64)], {}, False),  # one pool of two falls back
     ],
-    ids=["gqa-kernels", "mla-kernels", "ragged", "fallback", "decode-forced-off",
+    ids=["gqa-kernels", "mla-kernels", "two-pools", "fallback", "decode-forced-off",
          "unpacked-at-tp", "prefill-forced-off", "mla-int8-prefill", "spec-mq",
-         "spec-no-mq", "spec-mla-mq", "spec-mla-no-mq"],
+         "spec-no-mq", "spec-mla-mq", "second-pool-falls-back"],
 )
-def test_the_table_is_whole_where_every_launch_is_a_kernel(report, spec, whole):
-    ex = _stub(report, spec)
+def test_the_table_is_whole_where_every_launch_is_a_kernel(monkeypatch, pools, env, whole):
+    """The rule asks the dispatchers' own decision one yes/no question."""
+    for name, value in env.items():
+        monkeypatch.setenv(name, value)
+    routes = []
+    for kw in pools:
+        kw = dict(kw)
+        monkeypatch.setattr(attention, "_on_tpu", lambda on=kw.pop("on", True): on)
+        cache = _pool(**{k: kw.pop(k) for k in ("lanes", "block", "int8") if k in kw})
+        routes.append(attention.attention_routes(cache, 8, 128, **kw))
+    ex = _stub(routes)
     assert ex.whole_table is whole
     buckets = [ex._ctx_bucket(need) for need in range(1, 17)]
     assert buckets == ([16] * 16 if whole else GRID)
     assert list(ex._decode_cb_walk()) == ([16] if whole else [1, 2, 4, 8, 16])
 
 
-@pytest.mark.parametrize("decode", ["paged", "gather"])
+@pytest.mark.parametrize("decode", [True, False], ids=["paged", "gather"])
 def test_a_window_family_takes_the_whole_table_under_either(decode):
-    ex = _stub({"decode": decode, "prefill": "blockwise", "window": "window-xla"},
-               window=True)
+    ex = _stub([attention.Routes(False, decode, False, False, 1, False)], window=True)
     assert ex.whole_table and {ex._ctx_bucket(n) for n in range(1, 17)} == {16}
 
 
 def test_a_state_pool_alone_has_one_column_and_asks_no_report():
-    ex = _stub(None, paged=False)  # (a report of None: never read)
+    ex = _stub(None, paged=False)  # (routes of None: never read)
     assert not ex.whole_table and ex._ctx_bucket(1) == 1
 
 
@@ -235,3 +256,138 @@ def test_every_array_a_step_donates_is_placed_at_build(cpu_devices, model):
     ex = ModelExecutor(_cfg(model), init_seed=0)
     leaves = jax.tree.leaves((ex.k_cache, ex.v_cache, ex.token_counts))
     assert leaves and all(x.committed for x in leaves)
+
+
+# ------------------------------- the report is the dispatchers' decision
+
+# attention launch kind -> its Pallas call's name in a traced program (a
+# window layer's launches carry "window_" before it)
+GQA_KERNELS = {"decode": "paged_attention_kernel", "prefill": "flash_prefill_kernel",
+               "verify": "multiquery_paged_attention_kernel"}
+MLA_KERNELS = {"decode": "mla_paged_attention_kernel", "prefill": "mla_prefill_kernel",
+               "verify": "mla_multiquery_attention_kernel"}
+ATTENTION_KERNEL = re.compile(r"\b((?:window_)?(?:%s))\b" % "|".join(
+    sorted(set(GQA_KERNELS.values()) | set(MLA_KERNELS.values()))))
+
+
+def _traced_step_programs(monkeypatch):
+    """Patch `ModelExecutor._step_jit`: no kernel can run here, so every
+    step program is a stand-in that TRACES each new signature, keeps the
+    names of the attention kernels its dispatchers launched
+    ({program: names}) and hands back zeros of the program's outputs."""
+    seen = {}
+
+    def step_jit(self, impl, **jit_kw):
+        jitted = jax.jit(impl, **jit_kw)
+        mine = seen.setdefault(impl.__name__, set())
+        outs = {}
+
+        def call(*a, **kw):
+            key = str(jax.tree.map(lambda x: getattr(x, "shape", x), (a, kw)))
+            if key not in outs:
+                traced = jitted.trace(*a, **kw)
+                mine.update(ATTENTION_KERNEL.findall(str(traced.jaxpr)))
+                outs[key] = traced.out_info
+            return jax.tree.map(lambda o: jnp.zeros(o.shape, o.dtype), outs[key])
+
+        call._cache_size = lambda: len(outs)
+        return call
+
+    monkeypatch.setattr(ModelExecutor, "_step_jit", step_jit)
+    return seen
+
+
+def _launch_names(routes, prefix, spec):
+    """The attention kernels a pool's launches run as, by the decision."""
+    names = MLA_KERNELS if routes.latent else GQA_KERNELS
+    kinds = [k for k in ("decode", "prefill") if getattr(routes, k)]
+    if spec and routes.verify:
+        kinds.append("verify")
+    return {prefix + names[k] for k in kinds}
+
+
+@pytest.mark.parametrize(
+    "model,on_chip,spec,widen",
+    [
+        ("llama3-shard-tiny", False, 0, {}),
+        ("llama3-shard-tiny", True, 0, {}),
+        ("llama3-tiny", True, 0, {}),
+        ("deepseek-tiny", False, 0, {}),
+        ("deepseek-tiny", True, 0, {}),
+        ("granite-tiny", True, 0, {}),
+        ("mimo-tiny", False, 0, {}),
+        ("mimo-tiny", True, 0, {}),
+        ("brumby-tiny", True, 0, {}),
+        # the launches of a speculative engine's verify shapes
+        ("llama3-shard-tiny", True, 3, {}),
+        ("deepseek-tiny", True, 3, {}),
+        # ... and a window family (a sink a window layer) with K/V rows wide
+        # enough for the kernels
+        ("mimo-tiny", True, 0, dict(head_dim=128, attn_v_head_dim=128)),
+    ],
+)
+def test_the_report_is_the_route_each_dispatcher_takes_when_traced(
+    cpu_devices, monkeypatch, model, on_chip, spec, widen
+):
+    """ONE decision: the attention kernels the executor's step programs
+    launch when traced (every family prewarm_programs walks: decode,
+    split prefill, mixed, verify) are the ones `kernel_report()` and
+    `_attention_routes()` name, pool by pool, and `whole_table` follows
+    from the same decision."""
+    import dataclasses
+
+    from xllm_service_tpu.models.configs import get_model_config
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: on_chip)
+    seen = _traced_step_programs(monkeypatch)
+    cfg = _cfg(model, speculative_tokens=spec, max_seq_len=64)  # (a short grid to trace)
+    model_cfg = dataclasses.replace(get_model_config(model), **widen) if widen else None
+    ex = ModelExecutor(cfg, init_seed=0, model_cfg=model_cfg)
+    try:
+        ex.prewarm_programs(p_groups=False)
+        routes = ex._attention_routes()
+        want = set()
+        for r, prefix in zip(routes, ("", "window_")):
+            want |= _launch_names(r, prefix, spec)
+        got = set().union(*seen.values())
+        assert got == want, (got, ex.kernel_report())
+        assert "_decode_impl" in seen and (spec == 0 or any("verify" in p for p in seen))
+        rep = ex.kernel_report()
+        if routes:  # the names the records carry say the same
+            full = routes[0]
+            assert (rep["decode"] in ("paged", "mla")) is full.decode
+            assert (rep["prefill"] in ("flash", "mla-flash")) is full.prefill
+            assert rep["mixed"] == f"{rep['decode']}+{rep['prefill']}"
+            assert rep["mq"] == ("mla-mq" if full.latent else "mq") if full.verify \
+                else rep["mq"] == rep["prefill"]
+        if len(routes) > 1:
+            w = routes[1]
+            assert rep["window"] == f"window-{'pallas' if w.decode and w.prefill else 'xla'}"
+            assert not (w.verify and ex.cfg.window_sink)
+        assert ex.whole_table is bool(
+            ex.window_tables or (routes and all(r.bounded_by_context for r in routes))
+        )
+    finally:
+        attention.set_shard_context(None)
+
+
+@pytest.mark.parametrize("sinks", [False, True], ids=["plain", "sink"])
+def test_verify_shapes_over_a_pool_with_a_sink_go_as_the_report_says(monkeypatch, sinks):
+    """The multi-query kernel has no sink logit, so verify shapes over a
+    pool whose layers carry one go to the flash kernel, and the report
+    says so (a window family refuses to speculate at build, so this is
+    the dispatcher alone; until PR 52 the report said `mq` either way)."""
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    k = jnp.zeros((8, 2, BS, 128), jnp.bfloat16)
+    tables, rows = jnp.zeros((2, 4), jnp.int32), jnp.ones((2,), jnp.int32)
+    jaxpr = jax.make_jaxpr(
+        lambda q: attention.prefill_attention(
+            q, k, k, tables, rows, 4 * rows, 0.1,
+            sinks=jnp.zeros((4,), jnp.float32) if sinks else None,
+        )
+    )(jnp.zeros((2, 4, 4, 128), jnp.bfloat16))
+    routes = attention.attention_routes(k, 4, 128, sinks=sinks)
+    assert routes.verify is not sinks and routes.prefill
+    assert set(ATTENTION_KERNEL.findall(str(jaxpr))) == {
+        GQA_KERNELS["prefill" if sinks else "verify"]}
+    assert routes.report()["mq"] == ("flash" if sinks else "mq")

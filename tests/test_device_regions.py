@@ -394,7 +394,7 @@ def _compile_step(family: str, step: str, one_chip, R: int = R) -> str:
     pf_pack = s((P, len(executor_mod.PF_FIELDS) + LPAD + cb), i32)
     fn = jax.jit(
         ex._mixed_impl, donate_argnums=(0, 1, 2),
-        static_argnames=("lpad", "use_ragged", "interpret"),
+        static_argnames=("lpad",),
     )
     return fn.lower(k, v, counts, params, pack, prev, pf_pack, lpad=LPAD).compile().as_text()
 
@@ -402,7 +402,6 @@ def _compile_step(family: str, step: str, one_chip, R: int = R) -> str:
 # Pallas kernel -> the region its custom call sits in
 KERNEL_REGIONS = {
     "paged_attention_kernel": "attn", "prefill_attention_kernel": "attn",
-    "ragged_paged_attention_kernel": "attn",
     "mla_paged_attention_kernel": "attn", "mla_prefill_kernel": "attn",
     "kv_write_kernel": "cache_write",
     "retention_update_kernel": "state_mixer", "retention_chunk_kernel": "state_mixer",

@@ -467,11 +467,17 @@ def test_named_refusals_at_the_request_and_an_inert_prefix_half():
                             jax.random.key(0), jnp.float32)
 
 
-def test_the_ragged_kernel_is_not_offered_a_sink_or_unequal_rows():
-    k, v = jnp.zeros((2, 4, 2, 16, 256)), jnp.zeros((2, 4, 2, 16, 128))
-    assert attention._ragged_serves(k, k, None)
-    assert not attention._ragged_serves(k, v, None)
-    assert not attention._ragged_serves(k, k, jnp.zeros((4,)))
+def test_a_sink_takes_the_verify_shapes_off_the_multi_query_kernel(monkeypatch):
+    """The multi-query kernel has no sink logit: over a pool whose layers
+    carry one, verify shapes go as the chunks do (the flash kernel, which
+    has), and the decision says so to the dispatcher and the report alike."""
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    k = jax.ShapeDtypeStruct((2, 4, 2, 16, 256), jnp.bfloat16)
+    plain = attention.attention_routes(k, 4, 256)
+    sink = attention.attention_routes(k, 4, 256, sinks=True)
+    assert plain.verify and plain.report()["mq"] == "mq"
+    assert not sink.verify and sink.prefill and sink.report()["mq"] == "flash"
+    assert sink.bounded_by_context and sink.report()["mixed"] == "paged+flash"
 
 
 def test_the_parameter_tree_has_a_replicated_rule_for_every_leaf():

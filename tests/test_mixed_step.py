@@ -1,231 +1,186 @@
-"""Unified ragged paged-attention (ISSUE 9, docs/KERNELS.md).
+"""The mixed step (ISSUE 9, docs/KERNELS.md): decode rows and prefill
+chunks in one dispatch, attention as the decode and the prefill launch
+side by side.
 
 Three layers of differential coverage:
 
-1. KERNEL: ops/pallas/ragged_paged_attention.py in interpret mode vs the
-   ragged_attention_blockwise oracle over fuzzed mixed batches — ragged
-   prefill lengths (incl. unaligned tails), decode rows, dead rows,
-   prefix hits (pos0 > 0), GQA ratios, bf16 + int8 KV, sliding window,
-   and the packed-cache dispatcher path.
+1. ATTENTION: ops.attention.mixed_attention's pair ON THE KERNEL ROUTE
+   (the decode kernel and the flash kernel in interpret mode, through the
+   `_interpret` seam: the route every benchmark cell runs on the chip)
+   against the gather and the blockwise scan over fuzzed mixed batches —
+   ragged chunk lengths (incl. unaligned tails), decode rows, dead rows,
+   prefix hits (start > 0), GQA ratios, bf16 + int8 KV, sliding window.
 
-2. ENGINE: mixed-step engines (the default ragged step builder) emit
-   streams BYTE-IDENTICAL to split-step engines — greedy and seeded
-   sampling, overlap and sync modes, chunked prefill, prefix hits,
-   staggered and concurrent arrivals. This is the contract that lets the
-   fused hot loop replace the alternating prefill/decode steps: the
-   model's mixed_step keeps each half's split-program shapes
-   (models/llama.py docstring), so fusing the dispatch cannot change
-   what a client receives.
+2. ENGINE: mixed-step engines (the default step builder) emit streams
+   BYTE-IDENTICAL to split-step engines — greedy and seeded sampling,
+   overlap and sync modes, chunked prefill, prefix hits, staggered and
+   concurrent arrivals. This is the contract that lets the fused hot loop
+   replace the alternating prefill/decode steps: the model's mixed_step
+   keeps each half's split-program shapes (models/llama.py docstring),
+   so fusing the dispatch cannot change what a client receives.
 
-3. HATCHES: EngineConfig.enable_mixed_step routing,
-   automatic split fallback for guided + speculative + prefill_only, and
-   the XLLM_RAGGED_ATTENTION_KERNEL=1 interpret-mode engine e2e (the
-   Pallas branch actually serving an engine run on CPU).
+3. HATCHES: EngineConfig.enable_mixed_step routing, automatic split
+   fallback for guided + speculative + prefill_only, and an engine run
+   SERVED by the pair of kernels in interpret mode on the CPU.
 """
 
 import threading
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from xllm_service_tpu.common.config import EngineConfig
+from xllm_service_tpu.ops import attention
 from xllm_service_tpu.ops import kv_cache as kvc
-from xllm_service_tpu.ops.attention import (
-    ragged_attention_blockwise,
-    ragged_paged_attention,
-)
-from xllm_service_tpu.ops.pallas.ragged_paged_attention import (
-    ragged_paged_attention_kernel,
-)
 from xllm_service_tpu.ops.sampling import SamplingParams
 from xllm_service_tpu.runtime.engine import EngineRequest, InferenceEngine
 from xllm_service_tpu.runtime.executor import ModelExecutor
 
-# --------------------------------------------------------------- kernel
+# ------------------------------------------------------------ attention
 
 
-def make_mixed_case(rng, seg_lens, Hq=8, Hkv=4, D=128, BS=16, MB=8,
+def make_mixed_case(rng, R, chunks, Hq=8, Hkv=4, D=128, BS=16, MB=8,
                     num_blocks=64, dtype=jnp.float32):
-    """A mixed batch over a shared KV pool: per-row random valid length
-    (<= capacity; decode rows always 1 unless killed) and a random
-    absolute start (prefix hits / decode context)."""
-    B = len(seg_lens)
-    T = sum(seg_lens)
-    q = jnp.asarray(rng.standard_normal((T, Hq, D)), dtype)
+    """A mixed batch over a shared KV pool: R decode rows at random
+    contexts and len(chunks) prefill rows, each with a random valid
+    length (<= its capacity) and a random absolute start (prefix hits),
+    padded to the widest capacity. Returns (q_dec, q_pf, k, v, dec_tables,
+    dec_seq_lens, pf_tables, pf_start, pf_len)."""
+    P, Lpad = len(chunks), max(chunks)
+    q_dec = jnp.asarray(rng.standard_normal((R, Hq, D)), dtype)
+    q_pf = jnp.asarray(rng.standard_normal((P, Lpad, Hq, D)), dtype)
     k = jnp.asarray(rng.standard_normal((num_blocks, Hkv, BS, D)), dtype)
     v = jnp.asarray(rng.standard_normal((num_blocks, Hkv, BS, D)), dtype)
-    bt = jnp.asarray(
-        rng.choice(
-            np.arange(1, num_blocks), size=(B, MB), replace=False
-        ).astype(np.int32)
+    bt = rng.choice(
+        np.arange(1, num_blocks), size=(R + P, MB), replace=False
+    ).astype(np.int32)
+    seq_lens = rng.integers(1, MB * BS + 1, R).astype(np.int32)
+    pf_len = np.asarray([rng.integers(1, cap + 1) for cap in chunks], np.int32)
+    pf_start = np.asarray(
+        [rng.integers(0, MB * BS - n + 1) for n in pf_len], np.int32
     )
-    q_len = np.zeros((B,), np.int32)
-    pos0 = np.zeros((B,), np.int32)
-    for b, cap in enumerate(seg_lens):
-        q_len[b] = 1 if cap == 1 else rng.integers(1, cap + 1)
-        pos0[b] = rng.integers(0, MB * BS - q_len[b] + 1)
-    return q, k, v, bt, jnp.asarray(q_len), jnp.asarray(pos0)
+    return (q_dec, q_pf, k, v, jnp.asarray(bt[:R]), jnp.asarray(seq_lens),
+            jnp.asarray(bt[R:]), jnp.asarray(pf_start), jnp.asarray(pf_len))
+
+
+def _pair_and_reference(monkeypatch, case, scale, window=0):
+    """(decode out, prefill out) of mixed_attention on the kernel route
+    (interpret mode) and of the gather / the blockwise scan."""
+    q_dec, q_pf, k, v, dt, sl, pt, ps, pl = case
+    monkeypatch.setattr(attention, "_interpret", lambda: True)
+    routes = attention.attention_routes(k, q_dec.shape[-2], q_dec.shape[-1])
+    assert routes.decode and routes.prefill and routes.interpret
+    out = attention.mixed_attention(
+        q_dec, q_pf, k, v, dt, sl, pt, ps, pl, scale, window=window
+    )
+    ref = (
+        attention.paged_attention_gather(q_dec, k, v, dt, sl, scale, window=window),
+        jax.vmap(
+            lambda q, t, s, n: attention.prefill_attention_blockwise(
+                q, k, v, t, s, n, scale, window=window
+            )
+        )(q_pf, pt, ps, pl),
+    )
+    valid = (jnp.arange(q_pf.shape[1])[None, :] < pl[:, None])[:, :, None, None]
+    return [np.asarray(o, np.float32) for o in (out[0], jnp.where(valid, out[1], 0))], \
+        [np.asarray(o, np.float32) for o in (ref[0], jnp.where(valid, ref[1], 0))]
 
 
 @pytest.mark.parametrize("seed", [0, 1, 2])
 @pytest.mark.parametrize("gqa", [1, 4])
-def test_ragged_kernel_fuzzed_mixed_batches(seed, gqa):
-    """Fuzzed decode+prefill mixes (unaligned tails, prefix offsets)
-    match the blockwise oracle."""
+def test_mixed_pair_fuzzed_mixed_batches(monkeypatch, seed, gqa):
+    """Fuzzed decode+prefill mixes (unaligned tails, prefix offsets):
+    the pair of kernels matches the gather and the blockwise scan."""
     rng = np.random.default_rng(seed)
     Hkv = 4
-    # decode singletons interleaved with ragged prefill capacities
-    seg_lens = (1, 1, int(rng.integers(2, 33)), 1, int(rng.integers(2, 33)))
-    q, k, v, bt, q_len, pos0 = make_mixed_case(
-        rng, seg_lens, Hq=Hkv * gqa, Hkv=Hkv
-    )
-    scale = q.shape[-1] ** -0.5
-    ref = ragged_attention_blockwise(
-        q, k, v, bt, q_len, pos0, seg_lens, scale
-    )
-    out = ragged_paged_attention_kernel(
-        q, k, v, bt, q_len, pos0, seg_lens, scale, interpret=True, tile_q=16
-    )
-    np.testing.assert_allclose(
-        np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5
-    )
+    chunks = (int(rng.integers(2, 33)), int(rng.integers(2, 33)))
+    case = make_mixed_case(rng, 3, chunks, Hq=Hkv * gqa, Hkv=Hkv)
+    out, ref = _pair_and_reference(monkeypatch, case, case[0].shape[-1] ** -0.5)
+    for o, r in zip(out, ref):
+        np.testing.assert_allclose(o, r, atol=2e-5, rtol=2e-5)
 
 
-def test_ragged_kernel_dead_rows_zero():
-    """q_len 0 rows (inactive decode slots / padded prefill lanes) emit
-    zeros; live rows are untouched by their presence."""
+def test_mixed_pair_dead_rows_zero(monkeypatch):
+    """Inactive decode slots (seq_len 0) and padded prefill lanes (len 0)
+    emit zeros from the decode kernel and nothing a live row can see; live
+    rows are untouched by their presence."""
     rng = np.random.default_rng(3)
-    seg_lens = (1, 1, 16, 8)
-    q, k, v, bt, q_len, pos0 = make_mixed_case(rng, seg_lens)
-    q_len = jnp.asarray([1, 0, 16, 0], jnp.int32)
-    # The override raises row lengths past what the helper drew pos0 for;
-    # re-clamp so every row's context still fits its MB*BS block table.
-    pos0 = jnp.minimum(pos0, 8 * 16 - q_len)
-    scale = 0.125
-    out = np.asarray(ragged_paged_attention_kernel(
-        q, k, v, bt, q_len, pos0, seg_lens, scale, interpret=True, tile_q=16
-    ))
-    ref = np.asarray(ragged_attention_blockwise(
-        q, k, v, bt, q_len, pos0, seg_lens, scale
-    ))
-    assert np.all(out[1] == 0) and np.all(out[18:] == 0)
-    np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+    q_dec, q_pf, k, v, dt, sl, pt, ps, pl = make_mixed_case(rng, 4, (16, 8))
+    sl = sl.at[1].set(0).at[3].set(0)
+    pl = pl.at[1].set(0)
+    out, ref = _pair_and_reference(
+        monkeypatch, (q_dec, q_pf, k, v, dt, sl, pt, ps, pl), 0.125
+    )
+    assert np.all(out[0][1] == 0) and np.all(out[0][3] == 0)
+    assert np.all(out[1][1] == 0)
+    live = np.asarray(sl) > 0
+    np.testing.assert_allclose(out[0][live], ref[0][live], atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(out[1], ref[1], atol=2e-5, rtol=2e-5)
 
 
-def test_ragged_kernel_tiles_cross_row_boundaries():
-    """A tile smaller than one row's segment AND a tile holding many
-    rows both reduce exactly (the row-iteration/online-softmax no-op
-    merge argument in the kernel docstring)."""
+def test_mixed_pair_chunk_spans_query_tiles():
+    """A chunk wider than one query tile of the flash kernel beside many
+    decode rows: every tile reduces over its own context exactly."""
+    from xllm_service_tpu.ops.pallas.flash_prefill import flash_prefill_kernel
+
     rng = np.random.default_rng(4)
-    seg_lens = (1,) * 12 + (40,)  # tile_q=16: tiles mix decode rows,
-    q, k, v, bt, q_len, pos0 = make_mixed_case(rng, seg_lens, MB=4)
-    scale = 0.125
-    ref = ragged_attention_blockwise(
-        q, k, v, bt, q_len, pos0, seg_lens, scale
-    )
-    out = ragged_paged_attention_kernel(
-        q, k, v, bt, q_len, pos0, seg_lens, scale, interpret=True, tile_q=16
-    )
+    q_dec, q_pf, k, v, dt, sl, pt, ps, pl = make_mixed_case(rng, 12, (40,), MB=4)
+    out = flash_prefill_kernel(q_pf, k, v, pt, ps, pl, 0.125, interpret=True, tile_q=16)
+    ref = attention.prefill_attention_blockwise(q_pf[0], k, v, pt[0], ps[0], pl[0], 0.125)
+    n = int(pl[0])
     np.testing.assert_allclose(
-        np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5
+        np.asarray(out[0, :n]), np.asarray(ref[:n]), atol=2e-5, rtol=2e-5
     )
 
 
-def test_ragged_kernel_bf16():
+def test_mixed_pair_bf16(monkeypatch):
     rng = np.random.default_rng(5)
-    seg_lens = (1, 24, 1, 9)
-    q, k, v, bt, q_len, pos0 = make_mixed_case(
-        rng, seg_lens, dtype=jnp.bfloat16
-    )
-    scale = 0.125
-    ref = ragged_attention_blockwise(
-        q, k, v, bt, q_len, pos0, seg_lens, scale
-    )
-    out = ragged_paged_attention_kernel(
-        q, k, v, bt, q_len, pos0, seg_lens, scale, interpret=True, tile_q=16
-    )
-    np.testing.assert_allclose(
-        np.asarray(out, np.float32), np.asarray(ref, np.float32),
-        atol=3e-2, rtol=3e-2,
-    )
+    case = make_mixed_case(rng, 2, (24, 9), dtype=jnp.bfloat16)
+    out, ref = _pair_and_reference(monkeypatch, case, 0.125)
+    for o, r in zip(out, ref):
+        np.testing.assert_allclose(o, r, atol=3e-2, rtol=3e-2)
 
 
-def test_ragged_kernel_int8():
+def test_mixed_pair_int8(monkeypatch):
     """int8 KV: pool-native grouped scales stream and dequantize in VMEM
-    (same tolerance budget as the flash-prefill int8 case — dequant_tile
-    rounds to bf16 before the score matmul)."""
+    in both kernels (dequant_tile rounds to bf16 before the score matmul:
+    the flash-prefill int8 case's tolerance)."""
     rng = np.random.default_rng(6)
     # BS=128: int8 [G, BS] scale tiles carry BS on lanes (chip rule).
-    seg_lens = (1, 1, 24, 17)
-    q, k, v, bt, q_len, pos0 = make_mixed_case(
-        rng, seg_lens, BS=128, MB=2, num_blocks=16
+    q_dec, q_pf, k, v, *rest = make_mixed_case(
+        rng, 2, (24, 17), BS=128, MB=2, num_blocks=16
     )
-    kq, vq = kvc.quantize_pool(k), kvc.quantize_pool(v)
-    scale = 0.125
-    ref = ragged_attention_blockwise(
-        q, kq, vq, bt, q_len, pos0, seg_lens, scale
-    )
-    out = ragged_paged_attention_kernel(
-        q, kq, vq, bt, q_len, pos0, seg_lens, scale, interpret=True,
-        tile_q=16,
-    )
-    np.testing.assert_allclose(
-        np.asarray(out), np.asarray(ref), atol=2e-2, rtol=2e-2
-    )
+    case = (q_dec, q_pf, kvc.quantize_pool(k), kvc.quantize_pool(v), *rest)
+    out, ref = _pair_and_reference(monkeypatch, case, 0.125)
+    for o, r in zip(out, ref):
+        np.testing.assert_allclose(o, r, atol=2e-2, rtol=2e-2)
 
 
-def test_ragged_kernel_sliding_window():
+def test_mixed_pair_sliding_window(monkeypatch):
     rng = np.random.default_rng(7)
-    seg_lens = (1, 32, 1)
-    q, k, v, bt, q_len, pos0 = make_mixed_case(rng, seg_lens)
-    scale = 0.125
+    case = make_mixed_case(rng, 2, (32,))
     for window in (8, 24):
-        ref = ragged_attention_blockwise(
-            q, k, v, bt, q_len, pos0, seg_lens, scale, window=window
-        )
-        out = ragged_paged_attention_kernel(
-            q, k, v, bt, q_len, pos0, seg_lens, scale, interpret=True,
-            tile_q=16, window=window,
-        )
-        np.testing.assert_allclose(
-            np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5
-        )
+        out, ref = _pair_and_reference(monkeypatch, case, 0.125, window=window)
+        for o, r in zip(out, ref):
+            np.testing.assert_allclose(o, r, atol=2e-5, rtol=2e-5)
 
 
-def test_ragged_dispatcher_packed_cache(monkeypatch):
-    """head_dim < 128 rides the packed-pair cache layout through the
-    dispatcher (kernel_io_for/pack_queries) — kernel branch forced via
-    use_kernel + interpret, packed shapes opted in."""
-    monkeypatch.setenv("XLLM_PACKED_KV_KERNEL", "1")
+def test_mixed_pair_use_kernel_forces_the_reference(monkeypatch):
+    """`use_kernel=False` sends both halves to the gather and the
+    blockwise scan whatever the platform says: bit for bit the
+    dispatchers' own fallbacks."""
     rng = np.random.default_rng(8)
-    Hq, Hkv, D, BS, MB, NB = 4, 2, 32, 16, 4, 32
-    seg_lens = (1, 12, 1)
-    T = sum(seg_lens)
-    q = jnp.asarray(rng.standard_normal((T, Hq, D)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((NB, Hkv, BS, D)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((NB, Hkv, BS, D)), jnp.float32)
-    kp = kvc.as_paged(kvc.pack_pool(k)) if hasattr(kvc, "pack_pool") else None
-    if kp is None:
-        pytest.skip("no packed-pool helper in this build")
-    vp = kvc.as_paged(kvc.pack_pool(v))
-    bt = jnp.asarray(
-        rng.choice(np.arange(1, NB // 4), size=(3, MB),
-                   replace=False).astype(np.int32)
+    q_dec, q_pf, k, v, dt, sl, pt, ps, pl = make_mixed_case(rng, 3, (12,))
+    monkeypatch.setattr(attention, "_interpret", lambda: True)
+    forced = attention.mixed_attention(
+        q_dec, q_pf, k, v, dt, sl, pt, ps, pl, 0.125, use_kernel=False
     )
-    q_len = jnp.asarray([1, 12, 1], jnp.int32)
-    pos0 = jnp.asarray([20, 0, 5], jnp.int32)
-    scale = D ** -0.5
-    ref = ragged_paged_attention(
-        q, kp, vp, bt, q_len, pos0, seg_lens, scale, use_kernel=False
-    )
-    out = ragged_paged_attention(
-        q, kp, vp, bt, q_len, pos0, seg_lens, scale, use_kernel=True,
-        interpret=True,
-    )
-    np.testing.assert_allclose(
-        np.asarray(out), np.asarray(ref), atol=3e-5, rtol=3e-5
-    )
+    monkeypatch.setattr(attention, "_interpret", lambda: False)
+    plain = attention.mixed_attention(q_dec, q_pf, k, v, dt, sl, pt, ps, pl, 0.125)
+    for f, p_ in zip(forced, plain):
+        assert np.array_equal(np.asarray(f), np.asarray(p_))
 
 
 # --------------------------------------------------------------- engine
@@ -476,28 +431,30 @@ def test_guided_request_rides_mixed_batch():
         eng.stop()
 
 
-def test_ragged_kernel_engine_e2e_interpret(monkeypatch):
-    """The Pallas ragged kernel actually SERVES an engine run (interpret
-    mode on CPU, packed tiny-model cache opted in) and the greedy streams
-    match the reference-path mixed engine. llama3-packed-tiny is the one
-    tiny geometry that is kernel-eligible: head_dim 64 with 2 kv heads
-    packs pairwise into 128-lane cache rows (kv_pack_factor P=2);
-    llama3-tiny's D=32/Hkv=2 can never pack (P=4 doesn't divide 2)."""
+def test_kernel_pair_engine_e2e_interpret(monkeypatch):
+    """The pair of Pallas kernels the cells run (the decode kernel and
+    the flash kernel) actually SERVES an engine run (interpret mode on
+    CPU through the `_interpret` seam, packed tiny-model cache opted in)
+    and the greedy streams match the reference-path mixed engine.
+    llama3-packed-tiny is the one tiny geometry that is kernel-eligible:
+    head_dim 64 with 2 kv heads packs pairwise into 128-lane cache rows
+    (kv_pack_factor P=2); llama3-tiny's D=32/Hkv=2 can never pack (P=4
+    doesn't divide 2)."""
     reqs = _requests(n=3)
     cfg = _cfg(enable_mixed_step=True, model="llama3-packed-tiny")
     monkeypatch.setenv("XLLM_PACKED_KV_KERNEL", "1")
     ref = _run_engine(
         cfg, reqs, ex_cfg=_cfg(model="llama3-packed-tiny")
     )
-    monkeypatch.setenv("XLLM_RAGGED_ATTENTION_KERNEL", "1")
-    monkeypatch.setenv("XLLM_RAGGED_INTERPRET", "1")
+    monkeypatch.setattr(attention, "_interpret", lambda: True)
     eng = InferenceEngine(
         cfg,
         executor=ModelExecutor(
             _cfg(model="llama3-packed-tiny"), init_seed=11
         ),
     )
-    assert eng._kernel_names["mixed"] == "ragged"
+    assert eng._kernel_names["mixed"] == "paged+flash"
+    assert eng.executor.whole_table  # every launch walks its row's context
     eng.start()
     results, events = {}, []
     try:

@@ -5,7 +5,7 @@ The contract under test mirrors the sharded-engine tier's: an ep-sharded
 MoE engine is an IMPLEMENTATION DETAIL — token streams must be
 byte-identical to the 1-device engine on the same weights across every
 serving path the hot loop composes (greedy, seeded sampling, penalties,
-staggered admission through the mixed ragged step, and the composed
+staggered admission through the mixed step, and the composed
 speculative pipeline). Runs on the conftest virtual 8-device CPU
 platform; ep ∈ {2, 4} divide moe-shard-tiny's 8 experts.
 
@@ -145,16 +145,19 @@ def test_engine_ep_parity_grouped_kernel(cpu_devices, monkeypatch, ep):
 
 
 def test_engine_ep_parity_with_ragged_interpret(cpu_devices, monkeypatch):
-    """The full composed fast path: interpret-mode ragged attention AND
-    interpret-mode grouped MoE dispatch in the same fused mixed step,
-    ep=2 ≡ 1-device byte for byte."""
+    """The full composed fast path: the interpret-mode pair of attention
+    kernels (decode + flash, the cells' route, through the `_interpret`
+    seam) AND interpret-mode grouped MoE dispatch in the same fused mixed
+    step, ep=2 ≡ 1-device byte for byte."""
+    from xllm_service_tpu.ops import attention
+
     monkeypatch.setenv("XLLM_MOE_INTERPRET", "1")
-    monkeypatch.setenv("XLLM_RAGGED_INTERPRET", "1")
+    monkeypatch.setattr(attention, "_interpret", lambda: True)
     ref, ref_eng = _run_workload()
-    assert ref_eng.executor.kernel_report()["mixed"] == "ragged"
+    assert ref_eng.executor.kernel_report()["mixed"] == "paged+flash"
     streams, eng = _run_workload(ep_size=2)
     rep = eng.executor.kernel_report()
-    assert rep["mixed"] == "ragged" and rep["moe"] == "grouped"
+    assert rep["mixed"] == "paged+flash" and rep["moe"] == "grouped"
     assert rep["moe_shards"] == 2
     assert streams == ref
 

@@ -634,7 +634,7 @@ def test_mla_mq_dispatcher_env_gate(monkeypatch):
 
     rng = np.random.default_rng(5)
     S, kvr = 4, 40
-    # C=128: the dispatcher's tile-legality gate (attention._mla_kernel_ok)
+    # C=128: the dispatcher's tile-legality gate (attention._kernel_tile_ok)
     # requires a 128-multiple latent lane dim, as the production pool pads.
     q4, cache, bt = make_mla_prefill_case(rng, P=2, Lpad=S, C=128, MB=8)
     seq_lens = jnp.asarray([30, 90], jnp.int32)
@@ -865,7 +865,7 @@ def _layer_of(cache, layer):
 
 @pytest.mark.parametrize("layer", [0, 1, 2])
 @pytest.mark.parametrize("cache_kind", ["bf16", "int8", "packed64"])
-@pytest.mark.parametrize("kernel", ["decode", "multiquery", "flash", "ragged"])
+@pytest.mark.parametrize("kernel", ["decode", "multiquery", "flash", "mixed"])
 def test_stacked_kernels_read_their_layer(kernel, cache_kind, layer, monkeypatch):
     monkeypatch.setenv("XLLM_PACKED_KV_KERNEL", "1")
     monkeypatch.setenv("XLLM_MQ_ATTENTION_KERNEL", "1")
@@ -905,16 +905,28 @@ def test_stacked_kernels_read_their_layer(kernel, cache_kind, layer, monkeypatch
             )
         )(q, bt, start, true_len)
     else:
-        seg_lens = (1, 16)  # a decode row and a prefill row in one launch
-        q = q_of(sum(seg_lens))
-        q_len = jnp.asarray([1, 13], jnp.int32)
-        pos0 = jnp.asarray([ctx - 5, 2], jnp.int32)
-        out = attn_ops.ragged_paged_attention(
-            q, k, v, bt, q_len, pos0, seg_lens, scale, use_kernel=True,
-            interpret=True, layer=lyr,
+        # decode rows and chunks in one mixed step: the pair of kernels side
+        # by side over the same stack (interpret mode through the seam)
+        monkeypatch.setattr(attn_ops, "_interpret", lambda: True)
+        q_dec, q_pf = q_of(R), q_of(R, 16)
+        seq_lens = jnp.asarray([ctx - 3, BS // 2 + 1], jnp.int32)
+        start = jnp.asarray([ctx - 18, 2], jnp.int32)
+        true_len = jnp.asarray([16, 13], jnp.int32)
+        out = attn_ops.mixed_attention(
+            q_dec, q_pf, k, v, bt, seq_lens, bt, start, true_len, scale, layer=lyr
         )
-        ref = attn_ops.ragged_attention_blockwise(
-            q, k4, v4, bt, q_len, pos0, seg_lens, scale
+        ref = (
+            attn_ops.paged_attention_gather(q_dec, k4, v4, bt, seq_lens, scale),
+            jax.vmap(
+                lambda qi, ti, sp, tl: attn_ops.prefill_attention_gather(
+                    qi, k4, v4, ti, sp, tl, scale
+                )
+            )(q_pf, bt, start, true_len),
+        )
+        live = (jnp.arange(16)[None, :] < true_len[:, None])[:, :, None, None]
+        out, ref = (
+            jnp.concatenate([d.reshape(-1), jnp.where(live, p, 0).reshape(-1)])
+            for d, p in (out, ref)
         )
     out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
     np.testing.assert_allclose(out, ref, atol=3e-2, rtol=3e-2)

@@ -5,15 +5,16 @@ The contract under test: a tp-sharded engine is an IMPLEMENTATION
 DETAIL — token streams must be byte-identical to the 1-device engine on
 the same weights (same init_seed) across every serving path: greedy,
 seeded sampling, guided decoding, speculative decoding (the composed
-pipeline), the mixed ragged step, the streamed PD handoff, and the
+pipeline), the mixed step, the streamed PD handoff, and the
 prefix-fabric block fetch. Runs on the conftest virtual 8-device CPU
 platform; tp ∈ {2, 4, 8} all divide llama3-shard-tiny's 8 KV heads.
 
 The per-shard KERNEL dispatch (ops/attention.py shard_map wrapping) is
-asserted via kernel_report() — `shards` == tp and `mixed` == "ragged"
-under the interpret hook — not assumed: the interpret-mode Pallas
-ragged kernel actually launches once per shard inside the engine's
-fused step and must still match the 1-device stream bit for bit.
+asserted via the decision itself (executor._attention_routes(),
+kernel_report()) — `shards` == tp and `mixed` == "paged+flash" under the
+interpret seam — not assumed: the interpret-mode Pallas decode and flash
+kernels actually launch once per shard inside the engine's fused step
+and must still match the 1-device stream bit for bit.
 
 The KV wire planes are exercised per-shard: a tp holder's exports ride
 `shard_wire.ShardedKV` through kv_frame_to_bytes/kv_frame_array (N
@@ -28,6 +29,7 @@ import pytest
 
 from xllm_service_tpu.api.protocol import kv_frame_array, kv_frame_split, kv_frame_to_bytes
 from xllm_service_tpu.common.config import EngineConfig
+from xllm_service_tpu.ops import attention
 from xllm_service_tpu.ops.sampling import SamplingParams
 from xllm_service_tpu.parallel import shard_wire
 from xllm_service_tpu.runtime.engine import EngineRequest, InferenceEngine
@@ -132,22 +134,26 @@ def test_engine_tp_parity(cpu_devices, ref_streams, tp):
 
 
 def test_engine_tp_parity_ragged_interpret(cpu_devices, monkeypatch):
-    """tp ∈ {2, 8} with the interpret-mode ragged Pallas kernel driving
-    the fused mixed step: kernel_report() must RESOLVE to per-shard
-    ragged dispatch (shards == tp — asserted, not assumed), and the
-    streams must match the 1-device interpret run bit for bit."""
-    monkeypatch.setenv("XLLM_RAGGED_INTERPRET", "1")
+    """tp ∈ {2, 8} with the pair of kernels the cells run (the decode
+    kernel and the flash kernel, interpret mode through the `_interpret`
+    seam) serving every step: the decision the dispatchers take must
+    RESOLVE to per-shard dispatch (shards == tp — asserted, not
+    assumed), and the streams must match the 1-device interpret run bit
+    for bit."""
+    monkeypatch.setattr(attention, "_interpret", lambda: True)
     ref, ref_eng = _run_workload()
-    assert ref_eng.executor.kernel_report()["mixed"] == "ragged"
+    assert ref_eng.executor.kernel_report()["mixed"] == "paged+flash"
     for tp in (2, 8):
         streams, eng = _run_workload(tp_size=tp)
+        (routes,) = eng.executor._attention_routes()
+        assert routes.decode and routes.prefill and routes.interpret
+        assert routes.shards == tp
         rep = eng.executor.kernel_report()
-        assert rep["mixed"] == "ragged"
-        assert rep["shards"] == tp
+        assert rep["mixed"] == "paged+flash" and rep["shards"] == tp
         assert eng.mixed_steps > 0
-        # The engine's resolved dispatch counter saw the ragged label —
-        # the per-shard launch is what every mixed step dispatched.
-        assert eng._kernel_names["mixed"] == "ragged"
+        # The engine's resolved dispatch counter saw the pair's label —
+        # the per-shard launches are what every mixed step dispatched.
+        assert eng._kernel_names["mixed"] == "paged+flash"
         assert streams == ref
 
 
@@ -390,10 +396,11 @@ def test_sharded_wire_roundtrip_units(cpu_devices):
 # -------------------------------------------- per-shard kernel dispatch
 
 
-def test_sharded_kernel_dispatchers_bitwise(cpu_devices):
-    """Direct dispatcher-level proof: decode / flash-prefill / mq /
-    ragged kernels under a declared shard context (interpret mode,
-    tp ∈ {2, 4}) are BIT-identical to their unsharded kernel runs."""
+def test_sharded_kernel_dispatchers_bitwise(cpu_devices, monkeypatch):
+    """Direct dispatcher-level proof: decode / flash-prefill / mq
+    kernels, and the mixed step's pair of them, under a declared shard
+    context (interpret mode, tp ∈ {2, 4}) are BIT-identical to their
+    unsharded kernel runs."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -433,13 +440,15 @@ def test_sharded_kernel_dispatchers_bitwise(cpu_devices):
             jnp.asarray(tables), jnp.asarray(start), jnp.asarray(tlen),
             scale, interpret=True,
         )
-        seg = (1,) * R
-        rg0 = att.ragged_paged_attention(
-            jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
-            jnp.asarray(tables), jnp.asarray(np.minimum(seq_lens, 1)),
-            jnp.asarray(np.maximum(seq_lens - 1, 0)), seg, scale,
-            use_kernel=True, interpret=True,
+        # the mixed step's pair takes no `interpret`: the seam gives it
+        monkeypatch.setattr(att, "_interpret", lambda: True)
+        mx0 = att.mixed_attention(
+            jnp.asarray(q), jnp.asarray(qp), jnp.asarray(k), jnp.asarray(v),
+            jnp.asarray(tables), jnp.asarray(seq_lens),
+            jnp.asarray(tables), jnp.asarray(start), jnp.asarray(tlen), scale,
         )
+        assert np.array_equal(np.asarray(mx0[0]), np.asarray(dec0))
+        assert np.array_equal(np.asarray(mx0[1]), np.asarray(mq0))
         for tp in (2, 4):
             mesh = Mesh(np.asarray(jax.devices()[:tp]), ("tp",))
             ks = jax.device_put(
@@ -469,13 +478,13 @@ def test_sharded_kernel_dispatchers_bitwise(cpu_devices):
                 jnp.asarray(tlen), scale, interpret=True,
             )
             assert np.array_equal(np.asarray(mq), np.asarray(mq0))
-            rg = att.ragged_paged_attention(
-                qs, ks, vs, jnp.asarray(tables),
-                jnp.asarray(np.minimum(seq_lens, 1)),
-                jnp.asarray(np.maximum(seq_lens - 1, 0)), seg, scale,
-                use_kernel=True, interpret=True,
+            mx = att.mixed_attention(
+                qs, qps, ks, vs, jnp.asarray(tables), jnp.asarray(seq_lens),
+                jnp.asarray(tables), jnp.asarray(start), jnp.asarray(tlen),
+                scale,
             )
-            assert np.array_equal(np.asarray(rg), np.asarray(rg0))
+            for got, want in zip(mx, mx0):
+                assert np.array_equal(np.asarray(got), np.asarray(want))
     finally:
         att.set_shard_context(None)
 

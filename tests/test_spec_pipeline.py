@@ -1,6 +1,6 @@
 """Composed fast paths (ISSUE 13, docs/ENGINE_PIPELINE.md): seeded
 differential proof that speculative + guided decoding INSIDE the
-overlapped mixed ragged pipeline emits BYTE-IDENTICAL token streams to
+overlapped mixed pipeline emits BYTE-IDENTICAL token streams to
 the sync+split verify engine — the pre-ISSUE-13 configuration — across
 greedy and seeded sampling, guided and unguided, accept-heavy /
 reject-heavy / mixed-acceptance workloads, cancels and preemptions
@@ -16,6 +16,7 @@ on-device from the in-flight step's variable accepted counts."""
 import numpy as np
 
 from xllm_service_tpu.common.config import EngineConfig
+from xllm_service_tpu.ops import attention
 from xllm_service_tpu.ops.sampling import SamplingParams
 from xllm_service_tpu.runtime.engine import EngineRequest, InferenceEngine
 from xllm_service_tpu.runtime.executor import ModelExecutor
@@ -391,15 +392,16 @@ def test_guided_schema_rides_pipeline():
     assert out[True] == out[False]
 
 
-# ------------------------------------------- ragged kernel (interpret)
+# ---------------------------------------- the kernel pair (interpret)
 
 
 def test_spec_mixed_ragged_kernel_interpret(monkeypatch):
-    """Verify rows REALLY are ragged rows (q_len = k+1): the composed
-    engine's fused verify+prefill dispatch routes through the Pallas
-    ragged kernel in interpret mode on the one kernel-eligible tiny
-    geometry, and the greedy stream matches the reference-path composed
-    engine (same builder, blockwise attention)."""
+    """Verify rows REALLY are prefill-shaped rows (q_len = k+1): the
+    composed engine's fused verify+prefill dispatch routes them through
+    the multi-query kernel and the chunks through the flash kernel
+    (interpret mode, the `_interpret` seam) on the one kernel-eligible
+    tiny geometry, and the greedy stream matches the reference-path
+    composed engine (same builder, blockwise attention)."""
     def cfg():
         return _cfg(True, model="llama3-packed-tiny")
 
@@ -423,9 +425,10 @@ def test_spec_mixed_ragged_kernel_interpret(monkeypatch):
 
     monkeypatch.setenv("XLLM_PACKED_KV_KERNEL", "1")
     ref, _ = run()
-    monkeypatch.setenv("XLLM_RAGGED_ATTENTION_KERNEL", "1")
-    monkeypatch.setenv("XLLM_RAGGED_INTERPRET", "1")
+    monkeypatch.setattr(attention, "_interpret", lambda: True)
     got, eng = run()
+    (routes,) = eng.executor._attention_routes()
+    assert routes.verify and routes.prefill and routes.interpret
     assert eng.spec_pipeline_steps > 0
     assert got == ref
 

@@ -71,7 +71,6 @@ def as_on_tpu(monkeypatch):
     """The dispatchers ask the attached backend, which is the CPU here:
     steer them onto the kernel branch the chip would take."""
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
-    monkeypatch.setenv("XLLM_RAGGED_ATTENTION_KERNEL", "1")
     # a tp > 1 executor built earlier on this worker's thread leaves its
     # mesh declared (the context is per thread and read at trace time); the
     # programs here are one chip's unless a test declares its own
@@ -241,24 +240,6 @@ def test_prefill_kernel_compiles(one_chip, no_persistent_cache):
         ),
         s((4, 512, HQ, D)), cache, cache,
         s((4, 16), jnp.int32), s((4,), jnp.int32), s((4,), jnp.int32),
-    )
-    assert "tpu_custom_call" in text
-
-
-def test_ragged_kernel_compiles(one_chip, no_persistent_cache):
-    from xllm_service_tpu.ops.pallas.ragged_paged_attention import (
-        ragged_paged_attention_kernel,
-    )
-
-    s, cache = _kernel_shapes(one_chip)
-    seg_lens = (1,) * R + (256, 256)
-    B, T = len(seg_lens), sum(seg_lens)
-    text = _compile(
-        lambda q, k, v, bt, ql, p0: ragged_paged_attention_kernel(
-            q, k, v, bt, ql, p0, seg_lens, SCALE
-        ),
-        s((T, HQ, D)), cache, cache,
-        s((B, 16), jnp.int32), s((B,), jnp.int32), s((B,), jnp.int32),
     )
     assert "tpu_custom_call" in text
 
@@ -618,7 +599,6 @@ def test_granite_mixed_step_compiles_and_its_pools_stay(one_chip, no_persistent_
     lie, and the temporaries stay under 0.5 GB."""
     from xllm_service_tpu.models import granite
 
-    monkeypatch.setenv("XLLM_RAGGED_ATTENTION_KERNEL", "0")  # the cell's route: the pair of kernels
     cfg = get_model_config("granite-4.0-h-small")
 
     def s(shape, dtype=jnp.bfloat16):
@@ -793,8 +773,6 @@ def test_step_reads_every_weight_leaf_where_it_lies(one_chip, no_persistent_cach
     lanes, value heads of 128), the dense first layer and the experts,
     with all four attention launches of the cell in the program."""
     fn, args, names = _in_place_case(one_chip, case)
-    if case.startswith(("solar", "mimo")):  # the cell's route: the pair of attention kernels
-        os.environ["XLLM_RAGGED_ATTENTION_KERNEL"] = "0"  # (as_on_tpu's monkeypatch restores it)
     text = jax.jit(fn, donate_argnums=(1, 2)).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text  # the kernels' branch, as on the chip
     if case.startswith("solar"):
@@ -929,7 +907,6 @@ def test_one_decode_and_one_mixed_program_serve_every_context(
     from xllm_service_tpu.common.config import EngineConfig
     from xllm_service_tpu.runtime.executor import ModelExecutor, PrefillItem, SamplingBatch
 
-    monkeypatch.setenv("XLLM_RAGGED_ATTENTION_KERNEL", "0")  # the cells' route: the pair of kernels
     seen = _described_step_programs(monkeypatch, one_chip)
     R, bs, MB = 4, 16, 16
     ecfg = EngineConfig(

@@ -263,20 +263,20 @@ class EngineConfig:
     # iteration (what the pipeline held is flushed at the transition).
     sync_engine: bool = False
 
-    # Mixed (ragged) stepping. True (default) = the engine step builder
-    # emits ONE batch per iteration — all active decode slots PLUS the due
+    # Mixed stepping. True (default) = the engine step builder emits ONE
+    # batch per iteration — all active decode slots PLUS the due
     # chunked-prefill rows — served by a single compiled mixed step
     # (models.<family>.mixed_step via executor.mixed_start), so prefill
     # and decode stop competing for alternating engine steps
-    # (docs/KERNELS.md). Whether the attention inside that step runs as
-    # ONE ragged Pallas dispatch or as the split decode+prefill kernels is
-    # a separate hatch (XLLM_RAGGED_ATTENTION_KERNEL — opt-in until
-    # chip-validated). False = the split-step escape hatch (prefill batch
-    # then decode step, the pre-ISSUE-9 hot loop). Depth-0 iterations
-    # (sync_engine) and MLA families always run split. Guided requests
-    # ride the mixed batch (their final chunk samples under an in-graph
-    # mask row), and speculative engines fuse verify rows with the due
-    # prefill chunks (mixed_verify_step) where the family has one.
+    # (docs/KERNELS.md); the attention inside that step is the decode and
+    # the prefill launch side by side. False = the split-step escape
+    # hatch (prefill batch then decode step, the pre-ISSUE-9 hot loop).
+    # Depth-0 iterations (sync_engine) always run split
+    # (executor.fuses_prefill decides). Guided requests ride the mixed
+    # batch (their final chunk samples under an in-graph mask row), and
+    # speculative engines fuse verify rows with the due prefill chunks
+    # (mixed_verify_step) where the family has one (MLA families have
+    # none: their speculative engines run split).
     enable_mixed_step: bool = True
 
     # Speculative decoding (prompt-lookup / n-gram drafting; 0 disables).

@@ -189,12 +189,24 @@ def prefill_batch_step(
     return llama._unembed(params, cfg, llama._last_rows(x, true_len)), S, z
 
 
+def attention_routes(cfg: ModelConfig, S, tp: int = 1):
+    """No paged pool, no attention launch: the state pool alone."""
+    return ()
+
+
+def kernel_report(cfg: ModelConfig, S, tp: int = 1) -> dict:
+    """Which route the retention updates take, under every step's name."""
+    on = retention_ops.use_kernels(cfg.head_dim)
+    route = f"retention-{'pallas' if on else 'xla'}"
+    return {"decode": route, "prefill": route, "mixed": route}
+
+
 def mixed_step(
     params: Params, cfg: ModelConfig, S, z,
     dec_tokens, dec_positions, dec_tables, dec_active,  # the decode rows
     pf_tokens, pf_start, pf_len, pf_tables,  # the due prefill chunks
-    use_ragged: bool | None = None,  # the retention kernels' switch here
-    lora_dec=None, lora_pf=None, rope_delta=None, interpret: bool = False,
+    use_kernel: bool | None = None,
+    lora_dec=None, lora_pf=None, rope_delta=None,
 ):
     """Decode rows and prefill chunks in ONE program, each half with the
     shapes of its split program. A row is in one half only (a sequence in
@@ -214,9 +226,9 @@ def mixed_step(
     def layer_fn(x, lp, layer, S, z):
         x_dec, x_pf = x
         x_dec, S, z = _dec_layer(cfg, lp, layer, S, z, x_dec, dec_positions,
-                                 dec_slots, dec_active, use_ragged)
+                                 dec_slots, dec_active, use_kernel)
         x_pf, S, z = _pf_layer(cfg, lp, layer, S, z, x_pf, pf_positions, pf_slots,
-                               pf_start, pf_len, pf_valid, use_ragged)
+                               pf_start, pf_len, pf_valid, use_kernel)
         return (x_dec, x_pf), S, z
 
     (x_dec, x_pf), S, z = llama._scan_layers(layer_fn, (x_dec, x_pf), params, S, z)

@@ -48,6 +48,7 @@ from xllm_service_tpu.models.llama import (
 from xllm_service_tpu.obs.spans import region
 from xllm_service_tpu.ops import kv_write as kv_write_ops
 from xllm_service_tpu.ops.attention import (
+    attention_routes as pool_routes,
     mla_paged_attention,
     mla_prefill_attention,
 )
@@ -292,25 +293,31 @@ def _write(plan, layer):
     return write
 
 
-def _decode_attend(
-    cfg, plan, tables, seq_lens, layer, use_kernel=None, interpret=False
-):
+def attention_routes(cfg: ModelConfig, c_caches, tp: int = 1):
+    """The decisions for this family's attention launches: one latent
+    pool, with no head axis to split over a mesh."""
+    return (pool_routes(c_caches, latent=True),)
+
+
+def kernel_report(cfg: ModelConfig, c_caches, tp: int = 1) -> dict:
+    return attention_routes(cfg, c_caches)[0].report()
+
+
+def _decode_attend(cfg, plan, tables, seq_lens, layer, use_kernel=None):
     """R decode rows: one latent row a sequence lands in the stack, then
     absorbed attention over its blocks. Returns (write, read)."""
 
     def read(q_lat, c):
         return mla_paged_attention(
             q_lat, c, tables, seq_lens, mla_softmax_scale(cfg),
-            cfg.kv_lora_rank, use_kernel=use_kernel, interpret=interpret,
-            layer=layer,
+            cfg.kv_lora_rank, use_kernel=use_kernel, layer=layer,
         )
 
     return _write(plan, layer), read
 
 
 def _prefill_attend(
-    cfg, plan, tables, start, length, Lpad: int, layer,
-    use_kernel=None, interpret=False,
+    cfg, plan, tables, start, length, Lpad: int, layer, use_kernel=None
 ):
     """P chunks of Lpad rows each (flat [P*Lpad]): the chunks' latents
     land in the stack, then causal attention over each chunk's context
@@ -320,7 +327,7 @@ def _prefill_attend(
         ctx = mla_prefill_attention(
             q_lat.reshape(-1, Lpad, *q_lat.shape[1:]), c, tables, start,
             length, mla_softmax_scale(cfg), cfg.kv_lora_rank,
-            use_kernel=use_kernel, interpret=interpret, layer=layer,
+            use_kernel=use_kernel, layer=layer,
         )
         return ctx.reshape(-1, *ctx.shape[2:])
 
@@ -412,11 +419,10 @@ def mixed_step(
     pf_start: jnp.ndarray,  # [P] int32 (cached tokens before each chunk)
     pf_len: jnp.ndarray,  # [P] int32 (valid tokens per chunk; 0 = pad row)
     pf_tables: jnp.ndarray,  # [P, CBp] int32
-    use_ragged: bool | None = None,  # the two MLA kernels' switch here
+    use_kernel: bool | None = None,
     lora_dec=None,
     lora_pf=None,
     rope_delta=None,
-    interpret: bool = False,
 ):
     """ONE compiled step for a MIXED batch: R decode slots and P chunked-
     prefill rows in a single dispatch over the one carried latent stack,
@@ -425,7 +431,7 @@ def mixed_step(
     matmul (the projections, the shared and the dense MLP, and above all
     the expert product, which then streams each touched expert's weights
     once a step and not once a half); only the attention is two ops, the
-    decode rows' and the chunks' (no ragged latent kernel; their block
+    decode rows' and the chunks' (their block
     tables are disjoint, so both halves' rows are written first).
 
     Returns (dec_logits [R, V], pf_logits [P, V] of each chunk's LAST
@@ -445,12 +451,11 @@ def mixed_step(
 
     def make_attend(layer):
         dec_write, dec_read = _decode_attend(
-            cfg, dec_plan, dec_tables, dec_seq_lens, layer,
-            use_ragged, interpret,
+            cfg, dec_plan, dec_tables, dec_seq_lens, layer, use_kernel
         )
         pf_write, pf_read = _prefill_attend(
             cfg, pf_plan, pf_tables, pf_start, pf_len, Lpad, layer,
-            use_ragged, interpret,
+            use_kernel,
         )
 
         def attend(q_lat, rows, c):

@@ -110,7 +110,7 @@ from xllm_service_tpu.ops import mamba as mamba_ops
 from xllm_service_tpu.ops import moe as moe_ops
 from xllm_service_tpu.ops import rope as rope_ops
 from xllm_service_tpu.ops.attention import (
-    cache_kernel_route,
+    attention_routes as pool_routes,
     mixed_attention,
     paged_attention,
     prefill_attention,
@@ -155,11 +155,34 @@ def pool_shapes(cfg: ModelConfig, blocks: int, window_blocks: int, block_size: i
             pair(cfg.num_window_layers, window_blocks, cfg.window_kv_heads))
 
 
-def window_route(cfg: ModelConfig, k_window) -> str:
-    """Which route the window layers' attention takes over their pool:
-    "window-pallas" (the decode and flash kernels, launched under their
-    window names) or "window-xla" (the gather and blockwise twins)."""
-    return f"window-{'pallas' if cache_kernel_route(k_window)[0] else 'xla'}"
+def attention_routes(cfg: ModelConfig, k_caches, tp: int = 1):
+    """The decisions for the attention launches over this family's paged
+    pools: the full layers' and, in a window family, the window layers'
+    (a sink takes the verify shapes off the multi-query kernel). The
+    mixer pads its queries to the pool's key row, so that is their width."""
+
+    def over(K, sinks=False):
+        return pool_routes(K, cfg.num_heads, kvc.raw(K).shape[-1], tp=tp, sinks=sinks)
+
+    full = over(k_caches[0])
+    return (full, over(k_caches[1], cfg.window_sink)) if cfg.num_window_layers else (full,)
+
+
+def kernel_report(cfg: ModelConfig, k_caches, tp: int = 1) -> dict:
+    """The full layers' launches by name, and the second pool's route:
+    `window` in a window family, `state` beside a state pool."""
+    full, *window = attention_routes(cfg, k_caches, tp)
+    rep = full.report()
+    if not window:
+        rep["state"] = state_route(cfg, k_caches[1])
+        return rep
+    (w,) = window  # the decode and flash kernels under their window names,
+    # their gather and blockwise twins, or the pair where a hatch split them
+    if w.decode == w.prefill:
+        rep["window"] = f"window-{'pallas' if w.decode else 'xla'}"
+    else:
+        rep["window"] = f"window-{w.report()['mixed']}"
+    return rep
 
 
 def state_shapes(cfg: ModelConfig, slots: int):
@@ -518,7 +541,7 @@ def _gated(lp, cfg: ModelConfig, h, o):
 
 
 def _attn_mixer(lp, cfg: ModelConfig, h, a, K, V, dec: Optional[_Dec],
-                pf: Optional[_Pf], use_ragged=None, interpret=False, kind="attention"):
+                pf: Optional[_Pf], kind="attention"):
     """The GQA mixer over flat rows h [T, E], layer `a` of the attention
     layers of `kind`, K and V that kind's stacks: every row's K/V lands
     in the stacks first, then each half attends (a window layer through
@@ -552,7 +575,7 @@ def _attn_mixer(lp, cfg: ModelConfig, h, a, K, V, dec: Optional[_Dec],
     if dec is not None and pf is not None:
         o_dec, o_pf = mixed_attention(
             q[:R], q_pf, K, V, dec_tables, dec.seq_lens, pf_tables, pf.start,
-            pf.length, scale, use_ragged=use_ragged, interpret=interpret, layer=a, **kw,
+            pf.length, scale, use_kernel=dec.use_kernel, layer=a, **kw,
         )
         o = jnp.concatenate([o_dec, o_pf.reshape(-1, *o_pf.shape[2:])], axis=0)
     elif dec is not None:
@@ -667,8 +690,7 @@ def _layer(lp, cfg: ModelConfig, x, valid, kind, mix, caches, dense=False):
 
 
 def _run_layers(params, cfg: ModelConfig, x, k_caches, v_caches, valid,
-                dec: Optional[_Dec], pf: Optional[_Pf], use_ragged=None,
-                interpret=False):
+                dec: Optional[_Dec], pf: Optional[_Pf]):
     """The stack over flat token rows x [T, E]: one scan a run of equal
     layer kind, every pool on every scan's carry."""
 
@@ -678,11 +700,9 @@ def _run_layers(params, cfg: ModelConfig, x, k_caches, v_caches, valid,
             # window layers' K and V stacks
             (K, S), (V, conv) = caches
             if kind == "attention":
-                y, K, V = _attn_mixer(lp, cfg, h, i, K, V, dec, pf, use_ragged, interpret)
+                y, K, V = _attn_mixer(lp, cfg, h, i, K, V, dec, pf)
             elif kind == "window":
-                y, S, conv = _attn_mixer(
-                    lp, cfg, h, i, S, conv, dec, pf, use_ragged, interpret, kind
-                )
+                y, S, conv = _attn_mixer(lp, cfg, h, i, S, conv, dec, pf, kind)
             else:
                 y, S, conv = STATE_KINDS[kind].mixer(lp, cfg, h, i, S, conv, dec, pf)
             return y, ((K, S), (V, conv))
@@ -850,8 +870,8 @@ def mixed_step(
     params: Params, cfg: ModelConfig, k_caches, v_caches,
     dec_tokens, dec_positions, dec_tables, dec_active,  # the decode rows
     pf_tokens, pf_start, pf_len, pf_tables,  # the due prefill chunks
-    use_ragged: bool | None = None,
-    lora_dec=None, lora_pf=None, rope_delta=None, interpret: bool = False,
+    use_kernel: bool | None = None,
+    lora_dec=None, lora_pf=None, rope_delta=None,
 ):
     """Decode rows and prefill chunks in ONE program and ONE batch of
     R + P*Lpad token rows for every matmul (the projections, the shared
@@ -864,12 +884,12 @@ def mixed_step(
         raise NotImplementedError("granite: no LoRA and no M-RoPE on this family")
     R = dec_tokens.shape[0]
     P, Lpad = pf_tokens.shape
-    dec = _dec_half(cfg, k_caches, dec_positions, dec_tables, dec_active, None)
+    dec = _dec_half(cfg, k_caches, dec_positions, dec_tables, dec_active, use_kernel)
     pf, pf_valid = _pf_half(cfg, k_caches, pf_tables, pf_start, pf_len, P, Lpad)
     x = _embed(params, cfg, jnp.concatenate([dec_tokens, pf_tokens.reshape(-1)]))
     x, k_caches, v_caches = _run_layers(
         params, cfg, x, k_caches, v_caches,
-        jnp.concatenate([dec_active, pf_valid]), dec, pf, use_ragged, interpret,
+        jnp.concatenate([dec_active, pf_valid]), dec, pf,
     )
     last = llama._last_rows(x[R:].reshape(P, Lpad, -1), pf_len)
     return (

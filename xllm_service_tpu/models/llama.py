@@ -30,6 +30,7 @@ from xllm_service_tpu.obs.spans import region
 from xllm_service_tpu.ops import kv_cache as kv_cache_ops
 from xllm_service_tpu.ops import kv_write as kv_write_ops
 from xllm_service_tpu.ops.attention import (
+    attention_routes as pool_routes,
     mixed_attention,
     mixed_prefill_attention,
     paged_attention,
@@ -543,6 +544,17 @@ def decode_step(
     return logits, k_caches, v_caches
 
 
+def attention_routes(cfg: ModelConfig, k_caches, tp: int = 1):
+    """The decisions (ops.attention.Routes) for the attention launches of
+    this family's step programs, one a paged pool: here the K/V pools'."""
+    return (pool_routes(k_caches, cfg.num_heads, cfg.head_dim, tp=tp),)
+
+
+def kernel_report(cfg: ModelConfig, k_caches, tp: int = 1) -> dict:
+    """What each launch kind over the family's pools runs as, by name."""
+    return attention_routes(cfg, k_caches, tp)[0].report()
+
+
 def mixed_step(
     params: Params,
     cfg: ModelConfig,
@@ -556,11 +568,9 @@ def mixed_step(
     pf_start: jnp.ndarray,  # [P] int32 (cached tokens before each chunk)
     pf_len: jnp.ndarray,  # [P] int32 (valid tokens per chunk; 0 = pad row)
     pf_tables: jnp.ndarray,  # [P, CBp] int32
-    use_ragged: bool | None = None,
     lora_dec: jnp.ndarray | None = None,  # [R] adapter rows
     lora_pf: jnp.ndarray | None = None,  # [P] adapter rows
     rope_delta: jnp.ndarray | None = None,  # [R] M-RoPE lag (decode slots)
-    interpret: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """ONE compiled step for a MIXED batch: R decode slots and P chunked-
     prefill rows in a single dispatch, fused at the DISPATCH and
@@ -570,10 +580,9 @@ def mixed_step(
     bit-stable under a fixed row count — flattening both halves into one
     [R + P*Lpad, E] buffer made mixed-step streams drift from split-step
     streams at bf16 ULP scale (docs/KERNELS.md pins this contract; the
-    engine-level differential in tests/test_ragged_attention.py enforces
-    it). Attention runs through ops.attention.mixed_attention — one
-    ragged Pallas dispatch over both halves when the kernel is enabled,
-    the exact split-path decode+prefill attention ops otherwise.
+    engine-level differential in tests/test_mixed_step.py enforces
+    it). Attention runs through ops.attention.mixed_attention: the exact
+    split-path decode and prefill attention ops, side by side.
 
     Returns (dec_logits [R, V], pf_logits [P, V] — each prefill row's
     LAST valid position — k', v')."""
@@ -631,8 +640,7 @@ def mixed_step(
             q_dec, q_pf, k_caches, v_caches,
             dec_tables, dec_seq_lens,
             pf_tables, pf_start, pf_len,
-            scale, use_ragged=use_ragged, interpret=interpret,
-            window=cfg.sliding_window, layer=layer,
+            scale, window=cfg.sliding_window, layer=layer,
         )
         # Output projection + MLP, per half, split-step shapes.
         with region("attn_proj"):
@@ -670,11 +678,9 @@ def mixed_verify_step(
     pf_start: jnp.ndarray,  # [P] int32
     pf_len: jnp.ndarray,  # [P] int32 (0 = pad row)
     pf_tables: jnp.ndarray,  # [P, CBp] int32
-    use_ragged: bool | None = None,
     lora_ver: jnp.ndarray | None = None,  # [R] adapter rows (verify rows)
     lora_pf: jnp.ndarray | None = None,  # [P] adapter rows (prefill rows)
     ver_rope_delta: jnp.ndarray | None = None,  # [R] M-RoPE lag (<= 0)
-    interpret: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """ONE compiled step for a speculative MIXED batch: R verify rows
     (q_len = k+1 — the multi-query speculative-verify half) and P
@@ -686,9 +692,8 @@ def mixed_verify_step(
     [P, Lpad] one — because matmul row values are only bit-stable under
     a fixed row count (docs/KERNELS.md pins this; the composed
     differential in tests/test_spec_pipeline.py enforces it). Attention
-    runs through ops.attention.mixed_prefill_attention — one ragged
-    Pallas dispatch over the whole heterogeneous batch when the kernel
-    is enabled, the exact split prefill dispatcher per half otherwise.
+    runs through ops.attention.mixed_prefill_attention: the exact split
+    prefill dispatcher, once per half.
 
     Returns (ver_logits [R, S, V] — every position, the speculative
     verify contract — pf_logits [P, V], k', v')."""
@@ -758,8 +763,7 @@ def mixed_verify_step(
             q_ver, q_pf, k_caches, v_caches,
             ver_tables, ver_start, ver_len,
             pf_tables, pf_start, pf_len,
-            scale, use_ragged=use_ragged, interpret=interpret,
-            window=cfg.sliding_window, layer=layer,
+            scale, window=cfg.sliding_window, layer=layer,
         )
 
         x_ver = _out_mlp_rows(

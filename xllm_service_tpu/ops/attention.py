@@ -28,7 +28,9 @@ the kernel tests pass it.
 from __future__ import annotations
 
 import functools
+import os
 import threading
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -61,8 +63,6 @@ _SHARD_TLS = threading.local()
 
 
 def sharded_kernels_enabled() -> bool:
-    import os
-
     return os.environ.get("XLLM_SHARDED_KERNELS") != "0"
 
 
@@ -104,16 +104,6 @@ def _cache_shard_spec(cache, axis: str):
     if isinstance(cache, kvc.PagedKV):
         return kvc.PagedKV(spec, spec if cache.scale is not None else None)
     return spec
-
-
-def _shardable(q: jnp.ndarray, k_cache, ctx) -> bool:
-    """Whether this (query, cache) pair can shard over ctx's axis: the
-    query heads and the per-shard cache geometry must divide evenly —
-    gqa_kernel_eligible re-checks the cache side per shard."""
-    if ctx is None:
-        return False
-    n = ctx[0].shape[ctx[1]]
-    return q.shape[-2] % n == 0 and kvc.raw(k_cache).shape[-3] % n == 0
 
 
 def _kernel_call(body, ctx, q_spec_ndim: int, q, k_cache, v_cache,
@@ -179,8 +169,6 @@ def _packed_kernel_allowed(pack: int) -> bool:
     convention they ride the kernels only under XLLM_PACKED_KV_KERNEL=1
     (scripts/validate_kernel_tpu.py carries the packed cases; flip the
     default once they report PARITY OK on silicon)."""
-    import os
-
     return pack == 1 or os.environ.get("XLLM_PACKED_KV_KERNEL") == "1"
 
 
@@ -403,47 +391,22 @@ def prefill_attention_blockwise(
 
 
 
-def _kernel_tile_ok(cache, lane_dim: int, on: bool) -> bool:
+def _kernel_tile_ok(cache, on: bool) -> bool:
     """Mosaic tile-legality gate for every Pallas kernel path (chip
     findings, round 3): DMA slice dims must be tile MULTIPLES on the
-    last two dims. `lane_dim` is the per-row lane width (head_dim D for
-    GQA, the lane-padded latent dim C for MLA) and must be a 128
-    multiple; BS sits on sublanes of the [BS, lane_dim] data slice (16
+    last two dims. The pool's row width (head_dim D for GQA, where packed
+    head_dim<128 layouts carry 128-lane rows and unpacked narrow rows are
+    not eligible; the lane-padded latent dim C for MLA) must be a 128
+    multiple; BS sits on sublanes of the [BS, lanes] data slice (16
     bf16; int8's stricter bound is subsumed below); int8 additionally
     streams [G, BS] scale tiles with BS on LANES, so quantized caches
     need BS % 128."""
-    BS = kvc.raw(cache).shape[-2]
+    *_, BS, lanes = kvc.raw(cache).shape
     cq = isinstance(cache, kvc.PagedKV) and cache.quantized
     return (
         on
-        and lane_dim % 128 == 0
+        and lanes % 128 == 0
         and (BS % 128 == 0 if cq else BS % 16 == 0)
-    )
-
-
-def _gqa_kernel_ok(k_cache, on: bool) -> bool:
-    # Gate on the CACHE row width: packed head_dim<128 layouts carry
-    # 128-lane rows and are kernel-eligible; unpacked narrow rows are not.
-    return _kernel_tile_ok(k_cache, kvc.raw(k_cache).shape[-1], on)
-
-
-def gqa_kernel_eligible(
-    k_cache, q_head_dim: int, on: bool, shards: int = 1
-) -> bool:
-    """THE tile/lane/packing eligibility gate for every GQA Pallas path
-    (decode, flash prefill, multi-query verify, ragged mixed) — one
-    predicate instead of a per-dispatcher copy of the `_kernel_tile_ok`
-    + `_packed_kernel_allowed` pair (ISSUE 9 satellite). `on` is the
-    platform gate (_on_tpu() or interpret). `shards` > 1 evaluates the
-    PER-SHARD cache geometry of the shard_map'd dispatch: the (possibly
-    packed) cache-head axis must split evenly over tp or the per-shard
-    kernel is declined (the caller then serves the GSPMD path; the
-    config-level resolve_kv_packing fallback normally prevents this, but
-    the gate must hold for hand-built caches too)."""
-    if shards > 1 and kvc.raw(k_cache).shape[-3] % shards:
-        return False
-    return _gqa_kernel_ok(k_cache, on) and _packed_kernel_allowed(
-        _pack_ratio(k_cache, q_head_dim)
     )
 
 
@@ -457,15 +420,156 @@ def cache_kernel_route(cache, interpret: bool = False):
     do not split over the shards."""
     ctx = shard_context()
     ok = (
-        _gqa_kernel_ok(cache, _on_tpu() or interpret)
+        _kernel_tile_ok(cache, _on_tpu() or interpret)
         and (ctx is not None or declared_shard_context() is None)
         and (ctx is None or kvc.raw(cache).shape[-3] % ctx[0].shape[ctx[1]] == 0)
     )
     return ok, ctx
 
 
-def _mla_kernel_ok(c_cache, on: bool) -> bool:
-    return _kernel_tile_ok(c_cache, kvc.raw(c_cache).shape[-1], on)
+# ------------------------------------------------- which launch runs as what
+# ONE function turns what can be observed (the platform, the pool's tile
+# geometry, packing and quantization, the declared mesh, the hatches that
+# remain) into the route of every attention launch over a pool. The
+# dispatchers below take their branch from it and the executor's
+# kernel_report() prints it, so the two cannot disagree.
+
+MQ_MAX_ROWS = 8  # verify shapes: at most this many query rows a sequence
+
+
+@functools.lru_cache(maxsize=1)
+def _on_tpu() -> bool:
+    # A backend that fails to initialise raises here: an engine meant for
+    # the chip must not be routed to the gather/blockwise reference.
+    return jax.devices()[0].platform == "tpu"
+
+
+def _interpret() -> bool:
+    """The seam beside _on_tpu that a CPU test patches to True: every
+    attention launch then passes the platform gate and runs its Pallas
+    kernel in interpret mode, inside whatever step program traces it."""
+    return False
+
+
+class Routes(NamedTuple):
+    """What the attention launches over ONE pool run as (attention_routes)."""
+
+    latent: bool  # an MLA latent pool (else GQA K/V pools)
+    decode: bool  # the Pallas decode kernel (else the gather)
+    prefill: bool  # the Pallas flash kernel (else the blockwise scan)
+    verify: bool  # verify shapes take the multi-query kernel (else as `prefill`)
+    shards: int  # kernel launches a dispatch fans into (shard_map over tp)
+    interpret: bool  # the kernels run in interpret mode
+    forced_off: tuple = ()  # launch kinds a hatch sent to the fallback
+
+    @property
+    def bounded_by_context(self) -> bool:
+        """Whether every launch walks a row's context and not its table
+        (`nb = cdiv(seq_len, block_size)`): the kernels do, the gather and
+        the blockwise scan read every column they are given. Verify
+        shapes ride the multi-query kernel or go as `prefill` does."""
+        return self.decode and self.prefill
+
+    def report(self) -> dict:
+        """The names the records carry (bench rows, the engine's dispatch
+        counter, docs/OBSERVABILITY.md): the winning implementation of
+        each launch kind, a fallback that a hatch forced marked
+        ` (forced-off)`; `mixed` is the pair a fused step launches side by
+        side, `mq` what the verify shapes run as."""
+        decode, prefill, mq = (
+            ("mla", "mla-flash", "mla-mq") if self.latent
+            else ("paged", "flash", "mq")
+        )
+        if not self.decode:
+            decode = "gather" + " (forced-off)" * ("decode" in self.forced_off)
+        if not self.prefill:
+            prefill = "blockwise" + " (forced-off)" * ("prefill" in self.forced_off)
+        return {
+            "decode": decode,
+            "prefill": prefill,
+            "mixed": f"{decode}+{prefill}",
+            "mq": mq if self.verify else prefill,
+            "shards": self.shards,
+        }
+
+
+def attention_routes(
+    cache,
+    q_heads: int = 1,
+    q_head_dim: int | None = None,
+    *,
+    latent: bool = False,
+    tp: int = 1,
+    use_kernel: bool | None = None,
+    interpret: bool = False,
+    sinks: bool = False,
+) -> Routes:
+    """THE dispatch decision for the attention launches over `cache` (a
+    K pool, or the latent pool with `latent`): which of them run as
+    Pallas kernels, per how many shards, in which mode.
+
+    The platform gate is _on_tpu() (or interpret mode: the argument, or
+    the _interpret seam). The Mosaic tile gate (_kernel_tile_ok) reads
+    the pool's row width and block size; a packed-pair pool (head_dim <
+    128) rides the kernels only under XLLM_PACKED_KV_KERNEL=1. On a
+    declared tp mesh (`tp` its extent; XLLM_SHARDED_KERNELS=0 escapes to
+    GSPMD) a GQA launch fans into one per shard where the query heads
+    and the cache heads split evenly; a latent pool has no head axis to
+    split. XLLM_PAGED_ATTENTION_KERNEL / XLLM_PREFILL_ATTENTION_KERNEL
+    =0/1 force a GQA launch off or on (the second covers the multi-query
+    kernel too); XLLM_MQ_ATTENTION_KERNEL=0/1 gates the multi-query
+    kernel, which is on for bf16 GQA pools (mq-bf16 validated on a v5e)
+    and opt-in for int8 and latent pools, and has no sink logit: a
+    launch with `sinks` goes as `prefill`. An int8 latent pool's flash
+    kernel is not validated on a chip: it takes the blockwise scan.
+    `use_kernel` True / False (a caller's own switch) forces the decode
+    and the flash kernel on / off whatever the gates say."""
+    interp = interpret or _interpret()
+    on = _on_tpu() or interp
+    raw = kvc.raw(cache)
+    quantized = isinstance(cache, kvc.PagedKV) and cache.quantized
+    ok = _kernel_tile_ok(cache, on)
+    mq_env = os.environ.get("XLLM_MQ_ATTENTION_KERNEL")
+    shards, forced = 1, ()
+    if latent:
+        decode, prefill = ok, ok and not quantized
+        verify = ok and mq_env == "1"
+    else:
+        if (
+            tp > 1 and sharded_kernels_enabled()
+            and q_heads % tp == 0 and raw.shape[-3] % tp == 0
+        ):
+            shards = tp
+        ok = ok and _packed_kernel_allowed(_pack_ratio(cache, q_head_dim))
+        envs = {
+            "decode": os.environ.get("XLLM_PAGED_ATTENTION_KERNEL"),
+            "prefill": os.environ.get("XLLM_PREFILL_ATTENTION_KERNEL"),
+        }
+        decode, prefill = (
+            (env != "0") if ok else (env == "1") for env in envs.values()
+        )
+        forced = tuple(kind for kind, env in envs.items() if env == "0")
+        verify = (
+            ok and not sinks and envs["prefill"] != "0"
+            and (mq_env == "1" if quantized else mq_env != "0")
+        )
+    if use_kernel is not None:
+        decode = prefill = bool(use_kernel)
+        verify, forced = False, ()
+    return Routes(latent, decode, prefill, verify, shards, interp, forced)
+
+
+def _gqa_routes(q, k_cache, use_kernel, interpret, sinks=None):
+    """(routes, ctx) of a GQA dispatcher's launch: the decision for the
+    calling thread's declared mesh, and the shard context to launch
+    under where it fans out (None: one launch)."""
+    ctx = declared_shard_context()
+    routes = attention_routes(
+        k_cache, q.shape[-2], q.shape[-1],
+        tp=ctx[0].shape[ctx[1]] if ctx is not None else 1,
+        use_kernel=use_kernel, interpret=interpret, sinks=sinks is not None,
+    )
+    return routes, ctx if routes.shards > 1 else None
 
 
 @region("attn")
@@ -487,45 +591,21 @@ def prefill_attention(
     flash kernel (ops/pallas/flash_prefill.py) on TPU, vmapped blockwise
     scan elsewhere. window > 0 = sliding-window attention (each position
     attends its last `window` positions; kernels also skip blocks wholly
-    below the window). Same eligibility rules as the decode kernel (D a
-    lane multiple; int8 additionally needs BS scale rows 128-wide); env
-    override XLLM_PREFILL_ATTENTION_KERNEL=0/1 forces the path, and
-    `interpret` lets CI drive the kernel branch on CPU."""
-    import os
-
-    # One eligibility predicate for BOTH Pallas paths (flash prefill and
-    # the multi-query verify kernel). Under a shard context (tp>1) each
-    # kernel launches per-shard via shard_map and the packing trio
-    # (kernel_io_for) evaluates the per-shard cache geometry inside the
-    # mapped body.
-    # Packed-pair caches (head_dim < 128): queries embed block-diagonally
-    # into the 128-lane rows; outputs slice back (pack_queries docstring).
-    ctx = shard_context() if _shardable(q, k_cache, shard_context()) else None
-    shards = ctx[0].shape[ctx[1]] if ctx is not None else 1
-    kernel_ok = gqa_kernel_eligible(
-        k_cache, q.shape[-1], _on_tpu() or interpret, shards=shards
-    )
+    below the window). Which launch runs is attention_routes' decision
+    (`use_kernel` forces the flash kernel on or off, `interpret` lets CI
+    drive the kernel branch on CPU). Under a shard context (tp>1) each
+    kernel launches per-shard via shard_map and the packing trio
+    (kernel_io_for) evaluates the per-shard cache geometry inside the
+    mapped body. Packed-pair caches (head_dim < 128): queries embed
+    block-diagonally into the 128-lane rows; outputs slice back
+    (pack_queries docstring)."""
+    routes, ctx = _gqa_routes(q, k_cache, use_kernel, interpret, sinks)
 
     # Speculative-verify shapes (a handful of query rows per sequence):
     # the multi-query decode kernel streams each KV row ONCE like a decode
     # step — the flash-prefill kernel would pad S~4 rows to a 128-row
-    # query tile. Default ON for bf16 since the mq-bf16 case validated on
-    # a real v5e chip (round 3, scripts/validate_kernel_tpu.py); int8
-    # stays opt-in (XLLM_MQ_ATTENTION_KERNEL=1) until mq-int8 validates
-    # on the grouped scale layout. =0 disables outright.
-    S = q.shape[1]
-    mq_env = os.environ.get("XLLM_MQ_ATTENTION_KERNEL")
-    kq_mq = isinstance(k_cache, kvc.PagedKV) and k_cache.quantized
-    if (
-        use_kernel is None
-        and S <= 8
-        and sinks is None
-        and kernel_ok
-        and (mq_env == "1" if kq_mq else mq_env != "0")
-        # The function-wide kill switch keeps covering EVERY kernel path
-        # here: =0 forces the blockwise reference even for mq shapes.
-        and os.environ.get("XLLM_PREFILL_ATTENTION_KERNEL") != "0"
-    ):
+    # query tile.
+    if routes.verify and q.shape[1] <= MQ_MAX_ROWS:
         from xllm_service_tpu.ops.pallas.paged_attention import (
             multiquery_paged_attention_kernel,
         )
@@ -537,7 +617,7 @@ def prefill_attention(
             return unpack_outputs(
                 multiquery_paged_attention_kernel(
                     q_packed, kk, vv, bt, sl, scale,
-                    interpret=interpret, window=window, layer=lyr,
+                    interpret=routes.interpret, window=window, layer=lyr,
                 ),
                 pack, kv_heads,
             )
@@ -547,10 +627,7 @@ def prefill_attention(
             layer=layer,
         )
 
-    env = os.environ.get("XLLM_PREFILL_ATTENTION_KERNEL")
-    if use_kernel is None:
-        use_kernel = (env != "0") if kernel_ok else (env == "1")
-    if use_kernel:
+    if routes.prefill:
         from xllm_service_tpu.ops.pallas.flash_prefill import (
             flash_prefill_kernel,
         )
@@ -560,7 +637,7 @@ def prefill_attention(
             return unpack_outputs(
                 flash_prefill_kernel(
                     q_packed, kk, vv, bt, sp, tl, scale,
-                    interpret=interpret, window=window, layer=lyr,
+                    interpret=routes.interpret, window=window, layer=lyr,
                     sinks=sinks,
                 ),
                 pack, kv_heads,
@@ -619,27 +696,22 @@ def mla_paged_attention(
     use_kernel: bool | None = None, interpret: bool = False, layer=None,
 ):
     """Decode MLA attention over the latent pool (the stack plus `layer`,
-    or one layer's 4-D cache): the Pallas kernel on TPU
-    where the tiles are eligible (XLLM_MLA_ATTENTION_KERNEL=0 is the
-    hatch back to the gather path; nothing forces the kernel where the
-    shapes decline it), the gather elsewhere. Int8 latent caches ride the kernel too (sub-channel
+    or one layer's 4-D cache): the Pallas kernel on TPU where the tiles
+    are eligible (attention_routes; `use_kernel` forces either way), the
+    gather elsewhere. Int8 latent caches ride the kernel too (sub-channel
     scales stream in a separate plane and dequantize in VMEM); `interpret`
     lets CI drive the kernel branch on CPU."""
-    import os
-
-    if use_kernel is None:
-        use_kernel = (
-            _mla_kernel_ok(c_cache, _on_tpu() or interpret)
-            and os.environ.get("XLLM_MLA_ATTENTION_KERNEL") != "0"
-        )
-    if use_kernel:
+    routes = attention_routes(
+        c_cache, latent=True, use_kernel=use_kernel, interpret=interpret
+    )
+    if routes.decode:
         from xllm_service_tpu.ops.pallas.mla_attention import (
             mla_attention_kernel,
         )
 
         return mla_attention_kernel(
             q_lat, c_cache, block_table, seq_lens, scale, kv_rank,
-            interpret=interpret, layer=layer,
+            interpret=routes.interpret, layer=layer,
         )
     return mla_paged_attention_gather(
         q_lat, c_cache, block_table, seq_lens, scale, kv_rank, layer=layer
@@ -661,24 +733,16 @@ def mla_prefill_attention(
 ) -> jnp.ndarray:
     """Batched MLA chunked-prefill attention in ABSORBED form; Pallas
     flash kernel (ops/pallas/mla_prefill.py) on TPU, vmapped blockwise
-    scan elsewhere. Int8 latent caches ride both kernel branches
-    (sub-channel scales stream in their own plane, VMEM dequant);
-    XLLM_MLA_PREFILL_KERNEL=0/1 forces the flash path, `interpret` drives
-    the kernel branches in CI."""
-    import os
-
-    quantized = isinstance(c_cache, kvc.PagedKV) and c_cache.quantized
-    # Speculative-verify shapes: the multi-query MLA decode kernel streams
-    # each latent row once (see the GQA analog in prefill_attention);
-    # int8 latent caches dequantize in VMEM inside the kernel.
-    # Opt-in via XLLM_MQ_ATTENTION_KERNEL=1 until chip-validated.
-    S = q_lat.shape[1]
-    if (
-        use_kernel is None
-        and S <= 8
-        and _mla_kernel_ok(c_cache, _on_tpu() or interpret)
-        and os.environ.get("XLLM_MQ_ATTENTION_KERNEL") == "1"
-    ):
+    scan elsewhere (attention_routes decides; `use_kernel` forces the
+    flash kernel either way, `interpret` drives the kernel branches in
+    CI). Int8 latent caches ride both kernels (sub-channel scales stream
+    in their own plane, VMEM dequant). Speculative-verify shapes take the
+    multi-query MLA decode kernel, which streams each latent row once
+    (see the GQA analog in prefill_attention), where it is opted in."""
+    routes = attention_routes(
+        c_cache, latent=True, use_kernel=use_kernel, interpret=interpret
+    )
+    if routes.verify and q_lat.shape[1] <= MQ_MAX_ROWS:
         from xllm_service_tpu.ops.pallas.mla_attention import (
             mla_multiquery_attention_kernel,
         )
@@ -686,26 +750,16 @@ def mla_prefill_attention(
         seq_lens = jnp.where(true_len > 0, start_pos + 1, 0)
         return mla_multiquery_attention_kernel(
             q_lat, c_cache, block_tables, seq_lens, scale,
-            kv_rank, interpret=interpret, layer=layer,
+            kv_rank, interpret=routes.interpret, layer=layer,
         )
-    if use_kernel is None:
-        env = os.environ.get("XLLM_MLA_PREFILL_KERNEL")
-        # int8 stays OPT-IN (env == "1") until the mla-prefill-int8 chip
-        # case validates — the convention for every unvalidated kernel
-        # path; bf16 keeps its existing default.
-        kernel_ok = (
-            _mla_kernel_ok(c_cache, _on_tpu() or interpret)
-            and not quantized
-        )
-        use_kernel = (env != "0") if kernel_ok else (env == "1")
-    if use_kernel:
+    if routes.prefill:
         from xllm_service_tpu.ops.pallas.mla_prefill import (
             mla_flash_prefill_kernel,
         )
 
         return mla_flash_prefill_kernel(
             q_lat, c_cache, block_tables, start_pos, true_len,
-            scale, kv_rank, interpret=interpret, layer=layer,
+            scale, kv_rank, interpret=routes.interpret, layer=layer,
         )
     return jax.vmap(
         lambda qi, ti, sp, tl: mla_prefill_blockwise(
@@ -767,13 +821,6 @@ def mla_prefill_blockwise(
     return out.astype(q_lat.dtype)
 
 
-@functools.lru_cache(maxsize=1)
-def _on_tpu() -> bool:
-    # A backend that fails to initialise raises here: an engine meant for
-    # the chip must not be routed to the gather/blockwise reference.
-    return jax.devices()[0].platform == "tpu"
-
-
 @region("attn")
 def paged_attention(
     q, k_cache, v_cache, block_table, seq_lens, scale,
@@ -787,201 +834,44 @@ def paged_attention(
     The kernel is the DEFAULT on TPU since round 2: validated on a real v5e
     chip (scripts/validate_kernel_tpu.py — max |err| vs the gather oracle
     0.002 in bf16, 2.5-8x faster across llama-8B/70B-class decode shapes).
-    Set XLLM_PAGED_ATTENTION_KERNEL=0 to force the gather path, =1 to force
-    the kernel even where the default heuristics decline it. Under a
-    declared shard context (set_shard_context; tp>1 meshes) the kernel
-    launches per-shard through shard_map — one launch per tp shard over
-    its own head slice — instead of degrading to a GSPMD-replicated
-    custom call.
+    attention_routes decides (XLLM_PAGED_ATTENTION_KERNEL=0 forces the
+    gather path, =1 the kernel even where the gates decline it, as
+    `use_kernel` does for one call). Under a declared shard context
+    (set_shard_context; tp>1 meshes) the kernel launches per-shard through
+    shard_map — one launch per tp shard over its own head slice — instead
+    of degrading to a GSPMD-replicated custom call.
 
     head_dim < 128 models ride the kernel through the packed-pair cache
     layout (kv_cache.kv_pack_factor: a bare [BS, 64] block slice is below
     one 128-lane Mosaic tile — observed on-chip as a tpu.memref_slice
     verification failure — so P heads pack per 128-lane row and queries
     embed block-diagonally, see pack_queries)."""
-    import os
-
-    ctx = shard_context() if _shardable(q, k_cache, shard_context()) else None
-    shards = ctx[0].shape[ctx[1]] if ctx is not None else 1
-    env = os.environ.get("XLLM_PAGED_ATTENTION_KERNEL")
-    if use_kernel is None:
-        kernel_ok = gqa_kernel_eligible(
-            k_cache, q.shape[-1], _on_tpu() or interpret, shards=shards
-        )
-        use_kernel = (env != "0") if kernel_ok else (env == "1")
-    if use_kernel:
-        try:
-            from xllm_service_tpu.ops.pallas.paged_attention import (
-                paged_attention_kernel,
-            )
-        except ImportError:
-            use_kernel = False
-        else:
-            def body(qq, kk, vv, bt, sl, lyr):
-                # Per-shard packing: kernel_io_for reads the (per-shard,
-                # under shard_map) cache geometry.
-                pack, kv_heads, q_packed = kernel_io_for(kk, qq)
-                return unpack_outputs(
-                    paged_attention_kernel(
-                        q_packed, kk, vv, bt, sl, scale,
-                        window=window, interpret=interpret, layer=lyr,
-                        sinks=sinks,
-                    ),
-                    pack, kv_heads,
-                )
-
-            return _kernel_call(
-                body, ctx, 3, q, k_cache, v_cache, block_table, seq_lens,
-                layer=layer,
-            )
-    return paged_attention_gather(
-        q, k_cache, v_cache, block_table, seq_lens, scale, window=window,
-        layer=layer, sinks=sinks,
-    )
-
-
-# ------------------------------------------------ ragged mixed batches
-# One attention call for a batch mixing chunked-prefill rows (arbitrary
-# query length, prefix-aware start offsets) and decode rows (query length
-# 1) over the same paged KV — the Ragged Paged Attention shape (arxiv
-# 2604.15464; docs/KERNELS.md). The flattened-query contract:
-#
-#   q        [T, Hq, D]   — all rows' query tokens, segment-concatenated
-#   seg_lens tuple (static) — per-row segment CAPACITY; sum == T. A row's
-#                             tokens live at [q_lo[b], q_lo[b]+q_len[b])
-#                             with q_lo = exclusive prefix sum of seg_lens
-#   q_len    [B] int32    — valid tokens per row (<= seg_lens[b]; 0 = dead)
-#   pos0     [B] int32    — ABSOLUTE position of the row's first query
-#                             token (prefix hits / decode context offset)
-#   tables   [B, CB]      — per-row block table
-#
-# Row b's token j sits at absolute position pos0[b]+j and attends cache
-# positions 0..pos0[b]+j (causal; `window` restricts to the trailing
-# window). Decode rows are seg_lens[b] == 1 with pos0 = seq_len - 1.
-
-
-def ragged_attention_blockwise(
-    q: jnp.ndarray,  # [T, Hq, D] flattened ragged queries
-    k_cache,
-    v_cache,
-    block_tables: jnp.ndarray,  # [B, CB]
-    q_len: jnp.ndarray,  # [B] int32
-    pos0: jnp.ndarray,  # [B] int32
-    seg_lens: tuple,  # static per-row segment capacities
-    scale: float,
-    window: int = 0,
-    layer=None,
-    sinks=None,
-) -> jnp.ndarray:
-    """Blockwise oracle for the ragged mixed contract: each row runs the
-    chunked-prefill blockwise scan (prefill_attention_blockwise handles
-    query length 1 — a decode row — exactly like the decode gather, and
-    arbitrary ragged lengths with prefix offsets). Exact; the CPU/parity
-    reference for ops/pallas/ragged_paged_attention.py. Returns
-    [T, Hq, D] with dead rows (q_len 0) zeroed."""
-    outs = []
-    off = 0
-    for b, seg in enumerate(seg_lens):
-        out_b = prefill_attention_blockwise(
-            q[off:off + seg], k_cache, v_cache, block_tables[b],
-            pos0[b], q_len[b], scale, window=window, layer=layer,
-            sinks=sinks,
-        )
-        # Blockwise emits acc/l with l=0 rows zeroed already; mask the
-        # padded tail explicitly so dead segments are deterministic.
-        valid = (
-            jnp.arange(seg, dtype=jnp.int32)[:, None, None] < q_len[b]
-        )
-        outs.append(jnp.where(valid, out_b, 0).astype(q.dtype))
-        off += seg
-    return jnp.concatenate(outs, axis=0)
-
-
-def _ragged_serves(k_cache, v_cache, sinks) -> bool:
-    """The ragged kernel has no sink logit and takes key and value rows
-    of one width: a launch with either goes to the decode and the flash
-    kernel side by side (or to the blockwise oracle), which have both."""
-    return sinks is None and (
-        kvc.raw(k_cache).shape[-1] == kvc.raw(v_cache).shape[-1]
-    )
-
-
-def ragged_kernel_enabled(
-    k_cache, q_head_dim: int, use_kernel: bool | None = None,
-    interpret: bool = False, shards: int = 1,
-) -> bool:
-    """Dispatch decision for the ragged mixed kernel. Follows the repo's
-    opt-in-until-chip-validated convention: the kernel is NEW silicon
-    surface (queued in scripts/validate_kernel_tpu.py as ragged-*), so
-    the default is OFF even on TPU until parity lands —
-    XLLM_RAGGED_ATTENTION_KERNEL=1 opts in, =0 forces the reference
-    path, and `interpret` (the XLLM_RAGGED_INTERPRET CI hook) opts in
-    on its own — the hook exists to DRIVE the kernel branch on CPU, so
-    it must select it, not merely flavor it (=0 still wins).
-    Tile/lane/packing eligibility via the shared gate."""
-    import os
-
-    if use_kernel is not None:
-        return use_kernel and gqa_kernel_eligible(
-            k_cache, q_head_dim, _on_tpu() or interpret, shards=shards
-        )
-    env = os.environ.get("XLLM_RAGGED_ATTENTION_KERNEL")
-    if env == "0":
-        return False
-    return (env == "1" or interpret) and gqa_kernel_eligible(
-        k_cache, q_head_dim, _on_tpu() or interpret, shards=shards
-    )
-
-
-def ragged_paged_attention(
-    q: jnp.ndarray,  # [T, Hq, D]
-    k_cache,
-    v_cache,
-    block_tables: jnp.ndarray,  # [B, CB]
-    q_len: jnp.ndarray,  # [B]
-    pos0: jnp.ndarray,  # [B]
-    seg_lens: tuple,
-    scale: float,
-    use_kernel: bool | None = None,
-    interpret: bool = False,
-    window: int = 0,
-    layer=None,
-    sinks=None,
-) -> jnp.ndarray:
-    """Ragged mixed-batch paged attention: ONE Pallas dispatch over
-    prefill + decode rows when the kernel is enabled
-    (ragged_kernel_enabled), blockwise oracle otherwise — ONE dispatch
-    PER TP SHARD under a shard context (the fused mixed/spec engine
-    steps stay one-launch-per-shard on multi-chip meshes). GQA head
-    packing rides the kernel_io_for/pack_queries contract like every
-    other GQA kernel path; int8 caches stream pool-native grouped
-    scales."""
-    ctx = shard_context() if _shardable(q, k_cache, shard_context()) else None
-    shards = ctx[0].shape[ctx[1]] if ctx is not None else 1
-    if _ragged_serves(k_cache, v_cache, sinks) and ragged_kernel_enabled(
-        k_cache, q.shape[-1], use_kernel, interpret, shards=shards
-    ):
-        from xllm_service_tpu.ops.pallas.ragged_paged_attention import (
-            ragged_paged_attention_kernel,
+    routes, ctx = _gqa_routes(q, k_cache, use_kernel, interpret, sinks)
+    if routes.decode:
+        from xllm_service_tpu.ops.pallas.paged_attention import (
+            paged_attention_kernel,
         )
 
-        def body(qq, kk, vv, bt, ql, p0, lyr):
+        def body(qq, kk, vv, bt, sl, lyr):
+            # Per-shard packing: kernel_io_for reads the (per-shard,
+            # under shard_map) cache geometry.
             pack, kv_heads, q_packed = kernel_io_for(kk, qq)
             return unpack_outputs(
-                ragged_paged_attention_kernel(
-                    q_packed, kk, vv, bt, ql, p0, seg_lens, scale,
-                    interpret=interpret, window=window, layer=lyr,
+                paged_attention_kernel(
+                    q_packed, kk, vv, bt, sl, scale,
+                    window=window, interpret=routes.interpret, layer=lyr,
+                    sinks=sinks,
                 ),
                 pack, kv_heads,
             )
 
         return _kernel_call(
-            body, ctx, 3, q, k_cache, v_cache, block_tables, q_len, pos0,
+            body, ctx, 3, q, k_cache, v_cache, block_table, seq_lens,
             layer=layer,
         )
-    return ragged_attention_blockwise(
-        q, k_cache, v_cache, block_tables, q_len, pos0, seg_lens, scale,
-        window=window, layer=layer, sinks=sinks,
+    return paged_attention_gather(
+        q, k_cache, v_cache, block_table, seq_lens, scale, window=window,
+        layer=layer, sinks=sinks,
     )
 
 
@@ -997,62 +887,27 @@ def mixed_attention(
     pf_start: jnp.ndarray,  # [P]
     pf_len: jnp.ndarray,  # [P]
     scale: float,
-    use_ragged: bool | None = None,
-    interpret: bool = False,
+    use_kernel: bool | None = None,
     window: int = 0,
     layer=None,
     sinks=None,
 ):
     """Attention for one MIXED engine step (models.llama.mixed_step):
-    decode slots and chunked-prefill rows against the same paged KV.
-
-    Ragged kernel on: the whole batch flattens into ONE Pallas dispatch
-    (seg_lens = R decode singletons + P Lpad segments). Otherwise the
-    reference path runs each half through its own serving dispatcher —
-    the Pallas decode kernel + flash prefill on TPU, gather + blockwise
-    on CPU — so mixed-step outputs match the split engine's byte for
-    byte while still fusing the rest of the step into one dispatch.
-    The halves may carry different context-bucket table widths (the
-    executor buckets each exactly like its split program); the ragged
-    flatten pads the narrower table with garbage-block-0 entries, which
-    the kernel's context bound never walks."""
-    R = q_dec.shape[0]
-    P, Lpad = q_pf.shape[0], q_pf.shape[1]
-    if _ragged_serves(k_cache, v_cache, sinks) and ragged_kernel_enabled(
-        k_cache, q_dec.shape[-1], use_ragged, interpret
-    ):
-        seg_lens = (1,) * R + (Lpad,) * P
-        q_flat = jnp.concatenate(
-            [q_dec, q_pf.reshape(P * Lpad, *q_pf.shape[2:])], axis=0
-        )
-        CB = max(dec_tables.shape[1], pf_tables.shape[1])
-        dt = jnp.pad(dec_tables, ((0, 0), (0, CB - dec_tables.shape[1])))
-        pt = jnp.pad(pf_tables, ((0, 0), (0, CB - pf_tables.shape[1])))
-        tables = jnp.concatenate([dt, pt], axis=0)
-        q_len = jnp.concatenate(
-            [jnp.minimum(dec_seq_lens, 1), pf_len]
-        ).astype(jnp.int32)
-        pos0 = jnp.concatenate(
-            [jnp.maximum(dec_seq_lens - 1, 0), pf_start]
-        ).astype(jnp.int32)
-        out = ragged_paged_attention(
-            q_flat, k_cache, v_cache, tables, q_len, pos0, seg_lens,
-            scale, use_kernel=True, interpret=interpret, window=window,
-            layer=layer, sinks=sinks,
-        )
-        return out[:R], out[R:].reshape(*q_pf.shape[:-1], out.shape[-1])
-    # Reference pair: EXACTLY the split engine's dispatchers. interpret
-    # is deliberately NOT forwarded — it is the ragged-branch CI hook,
-    # and leaking it here would flip the prefill half onto the
-    # interpret-mode flash kernel while split-step engines run
-    # blockwise, breaking the mixed ≡ split byte-parity contract.
+    decode slots and chunked-prefill rows against the same paged KV, each
+    half through its own serving dispatcher — the Pallas decode kernel +
+    flash prefill on TPU, gather + blockwise on CPU — so mixed-step
+    outputs match the split engine's byte for byte while the rest of the
+    step fuses into one dispatch (`use_kernel` forces both halves'
+    route, as it does each dispatcher's). The halves may carry different
+    context-bucket table widths (the executor buckets each exactly like
+    its split program)."""
     dec_out = paged_attention(
         q_dec, k_cache, v_cache, dec_tables, dec_seq_lens, scale,
-        window=window, layer=layer, sinks=sinks,
+        use_kernel=use_kernel, window=window, layer=layer, sinks=sinks,
     )
     pf_out = prefill_attention(
         q_pf, k_cache, v_cache, pf_tables, pf_start, pf_len, scale,
-        window=window, layer=layer, sinks=sinks,
+        use_kernel=use_kernel, window=window, layer=layer, sinks=sinks,
     )
     return dec_out, pf_out
 
@@ -1070,53 +925,16 @@ def mixed_prefill_attention(
     b_start: jnp.ndarray,  # [B]
     b_len: jnp.ndarray,  # [B]
     scale: float,
-    use_ragged: bool | None = None,
-    interpret: bool = False,
     window: int = 0,
     layer=None,
 ):
     """Attention for one fused speculative MIXED step
     (models.llama.mixed_verify_step): TWO prefill-shaped halves — the
     multi-query verify rows [A, S] and the chunked-prefill rows
-    [B, Lpad] — against the same paged KV.
-
-    Ragged kernel on: the whole heterogeneous batch flattens into ONE
-    Pallas dispatch (seg_lens = A S-segments + B Lpad-segments — a
-    verify row is just a ragged row with q_len = k+1, which the kernel
-    already serves; docs/KERNELS.md). Otherwise each half runs the exact
-    split serving dispatcher (prefill_attention — the program the sync
-    verify and split prefill paths use), so composed-step outputs match
-    sync+split byte for byte. `interpret` is the ragged-branch CI hook
-    only and is deliberately not forwarded to the reference pair, same
-    as mixed_attention."""
-    A, La = q_a.shape[0], q_a.shape[1]
-    B, Lb = q_b.shape[0], q_b.shape[1]
-    if ragged_kernel_enabled(
-        k_cache, q_a.shape[-1], use_ragged, interpret
-    ):
-        seg_lens = (La,) * A + (Lb,) * B
-        q_flat = jnp.concatenate(
-            [
-                q_a.reshape(A * La, *q_a.shape[2:]),
-                q_b.reshape(B * Lb, *q_b.shape[2:]),
-            ],
-            axis=0,
-        )
-        CB = max(a_tables.shape[1], b_tables.shape[1])
-        at = jnp.pad(a_tables, ((0, 0), (0, CB - a_tables.shape[1])))
-        bt = jnp.pad(b_tables, ((0, 0), (0, CB - b_tables.shape[1])))
-        tables = jnp.concatenate([at, bt], axis=0)
-        q_len = jnp.concatenate([a_len, b_len]).astype(jnp.int32)
-        pos0 = jnp.concatenate([a_start, b_start]).astype(jnp.int32)
-        out = ragged_paged_attention(
-            q_flat, k_cache, v_cache, tables, q_len, pos0, seg_lens,
-            scale, use_kernel=True, interpret=interpret, window=window,
-            layer=layer,
-        )
-        return (
-            out[: A * La].reshape(q_a.shape),
-            out[A * La:].reshape(q_b.shape),
-        )
+    [B, Lpad] — against the same paged KV, each through the split
+    serving dispatcher (prefill_attention — the program the sync verify
+    and split prefill paths use), so composed-step outputs match
+    sync+split byte for byte."""
     return (
         prefill_attention(
             q_a, k_cache, v_cache, a_tables, a_start, a_len, scale,
@@ -1127,102 +945,3 @@ def mixed_prefill_attention(
             window=window, layer=layer,
         ),
     )
-
-
-def resolved_kernel_report(
-    k_cache, q_head_dim: int, ragged_interpret: bool = False,
-    shards: int = 1,
-) -> dict:
-    """The dispatch decisions the serving paths would take RIGHT NOW for
-    this cache/geometry — what actually runs, not which env var is set
-    (bench.py reports these; ISSUE 9 satellite: `attention_kernel:
-    default` told the record nothing). Values name the winning
-    implementation; a path whose env hatch forces it off reports the
-    fallback with a ` (forced-off)` marker. `shards` > 1 resolves the
-    per-shard (shard_map) dispatch of a tp mesh: the report's `shards`
-    key is how many kernel launches one engine dispatch fans into —
-    asserted (not assumed) by the virtual-mesh differential suite."""
-    import os
-
-    # The interpret hook drives only the RAGGED branch on CPU (the
-    # decode/prefill serving dispatchers never see it from the engine),
-    # so the platform gate for those stays _on_tpu().
-    on = _on_tpu()
-    eligible = gqa_kernel_eligible(k_cache, q_head_dim, on, shards=shards)
-
-    def resolve(env_name: str, kernel: str, fallback: str) -> str:
-        env = os.environ.get(env_name)
-        if env == "0":
-            return f"{fallback} (forced-off)"
-        if env == "1":
-            return kernel
-        return kernel if eligible else fallback
-
-    dec = resolve("XLLM_PAGED_ATTENTION_KERNEL", "paged", "gather")
-    pf = resolve("XLLM_PREFILL_ATTENTION_KERNEL", "flash", "blockwise")
-    ragged = (
-        "ragged"
-        if ragged_kernel_enabled(
-            k_cache, q_head_dim, interpret=ragged_interpret, shards=shards
-        )
-        else (
-            "split (forced-off)"
-            if os.environ.get("XLLM_RAGGED_ATTENTION_KERNEL") == "0"
-            else "split"
-        )
-    )
-    kq = isinstance(k_cache, kvc.PagedKV) and k_cache.quantized
-    mq_env = os.environ.get("XLLM_MQ_ATTENTION_KERNEL")
-    # The prefill dispatcher's function-wide kill switch covers its mq
-    # branch too (prefill_attention requires != "0"), so the report must
-    # mirror it — mq never runs with the prefill kernels forced off.
-    mq_on = (
-        eligible
-        and os.environ.get("XLLM_PREFILL_ATTENTION_KERNEL") != "0"
-        and (mq_env == "1" if kq else mq_env != "0")
-    )
-    return {
-        "decode": dec,
-        "prefill": pf,
-        "mixed": ragged,
-        "mq": "mq" if mq_on else "blockwise",
-        # Kernel launches one engine dispatch fans into: tp under the
-        # shard_map tier, 1 on single-device meshes (or with the
-        # XLLM_SHARDED_KERNELS=0 escape hatch back to GSPMD).
-        "shards": shards,
-    }
-
-
-def resolved_mla_kernel_report(c_cache) -> dict:
-    """MLA counterpart of resolved_kernel_report: mirrors the actual
-    dispatch decisions of mla_paged_attention / mla_prefill_attention —
-    including the _mla_kernel_ok tile/platform gate those dispatchers
-    apply — not just the env vars. A mixed step of the family runs the
-    decode and the prefill op side by side (no ragged latent kernel)."""
-    import os
-
-    ok = _mla_kernel_ok(c_cache, _on_tpu())
-    quantized = isinstance(c_cache, kvc.PagedKV) and c_cache.quantized
-    dec_env = os.environ.get("XLLM_MLA_ATTENTION_KERNEL")
-    pf_env = os.environ.get("XLLM_MLA_PREFILL_KERNEL")
-    mq_env = os.environ.get("XLLM_MQ_ATTENTION_KERNEL")
-    # mla_paged_attention: on where tile-eligible ("0" is the hatch).
-    dec = "mla" if ok and dec_env != "0" else "gather"
-    # mla_prefill_attention: default-on for eligible bf16 latents
-    # (kernel_ok = ok and not quantized); env == "1" forces, "0" kills.
-    pf_ok = ok and not quantized
-    if (pf_env != "0") if pf_ok else (pf_env == "1"):
-        pf = "mla-flash"
-    elif pf_ok and pf_env == "0":
-        pf = "blockwise (forced-off)"
-    else:
-        pf = "blockwise"
-    return {
-        "decode": dec,
-        "prefill": pf,
-        "mixed": f"{dec}+{pf}",
-        "mq": "mla-mq" if (ok and mq_env == "1") else "blockwise",
-        # MLA's latent cache has no KV-head axis to shard — the kernels
-        # stay single-launch (docs/SHARDING.md).
-        "shards": 1,
-    }

@@ -265,7 +265,7 @@ class _Seq:
         # (preempt + same-pass resume into the same slot must not let the
         # stale in-flight token through the drain's identity check).
         self.admit_gen = 0
-        # Mixed (ragged) stepping: prompt tokens DISPATCHED through
+        # Mixed stepping: prompt tokens DISPATCHED through
         # prefill chunks, >= `prefilled` while a chunk is in flight — the
         # step builder cuts the next chunk from here so back-to-back
         # chunks pipeline instead of waiting out each drain.
@@ -313,7 +313,7 @@ class _InFlight:
         self.t0 = t0
         self.nactive = nactive
         self.total_ctx = total_ctx
-        # Mixed (ragged) step: [(seq, admit_gen, row_idx, chunk_start,
+        # Mixed step: [(seq, admit_gen, row_idx, chunk_start,
         # chunk_end, queued_ms — the engine queue wait on a request's
         # first chunk, else None)] prefill rows riding this dispatch —
         # their sampled tokens sit at output index R + row_idx
@@ -485,13 +485,6 @@ class InferenceEngine:
         # pipeline depth (`_force_sync`: cfg.sync_engine), whether due
         # prefill chunks ride the dispatch (`mixed_step_enabled`:
         # executor.fuses_prefill) and cfg.speculative_tokens.
-        import os as _os
-
-        # Test hook: drive the ragged Pallas kernel branch in interpret
-        # mode on CPU (the dispatcher convention every kernel follows).
-        self._ragged_interpret = (
-            _os.environ.get("XLLM_RAGGED_INTERPRET") == "1"
-        )
         # Sequences mid-chunked-prefill under mixed stepping: they hold
         # slot + blocks (like split mode's waiting-held mid-chunk seqs)
         # but live HERE, keyed by request id, so the step builder can cut
@@ -847,7 +840,7 @@ class InferenceEngine:
             "xllm_engine_loop_errors_total",
             "Engine-loop iterations that raised (loop stays alive)",
         ).set_function(lambda: self.loop_errors)
-        # Mixed (ragged) step instruments (docs/KERNELS.md +
+        # Mixed step instruments (docs/KERNELS.md +
         # docs/OBSERVABILITY.md): how often the fused prefill+decode
         # dispatch runs and how it composes.
         self.metrics.counter(
@@ -913,14 +906,8 @@ class InferenceEngine:
             "decode": rep.get("decode", "unknown"),
             "prefill": rep.get("prefill", "unknown"),
             "mq": rep.get("mq", "unknown"),
-            # The report resolves XLLM_RAGGED_INTERPRET (incl. tile
-            # eligibility), so "ragged" here means the ragged branch
-            # actually dispatches — not merely that a hook is set.
-            "mixed": (
-                "ragged" if rep.get("mixed") == "ragged"
-                else f"mixed[{rep.get('decode', '?')}+"
-                f"{rep.get('prefill', '?')}]"
-            ),
+            # the pair a fused step launches side by side: "paged+flash"
+            "mixed": rep.get("mixed", "unknown"),
         }
         self.metrics.counter(
             "xllm_engine_kv_chunk_land_errors_total",
@@ -1420,7 +1407,7 @@ class InferenceEngine:
             self._pf_active.clear()
         return produced
 
-    # ------------------------------------------------ mixed (ragged) step
+    # --------------------------------------------------------- mixed step
 
     @thread_owned("engine")
     def _continue_pf_chunks(self, items_meta: List[tuple],
@@ -1638,7 +1625,6 @@ class InferenceEngine:
                     self._block_tables,
                     can,
                     batch,
-                    interpret=self._ragged_interpret,
                 )
             else:
                 tokens, logprobs = self.executor.decode_start(
@@ -1752,7 +1738,7 @@ class InferenceEngine:
         concurrent short prompts share a single device step (round-1 weak
         item 4).
 
-        Mixed (ragged) stepping passes `mixed_collect`: freshly admitted
+        Mixed stepping passes `mixed_collect`: freshly admitted
         seqs ELIGIBLE for the fused step (plain text — no media/stream,
         no guided mask, no SP-ring routing) are appended there (and
         registered in _pf_active) instead of prefilling here; their
@@ -3744,7 +3730,7 @@ class InferenceEngine:
         """Dispatch the next speculative verify step without fetching
         results (executor.verify_start), fused with the due prefill
         chunks when `items_meta` holds any (the composed path: verify
-        rows are q_len = k+1 ragged rows next to the chunks —
+        rows are q_len = k+1 prefill-shaped rows next to the chunks —
         docs/KERNELS.md). The step's verify inputs — last accepted
         token, position and step base — are gathered ON DEVICE from the
         in-flight step's output, so the VARIABLE accepted count never
@@ -3809,7 +3795,6 @@ class InferenceEngine:
                     self._block_tables,
                     can,
                     batch,
-                    interpret=self._ragged_interpret,
                 )
             )
         snapshot, nactive, total_ctx = self._snapshot_dispatch(

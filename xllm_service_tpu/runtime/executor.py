@@ -742,7 +742,7 @@ class ModelExecutor:
                 )
 
         # One context bucket or a grid of them (`_ctx_bucket`), decided
-        # once, from what kernel_report() says of the pools just built.
+        # once, from the routes of the launches over the pools just built.
         self.whole_table = self._table_costs_nothing()
         # Generated-token histogram per slot (presence/frequency penalties).
         # int32 [R, V] — 32 MB at V=128K, R=64; donated through every step.
@@ -1146,7 +1146,7 @@ class ModelExecutor:
         # (qwen2.5-3b decode: 4.44 GiB -> 0.3 MiB, compiled for a v5e), so
         # the second half of this budget is free HBM. The halving stays
         # for now: un-halving doubles the pool and changes what a cell
-        # serves, so it is its own, separately measured PR (ROADMAP A4b).
+        # serves, so it is its own, separately measured PR (ROADMAP A8).
         budget = (
             total_hbm * self.engine_cfg.hbm_utilization
             - n_params * param_bytes / tp
@@ -1330,24 +1330,16 @@ class ModelExecutor:
     def _table_costs_nothing(self) -> bool:
         """Whether a step may take the whole block table at every context
         (`_ctx_bucket`): a window family (on every backend), and wherever
-        every attention launch of the step programs resolves to a Pallas
-        kernel (kernel_report(): decode `paged` or `mla`, prefill `flash`
-        or `mla-flash`, and the verify shapes' `mq` where the engine
-        speculates). Their walks are bounded by a row's context (`nb =
-        cdiv(seq_len, block_size)`), not by its table; the gather and
+        every attention launch of the step programs is bounded by its
+        row's context (`nb = cdiv(seq_len, block_size)`) and not by its
+        table, as the Pallas kernels are (ops.attention.Routes: the
+        decision the dispatchers take their branch from); the gather and
         blockwise fallbacks read every column they are given."""
         if self.window_tables:
             return True
         if not self.has_paged_cache:  # a one-column table either way
             return False
-        rep = self.kernel_report()
-        launches = [
-            rep.get("decode") in ("paged", "mla"),
-            rep.get("prefill") in ("flash", "mla-flash"),
-        ]
-        if self.engine_cfg.speculative_tokens > 0:
-            launches.append(rep.get("mq") in ("mq", "mla-mq"))
-        return all(launches)
+        return all(r.bounded_by_context for r in self._attention_routes())
 
     def _ctx_bucket(self, need: int) -> int:
         """Blocks a table of a step whose rows need `need`: a table as wide
@@ -1781,7 +1773,6 @@ class ModelExecutor:
         families: Dict[str, int] = {}
         families["split"] = len(self.warmup())
 
-        interp = os.environ.get("XLLM_RAGGED_INTERPRET") == "1"
         p_walk = [1]
         if p_groups:
             pmax = min(self.PREFILL_GROUP_MAX, R)
@@ -1815,7 +1806,6 @@ class ModelExecutor:
                         self.mixed_start(
                             items, np.zeros((R,), np.int32), None, None,
                             positions, tables, active, batch,
-                            interpret=interp,
                         )
                         n += 1
             families["mixed"] = n
@@ -1850,7 +1840,7 @@ class ModelExecutor:
                             host_pos,
                             np.zeros((R,), np.int32),  # host_steps
                             None, None, None,  # every row host-fed
-                            tables, active, batch, interpret=interp,
+                            tables, active, batch,
                         )
                         n += 1
             families["mixed_verify"] = n
@@ -1879,8 +1869,7 @@ class ModelExecutor:
                     )
                     self.mixed_start(
                         pf_items(n_tok, sp, 1), np.zeros((R,), np.int32),
-                        None, None, positions, tables, active,
-                        gbatch, interpret=interp,
+                        None, None, positions, tables, active, gbatch,
                     )
                     n += 1
             families["guided"] = n
@@ -2076,23 +2065,6 @@ class ModelExecutor:
         step (runtime/engine.py's one loop): every family with a
         `mixed_step`."""
         return hasattr(self.model_mod, "mixed_step")
-
-    @property
-    def kernel_shards(self) -> int:
-        """How many per-shard kernel launches one attention dispatch fans
-        into (docs/SHARDING.md): tp under the shard_map tier, 1 on
-        single-device meshes, for MLA (latent cache replicated — nothing
-        to shard), or with the XLLM_SHARDED_KERNELS=0 escape hatch."""
-        from xllm_service_tpu.ops import attention
-
-        tp = self.mesh.shape.get("tp", 1)
-        if (
-            tp <= 1
-            or self.cfg.is_mla
-            or not attention.sharded_kernels_enabled()
-        ):
-            return 1
-        return tp
 
     def _set_shard_ctx(self) -> None:
         """Declare this executor's mesh as the calling thread's kernel
@@ -2300,54 +2272,25 @@ class ModelExecutor:
             return 1
         return ep
 
+    def _attention_routes(self):
+        """The family's decisions for the attention launches over this
+        executor's paged pools, on its mesh (ops.attention.Routes each)."""
+        return self.model_mod.attention_routes(
+            self.cfg, self.k_cache, tp=self.mesh.shape.get("tp", 1)
+        )
+
     def kernel_report(self) -> Dict[str, str]:
         """Resolved attention-dispatch decisions for THIS executor's cache
-        and geometry — what bench.py reports instead of echoing raw env
-        vars (ISSUE 9 satellite). Includes the per-shard fan-out
-        (`shards`) and marks the resolve_kv_packing downgrade as
-        `gather-fallback` so a tp that strands the packed layout shows up
-        in bench rows and /metrics, not just a log line."""
-        if self.cfg.is_retention:
-            from xllm_service_tpu.ops import retention as retention_ops
-
-            route = (
-                "retention-pallas"
-                if retention_ops.use_kernels(self.cfg.head_dim)
-                else "retention-xla"
-            )
-            return {"decode": route, "prefill": route, "mixed": route}
-        if self.cfg.is_hybrid:
-            from xllm_service_tpu.ops.attention import resolved_kernel_report
-
-            rep = resolved_kernel_report(
-                self._paged(self.k_cache), self.cfg.head_dim, shards=1
-            )
-            if self.window_tables:
-                rep["window"] = self.model_mod.window_route(self.cfg, self.k_cache[1])
-            else:
-                rep["state"] = self.model_mod.state_route(self.cfg, self.k_cache[1])
-            return self._add_moe_report(rep)
-        if self.cfg.is_mla:
-            from xllm_service_tpu.ops.attention import (
-                resolved_mla_kernel_report,
-            )
-
-            # The latent cache rides the k slot (num_caches == 1).
-            return self._add_moe_report(
-                resolved_mla_kernel_report(self.k_cache)
-            )
-        from xllm_service_tpu.ops.attention import resolved_kernel_report
-
-        rep = resolved_kernel_report(
-            self.k_cache, self.cfg.head_dim,
-            ragged_interpret=(
-                os.environ.get("XLLM_RAGGED_INTERPRET") == "1"
-            ),
-            shards=self.kernel_shards,
+        and geometry, as the family module names them: the decision the
+        dispatchers take (ops.attention.attention_routes), not a copy of
+        it. Includes the per-shard fan-out (`shards`) and marks the
+        resolve_kv_packing downgrade as `gather-fallback` so a tp that
+        strands the packed layout shows up in bench rows and /metrics,
+        not just a log line."""
+        rep = self.model_mod.kernel_report(
+            self.cfg, self.k_cache, tp=self.mesh.shape.get("tp", 1)
         )
-        if self.kv_pack_fallback and rep.get("decode", "").startswith(
-            "gather"
-        ):
+        if self.kv_pack_fallback and rep["decode"].startswith("gather"):
             rep["decode"] = "gather-fallback"
         return self._add_moe_report(rep)
 
@@ -2391,8 +2334,6 @@ class ModelExecutor:
         pf_mask_rows=None,  # [P] rows into guided_table (prefill rows)
         guided_table=None,  # [M+1+D, V] bool
         lpad=None,  # static: the padded chunk's width inside pf_pack
-        use_ragged=None,
-        interpret=False,
     ):
         """One fused engine step: decode slots + due prefill chunks in a
         single compiled dispatch (models.<family>.mixed_step). Sampling
@@ -2400,7 +2341,7 @@ class ModelExecutor:
         the split _decode_impl/_prefill_impl, and the model halves keep
         their split-program shapes (mixed_step docstring), so the
         emitted streams are byte-identical to split stepping
-        (tests/test_ragged_attention.py pins it). Output layout: decode
+        (tests/test_mixed_step.py pins it). Output layout: decode
         slots first, then the P prefill rows; the decode slots' tokens
         once more on their own, as the next overlapped dispatch's
         device-side feedback."""
@@ -2421,11 +2362,9 @@ class ModelExecutor:
             pf["start"],
             pf["len"],
             pf_tables,
-            use_ragged=use_ragged,
             lora_dec=lora_dec,
             lora_pf=lora_pf,
             rope_delta=rope_delta,
-            interpret=interpret,
         )
         tokens, logprob, _ = sampling_ops.sample_tokens(
             dec_logits, d["temperature"], d["top_k"], d["top_p"], step_keys,
@@ -2473,14 +2412,12 @@ class ModelExecutor:
         block_tables: np.ndarray,  # [R, max_blocks_per_seq]
         active: np.ndarray,  # [R] bool
         batch: SamplingBatch,
-        use_ragged: Optional[bool] = None,
-        interpret: bool = False,
     ):
         """Dispatch ONE mixed prefill+decode step without fetching results:
         returns (tokens, logprobs, feed) device arrays — the first two of
         width R + Ppad, decode slots at [:R], prefill row j at R + j;
         `feed` the decode slots' tokens alone, [R], what the next
-        overlapped dispatch takes as `prev_tokens`. The engine's ragged step
+        overlapped dispatch takes as `prev_tokens`. The engine's mixed step
         builder is the only caller (docs/KERNELS.md); media/M-RoPE items
         never reach here (routed to the split prefill path). Guided
         items DO ride (ISSUE 13): final chunks carry mask_row and the
@@ -2508,15 +2445,14 @@ class ModelExecutor:
                 self._mixed_jit = self._step_jit(
                     self._mixed_impl,
                     donate_argnums=(0, 1, 2),
-                    static_argnames=("lpad", "use_ragged", "interpret"),
+                    static_argnames=("lpad",),
                 )
             (
                 self.k_cache, self.v_cache, self.token_counts,
                 tokens, logprobs, feed,
             ) = self._mixed_jit(
                 self.k_cache, self.v_cache, self.token_counts, self.params,
-                pack, prev_tokens, pf_pack, lpad=lpad,
-                use_ragged=use_ragged, interpret=interpret, **opt,
+                pack, prev_tokens, pf_pack, lpad=lpad, **opt,
             )
         return tokens, logprobs, feed
 
@@ -2631,8 +2567,8 @@ class ModelExecutor:
     def supports_spec_mixed(self) -> bool:
         """Whether this model family can fuse speculative verify rows
         with prefill chunks in one dispatch (mixed_verify_step). MLA
-        families run the pipelined verify WITHOUT prefill fusion until
-        the ragged kernel grows a latent-row mode (docs/KERNELS.md)."""
+        families run the pipelined verify WITHOUT prefill fusion: their
+        module has no such step."""
         return hasattr(self.model_mod, "mixed_verify_step")
 
     @obs_spans.region("step_io")
@@ -2777,8 +2713,6 @@ class ModelExecutor:
         pf_min_p=None,
         pf_mask_rows=None,  # [P] (prefill rows)
         lpad=None,  # static: the padded chunk's width inside pf_pack
-        use_ragged=None,
-        interpret=False,
     ):
         """One fused speculative engine step: the pipelined verify rows
         AND the due prefill chunks in a single compiled dispatch
@@ -2807,11 +2741,9 @@ class ModelExecutor:
                 pf["start"],
                 pf["len"],
                 pf_tables,
-                use_ragged=use_ragged,
                 lora_ver=lora_idx,
                 lora_pf=lora_pf,
                 ver_rope_delta=rope_delta,
-                interpret=interpret,
             )
         )
         tokens, logprobs, n_emit, counts = sampling_ops.speculative_sample(
@@ -2851,7 +2783,6 @@ class ModelExecutor:
         block_tables: np.ndarray,  # [R, max_blocks_per_seq]
         active: np.ndarray,  # [R] bool
         batch: SamplingBatch,
-        interpret: bool = False,
     ):
         """Dispatch ONE pipelined speculative verify step — optionally
         fused with due prefill chunks — without fetching results.
@@ -2933,14 +2864,14 @@ class ModelExecutor:
                 self._mixed_verify_jit = self._step_jit(
                     self._mixed_verify_impl,
                     donate_argnums=(0, 1, 2),
-                    static_argnames=("lpad", "use_ragged", "interpret"),
+                    static_argnames=("lpad",),
                 )
             (
                 self.k_cache, self.v_cache, self.token_counts,
                 tokens, logprobs, n_emit, pf_tok, pf_lp,
             ) = self._mixed_verify_jit(
                 self.k_cache, self.v_cache, self.token_counts, self.params,
-                *args, pf_pack, lpad=lpad, interpret=interpret, **opt,
+                *args, pf_pack, lpad=lpad, **opt,
             )
         return tokens, logprobs, n_emit, pf_tok, pf_lp
 
