@@ -560,7 +560,7 @@ def run_mla_prefill_case(P, Lpad, Hq, kvr, dr, BS, MB, dtype=jnp.bfloat16,
 # Ordered so the never-yet-chip-validated kernels come first (round 3
 # queue: int8 scale-DMA decode, MLA decode, flash prefill) — the bf16
 # decode cases at the tail were already chip-validated in round 2.
-def _mamba_inputs(rows, Lc, H, P, N, seed=0):
+def _mamba_inputs(rows, Lc, H, P, N, seed=0, G=1):
     """Scan inputs in the band the benchmark's weights give: steps
     log-uniform in 1e-3..1e-1, A in 1..16."""
     ks = jax.random.split(jax.random.key(seed), 6)
@@ -568,14 +568,15 @@ def _mamba_inputs(rows, Lc, H, P, N, seed=0):
     x = jax.random.normal(ks[0], lead + (H, P), jnp.float32)
     dt = jnp.exp(jax.random.uniform(ks[1], lead + (H,), jnp.float32, np.log(1e-3), np.log(1e-1)))
     A = -jax.random.uniform(ks[2], (H,), jnp.float32, 1.0, 16.0)
-    B = jax.random.normal(ks[3], lead + (1, N), jnp.float32)
-    C = jax.random.normal(ks[4], lead + (1, N), jnp.float32)
+    B = jax.random.normal(ks[3], lead + (G, N), jnp.float32)
+    C = jax.random.normal(ks[4], lead + (G, N), jnp.float32)
     return x, dt, A, B, C, jnp.ones((H,), jnp.float32)
 
 
-def run_mamba_update_case(R, live, L, H, P, N, tile=None):
-    """mamba_update_kernel as granite-4.0-h-small's decode program calls
-    it: one launch a Mamba layer over the L-layer state pool of R slots,
+def run_mamba_update_case(R, live, L, H, P, N, tile=None, G=1):
+    """mamba_update_kernel as a hybrid cell's decode program calls it
+    (granite-4.0-h-small: G 1, N 128, P 64; falcon-h1-34b: G 2, N 256,
+    P 128): one launch a Mamba layer over the L-layer state pool of R slots,
     `live` of the R rows active (scattered). us a CALL (the L launches of
     one program / L), the share of 819 GB/s the live rows' state bytes
     (read and written) make of it, and the largest error of y and of the
@@ -583,14 +584,14 @@ def run_mamba_update_case(R, live, L, H, P, N, tile=None):
     from xllm_service_tpu.ops import mamba as mo
     from xllm_service_tpu.ops.pallas import mamba as pm
 
-    if tile:
-        pm.HEAD_TILE = tile
+    if tile:  # lane rows a grid step, whatever the block then weighs
+        pm.HEAD_TILE, pm.TILE_BYTES = tile, tile * N * 128 * 4
     rng = np.random.default_rng(0)
     act = np.zeros(R, bool)
     act[rng.choice(R, live, replace=False)] = True
     act = jnp.asarray(act)
-    x, dt, A, B, C, D = _mamba_inputs(R, None, H, P, N)
-    shape = mo.state_shapes(L, R, H, P, N, 4, H * P + 2 * N)[0]
+    x, dt, A, B, C, D = _mamba_inputs(R, None, H, P, N, G=G)
+    shape = mo.state_shapes(L, R, H, P, N, 4, H * P + 2 * G * N)[0]
     S = jax.jit(lambda k: jax.lax.map(
         lambda k_: jax.random.normal(k_, shape[1:], jnp.float32), jax.random.split(k, L)
     ))(jax.random.key(1))
@@ -618,24 +619,24 @@ def run_mamba_update_case(R, live, L, H, P, N, tile=None):
     tk = bench(once, iters=8) / L
     need = 2 * live * H * P * N * 4
     print(
-        f"MAMBA-UPDATE R={R} live={live} L={L} H={H} P={P} N={N} "
-        f"tile={pm.head_tile(shape[2])} err_y={err_y:.2e} err_S={err_s:.2e} "
+        f"MAMBA-UPDATE R={R} live={live} L={L} H={H} P={P} N={N} G={G} "
+        f"tile={pm.head_tile(shape[2] // G, N * shape[4] * 4)} err_y={err_y:.2e} err_S={err_s:.2e} "
         f"call={tk*1e6:8.1f}us row={tk*1e6/live:6.2f}us/live-row "
         f"bw={need/tk/1e9:6.1f}GB/s hbm_share={100*need/tk/819e9:5.1f}%"
     )
     return max(err_y, err_s) * 1e-2  # float32: the parity bound is main's 0.05
 
 
-def run_mamba_chunk_case(Lc, L, H, P, N, slots=8):
+def run_mamba_chunk_case(Lc, L, H, P, N, slots=8, G=1):
     """The chunk form (plain XLA, ops/mamba.py chunk_update) over an
     L-layer pool: one chunk of Lc tokens from a FRESH state, then the next
     from the CARRIED one, against the token-by-token recurrence; us a
     layer's call for each."""
     from xllm_service_tpu.ops import mamba as mo
 
-    x, dt, A, B, C, D = _mamba_inputs(1, 2 * Lc, H, P, N, seed=3)
+    x, dt, A, B, C, D = _mamba_inputs(1, 2 * Lc, H, P, N, seed=3, G=G)
     y_ref, S_ref = jax.jit(mo.recurrent_form)(x[0], dt[0], A, B[0], C[0], D)
-    shape = mo.state_shapes(L, slots, H, P, N, 4, H * P + 2 * N)[0]
+    shape = mo.state_shapes(L, slots, H, P, N, 4, H * P + 2 * G * N)[0]
     S = jnp.full(shape, 2.0, jnp.float32)
     slot = jnp.array([3], jnp.int32)
 
@@ -665,7 +666,7 @@ def run_mamba_chunk_case(Lc, L, H, P, N, slots=8):
         return bench(once, iters=8) / L
 
     t0, t1 = timed(fresh), timed(carried)
-    print(f"MAMBA-CHUNK Lc={Lc} L={L} H={H} P={P} N={N} err={err:.2e} "
+    print(f"MAMBA-CHUNK Lc={Lc} L={L} H={H} P={P} N={N} G={G} err={err:.2e} "
           f"fresh={t0*1e6:8.1f}us carried={t1*1e6:8.1f}us a layer")
     return err * 1e-2
 
@@ -708,6 +709,27 @@ CASES = [
     ("mamba-update-tile32", run_mamba_update_case,
      dict(R=64, live=44, L=9, H=128, P=64, N=128, tile=32)),
     ("mamba-chunk", run_mamba_chunk_case, dict(Lc=256, L=9, H=128, P=64, N=128)),
+    # falcon-h1-34b.dialog-steady's launches (PERF.md, PR 53): the update
+    # kernel at TWO B/C groups, state 256 and one 128-lane head a lane row
+    # (a 128 KiB plane) over the cut's 9 layers with 32 of 64 rows live,
+    # at 8 (head_tile's choice) and 16 lane rows a grid step (Mosaic takes
+    # no tile under 8 sublanes of the per-head operands); the chunk
+    # form at that shape; and the attention launches at a query group of 5
+    # (20 / 4 heads of 128): decode over contexts of 256-3840, the cache
+    # write, and one 256-token chunk through the flash-prefill kernel.
+    ("mamba-update-dialog", run_mamba_update_case,
+     dict(R=64, live=32, L=9, H=32, P=128, N=256, G=2)),
+    ("mamba-update-dialog-tile16", run_mamba_update_case,
+     dict(R=64, live=32, L=9, H=32, P=128, N=256, G=2, tile=16)),
+    ("mamba-chunk-dialog", run_mamba_chunk_case,
+     dict(Lc=256, L=9, H=32, P=128, N=256, G=2)),
+    ("cell-dialog-decode", run_cell_case,
+     dict(R=64, Hq=20, Hkv=4, D=128, BS=128, MB=32, L=9, N=800, live=32,
+          ctx_lo=256, ctx_hi=3840)),
+    ("kv-write-dialog", run_kv_write_case,
+     dict(S=64, live=32, L=9, N=800, Hc=4, D=128, BS=128)),
+    ("prefill-group5", run_prefill_case,
+     dict(P=1, Lpad=256, Hq=20, Hkv=4, D=128, BS=128, MB=24)),
     # int8 KV cache (scale DMA + column folding) at production block size
     ("dec-int8-a", run_case,
      dict(R=64, Hq=32, Hkv=8, D=128, BS=128, MB=16, ctx=2048, int8=True)),

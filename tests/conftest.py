@@ -75,6 +75,22 @@ def _locktrace_guard():
         )
 
 
+@pytest.fixture(autouse=True)
+def _no_mesh_left_declared():
+    """`ops.attention.set_shard_context` is per THREAD and read at trace
+    time: an executor built at tp > 1 on a worker's main thread leaves its
+    mesh declared, and a later test on that worker that plans or traces a
+    kernel launch itself (tests/test_pallas_kernels.py's cache writes)
+    then finds the Pallas route closed, by the order `--dist load` handed
+    the tests out (38 such failures in one whole run of PR 53, none in the
+    one before it). Every test starts with no mesh declared; an executor
+    declares its own before every step it traces."""
+    from xllm_service_tpu.ops import attention
+
+    attention.set_shard_context(None)
+    yield
+
+
 @pytest.fixture(scope="session")
 def cpu_devices():
     import jax

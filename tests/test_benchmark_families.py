@@ -30,9 +30,9 @@ def configurations():
 
 
 def test_the_benchmark_has_both_families():
-    # six since PR 49 (the name stays: the driver counts tests by name)
+    # seven since PR 53 (the name stays: the driver counts tests by name)
     assert {c["family"] for c in configurations()} == {
-        "llama", "brumby", "deepseek", "granite", "solar", "mimo"}
+        "llama", "brumby", "deepseek", "granite", "solar", "mimo", "falcon_h1"}
 
 
 @pytest.mark.parametrize("config", configurations(), ids=lambda c: c["name"])
@@ -57,8 +57,9 @@ def test_model_config_is_the_family_the_program_dispatches_on(config):
 
     cfg = family_mod.load(config).model_config(config["name"], config)
     module = models.get_module(cfg).__name__.rsplit(".", 1)[-1]
-    # the three hybrids are ONE stack: models/granite.py, the layer kinds as data
-    assert module == {"solar": "granite", "mimo": "granite"}.get(config["family"], config["family"])
+    # the four hybrids are ONE stack: models/granite.py, the layer kinds as data
+    assert module == {"solar": "granite", "mimo": "granite", "falcon_h1": "granite"}.get(
+        config["family"], config["family"])
     assert cfg.is_retention == (config["family"] == "brumby")
 
 
@@ -315,3 +316,60 @@ def test_the_mimo_family_is_the_cut_and_draws_sinks_that_take_their_share():
         assert float(jnp.std(leaf.astype(jnp.float32))) > 0.0
     assert abs(float(jnp.std(w["layers"]["w_down"])) * np.sqrt(32) / fam.ROUTED_OUT_SCALE - 1.0) < 0.1
     assert abs(float(jnp.std(w["lm_head"])) * np.sqrt(64) - 1.0) < 0.05
+
+
+def test_the_falcon_h1_family_is_the_cut_and_draws_against_its_multipliers():
+    """falcon-h1-34b as cut: 9 of 72 blocks, an eighth of both vocabulary
+    matrices, every block the parallel kind at the published widths and
+    multipliers; and at the rehearsal size the draws: every matrix behind
+    a multiplier at the INVERSE of it (so that no branch vanishes and the
+    scores have spread), float32 where the family says, no gain at 1,
+    nothing at 0."""
+    import jax
+    import jax.numpy as jnp
+
+    with open(os.path.join(BENCH, "configs", "falcon-h1-34b.json")) as f:
+        config = json.load(f)
+    fam = family_mod.load(config)
+    cfg = fam.model_config(config["name"], config)
+    assert (cfg.num_layers, cfg.vocab_size, cfg.layer_types) == (9, 32640, ("parallel",) * 9)
+    assert (config["num_hidden_layers_published"], config["vocab_size_published"]) == (72, 261120)
+    assert config["reduced"] == ["num_hidden_layers", "vocab_size"]
+    assert (cfg.num_heads, cfg.num_kv_heads, cfg.head_dim, cfg.rotary_dim) == (20, 4, 128, 128)
+    assert (cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_n_groups, cfg.mamba_d_state) == (32, 128, 2, 256)
+    assert cfg.ssm_multipliers == tuple(config["ssm_multipliers"]) and len(cfg.ssm_multipliers) == 5
+    assert cfg.mlp_multipliers == tuple(config["mlp_multipliers"]) and not cfg.is_moe
+    assert (cfg.key_multiplier, cfg.attention_out_multiplier, cfg.lm_head_multiplier) == (
+        0.011048543456039804, 0.0375, 0.0078125)
+    shapes = fam.weight_shapes(config)
+    assert shapes["mamba"]["w_in"] == (9, 5120, 9248) and shapes["mamba"]["conv_w"] == (9, 4, 5120)
+    assert shapes["attn"]["wq"] == (9, 5120, 2560) and shapes["attn"]["wk"] == (9, 5120, 512)
+    assert shapes["layers"]["w_gate"] == (9, 5120, 21504) and shapes["lm_head"] == (5120, 32640)
+    sizes = jax.tree.leaves(shapes, is_leaf=lambda x: isinstance(x, tuple))
+    small = 9 * (2 * 5120 + 5120 + 3 * 32 + 4096) + 5120  # norms, conv bias, per-head vectors
+    assert sum(int(np.prod(s)) for s in sizes) - small == 4_205_137_920
+    assert fam.lane_multipliers(config).shape == (9248,)
+
+    with open(os.path.join(BENCH, "configs", "rehearse-falcon-h1-tiny.json")) as f:
+        tiny = json.load(f)
+    w = jax.jit(lambda k: fam.make_weights(tiny, k, jnp.float32))(family_mod.seed_key(3))
+    for name in fam.FLOAT32_LEAVES:
+        assert all(g[name].dtype == jnp.float32 for g in w.values() if isinstance(g, dict) and name in g)
+    for leaf in jax.tree.leaves(w):  # nothing at a value that lets a path skip it
+        assert float(jnp.std(leaf.astype(jnp.float32))) > 0.0
+    std = lambda a: float(jnp.std(a))
+    E, F, d_in = 64, 96, 256
+    assert abs(std(w["attn"]["wo"]) * np.sqrt(96) * tiny["attention_out_multiplier"] / fam.ATTN_OUT_GAIN - 1) < 0.1
+    assert abs(std(w["mamba"]["w_out"]) * np.sqrt(d_in) * tiny["ssm_out_multiplier"] / fam.MAMBA_OUT_GAIN - 1) < 0.1
+    assert abs(std(w["layers"]["w_gate"]) * np.sqrt(E) * tiny["mlp_multipliers"][0] - 1) < 0.1
+    assert abs(std(w["layers"]["w_down"]) * np.sqrt(F) * tiny["mlp_multipliers"][1] / fam.MLP_OUT_GAIN - 1) < 0.1
+    assert abs(std(w["lm_head"]) * np.sqrt(E) * tiny["lm_head_multiplier"] - 1) < 0.1
+    assert abs(std(w["embed"]) * tiny["embedding_multiplier"] - 1) < 0.1
+    # each part of w_in's lanes at the inverse of ITS multiplier (z | x | B | C | dt)
+    parts = np.cumsum([0, d_in, d_in, 64, 64, 16])
+    for i, mult in enumerate(tiny["ssm_multipliers"]):
+        lanes = w["mamba"]["w_in"][..., parts[i]:parts[i + 1]]
+        assert abs(std(lanes) * np.sqrt(E) * mult * tiny["ssm_in_multiplier"] - 1) < 0.15, i
+    # a score's std is SCORE_STD under unit-RMS inputs
+    qk = std(w["attn"]["wq"]) * std(w["attn"]["wk"]) * E
+    assert abs(qk * tiny["key_multiplier"] * tiny["attention_in_multiplier"] ** 2 / fam.SCORE_STD - 1) < 0.1

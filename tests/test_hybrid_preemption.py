@@ -7,7 +7,8 @@ paged-cache family and for a family with a state slot BESIDE its K/V
 blocks (`solar-tiny`: a preempted sequence gives back its slot and its
 blocks, and recomputes its state when it resumes) and for a family with a
 SECOND paged pool (`mimo-tiny`: a preempted sequence gives back the blocks
-of both)."""
+of both) and for a family whose EVERY layer holds a state slot and K/V
+blocks (`falcon-h1-tiny`)."""
 
 import numpy as np
 import pytest
@@ -18,7 +19,7 @@ from xllm_service_tpu.runtime.engine import EngineRequest, InferenceEngine
 from xllm_service_tpu.runtime.executor import ModelExecutor
 
 
-MODELS = ["llama3-tiny", "solar-tiny", "mimo-tiny"]
+MODELS = ["llama3-tiny", "solar-tiny", "mimo-tiny", "falcon-h1-tiny"]
 
 
 def _engine(model, R=4, num_blocks=64):

@@ -444,7 +444,10 @@ def test_every_state_layer_kind_is_one_row_of_one_table():
     from xllm_service_tpu.models import configs
 
     assert tuple(granite.STATE_KINDS) == configs.STATE_LAYER_KINDS == tuple(configs._STATE_MIXER_PARAMS)
-    assert set(granite.MIXER_STACKS) == set(granite.STATE_KINDS) | {"attention", "window"} == set(granite.MIXER_REGIONS)
+    assert set(granite.MIXER_STACKS) == set(granite.STATE_KINDS) | {"attention", "window"}
+    # a layer kind is its mixers (configs.LAYER_MIXERS): the four of one, and the parallel kind of two
+    assert set(granite.MIXER_REGIONS) == set(configs.LAYER_MIXERS) == set(granite.MIXER_STACKS) | {"parallel"}
+    assert all(set(mx) <= set(granite.MIXER_STACKS) for mx in configs.LAYER_MIXERS.values())
     assert all(len(row) == 4 and all(callable(f) for f in row) for row in granite.STATE_KINDS.values())
     for name in ("solar-tiny", "granite-tiny"):
         c = get_model_config(name)
@@ -483,8 +486,13 @@ def test_a_kda_stack_is_low_rank_pairs_and_nothing_else():
     (dict(tp_size=2), "tp_size/ep_size/sp_size/dp_size"),
 ], ids=["speculation", "prefix-tiers", "int8-cache", "checkpoint", "sharded-state"])
 def test_named_refusals_at_build(kw, match):
-    with pytest.raises(StateFamilyUnsupported, match=match):
-        _engine(**kw)
+    from xllm_service_tpu.ops import attention
+
+    try:
+        with pytest.raises(StateFamilyUnsupported, match=match):
+            _engine(**kw)
+    finally:  # a build at tp > 1 declares its mesh for this thread before it refuses
+        attention.set_shard_context(None)
 
 
 def test_named_refusals_at_the_request_and_an_inert_prefix_half():

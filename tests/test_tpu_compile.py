@@ -734,6 +734,25 @@ def _in_place_case(one_chip, case):
         pf = (s((1, 512)), s((1,)), s((1,)), s((1, 256)))
         fn = lambda p, k, v, *a: granite.mixed_step(p, cfg, k, v, *a)  # noqa: E731
         return fn, (params, *pools, *dec(64, 256), *pf), names
+    if case.startswith("falcon"):
+        from xllm_service_tpu.models import granite
+
+        cfg = dataclasses.replace(  # ONE scan of two parallel blocks
+            get_model_config("falcon-h1-34b"), num_layers=2, layer_types=("parallel",) * 2,
+            vocab_size=8192,
+        )
+        params = jax.eval_shape(lambda k: granite.init_params(cfg, k, jnp.bfloat16), jax.random.key(0))
+        params = jax.tree.map(lambda a: s(a.shape, a.dtype), params)
+        state, conv = granite.state_shapes(cfg, 64)
+        kv = s((2, 600, 4, BS, 128), jnp.bfloat16)
+        pools = ((kv, s(state, jnp.float32)), (kv, s(conv, jnp.float32)))
+        names = ("wq", "wk", "wv", "wo", "w_in", "w_out", "w_gate", "w_up", "w_down")
+        if case == "falcon-decode-64":
+            fn = lambda p, k, v, *a: granite.decode_step(p, cfg, k, v, *a)  # noqa: E731
+            return fn, (params, *pools, *dec(64, 32)), names
+        pf = (s((1, 256)), s((1,)), s((1,)), s((1, 33)))
+        fn = lambda p, k, v, *a: granite.mixed_step(p, cfg, k, v, *a)  # noqa: E731
+        return fn, (params, *pools, *dec(64, 32), *pf), names
     from xllm_service_tpu.models import deepseek
 
     cfg = dataclasses.replace(  # 1 dense layer beside the scan of 2
@@ -755,7 +774,7 @@ def _in_place_case(one_chip, case):
 
 @pytest.mark.parametrize("case", ["brumby-decode-24", "deepseek-decode-3", "deepseek-mixed-576",
                                   "solar-decode-96", "solar-mixed-608", "mimo-decode-64",
-                                  "mimo-mixed-576"])
+                                  "mimo-mixed-576", "falcon-decode-64", "falcon-mixed-320"])
 def test_step_reads_every_weight_leaf_where_it_lies(one_chip, no_persistent_cache, as_on_tpu, case):
     """The brumby decode step at reason-batch's 24 rows and the deepseek
     decode (3 rows) and mixed (64 + 512 rows) steps of doc-steady, at the
@@ -771,7 +790,12 @@ def test_step_reads_every_weight_leaf_where_it_lies(one_chip, no_persistent_cach
     rows) steps at mimo-v2-flash's widths, every table 128 blocks wide:
     `wq`/`wk`/`wv`/`wo` of both kinds of attention layer (key heads of 192
     lanes, value heads of 128), the dense first layer and the experts,
-    with all four attention launches of the cell in the program."""
+    with all four attention launches of the cell in the program. The
+    parallel family's decode (64 rows) and mixed (64 + 256 rows) steps at
+    falcon-h1-34b's widths: both mixers' matrices and the dense MLP's of a
+    block, with the update kernel (two B/C groups, state 256), the paged
+    decode, flash-prefill and write kernels (a query group of 5) all in
+    the ONE layer body."""
     fn, args, names = _in_place_case(one_chip, case)
     text = jax.jit(fn, donate_argnums=(1, 2)).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text  # the kernels' branch, as on the chip
@@ -789,6 +813,12 @@ def test_step_reads_every_weight_leaf_where_it_lies(one_chip, no_persistent_cach
         for name in launches:
             assert f'"{name}"' in text or f"{name}" in text, name
         assert ("window_flash_prefill_kernel" in text) == (case == "mimo-mixed-576")
+    elif case.startswith("falcon"):
+        moved = _weight_leaves_moved(text, args[0], names, ("layers", "mamba", "attn"), dtype="bf16")
+        launches = ["mamba_update_kernel", "paged_attention_kernel", "kv_write_kernel"]
+        launches += ["flash_prefill_kernel"] * (case == "falcon-mixed-320")
+        for name in launches:
+            assert name in text, name
     else:
         moved = _weight_leaves_moved(text, args[0], names)
     assert not moved, "\n".join(moved)

@@ -13,6 +13,16 @@ from typing import Dict, Optional
 
 # Layer kinds of a hybrid stack whose sequence memory is a state slot.
 STATE_LAYER_KINDS = ("mamba", "kda")
+# A block with BOTH mixers: a Mamba-2 mixer and a GQA mixer over the same
+# normed input, their outputs summed into one residual add (Falcon-H1).
+PARALLEL_KIND = "parallel"
+# The mixers a layer of each kind runs: what memory a layer holds (a state
+# slot, rows of the paged pool, rows of the window pool), how many layers
+# each pool has and what the mixers weigh all follow from this table.
+LAYER_MIXERS = {
+    "mamba": ("mamba",), "kda": ("kda",), "attention": ("attention",),
+    "window": ("window",), PARALLEL_KIND: ("mamba", "attention"),
+}
 
 
 @dataclass(frozen=True)
@@ -122,7 +132,10 @@ class ModelConfig:
     # state pool ("mamba": a Mamba-2 state-space mixer, ops/mamba.py;
     # "kda": a gated delta rule with a decay per channel, ops/kda.py), or
     # "attention" (GQA over the paged K/V pool, which then holds the
-    # attention layers alone). A stack has one state-layer kind at most.
+    # attention layers alone), or "parallel": a block that runs a Mamba-2
+    # mixer AND a GQA mixer on one normed input and adds both to the
+    # residual stream at once, so it holds a state slot and K/V rows
+    # (LAYER_MIXERS). A stack has one state-layer kind at most.
     # () = every layer attends. The pattern is data of the configuration.
     layer_types: tuple = ()
     mamba_d_state: int = 0
@@ -168,6 +181,23 @@ class ModelConfig:
     attention_multiplier: float = 0.0
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
+    # Falcon-H1's muP multipliers (HF FalconH1Config), each 1 by default
+    # (an empty tuple: every entry 1): the logits are multiplied by
+    # `lm_head_multiplier`; an attention mixer's input by
+    # `attention_in_multiplier`, its keys by `key_multiplier`, its output
+    # by `attention_out_multiplier`; a Mamba-2 mixer's input by
+    # `ssm_in_multiplier`, the z, x, B, C and dt lanes of its input
+    # projection's result by `ssm_multipliers[0..4]`, its output by
+    # `ssm_out_multiplier`; a dense MLP's gate pre-activation by
+    # `mlp_multipliers[0]` and its down product by `mlp_multipliers[1]`.
+    lm_head_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    ssm_multipliers: tuple = ()
+    mlp_multipliers: tuple = ()
 
     @property
     def is_retention(self) -> bool:
@@ -177,10 +207,14 @@ class ModelConfig:
     def is_hybrid(self) -> bool:
         return bool(self.layer_types)
 
+    def mixer_layers(self, mixer: str) -> int:
+        """Layers of the stack that run `mixer` (alone or beside another)."""
+        return sum(mixer in LAYER_MIXERS.get(k, ()) for k in self.layer_types)
+
     @property
     def state_layer_kind(self) -> str:
-        """The stack's layer kind with a state slot ("" = none)."""
-        kinds = [k for k in STATE_LAYER_KINDS if k in self.layer_types]
+        """The stack's state mixer, whose layers hold a state slot ("" = none)."""
+        kinds = [k for k in STATE_LAYER_KINDS if self.mixer_layers(k)]
         if len(kinds) > 1:
             raise ValueError(f"layer_types holds two state-layer kinds: {kinds}")
         return kinds[0] if kinds else ""
@@ -188,7 +222,7 @@ class ModelConfig:
     @property
     def num_state_layers(self) -> int:
         """Layers whose sequence memory is a slot of the state pool."""
-        return sum(self.layer_types.count(k) for k in STATE_LAYER_KINDS)
+        return sum(self.mixer_layers(k) for k in STATE_LAYER_KINDS)
 
     @property
     def has_state_pool(self) -> bool:
@@ -199,7 +233,7 @@ class ModelConfig:
     def has_paged_cache(self) -> bool:
         """A sequence owns blocks of a paged pool that grow with it."""
         return not self.is_retention and (
-            not self.layer_types or "attention" in self.layer_types
+            not self.layer_types or self.mixer_layers("attention") > 0
         )
 
     @property
@@ -218,7 +252,7 @@ class ModelConfig:
     def num_attention_layers(self) -> int:
         """Layers that hold rows of the paged pool."""
         if self.layer_types:
-            return self.layer_types.count("attention")
+            return self.mixer_layers("attention")
         return 0 if self.is_retention else self.num_layers
 
     @property
@@ -1057,6 +1091,85 @@ register(
         attn_v_head_dim=128,
         rotary_dim=64,
         attn_value_scale=0.707,
+        max_position_embeddings=262144,
+    )
+)
+
+register(
+    # Falcon-H1's block at a test's size (tests/test_falcon_h1.py): EVERY
+    # layer the parallel kind (a Mamba-2 mixer and a GQA mixer on one
+    # normed input), two B/C groups, a state wider than a head (32 > 16),
+    # a query group of 3 (not a power of two), full rotary, a dense MLP,
+    # an untied head, every multiplier different from 1 and from the
+    # others.
+    ModelConfig(
+        name="falcon-h1-tiny",
+        vocab_size=512,
+        hidden_size=64,
+        intermediate_size=96,
+        num_layers=3,
+        num_heads=6,
+        num_kv_heads=2,
+        head_dim=16,
+        rope_theta=1e11,
+        rms_norm_eps=1e-5,
+        layer_types=("parallel",) * 3,
+        rotary_dim=16,
+        mamba_d_state=32,
+        mamba_d_conv=4,
+        mamba_n_heads=16,
+        mamba_d_head=16,
+        mamba_n_groups=2,
+        embedding_multiplier=5.5,
+        lm_head_multiplier=0.3,
+        attention_in_multiplier=1.3,
+        attention_out_multiplier=0.45,
+        key_multiplier=0.6,
+        ssm_in_multiplier=1.6,
+        ssm_out_multiplier=0.35,
+        ssm_multipliers=(0.7, 0.9, 0.5, 1.2, 0.8),
+        mlp_multipliers=(1.4, 0.55),
+        max_position_embeddings=4096,
+    )
+)
+
+register(
+    # https://huggingface.co/tiiuae/Falcon-H1-34B-Instruct/blob/main/config.json
+    # (model_type falcon_h1), as ONE CHIP'S SHARE of it at every published
+    # width: 9 of the 72 blocks (one pipeline stage of eight), every one a
+    # GQA mixer (20 / 4 heads of 128, full rotary, theta 1e11) in parallel
+    # with a Mamba-2 mixer (32 heads of 128 lanes, 2 groups, state 256)
+    # and a dense SwiGLU of 21,504, vocabulary rows 0-32,639 of 261,120
+    # (benchmarks/configs/falcon-h1-34b.json has the deployment). Random
+    # weights only: runtime/weights.py has no loader.
+    ModelConfig(
+        name="falcon-h1-34b",
+        vocab_size=32640,
+        hidden_size=5120,
+        intermediate_size=21504,
+        num_layers=9,
+        num_heads=20,
+        num_kv_heads=4,
+        head_dim=128,
+        rope_theta=1e11,
+        rms_norm_eps=1e-5,
+        layer_types=("parallel",) * 9,
+        rotary_dim=128,
+        mamba_d_state=256,
+        mamba_d_conv=4,
+        mamba_n_heads=32,
+        mamba_d_head=128,
+        mamba_n_groups=2,
+        embedding_multiplier=5.656854249492381,
+        lm_head_multiplier=0.0078125,
+        attention_in_multiplier=1.0,
+        attention_out_multiplier=0.0375,
+        key_multiplier=0.011048543456039804,
+        ssm_in_multiplier=0.25,
+        ssm_out_multiplier=0.08838834764831845,
+        ssm_multipliers=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+                         0.3535533905932738),
+        mlp_multipliers=(0.1767766952966369, 0.011160714285714284),
         max_position_embeddings=262144,
     )
 )

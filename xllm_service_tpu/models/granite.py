@@ -1,14 +1,18 @@
-"""The hybrid stack: state layers, GQA layers and window layers in ONE
-stack, a top-k expert block (beside a shared MLP where the family has
-one) in every layer behind a dense prefix, over the same runtime as the
-other families. Three published families run on it, and the difference
-between them is DATA of the configuration (`layer_types`, the multipliers,
-the head): Granite 4.0-H (HF `model_type: granitemoehybrid`: Mamba-2 state
-layers, a tied head, four multipliers), Solar-Open2 (`solar_open2`: KDA
-state layers, a gate on the GQA layers, an untied head, every multiplier
-1) and MiMo-V2-Flash (`mimo_v2_flash`: window layers beside full GQA
-layers, rotary on part of a head, key heads wider than value heads, a
-dense first layer, sigmoid-scored experts and no shared one).
+"""The hybrid stack: state layers, GQA layers, window layers and blocks
+with BOTH a state mixer and a GQA mixer in ONE stack, a top-k expert
+block (beside a shared MLP where the family has one) in every layer
+behind a dense prefix, or a dense MLP in every layer (`num_experts` 0),
+over the same runtime as the other families. Four published families run
+on it, and the difference between them is DATA of the configuration
+(`layer_types`, the multipliers, the head): Granite 4.0-H (HF
+`model_type: granitemoehybrid`: Mamba-2 state layers, a tied head, four
+multipliers), Solar-Open2 (`solar_open2`: KDA state layers, a gate on
+the GQA layers, an untied head, every multiplier 1), MiMo-V2-Flash
+(`mimo_v2_flash`: window layers beside full GQA layers, rotary on part
+of a head, key heads wider than value heads, a dense first layer,
+sigmoid-scored experts and no shared one) and Falcon-H1 (`falcon_h1`:
+every block the parallel kind, full rotary, two B/C groups, a dense MLP,
+muP multipliers inside the projections).
 
 With `h` the residual stream, `rms` RMSNorm with a learned gain, and the
 four multipliers of the configuration (each 1 by default):
@@ -46,11 +50,31 @@ four multipliers of the configuration (each 1 by default):
   (j > i - window) and, with `window_sink`, a learned logit a query head
   in the softmax's denominator whose mass is dropped, over a paged pool
   of its own.
+* "parallel" (Falcon-H1): BOTH of the above on the same normed rows `u`,
+  each behind its input multiplier and scaled by its output multiplier,
+  in ONE residual add:
+  `h <- h + attention_out_multiplier * Attn(attention_in_multiplier * u)
+  + ssm_out_multiplier * Mamba(ssm_in_multiplier * u)`; inside the Mamba
+  mixer the z, x, B, C and dt lanes of `W_in`'s result are scaled by
+  `ssm_multipliers[0..4]`, inside attention k by `key_multiplier`. The
+  layer writes a state slot, its convolution rows AND its K/V rows: all
+  four carried pools move in one scan body. Where the multipliers land in
+  the program (the reference keeps them unfolded): `ssm_in_multiplier`
+  and `ssm_multipliers` are ONE `[d_in + conv + H]` vector on the float32
+  result of `W_in` (`_ssm_lane_scales`; the weights stay as installed);
+  `attention_in_multiplier` and `key_multiplier` are in the score scale
+  (`_scale`: the product is linear in q and k, so the cache holds the
+  plain `W_k u` and rounds as every family's does) and, for v, in the
+  branch's scalar beside `attention_out_multiplier` (`_branch_scales`).
 * experts: `llama.moe_route` (softmax or sigmoid scores, top-k,
   renormalised) and the grouped product over the experts HELD
   (`cfg.experts_held`), the shared MLP where `n_shared_experts` > 0
   (`llama._mlp_block`); the first `first_k_dense_replace` layers have a
-  dense SwiGLU of `intermediate_size` in the block's place.
+  dense SwiGLU of `intermediate_size` in the block's place; with
+  `num_experts` 0 every layer has that dense SwiGLU (its leaves under
+  `layers`), the gate's pre-activation times `mlp_multipliers[0]`
+  (llama._mlp) and the down product times `mlp_multipliers[1]` (in the
+  residual add).
 
 **A sequence's two kinds of memory.** The carried caches are a pair of
 pairs, `k_caches = (K, S)` and `v_caches = (V, conv)`: K and V stacks
@@ -99,9 +123,10 @@ from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from xllm_service_tpu.models import llama
-from xllm_service_tpu.models.configs import ModelConfig
+from xllm_service_tpu.models.configs import LAYER_MIXERS, PARALLEL_KIND, ModelConfig
 from xllm_service_tpu.obs.spans import region
 from xllm_service_tpu.ops import kda as kda_ops
 from xllm_service_tpu.ops import kv_cache as kvc
@@ -124,9 +149,10 @@ NUM_CACHES = 2  # K and V (each paired with a state pool on the carry)
 QUANTIZABLE_WEIGHT_LEAVES = llama.QUANTIZABLE_WEIGHT_LEAVES + ("w_in", "w_out", "w_ogate")
 # a layer kind's stack of the parameter tree
 MIXER_STACKS = {"mamba": "mamba", "kda": "kda", "attention": "attn", "window": "attn_w"}
-# the device region of a mixer's residual add (obs.spans.DEVICE_REGIONS)
+# the device region of a layer kind's residual add (obs.spans.DEVICE_REGIONS);
+# the parallel kind's ONE add of both branches is the state mixer's
 MIXER_REGIONS = {"mamba": "state_mixer", "kda": "state_mixer", "attention": "attn_proj",
-                 "window": "attn_proj"}
+                 "window": "attn_proj", PARALLEL_KIND: "state_mixer"}
 L2_EPS = 1e-6  # under the root of KDA's q and k normalisation
 
 
@@ -210,7 +236,7 @@ class Segment(NamedTuple):
 def _segments(cfg: ModelConfig) -> List[Segment]:
     """Runs of equal layer kind; the dense prefix ends a run."""
     out: List[Segment] = []
-    seen = dict.fromkeys(MIXER_STACKS, 0)
+    seen = dict.fromkeys(LAYER_MIXERS, 0)
     for l, kind in enumerate(cfg.layer_types):
         if kind not in seen:
             raise ValueError(f"layer_types[{l}] = {kind!r}: one of {sorted(seen)}")
@@ -240,10 +266,13 @@ def _period(segs: List[Segment]) -> Tuple[List[Segment], int]:
 def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
     if len(cfg.layer_types) != cfg.num_layers:
         raise ValueError("hybrid stack: one layer type a layer")
-    if not cfg.is_moe:
-        raise ValueError("hybrid stack: every layer behind the dense prefix routes")
+    if not cfg.is_moe and cfg.first_k_dense_replace:
+        raise ValueError("hybrid stack: a dense prefix stands before ROUTED layers")
     if cfg.num_window_layers and cfg.state_layer_kind:
         raise ValueError("hybrid stack: window layers' pools ride in the state pools' places")
+    if PARALLEL_KIND in cfg.layer_types and set(cfg.layer_types) != {PARALLEL_KIND}:
+        raise ValueError("hybrid stack: the parallel kind's two mixer stacks are indexed "
+                         "by the layer, so every layer of the stack is of that kind")
     E, L, kd = cfg.hidden_size, cfg.num_layers, cfg.first_k_dense_replace
     Ls, La, Lw = cfg.num_state_layers, cfg.num_attention_layers, cfg.num_window_layers
     Hq, Hkv, D, Dv = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim, cfg.value_head_dim
@@ -262,13 +291,19 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
     params = {
         "embed": w((cfg.vocab_size, E), E),
         "final_norm": ones((E,)),
-        "layers": {
-            "attn_norm": ones((L, E)), "mlp_norm": ones((L, E)),
+        "layers": {"attn_norm": ones((L, E)), "mlp_norm": ones((L, E))},
+    }
+    if cfg.is_moe:
+        params["layers"].update({
             "router": w((Lm, E, X), E),
             "w_gate": w((Lm, Xh, E, Fm), E), "w_up": w((Lm, Xh, E, Fm), E),
             "w_down": w((Lm, Xh, Fm, E), Fm),
-        },
-    }
+        })
+    else:  # a dense SwiGLU in every layer
+        F = cfg.intermediate_size
+        params["layers"].update({
+            "w_gate": w((L, E, F), E), "w_up": w((L, E, F), E), "w_down": w((L, F, E), F),
+        })
     if Fs:
         params["layers"].update({
             "w_sh_gate": w((Lm, E, Fs), E), "w_sh_up": w((Lm, E, Fs), E),
@@ -316,16 +351,65 @@ def _embed(params: Params, cfg: ModelConfig, token_ids) -> jnp.ndarray:
 
 @region("head")
 def _unembed(params: Params, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
-    return llama._unembed(params, cfg, x) / cfg.logits_scaling
+    return _head_scaled(cfg, llama._unembed(params, cfg, x))
+
+
+def _head_scaled(cfg: ModelConfig, logits):
+    logits = logits / cfg.logits_scaling
+    return logits if cfg.lm_head_multiplier == 1.0 else logits * cfg.lm_head_multiplier
 
 
 def _scale(cfg: ModelConfig) -> float:
-    return cfg.attention_multiplier or cfg.head_dim ** -0.5
+    """The score scale: with muP multipliers q is `W_q (m_in u)` and k
+    `key_multiplier W_k (m_in u)`, and the product is linear in both."""
+    return (cfg.attention_multiplier or cfg.head_dim ** -0.5) * (
+        cfg.key_multiplier * cfg.attention_in_multiplier ** 2
+    )
 
 
-def _add(cfg: ModelConfig, x, y):
-    """x + residual_multiplier * y, the product in float32."""
-    return x + (y.astype(jnp.float32) * cfg.residual_multiplier).astype(x.dtype)
+def _add(cfg: ModelConfig, x, y, scale: float = 1.0):
+    """x + residual_multiplier * scale * y, the product in float32."""
+    return x + (y.astype(jnp.float32) * (cfg.residual_multiplier * scale)).astype(x.dtype)
+
+
+def _ffn_scale(cfg: ModelConfig) -> float:
+    """`mlp_multipliers[1]`, on the dense MLP's down product (1 without)."""
+    return cfg.mlp_multipliers[1] if cfg.mlp_multipliers else 1.0
+
+
+def _branch_scales(cfg: ModelConfig) -> Tuple[float, float]:
+    """What the parallel kind's (attention, state) branches are scaled by
+    on the way into the residual add: each output multiplier, and the
+    attention branch's input multiplier for its VALUES (q's and k's share
+    is in `_scale`; the state branch's is in `_ssm_lane_scales`)."""
+    return (cfg.attention_out_multiplier * cfg.attention_in_multiplier,
+            cfg.ssm_out_multiplier)
+
+
+def _ssm_lane_scales(cfg: ModelConfig):
+    """The `[d_in + conv + H]` vector on `W_in`'s float32 result:
+    `ssm_in_multiplier` (the product is linear in its input) times
+    `ssm_multipliers[0..4]` on the z, x, B, C and dt lanes. None where
+    every one is 1: the product is then left as it is."""
+    if cfg.ssm_in_multiplier == 1.0 and not cfg.ssm_multipliers:
+        return None
+    d_in, gn = cfg.mamba_d_inner, cfg.mamba_n_groups * cfg.mamba_d_state
+    mup = np.repeat(
+        np.asarray(cfg.ssm_multipliers or (1.0,) * 5, np.float64),
+        (d_in, d_in, gn, gn, cfg.mamba_n_heads),
+    )
+    return jnp.asarray(mup * cfg.ssm_in_multiplier, jnp.float32)
+
+
+def _ssm_in(lp, cfg: ModelConfig, h):
+    """`[z | xBC | dt]` of normed rows h [T, E], float32: `W_in`'s product,
+    its lanes scaled (`_ssm_lane_scales`)."""
+    d_in, Cd = cfg.mamba_d_inner, cfg.mamba_conv_dim
+    zxd = jnp.einsum("te,ef->tf", h, wt(lp["w_in"]), preferred_element_type=jnp.float32)
+    mup = _ssm_lane_scales(cfg)
+    if mup is not None:
+        zxd = zxd * mup
+    return zxd[:, :d_in], zxd[:, d_in:d_in + Cd], zxd[:, d_in + Cd:]
 
 
 # ------------------------------------------------------------ the halves
@@ -381,8 +465,7 @@ def _mamba_mixer(lp, cfg: ModelConfig, h, m, S, conv, dec: Optional[_Dec],
     H, Pd, N, G = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state, cfg.mamba_n_groups
     d_in, Cd = cfg.mamba_d_inner, cfg.mamba_conv_dim
     f32 = jnp.float32
-    zxd = jnp.einsum("te,ef->tf", h, wt(lp["w_in"]), preferred_element_type=f32)
-    z, xbc, dt = zxd[:, :d_in], zxd[:, d_in:d_in + Cd], zxd[:, d_in + Cd:]
+    z, xbc, dt = _ssm_in(lp, cfg, h)
     dt = jax.nn.softplus(dt + lp["dt_bias"])
     A = -jnp.exp(lp["A_log"].astype(f32))
     R = dec.R if dec is not None else 0
@@ -686,7 +769,7 @@ def _layer(lp, cfg: ModelConfig, x, valid, kind, mix, caches, dense=False):
     u = block_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
     with region("ffn"):
         ffn = llama._mlp_block(lp, _dense_cfg(cfg) if dense else cfg, u, rows_valid=valid)
-        return _add(cfg, x, ffn), caches
+        return _add(cfg, x, ffn, _ffn_scale(cfg)), caches
 
 
 def _run_layers(params, cfg: ModelConfig, x, k_caches, v_caches, valid,
@@ -699,7 +782,16 @@ def _run_layers(params, cfg: ModelConfig, x, k_caches, v_caches, valid,
             # the second of each pair: the state kind's pools, or the
             # window layers' K and V stacks
             (K, S), (V, conv) = caches
-            if kind == "attention":
+            if kind == PARALLEL_KIND:
+                # both mixers on the same normed rows, each branch scaled
+                # by its multipliers, summed in float32 for ONE residual
+                # add; all four pools move in this one body
+                ca, cs = _branch_scales(cfg)
+                a, K, V = _attn_mixer(lp, cfg, h, i, K, V, dec, pf)
+                s, S, conv = _mamba_mixer(lp, cfg, h, i, S, conv, dec, pf)
+                with region(MIXER_REGIONS[kind]):
+                    y = a.astype(jnp.float32) * ca + s.astype(jnp.float32) * cs
+            elif kind == "attention":
                 y, K, V = _attn_mixer(lp, cfg, h, i, K, V, dec, pf)
             elif kind == "window":
                 y, S, conv = _attn_mixer(lp, cfg, h, i, S, conv, dec, pf, kind)
@@ -740,7 +832,9 @@ def _run_layers(params, cfg: ModelConfig, x, k_caches, v_caches, valid,
     def run_segment(carry, seg: Segment, first, kind_first):
         """One scan over a run's layers: `first` the run's first layer of
         all layers, `kind_first` of the layers of its kind."""
-        stack = params[MIXER_STACKS[seg.kind]]
+        # the kind's mixer stacks: one, or the parallel kind's two (their
+        # leaves' names are disjoint)
+        stack = {k: v for mx in LAYER_MIXERS[seg.kind] for k, v in params[MIXER_STACKS[mx]].items()}
 
         def body(carry, i):
             x, kc, vc = carry
@@ -758,7 +852,7 @@ def _run_layers(params, cfg: ModelConfig, x, k_caches, v_caches, valid,
             return jax.lax.scan(body, carry, jnp.arange(seg.n, dtype=jnp.int32))
 
     period, reps = _period(_segments(cfg))
-    of_kind = {kind: sum(s.n for s in period if s.kind == kind) for kind in MIXER_STACKS}
+    of_kind = {kind: sum(s.n for s in period if s.kind == kind) for kind in LAYER_MIXERS}
     layers = sum(of_kind.values())
 
     def run_period(carry, p):  # p: which repeat of the period (0 where none)
@@ -910,13 +1004,12 @@ def hidden_dense(params: Params, cfg: ModelConfig, token_ids, rows_valid=None):
     B, L = token_ids.shape
     f32 = jnp.float32
     H, Pd, N, G = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state, cfg.mamba_n_groups
-    d_in, Cd = cfg.mamba_d_inner, cfg.mamba_conv_dim
+    d_in = cfg.mamba_d_inner
     causal = jnp.arange(L)[None, :] <= jnp.arange(L)[:, None]
     x = _embed(params, cfg, token_ids)
 
     def mamba(lp, h):  # [L, E]
-        zxd = jnp.einsum("te,ef->tf", h, wt(lp["w_in"]), preferred_element_type=f32)
-        z, xbc, dt = zxd[:, :d_in], zxd[:, d_in:d_in + Cd], zxd[:, d_in + Cd:]
+        z, xbc, dt = _ssm_in(lp, cfg, h)
         c = mamba_ops.conv_dense(xbc, lp["conv_w"], lp["conv_b"])
         y = mamba_ops.chunk_form(
             c[:, :d_in].reshape(L, H, Pd), jax.nn.softplus(dt + lp["dt_bias"]),
@@ -950,8 +1043,12 @@ def hidden_dense(params: Params, cfg: ModelConfig, token_ids, rows_valid=None):
         o = jnp.einsum("hgqk,khd->qhgd", p, v).reshape(L, -1)
         return jnp.einsum("th,he->te", _gated(lp, cfg, h, o).astype(h.dtype), wt(lp["wo"]))
 
+    def parallel(lp, h):  # both branches, scaled, summed in float32
+        ca, cs = _branch_scales(cfg)
+        return attention(lp, h).astype(f32) * ca + mamba(lp, h).astype(f32) * cs
+
     mixers = {"mamba": mamba, "kda": kda, "attention": attention,
-              "window": lambda lp, h: attention(lp, h, "window")}
+              "window": lambda lp, h: attention(lp, h, "window"), PARALLEL_KIND: parallel}
     li = dict.fromkeys(mixers, 0)
     kd = cfg.first_k_dense_replace
     norms = ("attn_norm", "mlp_norm")
@@ -963,17 +1060,16 @@ def hidden_dense(params: Params, cfg: ModelConfig, token_ids, rows_valid=None):
         else:
             lp.update({k: v[l - kd] for k, v in params["layers"].items() if k not in norms})
             mcfg = cfg
-        lp.update({k: v[li[kind]] for k, v in params[MIXER_STACKS[kind]].items()})
+        for mx in LAYER_MIXERS[kind]:
+            lp.update({k: v[li[kind]] for k, v in params[MIXER_STACKS[mx]].items()})
         li[kind] += 1
         mix = mixers[kind]
         h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
         x = _add(cfg, x, jax.vmap(lambda hx: mix(lp, hx))(h))
         u = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-        x = _add(cfg, x, jax.vmap(lambda ux: llama._mlp(lp, mcfg, ux))(u))
+        x = _add(cfg, x, jax.vmap(lambda ux: llama._mlp(lp, mcfg, ux))(u), _ffn_scale(cfg))
     return rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
 
 
 def forward_dense(params: Params, cfg: ModelConfig, token_ids) -> jnp.ndarray:
-    return llama._project(
-        params, cfg, hidden_dense(params, cfg, token_ids)
-    ) / cfg.logits_scaling
+    return _head_scaled(cfg, llama._project(params, cfg, hidden_dense(params, cfg, token_ids)))

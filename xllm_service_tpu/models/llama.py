@@ -209,6 +209,8 @@ def _mlp(
         gate = gate + d if d is not None else gate
         d = lora_ops.maybe_apply(lp, "w_up", x, lora_idx, 1.0)
         up = up + d if d is not None else up
+        if cfg.mlp_multipliers:  # muP (Falcon-H1): the gate's pre-activation
+            gate = (gate.astype(jnp.float32) * cfg.mlp_multipliers[0]).astype(gate.dtype)
         h = _act(cfg)(gate) * up
         out = _row_parallel("tf,fe->te", h, wt(lp["w_down"]))
         d = lora_ops.maybe_apply(lp, "w_down", h, lora_idx, 1.0)

@@ -104,7 +104,7 @@ DEVICE_REGIONS = (
     "attn_proj",    # q/k/v/o, biases, RoPE, QK-norm; MLA's down/up-projections, the absorb
     "attn",         # the paged, flash and MLA attention kernels and their plain twins
     "cache_write",  # the write plan, kv_write_kernel, latent rows
-    "state_mixer",  # power retention; Mamba-2's convolution, scan, update, gated output
+    "state_mixer",  # power retention; Mamba-2's convolution, scan, update, gated output; a parallel block's ONE residual add of both branches (its attention branch is attn_proj / cache_write / attn)
     "ffn",          # dense gate/up/down, the shared experts
     "moe_route",    # router, top-k, grouping, the counts output
     "moe_experts",  # the grouped expert kernels, their twins, the combine

@@ -40,7 +40,11 @@ prefill chunk names its slot.
 Two routes, one result: on the chip the decode update is the Pallas kernel
 `mamba_update_kernel`, in place on the stack the layer scan carries;
 elsewhere the `jax.numpy` route below (the CPU, tests, other shapes). The
-chunk form is `jax.numpy` on both.
+chunk form is `jax.numpy` on both. The kernel's layout rule
+(`kernel_eligible`): whole (8, 128) float32 tiles (lanes a multiple of
+128, N of 8) and no lane row with heads of two B/C groups ((H / G) % k
+== 0), with 8 lane rows or more a group (or one group); any number of
+groups.
 """
 
 from __future__ import annotations
@@ -51,7 +55,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from xllm_service_tpu.ops.pallas.mamba import mamba_update_kernel
+from xllm_service_tpu.ops.pallas.mamba import head_tile, mamba_update_kernel
 
 _HI = jax.lax.Precision.HIGHEST
 
@@ -87,12 +91,27 @@ def from_pool(s: jnp.ndarray, k: int) -> jnp.ndarray:
     return s.reshape(*lead, HP * k, lanes // k, N)
 
 
+def kernel_shape_ok(S, groups: int) -> bool:
+    """What `mamba_update_kernel` needs of a pool `S [.., H/k, N, k*P]`
+    with `groups` groups of B and C: whole (8, 128) float32 tiles, lane
+    rows that divide over the groups (a lane row of k heads then reads
+    ONE group's B and C), and a head tile inside a group that Mosaic
+    takes (`head_tile`: 8 lane rows or more a group, or one group and
+    all of its rows)."""
+    HP, N, lanes = S.shape[-3:]
+    return (lanes % 128 == 0 and N % 8 == 0 and HP % groups == 0
+            and head_tile(HP // groups, N * lanes * 4, groups) > 0)
+
+
 def kernel_eligible(S, groups: int, requested: Optional[bool] = None) -> bool:
+    """The decode update of pool `S` takes the kernel: on the chip, at a
+    shape the kernel takes (`kernel_shape_ok`); `requested` (tests, the
+    controls) overrides both."""
     if requested is not None:
         return requested
     from xllm_service_tpu.ops.attention import _on_tpu
 
-    return _on_tpu() and groups == 1 and S.shape[-1] % 128 == 0 and S.shape[-2] % 8 == 0
+    return _on_tpu() and kernel_shape_ok(S, groups)
 
 
 # ------------------------------------------------------------ convolution
@@ -189,12 +208,15 @@ def decode_update(
     if kernel_eligible(S, G, use_kernel):
         n_live, unit_rows = _units(active)
         lanes = S.shape[-1]
+        if G == 1:  # one plane a row
+            along = lambda t: jnp.broadcast_to(t[:, 0, :, None], (R, N, lanes))
+        else:  # a plane a group; a head tile's block spec picks its own
+            along = lambda t: jnp.broadcast_to(t[..., None], (R, G, N, lanes))
         S, y = mamba_update_kernel(
             S, layer, unit_rows, n_live,
             jnp.repeat(a, P, axis=-1).reshape(R, H // k, lanes),
             dtx.reshape(R, H // k, lanes),
-            jnp.broadcast_to(B[:, 0, :, None], (R, N, lanes)),
-            jnp.broadcast_to(C[:, 0, :, None], (R, N, lanes)),
+            along(B), along(C),
             interpret=interpret,
         )
         y = y.reshape(R, H, P)

@@ -185,10 +185,11 @@ def _hybrid_param_shardings(cfg: ModelConfig, ns) -> Dict[str, Any]:
         "final_norm": ns(None),
         "layers": {
             **rep(("attn_norm", "mlp_norm"), 2),
-            **rep(("router",), 3),
+            **rep(("router",) if cfg.is_moe else (), 3),
             **rep(("w_sh_gate", "w_sh_up", "w_sh_down") if cfg.n_shared_experts else (), 3),
             **rep(("router_bias",) if cfg.topk_method == "noaux_tc" else (), 2),
-            **rep(("w_gate", "w_up", "w_down"), 4),
+            # the held experts' matrices, or a dense MLP in every layer
+            **rep(("w_gate", "w_up", "w_down"), 4 if cfg.is_moe else 3),
         },
         "attn": rep(("wq", "wk", "wv", "wo") + (("w_ogate",) if cfg.attn_gate else ()), 3),
     }

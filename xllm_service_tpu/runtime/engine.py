@@ -44,6 +44,7 @@ from xllm_service_tpu.common.types import (
     StatusCode,
     Usage,
 )
+from xllm_service_tpu.models.configs import PARALLEL_KIND
 from xllm_service_tpu.obs import (
     BATCH_BUCKETS,
     ENGINE_PHASES,
@@ -743,9 +744,15 @@ class InferenceEngine:
             lambda: getattr(self.executor, "state_slot_bytes", 0)
             if self.state_family else 0
         )
-        # The two paged pools of a window family (0 and absent labels for
-        # every other family: its one pool is xllm_engine_kv_cache_usage's).
-        if self.window_family:
+        # The paged pools by name: the two of a window family, and the one
+        # of a family whose EVERY layer holds K/V rows beside a state slot
+        # (the parallel kind: its blocks and its slots are both a layer's),
+        # as pool "full" (absent for every other family: its one pool is
+        # xllm_engine_kv_cache_usage's).
+        both_in_a_layer = PARALLEL_KIND in getattr(
+            getattr(self.executor, "cfg", None), "layer_types", ()
+        )
+        if self.window_family or both_in_a_layer:
             live = self.metrics.gauge(
                 "xllm_engine_kv_blocks_live",
                 "Blocks of a paged pool owned by a sequence, by pool: the "
@@ -756,9 +763,10 @@ class InferenceEngine:
             live.labels(pool="full").set_function(
                 lambda: self.block_mgr.num_referenced_blocks
             )
-            live.labels(pool="window").set_function(
-                lambda: self.block_mgr.window_blocks_live
-            )
+            if self.window_family:
+                live.labels(pool="window").set_function(
+                    lambda: self.block_mgr.window_blocks_live
+                )
             size = self.metrics.gauge(
                 "xllm_engine_kv_block_bytes",
                 "Device bytes of one block of a paged pool over its layers, by pool",
@@ -767,9 +775,10 @@ class InferenceEngine:
             size.labels(pool="full").set_function(
                 lambda: self.executor.cache_row_bytes * self.block_size
             )
-            size.labels(pool="window").set_function(
-                lambda: self.executor._window_block_bytes()
-            )
+            if self.window_family:
+                size.labels(pool="window").set_function(
+                    lambda: self.executor._window_block_bytes()
+                )
         self.metrics.counter(
             "xllm_engine_window_blocks_freed_total",
             "Window-pool blocks freed behind a running sequence (every "

@@ -170,6 +170,39 @@ def _hf_sliding_window(hf: dict) -> int:
     )
 
 
+def falcon_h1_name_map(cfg: ModelConfig) -> Dict[str, str]:
+    """Which tensor of an HF `falcon_h1` checkpoint each leaf of the hybrid
+    stack's tree (models/granite.py, every layer the parallel kind) would
+    load from, `{i}` the block: what WOULD load, because no loader is
+    built (`checkpoint_path` is refused by name for a state-pool family,
+    runtime/executor.py; the presets run on random weights) and the names
+    are the released modelling code's as known offline, unconfirmed
+    against a checkpoint. A loader would also transpose every projection
+    (HF keeps [out, in]), lay `conv1d.weight` [lanes, 1, K] out as
+    [K, lanes], and read `in_proj`'s rows in the order z | x | B | C | dt;
+    the muP multipliers are the configuration's and touch no tensor."""
+    if "parallel" not in cfg.layer_types:
+        raise ValueError(f"{cfg.name}: not a stack of parallel blocks")
+    block = "model.layers.{i}."
+    return {
+        "embed": "model.embed_tokens.weight",
+        "final_norm": "model.final_layernorm.weight",
+        "lm_head": "lm_head.weight",
+        "layers.attn_norm": block + "input_layernorm.weight",
+        "layers.mlp_norm": block + "pre_ff_layernorm.weight",
+        **{f"layers.w_{k}": block + f"feed_forward.{k}_proj.weight" for k in ("gate", "up", "down")},
+        **{f"attn.w{k}": block + f"self_attn.{k}_proj.weight" for k in "qkvo"},
+        "mamba.w_in": block + "mamba.in_proj.weight",
+        "mamba.conv_w": block + "mamba.conv1d.weight",
+        "mamba.conv_b": block + "mamba.conv1d.bias",
+        "mamba.dt_bias": block + "mamba.dt_bias",
+        "mamba.A_log": block + "mamba.A_log",
+        "mamba.D": block + "mamba.D",
+        "mamba.gate_norm": block + "mamba.norm.weight",
+        "mamba.w_out": block + "mamba.out_proj.weight",
+    }
+
+
 def _hf_rope_scaling(hf: dict) -> dict:
     """ModelConfig rope_scaling_* fields from an HF config dict.
 
