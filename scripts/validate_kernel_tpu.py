@@ -671,6 +671,43 @@ def run_mamba_chunk_case(Lc, L, H, P, N, slots=8, G=1):
     return err * 1e-2
 
 
+def run_kda_gram_case(n, H, d, C=64):
+    """The decayed gram of one prefill chunk of a KDA layer (ops/kda.py
+    `_decayed_gram`: M[k] and M[q] of n chunks of C tokens, H heads of d
+    lanes, as `_chunk_scan` hands them over) with its diagonal sub-blocks
+    through `kda_gram_kernel` against the two-fusion `jax.numpy` form: ms a
+    call of each, the launch's own device time, and the bytes each form
+    moves for the diagonal (the pair tensor `[n, H, C/16, 16, 16, d]`
+    written and read again | the kernel's operands and its diagonals)."""
+    from xllm_service_tpu.ops import kda as ko
+
+    ks = jax.random.split(jax.random.key(5), 4)
+    shape = (n, 1, H, C, d)
+    l2 = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    k, q = l2(jax.random.normal(ks[0], shape)), l2(jax.random.normal(ks[1], shape)) * d ** -0.5
+    # per-token decays of 0.9-0.999 a channel, a head's own scale on top
+    g = -jnp.exp(jax.random.uniform(ks[2], shape, jnp.float32, np.log(1e-3), np.log(1e-1)))
+    G = jnp.cumsum(g * jnp.exp(jax.random.normal(ks[3], (n, 1, H, 1, 1))), axis=-2)
+    forms = {
+        use: jax.jit(lambda k, q, G, use=use: ko._decayed_gram(jnp.stack([k, q]), k, G, use))
+        for use in (True, False)
+    }
+    err = float(jnp.abs(forms[True](k, q, G) - forms[False](k, q, G)).max())
+    tk, tx = (bench(lambda f=forms[use]: f(k, q, G), iters=16) for use in (True, False))
+    own, launches = device_us(lambda: forms[True](k, q, G), "kda_gram_kernel")
+    tokens = n * C * H
+    pair = tokens * ko.BLOCK * d * 4  # [.., 16, 16, d] a token
+    xla_bytes = 2 * pair + (4 * d + 2 * ko.BLOCK) * tokens * 4
+    kernel_bytes = (4 * d + 2 * ko.BLOCK) * tokens * 4  # G, k, k, q in; two diagonals out
+    print(
+        f"KDA-GRAM n={n} H={H} d={d} C={C} err={err:.2e} "
+        f"kernel_form={tk*1e6:8.1f}us (launch {own:7.1f}us x{launches // 4}, {kernel_bytes/1e6:6.1f} MB "
+        f"{100*kernel_bytes/own/819e3:4.1f}% of 819 GB/s) two_fusion_form={tx*1e6:8.1f}us "
+        f"({xla_bytes/1e6:6.1f} MB for the diagonal)"
+    )
+    return err * 1e-2  # float32 sums of 128 terms in another order
+
+
 # llama-8B-class: Hq=32 Hkv=8 D=128; llama-70B-class: Hq=64 Hkv=8 D=128.
 # NOTE: D=64 decode is NOT included — Mosaic rejects the lane-padded HBM
 # block slice below one 128-lane tile (tpu.memref_slice verify failure
@@ -730,6 +767,11 @@ CASES = [
      dict(S=64, live=32, L=9, N=800, Hc=4, D=128, BS=128)),
     ("prefill-group5", run_prefill_case,
      dict(P=1, Lpad=256, Hq=20, Hkv=4, D=128, BS=128, MB=24)),
+    # solar-open2-250b.think-steady's chunk (PERF.md, PR 54): the decayed
+    # gram of one 512-token prefill chunk of a KDA layer, 64 heads of 128
+    # lanes in 8 chunks of 64: its diagonal sub-blocks as `kda_gram_kernel`
+    # against the two XLA fusions with the 268 MB pair tensor between them.
+    ("kda-gram-think", run_kda_gram_case, dict(n=8, H=64, d=128)),
     # int8 KV cache (scale DMA + column folding) at production block size
     ("dec-int8-a", run_case,
      dict(R=64, Hq=32, Hkv=8, D=128, BS=128, MB=16, ctx=2048, int8=True)),

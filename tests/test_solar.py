@@ -84,18 +84,20 @@ def _pad(a, n):
     return jnp.pad(a, ((0, n - a.shape[0]),) + ((0, 0),) * (a.ndim - 1), constant_values=1.0)
 
 
+@pytest.mark.parametrize("gram", [True, False], ids=["gram-kernel", "gram-xla"])
 @pytest.mark.parametrize("regime", sorted(REGIMES))
 @pytest.mark.parametrize("n_prefill,use_kernel", [(64, False), (57, True), (83, False)],
                          ids=["whole-chunks-xla", "ragged-tail-kernel", "three-chunks-xla"])
-def test_chunk_form_equals_the_recurrence_through_a_dirty_pool(regime, n_prefill, use_kernel):
+def test_chunk_form_equals_the_recurrence_through_a_dirty_pool(regime, n_prefill, use_kernel, gram):
     """Chunked prefill (a padding row beside the live one, a short last
     chunk, a first chunk that starts at 0 on a never-cleaned slot) then
     decode row by row: output and final state equal the token-by-token
-    recurrence, with decays near 1 and near 0 and beta near 2."""
+    recurrence, with decays near 1 and near 0 and beta near 2, whichever
+    route the gram's diagonal takes (`kda_gram_kernel` | `jax.numpy`)."""
     T, chunk = n_prefill + 6, 32
     x = _rule_inputs(T, regime)
     o_ref, S_ref = kda.recurrent_form(*x)
-    o_all, S_all = kda.chunk_form(*x, chunk=16)
+    o_all, S_all = kda.chunk_form(*x, chunk=16, use_kernel=gram, interpret=True)
     scale = float(jnp.abs(o_ref).max())
     np.testing.assert_allclose(o_all, o_ref, atol=2e-5 * max(scale, 1.0))
     np.testing.assert_allclose(S_all, S_ref, atol=3e-5)
@@ -105,7 +107,7 @@ def test_chunk_form_equals_the_recurrence_through_a_dirty_pool(regime, n_prefill
         n = min(chunk, n_prefill - start)
         rows = [jnp.stack([_pad(a[start:start + n], chunk)] * 2) for a in x]
         o, S = kda.chunk_update(S, layer, jnp.array([slot, -1]), jnp.array([start, 0]),
-                                jnp.array([n, 0]), *rows, chunk=16)
+                                jnp.array([n, 0]), *rows, chunk=16, use_kernel=gram, interpret=True)
         os_.append(o[0, :n])
     for t in range(n_prefill, T):  # decode: the slot is the ROW
         act = jnp.arange(4) == slot
@@ -132,6 +134,34 @@ def test_update_kernel_equals_the_xla_route(live):
     dead = jnp.logical_not(act)
     assert float(jnp.abs((S1 - S)[2][dead]).max(initial=0.0)) == 0.0
     assert float(jnp.abs((S1 - S)[:2]).max()) == 0.0
+
+
+@pytest.mark.parametrize("case", ["one-block", "chunk-64", "wide-lanes", "underflow"])
+def test_gram_kernel_equals_the_xla_route(case):
+    """Both grams (M[k], M[q]) through `kda_gram_kernel` equal the
+    `jax.numpy` route's: the diagonal sub-blocks alone (one block of 16),
+    whole (four sub-blocks: the off-diagonal products around the kernel's
+    diagonal), at the benchmark's 128 lanes, and where every decay of a
+    chunk underflows: 0, the limit, never NaN. Nothing above the diagonal."""
+    C, d, glo = {"one-block": (16, D, -0.05), "chunk-64": (64, D, -0.05),
+                 "wide-lanes": (32, 128, -0.05), "underflow": (64, D, -400.0)}[case]
+    ks = jax.random.split(jax.random.key(11), 3)
+    shape = (3, 2, H, C, d)  # chunks, rows, heads: as `_chunk_scan` hands them over
+    k, q = (jax.random.normal(key, shape) for key in ks[:2])
+    G = jnp.cumsum(glo * jax.random.uniform(ks[2], shape, minval=0.5), axis=-2)
+    x = jnp.stack([k, q])
+    want = kda._decayed_gram(x, k, G)
+    got = kda._decayed_gram(x, k, G, use_kernel=True, interpret=True)
+    assert got.shape == want.shape == (2, *shape[:-1], C)
+    assert bool(jnp.isfinite(got).all())
+    np.testing.assert_allclose(got, want, atol=2e-5 * float(jnp.abs(want).max()))
+    assert float(jnp.abs(jnp.triu(got, 1)).max()) == 0.0
+    if case == "underflow":  # a token sees itself and nothing else
+        eye = jnp.einsum("...id,...id->...i", x, k[None])
+        np.testing.assert_allclose(jnp.diagonal(got, axis1=-2, axis2=-1), eye, rtol=1e-5, atol=1e-5)
+        assert float(jnp.abs(jnp.tril(got, -1)).max()) == 0.0
+    one = kda._decayed_gram(q, k, G, use_kernel=True, interpret=True)  # one row set, no leading axis
+    np.testing.assert_allclose(one, want[1], atol=2e-5 * float(jnp.abs(want).max()))
 
 
 @pytest.mark.parametrize("n", [16, 64], ids=["forward-substitution", "joined-halves"])

@@ -876,6 +876,39 @@ def test_solar_tiny_steps_compile_and_neither_pool_is_laid_out_again(one_chip, n
                 moved.append(line.strip()[:160])
     assert not moved, "\n".join(moved)
     assert ("kda_update_kernel" in text) == (step == "mixed")
+    # the gram's pair tensor never reaches HBM: where a chunk is, its
+    # diagonal sub-blocks are the kernel's
+    assert ("kda_gram_kernel" in text) == (step != "decode")
+    assert not _pair_tensors(text)
+
+
+def _pair_tensors(text):
+    """Instructions of a compiled text with a float32 result shaped
+    [..., 16, 16, 128]: the decayed gram's pair terms (sub-block row,
+    sub-block column, key lane), in any leading shape."""
+    import re
+
+    return [line.strip()[:160] for line in text.splitlines()
+            if re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \(?f32\[(?:\d+,)*16,16,128\]", line)]
+
+
+def test_the_chunk_form_at_think_steady_holds_no_pair_tensor(one_chip, no_persistent_cache):
+    """`_chunk_scan` alone at the cell's shape (one row of 512 tokens, 64
+    heads of 128 lanes, chunks of 64): with the gram's diagonal as
+    `kda_gram_kernel` no `f32[8,64,4,16,16,128]` (268 MB a layer and
+    chunk) is in the program; the `jax.numpy` route still has it, so the
+    search finds what it looks for."""
+    from xllm_service_tpu.ops import kda
+
+    def s(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    rows = s(1, 512, 64, 128)
+    args = (rows, rows, rows, rows, s(1, 512, 64), s(1, 64, 128, 128))
+    text = _compile(lambda *a: kda._chunk_scan(*a, 64, use_kernel=True), *args)
+    assert "kda_gram_kernel" in text and not _pair_tensors(text)
+    text = _compile(lambda *a: kda._chunk_scan(*a, 64), *args)
+    assert "kda_gram_kernel" not in text and _pair_tensors(text)
 
 
 # ---- PR 51: one context bucket wherever attention runs as the kernels

@@ -219,7 +219,10 @@ def state_shapes(cfg: ModelConfig, slots: int):
 
 def state_route(cfg: ModelConfig, state) -> str:
     """Which route the decode update of `state` (the state pool) takes:
-    "<kind>-pallas" (the kind's update kernel) or "<kind>-xla"."""
+    "<kind>-pallas" (the kind's update kernel) or "<kind>-xla". For
+    "kda" the same predicate routes the chunk form's decayed gram, so
+    "kda-pallas" says the update AND the gram's diagonal are the kernels
+    (`kda_update_kernel`, `kda_gram_kernel`) and "kda-xla" that neither is."""
     kind = cfg.state_layer_kind
     on_kernel = STATE_KINDS[kind].on_kernel(cfg, state)
     return f"{kind}-{'pallas' if on_kernel else 'xla'}"
@@ -577,6 +580,7 @@ def _kda_mixer(lp, cfg: ModelConfig, h, m, S, conv, dec: Optional[_Dec],
         o, S = kda_ops.chunk_update(
             S, m, pf.slots, pf.start, pf.length, *_kda_heads(cfg, c),
             g[R:].reshape(pf.P, pf.Lpad, H, d), beta[R:].reshape(pf.P, pf.Lpad, H),
+            use_kernel=dec.use_kernel if dec is not None else None,
         )
         os.append(o.reshape(pf.P * pf.Lpad, H, d))
     o = jnp.concatenate(os, axis=0) if len(os) > 1 else os[0]

@@ -41,21 +41,27 @@ ignores what the slot held, so a freed slot needs no cleaning.
 convolution pool is ops/mamba.py's, `[Lk, slots, (K-1) * 3 H d]`. A
 DECODE row's slot is its row index; a prefill chunk names its slot.
 
-Two routes, one result: on the chip the decode update is the Pallas kernel
-`kda_update_kernel`, in place on the stack the layer scan carries;
-elsewhere the `jax.numpy` route below. The chunk form is `jax.numpy` on
-both.
+Two routes, one result, chosen by ONE predicate (`kernel_eligible`: on
+the chip, whole (8, 128) tiles of state): there the decode update is the
+Pallas kernel `kda_update_kernel`, in place on the stack the layer scan
+carries, and the DIAGONAL sub-blocks of the chunk form's decayed gram are
+`kda_gram_kernel`, which keeps the per-pair decays `[16, 16, d]` of a
+sub-block in VMEM where XLA writes them to HBM (268 MB a layer and chunk
+at 64 heads of 128); elsewhere the `jax.numpy` route below. The rest of
+the chunk form (the off-diagonal blocks, the inverse, the scan over the
+chunks) is `jax.numpy` on both.
 """
 
 from __future__ import annotations
 
+import math
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from xllm_service_tpu.ops.mamba import _units
-from xllm_service_tpu.ops.pallas.kda import head_tile, kda_update_kernel
+from xllm_service_tpu.ops.pallas.kda import head_tile, kda_gram_kernel, kda_update_kernel
 
 _HI = jax.lax.Precision.HIGHEST
 CHUNK = 64  # tokens of one chunk of the chunk form (the published kernels')
@@ -123,21 +129,42 @@ def decode_update(
 # --------------------------------------------------------- the chunk form
 
 
-def _decayed_gram(x, k, G):
+def _gram_diagonal_kernel(x, k, G, interpret: bool):
+    """`_decayed_gram`'s diagonal sub-blocks [..., n, BLOCK, BLOCK] through
+    `kda_gram_kernel`: tokens flattened ahead of the last leading
+    dimension (the heads: [P, L, H, d] as the projections leave it when
+    the leading ones are the chunks), each row set its own operand."""
+    B, sets = BLOCK, x.shape[:x.ndim - k.ndim]
+    *outer, H, C, d = k.shape if k.ndim > 2 else (1, *k.shape)
+    rows = lambda t: jnp.moveaxis(t.reshape(-1, H, C, d), 1, 2).reshape(-1, H, d)
+    xs = x.reshape(-1, *k.shape)
+    diag = kda_gram_kernel(
+        rows(G), rows(k), [rows(xs[s]) for s in range(xs.shape[0])], block=B, interpret=interpret,
+    )  # [S, H, outer x C / B, B, B]
+    diag = jnp.moveaxis(diag.reshape(-1, H, math.prod(outer), C // B, B, B), 1, 2)
+    return diag.reshape(*sets, *k.shape[:-2], C // B, B, B)
+
+
+def _decayed_gram(x, k, G, use_kernel: bool = False, interpret: bool = False):
     """M_ij = sum_c x_i[c] k_j[c] exp(G_i[c] - G_j[c]) for i >= j, 0
     above the diagonal. k, G [..., C, d], G falling along C; x the same
     or with more leading dimensions (several row sets against one k). No
-    exponent taken is positive (the module docstring says how)."""
+    exponent taken is positive (the module docstring says how). With
+    `use_kernel` the diagonal sub-blocks are `kda_gram_kernel`'s (whole
+    sub-blocks of BLOCK tokens only); the rest is the same text."""
     *lead, C, d = x.shape
     B = BLOCK if C % BLOCK == 0 else C
     n = C // B
     blocks = lambda t: t.reshape(*t.shape[:-2], n, B, d)
     xb, kb, Gb = blocks(x), blocks(k), blocks(G)
-    pos = jnp.arange(B)
-    tri = pos[:, None] >= pos[None, :]
-    diff = Gb[..., :, None, :] - Gb[..., None, :, :]  # [.., n, B(i), B(j), d]
-    decay = jnp.exp(jnp.where(tri[..., None], jnp.minimum(diff, 0.0), -jnp.inf))
-    diag = jnp.sum(xb[..., :, None, :] * (kb[..., None, :, :] * decay), axis=-1)
+    if use_kernel and B == BLOCK:
+        diag = _gram_diagonal_kernel(x, k, G, interpret)
+    else:
+        pos = jnp.arange(B)
+        tri = pos[:, None] >= pos[None, :]
+        diff = Gb[..., :, None, :] - Gb[..., None, :, :]  # [.., n, B(i), B(j), d]
+        decay = jnp.exp(jnp.where(tri[..., None], jnp.minimum(diff, 0.0), -jnp.inf))
+        diag = jnp.sum(xb[..., :, None, :] * (kb[..., None, :, :] * decay), axis=-1)
     rows = []
     for a in range(n):
         parts = []
@@ -189,7 +216,8 @@ def _unit_lower_inverse(A):
     return level[0]
 
 
-def _chunk_scan(q, k, v, g, beta, s0, chunk: int):
+def _chunk_scan(q, k, v, g, beta, s0, chunk: int, use_kernel: bool = False,
+                interpret: bool = False):
     """The chunk form over sequences of n chunks from carried states.
     q, k, v, g [P, L, H, d] f32 (L a multiple of `chunk`; a masked token
     has g = 0), beta [P, L, H] (a masked token's is 0), s0 [P, H, d, d].
@@ -203,7 +231,7 @@ def _chunk_scan(q, k, v, g, beta, s0, chunk: int):
 
     q, k, v, g, beta = (heads_first(t) for t in (q, k, v, g, beta))
     G = jnp.cumsum(g, axis=-2)  # [n, P, H, C, d]: falling, <= 0
-    Mk, Mq = _decayed_gram(jnp.stack([k, q]), k, G)  # both grams in one pass
+    Mk, Mq = _decayed_gram(jnp.stack([k, q]), k, G, use_kernel, interpret)  # both grams in one pass
     A = jnp.tril(beta[..., None] * Mk, -1)
     T = _unit_lower_inverse(A) * beta[..., None, :]
     U = jnp.einsum("...ij,...jd->...id", T, v, precision=_HI)
@@ -240,6 +268,7 @@ def _masked(q, k, v, g, beta, length, chunk: int):
 
 def chunk_update(
     S, layer, slots, start, length, q, k, v, g, beta, chunk: int = CHUNK,
+    use_kernel: Optional[bool] = None, interpret: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """One prefill chunk per row against the row's carried state. slots,
     start, length [P] int32 (length 0: a padding row, touches no slot;
@@ -254,7 +283,10 @@ def chunk_update(
     ]
     s0 = jnp.stack(olds).astype(jnp.float32)
     s0 = jnp.where((start > 0)[:, None, None, None], s0, 0.0)
-    o, sT = _chunk_scan(*_masked(q, k, v, g, beta, length, chunk), s0, chunk)
+    o, sT = _chunk_scan(
+        *_masked(q, k, v, g, beta, length, chunk), s0, chunk,
+        kernel_eligible(S, use_kernel), interpret,
+    )
     new = sT.astype(S.dtype)
     for p in range(Pn):
         row = jnp.where(length[p] > 0, new[p], olds[p])
@@ -262,14 +294,15 @@ def chunk_update(
     return o[:, :Lc], S
 
 
-def chunk_form(q, k, v, g, beta, chunk: int = CHUNK):
+def chunk_form(q, k, v, g, beta, chunk: int = CHUNK, use_kernel: bool = False,
+               interpret: bool = False):
     """A whole sequence in chunks from an empty state: q, k, v, g
     [T, H, d], beta [T, H] -> (o [T, H, d] f32, S_T [H, d, d])."""
     T, H, d = q.shape
     chunk = min(chunk, T)
     o, S = _chunk_scan(
         *_masked(*(t[None] for t in (q, k, v, g, beta)), jnp.full((1,), T, jnp.int32), chunk),
-        jnp.zeros((1, H, d, d), jnp.float32), chunk,
+        jnp.zeros((1, H, d, d), jnp.float32), chunk, use_kernel, interpret,
     )
     return o[0, :T], S[0]
 
