@@ -15,6 +15,7 @@ chosen subset.
 
 from __future__ import annotations
 
+import functools
 import sys
 import time
 
@@ -557,6 +558,96 @@ def run_mla_prefill_case(P, Lpad, Hq, kvr, dr, BS, MB, dtype=jnp.bfloat16,
     return err
 
 
+def run_mla_prefill_forms_case(Hq, kvr, dn, dr, dv, BS, Lpad, MB, contexts,
+                               **kernel_kw):
+    """The two forms of the MLA prefill launch as deepseek-v2.doc-steady's
+    mixed step calls it (PERF.md, PR 55): ONE chunk of `Lpad` rows, all
+    `Hq` heads, a table as wide as the one context bucket (`MB` blocks),
+    at each of `contexts` cached tokens before the chunk. ABSORBED:
+    `mla_prefill_kernel` over q_lat (the projection into the latent
+    space and W_UV after it are XLA's and not in `kernel=`);
+    MATERIALISED: `mla_materialised_prefill_kernel`. `kernel=` is the
+    op's own device time from a trace, `call=` the host's time a call;
+    the error is the largest |difference| of the heads' value-space
+    outputs against the blockwise scan in float32 (and its share of the
+    oracle's largest value)."""
+    from xllm_service_tpu.ops.attention import mla_prefill_blockwise
+    from xllm_service_tpu.ops.pallas.mla_prefill import (
+        mla_flash_prefill_kernel,
+        mla_materialised_prefill_kernel,
+    )
+
+    bf = jnp.bfloat16
+    C = (kvr + dr + 127) // 128 * 128
+    N = MB + 1
+    ks = jax.random.split(jax.random.key(0), 5)
+    # the heads as the projection writes them ([q_nope | rope part, not
+    # roped]: the materialised kernel reads them where they lie), and q_pe
+    q = jax.random.normal(ks[0], (1, Lpad, Hq, dn + dr), bf)
+    q_nope = q[..., :dn]
+    q_pe = jax.random.normal(ks[1], (1, Lpad, Hq, dr), bf)
+    w_uk = (jax.random.normal(ks[2], (Hq, kvr, dn)) / kvr**0.5).astype(bf)
+    w_uv = (jax.random.normal(ks[3], (Hq, kvr, dv)) / kvr**0.5).astype(bf)
+    cache = jax.random.normal(ks[4], (N, 1, BS, kvr + dr), bf)
+    cache = jnp.pad(cache, ((0, 0),) * 3 + ((0, C - kvr - dr),))
+    bt = jnp.asarray(1 + np.arange(MB)[None], jnp.int32)
+    tl = jnp.asarray([Lpad], jnp.int32)
+    scale = (dn + dr) ** -0.5
+
+    @jax.jit
+    def absorb(qn, qp, wk):
+        q_lat = jnp.einsum("plhd,hkd->plhk", qn, wk)
+        q_lat = jnp.concatenate([q_lat, qp.astype(q_lat.dtype)], -1)
+        return jnp.pad(q_lat, ((0, 0),) * 3 + ((0, C - kvr - dr),))
+
+    up_v = jax.jit(lambda ctx, wv: jnp.einsum("plhk,hkv->plhv", ctx, wv))
+    f32 = lambda x: x.astype(jnp.float32)
+
+    @functools.partial(jax.jit, static_argnames="nb")
+    def oracle(sp, nb):
+        ctx = mla_prefill_blockwise(
+            absorb(f32(q_nope), f32(q_pe), f32(w_uk))[0], f32(cache),
+            bt[0, :nb], sp[0], tl[0], scale, kvr,
+        )
+        return up_v(ctx[None], f32(w_uv))
+
+    q_lat = absorb(q_nope, q_pe, w_uk)
+    worst = 0.0
+    for ctx_len in contexts:
+        sp = jnp.asarray([ctx_len], jnp.int32)
+        ref = np.asarray(oracle(sp, -(-(ctx_len + Lpad) // BS)))
+        forms = {
+            "absorbed": (
+                "mla_prefill_kernel",
+                lambda: mla_flash_prefill_kernel(
+                    q_lat, cache, bt, sp, tl, scale, kvr
+                ),
+                lambda o: up_v(o, w_uv),
+            ),
+            "materialised": (
+                "mla_materialised_prefill_kernel",
+                lambda: mla_materialised_prefill_kernel(
+                    q, q_pe, w_uk, w_uv, cache, bt, sp, tl, scale,
+                    kvr, **kernel_kw
+                ),
+                lambda o: o,
+            ),
+        }
+        for form, (op, ker, finish) in forms.items():
+            err = float(np.max(np.abs(np.asarray(f32(finish(ker()))) - ref)))
+            us, n = device_us(ker, op)
+            print(
+                f"MLA-PREFILL-FORMS {form:12s} cached={ctx_len:5d} L={Lpad} "
+                f"Hq={Hq} MB={MB} err={err:.4f} "
+                f"({err / float(np.max(np.abs(ref))):.4f} of max) "
+                f"kernel={us:8.1f}us ({n} launches) "
+                f"call={bench(ker, iters=16)*1e6:8.1f}us",
+                flush=True,
+            )
+            worst = max(worst, err)
+    return worst
+
+
 # Ordered so the never-yet-chip-validated kernels come first (round 3
 # queue: int8 scale-DMA decode, MLA decode, flash prefill) — the bf16
 # decode cases at the tail were already chip-validated in round 2.
@@ -792,6 +883,12 @@ CASES = [
      dict(P=4, Lpad=512, Hq=32, Hkv=8, D=128, BS=128, MB=8, int8=True)),
     ("mla-prefill", run_mla_prefill_case,
      dict(P=2, Lpad=512, Hq=128, kvr=512, dr=64, BS=128, MB=8)),
+    # deepseek-v2.doc-steady's chunk (PERF.md, PR 55): one 512-row chunk
+    # of 128 heads under the cell's one context bucket (64 blocks), in
+    # the absorbed and in the materialised form, at five cached lengths.
+    ("mla-prefill-forms", run_mla_prefill_forms_case,
+     dict(Hq=128, kvr=512, dn=128, dr=64, dv=128, BS=128, Lpad=512, MB=64,
+          contexts=(0, 512, 1536, 3584, 7168))),
     # Multi-query decode (speculative verify) at production shapes
     ("mq-bf16", run_mq_case,
      dict(R=64, S=4, Hq=32, Hkv=8, D=128, BS=128, MB=16, ctx=2048)),

@@ -113,6 +113,52 @@ def test_the_table_is_whole_where_every_launch_is_a_kernel(monkeypatch, pools, e
     assert list(ex._decode_cb_walk()) == ([16] if whole else [1, 2, 4, 8, 16])
 
 
+@pytest.mark.parametrize(
+    "rows,pool,on_chip,prefill",
+    [
+        (512, {}, True, "mla-flash-mat"),
+        (256, {}, True, "mla-flash-mat"),
+        (128, {}, True, "mla-flash"),  # under the bound: absorbed
+        (attention.MQ_MAX_ROWS, {}, True, "mla-flash"),  # the verify shapes
+        (0, {}, True, "mla-flash"),  # asked about no launch in particular
+        (512, dict(int8=True, block=128), True, "blockwise"),
+        (512, {}, False, "blockwise"),  # off the chip: the scan, the oracle
+        (512, dict(latent=False), True, "flash"),  # a GQA pool has one form
+    ],
+    ids=["512", "256", "128", "verify", "unasked", "int8", "cpu", "gqa"],
+)
+def test_a_latent_pools_prefill_form_goes_by_the_rows_of_a_chunk(
+    monkeypatch, rows, pool, on_chip, prefill
+):
+    """An MLA prefill launch has two forms of one algebra: the materialised
+    flash kernel from MLA_MATERIALISE_ROWS rows a chunk on, the absorbed
+    one below; an int8 latent pool keeps the blockwise scan. The report
+    names the form, and either kernel walks a chunk's context."""
+    monkeypatch.setattr(attention, "_on_tpu", lambda: on_chip)
+    pool = dict(pool)
+    latent = pool.pop("latent", True)
+    routes = attention.attention_routes(
+        _pool(**pool), 8, 128, latent=latent, prefill_rows=rows
+    )
+    assert routes.materialised is (prefill == "mla-flash-mat")
+    rep = routes.report()
+    assert rep["prefill"] == prefill
+    assert rep["mixed"] == f"{rep['decode']}+{prefill}"
+    # the verify shapes: the multi-query kernel where it serves (GQA), else
+    # what a chunk of a few rows takes, never the materialised form
+    assert rep["mq"] == ("mq" if routes.verify else prefill.replace("-mat", ""))
+    assert routes.bounded_by_context is (prefill != "blockwise")
+    # a caller's own switch forces the kernels: the rows still pick the form
+    forced = attention.attention_routes(
+        _pool(**pool), 8, 128, latent=latent, prefill_rows=rows, use_kernel=True
+    )
+    assert forced.materialised is (latent and "int8" not in pool and rows >= 256)
+    off = attention.attention_routes(
+        _pool(**pool), 8, 128, latent=latent, prefill_rows=rows, use_kernel=False
+    )
+    assert not off.materialised and off.report()["prefill"] == "blockwise"
+
+
 @pytest.mark.parametrize("decode", [True, False], ids=["paged", "gather"])
 def test_a_window_family_takes_the_whole_table_under_either(decode):
     ex = _stub([attention.Routes(False, decode, False, False, 1, False)], window=True)
@@ -265,6 +311,7 @@ def test_every_array_a_step_donates_is_placed_at_build(cpu_devices, model):
 GQA_KERNELS = {"decode": "paged_attention_kernel", "prefill": "flash_prefill_kernel",
                "verify": "multiquery_paged_attention_kernel"}
 MLA_KERNELS = {"decode": "mla_paged_attention_kernel", "prefill": "mla_prefill_kernel",
+               "prefill_mat": "mla_materialised_prefill_kernel",
                "verify": "mla_multiquery_attention_kernel"}
 ATTENTION_KERNEL = re.compile(r"\b((?:window_)?(?:%s))\b" % "|".join(
     sorted(set(GQA_KERNELS.values()) | set(MLA_KERNELS.values()))))
@@ -300,15 +347,19 @@ def _traced_step_programs(monkeypatch):
 def _launch_names(routes, prefix, spec):
     """The attention kernels a pool's launches run as, by the decision."""
     names = MLA_KERNELS if routes.latent else GQA_KERNELS
-    kinds = [k for k in ("decode", "prefill") if getattr(routes, k)]
+    kinds = ["decode"] * routes.decode
+    if routes.prefill:  # a latent pool's chunk: the form its rows picked
+        kinds.append("prefill_mat" if routes.materialised else "prefill")
     if spec and routes.verify:
         kinds.append("verify")
+    elif spec and routes.prefill:  # verify shapes go as a few-row (absorbed) prefill
+        kinds.append("prefill")
     return {prefix + names[k] for k in kinds}
 
 
 @pytest.mark.parametrize(
-    "model,on_chip,spec,widen",
-    [
+    "model,on_chip,spec,widen,engine",
+    [(*case, {}) for case in [
         ("llama3-shard-tiny", False, 0, {}),
         ("llama3-shard-tiny", True, 0, {}),
         ("llama3-tiny", True, 0, {}),
@@ -324,10 +375,16 @@ def _launch_names(routes, prefix, spec):
         # ... and a window family (a sink a window layer) with K/V rows wide
         # enough for the kernels
         ("mimo-tiny", True, 0, dict(head_dim=128, attn_v_head_dim=128)),
+    ]] + [
+        # a latent pool's chunk of 256 rows: the materialised form, on the
+        # chip alone; the verify shapes beside it stay absorbed
+        ("deepseek-tiny", on_chip, spec, {},
+         dict(max_seq_len=256, max_prefill_tokens=256, prefill_buckets=[256]))
+        for on_chip, spec in [(False, 0), (True, 0), (True, 3)]
     ],
 )
 def test_the_report_is_the_route_each_dispatcher_takes_when_traced(
-    cpu_devices, monkeypatch, model, on_chip, spec, widen
+    cpu_devices, monkeypatch, model, on_chip, spec, widen, engine
 ):
     """ONE decision: the attention kernels the executor's step programs
     launch when traced (every family prewarm_programs walks: decode,
@@ -340,7 +397,7 @@ def test_the_report_is_the_route_each_dispatcher_takes_when_traced(
 
     monkeypatch.setattr(attention, "_on_tpu", lambda: on_chip)
     seen = _traced_step_programs(monkeypatch)
-    cfg = _cfg(model, speculative_tokens=spec, max_seq_len=64)  # (a short grid to trace)
+    cfg = _cfg(model, **{"speculative_tokens": spec, "max_seq_len": 64, **engine})  # (a short grid to trace)
     model_cfg = dataclasses.replace(get_model_config(model), **widen) if widen else None
     ex = ModelExecutor(cfg, init_seed=0, model_cfg=model_cfg)
     try:
@@ -356,10 +413,12 @@ def test_the_report_is_the_route_each_dispatcher_takes_when_traced(
         if routes:  # the names the records carry say the same
             full = routes[0]
             assert (rep["decode"] in ("paged", "mla")) is full.decode
-            assert (rep["prefill"] in ("flash", "mla-flash")) is full.prefill
+            assert (rep["prefill"] in ("flash", "mla-flash", "mla-flash-mat")) is full.prefill
+            assert (rep["prefill"] == "mla-flash-mat") is full.materialised
+            assert full.materialised is (full.latent and on_chip and bool(engine))
             assert rep["mixed"] == f"{rep['decode']}+{rep['prefill']}"
             assert rep["mq"] == ("mla-mq" if full.latent else "mq") if full.verify \
-                else rep["mq"] == rep["prefill"]
+                else rep["mq"] == rep["prefill"].replace("-mat", "")
         if len(routes) > 1:
             w = routes[1]
             assert rep["window"] == f"window-{'pallas' if w.decode and w.prefill else 'xla'}"

@@ -524,6 +524,54 @@ def test_carried_stack_steps_match_dense(model):
     assert v.shape == (cfg.num_layers, 1, 1, 1, 1)  # the dummy, untouched
 
 
+@pytest.mark.parametrize("step", ["mixed", "prefill"])
+def test_the_materialised_route_gives_the_absorbed_routes_logits(monkeypatch, step):
+    """A 256-row chunk takes the materialised flash kernel where the
+    kernels run (here in interpret mode, through the `_interpret` seam)
+    and the absorbed one where the rule's bound is out of reach: the two
+    forms are one algebra, so mixed_step and prefill_batch_step give the
+    same logits either way, and forward_dense's."""
+    from xllm_service_tpu.ops import attention
+
+    cfg = get_model_config("deepseek-hetero-tiny")
+    params = deepseek.init_params(cfg, jax.random.key(3), jnp.float32)
+    rng = np.random.default_rng(9)
+    A, B = rng.integers(1, 500, 20).tolist(), rng.integers(1, 500, 200).tolist()
+    tab_a, tab_b = _i32([1, 2]), _i32([3 + i for i in range(16)])
+    monkeypatch.setattr(attention, "_interpret", lambda: True)
+
+    def run():
+        launched = set()
+        real = attention.mla_materialised_prefill_attention
+        monkeypatch.setattr(
+            deepseek, "mla_materialised_prefill_attention",
+            lambda *a, **kw: (launched.add("materialised"), real(*a, **kw))[1],
+        )
+        k, v = _pool(cfg, 24)
+        _, k, v = deepseek.prefill_batch_step(params, cfg, k, v, _chunk(A), _i32(0), _i32(20), tab_a)
+        if step == "prefill":  # B in two chunks: cached rows before the second
+            _, k, v = deepseek.prefill_batch_step(
+                params, cfg, k, v, _chunk(B[:128], 256), _i32(0), _i32(128), tab_b)
+            pf, k, v = deepseek.prefill_batch_step(
+                params, cfg, k, v, _chunk(B[128:], 256), _i32(128), _i32(72), tab_b)
+            return np.asarray(pf[0]), launched
+        tables = jnp.zeros((2, 2), jnp.int32).at[0].set(tab_a[0])
+        dec, pf, k, v = deepseek.mixed_step(
+            params, cfg, k, v, _i32(7, 0), _i32(20, 0), tables, jnp.asarray([True, False]),
+            _chunk(B, 256), _i32(0), _i32(200), tab_b,
+        )
+        return np.concatenate([np.asarray(dec[0]), np.asarray(pf[0])]), launched
+
+    mat, launched = run()
+    assert launched == {"materialised"}
+    monkeypatch.setattr(attention, "MLA_MATERIALISE_ROWS", 1 << 30)
+    absorbed, launched = run()
+    assert not launched
+    np.testing.assert_allclose(mat, absorbed, atol=2e-4)
+    dense = np.asarray(deepseek.forward_dense(params, cfg, jnp.asarray([B], jnp.int32))[0, -1])
+    np.testing.assert_allclose(mat[-dense.shape[0]:], dense, atol=2e-4)
+
+
 def test_mixed_step_moves_no_layer_of_the_pool():
     """The compiled mixed step holds less than ONE layer of the pool in
     temporaries with the stack donated: nothing scans the pool in or

@@ -403,6 +403,7 @@ def _compile_step(family: str, step: str, one_chip, R: int = R) -> str:
 KERNEL_REGIONS = {
     "paged_attention_kernel": "attn", "prefill_attention_kernel": "attn",
     "mla_paged_attention_kernel": "attn", "mla_prefill_kernel": "attn",
+    "mla_materialised_prefill_kernel": "attn",
     "kv_write_kernel": "cache_write",
     "retention_update_kernel": "state_mixer", "retention_chunk_kernel": "state_mixer",
     "mamba_update_kernel": "state_mixer", "mamba_chunk_kernel": "state_mixer",
@@ -415,8 +416,9 @@ EXPECTED_KERNELS = {
     ("brumby", "mixed"): {"retention_update_kernel", "retention_chunk_kernel"},
     ("deepseek", "decode"): {"mla_paged_attention_kernel", "kv_write_kernel", "moe_grouped_kernel",
                              "moe_grouped_down_kernel"},
-    ("deepseek", "mixed"): {"mla_paged_attention_kernel", "mla_prefill_kernel", "kv_write_kernel",
-                            "moe_grouped_kernel", "moe_grouped_down_kernel"},
+    # a 256-row chunk over a bf16 latent pool: the materialised form (PR 55)
+    ("deepseek", "mixed"): {"mla_paged_attention_kernel", "mla_materialised_prefill_kernel",
+                            "kv_write_kernel", "moe_grouped_kernel", "moe_grouped_down_kernel"},
     ("granite", "decode"): {"mamba_update_kernel", "paged_attention_kernel", "kv_write_kernel",
                             "moe_grouped_kernel", "moe_grouped_down_kernel"},
     ("granite", "mixed"): {"mamba_update_kernel", "paged_attention_kernel", "kv_write_kernel",
@@ -450,6 +452,8 @@ def test_every_compiled_op_of_a_step_program_has_a_region(
             kernels[name] = got.get(i.key)
     assert EXPECTED_KERNELS[family, step] <= set(kernels), sorted(kernels)
     assert kernels == {k: KERNEL_REGIONS[k] for k in kernels}
+    # a step program holds ONE of the two MLA prefill forms (`lpad` is static)
+    assert not {"mla_prefill_kernel", "mla_materialised_prefill_kernel"} <= set(kernels)
     # the weights' matmuls are where the floors of PERF.md hold them
     named = Counter(got.values())
     assert named["ffn"] and named["attn_proj"] and named["head"] and named["sample"]

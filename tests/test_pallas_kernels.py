@@ -775,6 +775,121 @@ def test_mla_flash_prefill_int8_matches_blockwise():
         )
 
 
+# ---------------------------- MLA flash prefill, the materialised form
+
+
+def make_mla_materialised_case(
+    rng, P=2, Lpad=32, Hq=8, dn=24, dr=16, dv=20, kvr=40, C=128, BS=16,
+    MB=8, num_blocks=64, layers=None,
+):
+    """Un-absorbed queries, a layer's W_UK / W_UV and a latent pool whose
+    rows are zero past kvr + dr, as the model writes them; `layers`: the
+    stacked pool."""
+    f32 = lambda a: jnp.asarray(a, jnp.float32)
+    # the heads as the projection writes them: [q_nope | rope part, not roped]
+    q = f32(rng.standard_normal((P, Lpad, Hq, dn + dr)))
+    q_pe = f32(rng.standard_normal((P, Lpad, Hq, dr)))
+    w_uk = f32(rng.standard_normal((Hq, kvr, dn)) / np.sqrt(kvr))
+    w_uv = f32(rng.standard_normal((Hq, kvr, dv)) / np.sqrt(kvr))
+    shape = (num_blocks, 1, BS, C)
+    cache = rng.standard_normal(shape if layers is None else (layers,) + shape)
+    cache[..., kvr + dr:] = 0.0
+    bt = np.stack([
+        rng.choice(np.arange(1, num_blocks), size=MB, replace=False)
+        for _ in range(P)
+    ]).astype(np.int32)
+    return q, q_pe, w_uk, w_uv, f32(cache), jnp.asarray(bt)
+
+
+def _mla_absorbed_ref(q, q_pe, w_uk, w_uv, cache, bt, start_pos,
+                      true_len, scale, kvr, layer=None):
+    """The oracle: the blockwise scan over the absorbed queries, W_UV
+    after it (the heads' value-space outputs)."""
+    q_nope = q[..., :w_uk.shape[-1]]
+    q_lat = jnp.concatenate(
+        [jnp.einsum("plhd,hkd->plhk", q_nope, w_uk), q_pe], axis=-1
+    )
+    q_lat = jnp.pad(
+        q_lat, ((0, 0),) * 3 + ((0, cache.shape[-1] - q_lat.shape[-1]),)
+    )
+    ctx = jax.vmap(
+        lambda qi, ti, sp, tl: mla_prefill_blockwise(
+            qi, cache, ti, sp, tl, scale, kvr, layer=layer
+        )
+    )(q_lat, bt, start_pos, true_len)
+    return jnp.einsum("plhk,hkv->plhv", ctx, w_uv)
+
+
+@pytest.mark.parametrize("head_group,tile_q", [(2, 16), (8, 32)])
+@pytest.mark.parametrize(
+    "starts,lens,layer",
+    [
+        ((0, 0), (32, 17), None),       # no cached token; a ragged chunk
+        ((21, 37), (32, 20), None),     # not a multiple of the block
+        ((32, 64), (32, 32), 2),        # whole blocks; the stacked pool
+        ((96, 96), (32, 9), None),      # the end of the largest bucket
+        ((16, 48), (32, 0), 1),         # a pad row (true_len 0), stacked
+    ],
+)
+def test_mla_materialised_prefill_matches_blockwise(
+    starts, lens, layer, head_group, tile_q
+):
+    """The materialised flash kernel (keys and values made from the
+    latent blocks in VMEM) against the blockwise scan over the absorbed
+    queries: the two forms are one algebra."""
+    from xllm_service_tpu.ops.pallas.mla_prefill import (
+        mla_materialised_prefill_kernel,
+    )
+
+    rng = np.random.default_rng(55)
+    kvr = 40
+    case = make_mla_materialised_case(
+        rng, kvr=kvr, layers=None if layer is None else 3
+    )
+    start_pos = jnp.asarray(starts, jnp.int32)
+    true_len = jnp.asarray(lens, jnp.int32)
+    ref = _mla_absorbed_ref(
+        *case, start_pos, true_len, 0.125, kvr, layer=layer
+    )
+    out = mla_materialised_prefill_kernel(
+        *case, start_pos, true_len, 0.125, kvr, interpret=True, chunk=2,
+        tile_q=tile_q, head_group=head_group, layer=layer,
+    )
+    assert out.shape == ref.shape
+    for p, tl in enumerate(lens):
+        np.testing.assert_allclose(
+            np.asarray(out)[p, :tl], np.asarray(ref)[p, :tl],
+            atol=3e-5, rtol=3e-5,
+        )
+        assert not np.asarray(out)[p, tl:].any()  # rows past true_len: zeros
+
+
+def test_mla_materialised_prefill_dispatcher_in_bf16():
+    """ops.attention's dispatcher of the form, over a bfloat16 pool as the
+    routes require: within the rounding of the keys and values it makes."""
+    from xllm_service_tpu.ops.attention import (
+        mla_materialised_prefill_attention,
+    )
+
+    rng = np.random.default_rng(56)
+    kvr = 40
+    case = make_mla_materialised_case(rng, kvr=kvr)
+    start_pos = jnp.asarray([8, 40], jnp.int32)
+    true_len = jnp.asarray([32, 25], jnp.int32)
+    ref = _mla_absorbed_ref(*case, start_pos, true_len, 0.125, kvr)
+    *ops, bt = case
+    out = mla_materialised_prefill_attention(
+        *(a.astype(jnp.bfloat16) for a in ops), bt, start_pos, true_len,
+        0.125, kvr, interpret=True,
+    )
+    assert out.dtype == jnp.bfloat16
+    for p, tl in enumerate([32, 25]):
+        np.testing.assert_allclose(
+            np.asarray(out.astype(jnp.float32))[p, :tl],
+            np.asarray(ref)[p, :tl], atol=6e-2, rtol=6e-2,
+        )
+
+
 # ------------------------------------------------ Mosaic layout rules
 
 

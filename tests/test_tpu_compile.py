@@ -14,6 +14,7 @@ back without a chip).
 
 import dataclasses
 import os
+import re
 import subprocess
 import sys
 
@@ -541,6 +542,30 @@ def test_mla_prefill_kernel_compiles_at_published_widths(one_chip, no_persistent
     assert "tpu_custom_call" in text and "mla_prefill_kernel" in text
 
 
+@pytest.mark.parametrize("rows", [256, 512])
+def test_mla_materialised_prefill_kernel_compiles_at_published_widths(
+    one_chip, no_persistent_cache, rows
+):
+    """The materialised form (PR 55) at DeepSeek-V2's widths: one chunk of
+    `rows` rows (the rule's bound and the cell's bucket), 128 heads whose
+    queries lie as the projection wrote them (192 lanes a head: every
+    other head's slice starts mid-tile), W_UK / W_UV a layer's, the table
+    as wide as the cell's one context bucket."""
+    from xllm_service_tpu.ops.pallas.mla_prefill import mla_materialised_prefill_kernel
+
+    s, stack = _mla_shapes(one_chip)
+    text = _compile(
+        lambda q, qp, wk, wv, c, bt, sp, tl, l: mla_materialised_prefill_kernel(
+            q, qp, wk, wv, c, bt, sp, tl, 0.1, MLA_KVR, layer=l),
+        s((1, rows, MLA_H, 192)), s((1, rows, MLA_H, 64)), s((MLA_H, MLA_KVR, 128)),
+        s((MLA_H, MLA_KVR, 128)), stack, s((1, 64), jnp.int32), s((1,), jnp.int32),
+        s((1,), jnp.int32), s((), jnp.int32),
+    )
+    assert "tpu_custom_call" in text and "mla_materialised_prefill_kernel" in text
+    # the absorbed kernel's readers match by prefix: this op is none of theirs
+    assert not re.search(r"\bmla_prefill_kernel", text)
+
+
 @pytest.mark.parametrize("pairs", [384, 3456])
 def test_grouped_expert_kernels_compile_at_published_widths(one_chip, no_persistent_cache, pairs):
     """64 decode rows x 6 and a 512-token chunk beside them x 6: the
@@ -579,9 +604,20 @@ def test_deepseek_v2_mixed_step_compiles_and_its_stack_stays(one_chip, no_persis
         s((1, 512), i32), s((1,), i32), s((1,), i32), s((1, 64), i32),
     ).compile()
     text = compiled.as_text()
-    for kernel in ("kv_write_kernel", "mla_paged_attention_kernel", "mla_prefill_kernel",
+    for kernel in ("kv_write_kernel", "mla_paged_attention_kernel", "mla_materialised_prefill_kernel",
                    "moe_grouped_kernel", "moe_grouped_down_kernel"):
         assert kernel in text, kernel
+    # a 512-row chunk over the bf16 stack takes the materialised form (PR
+    # 55): no op of the program starts with the absorbed kernel's name, the
+    # chunk's queries reach the launch as the projection wrote them
+    # (sliced by rows, never re-laid), and nothing as large as the chunk's
+    # absorbed queries or latent context is written
+    assert not re.search(r"\bmla_prefill_kernel", text)
+    launch = next(l for l in text.splitlines() if " custom-call(" in l and "mla_materialised_prefill_kernel" in l)
+    q_operand = re.sub(r"/\*.*?\*/", "", launch.split("custom-call(")[1]).split(",")[5].strip().lstrip("%")
+    producer = next(l for l in text.splitlines() if re.match(rf"\s*%?{re.escape(q_operand)} = ", l))
+    assert re.search(r" (bitcast|slice)\(", producer), producer[:200]
+    assert "512,128,640" not in text and "512,128,512" not in text
     _assert_nothing_moves(text, {f"5,{nb},1,128,640", f"{nb},1,128,640", "4,40,5120,1536",
                                  "40,5120,1536", "4,40,1536,5120", "40,1536,5120"})
     mem = compiled.memory_analysis()

@@ -189,12 +189,14 @@ def prefill_batch_step(
     return llama._unembed(params, cfg, llama._last_rows(x, true_len)), S, z
 
 
-def attention_routes(cfg: ModelConfig, S, tp: int = 1):
+def attention_routes(cfg: ModelConfig, S, tp: int = 1, prefill_rows: int = 0):
     """No paged pool, no attention launch: the state pool alone."""
     return ()
 
 
-def kernel_report(cfg: ModelConfig, S, tp: int = 1) -> dict:
+def kernel_report(
+    cfg: ModelConfig, S, tp: int = 1, prefill_rows: int = 0
+) -> dict:
     """Which route the retention updates take, under every step's name."""
     on = retention_ops.use_kernels(cfg.head_dim)
     route = f"retention-{'pallas' if on else 'xla'}"

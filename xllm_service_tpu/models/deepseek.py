@@ -13,6 +13,12 @@ decode)" as north-star config 3). TPU-first design choices:
     applied once to the attention-weighted latent), so per-head K/V for
     cached tokens is never materialized — scores are one [Hq, C] x [T, C]
     matmul per sequence, MXU-friendly;
+  * a prefill chunk of many rows runs in the MATERIALISED form on the
+    chip, as the published model does: one flash kernel makes each
+    head's keys and values from the latent blocks in VMEM, which costs
+    0.56x the absorbed form at 512 rows (the up-projection of a cached
+    position is shared by the chunk's rows); ops.attention's
+    attention_routes picks the form by the rows of a chunk;
   * the module exports the same function surface as models/llama.py
     (init_params / decode_step / mixed_step / prefill_batch_step /
     forward_dense), so the executor, engine, PD migration, and host tiers
@@ -49,12 +55,13 @@ from xllm_service_tpu.obs.spans import region
 from xllm_service_tpu.ops import kv_write as kv_write_ops
 from xllm_service_tpu.ops.attention import (
     attention_routes as pool_routes,
+    mla_materialised_prefill_attention,
     mla_paged_attention,
     mla_prefill_attention,
 )
 from xllm_service_tpu.ops import rope as rope_ops
 from xllm_service_tpu.ops.norms import block_norm, rms_norm
-from xllm_service_tpu.ops.quant import wdtype, wt
+from xllm_service_tpu.ops.quant import is_quant, wdtype, wt
 
 Params = Dict[str, Any]
 
@@ -202,9 +209,19 @@ def _dense_cfg(cfg: ModelConfig) -> ModelConfig:
 
 
 @region("attn_proj")
-def _q_heads(lp, cfg: ModelConfig, h: jnp.ndarray, positions: jnp.ndarray):
-    """h [T, E] -> (q_nope [T, Hq, dn], q_pe [T, Hq, dr] roped)."""
-    T = h.shape[0]
+def _q_heads(lp, cfg: ModelConfig, h: jnp.ndarray, positions: jnp.ndarray,
+             split: int = 0):
+    """h [T, E] -> (q [T, Hq, dn + dr], q_pe [T, Hq, dr] roped): the
+    heads as the projection wrote them, [q_nope | rope part] with the rope
+    part NOT roped (a consumer reads q[..., :dn]), and the roped rope part
+    on its own; the materialised prefill kernel reads both where they lie.
+    `split`: the rows before it and the rows from it on go to different
+    attention launches (a mixed step's decode rows and chunk rows), so
+    each part is fenced and cut into heads on its own and XLA lays each
+    out as ITS consumer reads it: one fence over both made all 576 rows
+    take the layout the decode rows' head-batched absorb wants, and the
+    prefill kernel's operand was transposed there and back (28 + 25 MB a
+    layer, read in the compiled text)."""
     dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     if cfg.q_lora_rank > 0:
         cq = jnp.einsum("te,eq->tq", h, wt(lp["w_dq"]))
@@ -212,10 +229,15 @@ def _q_heads(lp, cfg: ModelConfig, h: jnp.ndarray, positions: jnp.ndarray):
         q = jnp.einsum("tq,qh->th", cq, wt(lp["w_uq"]))
     else:
         q = jnp.einsum("te,eh->th", h, wt(lp["w_q"]))
-    q = _plain_product(q).reshape(T, cfg.num_heads, dn + dr)
-    q_nope, q_pe = q[..., :dn], q[..., dn:]
-    q_pe = rope_ops.apply_rope_scaled(q_pe, positions, cfg)
-    return q_nope, q_pe
+
+    def heads(q, positions):
+        q = _plain_product(q).reshape(-1, cfg.num_heads, dn + dr)
+        return q, rope_ops.apply_rope_scaled(q[..., dn:], positions, cfg)
+
+    if not split:
+        return heads(q, positions)
+    parts = heads(q[:split], positions[:split]), heads(q[split:], positions[split:])
+    return tuple(jnp.concatenate(part) for part in zip(*parts))
 
 
 def _pad_lanes(x: jnp.ndarray, width: int) -> jnp.ndarray:
@@ -244,9 +266,11 @@ def _latent_rows(lp, cfg: ModelConfig, h: jnp.ndarray, positions: jnp.ndarray):
 
 
 @region("attn_proj")
-def _absorb_q(lp, cfg: ModelConfig, q_nope, q_pe) -> jnp.ndarray:
-    """Project q_nope into the latent space and append q_pe: [.., Hq, C]
-    (lane-padded to match the cache rows)."""
+def _absorb_q(lp, cfg: ModelConfig, q, q_pe) -> jnp.ndarray:
+    """Project the heads' q_nope (q[..., :dn]) into the latent space and
+    append the roped q_pe: [.., Hq, C] (lane-padded to match the cache
+    rows)."""
+    q_nope = q[..., :cfg.qk_nope_head_dim]
     q_lat = jnp.einsum("...hd,hkd->...hk", q_nope, wt(lp["w_uk"]))
     return _pad_lanes(
         jnp.concatenate([q_lat, q_pe], axis=-1), cfg.mla_cache_dim
@@ -254,11 +278,31 @@ def _absorb_q(lp, cfg: ModelConfig, q_nope, q_pe) -> jnp.ndarray:
 
 
 @region("attn_proj")
-def _attn_out(lp, cfg: ModelConfig, ctx_lat: jnp.ndarray) -> jnp.ndarray:
-    """ctx_lat [..., Hq, kvr] -> hidden [..., E] via W_UV then W_O."""
-    o = jnp.einsum("...hk,hkv->...hv", ctx_lat, wt(lp["w_uv"]))
+def _up_v(lp, ctx_lat: jnp.ndarray) -> jnp.ndarray:
+    """The absorbed form's context ctx_lat [..., Hq, kvr] -> the heads'
+    value-space outputs [..., Hq, dv] via W_UV."""
+    return jnp.einsum("...hk,hkv->...hv", ctx_lat, wt(lp["w_uv"]))
+
+
+@region("attn_proj")
+def _attn_out(lp, cfg: ModelConfig, o: jnp.ndarray) -> jnp.ndarray:
+    """The heads' outputs o [..., Hq, dv] -> hidden [..., E] via W_O."""
     flat = o.reshape(*o.shape[:-2], cfg.num_heads * cfg.v_head_dim)
     return jnp.einsum("...h,he->...e", flat, wt(lp["wo"]))
+
+
+def _up_projections(lp):
+    """(W_UK, W_UV, w_layer) for a Pallas launch that makes keys and
+    values from latents: plain leaves go as the layers' whole STACKS with
+    this layer's index in them (`up_stacks`, _run_layers), which the
+    launch reads where they lie, since a custom call cannot read through
+    the scan's slice and XLA would copy the layer out (2 x 16 MB);
+    quantized leaves dequantize a layer at a time, as everywhere
+    (w_layer None: one layer's)."""
+    w_uk, w_uv, i = lp["up_stacks"]
+    if is_quant(w_uk) or is_quant(w_uv):
+        return wt(lp["w_uk"]), wt(lp["w_uv"]), None
+    return w_uk, w_uv, i
 
 
 @region("embed")
@@ -266,18 +310,20 @@ def _embed_rows(params: Params, token_ids) -> jnp.ndarray:
     return params["embed"][token_ids].astype(wdtype(params["layers"]["w_dkv"]))
 
 
-def _layer(lp, cfg, mcfg, x, positions, valid, attend, c):
+def _layer(lp, cfg, mcfg, x, positions, valid, attend, c, split=0):
     """One layer over a flat batch of token rows x [T, E] at `positions`
-    [T]: the rows' latents, `attend(q_lat [T, Hq, C], rows [T, C], c) ->
-    (ctx [T, Hq, kvr], c)` (which lands the rows in the carried stack and
-    attends over it), the output projection and the MLP over the rows
-    that are `valid` [T]."""
+    [T]: the rows' latents, `attend(lp, q [T, Hq, dn + dr], q_pe [T, Hq,
+    dr], rows [T, C], c) -> (o [T, Hq, dv], c)` (which lands the rows in
+    the carried stack and attends over it, in whichever form its launch
+    takes), the output projection and the MLP over the rows that are
+    `valid` [T]. `split`: where `attend` cuts the rows into two launches
+    (_q_heads)."""
     h = block_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
-    q_nope, q_pe = _q_heads(lp, cfg, h, positions)
+    q, q_pe = _q_heads(lp, cfg, h, positions, split)
     rows = _latent_rows(lp, cfg, h, positions)
-    ctx, c = attend(_absorb_q(lp, cfg, q_nope, q_pe), rows, c)
+    o, c = attend(lp, q, q_pe, rows, c)
     with region("attn_proj"):
-        x = x + _attn_out(lp, cfg, ctx)
+        x = x + _attn_out(lp, cfg, o)
     h = block_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
     with region("ffn"):
         return x + _mlp_block(lp, mcfg, h, rows_valid=valid), c
@@ -293,25 +339,32 @@ def _write(plan, layer):
     return write
 
 
-def attention_routes(cfg: ModelConfig, c_caches, tp: int = 1):
+def attention_routes(
+    cfg: ModelConfig, c_caches, tp: int = 1, prefill_rows: int = 0
+):
     """The decisions for this family's attention launches: one latent
-    pool, with no head axis to split over a mesh."""
-    return (pool_routes(c_caches, latent=True),)
+    pool, with no head axis to split over a mesh; `prefill_rows` (the
+    rows of a chunk) picks the form of the prefill launch."""
+    return (pool_routes(c_caches, latent=True, prefill_rows=prefill_rows),)
 
 
-def kernel_report(cfg: ModelConfig, c_caches, tp: int = 1) -> dict:
-    return attention_routes(cfg, c_caches)[0].report()
+def kernel_report(
+    cfg: ModelConfig, c_caches, tp: int = 1, prefill_rows: int = 0
+) -> dict:
+    return attention_routes(cfg, c_caches, tp, prefill_rows)[0].report()
 
 
 def _decode_attend(cfg, plan, tables, seq_lens, layer, use_kernel=None):
     """R decode rows: one latent row a sequence lands in the stack, then
     absorbed attention over its blocks. Returns (write, read)."""
 
-    def read(q_lat, c):
-        return mla_paged_attention(
-            q_lat, c, tables, seq_lens, mla_softmax_scale(cfg),
-            cfg.kv_lora_rank, use_kernel=use_kernel, layer=layer,
+    def read(lp, q, q_pe, c):
+        ctx = mla_paged_attention(
+            _absorb_q(lp, cfg, q, q_pe), c, tables, seq_lens,
+            mla_softmax_scale(cfg), cfg.kv_lora_rank, use_kernel=use_kernel,
+            layer=layer,
         )
+        return _up_v(lp, ctx)
 
     return _write(plan, layer), read
 
@@ -320,24 +373,45 @@ def _prefill_attend(
     cfg, plan, tables, start, length, Lpad: int, layer, use_kernel=None
 ):
     """P chunks of Lpad rows each (flat [P*Lpad]): the chunks' latents
-    land in the stack, then causal attention over each chunk's context
-    (flash kernel on TPU). Returns (write, read)."""
+    land in the stack, then causal attention over each chunk's context,
+    the chunk's own rows read back from the pool as latents like every
+    cached position. The form is the pool's Routes' for a launch of Lpad
+    rows: the materialised flash kernel takes the queries as they are
+    and gives the heads' outputs; every other route (the absorbed flash
+    kernel, the verify shapes, the blockwise scan) goes through the
+    latent space, W_UK before it and W_UV after. Returns (write, read)."""
+    scale = mla_softmax_scale(cfg)
 
-    def read(q_lat, c):
-        ctx = mla_prefill_attention(
-            q_lat.reshape(-1, Lpad, *q_lat.shape[1:]), c, tables, start,
-            length, mla_softmax_scale(cfg), cfg.kv_lora_rank,
-            use_kernel=use_kernel, layer=layer,
+    def chunks(a):
+        return a.reshape(-1, Lpad, *a.shape[1:])
+
+    def read(lp, q, q_pe, c):
+        routes = pool_routes(
+            c, latent=True, use_kernel=use_kernel, prefill_rows=Lpad
         )
-        return ctx.reshape(-1, *ctx.shape[2:])
+        if routes.materialised:
+            w_uk, w_uv, w_layer = _up_projections(lp)
+            o = mla_materialised_prefill_attention(
+                chunks(q), chunks(q_pe), w_uk, w_uv, c, tables, start,
+                length, scale, cfg.kv_lora_rank, interpret=routes.interpret,
+                layer=layer, w_layer=w_layer,
+            )
+        else:
+            ctx = mla_prefill_attention(
+                chunks(_absorb_q(lp, cfg, q, q_pe)), c, tables, start,
+                length, scale, cfg.kv_lora_rank, use_kernel=use_kernel,
+                layer=layer,
+            )
+            o = _up_v(lp, ctx)
+        return o.reshape(-1, *o.shape[2:])
 
     return _write(plan, layer), read
 
 
 def _attend(write, read):
-    def attend(q_lat, rows, c):
+    def attend(lp, q, q_pe, rows, c):
         c = write(rows, c)
-        return read(q_lat, c), c
+        return read(lp, q, q_pe, c), c
 
     return attend
 
@@ -352,7 +426,7 @@ def _chunk_coords(start_pos, true_len, Lpad: int):
 
 
 def _run_layers(params, cfg, x, k_caches, v_caches, positions, valid,
-                make_attend):
+                make_attend, split=0):
     """The layer stack over flat token rows x [T, E], over the CARRIED
     latent pool: one scan for a homogeneous model, or the dense-prefix scan
     (pool layers 0..k-1) followed by the expert-suffix scan (layers
@@ -362,10 +436,14 @@ def _run_layers(params, cfg, x, k_caches, v_caches, positions, valid,
     family). `make_attend(layer)` gives the layer's attend closure (the
     plans and tables are the step's, made once outside the scans)."""
 
-    def layer_fn(mcfg):
+    def layer_fn(mcfg, stack, first):
+        whole = params[stack]
+
         def fn(x, lp, layer, c, v):
+            lp = {**lp, "up_stacks": (whole["w_uk"], whole["w_uv"], layer - first)}
             x, c = _layer(
-                lp, cfg, mcfg, x, positions, valid, make_attend(layer), c
+                lp, cfg, mcfg, x, positions, valid, make_attend(layer), c,
+                split,
             )
             return x, c, v
 
@@ -374,11 +452,12 @@ def _run_layers(params, cfg, x, k_caches, v_caches, positions, valid,
     kd = cfg.first_k_dense_replace if "dense_layers" in params else 0
     if kd > 0:
         x, k_caches, v_caches = _scan_layers(
-            layer_fn(_dense_cfg(cfg)), x, params, k_caches, v_caches,
-            stack="dense_layers",
+            layer_fn(_dense_cfg(cfg), "dense_layers", 0), x, params, k_caches,
+            v_caches, stack="dense_layers",
         )
     return _scan_layers(
-        layer_fn(cfg), x, params, k_caches, v_caches, first_layer=kd
+        layer_fn(cfg, "layers", kd), x, params, k_caches, v_caches,
+        first_layer=kd,
     )
 
 
@@ -458,14 +537,17 @@ def mixed_step(
             use_kernel,
         )
 
-        def attend(q_lat, rows, c):
+        def attend(lp, q, q_pe, rows, c):
             # Both writes, then both reads of the pool as it then is (as
             # llama.mixed_step orders them): a read between the writes
             # would make the compiler keep a copy of the stack.
             c = pf_write(rows[R:], dec_write(rows[:R], c))
             with region("attn"):  # the halves cut apart and joined again
-                ctx = [dec_read(q_lat[:R], c), pf_read(q_lat[R:], c)]
-                return jnp.concatenate(ctx, axis=0), c
+                o = [
+                    dec_read(lp, q[:R], q_pe[:R], c),
+                    pf_read(lp, q[R:], q_pe[R:], c),
+                ]
+                return jnp.concatenate(o, axis=0), c
 
         return attend
 
@@ -476,7 +558,7 @@ def mixed_step(
         params, cfg, x, k_caches, v_caches,
         jnp.concatenate([dec_positions, pf_positions]),
         jnp.concatenate([dec_active, pf_valid]),
-        make_attend,
+        make_attend, split=R,
     )
     last = _last_rows(x[R:].reshape(P, Lpad, -1), pf_len)
     return (
@@ -570,7 +652,8 @@ def hidden_dense(
         def layer_fn(x, lp):
             def one_seq(hx):
                 h = rms_norm(hx, lp["attn_norm"], cfg.rms_norm_eps)
-                q_nope, q_pe = _q_heads(lp, cfg, h, positions)
+                q, q_pe = _q_heads(lp, cfg, h, positions)
+                q_nope = q[..., :dn]
                 rows = _latent_rows(lp, cfg, h, positions)  # [L, C]
                 # rows are lane-padded past kvr + dr; slice the true spans.
                 c, k_pe = rows[..., :kvr], rows[..., kvr:kvr + dr]

@@ -181,7 +181,9 @@ def pool_shapes(cfg: ModelConfig, blocks: int, window_blocks: int, block_size: i
             pair(cfg.num_window_layers, window_blocks, cfg.window_kv_heads))
 
 
-def attention_routes(cfg: ModelConfig, k_caches, tp: int = 1):
+def attention_routes(
+    cfg: ModelConfig, k_caches, tp: int = 1, prefill_rows: int = 0
+):
     """The decisions for the attention launches over this family's paged
     pools: the full layers' and, in a window family, the window layers'
     (a sink takes the verify shapes off the multi-query kernel). The
@@ -194,7 +196,9 @@ def attention_routes(cfg: ModelConfig, k_caches, tp: int = 1):
     return (full, over(k_caches[1], cfg.window_sink)) if cfg.num_window_layers else (full,)
 
 
-def kernel_report(cfg: ModelConfig, k_caches, tp: int = 1) -> dict:
+def kernel_report(
+    cfg: ModelConfig, k_caches, tp: int = 1, prefill_rows: int = 0
+) -> dict:
     """The full layers' launches by name, and the second pool's route:
     `window` in a window family, `state` beside a state pool."""
     full, *window = attention_routes(cfg, k_caches, tp)

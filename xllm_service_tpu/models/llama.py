@@ -546,13 +546,19 @@ def decode_step(
     return logits, k_caches, v_caches
 
 
-def attention_routes(cfg: ModelConfig, k_caches, tp: int = 1):
+def attention_routes(
+    cfg: ModelConfig, k_caches, tp: int = 1, prefill_rows: int = 0
+):
     """The decisions (ops.attention.Routes) for the attention launches of
-    this family's step programs, one a paged pool: here the K/V pools'."""
+    this family's step programs, one a paged pool: here the K/V pools'.
+    `prefill_rows` is the rows of a chunk of the prefill launch asked
+    about (every family takes it; a latent pool's form depends on it)."""
     return (pool_routes(k_caches, cfg.num_heads, cfg.head_dim, tp=tp),)
 
 
-def kernel_report(cfg: ModelConfig, k_caches, tp: int = 1) -> dict:
+def kernel_report(
+    cfg: ModelConfig, k_caches, tp: int = 1, prefill_rows: int = 0
+) -> dict:
     """What each launch kind over the family's pools runs as, by name."""
     return attention_routes(cfg, k_caches, tp)[0].report()
 

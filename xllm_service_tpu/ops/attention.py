@@ -435,6 +435,12 @@ def cache_kernel_route(cache, interpret: bool = False):
 # kernel_report() prints it, so the two cannot disagree.
 
 MQ_MAX_ROWS = 8  # verify shapes: at most this many query rows a sequence
+# An MLA prefill launch of at least this many rows a chunk makes each
+# head's keys and values from the latent blocks (the materialised form);
+# below it the absorbed form is the cheaper one. The up-projection of a
+# cached position is shared by the rows of the chunk: the two forms cost
+# the same at about 170 rows (ops/pallas/mla_prefill.py).
+MLA_MATERIALISE_ROWS = 256
 
 
 @functools.lru_cache(maxsize=1)
@@ -461,6 +467,9 @@ class Routes(NamedTuple):
     shards: int  # kernel launches a dispatch fans into (shard_map over tp)
     interpret: bool  # the kernels run in interpret mode
     forced_off: tuple = ()  # launch kinds a hatch sent to the fallback
+    # the prefill launch is the MATERIALISED MLA flash kernel (else, where
+    # `prefill`, the absorbed one): decided by the rows of a chunk
+    materialised: bool = False
 
     @property
     def bounded_by_context(self) -> bool:
@@ -475,7 +484,9 @@ class Routes(NamedTuple):
         counter, docs/OBSERVABILITY.md): the winning implementation of
         each launch kind, a fallback that a hatch forced marked
         ` (forced-off)`; `mixed` is the pair a fused step launches side by
-        side, `mq` what the verify shapes run as."""
+        side, `mq` what the verify shapes run as; `mla-flash-mat` is the
+        materialised form of a latent pool's flash kernel, for the rows
+        the routes were asked about."""
         decode, prefill, mq = (
             ("mla", "mla-flash", "mla-mq") if self.latent
             else ("paged", "flash", "mq")
@@ -484,11 +495,15 @@ class Routes(NamedTuple):
             decode = "gather" + " (forced-off)" * ("decode" in self.forced_off)
         if not self.prefill:
             prefill = "blockwise" + " (forced-off)" * ("prefill" in self.forced_off)
+        if not self.verify:
+            mq = prefill  # a few rows a sequence: never the materialised form
+        if self.materialised:
+            prefill = "mla-flash-mat"
         return {
             "decode": decode,
             "prefill": prefill,
             "mixed": f"{decode}+{prefill}",
-            "mq": mq if self.verify else prefill,
+            "mq": mq,
             "shards": self.shards,
         }
 
@@ -503,6 +518,7 @@ def attention_routes(
     use_kernel: bool | None = None,
     interpret: bool = False,
     sinks: bool = False,
+    prefill_rows: int = 0,
 ) -> Routes:
     """THE dispatch decision for the attention launches over `cache` (a
     K pool, or the latent pool with `latent`): which of them run as
@@ -523,7 +539,12 @@ def attention_routes(
     launch with `sinks` goes as `prefill`. An int8 latent pool's flash
     kernel is not validated on a chip: it takes the blockwise scan.
     `use_kernel` True / False (a caller's own switch) forces the decode
-    and the flash kernel on / off whatever the gates say."""
+    and the flash kernel on / off whatever the gates say. A latent
+    pool's flash kernel has two forms, and `prefill_rows` (the rows of a
+    chunk of the launch asked about: static at trace time, so a step
+    program holds one of the two) picks one: the materialised kernel
+    from MLA_MATERIALISE_ROWS rows on, the absorbed one below, where the
+    verify shapes are."""
     interp = interpret or _interpret()
     on = _on_tpu() or interp
     raw = kvc.raw(cache)
@@ -556,7 +577,13 @@ def attention_routes(
     if use_kernel is not None:
         decode = prefill = bool(use_kernel)
         verify, forced = False, ()
-    return Routes(latent, decode, prefill, verify, shards, interp, forced)
+    materialised = (
+        latent and prefill and not quantized
+        and prefill_rows >= MLA_MATERIALISE_ROWS
+    )
+    return Routes(
+        latent, decode, prefill, verify, shards, interp, forced, materialised
+    )
 
 
 def _gqa_routes(q, k_cache, use_kernel, interpret, sinks=None):
@@ -731,7 +758,9 @@ def mla_prefill_attention(
     interpret: bool = False,
     layer=None,  # int32 scalar: c_cache is the STACK [L, N, 1, BS, C]
 ) -> jnp.ndarray:
-    """Batched MLA chunked-prefill attention in ABSORBED form; Pallas
+    """Batched MLA chunked-prefill attention in ABSORBED form (a launch
+    whose Routes say `materialised`, a chunk of many rows on the chip,
+    goes to mla_materialised_prefill_attention instead); Pallas
     flash kernel (ops/pallas/mla_prefill.py) on TPU, vmapped blockwise
     scan elsewhere (attention_routes decides; `use_kernel` forces the
     flash kernel either way, `interpret` drives the kernel branches in
@@ -766,6 +795,45 @@ def mla_prefill_attention(
             qi, c_cache, ti, sp, tl, scale, kv_rank, layer=layer
         )
     )(q_lat, block_tables, start_pos, true_len)
+
+
+@region("attn")
+def mla_materialised_prefill_attention(
+    q: jnp.ndarray,  # [P, Lpad, Hq, dn + dr] — the chunk's query heads, not roped
+    q_pe: jnp.ndarray,  # [P, Lpad, Hq, dr] — their rope part, roped
+    w_uk: jnp.ndarray,  # [Hq, kv_rank, dn], or the layers' stack [n, Hq, ..]
+    w_uv: jnp.ndarray,  # [Hq, kv_rank, dv], or the layers' stack
+    c_cache,
+    block_tables: jnp.ndarray,  # [P, CB]
+    start_pos: jnp.ndarray,  # [P]
+    true_len: jnp.ndarray,  # [P]
+    scale: float,
+    kv_rank: int,
+    interpret: bool = False,
+    layer=None,  # int32 scalar: c_cache is the STACK [L, N, 1, BS, C]
+    w_layer=None,  # int32 scalar: w_uk / w_uv are the layers' stacks
+) -> jnp.ndarray:
+    """Batched MLA chunked-prefill attention in the MATERIALISED form, for
+    a launch whose Routes say `materialised` (attention_routes with the
+    chunk's rows as `prefill_rows`): ONE Pallas flash kernel that makes
+    each head's keys and values from the latent blocks in VMEM
+    (ops/pallas/mla_prefill.py). Takes the query heads as the projection
+    wrote them (a head's [q_nope | rope part]: the kernel reads the first
+    dn lanes a head and takes the roped part from `q_pe`, so nothing is
+    sliced out or re-laid before the launch) and returns the heads'
+    VALUE-space outputs [P, Lpad, Hq, dv]: no absorbed q_lat before it
+    and no W_UV product after it. The other routes of a chunk are
+    mla_prefill_attention's, whose blockwise scan is this kernel's
+    oracle."""
+    from xllm_service_tpu.ops.pallas.mla_prefill import (
+        mla_materialised_prefill_kernel,
+    )
+
+    return mla_materialised_prefill_kernel(
+        q, q_pe, w_uk, w_uv, c_cache, block_tables, start_pos,
+        true_len, scale, kv_rank, interpret=interpret, layer=layer,
+        w_layer=w_layer,
+    )
 
 
 def mla_prefill_blockwise(

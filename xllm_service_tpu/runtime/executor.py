@@ -741,6 +741,22 @@ class ModelExecutor:
                     None,
                 )
 
+        self.prefill_buckets = sorted(
+            b for b in engine_cfg.prefill_buckets if b <= engine_cfg.max_seq_len
+        )
+        # Buckets must cover max_seq_len so any admissible suffix fits.
+        if not self.prefill_buckets or self.prefill_buckets[-1] < engine_cfg.max_seq_len:
+            self.prefill_buckets.append(engine_cfg.max_seq_len)
+        if self.has_state_pool:
+            # No prefix hit shortens a suffix and no chunk passes the
+            # step's budget, so no bucket beyond it is ever dispatched
+            # (and max_seq_len bounds no memory: a 32768-token bucket
+            # would only be a program nobody runs).
+            top = min(engine_cfg.max_prefill_tokens, engine_cfg.max_seq_len)
+            self.prefill_buckets = sorted(
+                {b for b in self.prefill_buckets if b < top} | {top}
+            )
+
         # One context bucket or a grid of them (`_ctx_bucket`), decided
         # once, from the routes of the launches over the pools just built.
         self.whole_table = self._table_costs_nothing()
@@ -781,22 +797,6 @@ class ModelExecutor:
             return k, v
 
         self._import_jit = jax.jit(_import_impl, donate_argnums=(0, 1))
-        self.prefill_buckets = sorted(
-            b for b in engine_cfg.prefill_buckets if b <= engine_cfg.max_seq_len
-        )
-        # Buckets must cover max_seq_len so any admissible suffix fits.
-        if not self.prefill_buckets or self.prefill_buckets[-1] < engine_cfg.max_seq_len:
-            self.prefill_buckets.append(engine_cfg.max_seq_len)
-        if self.has_state_pool:
-            # No prefix hit shortens a suffix and no chunk passes the
-            # step's budget, so no bucket beyond it is ever dispatched
-            # (and max_seq_len bounds no memory: a 32768-token bucket
-            # would only be a program nobody runs).
-            top = min(engine_cfg.max_prefill_tokens, engine_cfg.max_seq_len)
-            self.prefill_buckets = sorted(
-                {b for b in self.prefill_buckets if b < top} | {top}
-            )
-
         # Expert-routing counts (docs/MOE.md, docs/OBSERVABILITY.md):
         # cumulative choice counts over the PUBLISHED experts, summed
         # over layers and steps, booked from the step programs' own
@@ -2272,11 +2272,20 @@ class ModelExecutor:
             return 1
         return ep
 
+    def _full_chunk_rows(self) -> int:
+        """The rows of the bucket a whole chunk of the step's prefill
+        budget takes: the launch the report names and the routes are
+        asked about (a latent pool's prefill form goes by the rows)."""
+        return self.bucket_len(
+            min(self.engine_cfg.max_prefill_tokens, self.engine_cfg.max_seq_len)
+        )
+
     def _attention_routes(self):
         """The family's decisions for the attention launches over this
         executor's paged pools, on its mesh (ops.attention.Routes each)."""
         return self.model_mod.attention_routes(
-            self.cfg, self.k_cache, tp=self.mesh.shape.get("tp", 1)
+            self.cfg, self.k_cache, tp=self.mesh.shape.get("tp", 1),
+            prefill_rows=self._full_chunk_rows(),
         )
 
     def kernel_report(self) -> Dict[str, str]:
@@ -2288,7 +2297,8 @@ class ModelExecutor:
         strands the packed layout shows up in bench rows and /metrics,
         not just a log line."""
         rep = self.model_mod.kernel_report(
-            self.cfg, self.k_cache, tp=self.mesh.shape.get("tp", 1)
+            self.cfg, self.k_cache, tp=self.mesh.shape.get("tp", 1),
+            prefill_rows=self._full_chunk_rows(),
         )
         if self.kv_pack_fallback and rep["decode"].startswith("gather"):
             rep["decode"] = "gather-fallback"
