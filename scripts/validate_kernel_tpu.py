@@ -803,6 +803,178 @@ def run_kda_gram_case(n, H, d, C=64):
 # NOTE: D=64 decode is NOT included — Mosaic rejects the lane-padded HBM
 # block slice below one 128-lane tile (tpu.memref_slice verify failure
 # on-chip); ops/attention.py falls back to gather there.
+def run_lightning_update_case(R, live, L, H, d):
+    """lightning_update_kernel as minicpm-sala's decode program calls it:
+    one launch a lightning layer over the L-layer state pool of R slots,
+    `live` of the R rows active (scattered). us a CALL, the share of 819
+    GB/s the live rows' state bytes (read and written) make of it, and the
+    largest error of o and of the state against the jax.numpy route."""
+    from xllm_service_tpu.ops import lightning as lo
+
+    rng = np.random.default_rng(0)
+    act = np.zeros(R, bool)
+    act[rng.choice(R, live, replace=False)] = True
+    act = jnp.asarray(act)
+    ks = jax.random.split(jax.random.key(0), 3)
+    q, k, v = (jax.random.normal(kk, (R, H, d), jnp.float32) for kk in ks)
+    q = q * d ** -0.5
+    log_lam = jnp.asarray(lo.log_decay(H, tuple(range(10, 10 + L)), 32))
+    shape = lo.state_shape(L, R, H, d)
+    S = jax.jit(lambda kk: jax.lax.map(
+        lambda k_: jax.random.normal(k_, shape[1:], jnp.float32), jax.random.split(kk, L)
+    ))(jax.random.key(1))
+
+    def step(use):
+        def run(S_):
+            def body(S_, l):
+                o, S_ = lo.decode_update(S_, l, act, q, k, v, log_lam[l], use_kernel=use)
+                return S_, o
+            return jax.lax.scan(body, S_, jnp.arange(L, dtype=jnp.int32))
+        return jax.jit(run, donate_argnums=0)
+
+    kern, ref = step(True), step(False)
+    S_k, o_k = kern(S + 0.0)
+    S_r, o_r = ref(S + 0.0)
+    err_o = float(jnp.abs(o_k - o_r).max())
+    err_s = max(float(jnp.abs(S_k[l] - S_r[l]).max()) for l in (0, L - 1))
+    del S_r, o_r
+    need = 2 * live * H * d * d * 4
+    out = []
+    for name, fn, st in (("kernel", kern, [S_k]), ("xla", ref, [S + 0.0])):
+        def once(fn=fn, st=st):
+            st[0], o = fn(st[0])
+            return o
+        t = bench(once, iters=8) / L
+        out.append(f"{name}={t*1e6:8.1f}us hbm_share={100*need/t/819e9:5.1f}%")
+    print(f"LIGHTNING-UPDATE R={R} live={live} L={L} H={H} d={d} err_o={err_o:.2e} "
+          f"err_S={err_s:.2e} " + " ".join(out))
+    return max(err_o, err_s) * 1e-2
+
+
+def run_lightning_chunk_case(Lc, L, H, d, slots=8):
+    """The chunked form (plain XLA, ops/lightning.py chunk_update) over an
+    L-layer pool: one chunk of Lc tokens from a FRESH state, then the next
+    from the CARRIED one, against the token-by-token recurrence; ms a
+    layer's call for each."""
+    from xllm_service_tpu.ops import lightning as lo
+
+    ks = jax.random.split(jax.random.key(3), 3)
+    q, k, v = (jax.random.normal(kk, (1, 2 * Lc, H, d), jnp.float32) for kk in ks)
+    q = q * d ** -0.5
+    log_lam = jnp.asarray(lo.log_decay(H, (12,), 32)[0])
+    o_ref, S_ref = jax.jit(lo.recurrent_form)(q[0], k[0], v[0], log_lam)
+    S = jnp.full(lo.state_shape(L, slots, H, d), 2.0, jnp.float32)
+    slot = jnp.array([3], jnp.int32)
+
+    def make(start):
+        sl = slice(start, start + Lc)
+        def run(S_, q_, k_, v_):
+            def body(S_, l):
+                o, S_ = lo.chunk_update(S_, l, slot, jnp.array([start]), jnp.array([Lc]),
+                                        q_[:, sl], k_[:, sl], v_[:, sl], log_lam)
+                return S_, o
+            return jax.lax.scan(body, S_, jnp.arange(L, dtype=jnp.int32))
+        jitted = jax.jit(run, donate_argnums=0)
+        return lambda S_: jitted(S_, q, k, v)
+
+    fresh, carried = make(0), make(Lc)
+    S, o0 = fresh(S)
+    S, o1 = carried(S)
+    scale = float(jnp.abs(o_ref).max())
+    err = max(float(jnp.abs(o0[L - 1, 0] - o_ref[:Lc]).max()),
+              float(jnp.abs(o1[0, 0] - o_ref[Lc:]).max())) / scale
+    err_s = float(jnp.abs(S[L - 1, 3] - S_ref).max()) / float(jnp.abs(S_ref).max())
+    state = [S]
+
+    def timed(fn):
+        def once():
+            state[0], o = fn(state[0])
+            return o
+        return bench(once, iters=4) / L
+
+    t0, t1 = timed(fresh), timed(carried)
+    print(f"LIGHTNING-CHUNK Lc={Lc} L={L} H={H} d={d} rel_err_o={err:.2e} rel_err_S={err_s:.2e} "
+          f"fresh={t0*1e3:7.2f}ms carried={t1*1e3:7.2f}ms a layer")
+    return max(err, err_s) * 1e-1
+
+
+def run_sparse_case(R, Hq, Hkv, D, BS, CB, L, N, ctx_lo, ctx_hi, chunk, chunk_start):
+    """minicpm-sala's selected-page launches over an L-layer pool of N
+    pages: stage 1 + stage 2 of R decode rows at contexts ctx_lo..ctx_hi
+    (the decode kernel a KV head a row, against the gather), the
+    compressed-key write and the selected attention of one `chunk`-row
+    prefill chunk that starts at `chunk_start` (its first ROW_TILE rows
+    against the gather; the whole chunk timed). The pools are ARGUMENTS of
+    every jitted function: closed over they would be constants of it."""
+    from xllm_service_tpu.ops import kv_cache as kvc
+    from xllm_service_tpu.ops import sparse_attention as sp
+
+    sel = sp.Selection(BS, 64, 32, 16, 1, 32, 8192)
+    ks = jax.random.split(jax.random.key(0), 5)
+    mk = lambda kk: jax.jit(lambda k_: jax.lax.map(
+        lambda k1: jax.random.normal(k1, (N, Hkv, BS, D), jnp.bfloat16),
+        jax.random.split(k_, L)))(kk)
+    K, V = mk(ks[0]), mk(ks[1])
+    CK = jax.random.normal(ks[2], (L, N, Hkv * sel.per_block, D), jnp.float32) * 0.3
+    pools = (K, V, CK)
+    paged = lambda x: kvc.PagedKV(x, None)
+    rng = np.random.default_rng(0)
+    tables = jnp.asarray(np.stack([rng.permutation(N - 1)[:CB] + 1 for _ in range(R)]), jnp.int32)
+    positions = jnp.asarray(rng.integers(ctx_lo, ctx_hi, R) - 1, jnp.int32)
+    active = jnp.ones((R,), bool)
+    q = jax.random.normal(ks[3], (R, Hq, D), jnp.bfloat16)
+    scale = D ** -0.5
+    layer = jnp.int32(L - 1)
+    dec = lambda use: jax.jit(lambda q_, K_, V_, CK_: sp.decode_attention(
+        q_, paged(K_), paged(V_), CK_, layer, tables, positions, active, scale, sel,
+        use_kernel=use))
+    dec_k = dec(True)
+    o_k, o_r = dec_k(q, *pools), dec(False)(q, *pools)
+    err = float(jnp.abs(o_k.astype(jnp.float32) - o_r.astype(jnp.float32)).max())
+    del o_r
+    t_dec = bench(lambda: dec_k(q, *pools), iters=8)
+    stage1 = jax.jit(lambda q_, CK_: sp.virtual_tables(
+        q_, CK_, layer, tables, positions, active, scale, sel, sel.dense_blocks)[0])
+    t_s1 = bench(lambda: stage1(q, CK), iters=8)
+    need = R * Hkv * 64 * BS * 2 * D * 2
+    print(f"SPARSE-DECODE R={R} ctx={ctx_lo}-{ctx_hi} err={err:.3e} stage1+2={t_dec*1e6:8.1f}us "
+          f"stage1={t_s1*1e6:8.1f}us stage2_hbm_share={100*need/max(t_dec-t_s1,1e-9)/819e9:5.1f}%",
+          flush=True)
+    # one prefill chunk past dense_len
+    qc = jax.random.normal(ks[4], (chunk, Hq, D), jnp.bfloat16)
+    pos = chunk_start + jnp.arange(chunk, dtype=jnp.int32)
+    live = jnp.ones((chunk,), bool)
+    n = sp.ROW_TILE
+    sel_fn = lambda use, rows: jax.jit(lambda q_, K_, V_, CK_: sp.chunk_selected_attention(
+        q_, paged(K_), paged(V_), CK_, layer, tables[0], pos[:rows], live[:rows], scale, sel,
+        use_kernel=use))
+    whole = sel_fn(True, chunk)
+    o_c = whole(qc, *pools)
+    first = sel_fn(False, n)(qc[:n], *pools)
+    err_c = float(jnp.abs(o_c[:n].astype(jnp.float32) - first.astype(jnp.float32)).max())
+    del first
+    t_c = bench(lambda: whole(qc, *pools), iters=4)
+    s1 = jax.jit(lambda q_, CK_: jax.lax.map(lambda xs: sp.virtual_tables(
+        xs[0], CK_, layer, tables[0], xs[1], xs[2], scale, sel, sel.topk)[0],
+        (q_.reshape(-1, n, Hq, D), pos.reshape(-1, n), live.reshape(-1, n))))
+    t_c1 = bench(lambda: s1(qc, CK), iters=4)
+    wr = jax.jit(lambda ck, K_: sp.write_compressed(
+        ck, paged(K_), layer, tables[:1], jnp.array([chunk_start]), jnp.array([chunk]), chunk,
+        sel), donate_argnums=0)
+    ck_box = [CK + 0.0]
+
+    def write_once():
+        ck_box[0] = wr(ck_box[0], K)
+        return ck_box[0][0, 0]
+
+    t_w = bench(write_once, iters=8)
+    need_c = chunk * Hkv * 64 * BS * 2 * D * 2
+    print(f"SPARSE-CHUNK rows={chunk} start={chunk_start} err={err_c:.3e} "
+          f"stage1+2={t_c*1e3:7.2f}ms stage1={t_c1*1e3:7.2f}ms write={t_w*1e6:7.1f}us "
+          f"stage2_hbm_share={100*need_c/max(t_c-t_c1,1e-9)/819e9:5.1f}%", flush=True)
+    return max(err, err_c)
+
+
 CASES = [
     # The decode kernel at the benchmark cells' own shapes (PERF.md, PR 40):
     # qwen2.5-3b.decode-batch (125 of 128 rows live, 256-768 tokens, table
@@ -858,6 +1030,21 @@ CASES = [
      dict(S=64, live=32, L=9, N=800, Hc=4, D=128, BS=128)),
     ("prefill-group5", run_prefill_case,
      dict(P=1, Lpad=256, Hq=20, Hkv=4, D=128, BS=128, MB=24)),
+    # minicpm-sala.longdoc-steady's launches (PERF.md, PR 56): the lightning
+    # update kernel over the cut's 6 lightning layers with 20 of 32 rows
+    # live against its XLA route; the chunked form at the cell's 4,096-row
+    # chunk against the recurrence; and the selected-page path: 32 decode
+    # rows at contexts of 9k-49k and one 4,096-row chunk at 28,672 through
+    # the decode kernel a KV head a row (a query group of 16, pages of 64,
+    # tables of 1,024 columns), with stage 1 and the compressed-key write
+    # timed apart.
+    ("lightning-update-longdoc", run_lightning_update_case,
+     dict(R=32, live=20, L=6, H=32, d=128)),
+    ("lightning-chunk-longdoc", run_lightning_chunk_case,
+     dict(Lc=4096, L=6, H=32, d=128)),
+    ("sparse-longdoc", run_sparse_case,
+     dict(R=32, Hq=32, Hkv=2, D=128, BS=64, CB=1024, L=2, N=20000, ctx_lo=9000,
+          ctx_hi=49000, chunk=4096, chunk_start=28672)),
     # solar-open2-250b.think-steady's chunk (PERF.md, PR 54): the decayed
     # gram of one 512-token prefill chunk of a KDA layer, 64 heads of 128
     # lanes in 8 chunks of 64: its diagonal sub-blocks as `kda_gram_kernel`

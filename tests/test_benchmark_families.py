@@ -30,9 +30,9 @@ def configurations():
 
 
 def test_the_benchmark_has_both_families():
-    # seven since PR 53 (the name stays: the driver counts tests by name)
+    # eight since PR 56 (the name stays: the driver counts tests by name)
     assert {c["family"] for c in configurations()} == {
-        "llama", "brumby", "deepseek", "granite", "solar", "mimo", "falcon_h1"}
+        "llama", "brumby", "deepseek", "granite", "solar", "mimo", "falcon_h1", "minicpm_sala"}
 
 
 @pytest.mark.parametrize("config", configurations(), ids=lambda c: c["name"])
@@ -57,8 +57,9 @@ def test_model_config_is_the_family_the_program_dispatches_on(config):
 
     cfg = family_mod.load(config).model_config(config["name"], config)
     module = models.get_module(cfg).__name__.rsplit(".", 1)[-1]
-    # the four hybrids are ONE stack: models/granite.py, the layer kinds as data
-    assert module == {"solar": "granite", "mimo": "granite", "falcon_h1": "granite"}.get(
+    # the five hybrids are ONE stack: models/granite.py, the layer kinds as data
+    assert module == {"solar": "granite", "mimo": "granite", "falcon_h1": "granite",
+                      "minicpm_sala": "granite"}.get(
         config["family"], config["family"])
     assert cfg.is_retention == (config["family"] == "brumby")
 
@@ -373,3 +374,61 @@ def test_the_falcon_h1_family_is_the_cut_and_draws_against_its_multipliers():
     # a score's std is SCORE_STD under unit-RMS inputs
     qk = std(w["attn"]["wq"]) * std(w["attn"]["wk"]) * E
     assert abs(qk * tiny["key_multiplier"] * tiny["attention_in_multiplier"] ** 2 / fam.SCORE_STD - 1) < 0.1
+
+
+def test_the_minicpm_sala_family_is_the_cut_and_draws_a_common_embedding_row():
+    """minicpm-sala as cut: published layers 9-16 of 32 at every published
+    width (a sparse layer, six lightning layers, a sparse layer), the whole
+    vocabulary, the selection's constants the family's published ones; and
+    at the rehearsal size the draw: every embedding row shares a common
+    part (EMBED_COMMON of its RMS), the sparse layers' q and k gains stand
+    around sqrt(QK_GAIN), each OUT gain is its matrix's, norm gains are
+    float32, nothing at 0 and no gain at 1."""
+    import jax
+    import jax.numpy as jnp
+
+    with open(os.path.join(BENCH, "configs", "minicpm-sala.json")) as f:
+        config = json.load(f)
+    fam = family_mod.load(config)
+    cfg = fam.model_config(config["name"], config)
+    assert (cfg.num_layers, cfg.vocab_size) == (8, 73448) and config["reduced"] == ["num_hidden_layers"]
+    assert cfg.layer_types == ("sparse",) + ("lightning",) * 6 + ("sparse",)
+    assert config["num_hidden_layers_published"] == 32 == len(config["mixer_types"])
+    assert [i for i, k in enumerate(config["mixer_types"]) if k == "minicpm4"] == [
+        0, 9, 16, 17, 22, 29, 30, 31]
+    assert fam.held_layers(config) == [(9, "minicpm4")] + [
+        (l, "lightning-attn") for l in range(10, 16)] + [(16, "minicpm4")]
+    assert config["sparse_config"] == {"kernel_size": 32, "kernel_stride": 16, "block_size": 64,
+                                       "topk": 64, "init_blocks": 1, "window_size": 2048,
+                                       "dense_len": 8192}
+    assert fam.residual_scale(config) == 1.4 / 32 ** 0.5 == cfg.residual_multiplier
+    shapes = fam.weight_shapes(config)
+    assert shapes["attn"]["wq"] == (2, 4096, 4096) and shapes["attn"]["wk"] == (2, 4096, 256)
+    assert shapes["lightning"]["wk"] == (6, 4096, 4096) and shapes["lightning"]["o_norm"] == (6, 4096)
+    assert shapes["layers"]["w_gate"] == (8, 4096, 16384) and shapes["lm_head"] == (4096, 73448)
+    sizes = jax.tree.leaves(shapes, is_leaf=lambda x: isinstance(x, tuple))
+    small = 8 * 2 * 4096 + 4096 + 2 * 2 * 128 + 6 * (2 * 128 + 4096)  # every norm gain
+    assert sum(int(np.prod(s)) for s in sizes) - small == 2_820_472_832
+    # the decay: slow heads of a deep layer remember hundreds of tokens
+    lam = fam.decay(config, 15)
+    assert lam.shape == (32,) and 0.4 < lam[0] < 0.7 and 0.997 < lam[-1] < 0.999
+
+    with open(os.path.join(BENCH, "configs", "rehearse-minicpm-sala-tiny.json")) as f:
+        tiny = json.load(f)
+    w = jax.jit(lambda k: fam.make_weights(tiny, k, jnp.float32))(family_mod.seed_key(3))
+    for leaf in jax.tree.leaves(w):  # nothing at a value that lets a path skip it
+        assert float(jnp.std(leaf.astype(jnp.float32))) > 0.0
+    std = lambda a: float(jnp.std(a))
+    E, F = 64, 96
+    emb = w["embed"] * tiny["scale_emb"]
+    common = emb.mean(axis=0)
+    assert abs(float(jnp.sqrt(jnp.mean(common ** 2))) / fam.EMBED_COMMON - 1) < 0.25
+    assert abs(float(jnp.sqrt(jnp.mean(emb ** 2))) - 1) < 0.15  # h0 has unit RMS
+    assert abs(float(w["attn"]["q_norm"].mean()) ** 2 / fam.QK_GAIN - 1) < 0.1
+    assert abs(float(w["lightning"]["q_norm"].mean()) - 1) < 0.1
+    assert abs(std(w["attn"]["wo"]) * np.sqrt(E) / fam.ATTN_OUT_GAIN - 1) < 0.1
+    assert abs(std(w["lightning"]["wo"]) * np.sqrt(E) / fam.LIGHTNING_OUT_GAIN - 1) < 0.1
+    assert abs(std(w["layers"]["w_down"]) * np.sqrt(F) / fam.MLP_OUT_GAIN - 1) < 0.1
+    assert abs(std(w["lm_head"]) * np.sqrt(E) / (E / tiny["dim_model_base"]) - 1) < 0.1
+    assert all(w[g][k].dtype == jnp.float32 for g in ("attn", "lightning", "layers")
+               for k in w[g] if k.endswith("norm"))

@@ -8,7 +8,10 @@ blocks (`solar-tiny`: a preempted sequence gives back its slot and its
 blocks, and recomputes its state when it resumes) and for a family with a
 SECOND paged pool (`mimo-tiny`: a preempted sequence gives back the blocks
 of both) and for a family whose EVERY layer holds a state slot and K/V
-blocks (`falcon-h1-tiny`)."""
+blocks (`falcon-h1-tiny`) and for a family whose sparse layers keep
+compressed-key rows beside their K/V blocks and whose other layers hold a
+lightning state slot (`minicpm-sala-tiny`: its sequences run past
+dense_len, so a resumed one selects its pages again)."""
 
 import numpy as np
 import pytest
@@ -19,12 +22,13 @@ from xllm_service_tpu.runtime.engine import EngineRequest, InferenceEngine
 from xllm_service_tpu.runtime.executor import ModelExecutor
 
 
-MODELS = ["llama3-tiny", "solar-tiny", "mimo-tiny", "falcon-h1-tiny"]
+MODELS = ["llama3-tiny", "solar-tiny", "mimo-tiny", "falcon-h1-tiny", "minicpm-sala-tiny"]
 
 
 def _engine(model, R=4, num_blocks=64):
     cfg = EngineConfig(
-        model=model, dtype="float32", block_size=16,
+        # (the sparse family's page is its selection's block)
+        model=model, dtype="float32", block_size=8 if model == "minicpm-sala-tiny" else 16,
         num_blocks=num_blocks, max_running_requests=R, max_seq_len=256,
         prefill_buckets=[32, 64, 128],
         # a state family's engines step synchronously, as tests/test_granite.py's

@@ -475,8 +475,10 @@ def test_every_state_layer_kind_is_one_row_of_one_table():
 
     assert tuple(granite.STATE_KINDS) == configs.STATE_LAYER_KINDS == tuple(configs._STATE_MIXER_PARAMS)
     assert set(granite.MIXER_STACKS) == set(granite.STATE_KINDS) | {"attention", "window"}
-    # a layer kind is its mixers (configs.LAYER_MIXERS): the four of one, and the parallel kind of two
-    assert set(granite.MIXER_REGIONS) == set(configs.LAYER_MIXERS) == set(granite.MIXER_STACKS) | {"parallel"}
+    # a layer kind is its mixers (configs.LAYER_MIXERS): the five of one mixer and a stack of its own,
+    # the parallel kind of two, and the sparse kind of the attention mixer over the attention stack
+    assert set(granite.MIXER_REGIONS) == set(configs.LAYER_MIXERS) == set(granite.MIXER_STACKS) | {
+        "parallel", "sparse"}
     assert all(set(mx) <= set(granite.MIXER_STACKS) for mx in configs.LAYER_MIXERS.values())
     assert all(len(row) == 4 and all(callable(f) for f in row) for row in granite.STATE_KINDS.values())
     for name in ("solar-tiny", "granite-tiny"):

@@ -670,6 +670,74 @@ def test_granite_mixed_step_compiles_and_its_pools_stay(one_chip, no_persistent_
     assert 12.5e9 < mem.argument_size_in_bytes < 13.5e9  # weights + state pool + 1.05 GB of K and V
 
 
+def test_lightning_update_kernel_compiles_at_published_widths(one_chip, no_persistent_cache):
+    """Mosaic takes `lightning_update_kernel` at minicpm-sala's shape: 32
+    rows of 32 heads, planes of 128 x 128 float32, a head tile of 16 (1 MiB
+    a block), the heads' keys and queries arriving a head a lane and
+    broadcast along the lanes inside the body."""
+    from xllm_service_tpu.ops import lightning as lo
+
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = _compile(
+        lambda S, act, q, k, v, lam: lo.decode_update(S, jnp.int32(3), act, q, k, v, lam,
+                                                      use_kernel=True),
+        s(lo.state_shape(6, 32, 32, 128)), s((32,), jnp.bool_),
+        s((32, 32, 128)), s((32, 32, 128)), s((32, 32, 128)), s((32,)),
+    )
+    assert _kernel_calls(text, "lightning_update_kernel") == 1
+
+
+@pytest.mark.parametrize("step", ["decode", "mixed"])
+def test_minicpm_sala_steps_compile_at_published_widths(one_chip, no_persistent_cache, as_on_tpu, step):
+    """minicpm-sala as the benchmark cuts it (a sparse layer, six lightning
+    layers, a sparse layer; the whole vocabulary): the decode step of 32
+    rows and the mixed step with a 4,096-row chunk, every table 1,024
+    blocks wide, fit the chip beside 5.64 GB of weights, a 32-slot state
+    pool and 30,000 pages of K, V and compressed keys, with the lightning
+    update kernel, the K/V write, the decode kernel a KV head a row and
+    (mixed) the flash kernel in them; the state pool, the compressed-key
+    pool and the K/V stacks are read and written where they lie, and stage
+    1's scores and the chunked form's sub-chunks keep the temporaries
+    under 1.5 GB."""
+    from xllm_service_tpu.models import granite
+
+    cfg = get_model_config("minicpm-sala")
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.eval_shape(lambda k: granite.init_params(cfg, k, jnp.bfloat16), jax.random.key(0))
+    params = jax.tree.map(lambda a: s(a.shape, a.dtype), params)
+    R, nb, i32, CB = 32, 30000, jnp.int32, 1024
+    state, ck = granite.state_shapes(cfg, R, nb)
+    kv = kvc.PagedKV(s((2, nb, 2, 64, 128)), None)
+    pools = ((kv, s(state, jnp.float32)), (kv, s(ck, jnp.float32)))
+    dec = (s((R,), i32), s((R,), i32), s((R, CB), i32), s((R,), jnp.bool_))
+    if step == "decode":
+        fn, args = granite.decode_step, (params, *pools, *dec)
+    else:
+        pf = (s((1, 4096), i32), s((1,), i32), s((1,), i32), s((1, CB + 1), i32))
+        fn, args = granite.mixed_step, (params, *pools, *dec, *pf)
+    compiled = jax.jit(
+        lambda p, k, v, *a: fn(p, cfg, k, v, *a), donate_argnums=(1, 2)
+    ).lower(*args).compile()
+    text = compiled.as_text()
+    kernels = ["lightning_update_kernel", "kv_write_kernel", "paged_attention_kernel"]
+    for kernel in kernels + ["flash_prefill_kernel"] * (step == "mixed"):
+        assert kernel in text, kernel
+    import re
+
+    pools_ = {",".join(map(str, sh)) for sh in (state, ck, (2, nb, 2, 64, 128))}
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if (m := re.match(r"\s*%?[\w.\-]+ = \w+\[([\d,]*)\]\S* copy\(", line)) and m.group(1) in pools_]
+    assert not copies, "\n".join(copies)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1.5e9
+    assert 10.0e9 < mem.argument_size_in_bytes < 10.5e9  # weights + state pool + 4.2 GB of pages
+
+
 # ---- PR 45: a weight is read where it lies. The layer scan hands a step
 # its layer's leaves by index; a product on a leaf must take that slice as a
 # bitcast inside its own fusion. What it must not do is what brumby's `_qkv`
@@ -789,6 +857,26 @@ def _in_place_case(one_chip, case):
         pf = (s((1, 256)), s((1,)), s((1,)), s((1, 33)))
         fn = lambda p, k, v, *a: granite.mixed_step(p, cfg, k, v, *a)  # noqa: E731
         return fn, (params, *pools, *dec(64, 32), *pf), names
+    if case.startswith("sala"):
+        from xllm_service_tpu.models import granite
+
+        cfg = dataclasses.replace(  # a sparse layer and a scan of two lightning layers
+            get_model_config("minicpm-sala"), num_layers=3,
+            layer_types=("sparse", "lightning", "lightning"), layer_ids=(9, 10, 11),
+            vocab_size=8192,
+        )
+        params = jax.eval_shape(lambda k: granite.init_params(cfg, k, jnp.bfloat16), jax.random.key(0))
+        params = jax.tree.map(lambda a: s(a.shape, a.dtype), params)
+        state, ck = granite.state_shapes(cfg, 32, 3000)
+        kv = kvc.PagedKV(s((1, 3000, 2, 64, 128), jnp.bfloat16), None)
+        pools = ((kv, s(state, jnp.float32)), (kv, s(ck, jnp.float32)))
+        names = ("wq", "wk", "wv", "wo", "w_ogate", "w_gate", "w_up", "w_down")
+        if case == "sala-decode-32":
+            fn = lambda p, k, v, *a: granite.decode_step(p, cfg, k, v, *a)  # noqa: E731
+            return fn, (params, *pools, *dec(32, 1024)), names
+        pf = (s((1, 2048)), s((1,)), s((1,)), s((1, 1025)))  # (4,096 rows would give activations a square leaf's shape)
+        fn = lambda p, k, v, *a: granite.mixed_step(p, cfg, k, v, *a)  # noqa: E731
+        return fn, (params, *pools, *dec(32, 1024), *pf), names
     from xllm_service_tpu.models import deepseek
 
     cfg = dataclasses.replace(  # 1 dense layer beside the scan of 2
@@ -810,7 +898,8 @@ def _in_place_case(one_chip, case):
 
 @pytest.mark.parametrize("case", ["brumby-decode-24", "deepseek-decode-3", "deepseek-mixed-576",
                                   "solar-decode-96", "solar-mixed-608", "mimo-decode-64",
-                                  "mimo-mixed-576", "falcon-decode-64", "falcon-mixed-320"])
+                                  "mimo-mixed-576", "falcon-decode-64", "falcon-mixed-320",
+                                  "sala-decode-32", "sala-mixed-2080"])
 def test_step_reads_every_weight_leaf_where_it_lies(one_chip, no_persistent_cache, as_on_tpu, case):
     """The brumby decode step at reason-batch's 24 rows and the deepseek
     decode (3 rows) and mixed (64 + 512 rows) steps of doc-steady, at the
@@ -831,7 +920,12 @@ def test_step_reads_every_weight_leaf_where_it_lies(one_chip, no_persistent_cach
     falcon-h1-34b's widths: both mixers' matrices and the dense MLP's of a
     block, with the update kernel (two B/C groups, state 256), the paged
     decode, flash-prefill and write kernels (a query group of 5) all in
-    the ONE layer body."""
+    the ONE layer body. The sparse + lightning family's decode (32 rows)
+    and mixed (32 + 2,048 rows) steps at minicpm-sala's widths, every table
+    1,024 blocks wide: the sparse layer's matrices and gate (a query group
+    of 16), the lightning layers' five square matrices, the dense MLP,
+    with the lightning update kernel, the decode kernel a KV head a row,
+    the write kernel and (mixed) the flash kernel in the program."""
     fn, args, names = _in_place_case(one_chip, case)
     text = jax.jit(fn, donate_argnums=(1, 2)).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text  # the kernels' branch, as on the chip
@@ -853,6 +947,12 @@ def test_step_reads_every_weight_leaf_where_it_lies(one_chip, no_persistent_cach
         moved = _weight_leaves_moved(text, args[0], names, ("layers", "mamba", "attn"), dtype="bf16")
         launches = ["mamba_update_kernel", "paged_attention_kernel", "kv_write_kernel"]
         launches += ["flash_prefill_kernel"] * (case == "falcon-mixed-320")
+        for name in launches:
+            assert name in text, name
+    elif case.startswith("sala"):
+        moved = _weight_leaves_moved(text, args[0], names, ("layers", "lightning", "attn"), dtype="bf16")
+        launches = ["lightning_update_kernel", "paged_attention_kernel", "kv_write_kernel"]
+        launches += ["flash_prefill_kernel"] * (case == "sala-mixed-2080")
         for name in launches:
             assert name in text, name
     else:

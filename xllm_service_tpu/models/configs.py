@@ -12,7 +12,7 @@ from typing import Dict, Optional
 
 
 # Layer kinds of a hybrid stack whose sequence memory is a state slot.
-STATE_LAYER_KINDS = ("mamba", "kda")
+STATE_LAYER_KINDS = ("mamba", "kda", "lightning")
 # A block with BOTH mixers: a Mamba-2 mixer and a GQA mixer over the same
 # normed input, their outputs summed into one residual add (Falcon-H1).
 PARALLEL_KIND = "parallel"
@@ -22,6 +22,10 @@ PARALLEL_KIND = "parallel"
 LAYER_MIXERS = {
     "mamba": ("mamba",), "kda": ("kda",), "attention": ("attention",),
     "window": ("window",), PARALLEL_KIND: ("mamba", "attention"),
+    # a decayed outer-product state with rotary (ops/lightning.py), and GQA
+    # over the pages a query group selects (ops/sparse_attention.py): rows
+    # of the paged pool and of the compressed-key pool beside it
+    "lightning": ("lightning",), "sparse": ("attention",),
 }
 
 
@@ -198,6 +202,31 @@ class ModelConfig:
     ssm_out_multiplier: float = 1.0
     ssm_multipliers: tuple = ()
     mlp_multipliers: tuple = ()
+    # Lightning linear-attention layers ("lightning" in layer_types:
+    # `S <- lambda_h S + k^T v`, `o = q S / sqrt(d)`, QK-norm, rotary on
+    # every lane of q and k at `rope_theta`, an output norm and a per-lane
+    # sigmoid gate): heads of one key and value width. The decay is a
+    # CONSTANT of the head and of the layer's PUBLISHED index
+    # (`layer_ids[l]` of `published_layers`; ops/lightning.py), so a cut
+    # of the published depth keeps its layers' own decays.
+    lightning_n_heads: int = 0
+    lightning_d_head: int = 0
+    layer_ids: tuple = ()  # the published index of every layer (() = 0..L-1)
+    published_layers: int = 0  # the published depth (0 = num_layers)
+    # Block-sparse attention layers ("sparse" in layer_types, InfLLM-V2:
+    # ops/sparse_attention.py): a query past `sparse_dense_len` tokens of
+    # context attends `sparse_topk` blocks of `sparse_block_size` tokens a
+    # KV head: the first `sparse_init_blocks`, the blocks that cover the
+    # last `sparse_window` tokens, and the best of the rest by the query
+    # group's softmax over compressed keys (the mean of
+    # `sparse_kernel_size` keys every `sparse_kernel_stride`).
+    sparse_block_size: int = 0
+    sparse_topk: int = 0
+    sparse_kernel_size: int = 0
+    sparse_kernel_stride: int = 0
+    sparse_init_blocks: int = 0
+    sparse_window: int = 0
+    sparse_dense_len: int = 0
 
     @property
     def is_retention(self) -> bool:
@@ -242,6 +271,17 @@ class ModelConfig:
         whose blocks a sequence frees once they are `sliding_window`
         behind it."""
         return self.layer_types.count("window")
+
+    @property
+    def num_sparse_layers(self) -> int:
+        """Layers whose queries select their pages (they hold rows of the
+        paged pool AND of the compressed-key pool)."""
+        return self.layer_types.count("sparse")
+
+    @property
+    def sparse_keys_per_block(self) -> int:
+        """Compressed keys that START in one block of the paged pool."""
+        return self.sparse_block_size // self.sparse_kernel_stride
 
     @property
     def value_head_dim(self) -> int:
@@ -378,7 +418,13 @@ def _kda_mixer_params(cfg: ModelConfig) -> int:
 
 
 # matrices of one state layer's mixer, by kind
-_STATE_MIXER_PARAMS = {"mamba": _mamba_mixer_params, "kda": _kda_mixer_params}
+def _lightning_mixer_params(cfg: ModelConfig) -> int:
+    # q, k, v, the gate and o, each hidden x (heads x d_head)
+    return 5 * cfg.hidden_size * cfg.lightning_n_heads * cfg.lightning_d_head
+
+
+_STATE_MIXER_PARAMS = {"mamba": _mamba_mixer_params, "kda": _kda_mixer_params,
+                       "lightning": _lightning_mixer_params}
 
 
 _REGISTRY: Dict[str, ModelConfig] = {}
@@ -1171,6 +1217,90 @@ register(
                          0.3535533905932738),
         mlp_multipliers=(0.1767766952966369, 0.011160714285714284),
         max_position_embeddings=262144,
+    )
+)
+
+_SALA_SCALE_DEPTH, _SALA_DEPTH = 1.4, 32  # MiniCPM's muP: scale_depth / sqrt(published depth)
+
+register(
+    # MiniCPM-SALA's two mixers at a test's size (tests/test_minicpm_sala.py):
+    # sparse layers (a query group of 2, QK-norm, NoPE, a gate; blocks of 8,
+    # top-4 of them past 64 tokens: the first, the two that cover the last
+    # 16 tokens, and one chosen) around lightning layers (4 heads of 16,
+    # rotary, decays of published layers 10 and 11 of 32), a dense MLP, an
+    # untied head, the three muP scalars.
+    ModelConfig(
+        name="minicpm-sala-tiny",
+        vocab_size=512,
+        hidden_size=64,
+        intermediate_size=96,
+        num_layers=4,
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=16,
+        rope_theta=10000.0,
+        rms_norm_eps=1e-6,
+        qk_norm=True,
+        attn_gate=True,
+        layer_types=("sparse", "lightning", "lightning", "sparse"),
+        lightning_n_heads=4,
+        lightning_d_head=16,
+        layer_ids=(9, 10, 11, 16),
+        published_layers=_SALA_DEPTH,
+        sparse_block_size=8,
+        sparse_topk=4,
+        sparse_kernel_size=4,
+        sparse_kernel_stride=2,
+        sparse_init_blocks=1,
+        sparse_window=16,
+        sparse_dense_len=64,
+        embedding_multiplier=12.0,
+        residual_multiplier=_SALA_SCALE_DEPTH / _SALA_DEPTH ** 0.5,
+        logits_scaling=2.0,
+        max_position_embeddings=4096,
+    )
+)
+
+register(
+    # https://huggingface.co/openbmb/MiniCPM-SALA/blob/main/config.json
+    # (model_type minicpm_sala), as ONE CHIP'S SHARE of it at every
+    # published width: published layers 9 to 16 of 32 (the second of four
+    # pipeline stages): a block-sparse layer (32 query heads over 2 KV
+    # heads of 128, QK-norm, NoPE, a gate; InfLLM-V2's selection: blocks of
+    # 64, top-64, past 8,192 tokens), six Lightning layers (32 heads of
+    # 128, rotary, the decays of layers 10 to 15), a block-sparse layer; a
+    # dense SwiGLU of 16,384; the whole vocabulary, untied
+    # (benchmarks/configs/minicpm-sala.json has the deployment and what
+    # is assumed). Random weights only: runtime/weights.py has no loader.
+    ModelConfig(
+        name="minicpm-sala",
+        vocab_size=73448,
+        hidden_size=4096,
+        intermediate_size=16384,
+        num_layers=8,
+        num_heads=32,
+        num_kv_heads=2,
+        head_dim=128,
+        rope_theta=10000.0,
+        rms_norm_eps=1e-6,
+        qk_norm=True,
+        attn_gate=True,
+        layer_types=("sparse",) + ("lightning",) * 6 + ("sparse",),
+        lightning_n_heads=32,
+        lightning_d_head=128,
+        layer_ids=tuple(range(9, 17)),
+        published_layers=_SALA_DEPTH,
+        sparse_block_size=64,
+        sparse_topk=64,
+        sparse_kernel_size=32,
+        sparse_kernel_stride=16,
+        sparse_init_blocks=1,
+        sparse_window=2048,
+        sparse_dense_len=8192,
+        embedding_multiplier=12.0,
+        residual_multiplier=_SALA_SCALE_DEPTH / _SALA_DEPTH ** 0.5,
+        logits_scaling=16.0,
+        max_position_embeddings=524288,
     )
 )
 

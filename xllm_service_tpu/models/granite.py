@@ -2,7 +2,7 @@
 with BOTH a state mixer and a GQA mixer in ONE stack, a top-k expert
 block (beside a shared MLP where the family has one) in every layer
 behind a dense prefix, or a dense MLP in every layer (`num_experts` 0),
-over the same runtime as the other families. Four published families run
+over the same runtime as the other families. Five published families run
 on it, and the difference between them is DATA of the configuration
 (`layer_types`, the multipliers, the head): Granite 4.0-H (HF
 `model_type: granitemoehybrid`: Mamba-2 state layers, a tied head, four
@@ -12,7 +12,10 @@ the GQA layers, an untied head, every multiplier 1), MiMo-V2-Flash
 of a head, key heads wider than value heads, a dense first layer,
 sigmoid-scored experts and no shared one) and Falcon-H1 (`falcon_h1`:
 every block the parallel kind, full rotary, two B/C groups, a dense MLP,
-muP multipliers inside the projections).
+muP multipliers inside the projections) and MiniCPM-SALA
+(`minicpm_sala`: block-sparse attention layers whose queries select their
+pages beside Lightning linear-attention layers, a dense MLP, MiniCPM's muP
+scalars on the four multipliers).
 
 With `h` the residual stream, `rms` RMSNorm with a learned gain, and the
 four multipliers of the configuration (each 1 by default):
@@ -66,6 +69,27 @@ four multipliers of the configuration (each 1 by default):
   (`_scale`: the product is linear in q and k, so the cache holds the
   plain `W_k u` and rounds as every family's does) and, for v, in the
   branch's scalar beside `attention_out_multiplier` (`_branch_scales`).
+* "lightning" (MiniCPM-SALA's `lightning-attn`): `q, k, v = W_q u, W_k u,
+  W_v u` a head of `lightning_d_head` lanes; `q = rope(rms_q(q)) /
+  sqrt(d)`, `k = rope(rms_k(k))` (rotary on EVERY lane at `rope_theta`:
+  a state mixer reads its rows' positions); `S <- lambda S + k^T v`, `o =
+  q S` over a float32 state slot (ops/lightning.py: the recurrence for a
+  decode row, the chunked form in sub-chunks for a prefill chunk), the
+  decay a CONSTANT of the head and of the layer's PUBLISHED index
+  (`layer_ids`, `published_layers`); out `= W_o [sigmoid(W_g u) *
+  rms_o(o)]`, `rms_o` over a head's lanes with a gain over all of them.
+  No convolution: the stack's fourth pool is free, and holds:
+* "sparse" (MiniCPM-SALA's `minicpm4`, InfLLM-V2): the "attention" mixer
+  with QK-norm (`q_norm`, `k_norm` in the `attn` stack; the cache holds
+  the normed key), NoPE, the gate, over the SAME K/V pool, plus a pool of
+  compressed keys (the mean of `sparse_kernel_size` keys every
+  `sparse_kernel_stride`) under the same block table. A row whose context
+  is at most `sparse_dense_len` attends all of it through the launches a
+  full layer takes; a row past it attends the `sparse_topk` blocks its
+  KV head's query group selects (ops/sparse_attention.py: stage 1 in XLA,
+  stage 2 the decode launch over a virtual table, a KV head a row). The
+  switch is per ROW, so a token's output does not depend on how its
+  request was cut into chunks.
 * experts: `llama.moe_route` (softmax or sigmoid scores, top-k,
   renormalised) and the grouped product over the experts HELD
   (`cfg.experts_held`), the shared MLP where `n_shared_experts` > 0
@@ -131,9 +155,11 @@ from xllm_service_tpu.obs.spans import region
 from xllm_service_tpu.ops import kda as kda_ops
 from xllm_service_tpu.ops import kv_cache as kvc
 from xllm_service_tpu.ops import kv_write as kv_write_ops
+from xllm_service_tpu.ops import lightning as lightning_ops
 from xllm_service_tpu.ops import mamba as mamba_ops
 from xllm_service_tpu.ops import moe as moe_ops
 from xllm_service_tpu.ops import rope as rope_ops
+from xllm_service_tpu.ops import sparse_attention as sparse_ops
 from xllm_service_tpu.ops.attention import (
     attention_routes as pool_routes,
     mixed_attention,
@@ -148,11 +174,13 @@ Params = Dict
 NUM_CACHES = 2  # K and V (each paired with a state pool on the carry)
 QUANTIZABLE_WEIGHT_LEAVES = llama.QUANTIZABLE_WEIGHT_LEAVES + ("w_in", "w_out", "w_ogate")
 # a layer kind's stack of the parameter tree
-MIXER_STACKS = {"mamba": "mamba", "kda": "kda", "attention": "attn", "window": "attn_w"}
+MIXER_STACKS = {"mamba": "mamba", "kda": "kda", "attention": "attn", "window": "attn_w",
+                "lightning": "lightning"}
 # the device region of a layer kind's residual add (obs.spans.DEVICE_REGIONS);
 # the parallel kind's ONE add of both branches is the state mixer's
 MIXER_REGIONS = {"mamba": "state_mixer", "kda": "state_mixer", "attention": "attn_proj",
-                 "window": "attn_proj", PARALLEL_KIND: "state_mixer"}
+                 "window": "attn_proj", PARALLEL_KIND: "state_mixer",
+                 "lightning": "state_mixer", "sparse": "attn_proj"}
 L2_EPS = 1e-6  # under the root of KDA's q and k normalisation
 
 
@@ -205,6 +233,11 @@ def kernel_report(
     rep = full.report()
     if not window:
         rep["state"] = state_route(cfg, k_caches[1])
+        if cfg.num_sparse_layers:
+            # stage 1 is XLA on every platform; stage 2 is the decode
+            # launch over the selected pages, for decode rows and for a
+            # chunk's rows past dense_len (rows under it: `prefill`)
+            rep["sparse"] = f"select-xla+{rep['decode']}"
         return rep
     (w,) = window  # the decode and flash kernels under their window names,
     # their gather and blockwise twins, or the pair where a hatch split them
@@ -215,10 +248,15 @@ def kernel_report(
     return rep
 
 
-def state_shapes(cfg: ModelConfig, slots: int):
+def state_shapes(cfg: ModelConfig, slots: int, blocks: int = 0):
     """(state pool shape, convolution pool shape) over the stack's state
-    layers, in their kind's layout (ops/mamba.py, ops/kda.py)."""
-    return STATE_KINDS[cfg.state_layer_kind].shapes(cfg, slots)
+    layers, in their kind's layout (ops/mamba.py, ops/kda.py); a stack
+    with sparse layers has no convolution and holds the compressed-key
+    pool of `blocks` pages in its place (ops/sparse_attention.py)."""
+    state, conv = STATE_KINDS[cfg.state_layer_kind].shapes(cfg, slots)
+    if cfg.num_sparse_layers:
+        conv = sparse_ops.pool_shape(cfg, blocks)
+    return state, conv
 
 
 def state_route(cfg: ModelConfig, state) -> str:
@@ -280,6 +318,12 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
     if PARALLEL_KIND in cfg.layer_types and set(cfg.layer_types) != {PARALLEL_KIND}:
         raise ValueError("hybrid stack: the parallel kind's two mixer stacks are indexed "
                          "by the layer, so every layer of the stack is of that kind")
+    if cfg.num_sparse_layers:
+        if cfg.mixer_layers("attention") != cfg.num_sparse_layers or cfg.state_layer_kind != "lightning":
+            raise ValueError("hybrid stack: sparse layers index the attention stack and keep "
+                             "their compressed keys in the convolution pool's place, so they "
+                             "stand beside lightning layers alone")
+        sparse_ops.selection_of(cfg)
     E, L, kd = cfg.hidden_size, cfg.num_layers, cfg.first_k_dense_replace
     Ls, La, Lw = cfg.num_state_layers, cfg.num_attention_layers, cfg.num_window_layers
     Hq, Hkv, D, Dv = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim, cfg.value_head_dim
@@ -330,6 +374,8 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
     params["attn"] = gqa(La, Hkv)
     if cfg.attn_gate:
         params["attn"]["w_ogate"] = w((La, E, Hq * D), E)
+    if cfg.num_sparse_layers and cfg.qk_norm:
+        params["attn"].update({"q_norm": ones((La, D)), "k_norm": ones((La, D))})
     if Lw:
         params["attn_w"] = gqa(Lw, cfg.window_kv_heads)
         if cfg.window_sink:
@@ -604,6 +650,9 @@ def _qkv(lp, cfg: ModelConfig, h, kind="attention", positions=None):
     q = fence(jnp.einsum("te,eh->th", h, wt(lp["wq"]))).reshape(T, cfg.num_heads, cfg.head_dim)
     k = fence(jnp.einsum("te,eh->th", h, wt(lp["wk"]))).reshape(T, Hkv, cfg.head_dim)
     v = fence(jnp.einsum("te,eh->th", h, wt(lp["wv"]))).reshape(T, Hkv, cfg.value_head_dim)
+    if "q_norm" in lp:  # QK-norm: a sparse layer's (the cache holds the normed key)
+        q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
+        k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
     if cfg.rotary_dim:
         theta = cfg.window_rope_theta if window else cfg.rope_theta
         q = rope_ops.apply_partial_rope(q, positions, theta, cfg.rotary_dim)
@@ -611,6 +660,12 @@ def _qkv(lp, cfg: ModelConfig, h, kind="attention", positions=None):
     if cfg.attn_value_scale != 1.0:
         v = (v.astype(jnp.float32) * cfg.attn_value_scale).astype(v.dtype)
     return q, k, v
+
+
+def _row_positions(dec: Optional[_Dec], pf: Optional[_Pf]):
+    """The positions of a step's flat rows: decode rows, then the chunks'."""
+    positions = [half.positions for half in (dec, pf) if half is not None]
+    return jnp.concatenate(positions) if len(positions) > 1 else positions[0]
 
 
 def _pad_lanes(x, lanes: int):
@@ -649,10 +704,7 @@ def _attn_mixer(lp, cfg: ModelConfig, h, a, K, V, dec: Optional[_Dec],
     else:
         at = lambda half: (half.tables, half.plan)
         kw = {}
-    positions = None
-    if cfg.rotary_dim:
-        positions = [half.positions for half in (dec, pf) if half is not None]
-        positions = jnp.concatenate(positions) if len(positions) > 1 else positions[0]
+    positions = _row_positions(dec, pf) if cfg.rotary_dim else None
     q, k, v = _qkv(lp, cfg, h, kind, positions)
     lanes = kvc.raw(K).shape[-1]  # the pool's key row: key_lanes(cfg)
     q, k = _pad_lanes(q, lanes), _pad_lanes(k, lanes)
@@ -682,6 +734,129 @@ def _attn_mixer(lp, cfg: ModelConfig, h, a, K, V, dec: Optional[_Dec],
     with region("attn_proj"):
         flat = _gated(lp, cfg, h, o.reshape(o.shape[0], -1)).astype(h.dtype)
         return jnp.einsum("th,he->te", flat, wt(lp["wo"])), K, V
+
+
+def _sparse_mixer(lp, cfg: ModelConfig, h, a, K, V, CK, dec: Optional[_Dec],
+                  pf: Optional[_Pf]):
+    """The block-sparse GQA mixer over flat rows h [T, E], sparse layer
+    `a`: every row's K/V lands in the stacks, then the compressed keys
+    those rows complete land in CK, then each half attends: a decode row
+    and a chunk's row past `sparse_dense_len` over the blocks its query
+    group selects (ops/sparse_attention.py), a chunk's rows under it
+    through the flash launch as a full layer's do. The switch is per ROW:
+    a token's output does not depend on how its request was cut up.
+    Returns (out [T, E], K', V', CK')."""
+    R = dec.R if dec is not None else 0
+    scale, sel = _scale(cfg), sparse_ops.selection_of(cfg)
+    q, k, v = _qkv(lp, cfg, h)
+    outs = []
+    if dec is not None:
+        K, V = kv_write_ops.write_kv(K, V, dec.plan, k[:R], v[:R], a)
+        CK = sparse_ops.write_compressed(
+            CK, K, a, dec.tables, dec.positions, dec.active.astype(jnp.int32), 1, sel
+        )
+    if pf is not None:
+        K, V = kv_write_ops.write_kv(K, V, pf.plan, k[R:], v[R:], a)
+        CK = sparse_ops.write_compressed(CK, K, a, pf.tables, pf.start, pf.length, pf.Lpad, sel)
+    if dec is not None:
+        outs.append(sparse_ops.decode_attention(
+            q[:R], K, V, CK, a, dec.tables, dec.positions, dec.active, scale, sel,
+            use_kernel=dec.use_kernel,
+        ))
+    if pf is not None:
+        q_pf = q[R:].reshape(pf.P, pf.Lpad, *q.shape[1:])
+        pos = pf.positions.reshape(pf.P, pf.Lpad)
+        valid = jnp.arange(pf.Lpad, dtype=jnp.int32)[None, :] < pf.length[:, None]
+        past = valid & (pos + 1 > sel.dense_len)
+        blank = jnp.zeros((*q_pf.shape[:3], kvc.raw(V).shape[-1]), q.dtype)
+        # a chunk wholly on one side of dense_len runs one of the two
+        o_pf = jax.lax.cond(
+            jnp.any(valid & ~past),
+            lambda: prefill_attention(
+                q_pf, K, V, pf.tables, pf.start, pf.length, scale, layer=a,
+            ).astype(q.dtype),
+            lambda: blank,
+        )
+        for p in range(pf.P):
+            o_sel = jax.lax.cond(
+                jnp.any(past[p]),
+                lambda p=p: sparse_ops.chunk_selected_attention(
+                    q_pf[p], K, V, CK, a, pf.tables[p], pos[p], past[p], scale, sel,
+                    use_kernel=dec.use_kernel if dec is not None else None,
+                ).astype(q.dtype),
+                lambda p=p: blank[p],
+            )
+            o_pf = o_pf.at[p].set(jnp.where(past[p][:, None, None], o_sel, o_pf[p]))
+        outs.append(o_pf.reshape(-1, *o_pf.shape[2:]))
+    o = jnp.concatenate(outs, axis=0) if len(outs) > 1 else outs[0]
+    with region("attn_proj"):
+        flat = _gated(lp, cfg, h, o.reshape(o.shape[0], -1)).astype(h.dtype)
+        return jnp.einsum("th,he->te", flat, wt(lp["wo"])), K, V, CK
+
+
+def lightning_layer_ids(cfg: ModelConfig) -> Tuple[int, ...]:
+    """The PUBLISHED index of every lightning layer of the stack."""
+    ids = cfg.layer_ids or tuple(range(cfg.num_layers))
+    return tuple(i for i, kind in zip(ids, cfg.layer_types) if kind == "lightning")
+
+
+@region("state_mixer")
+def _lightning_mixer(lp, cfg: ModelConfig, h, m, S, conv, dec: Optional[_Dec],
+                     pf: Optional[_Pf]):
+    """The Lightning mixer over flat rows h [T, E] (decode rows first, then
+    the chunks' rows), lightning layer `m`: QK-norm, rotary on every lane
+    of q and k, the decayed state (ops/lightning.py), the output norm
+    over a head's lanes and the per-lane gate. `conv` (the stack's fourth
+    pool: a sparse layer's compressed keys) passes through. Returns
+    (out [T, E], S', conv)."""
+    T = h.shape[0]
+    H, d = cfg.lightning_n_heads, cfg.lightning_d_head
+    f32 = jnp.float32
+    R = dec.R if dec is not None else 0
+
+    def through(name):  # fenced: the consumers are head-batched
+        return llama._plain_product(jnp.einsum(
+            "te,ef->tf", h, wt(lp[name]), preferred_element_type=f32
+        ))
+
+    positions = _row_positions(dec, pf)
+
+    def head(name, gain):  # norm over a head's lanes, then rotary
+        x = rms_norm(through(name).reshape(T, H, d), lp[gain], cfg.rms_norm_eps)
+        return rope_ops.apply_rope(x, positions, cfg.rope_theta)
+
+    q, k = head("wq", "q_norm") * d ** -0.5, head("wk", "k_norm")
+    v = through("wv").reshape(T, H, d)
+    log_lam = jnp.asarray(lightning_ops.log_decay(
+        H, lightning_layer_ids(cfg), cfg.published_layers or cfg.num_layers
+    ))[m]
+    os = []
+    if dec is not None:
+        o, S = lightning_ops.decode_update(
+            S, m, dec.active, q[:R], k[:R], v[:R], log_lam, use_kernel=dec.use_kernel
+        )
+        os.append(o)
+    if pf is not None:
+        chunks = lambda t: t[R:].reshape(pf.P, pf.Lpad, H, d)
+        o, S = lightning_ops.chunk_update(
+            S, m, pf.slots, pf.start, pf.length, chunks(q), chunks(k), chunks(v), log_lam
+        )
+        os.append(o.reshape(pf.P * pf.Lpad, H, d))
+    o = jnp.concatenate(os, axis=0) if len(os) > 1 else os[0]
+    y = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + cfg.rms_norm_eps)
+    y = jax.nn.sigmoid(through("w_ogate")) * (y.reshape(T, H * d) * lp["o_norm"])
+    wo = wt(lp["wo"])
+    return jnp.einsum("tf,fe->te", y.astype(wo.dtype), wo), S, conv
+
+
+def _lightning_stack(cfg: ModelConfig, w, ones, Ls):
+    E, H, d = cfg.hidden_size, cfg.lightning_n_heads, cfg.lightning_d_head
+    return {
+        "wq": w((Ls, E, H * d), E), "wk": w((Ls, E, H * d), E), "wv": w((Ls, E, H * d), E),
+        "w_ogate": w((Ls, E, H * d), E),
+        "q_norm": ones((Ls, d)), "k_norm": ones((Ls, d)), "o_norm": ones((Ls, H * d)),
+        "wo": w((Ls, H * d, E), H * d),
+    }
 
 
 def _mamba_stack(cfg: ModelConfig, w, ones, Ls):
@@ -757,6 +932,16 @@ STATE_KINDS = {
         lambda cfg, state: kda_ops.kernel_eligible(state),
         _kda_stack,
     ),
+    "lightning": StateKind(
+        _lightning_mixer,
+        # no convolution: the second pool is empty unless sparse layers
+        # keep their compressed keys there (state_shapes)
+        lambda cfg, slots: (lightning_ops.state_shape(
+            cfg.num_state_layers, slots, cfg.lightning_n_heads, cfg.lightning_d_head
+        ), (cfg.num_state_layers, slots, 0)),
+        lambda cfg, state: lightning_ops.kernel_eligible(state),
+        _lightning_stack,
+    ),
 }
 
 
@@ -803,6 +988,8 @@ def _run_layers(params, cfg: ModelConfig, x, k_caches, v_caches, valid,
                 y, K, V = _attn_mixer(lp, cfg, h, i, K, V, dec, pf)
             elif kind == "window":
                 y, S, conv = _attn_mixer(lp, cfg, h, i, S, conv, dec, pf, kind)
+            elif kind == "sparse":  # its compressed keys: the fourth pool
+                y, K, V, conv = _sparse_mixer(lp, cfg, h, i, K, V, conv, dec, pf)
             else:
                 y, S, conv = STATE_KINDS[kind].mixer(lp, cfg, h, i, S, conv, dec, pf)
             return y, ((K, S), (V, conv))
@@ -886,9 +1073,15 @@ def _run_layers(params, cfg: ModelConfig, x, k_caches, v_caches, valid,
 # ---------------------------------------------------------------- steps
 
 
+def _takes_positions(cfg: ModelConfig) -> bool:
+    """A mixer of the stack reads a row's position: rotary (an attention
+    kind's or a lightning layer's) or a sparse layer's selection."""
+    return bool(cfg.rotary_dim or cfg.num_sparse_layers or cfg.state_layer_kind == "lightning")
+
+
 def _dec_half(cfg: ModelConfig, k_caches, positions, tables, active, use_kernel) -> _Dec:
     more = {}
-    if cfg.rotary_dim:
+    if _takes_positions(cfg):
         more["positions"] = positions
     if cfg.num_window_layers:
         tables, tables_w = _split_windows(tables)
@@ -906,7 +1099,7 @@ def _pf_half(cfg: ModelConfig, k_caches, block_tables, start, length, P, Lpad
              ) -> Tuple[_Pf, jnp.ndarray]:
     lane = lambda: jnp.arange(Lpad, dtype=jnp.int32)[None, :]
     more = {}
-    if cfg.rotary_dim:
+    if _takes_positions(cfg):
         more["positions"] = (start[:, None] + lane()).reshape(-1)
     if cfg.num_window_layers:  # two tables and no state slot
         tables, tables_w = _split_windows(block_tables)
@@ -1009,6 +1202,12 @@ def hidden_dense(params: Params, cfg: ModelConfig, token_ids, rows_valid=None):
     token-by-token recurrence, materialised attention, the
     all-experts combine (llama._mlp): the oracle of the step programs.
     No pool, no cache, no kernel."""
+    if cfg.num_sparse_layers or cfg.state_layer_kind == "lightning":
+        raise NotImplementedError(
+            f"{cfg.name}: the dense forward (the embeddings endpoint's) has no block "
+            f"selection and no lightning state: benchmarks/families/minicpm_sala.py is "
+            f"this family's plain forward"
+        )
     B, L = token_ids.shape
     f32 = jnp.float32
     H, Pd, N, G = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state, cfg.mamba_n_groups

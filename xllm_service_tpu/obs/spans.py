@@ -103,7 +103,8 @@ DEVICE_REGIONS = (
     "norm",         # a block's pre-mixer and pre-MLP RMSNorm
     "attn_proj",    # q/k/v/o, biases, RoPE, QK-norm; MLA's down/up-projections, the absorb
     "attn",         # the paged, flash and MLA attention kernels and their plain twins
-    "cache_write",  # the write plan, kv_write_kernel, latent rows
+    "attn_select",  # a sparse layer's stage 1: the scores over compressed keys, the pooling to blocks, the top-k, the selected table
+    "cache_write",  # the write plan, kv_write_kernel, latent rows, a sparse layer's compressed keys
     "state_mixer",  # power retention; Mamba-2's convolution, scan, update, gated output; a parallel block's ONE residual add of both branches (its attention branch is attn_proj / cache_write / attn)
     "ffn",          # dense gate/up/down, the shared experts
     "moe_route",    # router, top-k, grouping, the counts output

@@ -179,6 +179,10 @@ def _hybrid_param_shardings(cfg: ModelConfig, ns) -> Dict[str, Any]:
             **rep(("wq", "wk", "wv", "wo", "conv_w", "w_f1", "w_f2", "w_beta",
                    "w_g1", "w_g2"), 3),
         },
+        "lightning": {
+            **rep(("q_norm", "k_norm", "o_norm"), 2),
+            **rep(("wq", "wk", "wv", "w_ogate", "wo"), 3),
+        },
     }
     tree = {
         "embed": ns(None, None),
@@ -191,7 +195,10 @@ def _hybrid_param_shardings(cfg: ModelConfig, ns) -> Dict[str, Any]:
             # the held experts' matrices, or a dense MLP in every layer
             **rep(("w_gate", "w_up", "w_down"), 4 if cfg.is_moe else 3),
         },
-        "attn": rep(("wq", "wk", "wv", "wo") + (("w_ogate",) if cfg.attn_gate else ()), 3),
+        "attn": {
+            **rep(("wq", "wk", "wv", "wo") + (("w_ogate",) if cfg.attn_gate else ()), 3),
+            **rep(("q_norm", "k_norm") if cfg.num_sparse_layers and cfg.qk_norm else (), 2),
+        },
     }
     if cfg.state_layer_kind:
         tree[cfg.state_layer_kind] = state_stacks[cfg.state_layer_kind]

@@ -335,6 +335,14 @@ class WindowFamilyUnsupported(NotImplementedError):
     name at engine build or at the request, never served wrongly."""
 
 
+class SparseFamilyUnsupported(NotImplementedError):
+    """A feature that is not built for a family whose attention layers
+    SELECT the pages a query reads (ops/sparse_attention.py): its sequences
+    own rows of a compressed-key pool beside their K/V blocks, and a state
+    slot. Raised by name at engine build or at the request, never served
+    wrongly."""
+
+
 class WindowBlockManager(_NoPrefixReuse, BlockManager):
     """Blocks for a family whose attention layers are of two kinds
     (models/granite.py): the FULL layers' K/V in this manager's pool,

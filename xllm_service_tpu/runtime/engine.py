@@ -779,6 +779,38 @@ class InferenceEngine:
                 size.labels(pool="window").set_function(
                     lambda: self.executor._window_block_bytes()
                 )
+        # A family whose attention layers SELECT their pages
+        # (ops/sparse_attention.py): token rows of a sparse layer by the
+        # path their position sends them down, and for the selected rows
+        # the pages read against the pages their contexts hold. Booked on
+        # the host from the positions every dispatch already has.
+        ecfg = getattr(self.executor, "cfg", None)
+        self._sparse = None
+        if getattr(ecfg, "num_sparse_layers", 0):
+            self._sparse = (
+                ecfg.sparse_dense_len, ecfg.sparse_topk, ecfg.sparse_block_size
+            )
+        self._sparse_counts = {
+            "rows_selected": 0, "rows_dense": 0,
+            "pages_selected": 0, "pages_live": 0,
+        }
+        for key, name, text in (
+            ("rows_selected", "xllm_engine_attn_rows_selected_total",
+             "Token rows of a sparse layer past sparse_dense_len: they "
+             "attend the pages their query group selects"),
+            ("rows_dense", "xllm_engine_attn_rows_dense_total",
+             "Token rows of a sparse layer at or under sparse_dense_len: "
+             "they attend their whole context"),
+            ("pages_selected", "xllm_engine_sparse_pages_selected_total",
+             "Pages the selected rows read, a KV head and sparse layer "
+             "(sparse_topk a row)"),
+            ("pages_live", "xllm_engine_sparse_pages_live_total",
+             "Pages the selected rows' contexts hold (what a dense "
+             "launch would read for them)"),
+        ):
+            self.metrics.counter(name, text).set_function(
+                lambda key=key: self._sparse_counts[key]
+            )
         self.metrics.counter(
             "xllm_engine_window_blocks_freed_total",
             "Window-pool blocks freed behind a running sequence (every "
@@ -1052,6 +1084,20 @@ class InferenceEngine:
         if self.state_family:
             self._m_state_in_use.observe(self.R - len(self._free_slots))
 
+    def _book_sparse_rows(self, positions: np.ndarray) -> None:
+        """Rows a dispatch sends through the sparse layers, by path."""
+        if self._sparse is None or not len(positions):
+            return
+        dense_len, topk, bs = self._sparse
+        ctx = np.asarray(positions, np.int64) + 1
+        past = ctx > dense_len
+        n = int(past.sum())
+        c = self._sparse_counts
+        c["rows_selected"] += n
+        c["rows_dense"] += len(ctx) - n
+        c["pages_selected"] += n * topk
+        c["pages_live"] += int((-(-ctx[past] // bs)).sum())
+
     def _no_state_handoff(self) -> None:
         if self.state_family or self.window_family:
             self.executor._no_state_handoff()  # raises, by name
@@ -1064,6 +1110,7 @@ class InferenceEngine:
         the blocks this step's queries can see (`_slide_window`)."""
         table = np.zeros((self._block_tables.shape[1],), np.int32)
         table[: len(seq.block_ids)] = seq.block_ids
+        self._book_sparse_rows(np.arange(first, end))
         if self.window_family:
             self._slide_window(seq, first, end, table[self.max_blocks:])
         return table
@@ -1669,6 +1716,7 @@ class InferenceEngine:
         variable and re-derived at drain)."""
         nactive = int(can.sum())
         total_ctx = int(self._ps_positions[can].sum()) + nactive
+        self._book_sparse_rows(self._ps_positions[can])
         snapshot = {}
         for slot in np.nonzero(can)[0]:
             seq = self._running[int(slot)]

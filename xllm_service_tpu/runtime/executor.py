@@ -46,6 +46,7 @@ from xllm_service_tpu.obs import spans as obs_spans
 from xllm_service_tpu.runtime import compile_cache as compile_cache_mod
 from xllm_service_tpu.runtime.block_manager import (
     StateFamilyUnsupported,
+    SparseFamilyUnsupported,
     WindowFamilyUnsupported,
 )
 from xllm_service_tpu import models
@@ -575,6 +576,8 @@ class ModelExecutor:
         self.has_paged_cache = self.cfg.has_paged_cache
         self.slot_column = self.has_state_pool and self.has_paged_cache
         self.state_pool_bytes = 0
+        if self.cfg.num_sparse_layers:
+            self._refuse_for_sparse_family(tp, ep)
         if self.has_state_pool:
             self._refuse_for_state_family(tp, ep)
             self.state_pool_bytes = self._check_state_pool()
@@ -681,7 +684,13 @@ class ModelExecutor:
                 # and the v slot: alone (retention: S and its normaliser
                 # z), or each as the second of a pair behind the paged K
                 # and V stacks of the attention layers (a hybrid stack).
-                shapes = self.model_mod.state_shapes(self.cfg, self.R)
+                # (a stack with sparse layers: the compressed-key pool
+                # rides the second state array's place, a page a block
+                # of the K/V pool)
+                shapes = self.model_mod.state_shapes(
+                    self.cfg, self.R,
+                    *([self.num_blocks] if self.cfg.num_sparse_layers else []),
+                )
                 rep_sh = NamedSharding(self.mesh, P())
 
                 def one(sh):
@@ -1005,6 +1014,40 @@ class ModelExecutor:
                 f"{self.cfg.name} (random weights only)"
             )
 
+    def _refuse_for_sparse_family(self, tp: int, ep: int) -> None:
+        """What is not built for a family with sparse layers, by name, at
+        build (before the state family's own refusals, which name the
+        state slot alone)."""
+        e, name = self.engine_cfg, self.cfg.name
+        if tp > 1 or ep > 1 or e.sp_size > 1 or e.dp_size > 1:
+            raise SparseFamilyUnsupported(
+                f"tp_size/ep_size/sp_size/dp_size > 1: the selected-page "
+                f"path of {name} reads the pool a KV head a row "
+                f"({self.cfg.num_kv_heads} KV heads: a shard count that "
+                f"does not divide them has no launch, and the selection "
+                f"and the compressed-key pool a shard are not built)"
+            )
+        if e.block_size != self.cfg.sparse_block_size:
+            raise SparseFamilyUnsupported(
+                f"block_size={e.block_size}: the selection's unit is the "
+                f"pool's page, sparse_block_size="
+                f"{self.cfg.sparse_block_size} tokens (a page of two "
+                f"selection blocks is not built)"
+            )
+        if e.speculative_tokens > 0:
+            raise SparseFamilyUnsupported(
+                "speculative_tokens > 0: the verify shapes have no "
+                "selected-page launch (each draft position selects its "
+                "own blocks) and the state slot no roll-back"
+            )
+        if e.num_host_blocks > 0 or e.num_ssd_blocks > 0:
+            raise SparseFamilyUnsupported(
+                "prefix cache: num_host_blocks/num_ssd_blocks > 0 asks for "
+                "the prefix cache's host tiers; a reused prefix would need "
+                "its compressed-key rows and a state snapshot at the "
+                "boundary, which are not built"
+            )
+
     def _refuse_for_window_family(self, tp: int, ep: int) -> None:
         """What is not built for a family with window layers, by name, at
         build."""
@@ -1068,6 +1111,16 @@ class ModelExecutor:
         if block_tables.shape[1] <= M:  # a warm-up's table: no block is live
             window = np.zeros_like(full)
         return np.concatenate([full, window], axis=1)
+
+    @property
+    def compressed_block_bytes(self) -> int:
+        """Bytes a block of the paged pool brings in the compressed-key
+        pool of a stack with sparse layers (0 elsewhere): the keys that
+        start in it, over the sparse layers, in `state_dtype`."""
+        if not self.cfg.num_sparse_layers:
+            return 0
+        _, ck = self.model_mod.state_shapes(self.cfg, 1, 1)
+        return int(np.prod(ck)) * jnp.dtype(self.state_dtype).itemsize
 
     def _check_state_pool(self) -> int:
         """Bytes of the state pool: `max_running_requests` slots, sized by
@@ -1184,7 +1237,7 @@ class ModelExecutor:
             * heads_per_dev
             * cache_dim
             * kv_elem_bytes
-        )
+        ) + self.compressed_block_bytes
         n = int(budget // block_bytes)
         if n < 16:
             import warnings
@@ -2981,6 +3034,13 @@ class ModelExecutor:
         return out
 
     def _no_state_handoff(self) -> None:
+        if self.cfg.num_sparse_layers:
+            raise SparseFamilyUnsupported(
+                "PD handoff: a sequence of a family with sparse layers "
+                "holds K/V blocks, their compressed-key rows and a state "
+                "slot; the export and import of the second and third pool "
+                "(runtime/transfer.py) are not built"
+            )
         if self.window_tables:
             raise WindowFamilyUnsupported(
                 "PD handoff: a sequence of a window family holds blocks of "
