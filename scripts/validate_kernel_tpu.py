@@ -958,6 +958,16 @@ def run_sparse_case(R, Hq, Hkv, D, BS, CB, L, N, ctx_lo, ctx_hi, chunk, chunk_st
         xs[0], CK_, layer, tables[0], xs[1], xs[2], scale, sel, sel.topk)[0],
         (q_.reshape(-1, n, Hq, D), pos.reshape(-1, n), live.reshape(-1, n))))
     t_c1 = bench(lambda: s1(qc, CK), iters=4)
+    # the selection alone (scores made once) at the three widths stage 1 switches between:
+    # 16 tiles of ROW_TILE rows, each tile the last rows the width reaches
+    pick = jax.jit(lambda s, p: jax.lax.map(lambda s1_: sp.select_blocks(s1_, p, sel), s))
+    t_sel = {}
+    for cols in (CB // 4, CB // 2, CB):
+        sc = jax.random.uniform(ks[4], (16, n, Hkv, cols), jnp.float32)
+        p_t = cols * BS - n + jnp.arange(n, dtype=jnp.int32)
+        t_sel[cols] = bench(lambda: pick(sc, p_t), iters=8) / 16
+    print("SPARSE-SELECT rows=%d x %d KV heads, us a tile: " % (n, Hkv)
+          + " ".join(f"cols={c}:{t*1e6:7.1f}" for c, t in t_sel.items()), flush=True)
     wr = jax.jit(lambda ck, K_: sp.write_compressed(
         ck, paged(K_), layer, tables[:1], jnp.array([chunk_start]), jnp.array([chunk]), chunk,
         sel), donate_argnums=0)

@@ -264,6 +264,69 @@ def test_at_topk_blocks_or_fewer_the_selection_is_the_whole_context(seeded):
                                fam.sparse_mixer(u, lp, short, always_dense=True), atol=1e-6)
 
 
+PUBLISHED = sparse_ops.Selection(64, 64, 32, 16, 1, 32, 8192)
+SCORES = {
+    "uniform": lambda k, s: jax.random.uniform(k, s) * 16,
+    "nine-tenths-zeros": lambda k, s: jnp.where(
+        jax.random.uniform(jax.random.fold_in(k, 1), s) < 0.9, 0.0, jax.random.uniform(k, s)),
+    "whole-numbers": lambda k, s: jnp.round(jax.random.uniform(k, s) * 4),
+    "all-equal": lambda k, s: jnp.full(s, 0.25),
+}
+# the first of the 64 rows' positions (65 tokens apart: a block a row), by the table's columns NB
+ROWS_AT = {
+    "under-dense-len": lambda NB: PUBLISHED.dense_len - 64 * 65,
+    "topk-blocks-or-fewer": lambda NB: 0,
+    "past-dense-len": lambda NB: max(NB * PUBLISHED.block, PUBLISHED.dense_len + 64 * 65) - 64 * 65,
+}
+
+
+@pytest.mark.parametrize("where", sorted(ROWS_AT))
+@pytest.mark.parametrize("scores", sorted(SCORES))
+@pytest.mark.parametrize("NB", [32, 256, 512, 1024], ids=lambda n: f"{n}-columns")
+def test_the_selection_is_top_k_and_a_sort_of_its_indices_bit_for_bit(NB, scores, where):
+    """The threshold and the running counts against the sorts they
+    replace, over the same ranked scores: ties (a softmax that underflowed
+    to exact zeros, scores that repeat) go to the lower index as
+    `lax.top_k`'s do, rows under dense_len included."""
+    sel, rows = PUBLISHED, 64
+    pos = ROWS_AT[where](NB) + 65 * jnp.arange(rows, dtype=jnp.int32)
+    s = SCORES[scores](jax.random.key(NB), (rows, 2, NB)).astype(jnp.float32)
+    ranked = sparse_ops.ranked_scores(s, pos, sel)
+    assert ranked.shape[-1] == max(NB, sel.topk)
+    want = jnp.sort(jax.lax.top_k(ranked, sel.topk)[1].astype(jnp.int32), axis=-1)
+    got = jax.jit(sparse_ops.select_blocks, static_argnums=2)(s, pos, sel)
+    assert got.dtype == jnp.int32 and got.shape == (rows, 2, sel.topk)
+    np.testing.assert_array_equal(got, want)
+
+
+def _primitives(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _primitives(sub)
+
+
+def test_stage_one_holds_no_sort():
+    """Neither the selection nor the whole of stage 1 (its three width
+    branches, the rolled rounds) sorts: on the chip `lax.top_k` of 64 in
+    1,024 columns is a full sort of every (row, KV head)."""
+    sel = PUBLISHED
+    s, pos = jnp.zeros((64, 2, 1024)), jnp.arange(64, dtype=jnp.int32)
+    CK = jnp.zeros((1, 8, 2 * sel.per_block, 128))
+    q, table = jnp.zeros((64, 32, 128)), jnp.zeros((1024,), jnp.int32)
+    for closed in (
+        jax.make_jaxpr(lambda s_, p_: sparse_ops.select_blocks(s_, p_, sel))(s, pos),
+        jax.make_jaxpr(lambda q_, CK_: sparse_ops.virtual_tables(
+            q_, CK_, 0, table, pos, pos >= 0, 0.1, sel, sel.topk))(q, CK),
+    ):
+        seen = set(_primitives(closed.jaxpr))
+        assert "scan" in seen and "dot_general" in seen  # the rolled rounds; the running counts
+        assert not {p for p in seen if "sort" in p or "top_k" in p}, seen
+
+
 # ------------------------------------------------- the compressed-key pool
 
 

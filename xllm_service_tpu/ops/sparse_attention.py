@@ -177,19 +177,77 @@ def block_scores(q, ck, positions, scale: float, sel: Selection):
     )
 
 
+def ranked_scores(scores, positions, sel: Selection):
+    """What the selection ranks: scores [T, Hkv, NB], positions [T] ->
+    [T, Hkv, max(NB, topk)] float32: a forced block FORCED, a block past
+    the row's own -1, the others their score."""
+    if scores.shape[-1] < sel.topk:  # a table narrower than the selection: no row is past dense_len
+        scores = jnp.pad(scores, ((0, 0), (0, 0), (0, sel.topk - scores.shape[-1])))
+    b = jnp.arange(scores.shape[-1], dtype=jnp.int32)[None, None, :]
+    own = (positions // sel.block)[:, None, None]
+    forced = (b < sel.init_blocks) | ((b > own - sel.local_blocks) & (b <= own))
+    return jnp.where(forced, FORCED, jnp.where(b > own, -1.0, scores))
+
+
+def _ordered_keys(x):
+    """float32 -> uint32 in the same order. Zeros of either sign and
+    subnormals are one key: the chip's compares flush them too."""
+    x = jnp.where(jnp.abs(x) < jnp.finfo(jnp.float32).tiny, 0.0, x)
+    i = jax.lax.bitcast_convert_type(x, jnp.int32)
+    i = jnp.where(i < 0, i ^ jnp.int32(0x7FFFFFFF), i)  # signed order = float order
+    return jax.lax.bitcast_convert_type(i, jnp.uint32) ^ jnp.uint32(0x80000000)
+
+
+def _running_count(mask):
+    """Inclusive running count of mask [NB, R] down its columns, int32:
+    a triangular matmul of zeros and ones (exact in bfloat16 x bfloat16
+    -> float32 up to 2^24), so the MXU does it and no scan stands."""
+    n = mask.shape[0]
+    i = jnp.arange(n, dtype=jnp.int32)
+    upto = (i[:, None] >= i[None, :]).astype(jnp.bfloat16)  # [b, a]: a <= b
+    return jnp.dot(upto, mask.astype(jnp.bfloat16),
+                   preferred_element_type=jnp.float32).astype(jnp.int32)
+
+
+def top_columns(ranked, topk: int):
+    """The `topk` largest columns of each row of ranked [..., NB] float32
+    (NB >= topk), ascending, ties to the LOWER index: `lax.top_k` and a
+    sort of its indices, bit for bit, without either sort.
+
+    The columns ride the MAJOR axis ([NB, R]: a count over them is vreg
+    adds, a running count one matmul). (1) The threshold: the key of the
+    topk-th largest, bit by bit from the top, 32 rounds of "how many
+    columns are at or above the trial" in a ROLLED loop (unrolled it
+    would cost every process its compile). (2) The ties: every column
+    above the threshold is in, and of the columns AT it the first
+    `topk - above`: a picked column's rank is its running count of
+    `above` plus its running count of `equal` capped at that need.
+    (3) The compaction: entry r of the table is the number of columns
+    whose inclusive rank is <= r (the rank is monotone, so that is the
+    index of the r-th picked): one compare-and-count, fused."""
+    lead, NB = ranked.shape[:-1], ranked.shape[-1]
+    u = _ordered_keys(ranked.reshape(-1, NB)).T  # [NB, R]
+    top = jnp.uint32(0x80000000)
+
+    def round_(i, t):
+        trial = t | (top >> i.astype(jnp.uint32))
+        n = jnp.sum((u >= trial).astype(jnp.int32), axis=0, keepdims=True)
+        return jnp.where(n >= topk, trial, t)
+
+    t = jax.lax.fori_loop(0, 32, round_, jnp.zeros((1, u.shape[1]), jnp.uint32))
+    above, equal = u > t, u == t
+    need = topk - jnp.sum(above.astype(jnp.int32), axis=0, keepdims=True)
+    rank = _running_count(above) + jnp.minimum(_running_count(equal), need)  # [NB, R]
+    r = jnp.arange(topk, dtype=jnp.int32)[:, None, None]
+    table = jnp.sum((rank[None] <= r).astype(jnp.int32), axis=1)  # [topk, R]
+    return table.T.reshape(*lead, topk)
+
+
 def select_blocks(scores, positions, sel: Selection):
     """The `topk` LOGICAL blocks of each (row, KV head), ascending (the
     row's own block last): scores [T, Hkv, NB], positions [T] -> [T, Hkv,
     topk] int32. Only rows past dense_len are meaningful."""
-    if scores.shape[-1] < sel.topk:  # a table narrower than the selection: no row is past dense_len
-        scores = jnp.pad(scores, ((0, 0), (0, 0), (0, sel.topk - scores.shape[-1])))
-    NB = scores.shape[-1]
-    b = jnp.arange(NB, dtype=jnp.int32)[None, None, :]
-    own = (positions // sel.block)[:, None, None]
-    forced = (b < sel.init_blocks) | ((b > own - sel.local_blocks) & (b <= own))
-    ranked = jnp.where(forced, FORCED, jnp.where(b > own, -1.0, scores))
-    _, idx = jax.lax.top_k(ranked, sel.topk)
-    return jnp.sort(idx.astype(jnp.int32), axis=-1)
+    return top_columns(ranked_scores(scores, positions, sel), sel.topk)
 
 
 @region("attn_select")
