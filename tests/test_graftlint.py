@@ -540,7 +540,10 @@ class TestSpanStages:
          "fetch"),
         ('with region("ffn"):\n    pass\n@obs_spans.region("mlp")\ndef f():\n    pass\n',
          "mlp"),
-    ], ids=["engine-phase", "executor-leaf", "device-region"])
+        ('with startup_phase("pools"):\n    pass\n'
+         '@obs_startup.startup_phase("warmup")\ndef f():\n    pass\n',
+         "warmup"),
+    ], ids=["engine-phase", "executor-leaf", "device-region", "startup-phase"])
     def test_off_vocabulary_phase_or_leaf_trips(self, src, bad):
         fs = run_one(self._pass(), src)
         assert len(fs) == 1 and fs[0].line in (2, 3)
@@ -553,6 +556,8 @@ class TestSpanStages:
             'with phase(name):\n    pass\n'
             'def phase(self, name):\n    pass\n'
             'window_region("nowhere")\n'
+            'my_startup_phase("never")\n'
+            'with startup_phase(name):\n    pass\n'
             'with region(MIXER_REGIONS[kind]):\n    pass\n'
         )
         assert run_one(self._pass(), src) == []

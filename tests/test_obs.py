@@ -514,6 +514,87 @@ class TestThreadedPlaneStats:
             store.close()
 
 
+class TestStartupPhases:
+    """obs/startup.py `startup_phase` (docs/OBSERVABILITY.md "Start-up
+    timeline"): the engine loop's scope helper over STARTUP_PHASES."""
+
+    class TickClock:
+        def __init__(self):
+            self.t = 0
+
+        def __call__(self):
+            self.t += 1
+            return float(self.t)
+
+    def test_scopes_are_exclusive_and_sum_to_the_outermost(self):
+        from xllm_service_tpu.obs import STARTUP_PHASES
+        from xllm_service_tpu.obs.startup import StartupTimeline
+
+        clock = self.TickClock()
+        tl = StartupTimeline(clock=clock)
+
+        @tl.phase("engine")
+        def build_engine():
+            with tl.phase("params"):
+                with tl.phase("pools"):
+                    pass
+                with tl.phase("programs"):
+                    pass
+            return "built"
+
+        with tl.phase("instance"):
+            opened = clock.t  # the outermost scope's own reading
+            assert build_engine() == "built"
+        assert clock.t == 10  # every push and pop read the clock once
+        assert tl._thread_phases()._stack == []
+        # exclusive: an inner scope suspends the outer, so the phases' own
+        # seconds add up to the wall time under the outermost scope
+        assert sum(tl.phase_seconds.values()) == clock.t - opened
+        assert all(tl.phase_seconds[p] > 0 for p in STARTUP_PHASES)
+        assert 1 <= len(STARTUP_PHASES) <= 6
+
+    def test_a_literal_outside_the_vocabulary_is_refused(self):
+        from xllm_service_tpu.obs import startup_phase
+
+        with pytest.raises(ValueError):
+            startup_phase("warmup")
+
+    def test_each_thread_has_its_own_scopes(self):
+        import threading
+
+        from xllm_service_tpu.obs.startup import StartupTimeline
+
+        tl = StartupTimeline()
+        inside, release = threading.Event(), threading.Event()
+
+        def other():
+            with tl.phase("pools"):
+                inside.set()
+                assert release.wait(10)
+
+        t = threading.Thread(target=other)
+        t.start()
+        assert inside.wait(10)
+        with tl.phase("params"):
+            assert [n for n, _ in tl._thread_phases()._stack] == ["params"]
+        release.set()
+        t.join(10)
+        assert not t.is_alive()
+        assert tl.phase_seconds["params"] > 0 and tl.phase_seconds["pools"] > 0
+
+    def test_an_instance_over_a_fake_engine_counts_its_start(self):
+        from xllm_service_tpu.obs.startup import TIMELINE
+
+        before = TIMELINE.phase_seconds["instance"]
+        inst = InstanceServer(
+            EngineConfig(model="fake", instance_name="startup0"),
+            engine=FakeEngine(),
+        )
+        inst.start()
+        inst.stop()
+        assert TIMELINE.phase_seconds["instance"] > before
+
+
 class TestMetricNameLint:
     def test_lint_clean(self, capsys):
         sys.path.insert(

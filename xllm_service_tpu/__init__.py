@@ -16,4 +16,9 @@ Layering follows SURVEY.md; reference file:line citations appear in each
 module's docstring.
 """
 
+import time
+
 __version__ = "0.1.0"
+# When this process first imported the package: where
+# `xllm_engine_first_step_seconds` counts from (obs/startup.py).
+IMPORTED_AT = time.monotonic()

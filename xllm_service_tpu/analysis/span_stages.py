@@ -1,6 +1,6 @@
 """Span-stages pass: distributed-tracing vocabulary + plane coverage.
 
-Four layers, mirroring the fault-points registry idiom
+Five layers, mirroring the fault-points registry idiom
 (docs/OBSERVABILITY.md "Distributed tracing", "Engine step timeline"):
 
 * VOCABULARY — scan the package plus the bench entry points for every
@@ -17,6 +17,11 @@ Four layers, mirroring the fault-points registry idiom
   (`_leaf(...)`) in `obs.spans.EXECUTOR_LEAVES`: a phase outside the
   vocabulary has no `xllm_engine_loop_seconds_total` child, and the
   benchmark's readers key on the names.
+
+* START-UP PHASES — every literal handed to `startup_phase(...)`
+  (obs/startup.py, docs/OBSERVABILITY.md "Start-up timeline") must be in
+  `obs.spans.STARTUP_PHASES`: the helper refuses another name only when
+  the line runs, and a start is the one path a test may never take.
 
 * DEVICE REGIONS — every literal handed to `region(...)` (a model's
   `jax.named_scope`, docs/OBSERVABILITY.md "Device regions") must be in
@@ -53,6 +58,10 @@ EMIT_RE = re.compile(
 # `_leaf("launch")`.
 PHASE_RE = re.compile(r"(?<![A-Za-z0-9_])phase\(\s*[\"']([a-z_]+)[\"']")
 LEAF_RE = re.compile(r"(?<![A-Za-z0-9_])_leaf\(\s*[\"']([a-z_]+)[\"']")
+# A start-up phase: `startup_phase("pools")`, `@startup_phase("engine")`.
+STARTUP_RE = re.compile(
+    r"(?<![A-Za-z0-9_])startup_phase\(\s*[\"']([a-z_]+)[\"']"
+)
 # A device region: `region("ffn")`, `@obs_spans.region("sample")`.
 REGION_RE = re.compile(r"(?<![A-Za-z0-9_])region\(\s*[\"']([a-z_]+)[\"']")
 
@@ -95,6 +104,7 @@ class SpanStagesPass(LintPass):
         phases: Optional[Sequence[str]] = None,
         leaves: Optional[Sequence[str]] = None,
         regions: Optional[Sequence[str]] = None,
+        startup: Optional[Sequence[str]] = None,
     ):
         # Injectable for fixture tests; the repo run uses the canonical
         # vocabularies and the plane registry above.
@@ -103,6 +113,7 @@ class SpanStagesPass(LintPass):
         self._phases = phases
         self._leaves = leaves
         self._regions = regions
+        self._startup = startup
 
     @property
     def vocab(self) -> frozenset:
@@ -120,8 +131,11 @@ class SpanStagesPass(LintPass):
 
         unread = "it would have no counter child and no reader"
         phases, leaves, regions = self._phases, self._leaves, self._regions
+        startup = self._startup
         if regions is None:
             regions = spans.DEVICE_REGIONS
+        if startup is None:
+            startup = spans.STARTUP_PHASES
         if phases is None:
             phases = spans.ENGINE_PHASES
         if leaves is None:
@@ -129,6 +143,8 @@ class SpanStagesPass(LintPass):
         return (
             (PHASE_RE, frozenset(phases), "engine phase", "ENGINE_PHASES", unread),
             (LEAF_RE, frozenset(leaves), "executor leaf", "EXECUTOR_LEAVES", unread),
+            (STARTUP_RE, frozenset(startup), "start-up phase", "STARTUP_PHASES",
+             "startup_phase() refuses it when the line runs"),
             (REGION_RE, frozenset(regions), "device region", "DEVICE_REGIONS",
              "region() refuses it when the code is traced"),
         )

@@ -42,6 +42,7 @@ from xllm_service_tpu.obs import (
     SpanRing,
     absorb_exposition,
     render_families,
+    startup_phase,
 )
 from xllm_service_tpu.service.response_handler import ResponseHandler
 from xllm_service_tpu.tokenizer import ChatTemplate, create_tokenizer
@@ -65,6 +66,7 @@ from xllm_service_tpu.api.instance_serving import ServingMixin  # noqa: E402
 class InstanceServer(
     KVHandoffMixin, FabricMixin, MultimodalMixin, ServingMixin
 ):
+    @startup_phase("instance")
     def __init__(
         self,
         engine_cfg: EngineConfig,
@@ -417,6 +419,7 @@ class InstanceServer(
             lm.kv_stall_ms_ewma = ewma
         return lm
 
+    @startup_phase("instance")
     def start(self) -> None:
         with _LOCAL_MU:
             _LOCAL_INSTANCES[self.name] = self

@@ -29,6 +29,8 @@ from xllm_service_tpu.obs.spans import (
     EXECUTOR_LEAVES,
     INSTANCE_SPAN_STAGES,
     SPAN_STAGES,
+    STARTUP_PHASES,
+    STEP_PROGRAMS,
     ClockSync,
     EnginePhases,
     annotation,
@@ -40,6 +42,7 @@ from xllm_service_tpu.obs.spans import (
     to_chrome_trace,
     trace_to_chrome,
 )
+from xllm_service_tpu.obs.startup import startup_phase
 
 __all__ = [
     "BATCH_BUCKETS",
@@ -57,10 +60,13 @@ __all__ = [
     "EXECUTOR_LEAVES",
     "INSTANCE_SPAN_STAGES",
     "SPAN_STAGES",
+    "STARTUP_PHASES",
+    "STEP_PROGRAMS",
     "ClockSync",
     "EnginePhases",
     "annotation",
     "region",
+    "startup_phase",
     "FlightRecorder",
     "SpanRing",
     "assemble_trace",

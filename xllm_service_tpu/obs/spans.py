@@ -86,6 +86,34 @@ ENGINE_PHASES = (
     "emit",          # per-token bookkeeping, stop checks, callbacks
 )
 
+# Start-up phase vocabulary (docs/OBSERVABILITY.md "Start-up timeline"):
+# what a process is building before it serves, exclusive like the engine's
+# phases and timed by the same helper (obs/startup.py `startup_phase`).
+# Each phase is a `TraceAnnotation("xllm.startup.<phase>")` and one child
+# of `xllm_engine_startup_seconds{phase=...}`. The span-stages lint pass
+# rejects a phase literal outside this tuple.
+STARTUP_PHASES = (
+    "params",    # ModelExecutor: configuration, mesh, compile cache, the parameter tree drawn or loaded, placed, quantized
+    "pools",     # pool sizing (_decide_num_blocks, window blocks); the K/V, state and compressed-key pools and the token counts allocated
+    "programs",  # the step programs' jit wrappers; warmup / prewarm_programs where warmup_on_start runs them
+    "engine",    # InferenceEngine.__init__ outside the executor: block manager, tiers, registry; start()'s thread
+    "instance",  # InstanceServer: tokenizer, HTTP plane, worker threads, until the master has registered it
+)
+
+# The executor's step programs, by the name of the function a trace and
+# JAX's compile events show (`ModelExecutor._step_jit` refuses another):
+# the `program` children of `xllm_engine_program_seconds_total` and
+# `xllm_engine_program_builds_total` (obs/startup.py); what else a process
+# compiles (the build's allocations, the weights' draw) counts as `other`.
+STEP_PROGRAMS = (
+    "_decode_impl",
+    "_prefill_impl",
+    "_mixed_impl",
+    "_verify_pipe_impl",
+    "_mixed_verify_impl",
+    "_import_impl",  # a handed-over sequence's blocks written into the pools
+)
+
 # Leaf annotations inside the executor's dispatch entry points
 # ("xllm.executor.<leaf>"): what the host does before a step launches.
 EXECUTOR_LEAVES = ("host_inputs", "launch")
@@ -163,11 +191,13 @@ class _PhaseScope:
 
 
 class EnginePhases:
-    """The engine thread's time by phase: one helper, two outputs.
+    """A thread's time by phase: one helper, two outputs.
 
-    `with phases.phase("dispatch"):` opens the phase's annotation and, on
-    leaving, adds the elapsed seconds of `clock` to `add(phase, seconds)`
-    (the engine hands in its labelled counter). Phases are EXCLUSIVE:
+    `with phases.phase("dispatch"):` opens the phase's annotation
+    (`prefix` + name) and, on leaving, adds the elapsed seconds of `clock`
+    to `add(phase, seconds)` (the engine hands in its labelled counter).
+    `names` is the vocabulary: the engine loop's by default, a start's
+    (`STARTUP_PHASES`, obs/startup.py) where given. Phases are EXCLUSIVE:
     entering one inside another suspends the outer — its interval and
     its annotation close, and both reopen when the inner one leaves — so
     the seconds of all phases sum to the time spent under the outermost
@@ -175,24 +205,29 @@ class EnginePhases:
     `annotate=False` keeps the counter and opens no annotation: the
     engine uses it around the executor call, whose own leaf annotations
     (EXECUTOR_LEAVES) then stay leaves. Single-threaded by contract (the
-    engine thread); outside any scope nothing is recorded."""
+    engine thread; one helper a thread for a start); outside any scope
+    nothing is recorded."""
 
     def __init__(
         self,
         add: Callable[[str, float], None],
         clock: Callable[[], float] = time.monotonic,
         annotate: Optional[Callable[[str], Any]] = annotation,
+        names: Tuple[str, ...] = ENGINE_PHASES,
+        prefix: str = "xllm.engine.",
     ):
         self._add = add
         self._clock = clock
         self._annotate = annotate
+        self._names = names
+        self._prefix = prefix
         self._stack: List[Tuple[str, bool]] = []
         self._since = 0.0
         self._open: Any = None
 
     def phase(self, name: str, annotate: bool = True) -> _PhaseScope:
-        if name not in ENGINE_PHASES:
-            raise ValueError(f"{name!r} is not one of ENGINE_PHASES")
+        if name not in self._names:
+            raise ValueError(f"{name!r} is not one of {self._names}")
         return _PhaseScope(self, name, annotate)
 
     def _close(self, now: float) -> None:
@@ -207,7 +242,7 @@ class EnginePhases:
         if self._stack:
             name, annotate = self._stack[-1]
             if annotate and self._annotate is not None:
-                self._open = self._annotate("xllm.engine." + name)
+                self._open = self._annotate(self._prefix + name)
                 self._open.__enter__()
 
     def _push(self, name: str, annotate: bool) -> None:

@@ -52,6 +52,7 @@ from xllm_service_tpu.obs import (
     EnginePhases,
     MetricsRegistry,
 )
+from xllm_service_tpu.obs import startup as obs_startup
 from xllm_service_tpu.ops.sampling import SamplingParams
 from xllm_service_tpu.runtime.block_manager import (
     BlockManager,
@@ -362,6 +363,7 @@ _on_roomy_stack.__code__ = _on_roomy_stack.__code__.replace(
 
 
 class InferenceEngine:
+    @obs_startup.startup_phase("engine")
     def __init__(
         self,
         engine_cfg: EngineConfig,
@@ -665,10 +667,13 @@ class InferenceEngine:
             "Engine thread time by exclusive loop phase",
             labelnames=("phase",),
         )
-        phase_inc = {
+        phase_inc = self._phase_inc = {
             p: loop_seconds.labels(phase=p).inc for p in ENGINE_PHASES
         }
         self._phases = EnginePhases(lambda p, dt: phase_inc[p](dt))
+        # Start-up timeline (docs/OBSERVABILITY.md): the process's phases
+        # of a start and its trace / lower / compile seconds by program.
+        obs_startup.TIMELINE.export(self.metrics)
         self._m_tbt = self.metrics.histogram(
             "xllm_engine_tbt_ms", "Time between tokens per running "
             "sequence", buckets=LATENCY_BUCKETS_MS,
@@ -843,11 +848,11 @@ class InferenceEngine:
             "Decode steps dispatched while the prior step was still in "
             "flight",
         ).set_function(lambda: self.overlap_steps)
-        # Collective-overlap + compile-cache instruments (ISSUE 18,
-        # docs/OBSERVABILITY.md). Hit/miss semantics: a dispatch that
-        # reused an already-lowered program is a hit; every fresh
-        # lowering past the prewarm watermark is a miss (with no
-        # prewarm, ALL lowerings are misses).
+        # Collective-overlap + dispatch-cache instruments (ISSUE 18,
+        # docs/OBSERVABILITY.md): every fresh lowering past the prewarm
+        # watermark is a miss (with no prewarm, ALL lowerings are). What
+        # the persistent cache on disk did is another series:
+        # xllm_engine_program_builds_total (obs/startup.py).
         self.metrics.counter(
             "xllm_engine_collective_overlap_steps_total",
             "Engine dispatches whose traced step programs carry the "
@@ -856,22 +861,12 @@ class InferenceEngine:
         ).set_function(lambda: self.collective_overlap_steps)
         self.metrics.counter(
             "xllm_engine_compile_cache_misses_total",
-            "Fresh program lowerings past the prewarm watermark (the "
-            "first-post-idle-recompile class prewarm_programs exists "
-            "to kill)",
+            "Entries the step programs' jit DISPATCH caches gained past "
+            "the prewarm watermark (fresh lowerings: the "
+            "first-post-idle-recompile class prewarm_programs exists to "
+            "kill); not the persistent cache on disk, which is "
+            "xllm_engine_program_builds_total",
         ).set_function(lambda: self.compile_cache_misses())
-        self.metrics.counter(
-            "xllm_engine_compile_cache_hits_total",
-            "Engine dispatches served from already-compiled programs "
-            "(no fresh lowering)",
-        ).set_function(lambda: self.compile_cache_hits())
-        self.metrics.counter(
-            "xllm_engine_compile_cache_prewarm_ms_total",
-            "Wall-clock ms spent compiling the bucket-program family "
-            "at instance start (prewarm_programs)",
-        ).set_function(
-            lambda: getattr(self.executor, "prewarm_ms", 0.0)
-        )
         self.metrics.counter(
             "xllm_engine_late_stop_discards_total",
             "In-flight sampled tokens discarded because their sequence "
@@ -1185,9 +1180,19 @@ class InferenceEngine:
             return 0
         return max(0, count() - getattr(ex, "prewarmed_lowerings", 0))
 
-    def compile_cache_hits(self) -> int:
-        """Dispatches that reused an already-compiled program."""
-        return max(0, self.decode_dispatches - self.compile_cache_misses())
+    def _mark_first_step(self) -> None:
+        """Arm `xllm_engine_first_step_seconds`: the first `device_wait`
+        the engine thread books sets it and takes this hook out again, so
+        no later step pays for it."""
+        phase_inc = self._phase_inc  # the hook holds the table, not the engine
+        inc = phase_inc["device_wait"]
+
+        def first(dt: float) -> None:
+            phase_inc["device_wait"] = inc
+            inc(dt)
+            obs_startup.TIMELINE.mark_first_step()
+
+        phase_inc["device_wait"] = first
 
     def start(self) -> None:
         if self.cfg.warmup_on_start and hasattr(self.executor, "warmup"):
@@ -1197,12 +1202,14 @@ class InferenceEngine:
             # cache amortizes the enumeration across restarts. Without
             # a dir the full walk would pay its whole compile bill
             # every start, so keep the classic split-step warmup.
-            if compile_cache_mod.resolve_cache_dir(
-                self.cfg.compilation_cache_dir
-            ) and hasattr(self.executor, "prewarm_programs"):
-                self.executor.prewarm_programs()
-            else:
-                self.executor.warmup()
+            with obs_startup.startup_phase("programs"):
+                if compile_cache_mod.resolve_cache_dir(
+                    self.cfg.compilation_cache_dir
+                ) and hasattr(self.executor, "prewarm_programs"):
+                    self.executor.prewarm_programs()
+                else:
+                    self.executor.warmup()
+        self._mark_first_step()  # after the warm-up's own reads
         self._thread = threading.Thread(target=self._loop, daemon=True)
         self._thread.start()
 

@@ -43,6 +43,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from xllm_service_tpu.common.config import EngineConfig
 from xllm_service_tpu.obs import regions as obs_regions
 from xllm_service_tpu.obs import spans as obs_spans
+from xllm_service_tpu.obs import startup as obs_startup
 from xllm_service_tpu.runtime import compile_cache as compile_cache_mod
 from xllm_service_tpu.runtime.block_manager import (
     StateFamilyUnsupported,
@@ -162,7 +163,7 @@ def _setup_compilation_cache(cache_dir: str) -> None:
                 f"compilation_cache_dir={cache_dir!r} ignored: process "
                 f"already caches to {_COMPILATION_CACHE_DIR!r} (jax "
                 f"config is process-global)",
-                stacklevel=3,
+                stacklevel=4,  # past __init__ and its start-up scope
             )
         return
     _COMPILATION_CACHE_DIR = cache_dir
@@ -457,6 +458,7 @@ class ModelExecutor:
             self.book_moe()
         return out
 
+    @obs_startup.startup_phase("params")
     def __init__(
         self,
         engine_cfg: EngineConfig,
@@ -464,6 +466,9 @@ class ModelExecutor:
         mesh: Optional[Mesh] = None,
         init_seed: int = 0,
     ):
+        # What this process traces, lowers and compiles from here on is
+        # booked by program (obs/startup.py); once a process.
+        obs_startup.TIMELINE.install()
         self.engine_cfg = engine_cfg
         # Multi-host: join the process group BEFORE the first backend
         # touch, so build_mesh below sees the GLOBAL device list
@@ -590,13 +595,14 @@ class ModelExecutor:
         self.window_blocks = self.window_pool_bytes = 0
         if self.has_paged_cache:
             self.block_size = engine_cfg.block_size
-            if self.window_tables:
-                self._refuse_for_window_family(tp, ep)
-                self.window_blocks = self._decide_window_blocks()
-                self.window_pool_bytes = (
-                    self.window_blocks * self._window_block_bytes()
-                )
-            self.num_blocks = self._decide_num_blocks()
+            with obs_startup.startup_phase("pools"):
+                if self.window_tables:
+                    self._refuse_for_window_family(tp, ep)
+                    self.window_blocks = self._decide_window_blocks()
+                    self.window_pool_bytes = (
+                        self.window_blocks * self._window_block_bytes()
+                    )
+                self.num_blocks = self._decide_num_blocks()
         else:
             self.block_size = engine_cfg.max_seq_len
             self.num_blocks = self.R + 1
@@ -638,6 +644,7 @@ class ModelExecutor:
                     bits=4 if engine_cfg.weight_dtype == "int4" else 8,
                 )
 
+        with self.mesh, obs_startup.startup_phase("pools"):
             # [L, N, Hkv, BS, D]: KV-head-major within a block so the Pallas
             # decode kernel can DMA one (block, head) tile of shape [BS, D]
             # with TPU-legal last-two-dims tiling. MLA families cache one
@@ -774,7 +781,7 @@ class ModelExecutor:
         # Replicated over the mesh like the copy every step hands back: an
         # unplaced first copy made the first step program of a new
         # executor compile twice.
-        with self.mesh:
+        with self.mesh, obs_startup.startup_phase("pools"):
             self.token_counts = jax.jit(
                 lambda: jnp.zeros((self.R, self.cfg.vocab_size), jnp.int32),
                 out_shardings=NamedSharding(self.mesh, P()),
@@ -790,13 +797,7 @@ class ModelExecutor:
         self._step_signatures: Dict[str, list] = {}
         self._region_maps: Dict[tuple, Dict[str, str]] = {}
         obs_regions.register(self)
-        self._decode_jit = self._step_jit(
-            self._decode_impl, donate_argnums=(0, 1, 2),
-            static_argnames=("use_kernel",)
-        )
-        self._prefill_jit = self._step_jit(
-            self._prefill_impl, donate_argnums=(0, 1)
-        )
+
         def _import_impl(k, v, blocks, ids):
             # blocks [2, L, P, Hkv, BS, D] in model dtype (migration payloads
             # stay bf16 on the wire/host tiers; int8 caches requantize here).
@@ -805,7 +806,15 @@ class ModelExecutor:
                 v = kvc.set_blocks(v, ids, blocks[1])
             return k, v
 
-        self._import_jit = jax.jit(_import_impl, donate_argnums=(0, 1))
+        with obs_startup.startup_phase("programs"):
+            self._decode_jit = self._step_jit(
+                self._decode_impl, donate_argnums=(0, 1, 2),
+                static_argnames=("use_kernel",)
+            )
+            self._prefill_jit = self._step_jit(
+                self._prefill_impl, donate_argnums=(0, 1)
+            )
+            self._import_jit = jax.jit(_import_impl, donate_argnums=(0, 1))
         # Expert-routing counts (docs/MOE.md, docs/OBSERVABILITY.md):
         # cumulative choice counts over the PUBLISHED experts, summed
         # over layers and steps, booked from the step programs' own
@@ -2152,6 +2161,11 @@ class ModelExecutor:
         shapes, dtypes, shardings, static arguments, no buffer), which
         `program_regions()` lowers again on demand. A call that lowers
         nothing pays one comparison for it."""
+        if impl.__name__ not in obs_spans.STEP_PROGRAMS:
+            raise ValueError(
+                f"{impl.__name__!r} is not one of obs.spans.STEP_PROGRAMS: "
+                f"its build seconds would count as 'other'"
+            )
         moe_model = self.cfg.is_moe
         fn = impl
         if moe_model:
