@@ -803,6 +803,60 @@ def run_kda_gram_case(n, H, d, C=64):
 # NOTE: D=64 decode is NOT included — Mosaic rejects the lane-padded HBM
 # block slice below one 128-lane tile (tpu.memref_slice verify failure
 # on-chip); ops/attention.py falls back to gather there.
+def run_kda_chunk_case(Lc, H, d, slots=4, start=512):
+    """One prefill chunk of a KDA layer from the convolution's output
+    (ops/kda.py `chunk_update`: one row of Lc tokens against a
+    carried state) as `kda_chunk_kernel` against the `jax.numpy` chunk
+    form with the gram's diagonal as `kda_gram_kernel` (what the mixed
+    step held before the kernel) and without it: output and state apart,
+    us a call of each with the pool donated, and the launch's own device
+    time."""
+    from xllm_service_tpu.ops import kda as ko
+
+    ks = jax.random.split(jax.random.key(6), 5)
+    qkv = jax.random.normal(ks[0], (1, Lc, 3 * H * d))
+    # per-token decays of 0.9-0.999 a channel, a head's own scale on top
+    g = -jnp.exp(jax.random.uniform(ks[1], (1, Lc, H, d), jnp.float32, np.log(1e-3), np.log(1e-1)))
+    g = g * jnp.exp(jax.random.normal(ks[2], (1, 1, H, 1)))
+    beta = 2 * jax.nn.sigmoid(jax.random.normal(ks[3], (1, Lc, H)))
+    S0 = jax.random.normal(ks[4], (1, slots, H, d, d))
+    meta = (jnp.int32(0), jnp.array([1]), jnp.array([start]), jnp.array([Lc - 3]))
+
+    def xla_form(gram):  # the chunk form around `_chunk_scan`, as `chunk_update` hands it over
+        def f(S, qkv, g, beta):
+            x = (*ko.qkv_heads(qkv, H, d), g, beta)
+            o, sT = ko._chunk_scan(*ko._masked(*x, meta[3], ko.CHUNK), S[0, 1][None], ko.CHUNK, gram)
+            return o, S.at[0, 1].set(sT[0])
+        return f
+
+    forms = {
+        "kernel": lambda S, *x: ko.chunk_update(S, *meta, *x, use_kernel=True),
+        "xla+gram": xla_form(True), "xla": xla_form(False),
+    }
+    out, us = {}, {}
+    for name, f in forms.items():
+        jf = jax.jit(f, donate_argnums=0)
+        hold = {"S": S0 + 0.0}
+
+        def call(jf=jf, hold=hold):
+            o, hold["S"] = jf(hold["S"], qkv, g, beta)
+            return o
+
+        o = call()
+        out[name] = (o[:, :Lc - 3], hold["S"][0, 1] + 0.0)
+        us[name] = bench(call, iters=16) * 1e6
+        if name == "kernel":
+            own, launches = device_us(call, "kda_chunk_kernel")
+    err_o = float(jnp.abs(out["kernel"][0] - out["xla"][0]).max()) / float(jnp.abs(out["xla"][0]).max())
+    err_s = float(jnp.abs(out["kernel"][1] - out["xla"][1]).max()) / float(jnp.abs(out["xla"][1]).max())
+    print(
+        f"KDA-CHUNK Lc={Lc} H={H} d={d} err_o={err_o:.2e} err_S={err_s:.2e} "
+        f"kernel={us['kernel']:8.1f}us (launch {own:7.1f}us x{launches // 4}) "
+        f"xla+gram={us['xla+gram']:8.1f}us xla={us['xla']:8.1f}us"
+    )
+    return max(err_o, err_s) * 1e-2  # float32 sums in another order
+
+
 def run_lightning_update_case(R, live, L, H, d):
     """lightning_update_kernel as minicpm-sala's decode program calls it:
     one launch a lightning layer over the L-layer state pool of R slots,
@@ -1060,6 +1114,9 @@ CASES = [
     # lanes in 8 chunks of 64: its diagonal sub-blocks as `kda_gram_kernel`
     # against the two XLA fusions with the 268 MB pair tensor between them.
     ("kda-gram-think", run_kda_gram_case, dict(n=8, H=64, d=128)),
+    # ... and the whole chunk form of that chunk against a carried state:
+    # `kda_chunk_kernel` against the XLA form around the gram kernel
+    ("kda-chunk-think", run_kda_chunk_case, dict(Lc=512, H=64, d=128)),
     # int8 KV cache (scale DMA + column folding) at production block size
     ("dec-int8-a", run_case,
      dict(R=64, Hq=32, Hkv=8, D=128, BS=128, MB=16, ctx=2048, int8=True)),

@@ -106,7 +106,7 @@ def test_chunk_form_equals_the_recurrence_through_a_dirty_pool(regime, n_prefill
     for start in range(0, n_prefill, chunk):
         n = min(chunk, n_prefill - start)
         rows = [jnp.stack([_pad(a[start:start + n], chunk)] * 2) for a in x]
-        o, S = kda.chunk_update(S, layer, jnp.array([slot, -1]), jnp.array([start, 0]),
+        o, S = kda.chunk_update_heads(S, layer, jnp.array([slot, -1]), jnp.array([start, 0]),
                                 jnp.array([n, 0]), *rows, chunk=16, use_kernel=gram, interpret=True)
         os_.append(o[0, :n])
     for t in range(n_prefill, T):  # decode: the slot is the ROW
@@ -162,6 +162,85 @@ def test_gram_kernel_equals_the_xla_route(case):
         assert float(jnp.abs(jnp.tril(got, -1)).max()) == 0.0
     one = kda._decayed_gram(q, k, G, use_kernel=True, interpret=True)  # one row set, no leading axis
     np.testing.assert_allclose(one, want[1], atol=2e-5 * float(jnp.abs(want).max()))
+
+
+def _chunk_rows(regime, Lc, seed=4):
+    """Three rows of one step as the convolution leaves them (q | k | v
+    side by side, not normalised): a ragged row that starts mid-context
+    on a carried state, a padding row, and a row that starts at 0 on a
+    dirty slot and ends inside a sub-block."""
+    _, _, _, g, beta = _rule_inputs(3 * Lc, regime, seed=seed)
+    qkv = 3.0 * jax.random.normal(jax.random.key(seed), (3, Lc, CONV))
+    rows = (qkv, g.reshape(3, Lc, H, D), beta.reshape(3, Lc, H))
+    return rows, jnp.array([3, -1, 1]), jnp.array([Lc, 0, 0]), jnp.array([Lc - 5, 0, Lc // 2 + 3])
+
+
+@pytest.mark.parametrize("regime,chunk", [("slow", 64), ("fast", 64), ("mixed", 64), ("beta-2", 64),
+                                          ("mixed", 32), ("mixed", 16)])
+def test_chunk_kernel_equals_the_xla_route(regime, chunk):
+    """`kda_chunk_kernel` (interpret mode) against `qkv_heads` and
+    `_chunk_scan` through `chunk_update`: two chunks a row, valid
+    lengths that are no multiple of the sub-block, a row that starts
+    mid-context on a non-zero state, one that starts at 0 on a
+    never-cleaned slot, a padding row beside them, decays near 1 and near
+    0, beta near 2; 4, 2 and 1 sub-blocks a chunk (two, one and no level
+    of joins in the inverse). Output and state within the gram kernel's
+    tolerance; what the rows do not own is not touched."""
+    rows, slots, start, length = _chunk_rows(regime, 128 if chunk == 64 else 64)
+    S = jax.random.normal(jax.random.key(3), kda.state_shapes(2, 5, H, D, K)[0])
+    want_o, want_S = kda.chunk_update(S, 1, slots, start, length, *rows, chunk=chunk, use_kernel=False)
+    got_o, got_S = kda.chunk_update(S, 1, slots, start, length, *rows, chunk=chunk,
+                                         use_kernel=True, interpret=True)
+    assert bool(jnp.isfinite(got_o).all())
+    for p in (0, 2):  # the live rows, as far as they are valid
+        n = int(length[p])
+        np.testing.assert_allclose(got_o[p, :n], want_o[p, :n], atol=2e-5 * float(jnp.abs(want_o).max()))
+    assert float(jnp.abs(got_o[1]).max()) == 0.0  # the padding row reads out nothing
+    np.testing.assert_allclose(got_S, want_S, atol=2e-5 * float(jnp.abs(want_S).max()))
+    assert float(jnp.abs(got_S[1, 3] - S[1, 3]).max()) > 0.0
+    untouched = jnp.array([0, 2, 4])  # slot 0 is where the padding row's -1 clips to
+    assert float(jnp.abs((got_S - S)[1, untouched]).max()) == 0.0
+    assert float(jnp.abs((got_S - S)[0]).max()) == 0.0  # the other layer
+
+
+def test_chunk_kernel_with_no_live_row_moves_nothing():
+    rows, slots, start, _ = _chunk_rows("mixed", 64)
+    S = jax.random.normal(jax.random.key(3), kda.state_shapes(2, 5, H, D, K)[0])
+    o, S1 = kda.chunk_update(S, 1, slots, start, jnp.zeros(3, jnp.int32), *rows,
+                                  use_kernel=True, interpret=True)
+    assert float(jnp.abs(o).max()) == 0.0 and float(jnp.abs(S1 - S).max()) == 0.0
+
+
+def test_chunk_kernel_carries_the_state_across_token_tiles():
+    """A row of 1,024 tokens is two grid steps of 512 a head tile: the
+    state rides the output block from one to the next, the decay's mask
+    counts from the tile's first token, and a ragged end falls in the
+    second tile."""
+    Lc = 1024
+    _, _, _, g, beta = _rule_inputs(Lc, "mixed", seed=7)
+    qkv = 3.0 * jax.random.normal(jax.random.key(7), (1, Lc, CONV))
+    rows = (qkv, g[None], beta[None])
+    S = jax.random.normal(jax.random.key(3), kda.state_shapes(1, 2, H, D, K)[0])
+    meta = (0, jnp.array([1]), jnp.array([64]), jnp.array([Lc - 37]))
+    want_o, want_S = kda.chunk_update(S, *meta, *rows, use_kernel=False)
+    got_o, got_S = kda.chunk_update(S, *meta, *rows, use_kernel=True, interpret=True)
+    np.testing.assert_allclose(got_o[0, :Lc - 37], want_o[0, :Lc - 37],
+                               atol=2e-5 * float(jnp.abs(want_o).max()))
+    np.testing.assert_allclose(got_S, want_S, atol=2e-5 * float(jnp.abs(want_S).max()))
+
+
+def test_chunk_update_is_chunk_update_heads_of_the_split():
+    """The `jax.numpy` side of `chunk_update` is `qkv_heads` then
+    `chunk_update_heads`: the model's q, k and v are that split."""
+    (qkv, g, beta), slots, start, length = _chunk_rows("mixed", 64)
+    S = jax.random.normal(jax.random.key(3), kda.state_shapes(2, 5, H, D, K)[0])
+    q, k, v = kda.qkv_heads(qkv, H, D)
+    np.testing.assert_allclose(jnp.linalg.norm(k, axis=-1), 1.0, atol=1e-5)
+    np.testing.assert_allclose(jnp.linalg.norm(q, axis=-1), D ** -0.5, atol=1e-5)
+    want = kda.chunk_update_heads(S, 1, slots, start, length, q, k, v, g, beta, use_kernel=False)
+    got = kda.chunk_update(S, 1, slots, start, length, qkv, g, beta, use_kernel=False)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=0)
 
 
 @pytest.mark.parametrize("n", [16, 64], ids=["forward-substitution", "joined-halves"])
@@ -331,6 +410,38 @@ def test_same_prompt_twice_is_recomputed_not_cached(served):
     _drain(eng)
     assert again["again"] == outs["two-chunks"]  # a reused, never-cleaned slot
     assert eng.prefix_cached_tokens == 0
+
+
+def test_the_engine_emits_the_same_tokens_through_the_chunk_kernel(monkeypatch):
+    """Three prompts of 1, 2 and 3 chunks served together (mixed steps,
+    then decode steps) on a float32 engine whose KDA layers' chunks go
+    through `kda_chunk_kernel` (interpret mode) emit the tokens the XLA
+    form's engine emits, and the process's counter says which form each
+    engine's programs hold."""
+    from xllm_service_tpu.obs.startup import TIMELINE
+
+    rng = np.random.default_rng(1)
+    prompts = {rid: list(rng.integers(0, 512, n)) for rid, n in PROMPTS.items()}
+    real, emitted = kda.chunk_update, {}
+    for form in ("xla", "kernel"):
+        if form == "kernel":
+            monkeypatch.setattr(kda, "chunk_update", lambda *a, **kw: real(
+                *a, **{**kw, "use_kernel": True, "interpret": True}))
+        before = dict(TIMELINE.kda_chunk_forms)
+        eng, _ = _engine(sync_engine=False)
+        outs = emitted.setdefault(form, {})
+        for rid, p in prompts.items():
+            eng.add_request(_req(rid, outs, p, max_new=6))
+        _drain(eng)
+        other = "xla" if form == "kernel" else "kernel"
+        assert eng.mixed_steps > 0
+        assert TIMELINE.kda_chunk_forms[form] > before[form]
+        assert TIMELINE.kda_chunk_forms[other] == before[other]
+        series = [line for line in eng.metrics.render().splitlines()
+                  if line.startswith("xllm_engine_kda_chunk_kernel_total{")]
+        assert len(series) == 2 and any(f'form="{form}"' in line for line in series)
+    for rid in prompts:
+        assert emitted["kernel"][rid] == emitted["xla"][rid] and len(emitted["xla"][rid]) == 6
 
 
 def _two_warm_rows(cfg, seed):

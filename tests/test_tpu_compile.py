@@ -960,6 +960,17 @@ def test_step_reads_every_weight_leaf_where_it_lies(one_chip, no_persistent_cach
     assert not moved, "\n".join(moved)
     if case.startswith("solar"):
         assert "kda_update_kernel" in text
+    if case == "solar-mixed-608":
+        # the chunk's rows [1, 512, 64 x 128] reach `kda_chunk_kernel` as the convolution
+        # leaves them and leave it token-major: no transpose of q, k, v, the decay or the
+        # output (the jax.numpy form moved all of them heads-first and back), no gram
+        # launch. What is left re-tiles [512, 64, 128] twice, between (token, lane) and
+        # (head, lane) tiles: the decay inside the fusion that masks it, and the output for
+        # the norm over a head's lanes
+        assert "kda_chunk_kernel" in text and "kda_gram_kernel" not in text
+        moved = _rows_moved(text, 512 * 64 * 128)
+        assert not [line for line in moved if " transpose(" in line], "\n".join(moved)
+        assert len(moved) <= 2, "\n".join(moved)
 
 
 @pytest.mark.parametrize("step", ["decode", "prefill", "mixed"])
@@ -1012,9 +1023,10 @@ def test_solar_tiny_steps_compile_and_neither_pool_is_laid_out_again(one_chip, n
                 moved.append(line.strip()[:160])
     assert not moved, "\n".join(moved)
     assert ("kda_update_kernel" in text) == (step == "mixed")
-    # the gram's pair tensor never reaches HBM: where a chunk is, its
-    # diagonal sub-blocks are the kernel's
-    assert ("kda_gram_kernel" in text) == (step != "decode")
+    # where a chunk is, its whole chunk form is the kernel: the gram's pair
+    # tensor never reaches HBM, and no second launch forms the gram
+    assert ("kda_chunk_kernel" in text) == (step != "decode")
+    assert "kda_gram_kernel" not in text
     assert not _pair_tensors(text)
 
 
@@ -1045,6 +1057,53 @@ def test_the_chunk_form_at_think_steady_holds_no_pair_tensor(one_chip, no_persis
     assert "kda_gram_kernel" in text and not _pair_tensors(text)
     text = _compile(lambda *a: kda._chunk_scan(*a, 64), *args)
     assert "kda_gram_kernel" not in text and _pair_tensors(text)
+
+
+def _rows_moved(text, elements):
+    """Instructions of a compiled text that lay `elements` float32 values
+    out again: a copy or a transpose (alone, or as what a fusion is named
+    for) with a result of that many elements."""
+    import math
+    import re
+
+    moved = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = f32\[([\d,]+)\]\S* (copy|transpose|fusion)\(", line)
+        if m and math.prod(map(int, m.group(2).split(","))) == elements and (
+                m.group(3) != "fusion" or re.search(r"copy|transpose", m.group(1))):
+            moved.append(line.strip()[:160])
+    return moved
+
+
+def test_the_chunk_kernel_at_think_steady_reads_the_rows_where_they_lie(one_chip, no_persistent_cache):
+    """`chunk_update` at the cell's shape (one row of 512 tokens, 64
+    heads of 128 lanes, the 96-slot pool of 6 layers donated) between
+    arrays that lie as the projections leave them and the out-projection
+    takes them (`[tokens, H d]`: (8, 128) tiles of (token, lane)): as
+    `kda_chunk_kernel` the program holds no copy and no transpose of q, k,
+    v, the decay or the output (a head is 128 lanes of the kernel's
+    blocks) and no gram launch; the `jax.numpy` form moves
+    them heads-first and back, so the search finds what it looks for."""
+    from xllm_service_tpu.ops import kda
+
+    def s(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    one = s(1, dtype=jnp.int32)
+    args = (s(6, 96, 64, 128, 128), s(dtype=jnp.int32), one, one, one,
+            s(1, 512, 3 * 64 * 128), s(1, 512, 64 * 128), s(1, 512, 64))
+
+    def text(use_kernel):
+        def fn(S, layer, slots, start, length, qkv, g, beta):
+            o, S = kda.chunk_update(S, layer, slots, start, length, qkv,
+                                         g.reshape(1, 512, 64, 128), beta, use_kernel=use_kernel)
+            return o.reshape(1, 512, 64 * 128), S
+        return jax.jit(fn, donate_argnums=0).lower(*args).compile().as_text()
+
+    kernel = text(True)
+    assert "kda_chunk_kernel" in kernel and "kda_gram_kernel" not in kernel
+    assert not _rows_moved(kernel, 512 * 64 * 128)
+    assert _rows_moved(text(False), 512 * 64 * 128)
 
 
 # ---- PR 51: one context bucket wherever attention runs as the kernels

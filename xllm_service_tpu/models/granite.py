@@ -181,7 +181,6 @@ MIXER_STACKS = {"mamba": "mamba", "kda": "kda", "attention": "attn", "window": "
 MIXER_REGIONS = {"mamba": "state_mixer", "kda": "state_mixer", "attention": "attn_proj",
                  "window": "attn_proj", PARALLEL_KIND: "state_mixer",
                  "lightning": "state_mixer", "sparse": "attn_proj"}
-L2_EPS = 1e-6  # under the root of KDA's q and k normalisation
 
 
 def key_lanes(cfg: ModelConfig) -> int:
@@ -262,9 +261,9 @@ def state_shapes(cfg: ModelConfig, slots: int, blocks: int = 0):
 def state_route(cfg: ModelConfig, state) -> str:
     """Which route the decode update of `state` (the state pool) takes:
     "<kind>-pallas" (the kind's update kernel) or "<kind>-xla". For
-    "kda" the same predicate routes the chunk form's decayed gram, so
-    "kda-pallas" says the update AND the gram's diagonal are the kernels
-    (`kda_update_kernel`, `kda_gram_kernel`) and "kda-xla" that neither is."""
+    "kda" the same predicate routes a prefill chunk's chunk form, so
+    "kda-pallas" says the update AND the chunk form are the kernels
+    (`kda_update_kernel`, `kda_chunk_kernel`) and "kda-xla" that neither is."""
     kind = cfg.state_layer_kind
     on_kernel = STATE_KINDS[kind].on_kernel(cfg, state)
     return f"{kind}-{'pallas' if on_kernel else 'xla'}"
@@ -582,20 +581,12 @@ def _kda_inputs(lp, cfg: ModelConfig, h):
         return y
 
     qkv = jnp.concatenate([through("wq"), through("wk"), through("wv")], axis=-1)
-    g = jax.nn.softplus(through("w_f1", "w_f2") + lp["dt_bias"]).reshape(T, H, d)
-    g = -jnp.exp(lp["A_log"].astype(f32))[:, None] * g
+    # (a head's rate on its d lanes, flat: the rows stay (token, lane) tiles for the chunk kernel)
+    rate = jnp.repeat(-jnp.exp(lp["A_log"].astype(f32)), d)
+    g = (rate * jax.nn.softplus(through("w_f1", "w_f2") + lp["dt_bias"])).reshape(T, H, d)
     beta = (2.0 if cfg.kda_neg_eigval else 1.0) * jax.nn.sigmoid(through("w_beta"))
     gate = jax.nn.sigmoid(through("w_g1", "w_g2")).reshape(T, H, d)
     return qkv, g, beta, gate
-
-
-def _kda_heads(cfg: ModelConfig, c):
-    """The convolution's output [..., 3 H d] -> q (l2-normalised, scaled
-    by d**-0.5), k (l2-normalised), v, each [..., H, d]."""
-    H, d = cfg.kda_n_heads, cfg.kda_d_head
-    q, k, v = (c[..., i * H * d:(i + 1) * H * d].reshape(*c.shape[:-1], H, d) for i in range(3))
-    l2 = lambda x: x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + L2_EPS)
-    return l2(q) * d ** -0.5, l2(k), v
 
 
 @region("state_mixer")
@@ -614,12 +605,15 @@ def _kda_mixer(lp, cfg: ModelConfig, h, m, S, conv, dec: Optional[_Dec],
     chunks' rows), KDA layer `m`: returns (out [T, E], S', conv')."""
     H, d, Cd = cfg.kda_n_heads, cfg.kda_d_head, cfg.kda_conv_dim
     qkv, g, beta, gate = _kda_inputs(lp, cfg, h)
+    # cut by rows while flat: the chunk's rows reach the kernel in the tiles they lie in
+    g = g.reshape(-1, H * d)
     R = dec.R if dec is not None else 0
     os = []
     if dec is not None:
         c, conv = mamba_ops.conv_decode(conv, m, dec.active, qkv[:R], lp["conv_w"], lp["conv_b"])
         o, S = kda_ops.decode_update(
-            S, m, dec.active, *_kda_heads(cfg, c), g[:R], beta[:R], use_kernel=dec.use_kernel
+            S, m, dec.active, *kda_ops.qkv_heads(c, H, d), g[:R].reshape(R, H, d), beta[:R],
+            use_kernel=dec.use_kernel,
         )
         os.append(o)
     if pf is not None:
@@ -628,7 +622,7 @@ def _kda_mixer(lp, cfg: ModelConfig, h, m, S, conv, dec: Optional[_Dec],
             qkv[R:].reshape(pf.P, pf.Lpad, Cd), lp["conv_w"], lp["conv_b"],
         )
         o, S = kda_ops.chunk_update(
-            S, m, pf.slots, pf.start, pf.length, *_kda_heads(cfg, c),
+            S, m, pf.slots, pf.start, pf.length, c,
             g[R:].reshape(pf.P, pf.Lpad, H, d), beta[R:].reshape(pf.P, pf.Lpad, H),
             use_kernel=dec.use_kernel if dec is not None else None,
         )
@@ -1229,7 +1223,8 @@ def hidden_dense(params: Params, cfg: ModelConfig, token_ids, rows_valid=None):
     def kda(lp, h):  # the recurrence, token by token
         qkv, g, beta, gate = _kda_inputs(lp, cfg, h)
         c = mamba_ops.conv_dense(qkv, lp["conv_w"], lp["conv_b"])
-        o, _ = kda_ops.recurrent_form(*_kda_heads(cfg, c), g, beta)
+        heads = kda_ops.qkv_heads(c, cfg.kda_n_heads, cfg.kda_d_head)
+        o, _ = kda_ops.recurrent_form(*heads, g, beta)
         return _kda_out(lp, cfg, o, gate)
 
     pos = jnp.arange(L, dtype=jnp.int32)

@@ -52,6 +52,7 @@ from xllm_service_tpu.obs.spans import (
 )
 
 PROGRAM_STAGES = ("trace", "lower", "compile", "cache_read")
+KDA_CHUNK_FORMS = ("kernel", "xla")  # ops/kda.py `chunk_update`: which form a traced layer holds
 BUILD_CACHE = ("hit", "miss", "none")
 OTHER = "other"
 
@@ -122,6 +123,13 @@ class StartupTimeline:
         self.program_builds: Dict[Tuple[str, str], int] = {
             (p, c): 0 for p in programs for c in BUILD_CACHE
         }
+        self.kda_chunk_forms: Dict[str, int] = dict.fromkeys(KDA_CHUNK_FORMS, 0)
+
+    def count_kda_chunk_form(self, form: str) -> None:
+        """A KDA layer's chunk form was traced into a program as `form`:
+        once a layer body and trace, never on a step's path."""
+        with self._mu:
+            self.kda_chunk_forms[form] += 1
 
     # ------------------------------------------------------------ phases
 
@@ -240,6 +248,18 @@ class StartupTimeline:
         for key in self.program_builds:
             builds.labels(program=key[0], cache=key[1]).set_function(
                 lambda key=key: self.program_builds[key]
+            )
+        forms = registry.counter(
+            "xllm_engine_kda_chunk_kernel_total",
+            "KDA layer bodies traced into this process's programs by the "
+            "form their prefill chunk takes: kernel (kda_chunk_kernel) or "
+            "xla (the jax.numpy chunk form); counted when a program is "
+            "traced, a layer body once",
+            labelnames=("form",),
+        )
+        for form in KDA_CHUNK_FORMS:
+            forms.labels(form=form).set_function(
+                lambda form=form: self.kda_chunk_forms[form]
             )
 
 

@@ -44,12 +44,18 @@ DECODE row's slot is its row index; a prefill chunk names its slot.
 Two routes, one result, chosen by ONE predicate (`kernel_eligible`: on
 the chip, whole (8, 128) tiles of state): there the decode update is the
 Pallas kernel `kda_update_kernel`, in place on the stack the layer scan
-carries, and the DIAGONAL sub-blocks of the chunk form's decayed gram are
-`kda_gram_kernel`, which keeps the per-pair decays `[16, 16, d]` of a
-sub-block in VMEM where XLA writes them to HBM (268 MB a layer and chunk
-at 64 heads of 128); elsewhere the `jax.numpy` route below. The rest of
-the chunk form (the off-diagonal blocks, the inverse, the scan over the
-chunks) is `jax.numpy` on both.
+carries, and a prefill chunk's whole chunk form is ONE launch a layer,
+`kda_chunk_kernel` (`chunk_update`; `chunk_kernel_eligible`: chunks of a
+power of two of sub-blocks): it reads the convolution's output, g and beta
+token-major as the projections leave them, normalises q and k, forms the
+gram, the inverse, U~ and both outputs in VMEM, carries a head's state
+across the row's chunks in its output block and writes the slot once;
+elsewhere `qkv_heads` and the `jax.numpy` route below
+(`chunk_update_heads`, `_chunk_scan`), which is also what the kernel is
+tested against. `kda_gram_kernel` (the gram's diagonal sub-blocks alone,
+PR 54) is that route's `use_kernel` and no step's any more.
+`chunk_update` counts which form a traced layer holds
+(`xllm_engine_kda_chunk_kernel_total{form}`).
 """
 
 from __future__ import annotations
@@ -60,11 +66,18 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from xllm_service_tpu.obs.startup import TIMELINE
 from xllm_service_tpu.ops.mamba import _units
-from xllm_service_tpu.ops.pallas.kda import head_tile, kda_gram_kernel, kda_update_kernel
+from xllm_service_tpu.ops.pallas.kda import (
+    head_tile,
+    kda_chunk_kernel,
+    kda_gram_kernel,
+    kda_update_kernel,
+)
 
 _HI = jax.lax.Precision.HIGHEST
 CHUNK = 64  # tokens of one chunk of the chunk form (the published kernels')
+L2_EPS = 1e-6  # under the root of q's and k's normalisation
 BLOCK = 16  # tokens of one sub-block of the decayed gram
 
 
@@ -82,6 +95,13 @@ def kernel_eligible(S, requested: Optional[bool] = None) -> bool:
     from xllm_service_tpu.ops.attention import _on_tpu
 
     return _on_tpu() and S.shape[-1] % 128 == 0 and S.shape[-2] % 8 == 0
+
+
+def chunk_kernel_eligible(S, chunk: int, requested: Optional[bool] = None) -> bool:
+    """`kernel_eligible`, and chunks the kernel can walk: whole sub-blocks,
+    a power of two of them (the inverse joins neighbours)."""
+    n = chunk // BLOCK
+    return chunk % BLOCK == 0 and n & (n - 1) == 0 and kernel_eligible(S, requested)
 
 
 def columns(alpha, k, bk, q, tile: int):
@@ -266,7 +286,7 @@ def _masked(q, k, v, g, beta, length, chunk: int):
     return out
 
 
-def chunk_update(
+def chunk_update_heads(
     S, layer, slots, start, length, q, k, v, g, beta, chunk: int = CHUNK,
     use_kernel: Optional[bool] = None, interpret: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -292,6 +312,46 @@ def chunk_update(
         row = jnp.where(length[p] > 0, new[p], olds[p])
         S = jax.lax.dynamic_update_slice(S, row[None, None], (layer, slots[p], 0, 0, 0))
     return o[:, :Lc], S
+
+
+def qkv_heads(c, H: int, d: int):
+    """The convolution's output [..., 3 H d] -> q (l2-normalised, scaled
+    by d**-0.5), k (l2-normalised), v, each [..., H, d]."""
+    q, k, v = (c[..., i * H * d:(i + 1) * H * d].reshape(*c.shape[:-1], H, d) for i in range(3))
+    l2 = lambda x: x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + L2_EPS)
+    return l2(q) * d ** -0.5, l2(k), v
+
+
+def chunk_update(
+    S, layer, slots, start, length, qkv, g, beta, chunk: int = CHUNK,
+    use_kernel: Optional[bool] = None, interpret: bool = False,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """One prefill chunk per row against the row's carried state, from the
+    convolution's output qkv [P, Lc, 3 H d] (`qkv_heads` says what q, k
+    and v are of it), g [P, Lc, H, d] and beta [P, Lc, H]; slots, start,
+    length as `chunk_update_heads` has them. On the chip ONE launch,
+    `kda_chunk_kernel`, which reads the rows where they lie and normalises
+    q and k itself; elsewhere `qkv_heads` and `chunk_update_heads`. Counts
+    which of the two a traced layer holds. Returns (o [P, Lc, H, d] f32,
+    S')."""
+    _, Lc, H, d = g.shape
+    chunk = min(chunk, Lc)
+    on_kernel = chunk_kernel_eligible(S, chunk, use_kernel)
+    TIMELINE.count_kda_chunk_form("kernel" if on_kernel else "xla")  # when a program is traced
+    if not on_kernel:
+        return chunk_update_heads(S, layer, slots, start, length, *qkv_heads(qkv, H, d), g, beta,
+                                  chunk=chunk, use_kernel=False)
+    f32 = jnp.float32
+    valid = jnp.arange(Lc, dtype=jnp.int32)[None, :] < length[:, None]  # [P, Lc]
+    rows = [qkv.astype(f32), g.astype(f32), jnp.where(valid[..., None], beta.astype(f32), 0.0)]
+    if -Lc % chunk:
+        rows = [jnp.pad(t, ((0, 0), (0, -Lc % chunk)) + ((0, 0),) * (t.ndim - 2)) for t in rows]
+    n_live, unit_rows = _units(length > 0)
+    o, S = kda_chunk_kernel(
+        S, layer, unit_rows, n_live, jnp.clip(slots, 0, S.shape[1] - 1), start, length, *rows,
+        chunk=chunk, block=BLOCK, eps=L2_EPS, interpret=interpret,
+    )
+    return jnp.where((length > 0)[:, None, None, None], o[:, :Lc], 0.0), S
 
 
 def chunk_form(q, k, v, g, beta, chunk: int = CHUNK, use_kernel: bool = False,

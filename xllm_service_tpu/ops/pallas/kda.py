@@ -1,6 +1,7 @@
-"""Pallas TPU kernels of the KDA state pool and of its chunk form's gram
-(ops/kda.py has the equations, the layout and the `jax.numpy` routes these
-must equal).
+"""Pallas TPU kernels of the KDA state pool: the decode update, the chunk
+form of a prefill chunk, and (alone, for the `jax.numpy` chunk form) the
+diagonal of its gram (ops/kda.py has the equations, the layout and the
+`jax.numpy` routes these must equal).
 
 A sequence's delta-rule state is one slot of `S [Lk, slots, H, dk, dv]`
 float32: a head's `[dk, dv]` matrix with the KEY lane on the sublanes and
@@ -33,8 +34,50 @@ indices of the last live step, so Pallas moves nothing for it and its
 body is skipped. With no live row at all the one block that is visited is
 copied through.
 
-The chunk form of a prefill stays in XLA (ops/kda.py `chunk_update`) but
-for the diagonal sub-blocks of its decayed gram:
+`kda_chunk_kernel` (a prefill chunk a row: ops/kda.py's chunk form, whole,
+from the convolution's output): grid (unit, head tile, token tile of up to
+512 tokens), a row's token tiles in order. The operands are the rows AS THE
+PROJECTIONS LEAVE THEM, `[tokens, lanes]` in (8, 128) tiles of (token,
+lane): the convolution's `[P L, 3 H d]` (q | k | v) and the log decay
+`[P L, H d]`; a head is 128 lanes, so its tokens are one `[512, 128]` block
+(a ref a head and operand: Mosaic's strided load wants a 128-lane ref), and
+nothing is copied heads-first on the way in or out. A grid step takes 4
+heads (128 lanes of (head, sub-block) at 32 sub-blocks a head) and
+
+  1. BY POSITION (`_lay_by_position`, the gram kernel's layout: a lane a
+     (head, sub-block), position i of every sub-block one `[d, 128]` tile):
+     lays out g, k and q; l2-normalises q and k there (a head's lanes are
+     the sublanes: a sum over sublanes a token); sums g from its chunk's
+     first token (15 tile adds, then the chunk's earlier sub-blocks' totals
+     by three lane rolls), a token past the row's length counting 0; forms
+     the diagonal sub-blocks of both grams (`_gram_pairs`, the body
+     `kda_gram_kernel` has); inverts `I + diag(beta) M[k]` of every diagonal
+     sub-block by forward substitution IN THAT LAYOUT (row i of 128
+     inverses is 16 multiply-adds of two vregs);
+  2. goes back to a row a token: the inverses and `M[q]` as `[token, 16]`
+     rows (two 128 x 128 transposes, lane rolls, strided stores), G and the
+     normalised k and q as `[head, 512, d]` (a transpose a position);
+  3. chunk by chunk, two heads a loop trip, on the MXU in float32
+     (HIGHEST) around exponentials that are never positive. What is a
+     head's own tokens-by-tokens matrix (the gram, the inverse) is a
+     diagonal block of ONE `[128, 128]` matrix of the two heads, so the
+     products are whole tiles (as `[64, 64]` operands the inverse's joins
+     alone cost 345 us a layer and chunk, as whole tiles all eight square
+     products 447: docs/KERNELS.md): the gram below the diagonal
+     sub-blocks (a row block against the earlier tokens through its entry
+     point), the inverse's joins level by level, `K+ S` and `(q exp G) S`
+     in one product against each head's state, `U~ = T (V - K+ S)`, the
+     output `(q exp G) S + M[q] U~` written into the head's lanes of the
+     token-major output block, and the state `Diag(exp G_C) S + (k exp(G_C
+     - G))^T U~`.
+
+The head's `[d, d]` state lives in the step's OUTPUT block of the pool (the
+row's slot, the tile's heads), which Pallas keeps in VMEM while the slot
+does not change: read from the slot at the row's first token tile (zeros
+if the row starts at 0), written back once after its last. Dead units
+repeat the last live step's blocks and run nothing; with no live row the
+one block visited is copied through. U, W, the grams and the normalised q
+and k never reach HBM.
 
 `kda_gram_kernel` (a prefill chunk: `M[x]_ij = sum_c x_i[c] k_j[c]
 exp(G_i[c] - G_j[c])` for the pairs i >= j of one sub-block of 16 tokens):
@@ -161,18 +204,15 @@ def kda_update_kernel(S, layer, unit_rows, n_live, cols, v, *, interpret=False):
 GRAM_LANES = 128  # (head, sub-block) pairs of one grid step: the lanes of its tiles
 
 
-def _gram_kernel_body(*refs, block: int, sets: int, heads: int, nb: int):
-    arrays = 2 + sets  # G, k and the row sets: `heads` refs [T, d] each, a head a ref
-    ins = [refs[a * heads:(a + 1) * heads] for a in range(arrays)]
-    o_ref = refs[arrays * heads]  # [sets, lanes, block * block]
-    g_s, k_s, *x_s = refs[arrays * heads + 1:arrays * (heads + 1) + 1]  # [block, d, lanes] each
-    m_s = refs[-1]  # [sets, block * block, lanes]: row i * block + j is M_ij of every lane
-    d = g_s.shape[1]
+def _lay_by_position(ins, outs, *, block: int, nb: int):
+    """Each array of `ins` (one ref `[T, d]` a head) into its `[block, d,
+    lanes]` scratch of `outs`: position i of every sub-block (rows i,
+    i + block, ... of a head's tokens: one strided load) side by side on
+    the lanes, the key lane on the sublanes."""
+    heads, d = len(ins[0]), outs[0].shape[1]
 
     def by_position(i, carry):
-        # position i of every sub-block (rows i, i + block, ... of a head's
-        # tokens) side by side on the lanes, the key lane on the sublanes
-        for head_refs, out in zip(ins, (g_s, k_s, *x_s)):
+        for head_refs, out in zip(ins, outs):
             rows = [ref[pl.ds(i, nb, stride=block), :] for ref in head_refs]
             if heads * nb < GRAM_LANES:
                 rows.append(jnp.zeros((GRAM_LANES - heads * nb, d), jnp.float32))
@@ -180,6 +220,13 @@ def _gram_kernel_body(*refs, block: int, sets: int, heads: int, nb: int):
         return carry
 
     jax.lax.fori_loop(0, block, by_position, 0)
+
+
+def _gram_pairs(g_s, k_s, x_s, m_s, *, block: int):
+    """The diagonal sub-blocks' decayed grams of the row sets `x_s` against
+    `k_s`, all by position (`_lay_by_position`): row i * block + j of
+    `m_s[s]` is M_ij of every lane, 0 above the diagonal."""
+    d, sets = g_s.shape[1], len(x_s)
     m_s[...] = jnp.zeros(m_s.shape, jnp.float32)  # above the diagonal: 0
     rows = 8 if d % 8 == 0 else d  # key lanes of one slab: a vreg's sublanes
     for i in range(block):  # token i against every j <= i of its sub-block, all j at once
@@ -195,6 +242,16 @@ def _gram_kernel_body(*refs, block: int, sets: int, heads: int, nb: int):
         sums = jax.lax.fori_loop(0, d // rows, slab, (zero,) * sets)
         for s in range(sets):
             m_s[s, i * block:i * block + i + 1, :] = jnp.sum(sums[s], axis=1)
+
+
+def _gram_kernel_body(*refs, block: int, sets: int, heads: int, nb: int):
+    arrays = 2 + sets  # G, k and the row sets: `heads` refs [T, d] each, a head a ref
+    ins = [refs[a * heads:(a + 1) * heads] for a in range(arrays)]
+    o_ref = refs[arrays * heads]  # [sets, lanes, block * block]
+    g_s, k_s, *x_s = refs[arrays * heads + 1:arrays * (heads + 1) + 1]  # [block, d, lanes] each
+    m_s = refs[-1]  # [sets, block * block, lanes]
+    _lay_by_position(ins, (g_s, k_s, *x_s), block=block, nb=nb)
+    _gram_pairs(g_s, k_s, x_s, m_s, block=block)
     for s in range(sets):  # a (head, sub-block)'s block is one row of the output
         for c in range(0, block * block, GRAM_LANES):
             o_ref[s, :, c:c + GRAM_LANES] = m_s[s, c:c + GRAM_LANES, :].T
@@ -242,3 +299,318 @@ def kda_gram_kernel(G, k, xs, *, block: int, interpret=False):
     out = out[:, :, :, :heads * nb].reshape(*tiles, S, heads, nb, block, block)
     out = out.transpose(2, 1, 3, 0, 4, 5, 6).reshape(S, H, Np // block, block, block)
     return out[:, :, :N // block]
+
+
+_HI = jax.lax.Precision.HIGHEST
+CHUNK_TILE = 512  # tokens of one grid step of the chunk kernel, at most
+HEADS_TOGETHER = 2  # heads of one trip of its loop: 2 x 64 tokens make its products whole tiles
+
+
+def _dot(a, b, dims=((1,), (0,))):
+    return jax.lax.dot_general(
+        a, b, (dims, ((), ())), precision=_HI, preferred_element_type=jnp.float32
+    )
+
+
+def _chunk_kernel_body(meta, rows, slots, start, length, *refs, heads: int, together: int,
+                       nb: int, block: int, chunk: int, eps: float):
+    f32 = jnp.float32
+    q_in, k_in, v_in, g_in = (refs[a * heads:(a + 1) * heads] for a in range(4))  # [TL, d] a head
+    bpos_ref, bt_ref, s_ref, o_ref, so_ref = refs[4 * heads:4 * heads + 5]
+    g_s, k_s, q_s, m_s, inv_s, a_s, tokt_s, tokm_s, G_t, k_t, q_t, v_t = refs[4 * heads + 5:]
+    u, tile = pl.program_id(0), pl.program_id(2)
+    TL, d = k_in[0].shape
+    L, nbc, n_c = GRAM_LANES, chunk // block, TL // chunk
+
+    def sub_blocks(shape):  # (sub-block of the row, sub-block of the column)
+        return tuple(jax.lax.broadcasted_iota(jnp.int32, shape, a) // block for a in (0, 1))
+
+    def by_position():
+        """Stage 1: g, k and q `[block, d, L]` (`_lay_by_position`), then
+        in that layout, where a token is a lane and a head's lane a
+        sublane: q and k l2-normalised (q scaled by d ** -0.5), and g
+        summed from its chunk's first token (G)."""
+        _lay_by_position((g_in, k_in, q_in), (g_s, k_s, q_s), block=block, nb=nb)
+
+        def normalise(i, carry):
+            for ref, scale in ((k_s, 1.0), (q_s, d ** -0.5)):
+                x = ref[i]
+                ref[i] = x * (scale * jax.lax.rsqrt(jnp.sum(x * x, axis=0, keepdims=True) + eps))
+            return carry
+
+        jax.lax.fori_loop(0, block, normalise, 0)
+
+        # a token past the row's length is inert: its g is 0 (beta comes masked)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (d, L), 1)
+        left = length[rows[u]] - tile * TL - lane % nb * block  # valid positions of a lane's sub-block
+
+        def within(i, run):  # ... over the tokens of a sub-block
+            run = run + jnp.where(i < left, g_s[i], 0.0)
+            g_s[i] = run
+            return run
+
+        jax.lax.fori_loop(0, block, within, jnp.zeros((d, L), f32))
+        if nbc > 1:  # ... and over the sub-blocks before it in its chunk: lanes to the left
+            total = g_s[block - 1]
+            place = lane % nb % nbc
+            before = jnp.zeros_like(total)
+            for n in range(1, nbc):
+                before = before + jnp.where(place >= n, pltpu.roll(total, n, 1), 0.0)
+
+            def across(i, carry):
+                g_s[i] = g_s[i] + before
+                return carry
+
+            jax.lax.fori_loop(0, block, across, 0)
+
+    def inverse_rows():
+        """(I + A)^-1 of every diagonal sub-block by forward substitution,
+        by position: row i * block + k of `inv_s` is entry (i, k) of every
+        lane's sub-block. A_ij = beta_i M[k]_ij below the diagonal."""
+        kk = jax.lax.broadcasted_iota(jnp.int32, (block, L), 0)
+
+        def unit(i, carry):
+            inv_s[pl.ds(pl.multiple_of(i * block, block), block), :] = (kk == i).astype(f32)
+            return carry
+
+        jax.lax.fori_loop(0, block, unit, 0)
+
+        def row(i, carry):  # row i from the rows above it (a_ij = 0 from j = i on)
+            at = pl.ds(pl.multiple_of(i * block, block), block)
+            a_s[...] = jnp.where(kk < i, m_s[0, at, :] * bpos_ref[pl.ds(i, 1), :], 0.0)
+
+            def term(j, acc):
+                above = inv_s[pl.ds(pl.multiple_of(j * block, block), block), :]
+                return acc + a_s[pl.ds(j, 1), :] * above
+
+            acc = jax.lax.fori_loop(0, block, term, jnp.zeros((block, L), f32))
+            inv_s[at, :] = (kk == i).astype(f32) - acc
+            return carry
+
+        jax.lax.fori_loop(1, block, row, 0)
+
+    def token_rows(src, out):
+        """By position `[block * block, L]` -> a row a token: row
+        (lane * block + i) of `out`, which is token (sub-block, i) of the
+        lane's head, holds entries (i, 0..block) of its sub-block on its
+        first `block` lanes (the other lanes hold other rows' entries)."""
+        per = L // block  # positions of one transposed tile
+        for half in range(block * block // L):
+            tile_t = src[half * L:(half + 1) * L, :].T  # [lane, (i, k)]
+            for ii in range(per):
+                piece = tile_t if ii == 0 else pltpu.roll(tile_t, L - ii * block, 1)
+                out[pl.ds(half * per + ii, L, stride=block), :] = piece
+
+    def token_major():
+        """G, k and q back to a row a token, a head its own `[TL, d]`."""
+        def back(i, carry):
+            for src, out in ((g_s, G_t), (k_s, k_t), (q_s, q_t)):
+                rows_t = src[i].T  # [lane, d]
+                for h in range(heads):
+                    out[h, pl.ds(i, nb, stride=block), :] = rows_t[h * nb:(h + 1) * nb]
+            return carry
+
+        jax.lax.fori_loop(0, block, back, 0)
+        for h in range(heads):
+            v_t[h] = v_in[h][...]
+
+    W = together * chunk  # rows of the heads of one trip, a head after the other
+
+    def block_diagonal(rows_, masks):
+        """[W, W]: the sub-blocks of `rows_` (token rows [W, L]) on the
+        diagonal, 0 elsewhere."""
+        rb, cb = masks
+        out = jnp.zeros((W, L), f32)
+        for b in range(W // block):
+            piece = pltpu.roll(rows_, b * block, 1) if b else rows_
+            out = jnp.where((rb == b) & (cb == b), piece, out)
+        return out[:, :W]
+
+    def per_heads(first, c, masks):
+        """Stage 3, chunk c of `together` heads from `first` on against
+        their states: what is a head's own matrix of tokens by tokens (the
+        gram, the inverse) is a diagonal block of ONE [W, W] matrix, so
+        that the products are whole MXU tiles."""
+        wide, own_head, pairs = masks
+        hs_ = [first + j for j in range(together)]
+        at = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+        G, k, q, v = ([ref[h, at, :] for h in hs_] for ref in (G_t, k_t, q_t, v_t))
+        beta = bt_ref[pl.ds(first // together * n_c + c, 1), :]  # [1, W]: a column's factor
+        # the gram below the diagonal sub-blocks: a row block against every
+        # earlier token through its entry point (no exponent positive)
+        mk, mq = [[jnp.zeros((block, W), f32)] for _ in hs_], [[jnp.zeros((block, W), f32)] for _ in hs_]
+        for a in range(1, nbc):
+            own, before = slice(a * block, (a + 1) * block), slice(0, a * block)
+            xk, xq, km = [], [], []
+            for j in range(together):
+                ref = G[j][a * block - 1:a * block, :]
+                e = jnp.exp(jnp.minimum(G[j][own] - ref, 0.0))
+                xk.append(k[j][own] * e)
+                xq.append(q[j][own] * e)
+                km.append(k[j][before] * jnp.exp(jnp.minimum(ref - G[j][before], 0.0)))
+                km.append(jnp.zeros((chunk - a * block, d), f32))
+            # [2 W / nbc, W]: a head's rows against its own tokens only, 0 from its own column on
+            p = _dot(jnp.concatenate(xk + xq, axis=0), jnp.concatenate(km, axis=0), ((1,), (1,)))
+            p = jnp.where(own_head, p, 0.0)
+            for j in range(together):
+                mk[j].append(p[j * block:(j + 1) * block])
+                mq[j].append(p[(together + j) * block:(together + j + 1) * block])
+        Mk = jnp.concatenate([piece for m in mk for piece in m], axis=0)
+        # T = (I + diag(beta) M[k])^-1 diag(beta): the diagonal sub-blocks' inverses, neighbours
+        # joined level by level (beta scales a column of the left factor: X diag(beta) M X)
+        tok_at = [pl.ds(pl.multiple_of(h * TL + c * chunk, chunk), chunk) for h in hs_]
+        X = block_diagonal(jnp.concatenate([tokt_s[t, :] for t in tok_at], axis=0), wide)
+        for pair in pairs:
+            X = X - _dot(X * beta, _dot(jnp.where(pair, Mk, 0.0), X))
+        Mq = block_diagonal(jnp.concatenate([tokm_s[t, :] for t in tok_at], axis=0), wide) \
+            + jnp.concatenate([piece for m in mq for piece in m], axis=0)
+        S = [so_ref[h].astype(f32) for h in hs_]
+        eG = [jnp.exp(x) for x in G]
+        KQ = [_dot(jnp.concatenate([k[j] * eG[j], q[j] * eG[j]], axis=0), S[j])  # K+ S | (q exp G) S
+              for j in range(together)]
+        rest = jnp.concatenate([v[j] - KQ[j][:chunk] for j in range(together)], axis=0)
+        Ut = _dot(X * beta, rest)  # T (V - K+ S), the heads' one under the other
+        o = jnp.concatenate([x[chunk:] for x in KQ], axis=0) + _dot(Mq, Ut)
+        for j, h in enumerate(hs_):
+            o_ref[at, pl.ds(pl.multiple_of(h * d, d), d)] = o[j * chunk:(j + 1) * chunk]
+            GC = G[j][chunk - 1:chunk, :]
+            decay = jnp.broadcast_to(jnp.exp(GC), (8, d)).T[:, :1]  # along the key lane: a column
+            moved = _dot(k[j] * jnp.exp(GC - G[j]), Ut[j * chunk:(j + 1) * chunk], ((0,), (0,)))
+            so_ref[h] = (decay * S[j] + moved).astype(so_ref.dtype)
+
+    @pl.when(u < meta[0])
+    def _live():
+        @pl.when(tile == 0)
+        def _first():  # the state rides the output block; a row that starts at 0 ignores its slot
+            so_ref[...] = jnp.where(start[rows[u]] > 0, s_ref[...], jnp.zeros_like(s_ref))
+
+        by_position()
+        _gram_pairs(g_s, k_s, (k_s, q_s), m_s, block=block)
+        inverse_rows()
+        token_rows(inv_s, tokt_s)
+        token_rows(m_s.at[1], tokm_s)
+        token_major()
+        # what no trip changes, once: the sub-block of a row and of a lane, whose rows are
+        # whose tokens, and the pairs of sub-blocks that each level of the inverse joins
+        rb, cb = sub_blocks((W, W))
+        pairs, size = [], 1
+        while size < nbc:
+            pairs.append((rb // size % 2 == 1) & (cb // size == rb // size - 1))
+            size *= 2
+        rows_of, tokens_of = sub_blocks((2 * together * block, W))
+        own_head = rows_of % together == tokens_of // nbc
+        masks = (sub_blocks((W, L)), own_head, pairs)
+        trips = heads // together
+
+        def step(it, carry):  # a chunk after the one before it, every head
+            per_heads(it % trips * together, it // trips, masks)
+            return carry
+
+        jax.lax.fori_loop(0, n_c * trips, step, 0)
+
+    @pl.when(meta[0] == 0)
+    def _none():
+        so_ref[...] = s_ref[...]
+
+
+def chunk_tile(length: int, chunk: int) -> int:
+    """Tokens of one grid step: the largest whole number of chunks that
+    divides `length` and is at most CHUNK_TILE."""
+    return max(t for t in range(chunk, max(min(length, CHUNK_TILE), chunk) + 1, chunk)
+               if length % t == 0)
+
+
+def kda_chunk_kernel(S, layer, unit_rows, n_live, slots, start, length, qkv, g, beta, *,
+                     chunk: int, block: int, eps: float, interpret=False):
+    """The chunk form of a prefill chunk a row, against the row's slot of
+    S [Lk, slots, H, dk, dv], from the convolution's output: qkv
+    [P, L, 3 H d] f32 (q | k | v side by side, NOT normalised: the kernel
+    l2-normalises q and k over a head's lanes under the root of (sum of
+    squares + eps) and scales q by d ** -0.5), g [P, L, H, d] and beta
+    [P, L, H] f32, all token-major as the projections leave them; L a
+    whole number of `chunk`s (of `block`-token sub-blocks, a power of two
+    of them). A token past its row's `length` is inert: the kernel reads
+    its g as 0, and its beta comes as 0. unit_rows [P]: live rows first,
+    then the last live row repeated; n_live how many; slots, start,
+    length [P] int32 (start 0: the slot's content is ignored). Returns (o [P, L, H, d] f32; a dead row's is not
+    written, S')."""
+    P, Lp, H, d = g.shape
+    TL = chunk_tile(Lp, chunk)
+    nL, nb, n_c = Lp // TL, TL // block, TL // chunk
+    heads = head_tile(H, GRAM_LANES // nb)  # of one grid step: 128 lanes of (head, sub-block)
+    T = H // heads
+    i32 = jnp.int32
+    meta = jnp.stack([jnp.asarray(n_live, i32), jnp.asarray(layer, i32)])
+    # beta twice, both small: by position [.., i, (head, sub-block)], and a row a (trip of
+    # heads, chunk)
+    bpos = beta.reshape(P, nL, nb, block, T, heads).transpose(0, 1, 4, 3, 5, 2)
+    bpos = jnp.pad(bpos.reshape(P, nL, T, block, heads * nb),
+                   ((0, 0),) * 4 + ((0, GRAM_LANES - heads * nb),))
+    together = HEADS_TOGETHER if heads % HEADS_TOGETHER == 0 else 1
+    bt = beta.reshape(P, nL, n_c, chunk, T, heads // together, together)
+    bt = bt.transpose(0, 1, 4, 5, 2, 6, 3).reshape(P, nL, T, heads // together * n_c, together * chunk)
+
+    def where(u, t, l, meta):  # a dead unit keeps the last live step's blocks
+        live = u < meta[0]
+        return jnp.where(live, t, T - 1), jnp.where(live, l, nL - 1)
+
+    def head_rows(part, h):  # a head's lanes of a row of tokens: of q, k, v (qkv) or of g
+        def index(u, t, l, meta, rows, *_):
+            t, l = where(u, t, l, meta)
+            return (rows[u] * nL + l, part * H + t * heads + h)
+        return pl.BlockSpec((TL, d), index)
+
+    def tile_rows(u, t, l, meta, rows, *_):
+        t, l = where(u, t, l, meta)
+        return (rows[u] * nL + l, t)
+
+    def small(u, t, l, meta, rows, *_):
+        t, l = where(u, t, l, meta)
+        return (rows[u], l, t, 0, 0)
+
+    def state(u, t, l, meta, rows, slots, *_):
+        return (meta[1], slots[rows[u]], where(u, t, l, meta)[0], 0, 0)
+
+    s_spec = pl.BlockSpec((None, None, heads, d, d), state)
+    by_head = pltpu.VMEM((heads, TL, d), jnp.float32)
+    by_position = pltpu.VMEM((block, d, GRAM_LANES), jnp.float32)
+    token_rows = pltpu.VMEM((GRAM_LANES * block, GRAM_LANES), jnp.float32)
+    qkv2, g2 = qkv.reshape(P * Lp, 3 * H * d), g.reshape(P * Lp, H * d)
+    o, S = pl.pallas_call(
+        functools.partial(_chunk_kernel_body, heads=heads, together=together, nb=nb, block=block,
+                          chunk=chunk, eps=eps),
+        name="kda_chunk_kernel",  # op name in the device trace
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(P, T, nL),
+            in_specs=[head_rows(part, h) for part in (0, 1, 2, 0) for h in range(heads)] + [
+                pl.BlockSpec((None, None, None, block, GRAM_LANES), small),
+                pl.BlockSpec((None, None, None, heads // together * n_c, together * chunk), small),
+                s_spec,
+            ],
+            out_specs=[pl.BlockSpec((TL, heads * d), tile_rows), s_spec],
+            scratch_shapes=[by_position] * 3 + [
+                pltpu.VMEM((2, block * block, GRAM_LANES), jnp.float32),
+                pltpu.VMEM((block * block, GRAM_LANES), jnp.float32),
+                pltpu.VMEM((block, GRAM_LANES), jnp.float32),
+                token_rows, token_rows,
+            ] + [by_head] * 4,
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((P * Lp, H * d), jnp.float32),
+            jax.ShapeDtypeStruct(S.shape, S.dtype),
+        ],
+        input_output_aliases={5 + 4 * heads + 2: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=64 * 2 ** 20,
+        ),
+        cost_estimate=pl.CostEstimate(
+            flops=P * Lp * H * (2 * d * (block + 1) + 2 * (3 * d * d + 4 * chunk * d + 2 * chunk * chunk)),
+            transcendentals=P * Lp * H * d * (block + 1) // 2,
+            bytes_accessed=4 * (5 * P * Lp * H * d + 2 * P * H * d * d),
+        ),
+        interpret=interpret,
+    )(meta, unit_rows.astype(i32), slots.astype(i32), start.astype(i32), length.astype(i32),
+      *([qkv2] * (3 * heads)), *([g2] * heads), bpos, bt, S)
+    return o.reshape(P, Lp, H, d), S
