@@ -1039,6 +1039,55 @@ def run_sparse_case(R, Hq, Hkv, D, BS, CB, L, N, ctx_lo, ctx_hi, chunk, chunk_st
     return max(err, err_c)
 
 
+def run_moe_case(rows, X, E, F, K=8, L=4):
+    """Both grouped expert kernels (`moe_grouped_kernel`,
+    `moe_grouped_down_kernel`) as a step program calls them: the layers'
+    stacked leaves of X held experts of width F and a layer index, `rows`
+    token rows x K pairs sorted by expert (every token K DISTINCT experts,
+    uniformly: no selection bias). Error against `expert_product_reference`
+    (every expert over every row) on the first and the last layer; us a
+    call, the two launches' own device time, the experts touched and the
+    share of the roofline (the touched experts' matrices once, each pair's
+    row in and out, over 819 GB/s; or the pairs' FLOPs over 197 TFLOP/s)."""
+    from xllm_service_tpu.ops.moe import expert_product_reference
+    from xllm_service_tpu.ops.pallas.moe_dispatch import moe_grouped_kernel
+
+    rng = np.random.default_rng(0)
+    chosen = np.argsort(rng.random((rows, X)), axis=1)[:, :K]
+    sizes = np.bincount(chosen.reshape(-1), minlength=X).astype(np.int32)
+    M = rows * K
+    keys = jax.random.split(jax.random.key(0), 4)
+    draw = lambda key, a, b: jax.jit(lambda: jax.lax.map(
+        lambda k_: (jax.random.normal(k_, (X, a, b), jnp.float32) / np.sqrt(a)).astype(jnp.bfloat16),
+        jax.random.split(key, L),  # a layer at a time on the chip
+    ))()
+    wg, wu, wd = draw(keys[0], E, F), draw(keys[1], E, F), draw(keys[2], F, E)
+    xs = jax.random.normal(keys[3], (M, E), jnp.bfloat16)
+    gs = jnp.asarray(sizes)
+    # (the stacks are arguments: a closed-over array is a constant of the program)
+    jker = jax.jit(lambda x, a, b, c, l: moe_grouped_kernel(x, gs, a, b, c, layer=l))
+    ker = lambda l=0: jker(xs, wg, wu, wd, jnp.int32(l))
+    jref = jax.jit(lambda x, a, b, c: expert_product_reference(x, gs, a, b, c))
+    err = 0.0
+    for l in (0, L - 1):
+        got = np.asarray(ker(l).astype(jnp.float32))
+        ref = np.asarray(jref(xs, wg[l], wu[l], wd[l]).astype(jnp.float32))
+        err = max(err, float(np.max(np.abs(got - ref))))
+    tk = bench(ker)
+    up, n_up = device_us(ker, "moe_grouped_kernel")
+    down, n_down = device_us(ker, "moe_grouped_down_kernel")
+    touched = int((sizes > 0).sum())
+    need_bytes = touched * 3 * E * F * 2 + 2 * M * E * 2
+    floor = max(need_bytes / 819e9, M * 6 * E * F / 197e12)
+    print(
+        f"MOE rows={rows} pairs={M} X={X} E={E} F={F} L={L} touched={touched} "
+        f"pairs/expert={M / X:.1f} err={err:.4f} call={tk*1e6:8.1f}us "
+        f"gate_up={up:7.1f}us (x{n_up}) down={down:7.1f}us (x{n_down}) "
+        f"floor={floor*1e6:7.1f}us roofline={100*floor/((up + down)*1e-6):5.1f}%"
+    )
+    return err
+
+
 CASES = [
     # The decode kernel at the benchmark cells' own shapes (PERF.md, PR 40):
     # qwen2.5-3b.decode-batch (125 of 128 rows live, 256-768 tokens, table
@@ -1109,6 +1158,28 @@ CASES = [
     ("sparse-longdoc", run_sparse_case,
      dict(R=32, Hq=32, Hkv=2, D=128, BS=64, CB=1024, L=2, N=20000, ctx_lo=9000,
           ctx_hi=49000, chunk=4096, chunk_start=28672)),
+    # laguna-xs.2.agent-steady's launches (PERF.md, PR 60): the decode kernel
+    # at a query group of 6 (48 / 8 heads of 128, padded to 8 sublanes) over
+    # the cut's 2 full layers, 16 of 64 rows live at contexts of 1k-8k (a
+    # 72-column table: the gather oracle reads every column of every row, and
+    # the cell's 264 would be 4 GB of it); the window launch at 64 / 8 heads over the 3 window
+    # layers, a window of 512 positions (FOUR blocks) and no sink; one
+    # 512-token chunk through the flash kernel at each geometry; and both
+    # grouped expert kernels at 256 held experts of width 512: a decode step
+    # of 64 rows (2 pairs an expert, ~221 touched) and a mixed step of 576
+    # (18 an expert: 36 row tiles met by 256 experts).
+    ("cell-agent-full", run_cell_case,
+     dict(R=64, Hq=48, Hkv=8, D=128, BS=128, MB=72, L=2, N=1200, live=16,
+          ctx_lo=1024, ctx_hi=8192)),
+    ("cell-agent-window", run_cell_case,
+     dict(R=64, Hq=64, Hkv=8, D=128, BS=128, MB=72, L=3, N=1200, live=16,
+          ctx_lo=1024, ctx_hi=8192, window=512)),
+    ("prefill-group6", run_prefill_case,
+     dict(P=1, Lpad=512, Hq=48, Hkv=8, D=128, BS=128, MB=24)),
+    ("prefill-window4", run_prefill_case,
+     dict(P=1, Lpad=512, Hq=64, Hkv=8, D=128, BS=128, MB=24, window=512)),
+    ("moe-agent-decode", run_moe_case, dict(rows=64, X=256, E=2048, F=512)),
+    ("moe-agent-mixed", run_moe_case, dict(rows=576, X=256, E=2048, F=512)),
     # solar-open2-250b.think-steady's chunk (PERF.md, PR 54): the decayed
     # gram of one 512-token prefill chunk of a KDA layer, 64 heads of 128
     # lanes in 8 chunks of 64: its diagonal sub-blocks as `kda_gram_kernel`

@@ -30,9 +30,10 @@ def configurations():
 
 
 def test_the_benchmark_has_both_families():
-    # eight since PR 56 (the name stays: the driver counts tests by name)
+    # nine since PR 60 (the name stays: the driver counts tests by name)
     assert {c["family"] for c in configurations()} == {
-        "llama", "brumby", "deepseek", "granite", "solar", "mimo", "falcon_h1", "minicpm_sala"}
+        "llama", "brumby", "deepseek", "granite", "solar", "mimo", "falcon_h1", "minicpm_sala",
+        "laguna"}
 
 
 @pytest.mark.parametrize("config", configurations(), ids=lambda c: c["name"])
@@ -57,9 +58,9 @@ def test_model_config_is_the_family_the_program_dispatches_on(config):
 
     cfg = family_mod.load(config).model_config(config["name"], config)
     module = models.get_module(cfg).__name__.rsplit(".", 1)[-1]
-    # the five hybrids are ONE stack: models/granite.py, the layer kinds as data
+    # the six hybrids are ONE stack: models/granite.py, the layer kinds as data
     assert module == {"solar": "granite", "mimo": "granite", "falcon_h1": "granite",
-                      "minicpm_sala": "granite"}.get(
+                      "minicpm_sala": "granite", "laguna": "granite"}.get(
         config["family"], config["family"])
     assert cfg.is_retention == (config["family"] == "brumby")
 
@@ -317,6 +318,70 @@ def test_the_mimo_family_is_the_cut_and_draws_sinks_that_take_their_share():
         assert float(jnp.std(leaf.astype(jnp.float32))) > 0.0
     assert abs(float(jnp.std(w["layers"]["w_down"])) * np.sqrt(32) / fam.ROUTED_OUT_SCALE - 1.0) < 0.1
     assert abs(float(jnp.std(w["lm_head"])) * np.sqrt(64) - 1.0) < 0.05
+
+
+def test_the_laguna_family_is_the_cut_and_draws_a_gate_that_is_no_constant():
+    """laguna-xs.2 as cut: published layers 0-4 of 40 and NOTHING else cut
+    (all 256 experts, both head counts, the whole vocabulary; the published
+    per-layer lists whole and read at `layers_held`; every number of the
+    catalog row's config but the depth); and at the rehearsal size the
+    draws: the gate's pre-activation ~ N(0, 1) a head (sigmoid(g) spreads,
+    it is no constant), the routed experts' down matrices at
+    ROUTED_OUT_SCALE, the dense and shared down matrices at MLP_OUT_GAIN,
+    both output projections plain (a gain there makes the routing
+    degenerate: families/laguna.py), no gain at 1, nothing at 0."""
+    import jax
+    import jax.numpy as jnp
+
+    with open(os.path.join(BENCH, "configs", "laguna-xs.2.json")) as f:
+        config = json.load(f)
+    fam = family_mod.load(config)
+    cfg = fam.model_config(config["name"], config)
+    assert config["reduced"] == ["num_hidden_layers"] and config["layers_held"] == [0, 1, 2, 3, 4]
+    assert (config["num_hidden_layers"], config["num_hidden_layers_published"]) == (5, 40)
+    for key in ("layer_types", "mlp_layer_types", "num_attention_heads_per_layer"):
+        assert len(config[key]) == 40, key
+    assert config["num_attention_heads_per_layer"][:5] == [48, 64, 64, 64, 48]
+    assert (cfg.num_experts, cfg.held_experts, cfg.vocab_size, cfg.num_layers) == (256, (0, 256), 100352, 5)
+    assert cfg.layer_types == ("attention", "window", "window", "window", "attention")
+    assert (cfg.first_k_dense_replace, cfg.n_shared_experts, cfg.routed_scaling_factor) == (1, 1, 2.5)
+    assert (cfg.attn_heads("attention"), cfg.attn_heads("window"), cfg.num_kv_heads) == (48, 64, 8)
+    assert (cfg.rotary_dim, cfg.window_rotary_dim, cfg.sliding_window) == (64, 128, 512)
+    assert (cfg.rope_scaling_type, cfg.rope_scaling_factor, cfg.rope_beta_fast) == ("yarn", 64.0, 64.0)
+    assert (cfg.scoring_func, cfg.topk_method, cfg.norm_topk_prob) == ("sigmoid", "plain", True)
+    assert cfg.attn_gate and cfg.attn_gate_per_head and not cfg.window_sink
+    shapes = fam.weight_shapes(config)
+    assert shapes["layers"]["router"] == (4, 2048, 256) and shapes["layers"]["w_gate"] == (4, 256, 2048, 512)
+    assert shapes["layers"]["w_sh_down"] == (4, 512, 2048) and shapes["dense_layers"]["w_gate"] == (1, 2048, 8192)
+    assert shapes["attn"]["wq"] == (2, 2048, 48 * 128) and shapes["attn"]["w_ogate"] == (2, 2048, 48)
+    assert shapes["attn_w"]["wo"] == (3, 64 * 128, 2048) and shapes["attn_w"]["w_ogate"] == (3, 2048, 64)
+    sizes = jax.tree.leaves(shapes, is_leaf=lambda x: isinstance(x, tuple))
+    norms = 2 * 5 * 2048 + 2048
+    assert sum(int(np.prod(s)) for s in sizes) - norms == 3_869_835_264  # 7.74 GB in bfloat16
+    # the count that settles the gate: the published 33.4 B without a per-lane gate
+    E, D = 2048, 128
+    attn = lambda h: E * (2 * h * D + 2 * 8 * D)
+    whole = (39 * (256 * 3 * E * 512 + 3 * E * 512 + E * 256) + 2 * 100352 * E + 3 * E * 8192
+             + 10 * attn(48) + 30 * attn(64))
+    assert round(whole / 1e9, 2) == 33.44
+    assert round((whole + E * D * (10 * 48 + 30 * 64)) / 1e9, 2) == 34.07  # a gate a lane
+    assert E * (10 * 48 + 30 * 64) < 5e6  # a gate a head
+
+    with open(os.path.join(BENCH, "configs", "rehearse-laguna-tiny.json")) as f:
+        tiny = json.load(f)
+    w = jax.jit(lambda k: fam.make_weights(tiny, k, jnp.float32))(family_mod.seed_key(3))
+    u = jax.random.normal(jax.random.key(1), (512, 64))
+    g = jax.nn.sigmoid(u @ w["attn_w"]["w_ogate"][0])
+    assert 0.8 < float(jnp.std(u @ w["attn"]["w_ogate"][0])) < 1.2
+    assert float(g.min()) < 0.15 and float(g.max()) > 0.85 and 0.15 < float(jnp.std(g)) < 0.25
+    for leaf in jax.tree.leaves(w):  # nothing at a value that lets a path skip it
+        assert float(jnp.std(leaf.astype(jnp.float32))) > 0.0
+    std = lambda a, fan_in: float(jnp.std(a)) * np.sqrt(fan_in)
+    assert abs(std(w["layers"]["w_down"], 32) / fam.ROUTED_OUT_SCALE - 1.0) < 0.1
+    assert abs(std(w["layers"]["w_sh_down"], 32) / fam.MLP_OUT_GAIN - 1.0) < 0.1
+    assert abs(std(w["dense_layers"]["w_down"], 96) / fam.MLP_OUT_GAIN - 1.0) < 0.1
+    assert abs(std(w["attn"]["wo"], 96) - 1.0) < 0.1 and abs(std(w["attn_w"]["wo"], 128) - 1.0) < 0.1
+    assert abs(std(w["lm_head"], 64) - 1.0) < 0.05
 
 
 def test_the_falcon_h1_family_is_the_cut_and_draws_against_its_multipliers():

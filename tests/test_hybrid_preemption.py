@@ -11,7 +11,9 @@ of both) and for a family whose EVERY layer holds a state slot and K/V
 blocks (`falcon-h1-tiny`) and for a family whose sparse layers keep
 compressed-key rows beside their K/V blocks and whose other layers hold a
 lightning state slot (`minicpm-sala-tiny`: its sequences run past
-dense_len, so a resumed one selects its pages again)."""
+dense_len, so a resumed one selects its pages again) and for a window family
+whose window is SEVERAL blocks and whose two kinds differ in query heads
+(`laguna-tiny`: a preempted sequence gives back up to four window blocks)."""
 
 import numpy as np
 import pytest
@@ -22,13 +24,16 @@ from xllm_service_tpu.runtime.engine import EngineRequest, InferenceEngine
 from xllm_service_tpu.runtime.executor import ModelExecutor
 
 
-MODELS = ["llama3-tiny", "solar-tiny", "mimo-tiny", "falcon-h1-tiny", "minicpm-sala-tiny"]
+MODELS = ["llama3-tiny", "solar-tiny", "mimo-tiny", "falcon-h1-tiny", "minicpm-sala-tiny",
+          "laguna-tiny"]
 
 
 def _engine(model, R=4, num_blocks=64):
     cfg = EngineConfig(
         # (the sparse family's page is its selection's block)
-        model=model, dtype="float32", block_size=8 if model == "minicpm-sala-tiny" else 16,
+        # (... and laguna-tiny's window of 24 is three blocks of 8)
+        model=model, dtype="float32",
+        block_size=8 if model in ("minicpm-sala-tiny", "laguna-tiny") else 16,
         num_blocks=num_blocks, max_running_requests=R, max_seq_len=256,
         prefill_buckets=[32, 64, 128],
         # a state family's engines step synchronously, as tests/test_granite.py's

@@ -689,6 +689,102 @@ def test_lightning_update_kernel_compiles_at_published_widths(one_chip, no_persi
     assert _kernel_calls(text, "lightning_update_kernel") == 1
 
 
+@pytest.mark.parametrize("kind", ["full", "window"])
+def test_laguna_attention_launches_compile_at_published_widths(one_chip, no_persistent_cache, kind):
+    """The launches of laguna-xs.2.agent-steady at their new geometry: 64
+    decode rows and a 512-row chunk over 8 KV heads of 128 lanes, tables
+    264 blocks wide; the full layers at 48 query heads (a group of 6, which
+    the decode kernel pads to 8 sublanes and the flash kernel tiles at TQ x
+    6 rows), the window layers at 64 heads over a window of 512 positions
+    (four blocks) with NO sink."""
+    from xllm_service_tpu.ops.pallas import paged_attention as pa
+    from xllm_service_tpu.ops.pallas.flash_prefill import flash_prefill_kernel
+
+    s, _ = _kernel_shapes(one_chip)
+    heads, window, layers = (48, 0, 2) if kind == "full" else (64, 512, 3)
+    pool = s((layers, 700, 8, BS, 128))
+    text = _compile(
+        lambda q, k, v, bt, sl: pa.paged_attention_kernel(
+            q, k, v, bt, sl, SCALE, window=window, layer=jnp.int32(1)),
+        s((64, heads, 128)), pool, pool, s((64, 264), jnp.int32), s((64,), jnp.int32),
+    )
+    name = "window_paged_attention_kernel" if window else "paged_attention_kernel"
+    assert _kernel_calls(text, name) == 1
+    text = _compile(
+        lambda q, k, v, bt, sp, tl: flash_prefill_kernel(
+            q, k, v, bt, sp, tl, SCALE, window=window, layer=jnp.int32(1)),
+        s((1, 512, heads, 128)), pool, pool, s((1, 264), jnp.int32), s((1,), jnp.int32),
+        s((1,), jnp.int32),
+    )
+    name = "window_flash_prefill_kernel" if window else "flash_prefill_kernel"
+    assert _kernel_calls(text, name) == 1
+
+
+@pytest.mark.parametrize("pairs", [512, 4608])
+def test_grouped_expert_kernels_compile_at_256_held_experts(one_chip, no_persistent_cache, pairs):
+    """laguna-xs.2's expert product: 64 decode rows x 8 (2 pairs an expert)
+    and 576 rows x 8 (18 an expert, 36 row tiles met by 256 experts), the
+    layers' stacked leaves of 256 experts of width 512 and a layer index,
+    no layer and no expert sliced out."""
+    from xllm_service_tpu.ops.pallas.moe_dispatch import moe_grouped_kernel
+
+    s, _ = _mla_shapes(one_chip)
+    text = _compile(
+        lambda x, g, a, b, c, l: moe_grouped_kernel(x, g, a, b, c, layer=l),
+        s((pairs, 2048)), s((256,), jnp.int32), s((4, 256, 2048, 512)),
+        s((4, 256, 2048, 512)), s((4, 256, 512, 2048)), s((), jnp.int32),
+    )
+    assert "moe_grouped_kernel" in text and "moe_grouped_down_kernel" in text
+    _assert_nothing_moves(text, {"4,256,2048,512", "256,2048,512", "4,256,512,2048", "256,512,2048"})
+
+
+@pytest.mark.parametrize("step", ["decode", "mixed"])
+def test_laguna_steps_compile_at_published_widths(one_chip, no_persistent_cache, as_on_tpu, step):
+    """laguna-xs.2 as the benchmark cuts it (a dense full layer, three window
+    layers, a full layer; ALL 256 experts a layer, the whole vocabulary):
+    the decode step of 64 rows and the mixed step with a 512-row chunk,
+    every table 2 x 264 blocks wide, fit the chip beside 7.74 GB of
+    weights, 3,200 full blocks and the 393 window blocks
+    `_decide_window_blocks` gives 64 slots, with both kinds' decode
+    launches, the K/V write, both grouped expert kernels and (mixed) both
+    kinds' flash launches in them; neither pool is laid out again and the
+    temporaries stay under 100 MB."""
+    import re
+
+    from xllm_service_tpu.models import granite
+
+    cfg = get_model_config("laguna-xs.2")
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.eval_shape(lambda k: granite.init_params(cfg, k, jnp.bfloat16), jax.random.key(0))
+    params = jax.tree.map(lambda a: s(a.shape, a.dtype), params)
+    R, i32, CB = 64, jnp.int32, 264
+    (kf, vf), (kw, vw) = granite.pool_shapes(cfg, 3200, 1 + R * 6 + 8, BS)
+    pools = ((s(kf), s(kw)), (s(vf), s(vw)))
+    dec = (s((R,), i32), s((R,), i32), s((R, 2 * CB), i32), s((R,), jnp.bool_))
+    if step == "decode":
+        fn, args = granite.decode_step, (params, *pools, *dec)
+    else:
+        pf = (s((1, 512), i32), s((1,), i32), s((1,), i32), s((1, 2 * CB), i32))
+        fn, args = granite.mixed_step, (params, *pools, *dec, *pf)
+    compiled = jax.jit(
+        lambda p, k, v, *a: fn(p, cfg, k, v, *a), donate_argnums=(1, 2)
+    ).lower(*args).compile()
+    text = compiled.as_text()
+    kernels = ["paged_attention_kernel", "window_paged_attention_kernel", "kv_write_kernel",
+               "moe_grouped_kernel", "moe_grouped_down_kernel"]
+    for kernel in kernels + ["flash_prefill_kernel", "window_flash_prefill_kernel"] * (step == "mixed"):
+        assert kernel in text, kernel
+    pools_ = {",".join(map(str, sh)) for sh in (kf, kw)}
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if re.search(r" (copy|transpose|bitcast-convert)\(", line)
+              and any(f"[{p}]" in line.split("=")[1][:80] for p in pools_ if "=" in line)]
+    assert not copies, copies
+    assert compiled.memory_analysis().temp_size_in_bytes < 100e6
+
+
 @pytest.mark.parametrize("step", ["decode", "mixed"])
 def test_minicpm_sala_steps_compile_at_published_widths(one_chip, no_persistent_cache, as_on_tpu, step):
     """minicpm-sala as the benchmark cuts it (a sparse layer, six lightning
@@ -838,6 +934,25 @@ def _in_place_case(one_chip, case):
         pf = (s((1, 512)), s((1,)), s((1,)), s((1, 256)))
         fn = lambda p, k, v, *a: granite.mixed_step(p, cfg, k, v, *a)  # noqa: E731
         return fn, (params, *pools, *dec(64, 256), *pf), names
+    if case.startswith("laguna"):
+        from xllm_service_tpu.models import granite
+
+        cfg = dataclasses.replace(  # the dense full layer, a scan of two window layers, a full one
+            get_model_config("laguna-xs.2"), num_layers=4,
+            layer_types=("attention", "window", "window", "attention"), vocab_size=8192,
+        )
+        params = jax.eval_shape(lambda k: granite.init_params(cfg, k, jnp.bfloat16), jax.random.key(0))
+        params = jax.tree.map(lambda a: s(a.shape, a.dtype), params)
+        (kf, vf), (kw, vw) = granite.pool_shapes(cfg, 600, 393, BS)
+        pools = tuple((s(full, jnp.bfloat16), s(win, jnp.bfloat16)) for full, win in ((kf, kw), (vf, vw)))
+        names = ("wq", "wk", "wv", "wo", "w_ogate", "w_gate", "w_up", "w_down",
+                 "w_sh_gate", "w_sh_up", "w_sh_down")
+        if case == "laguna-decode-64":
+            fn = lambda p, k, v, *a: granite.decode_step(p, cfg, k, v, *a)  # noqa: E731
+            return fn, (params, *pools, *dec(64, 528)), names
+        pf = (s((1, 512)), s((1,)), s((1,)), s((1, 528)))
+        fn = lambda p, k, v, *a: granite.mixed_step(p, cfg, k, v, *a)  # noqa: E731
+        return fn, (params, *pools, *dec(64, 528), *pf), names
     if case.startswith("falcon"):
         from xllm_service_tpu.models import granite
 
@@ -899,7 +1014,8 @@ def _in_place_case(one_chip, case):
 @pytest.mark.parametrize("case", ["brumby-decode-24", "deepseek-decode-3", "deepseek-mixed-576",
                                   "solar-decode-96", "solar-mixed-608", "mimo-decode-64",
                                   "mimo-mixed-576", "falcon-decode-64", "falcon-mixed-320",
-                                  "sala-decode-32", "sala-mixed-2080"])
+                                  "sala-decode-32", "sala-mixed-2080", "laguna-decode-64",
+                                  "laguna-mixed-576"])
 def test_step_reads_every_weight_leaf_where_it_lies(one_chip, no_persistent_cache, as_on_tpu, case):
     """The brumby decode step at reason-batch's 24 rows and the deepseek
     decode (3 rows) and mixed (64 + 512 rows) steps of doc-steady, at the
@@ -925,7 +1041,12 @@ def test_step_reads_every_weight_leaf_where_it_lies(one_chip, no_persistent_cach
     1,024 blocks wide: the sparse layer's matrices and gate (a query group
     of 16), the lightning layers' five square matrices, the dense MLP,
     with the lightning update kernel, the decode kernel a KV head a row,
-    the write kernel and (mixed) the flash kernel in the program."""
+    the write kernel and (mixed) the flash kernel in the program. The
+    Laguna family's decode (64 rows) and mixed (64 + 512 rows) steps at
+    laguna-xs.2's widths, every table 264 blocks wide: both kinds'
+    matrices at their own query heads (48 and 64) and their per-head
+    gates, the dense first layer, the 256 experts' stacks and the shared
+    expert, with all four attention launches of the cell in the program."""
     fn, args, names = _in_place_case(one_chip, case)
     text = jax.jit(fn, donate_argnums=(1, 2)).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text  # the kernels' branch, as on the chip
@@ -943,6 +1064,20 @@ def test_step_reads_every_weight_leaf_where_it_lies(one_chip, no_persistent_cach
         for name in launches:
             assert f'"{name}"' in text or f"{name}" in text, name
         assert ("window_flash_prefill_kernel" in text) == (case == "mimo-mixed-576")
+    elif case.startswith("laguna"):
+        # (not the shared expert: its [2048, 512] is the shape of the chunk's rows and of 64
+        # rows' 512 pairs; not the window layers' gate: its [2048, 64] is the decode rows')
+        shared = ("w_sh_gate", "w_sh_up", "w_sh_down")
+        moved = _weight_leaves_moved(
+            text, args[0], tuple(n for n in names if n not in shared),
+            ("layers", "dense_layers", "attn"), dtype="bf16",
+        ) + _weight_leaves_moved(
+            text, args[0], tuple(n for n in names if n != "w_ogate"), ("attn_w",), dtype="bf16")
+        launches = ["paged_attention_kernel", "window_paged_attention_kernel", "kv_write_kernel",
+                    "moe_grouped_kernel"]
+        launches += ["flash_prefill_kernel", "window_flash_prefill_kernel"] * (case == "laguna-mixed-576")
+        for name in launches:
+            assert name in text, name
     elif case.startswith("falcon"):
         moved = _weight_leaves_moved(text, args[0], names, ("layers", "mamba", "attn"), dtype="bf16")
         launches = ["mamba_update_kernel", "paged_attention_kernel", "kv_write_kernel"]

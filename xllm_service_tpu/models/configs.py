@@ -159,8 +159,11 @@ class ModelConfig:
     kda_gate_rank: int = 0
     kda_neg_eigval: bool = True
     # A hybrid stack's attention layers gate their output per lane,
-    # `W_o [sigmoid(W_gate x) * attn]`.
+    # `W_o [sigmoid(W_gate x) * attn]` (`W_gate` [E, Hq D]), or with
+    # `attn_gate_per_head` per HEAD (`W_gate` [E, Hq]: one sigmoid a
+    # query head on all of its lanes).
     attn_gate: bool = False
+    attn_gate_per_head: bool = False
     # A hybrid stack's WINDOW layers ("window" in layer_types: GQA over
     # the last `sliding_window` positions, in a paged pool of their own
     # whose blocks are freed behind the sequence) have their own KV head
@@ -170,9 +173,17 @@ class ModelConfig:
     # head's width (0 = head_dim), `rotary_dim` the lanes of a head,
     # from lane 0, that rotate (0 = no rotary: both older families are
     # NoPE), `attn_value_scale` multiplies the values.
+    # The two kinds may differ in QUERY heads and in rotary LANES too
+    # (`window_num_heads`, `window_rotary_dim`: 0 = as the full layers),
+    # and in the rotary TABLE: the full layers' follows `rope_scaling_*`
+    # over `rotary_dim` lanes (ops/rope.rope_parameters: YaRN's
+    # frequencies, its attention factor on cos and sin), the window
+    # layers' is plain at `window_rope_theta`.
     window_kv_heads: int = 0
     window_rope_theta: float = 10000.0
     window_sink: bool = False
+    window_num_heads: int = 0
+    window_rotary_dim: int = 0
     attn_v_head_dim: int = 0
     rotary_dim: int = 0
     attn_value_scale: float = 1.0
@@ -271,6 +282,14 @@ class ModelConfig:
         whose blocks a sequence frees once they are `sliding_window`
         behind it."""
         return self.layer_types.count("window")
+
+    def attn_heads(self, kind: str = "attention") -> int:
+        """Query heads of a hybrid stack's attention layers of `kind`."""
+        return (self.window_num_heads if kind == "window" else 0) or self.num_heads
+
+    def attn_rotary_dim(self, kind: str = "attention") -> int:
+        """Lanes of a head, from lane 0, that rotate in layers of `kind`."""
+        return (self.window_rotary_dim if kind == "window" else 0) or self.rotary_dim
 
     @property
     def num_sparse_layers(self) -> int:
@@ -390,17 +409,17 @@ def _hybrid_mixer_params(cfg: ModelConfig) -> int:
     """Mixer matrices of a hybrid stack over all its layers."""
     E, D, Dv = cfg.hidden_size, cfg.head_dim, cfg.value_head_dim
 
-    def gqa(kv_heads):  # q and k at D lanes a head, v and o at Dv
-        return E * (cfg.num_heads + kv_heads) * (D + Dv)
+    def gqa(kind, kv_heads):  # q and k at D lanes a head, v and o at Dv; the gate
+        heads = cfg.attn_heads(kind)
+        gate = heads * (1 if cfg.attn_gate_per_head else D) if cfg.attn_gate else 0
+        return E * ((heads + kv_heads) * (D + Dv) + gate)
 
-    full = gqa(cfg.num_kv_heads)
-    if cfg.attn_gate:
-        full += E * cfg.num_heads * cfg.head_dim
+    full = gqa("attention", cfg.num_kv_heads)
     kind = cfg.state_layer_kind
     state = _STATE_MIXER_PARAMS[kind](cfg) if kind else 0
     return (
         cfg.num_state_layers * state + cfg.num_attention_layers * full
-        + cfg.num_window_layers * gqa(cfg.window_kv_heads)
+        + cfg.num_window_layers * gqa("window", cfg.window_kv_heads)
     )
 
 
@@ -1137,6 +1156,99 @@ register(
         attn_v_head_dim=128,
         rotary_dim=64,
         attn_value_scale=0.707,
+        max_position_embeddings=262144,
+    )
+)
+
+_LAGUNA_PERIOD = ("attention", "window", "window", "window")
+
+register(
+    # Laguna-XS.2's stack at a test's size (tests/test_laguna.py): full
+    # GQA layers of 6 query heads beside window layers of 8, both over 2
+    # KV heads (query groups of 3 and 4), rotary on half a head with YaRN's
+    # table on the full layers and on the whole head with a plain table on
+    # the window layers (the last 24 positions: three blocks of 8), a gate
+    # per head on both kinds, a dense first layer, then 32 sigmoid-scored
+    # experts, all held, top 4 renormalised times 2.5, beside a shared one.
+    ModelConfig(
+        name="laguna-tiny",
+        vocab_size=512,
+        hidden_size=64,
+        intermediate_size=96,
+        num_layers=5,
+        num_heads=6,
+        num_kv_heads=2,
+        head_dim=16,
+        rope_theta=10000.0,
+        rope_scaling_type="yarn",
+        rope_scaling_factor=16.0,
+        rope_original_max_position=64,
+        rope_beta_fast=4.0,
+        rope_beta_slow=1.0,
+        rope_attention_factor=1.27726,
+        rms_norm_eps=1e-6,
+        num_experts=32,
+        num_experts_per_tok=4,
+        moe_intermediate_size=32,
+        n_shared_experts=1,
+        scoring_func="sigmoid",
+        routed_scaling_factor=2.5,
+        first_k_dense_replace=1,
+        layer_types=_LAGUNA_PERIOD + ("attention",),
+        sliding_window=24,
+        window_kv_heads=2,
+        window_num_heads=8,
+        window_rope_theta=10000.0,
+        rotary_dim=8,
+        window_rotary_dim=16,
+        attn_gate=True,
+        attn_gate_per_head=True,
+        max_position_embeddings=4096,
+    )
+)
+
+register(
+    # https://huggingface.co/poolside/Laguna-XS.2/blob/main/config.json
+    # (model_type laguna), 33.4B-A3B, as ONE PIPELINE STAGE of it at every
+    # published width: published layers 0-4 of 40 (layer 0 full attention
+    # and a dense MLP, then window, window, window, full with experts), ALL
+    # 256 experts a layer (sigmoid scores, top 8 renormalised times 2.5)
+    # beside a shared one, the whole vocabulary: 3,869.8 M parameters, 7.74
+    # GB (benchmarks/configs/laguna-xs.2.json has the deployment). Random
+    # weights only: runtime/weights.py has no loader.
+    ModelConfig(
+        name="laguna-xs.2",
+        vocab_size=100352,
+        hidden_size=2048,
+        intermediate_size=8192,
+        num_layers=5,
+        num_heads=48,
+        num_kv_heads=8,
+        head_dim=128,
+        rope_theta=500000.0,
+        rope_scaling_type="yarn",
+        rope_scaling_factor=64.0,
+        rope_original_max_position=4096,
+        rope_beta_fast=64.0,
+        rope_beta_slow=1.0,
+        rope_attention_factor=1.4158883083359672,
+        rms_norm_eps=1e-6,
+        num_experts=256,
+        num_experts_per_tok=8,
+        moe_intermediate_size=512,
+        n_shared_experts=1,
+        scoring_func="sigmoid",
+        routed_scaling_factor=2.5,
+        first_k_dense_replace=1,
+        layer_types=_LAGUNA_PERIOD + ("attention",),
+        sliding_window=512,
+        window_kv_heads=8,
+        window_num_heads=64,
+        window_rope_theta=10000.0,
+        rotary_dim=64,
+        window_rotary_dim=128,
+        attn_gate=True,
+        attn_gate_per_head=True,
         max_position_embeddings=262144,
     )
 )

@@ -10,7 +10,9 @@ multipliers), Solar-Open2 (`solar_open2`: KDA state layers, a gate on
 the GQA layers, an untied head, every multiplier 1), MiMo-V2-Flash
 (`mimo_v2_flash`: window layers beside full GQA layers, rotary on part
 of a head, key heads wider than value heads, a dense first layer,
-sigmoid-scored experts and no shared one) and Falcon-H1 (`falcon_h1`:
+sigmoid-scored experts and no shared one), Laguna (`laguna`: window and
+full GQA layers that differ in QUERY heads, rotary lanes and rotary table,
+a gate per head, a shared expert under a routed scale) and Falcon-H1 (`falcon_h1`:
 every block the parallel kind, full rotary, two B/C groups, a dense MLP,
 muP multipliers inside the projections) and MiniCPM-SALA
 (`minicpm_sala`: block-sparse attention layers whose queries select their
@@ -44,12 +46,16 @@ four multipliers of the configuration (each 1 by default):
 * "attention": GQA, no bias, scores scaled by `attention_multiplier` (0
   = head_dim**-0.5), causal, full, over the paged K/V pool; with
   `cfg.attn_gate` the output is gated per lane before `W_o`,
-  `W_o [sigmoid(W_gate u) * attn]`. Rotary on lanes [0, `rotary_dim`) of
+  `W_o [sigmoid(W_gate u) * attn]` (with `attn_gate_per_head` per head:
+  `W_gate` [E, Hq]). Rotary on lanes [0, `rotary_dim`) of
   every q and k head (0: none; Granite and Solar-Open2 are NoPE by
-  construction), theta `rope_theta`; values `attn_value_scale * W_v u`,
-  `attn_v_head_dim` lanes a head where that is set.
-* "window": the same mixer with `window_kv_heads` KV heads, theta
-  `window_rope_theta`, over the last `sliding_window` positions
+  construction), theta `rope_theta`, or with `rope_scaling_type` the
+  scaled table of ops/rope.rope_parameters over those lanes, its
+  attention factor on cos and sin (`rotary_tables`); values
+  `attn_value_scale * W_v u`, `attn_v_head_dim` lanes a head where set.
+* "window": the same mixer with `window_kv_heads` KV heads (and
+  `window_num_heads` query heads, `window_rotary_dim` lanes, where set),
+  plain theta `window_rope_theta`, over the last `sliding_window` positions
   (j > i - window) and, with `window_sink`, a learned logit a query head
   in the softmax's denominator whose mass is dropped, over a paged pool
   of its own.
@@ -142,6 +148,7 @@ touched expert streams once a step.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
@@ -216,11 +223,13 @@ def attention_routes(
     (a sink takes the verify shapes off the multi-query kernel). The
     mixer pads its queries to the pool's key row, so that is their width."""
 
-    def over(K, sinks=False):
-        return pool_routes(K, cfg.num_heads, kvc.raw(K).shape[-1], tp=tp, sinks=sinks)
+    def over(K, kind="attention", sinks=False):
+        return pool_routes(K, cfg.attn_heads(kind), kvc.raw(K).shape[-1], tp=tp, sinks=sinks)
 
     full = over(k_caches[0])
-    return (full, over(k_caches[1], cfg.window_sink)) if cfg.num_window_layers else (full,)
+    if not cfg.num_window_layers:
+        return (full,)
+    return full, over(k_caches[1], "window", cfg.window_sink)
 
 
 def kernel_report(
@@ -244,7 +253,72 @@ def kernel_report(
         rep["window"] = f"window-{'pallas' if w.decode else 'xla'}"
     else:
         rep["window"] = f"window-{w.report()['mixed']}"
+    # by kind: the launch, the query group, the window in blocks of the
+    # pool and the rotary table its rows take
+    BS = kvc.raw(k_caches[1]).shape[-2]
+    tables = rotary_tables(cfg)
+    rep["kinds"] = {
+        kind: {
+            "launch": launch,
+            "query_group": cfg.attn_heads(kind) // kv_heads,
+            "window_blocks": -(-cfg.sliding_window // BS) if kind == "window" else 0,
+            "rotary": tables[kind].name,
+        }
+        for kind, launch, kv_heads in (
+            ("attention", rep["mixed"], cfg.num_kv_heads),
+            ("window", rep["window"], cfg.window_kv_heads),
+        )
+    }
     return rep
+
+
+# rope_scaling types whose frequencies are one table for every position
+# (ops/rope.rope_parameters); "longrope" chooses short or long by position
+ONE_TABLE_SCALINGS = ("linear", "dynamic", "llama3", "yarn")
+
+
+class RotaryTable(NamedTuple):
+    """What an attention kind's q and k rotate by: `lanes` of a head from
+    lane 0 (0: none), at plain `theta`, or by a scaled table `inv_freq`
+    [lanes / 2] whose `scale` multiplies cos and sin."""
+
+    lanes: int
+    theta: float
+    inv_freq: Optional[np.ndarray] = None
+    scale: float = 1.0
+    scaling: str = ""  # "yarn x64": the scaled table's kind and factor
+
+    @property
+    def name(self) -> str:
+        if not self.lanes:
+            return "none"
+        return f"{self.scaling or 'plain'} / {self.lanes} lanes"
+
+
+@functools.lru_cache(maxsize=None)
+def rotary_tables(cfg: ModelConfig) -> Dict[str, RotaryTable]:
+    """The rotary table of each attention kind of the stack, made once a
+    configuration: the full layers' over `rotary_dim` lanes at
+    `rope_theta`, scaled where `rope_scaling_type` says (YaRN's
+    frequencies and attention factor over THOSE lanes:
+    ops/rope.rope_parameters at dim = rotary_dim); the window layers' over
+    `window_rotary_dim` lanes, plain at `window_rope_theta`."""
+    lanes = cfg.attn_rotary_dim("attention")
+    full = RotaryTable(lanes, cfg.rope_theta)
+    if cfg.rope_scaling_type not in ("",) + ONE_TABLE_SCALINGS:
+        raise ValueError(
+            f"rope_scaling_type {cfg.rope_scaling_type!r} is not built for the hybrid "
+            f"stack: its attention layers rotate by ONE table a kind "
+            f"({', '.join(ONE_TABLE_SCALINGS)}), and this type picks a table by position"
+        )
+    if lanes and cfg.rope_scaling_type:
+        inv_freq, scale = rope_ops.rope_parameters(lanes, cfg)
+        full = RotaryTable(
+            lanes, cfg.rope_theta, inv_freq, scale,
+            f"{cfg.rope_scaling_type} x{cfg.rope_scaling_factor:g}",
+        )
+    return {"attention": full,
+            "window": RotaryTable(cfg.attn_rotary_dim("window"), cfg.window_rope_theta)}
 
 
 def state_shapes(cfg: ModelConfig, slots: int, blocks: int = 0):
@@ -364,21 +438,31 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
             cfg, w, ones, Ls
         )
 
-    def gqa(layers, kv_heads):
+    def gqa(layers, kv_heads, heads=Hq):
         return {
-            "wq": w((layers, E, Hq * D), E), "wk": w((layers, E, kv_heads * D), E),
-            "wv": w((layers, E, kv_heads * Dv), E), "wo": w((layers, Hq * Dv, E), Hq * Dv),
+            "wq": w((layers, E, heads * D), E), "wk": w((layers, E, kv_heads * D), E),
+            "wv": w((layers, E, kv_heads * Dv), E),
+            "wo": w((layers, heads * Dv, E), heads * Dv),
         }
+
+    if cfg.attn_gate_per_head and not cfg.attn_gate:
+        raise ValueError("attn_gate_per_head says which form attn_gate takes: set attn_gate too")
+
+    def gate(layers, heads=Hq):  # a sigmoid a lane, or a head
+        return w((layers, E, heads if cfg.attn_gate_per_head else heads * D), E)
 
     params["attn"] = gqa(La, Hkv)
     if cfg.attn_gate:
-        params["attn"]["w_ogate"] = w((La, E, Hq * D), E)
+        params["attn"]["w_ogate"] = gate(La)
     if cfg.num_sparse_layers and cfg.qk_norm:
         params["attn"].update({"q_norm": ones((La, D)), "k_norm": ones((La, D))})
     if Lw:
-        params["attn_w"] = gqa(Lw, cfg.window_kv_heads)
+        Hw = cfg.attn_heads("window")
+        params["attn_w"] = gqa(Lw, cfg.window_kv_heads, Hw)
+        if cfg.attn_gate:
+            params["attn_w"]["w_ogate"] = gate(Lw, Hw)
         if cfg.window_sink:
-            params["attn_w"]["sink"] = w((Lw, Hq), 1.0).astype(jnp.float32)
+            params["attn_w"]["sink"] = w((Lw, Hw), 1.0).astype(jnp.float32)
     if cfg.topk_method == "noaux_tc":  # the router's selection bias
         params["layers"]["router_bias"] = w((Lm, X), 100.0).astype(jnp.float32)
     if kd:
@@ -634,23 +718,28 @@ def _kda_mixer(lp, cfg: ModelConfig, h, m, S, conv, dec: Optional[_Dec],
 @region("attn_proj")
 def _qkv(lp, cfg: ModelConfig, h, kind="attention", positions=None):
     """h [T, E] -> q [T, Hq, D], k [T, Hkv, D], v [T, Hkv, Dv] of an
-    attention layer of `kind`: no bias; rotary on the first `rotary_dim`
-    lanes of q and k at `positions` [T], theta by kind (0 lanes: none);
-    the values times `attn_value_scale`."""
+    attention layer of `kind` (its own query heads): no bias; rotary on
+    the kind's first lanes of q and k at `positions` [T] by the kind's
+    table (`rotary_tables`: 0 lanes: none); the values times
+    `attn_value_scale`."""
     T = h.shape[0]
     window = kind == "window"
     Hkv = cfg.window_kv_heads if window else cfg.num_kv_heads
     fence = llama._plain_product  # the attention kernels and the K/V write take heads
-    q = fence(jnp.einsum("te,eh->th", h, wt(lp["wq"]))).reshape(T, cfg.num_heads, cfg.head_dim)
+    q = fence(jnp.einsum("te,eh->th", h, wt(lp["wq"]))).reshape(
+        T, cfg.attn_heads(kind), cfg.head_dim)
     k = fence(jnp.einsum("te,eh->th", h, wt(lp["wk"]))).reshape(T, Hkv, cfg.head_dim)
     v = fence(jnp.einsum("te,eh->th", h, wt(lp["wv"]))).reshape(T, Hkv, cfg.value_head_dim)
     if "q_norm" in lp:  # QK-norm: a sparse layer's (the cache holds the normed key)
         q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
         k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
-    if cfg.rotary_dim:
-        theta = cfg.window_rope_theta if window else cfg.rope_theta
-        q = rope_ops.apply_partial_rope(q, positions, theta, cfg.rotary_dim)
-        k = rope_ops.apply_partial_rope(k, positions, theta, cfg.rotary_dim)
+    table = rotary_tables(cfg)[kind]
+    if table.inv_freq is not None:
+        q, k = (rope_ops.apply_partial_rope_table(
+            t, positions, table.inv_freq, table.scale, table.lanes) for t in (q, k))
+    elif table.lanes:
+        q = rope_ops.apply_partial_rope(q, positions, table.theta, table.lanes)
+        k = rope_ops.apply_partial_rope(k, positions, table.theta, table.lanes)
     if cfg.attn_value_scale != 1.0:
         v = (v.astype(jnp.float32) * cfg.attn_value_scale).astype(v.dtype)
     return q, k, v
@@ -671,13 +760,17 @@ def _pad_lanes(x, lanes: int):
 
 def _gated(lp, cfg: ModelConfig, h, o):
     """The attention output o [T, Hq D] through the layer's gate,
-    sigmoid(W_gate h) per lane, where the configuration has one."""
+    sigmoid(W_gate h) per lane (or per head: `W_gate` [E, Hq], a head's
+    sigmoid on all of its lanes), where the configuration has one."""
     if not cfg.attn_gate:
         return o
-    gate = llama._plain_product(jnp.einsum(
+    gate = jax.nn.sigmoid(llama._plain_product(jnp.einsum(
         "te,eh->th", h, wt(lp["w_ogate"]), preferred_element_type=jnp.float32
-    ))
-    return jax.nn.sigmoid(gate) * o
+    )))
+    if cfg.attn_gate_per_head:
+        T, Hq = gate.shape
+        return (gate[:, :, None] * o.reshape(T, Hq, -1)).reshape(o.shape)
+    return gate * o
 
 
 def _attn_mixer(lp, cfg: ModelConfig, h, a, K, V, dec: Optional[_Dec],

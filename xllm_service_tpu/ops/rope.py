@@ -268,3 +268,21 @@ def apply_partial_rope(
         return apply_rope(x, positions, theta)
     rot = apply_rope(x[..., :rotary_dim], positions, theta)
     return jnp.concatenate([rot, x[..., rotary_dim:]], axis=-1)
+
+
+def apply_partial_rope_table(
+    x: jnp.ndarray,  # [..., num_heads, head_dim]
+    positions: jnp.ndarray,  # [...] int32, broadcastable to x's batch dims
+    inv_freq,  # [rotary_dim / 2]: a scaled table (rope_parameters)
+    scale: float,
+    rotary_dim: int,
+) -> jnp.ndarray:
+    """apply_partial_rope by a table made at build: lanes [0, rotary_dim)
+    of every head rotate by `positions * inv_freq` with `scale` on cos and
+    sin (YaRN's attention factor: the rotated lanes of q AND k carry it,
+    the lanes that pass do not)."""
+    angles = positions[..., None].astype(jnp.float32) * jnp.asarray(inv_freq, jnp.float32)
+    rot = _rotate(x[..., :rotary_dim], angles, scale)
+    if rotary_dim >= x.shape[-1]:
+        return rot
+    return jnp.concatenate([rot, x[..., rotary_dim:]], axis=-1)

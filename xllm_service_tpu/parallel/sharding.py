@@ -204,7 +204,7 @@ def _hybrid_param_shardings(cfg: ModelConfig, ns) -> Dict[str, Any]:
         tree[cfg.state_layer_kind] = state_stacks[cfg.state_layer_kind]
     if cfg.num_window_layers:
         tree["attn_w"] = {
-            **rep(("wq", "wk", "wv", "wo"), 3),
+            **rep(("wq", "wk", "wv", "wo") + (("w_ogate",) if cfg.attn_gate else ()), 3),
             **rep(("sink",) if cfg.window_sink else (), 2),
         }
     if cfg.first_k_dense_replace:

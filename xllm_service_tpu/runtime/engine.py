@@ -1048,6 +1048,13 @@ class InferenceEngine:
                 "layers and steps: each is one expert's weights read",
             ).set_function(lambda: _snap()["touched"])
             self.metrics.counter(
+                "xllm_engine_moe_experts_held_total",
+                "Held experts x expert layers, summed over step program "
+                "runs: what xllm_engine_moe_experts_touched_total is a "
+                "share of (touched / held = the share of the held experts' "
+                "weights a layer and step streams)",
+            ).set_function(lambda: _snap()["held_reads"])
+            self.metrics.counter(
                 "xllm_engine_moe_dropped_total",
                 "Pairs of a held expert that were not computed: 0 by "
                 "construction (the grouped product has no capacity); a "

@@ -826,6 +826,7 @@ class ModelExecutor:
         )  # guarded by: self._moe_mu
         # (held expert, layer) meetings: a layer read the expert's weights
         self._moe_touched = 0  # guarded by: self._moe_mu
+        self._moe_held_reads = 0  # guarded by: self._moe_mu
 
     # ------------------------------------------------------- multi-LoRA
 
@@ -1060,24 +1061,35 @@ class ModelExecutor:
     def _refuse_for_window_family(self, tp: int, ep: int) -> None:
         """What is not built for a family with window layers, by name, at
         build."""
-        e = self.engine_cfg
+        e, cfg = self.engine_cfg, self.cfg
         if tp > 1 or ep > 1 or e.sp_size > 1 or e.dp_size > 1:
+            # what a shard of each kind would hold differs by kind
+            differ = (
+                f"query heads ({cfg.attn_heads('attention')} and "
+                f"{cfg.attn_heads('window')} over {cfg.num_kv_heads} KV heads)"
+                if cfg.attn_heads("window") != cfg.num_heads else "KV heads"
+            )
+            sink = (
+                " and the window layers' kernels take their sink whole"
+                if cfg.window_sink else ""
+            )
             raise WindowFamilyUnsupported(
                 f"tp_size/ep_size/sp_size/dp_size > 1: the two pools of "
-                f"{self.cfg.name} differ in KV heads and the window "
-                f"layers' kernels take their sink whole (a launch a shard "
-                f"is not built)"
+                f"{cfg.name} differ in {differ}{sink} (a launch a shard of "
+                f"each kind over two block tables is not built)"
             )
         if e.kv_cache_dtype != "auto":
             raise WindowFamilyUnsupported(
                 f"kv_cache_dtype={e.kv_cache_dtype!r}: the window family's "
-                f"pools hold key rows wider than value rows in the model's "
-                f"dtype; an int8 layout for them is not built"
+                f"two pools are laid out in the model's dtype (key rows may "
+                f"be wider than value rows); an int8 layout with scales for "
+                f"a second pool is not built"
             )
         if e.speculative_tokens > 0:
             raise WindowFamilyUnsupported(
-                "speculative_tokens > 0: the verify launch has no sink "
-                "logit and no window table"
+                "speculative_tokens > 0: the verify launch takes no window "
+                "table" + (" and no sink logit" if cfg.window_sink else "")
+                + " (a draft's rows would attend past the window)"
             )
         if e.num_host_blocks > 0 or e.num_ssd_blocks > 0:
             raise WindowFamilyUnsupported(
@@ -2288,6 +2300,9 @@ class ModelExecutor:
         with self._moe_mu:
             self._moe_counts += step[:X].astype(np.int64)
             self._moe_touched += int(step[X + lo:X + lo + n].sum())
+            # what `touched` is a share of: every held expert of every
+            # expert layer, once a step program run
+            self._moe_held_reads += len(pending) * n * self.cfg.expert_layers
         return step[lo:lo + n] / max(1, self.cfg.expert_layers)
 
     def moe_stats(self, drain: bool = False) -> Dict[str, float]:
@@ -2304,7 +2319,7 @@ class ModelExecutor:
             self.book_moe()
         with self._moe_mu:
             counts = self._moe_counts.copy()
-            touched = self._moe_touched
+            touched, held_reads = self._moe_touched, self._moe_held_reads
         total = int(counts.sum())
         lo, n = self.cfg.held_experts
         held = int(counts[lo:lo + n].sum())
@@ -2315,6 +2330,7 @@ class ModelExecutor:
             "held": held,
             "absent": total - held,
             "touched": touched,
+            "held_reads": held_reads,
             "dropped": 0,
             "hot_expert_frac": (
                 float(counts.max()) / total if total else 0.0
